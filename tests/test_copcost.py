@@ -274,6 +274,30 @@ def test_seeded_padding_waste_is_a_gate_finding():
 # sched admission: budget + deferral + window feedback
 # ------------------------------------------------------------------ #
 
+def test_auto_budget_has_no_default_on_a_tpu_mesh():
+    """The auto budget comes from the device's own bytes_limit.  A CPU
+    mesh reports none and takes the host constant; a TPU mesh that
+    reports none raises instead of admitting against a guess."""
+    from types import SimpleNamespace
+
+    from tidb_tpu.analysis.copcost import (DEFAULT_CPU_HBM_BUDGET,
+                                           HBM_BUDGET_FRACTION,
+                                           mesh_hbm_budget)
+
+    def fake_mesh(platform, stats, n=4):
+        dev = SimpleNamespace(platform=platform,
+                              memory_stats=lambda: stats)
+        return SimpleNamespace(devices=np.array([dev] * n, dtype=object))
+
+    assert mesh_hbm_budget(fake_mesh("cpu", None)) == DEFAULT_CPU_HBM_BUDGET
+    limit = 16 << 30
+    assert mesh_hbm_budget(fake_mesh("tpu", {"bytes_limit": limit})) \
+        == int(HBM_BUDGET_FRACTION * limit) * 4
+    for stats in (None, {}, {"bytes_in_use": 1}):
+        with pytest.raises(RuntimeError, match="bytes_limit"):
+            mesh_hbm_budget(fake_mesh("tpu", stats))
+
+
 def test_budget_rejects_pre_trace_and_query_errors_cleanly(monkeypatch):
     """Integration: tidb_tpu_sched_hbm_budget below the query footprint
     => the statement fails with a structured planner-style error BEFORE

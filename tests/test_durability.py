@@ -117,11 +117,24 @@ def test_hard_kill_mid_workload(tmp_path):
         # crash-test child must not touch (durability lives in the WAL; the
         # SQL-level restart story is test_schema_and_data_survive_restart)
         import importlib.util
+        import os
         import sys
-        spec = importlib.util.spec_from_file_location("kvmod", %r)
+        import types
+        kv_py = %r
+        # stand-in parents so kv.py's `from ..native import ensure_built`
+        # resolves (tidb_tpu/native is jax-free) without running them
+        pkg_dir = os.path.dirname(os.path.dirname(kv_py))
+        for name, path in (("tidb_tpu", pkg_dir),
+                           ("tidb_tpu.store", os.path.dirname(kv_py))):
+            mod = types.ModuleType(name)
+            mod.__path__ = [path]
+            sys.modules[name] = mod
+        spec = importlib.util.spec_from_file_location("tidb_tpu.store.kv",
+                                                      kv_py)
         kvmod = importlib.util.module_from_spec(spec)
-        sys.modules["kvmod"] = kvmod   # dataclasses resolves via sys.modules
+        sys.modules[spec.name] = kvmod  # dataclasses resolves via sys.modules
         spec.loader.exec_module(kvmod)
+        assert "jax" not in sys.modules
         s = kvmod.KVStore(path=%r, sync=True)
         i = 0
         while True:

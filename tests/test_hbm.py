@@ -175,6 +175,17 @@ def test_backend_peaks_declared_for_tpu_microbench_for_cpu():
     assert bw > 1e8 and fl > 1e8        # calibrated-at-boot, not zero
 
 
+def test_backend_peaks_v5_lite_is_v5e_and_unknown_tpu_raises():
+    """jax calls a v5e chip "TPU v5 lite": it takes the v5e row by name,
+    not a catch-all, and a TPU with no row is an error, not a default."""
+    bw, fl, src = backend_peaks("TPU v5 lite")
+    assert (bw, fl) == (819e9, 197e12) and src == "declared:v5e"
+    assert backend_peaks("TPU v5e")[2] == "declared:v5e"
+    assert backend_peaks("TPU v5p")[:2] == (2765e9, 459e12)
+    with pytest.raises(ValueError, match="TPU v9"):
+        backend_peaks("TPU v9")
+
+
 def test_roofline_classification_three_bounds():
     peaks = (100e9, 100e9)              # 100 GB/s, 100 GFLOP/s
     mem = RoofStat(ewma_ms=10.0, transfer_bytes=800_000_000,

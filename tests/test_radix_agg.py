@@ -1,14 +1,13 @@
 """SCATTER-strategy device group-by (multi-pass scatter radix
-partition + first Pallas TPU kernel, ISSUE 11).
+partition, ISSUE 11).
 
 Layers under test:
 
 - kernel exactness: the SCATTER device program is bit-identical to the
   SEGMENT and SORT programs and the numpy oracle on the 8-vdev CPU mesh
   (NULL keys, multi-column keys, decimal limb sums past int64),
-- lowering equivalence: the Pallas kernels (interpret mode on the CPU
-  mesh) and the XLA 1-bit lowering produce the identical stable
-  permutation, hence bit-identical states,
+- partition exactness: the XLA 1-bit lowering returns the stable
+  bucket-major permutation numpy's stable argsort gives,
 - capacity discipline: the client regrows num_buckets from observed
   __ngroups__ (paging analog) on the SCATTER path too,
 - prehash hoist (satellite): a regrow sequence traces the avalanche
@@ -21,8 +20,7 @@ Layers under test:
   beats SCATTER's flips planner strategy selection with NO code change,
 - fusion: ('scatter-agg', B, passes) signature refuses mismatched
   bucket spaces; the SORT capacity-bucketed class refuses mismatched
-  capacities (fusion-breadth satellite),
-- gate/lint: TPU-PALLAS-SHAPE seeded violations.
+  capacities (fusion-breadth satellite).
 """
 
 import jax
@@ -36,7 +34,6 @@ from tidb_tpu.analysis.contracts import (PlanContractError,
                                          fusion_signature, verify_dag,
                                          verify_fusion_group)
 from tidb_tpu.analysis.copcost import cost_findings
-from tidb_tpu.analysis.lint import lint_source
 from tidb_tpu.chunk.column import Column
 from tidb_tpu.copr import dag as D
 from tidb_tpu.copr import radix, segment
@@ -54,14 +51,6 @@ N_DEV = 8
 @pytest.fixture(scope="module")
 def mesh():
     return get_mesh()
-
-
-@pytest.fixture(autouse=True)
-def _auto_pallas_mode():
-    """Every test starts and ends in the default gate mode."""
-    radix.set_pallas_mode("auto")
-    yield
-    radix.set_pallas_mode("auto")
 
 
 def _snap(names, cols, n_shards=8):
@@ -186,50 +175,23 @@ def test_scatter_decimal_sum_past_int64(mesh):
 
 
 # ------------------------------------------------------------------ #
-# Pallas interpret mode vs XLA lowering
+# the partition itself
 # ------------------------------------------------------------------ #
 
-def test_pallas_interpret_and_xla_permutations_identical():
-    """Both lowerings are stable LSD radix sorts of the same partition
-    key, so they return THE identical permutation — checked directly on
-    the kernel seam (single device, no mesh)."""
+def test_scatter_permutation_is_stable_bucket_major_order():
+    """The partition is a stable LSD radix sort of the partition key:
+    exactly numpy's stable argsort of it (single device, no mesh)."""
     rng = np.random.default_rng(5)
     n = 10_000
     h = jax.numpy.asarray(
         rng.integers(0, 1 << 63, n, dtype=np.uint64), dtype=jax.numpy.uint64)
     sel = jax.numpy.asarray(rng.random(n) < 0.95)
     for num_buckets in (1024, 1 << 15):
-        radix.set_pallas_mode("off")
-        p_xla = np.asarray(
-            radix.scatter_permutation(h, sel, num_buckets, n, "cpu"))
-        radix.set_pallas_mode("on")
-        p_pal = np.asarray(
-            radix.scatter_permutation(h, sel, num_buckets, n, "cpu"))
-        assert (p_xla == p_pal).all()
-        # and the permutation really is the stable bucket-major order
+        perm = np.asarray(radix.scatter_permutation(h, sel, num_buckets, n))
         bits = D.radix_key_bits(num_buckets) - 1
         keys = np.asarray(h >> np.uint64(64 - bits)).astype(np.int64)
         keys[~np.asarray(sel)] = 1 << bits
-        assert (p_xla == np.argsort(keys, kind="stable")).all()
-
-
-def test_pallas_interpret_program_bit_identical_to_xla(mesh):
-    """End-to-end: the full sharded SCATTER program under the Pallas
-    gate (interpret mode on the CPU mesh) equals the XLA lowering bit
-    for bit; programs cache apart per gate mode (no stale serve)."""
-    rng = np.random.default_rng(23)
-    n = 30_000
-    k = rng.integers(0, 9000, n).astype(np.int64)
-    snap = _snap(["k"], [Column(dt.bigint(False), k, np.ones(n, bool))])
-    agg = _scatter_dag(1 << 14)
-    meta = [GroupKeyMeta(dt.bigint(False), 0)]
-    radix.set_pallas_mode("on")
-    m_pallas = _as_map(*_run_host_merged(agg, snap, meta, mesh))
-    radix.set_pallas_mode("off")
-    m_xla = _as_map(*_run_host_merged(agg, snap, meta, mesh))
-    assert m_pallas == m_xla
-    uk, uc = np.unique(k, return_counts=True)
-    assert m_xla == {(int(a),): (int(c),) for a, c in zip(uk, uc)}
+        assert (perm == np.argsort(keys, kind="stable")).all()
 
 
 # ------------------------------------------------------------------ #
@@ -530,51 +492,3 @@ def test_same_capacity_sort_tasks_fuse_into_one_launch(mesh):
         flat_s, _ = jax.tree_util.tree_flatten(solo)
         assert all((np.asarray(x) == np.asarray(y)).all()
                    for x, y in zip(flat_f, flat_s))
-
-
-# ------------------------------------------------------------------ #
-# TPU-PALLAS-SHAPE lint rule
-# ------------------------------------------------------------------ #
-
-def test_pallas_shape_lint_rule():
-    clean = (
-        "import jax\n"
-        "from jax.experimental import pallas as pl\n"
-        "TILE = 256\n"
-        "def f(x, n_tiles):\n"
-        "    return pl.pallas_call(k, grid=(n_tiles,),\n"
-        "        in_specs=[pl.BlockSpec((TILE,), lambda t: (t,))],\n"
-        "        out_specs=pl.BlockSpec((TILE,), lambda t: (t,)))(x)\n")
-    assert not [f for f in lint_source(clean, "copr/pallas/x.py")
-                if f.rule == "TPU-PALLAS-SHAPE"]
-    # cdiv is shape arithmetic — allowed
-    ok = clean.replace("grid=(n_tiles,)", "grid=(pl.cdiv(n, TILE),)")
-    assert not [f for f in lint_source(ok, "copr/pallas/x.py")
-                if f.rule == "TPU-PALLAS-SHAPE"]
-    # a call deriving the grid from data is not static
-    bad_grid = clean.replace("grid=(n_tiles,)",
-                             "grid=(compute_tiles(x),)")
-    finds = [f for f in lint_source(bad_grid, "copr/pallas/x.py")
-             if f.rule == "TPU-PALLAS-SHAPE"]
-    assert finds and "non-static grid" in finds[0].message
-    # non-static block shape
-    bad_block = clean.replace("pl.BlockSpec((TILE,), lambda t: (t,))],",
-                              "pl.BlockSpec((sz(x),), lambda t: (t,))],")
-    assert [f for f in lint_source(bad_block, "copr/pallas/x.py")
-            if f.rule == "TPU-PALLAS-SHAPE"]
-    # host callbacks never belong in a kernel module
-    cb = clean + "def g(x):\n    return jax.pure_callback(f, x, x)\n"
-    finds = [f for f in lint_source(cb, "copr/pallas/x.py")
-             if f.rule == "TPU-PALLAS-SHAPE"]
-    assert finds and "callback" in finds[0].message
-    # scoped: the same source outside copr/pallas/ is not judged
-    assert not [f for f in lint_source(cb, "copr/other.py")
-                if f.rule == "TPU-PALLAS-SHAPE"]
-    # the real kernel module is clean
-    import os
-    root = os.path.join(os.path.dirname(__file__), "..", "tidb_tpu")
-    with open(os.path.join(root, "copr", "pallas", "radix_kernel.py"),
-              encoding="utf-8") as fh:
-        assert not [f for f in
-                    lint_source(fh.read(), "copr/pallas/radix_kernel.py")
-                    if f.rule == "TPU-PALLAS-SHAPE"]

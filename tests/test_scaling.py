@@ -110,10 +110,6 @@ def test_per_device_flops_scale(mesh):
     prog1 = copr.get_program(agg)
     single = jax.jit(prog1._trace).lower(
         dev_cols(cols), jnp.int64(len(cols[0]))).compile().cost_analysis()
-    if isinstance(fl8, list):      # jax 0.4.x returns [dict], >=0.5 dict
-        fl8 = fl8[0] if fl8 else {}
-    if isinstance(single, list):
-        single = single[0] if single else {}
     f8, f1 = fl8.get("flops", 0.0), single.get("flops", 0.0)
     if not f8 or not f1:
         pytest.skip("backend reports no flops estimate")

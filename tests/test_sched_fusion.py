@@ -277,6 +277,33 @@ def test_incompatible_tables_do_not_fuse_end_to_end():
     assert sched.fused_launches == f0
 
 
+def test_refused_fused_launch_is_counted_and_results_unchanged(
+        monkeypatch, caplog):
+    """A fused launch that raises is served apart with the same answers;
+    what says it happened is `fused_refused` on /sched and one warning."""
+    dom, s, _data = _fusion_domain()
+    queries = FUSION_QUERIES[:1] + FUSION_QUERIES[2:4]   # one fusion class
+    solo = [Session(dom).must_query(q) for q in queries]
+    sched = dom.client._sched_obj
+
+    def boom(*_a, **_kw):
+        raise RuntimeError("fused program refused by the backend")
+    monkeypatch.setattr(spmd, "get_fused_program", boom)
+    r0, f0 = sched.fused_refused, sched.fused_launches
+    sched._refusals_logged.clear()       # logged once per digest, ever
+    with caplog.at_level("WARNING", logger="tidb_tpu.sched.scheduler"):
+        out = _run_concurrent(dom, sched, queries)
+    assert [out[i] for i in range(len(queries))] == solo
+    assert sched.fused_launches == f0
+    assert sched.fused_refused == r0 + 1
+    st = dom.client.sched_stats()
+    assert st["fused_refused"] == sched.fused_refused
+    assert st["batched_refused"] == sched.batched_refused
+    assert any("fused launch refused" in r.getMessage()
+               and "refused by the backend" in r.getMessage()
+               for r in caplog.records)
+
+
 def test_rows_kind_batched_launch_splits_rows_per_task():
     """Same row-returning program, DIFFERENT snapshots: the scheduler
     stacks the inputs along a batch slot dim and runs ONE vmapped rows

@@ -21,42 +21,52 @@ import argparse
 import sys
 
 
-def _domain(args=None):
+def start_server(cfg):
+    """Domain + MySQL wire server + HTTP status server, all started:
+    what ``serve`` runs and what ``chip_smoke.py`` drives.  Port 0 binds
+    an ephemeral port (read it back from ``srv.port`` / ``st.port``)."""
+    from .config import apply_to_domain
+    from .server import MySQLServer, StatusServer
     from .session.session import Domain
-    data_dir = getattr(args, "data_dir", None)
-    if data_dir:
-        return Domain(data_dir=data_dir,
-                      sync=bool(getattr(args, "sync_wal", False)))
-    return Domain()
+    if cfg.data_dir:
+        dom = Domain(data_dir=cfg.data_dir, sync=bool(cfg.sync_wal))
+    else:
+        dom = Domain()
+    apply_to_domain(cfg, dom)
+    dom.start_background()
+    srv = MySQLServer(dom, host=cfg.host, port=cfg.port)
+    srv.start()
+    st = StatusServer(dom, host=cfg.host, port=cfg.status_port)
+    st.start()
+    return dom, srv, st
 
 
 def cmd_serve(args) -> int:
+    import logging
     import time
-    from .config import apply_to_domain, load_config
-    from .server import MySQLServer, StatusServer
+    from .jaxcache import place_jax_compile_cache
+    from .config import load_config
     cfg = load_config(getattr(args, "config", None))
     # precedence: explicit CLI flag > config file > built-in default
     # (argparse defaults are None sentinels so an explicit flag at its
     # default value still wins)
-    if args.host is None:
-        args.host = cfg.host
-    if args.port is None:
-        args.port = cfg.port
-    if args.status_port is None:
-        args.status_port = cfg.status_port
-    if getattr(args, "data_dir", None) is None:
-        args.data_dir = cfg.data_dir
-    if not getattr(args, "sync_wal", False):
-        args.sync_wal = cfg.sync_wal
-    dom = _domain(args)
-    apply_to_domain(cfg, dom)
-    dom.start_background()
-    srv = MySQLServer(dom, host=args.host, port=args.port)
-    port = srv.start()
-    st = StatusServer(dom, host=args.host, port=args.status_port)
-    sport = st.start()
-    print(f"tidb-tpu server listening on {args.host}:{port} "
-          f"(status :{sport})", flush=True)
+    if args.host is not None:
+        cfg.host = args.host
+    if args.port is not None:
+        cfg.port = args.port
+    if args.status_port is not None:
+        cfg.status_port = args.status_port
+    if getattr(args, "data_dir", None) is not None:
+        cfg.data_dir = args.data_dir
+    if getattr(args, "sync_wal", False):
+        cfg.sync_wal = True
+    # INFO shows which devices the mesh resolved to on first dispatch
+    logging.basicConfig(level=logging.INFO,
+                        format="%(asctime)s %(name)s %(message)s")
+    place_jax_compile_cache()
+    _dom, srv, st = start_server(cfg)
+    print(f"tidb-tpu server listening on {cfg.host}:{srv.port} "
+          f"(status :{st.port})", flush=True)
     try:
         while True:
             time.sleep(3600)

@@ -678,16 +678,24 @@ def task_cost(task) -> Optional[LaunchCost]:
 
 def mesh_hbm_budget(mesh) -> int:
     """Default per-mesh HBM admission budget: a fraction of the
-    device-reported memory limit times the mesh size, with a host-memory
-    fallback when the backend exposes no stats (CPU meshes).  The raw
-    poll routes through obs/hbm — the single sanctioned memory_stats
-    seam (TPU-MEM-SOURCE)."""
+    device-reported memory limit times the mesh size.  CPU meshes
+    expose no stats and take a host-memory constant; a TPU mesh that
+    reports no ``bytes_limit`` is an error, never that constant (a
+    16 GiB guess would admit launches a smaller chip cannot hold).
+    The raw poll routes through obs/hbm — the single sanctioned
+    memory_stats seam (TPU-MEM-SOURCE)."""
     from ..obs.hbm import device_memory_stats
     stats = device_memory_stats(mesh)
     limit = int((stats or {}).get("bytes_limit", 0) or 0)
     n_dev = int(mesh.devices.size)
     if limit > 0:
         return int(HBM_BUDGET_FRACTION * limit) * n_dev
+    platform = mesh.devices.reshape(-1)[0].platform
+    if platform == "tpu":
+        raise RuntimeError(
+            "TPU mesh reports no bytes_limit in memory_stats(): the HBM "
+            "admission budget cannot be derived; set "
+            "tidb_tpu_sched_hbm_budget explicitly")
     return DEFAULT_CPU_HBM_BUDGET
 
 

@@ -103,17 +103,6 @@ Rules
                    lands in an ad-hoc counter is invisible to TRACE,
                    the flight recorder, and the latency histograms —
                    route every measured duration through obs/.
-- TPU-PALLAS-SHAPE in copr/pallas/ (the hand-written TPU kernel
-                   package): a ``pallas_call`` whose ``grid=`` or a
-                   ``BlockSpec`` whose block shape contains a
-                   non-static expression (any call besides the
-                   shape-arithmetic allowlist cdiv/len/min/max), or
-                   ANY host-callback use (pure_callback / io_callback /
-                   host_callback / debug_callback).  A traced-value
-                   grid recompiles per shape (or fails Mosaic
-                   outright); a host callback inside a kernel stalls
-                   the TPU pipeline on the host — both destroy exactly
-                   the performance a hand-written kernel exists for.
 - TPU-NARROW-CAST  a bit-narrowing ``.astype(...)`` (int8/16/32,
                    uint8/16/32, float16/bfloat16/float32 target) in a
                    traced module: a traced cast cannot raise on values
@@ -154,7 +143,6 @@ from typing import Iterable, Optional
 # legitimately concretize when xp is numpy.
 TRACED_MODULES = {
     "copr/exec.py", "copr/join.py", "copr/segment.py", "copr/radix.py",
-    "copr/pallas/radix_kernel.py",
     "parallel/spmd.py", "parallel/shuffle.py", "parallel/window.py",
     "parallel/exchange.py",
     # shardflow (ISSUE 12): the topology model and the sharding-flow
@@ -816,57 +804,6 @@ class _SpanLeakRules(_Scoped):
 
 
 # --------------------------------------------------------------------- #
-# rule: TPU-PALLAS-SHAPE (copr/pallas/ kernel hygiene)
-# --------------------------------------------------------------------- #
-
-# the hand-written TPU kernel package: every Pallas kernel lives here
-PALLAS_PREFIX = "copr/pallas/"
-# host-callback entry points that must never appear in a kernel module
-_HOST_CALLBACKS = frozenset({
-    "pure_callback", "io_callback", "host_callback", "debug_callback",
-    "call_host",
-})
-# calls allowed inside a static grid/block-shape expression: pure shape
-# arithmetic over module constants
-_SHAPE_CALL_ALLOW = frozenset({"cdiv", "len", "min", "max"})
-
-
-class _PallasRules(_Scoped):
-    """Kernel-module hygiene for copr/pallas/: static grids/blocks and
-    no host callbacks (see the rule table in the module docstring)."""
-
-    def visit_Call(self, node):
-        name = _call_name(node)
-        if name in _HOST_CALLBACKS:
-            self.add("TPU-PALLAS-SHAPE", node,
-                     f"{name}(...) in a Pallas kernel module: a host "
-                     "callback inside (or feeding) a TPU kernel stalls "
-                     "the device pipeline on the host — keep kernel "
-                     "modules callback-free")
-        elif name == "pallas_call":
-            for kw in node.keywords:
-                if kw.arg == "grid":
-                    self._check_static(kw.value, node, "grid")
-        elif name == "BlockSpec" and node.args:
-            self._check_static(node.args[0], node, "block shape")
-        self.generic_visit(node)
-
-    def _check_static(self, expr, node, what: str) -> None:
-        for sub in ast.walk(expr):
-            if isinstance(sub, ast.Call):
-                sub_name = _call_name(sub)
-                if sub_name not in _SHAPE_CALL_ALLOW:
-                    self.add(
-                        "TPU-PALLAS-SHAPE", node,
-                        f"non-static {what} in pallas_call: "
-                        f"{sub_name}(...) is not shape arithmetic — a "
-                        "runtime-derived grid/block shape recompiles "
-                        "per value (or fails Mosaic); derive shapes "
-                        "from static module constants")
-                    return
-
-
-# --------------------------------------------------------------------- #
 # rule: TPU-COMPILE-KEY (compilecache/ persistence seams)
 # --------------------------------------------------------------------- #
 
@@ -1128,10 +1065,6 @@ def lint_source(src: str, rel: str) -> list:
         pe = _PdEpochRules(rel, lines)
         pe.visit(tree)
         findings += pe.findings
-    if rel.startswith(PALLAS_PREFIX):
-        pr = _PallasRules(rel, lines)
-        pr.visit(tree)
-        findings += pr.findings
     if rel.startswith(SPAN_MODULE_PREFIXES):
         sl = _SpanLeakRules(rel, lines)
         sl.visit(tree)
@@ -1197,5 +1130,5 @@ __all__ = ["Finding", "lint_source", "lint_tree", "load_baseline",
            "new_findings", "TRACED_MODULES", "HOT_PATH_MODULES",
            "LOCK_EXCLUDES", "module_imports_threading",
            "RETRY_MODULE_PREFIXES",
-           "COMPILECACHE_PREFIX", "PALLAS_PREFIX", "PD_PREFIX",
+           "COMPILECACHE_PREFIX", "PD_PREFIX",
            "SPAN_MODULE_PREFIXES", "MEM_SOURCE_MODULES"]

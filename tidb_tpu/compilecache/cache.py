@@ -31,6 +31,7 @@ module is that pattern for the spmd cop programs:
 
 from __future__ import annotations
 
+import logging
 import os
 import pickle
 import threading
@@ -50,6 +51,8 @@ MAGIC = "copforge"
 # (no payload to size): small enough that a CPU-mesh pool holds the
 # whole corpus, large enough that eviction still means something
 NOMINAL_EXE_BYTES = 64 << 10
+
+_log = logging.getLogger(__name__)
 
 
 class _Counters(threading.local):
@@ -99,6 +102,7 @@ class CompileCache:
         self.disk_hits = 0         # persisted entries deserialized
         self.misses = 0            # AOT lower+compile runs
         self.uncacheable = 0       # programs the AOT path refused
+        self._uncacheable_logged: set = set()   # digests logged once
         self.rejected = 0          # corrupt/stale/mismatched disk entries
         self.persisted = 0         # entries written to the cache dir
         self.evictions = 0         # pool LRU evictions
@@ -297,10 +301,14 @@ class CompileCache:
             blob = pickle.dumps((header, payload, in_tree, out_tree),
                                 protocol=pickle.HIGHEST_PROTOCOL)
             self._persist_ok = True
-        except Exception:   # noqa: BLE001 - backend capability probe:
+        except Exception as e:   # noqa: BLE001 - backend capability probe:
             # runtimes without executable serialization keep the
-            # in-process pool (full warm semantics, no persistence)
+            # in-process pool (full warm semantics, no persistence);
+            # shown as persist_supported=false on /sched
             self._persist_ok = False
+            _log.warning("executable serialization unavailable, compile "
+                         "cache stays in-process: %s: %s",
+                         type(e).__name__, str(e)[:200])
             return 0
         try:
             os.makedirs(self.cache_dir, exist_ok=True)
@@ -409,10 +417,19 @@ class CompileCache:
         t0 = time.perf_counter_ns()
         try:
             exe = jit_fn.lower(*args).compile()
-        except Exception:   # noqa: BLE001 - AOT capability probe: the
-            # plain jit path serves programs the staging API refuses
+        except Exception as e:   # noqa: BLE001 - AOT capability probe:
+            # the plain jit path serves programs the staging API refuses
+            # (and raises for real if the program cannot compile at all)
             with self._mu:
                 self.uncacheable += 1
+                first = key.digest not in self._uncacheable_logged
+                if len(self._uncacheable_logged) > 256:
+                    self._uncacheable_logged.clear()
+                self._uncacheable_logged.add(key.digest)
+            if first:
+                _log.warning("AOT compile refused, serving through jit "
+                             "(digest %s): %s: %s", key.digest[:16],
+                             type(e).__name__, str(e)[:200])
             if claim is True:
                 from ..pd import release_compile_claim
                 release_compile_claim(entry_hex)

@@ -22,10 +22,7 @@ validation.
 
 from __future__ import annotations
 
-try:
-    import tomllib                 # py >= 3.11
-except ImportError:                # py 3.10: the identical-API backport
-    import tomli as tomllib
+import tomllib
 from dataclasses import dataclass, field
 from typing import Any, Optional
 

@@ -118,8 +118,7 @@ class GroupStrategy(enum.Enum):
                          # histogram + exclusive-cumsum offsets + stable
                          # gather/scatter reorder, O(passes*n) data
                          # movement instead of lax.sort's O(n log n)
-                         # comparator lanes; optional Pallas TPU kernel
-                         # for the fused histogram+scatter inner loop
+                         # comparator lanes
 
 
 # strategies whose per-device group tables merge HOST-side (per-device
@@ -134,10 +133,8 @@ HOST_MERGE_STRATEGIES = (GroupStrategy.SORT, GroupStrategy.SEGMENT,
 RADIX_STRATEGIES = (GroupStrategy.SEGMENT, GroupStrategy.SCATTER)
 
 # SCATTER radix geometry (jax-free so contracts/copcost can price passes
-# without importing the kernel module): each pass orders RADIX_BITS of
-# the partition key — the Pallas kernel as one 2^RADIX_BITS-digit
-# histogram+scatter counting sort, the XLA lowering as RADIX_BITS 1-bit
-# stable partition subpasses (identical stable permutation either way).
+# without importing jax): each pass orders RADIX_BITS of the partition
+# key, lowered as RADIX_BITS 1-bit stable partition subpasses.
 RADIX_BITS = 8
 # residual hash bits ordered BELOW the log2(B) bucket bits: two groups
 # colliding in the bucket bits alone would interleave into per-run
@@ -147,12 +144,11 @@ RADIX_BITS = 8
 # full-hash ordering.  Remaining collisions are the usual duplicates,
 # merged host-side by true key equality.
 RADIX_RESIDUAL_BITS = 8
-# the partition key must fit int32 (kernel lanes): bucket + residual
-# bits clamp to 30, plus one dead-row tail bit above them
+# the partition key must fit int32: bucket + residual bits clamp to 30,
+# plus one dead-row tail bit above them
 RADIX_KEY_BITS_MAX = 30
-# rows per kernel grid step (copr/pallas/radix_kernel.TILE reads this):
-# sizes the per-tile histogram/offset arrays both on device and in the
-# copcost pricing, so the model and the kernel agree by construction
+# rows per histogram tile in the copcost pricing of a pass (the per-tile
+# histogram/offset scratch the model charges beside the permutation)
 RADIX_TILE = 512
 # contract ceiling on the pass count: above this the partition does more
 # full-data passes than the comparator sort it replaces would ever pay —

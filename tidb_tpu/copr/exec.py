@@ -256,8 +256,8 @@ def _agg_partial_states(agg: D.Aggregation, batch: DeviceBatch, ev: Evaluator,
     each bucket's runs segment-reduce (copr/segment.py).
     SCATTER: SEGMENT with the giant sort replaced by a multi-pass
     scatter radix partition — histogram + exclusive cumsum + stable
-    scatter reorder per pass, O(passes*n) data movement, optionally a
-    Pallas TPU kernel for the inner loop (copr/radix.py).
+    scatter reorder per pass, O(passes*n) data movement
+    (copr/radix.py).
     Adds '__rows__' (COUNT(*) per group) for occupancy.
     """
     if agg.strategy == D.GroupStrategy.SCATTER:
@@ -548,14 +548,24 @@ def _exec_lookup_join(node: D.LookupJoin, batch: DeviceBatch, ev: Evaluator,
 
 
 def _exec_topn(node: D.TopN, batch: DeviceBatch, ev: Evaluator) -> DeviceBatch:
-    """Per-shard TopN via a stable multi-key lax.sort + head-k gather.
+    """Per-shard TopN via a multi-key lax.sort + head-k gather.
 
     Sort keys, ascending, in priority order: (1) dead-row flag so filtered
-    rows always sort last, (2) NULL flag encoding MySQL ordering (NULLs
-    first ASC, last DESC), (3) the order-preserving int64 key — bitwise-NOT
-    for DESC, an exact overflow-free order reversal.  No clamping: every
-    distinct key value keeps its rank (review finding: clamping collapsed
-    the extreme key values at the limit boundary)."""
+    rows always sort last, (2) per key, a NULL flag encoding MySQL
+    ordering (NULLs first ASC, last DESC), (3) the order-preserving
+    integer key — bitwise-NOT for DESC, an exact overflow-free order
+    reversal — and (4) the row index, which makes the order total and
+    batch-stable.  No clamping: every distinct key value keeps its rank
+    (review finding: clamping collapsed the extreme key values at the
+    limit boundary).
+
+    The comparator is kept as narrow as the plan allows, because the
+    TPU compiler's time for a sort grows steeply with the number and
+    width of its key lanes (int64 is emulated as two 32-bit lanes; a
+    stable sort carries a hidden index key on top of ours): a key known
+    non-NULL gets no NULL flag, a key read at a narrow physical width is
+    compared at int32, and the index rides as the last key of an
+    unstable sort."""
     memo: dict = {}
     n = len(batch.cols[0][0])
     sel = _sel_array(batch.sel, n)
@@ -564,20 +574,21 @@ def _exec_topn(node: D.TopN, batch: DeviceBatch, ev: Evaluator) -> DeviceBatch:
     for e, desc in (node.sort_keys or ((node.sort_key, node.desc),)):
         v, m = ev.eval(e, batch.cols, memo)
         v = _ensure_array(v, n)
-        key = sortable_int64(jnp, v, e.dtype.is_float,
-                             e.dtype.kind == K.UINT64)
-        if desc:
-            key = ~key           # exact descending order, no overflow
-        if m is True:
-            nullflag = jnp.zeros(n, jnp.int32)
-        else:
+        if m is not True:
             # NULL sorts first in ASC, last in DESC
             flag = jnp.where(m, 1, 0) if not desc else jnp.where(m, 0, 1)
-            nullflag = flag.astype(jnp.int32)  # valueflow: ok - literal 0/1 lanes
-        operands += [nullflag, key]
-    nk = len(operands)
-    *_, idx = lax.sort(tuple(operands)
-                       + (jnp.arange(n, dtype=jnp.int64),), num_keys=nk)
+            operands.append(flag.astype(jnp.int32))  # valueflow: ok - literal 0/1 lanes
+        if jnp.issubdtype(v.dtype, jnp.signedinteger) \
+                and v.dtype.itemsize <= 4:
+            key = v.astype(jnp.int32)   # valueflow: ok - widening only
+        else:
+            key = sortable_int64(jnp, v, e.dtype.is_float,
+                                 e.dtype.kind == K.UINT64)
+        operands.append(~key if desc else key)   # exact reversal
+    operands.append(jnp.arange(
+        n, dtype=jnp.int32 if n < 2 ** 31 else jnp.int64))
+    *_, idx = lax.sort(tuple(operands), num_keys=len(operands),
+                       is_stable=False)
     k = min(node.limit, n)
     idx = idx[:k]
     live = jnp.sum(sel)
@@ -644,19 +655,10 @@ def _find_agg(node: D.CopNode) -> Optional[D.Aggregation]:
 
 
 @functools.lru_cache(maxsize=256)
-def _cached_program(dag_root: D.CopNode, row_capacity: int,
-                    radix_token: str) -> CopProgram:
-    del radix_token          # key component only (Pallas-gate variant)
-    return CopProgram(dag_root, row_capacity)
-
-
 def get_program(dag_root: D.CopNode, row_capacity: int = 0) -> CopProgram:
     """jit-program cache keyed on (dag digest, capacity) — the analog of the
-    coprocessor cache + plan-digest jit cache (SURVEY.md §A.6).  SCATTER
-    programs additionally key on the Pallas-gate mode: the lowering is
-    baked in at trace time, so a sysvar flip must build a fresh program."""
-    from .radix import cache_token
-    return _cached_program(dag_root, row_capacity, cache_token(dag_root))
+    coprocessor cache + plan-digest jit cache (SURVEY.md §A.6)."""
+    return CopProgram(dag_root, row_capacity)
 
 
 __all__ = ["DeviceBatch", "CopProgram", "get_program", "compact",

@@ -3,24 +3,21 @@
 Reference analog: the reference's aggregation hot loops are compiled Go
 (agg_hash_executor.go); ours are C++ (native/hostops.cpp) behind numpy
 fallbacks — `count_keys`/`gather_lookup` return None-equivalent behavior
-by the caller checking `available()` first.  Build failures degrade to
-the numpy path silently: the native library is an accelerator, never a
-correctness dependency.
+by the caller checking `available()` first.  The library is built from
+source on first load (native.ensure_built); a failed build degrades to
+the numpy path: the native library is an accelerator, never a
+correctness dependency.  `available()` says which one is in use.
 """
 
 from __future__ import annotations
 
 import ctypes
-import os
 import subprocess
 import threading
 from typing import Optional
 
 import numpy as np
 
-_NATIVE_DIR = os.path.abspath(
-    os.path.join(os.path.dirname(__file__), os.pardir, "native"))
-_LIB_PATH = os.path.join(_NATIVE_DIR, "libtpuhostops.so")
 _lock = threading.Lock()
 _lib = None
 _tried = False
@@ -35,20 +32,9 @@ def _load() -> Optional[ctypes.CDLL]:
             return _lib
         _tried = True
         try:
-            src = os.path.join(_NATIVE_DIR, "hostops.cpp")
-            if (not os.path.exists(_LIB_PATH)
-                    or os.path.getmtime(_LIB_PATH) < os.path.getmtime(src)):
-                subprocess.run(["make", "-C", _NATIVE_DIR,
-                                "libtpuhostops.so"],
-                               check=True, capture_output=True)
-            try:
-                lib = ctypes.CDLL(_LIB_PATH)
-            except OSError:
-                # ABI mismatch (built on a newer glibc): rebuild locally
-                subprocess.run(["make", "-B", "-C", _NATIVE_DIR,
-                                "libtpuhostops.so"],
-                               check=True, capture_output=True)
-                lib = ctypes.CDLL(_LIB_PATH)
+            from ..native import ensure_built
+            lib = ctypes.CDLL(ensure_built("libtpuhostops.so",
+                                           "hostops.cpp"))
             I64, I32P, I64P = (ctypes.c_int64,
                                ctypes.POINTER(ctypes.c_int32),
                                ctypes.POINTER(ctypes.c_int64))

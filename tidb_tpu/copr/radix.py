@@ -4,11 +4,11 @@
 Motivation (ROADMAP "kill the real-TPU high-NDV cliff"): SEGMENT's
 partition pass is one giant single-key ``lax.sort`` — O(n log n)
 comparator lanes on hardware built for streaming data movement, and on
-real TPU the hndv bench rung still ran at 0.05x a single numpy core
-(BENCH_TPU.json).  Flare (PAPERS.md) is the precedent for replacing a
-general-purpose engine's sort-based shuffle with native specialized
-partitioning; HiFrames compiles dataframe aggregations to tight
-partition loops the same way.
+real TPU the hndv bench rung ran at 0.05x a single numpy core (the
+2026-07-31 record tabulated in ROADMAP.md).  Flare (PAPERS.md) is the
+precedent for replacing a general-purpose engine's sort-based shuffle
+with native specialized partitioning; HiFrames compiles dataframe
+aggregations to tight partition loops the same way.
 
 Algorithm (per device, static shapes, one traced program):
 
@@ -20,22 +20,13 @@ Algorithm (per device, static shapes, one traced program):
    bucket-major, RADIX_BITS per pass, LSB digit first: per pass a
    bucket-digit histogram, an exclusive cumsum of bucket offsets, and a
    gather/scatter reorder of the row-index permutation — O(passes * n)
-   data movement, no comparator network.  Two interchangeable
-   lowerings produce the IDENTICAL stable permutation:
-
-   - XLA (default off-TPU): each RADIX_BITS-digit pass runs as
-     RADIX_BITS 1-bit stable partition subpasses — a 1-bit counting
-     sort degenerates to one cumsum (the histogram+offsets of a 2-digit
-     space) plus one scatter, all fully vectorized.
-   - Pallas (default on TPU; ``tidb_tpu_radix_pallas`` sysvar): the
-     fused histogram+scatter inner loop runs as hand-written TPU
-     kernels (copr/pallas/radix_kernel.py), tile-parallel over the
-     grid, exercised in tier-1 through Pallas INTERPRET mode on the
-     CPU mesh so the kernel path is tested without hardware.
-
-   Both are stable LSD radix sorts of the same bucket key, so the
+   data movement, no comparator network.  The lowering is plain XLA:
+   each RADIX_BITS-digit pass runs as RADIX_BITS 1-bit stable partition
+   subpasses — a 1-bit counting sort degenerates to one cumsum (the
+   histogram+offsets of a 2-digit space) plus one scatter, all fully
+   vectorized.  It is a stable LSD radix sort of the bucket key, so the
    final permutation — and therefore every downstream state — is
-   bit-identical between them and across regrows.
+   bit-identical across regrows.
 3. The shared partition->states suffix of copr/segment.py
    (states_from_partition) detects segment boundaries and
    scatter-reduces into the (num_buckets,) state table: hash collisions
@@ -55,7 +46,6 @@ regrow loop already converges on it: more buckets = more ordered bits
 from __future__ import annotations
 
 import functools
-import os
 
 import jax.numpy as jnp
 import numpy as np
@@ -63,63 +53,6 @@ import numpy as np
 from ..ops.sortkeys import INT64_MAX
 from . import dag as D
 from .segment import batch_hash, states_from_partition
-
-# --------------------------------------------------------------------- #
-# Pallas gate: sysvar tidb_tpu_radix_pallas (default auto)
-#   auto - Pallas kernels on TPU backends, XLA lowering elsewhere
-#   on   - Pallas everywhere (interpret mode off-TPU: the tier-1 seam)
-#   off  - XLA lowering everywhere
-# --------------------------------------------------------------------- #
-
-_PALLAS_MODES = ("auto", "on", "off")
-_PALLAS_MODE = [os.environ.get("TIDB_TPU_RADIX_PALLAS", "auto") or "auto"]
-
-
-def set_pallas_mode(mode: str) -> None:
-    m = str(mode).strip().lower()
-    if m in ("1", "true"):
-        m = "on"
-    elif m in ("0", "false"):
-        m = "off"
-    if m not in _PALLAS_MODES:
-        raise ValueError(
-            f"tidb_tpu_radix_pallas must be one of {_PALLAS_MODES}, "
-            f"got {mode!r}")
-    _PALLAS_MODE[0] = m
-
-
-def pallas_mode() -> str:
-    return _PALLAS_MODE[0]
-
-
-def _pallas_choice(platform: str):
-    """(use_pallas, interpret) for the platform a program is being
-    traced for.  Interpret mode runs the SAME kernel body through the
-    Pallas interpreter — how tier-1 exercises the kernel path on the
-    CPU mesh."""
-    m = pallas_mode()
-    if m == "on":
-        return True, platform != "tpu"
-    if m == "off":
-        return False, False
-    return platform == "tpu", False
-
-
-def cache_token(dag) -> str:
-    """Program-cache key component for the Pallas gate: the mode is
-    baked into a SCATTER program at trace time, so flipping the sysvar
-    must key a fresh program instead of serving the other lowering from
-    an lru/compile cache.  Non-SCATTER dags return a constant token —
-    their traces never consult the gate."""
-    try:
-        for n in D.iter_nodes(dag):
-            if isinstance(n, D.Aggregation) \
-                    and n.strategy is D.GroupStrategy.SCATTER:
-                return pallas_mode()
-    except (TypeError, AttributeError):
-        pass
-    return ""
-
 
 # --------------------------------------------------------------------- #
 # the multi-pass scatter partition
@@ -143,42 +76,18 @@ def _partition_xla(bid, bits: int, n: int):
     return idx
 
 
-def _partition_pallas(bid, bits: int, n: int, interpret: bool):
-    """Stable LSD radix partition via the Pallas counting-sort kernels
-    (copr/pallas/radix_kernel.py), RADIX_BITS-digit passes.  Rows pad
-    to the kernel tile with a beyond-dead-bucket key so pads stay at
-    the very tail of every stable pass and slice back off exactly."""
-    from .pallas.radix_kernel import TILE, counting_sort_pass
-    n_pad = -(-n // TILE) * TILE
-    pad = n_pad - n
-    if pad:
-        tailkey = jnp.int32((1 << bits) - 1)
-        bid = jnp.concatenate([bid, jnp.full((pad,), tailkey, jnp.int32)])
-    idx = jnp.arange(n_pad, dtype=jnp.int32)
-    digit_mask = jnp.int32((1 << D.RADIX_BITS) - 1)
-    for p in range(-(-bits // D.RADIX_BITS)):
-        dig = (bid[idx] >> jnp.int32(p * D.RADIX_BITS)) & digit_mask
-        idx = counting_sort_pass(dig.astype(jnp.int32), idx, interpret)  # valueflow: ok - digit_mask bounds to RADIX_BITS bits
-    return idx[:n]
-
-
-def scatter_permutation(h, sel, num_buckets: int, n: int, platform: str):
+def scatter_permutation(h, sel, num_buckets: int, n: int):
     """Row permutation ordering rows bucket-major over the pow2
     ``num_buckets`` radix space: the partition key is the top
     log2(B) + RADIX_RESIDUAL_BITS bits of the uint64 hash (bucket id
     major, residual hash minor — the residual bits keep co-bucketed
     groups from interleaving into duplicate segments), dead rows in a
-    tail key one bit above.  Dispatches to the Pallas kernels or the
-    XLA lowering per the gate; both produce THE stable permutation of
-    the partition key, so results are bit-identical."""
+    tail key one bit above."""
     bits = D.radix_key_bits(num_buckets)
     key_bits = bits - 1                   # top bit = dead-row tail key
     # np scalar: stays 64-bit regardless of the embedder's x64 flag
     key = (h >> np.uint64(64 - key_bits)).astype(jnp.int32)  # valueflow: ok - top key_bits <= 31 bits survive the shift
     key = jnp.where(sel, key, jnp.int32(1 << key_bits))
-    use_pallas, interpret = _pallas_choice(platform)
-    if use_pallas:
-        return _partition_pallas(key, bits, n, interpret)
     return _partition_xla(key, bits, n)
 
 
@@ -187,7 +96,7 @@ def agg_scatter_states(agg: D.Aggregation, batch, ev, memo) -> dict:
     radix partition + the shared segment-reduce suffix.  State layout,
     host merge, and the ``__ngroups__`` regrow contract are identical
     to SEGMENT — only the partition pass differs."""
-    from .exec import _sel_array, group_keyinfo, trace_platform
+    from .exec import _sel_array, group_keyinfo
     B = agg.num_buckets
     assert B > 0 and (B & (B - 1)) == 0, \
         "SCATTER aggregation needs a power-of-two num_buckets"
@@ -198,7 +107,7 @@ def agg_scatter_states(agg: D.Aggregation, batch, ev, memo) -> dict:
 
     keyinfo = group_keyinfo(agg, batch, ev, memo, n)
     h = batch_hash(agg, batch, keyinfo, n)
-    idx = scatter_permutation(h, sel, B, n, trace_platform())
+    idx = scatter_permutation(h, sel, B, n)
     # boundary detection compares the FULL hash (not just bucket bits):
     # same int64 view + dead-row parking convention as SEGMENT
     hv = jnp.where(sel, h.astype(jnp.int64), INT64_MAX)
@@ -347,5 +256,4 @@ def phase_bench(n: int, num_buckets: int, iters: int = 3) -> dict:
 
 
 __all__ = ["agg_scatter_states", "scatter_permutation", "prehash_plan",
-           "get_hash_program", "HashProgram", "set_pallas_mode",
-           "pallas_mode", "cache_token", "phase_bench"]
+           "get_hash_program", "HashProgram", "phase_bench"]

@@ -9,7 +9,7 @@ Machine Learning Queries with Linear Algebra Query Processing"
 (SURVEY.md §7 hard part 4); the SORT strategy already exploits that, but
 its comparator carries 1 + 2*k int lanes per row and at millions of
 groups the multi-operand sort is what turned the real-TPU hndv bench
-rung into a 1000x cliff (BENCH_TPU.json `hndv_vs_numpy` 0.05x).
+rung into a 1000x cliff (0.05x numpy, ROADMAP.md's 2026-07-31 table).
 
 Algorithm (per device, one traced program, static shapes throughout):
 

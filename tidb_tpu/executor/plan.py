@@ -39,7 +39,8 @@ MAX_DENSE_GROUPS = 1_000_000
 # (hash -> radix bucket partition, ONE single-key sort lane, copcost-
 # derived pow2 bucket space) over SORT (multi-key comparator, 1 + 2*k
 # lanes) — the multi-operand sort is what turned the real-TPU 2M-group
-# bench rung into a 1000x cliff (BENCH_TPU.json hndv_vs_numpy 0.05x).
+# bench rung into a 1000x cliff (0.05x numpy, ROADMAP.md's 2026-07-31
+# table).
 SEGMENT_MIN_NDV = 1 << 15
 
 # stats handle for the CURRENT planning pass (set by the session around

@@ -39,19 +39,21 @@ ROOFLINE_STORE_CAP = 128
 # program is dominated by dispatch/launch overhead, not by data or math
 LAUNCH_BOUND_MS = 0.5
 
-# declared per-device-kind peaks: (bytes/s of HBM bandwidth, flops/s).
-# Substring-matched against jax's device_kind, most specific first.
-# These are roofline DENOMINATORS — deliberately round public numbers.
+# declared per-device-kind peaks: (part, substrings of jax's
+# device_kind, (bytes/s of HBM bandwidth, flops/s)), matched in order.
+# jax reports a v5e chip as "TPU v5 lite" and a v5p chip as "TPU v5p"
+# or plain "TPU v5"; the bare generation names come last so a lite
+# part never takes the full part's row.  These are roofline
+# DENOMINATORS — round public numbers (Google Cloud TPU docs; bf16
+# flops).  A TPU that matches no row is an error, not a default.
 TPU_PEAKS = (
-    ("v5p", (2765e9, 459e12)),
-    ("v5e", (819e9, 197e12)),
-    ("v5", (819e9, 197e12)),
-    ("v6", (1640e9, 918e12)),
-    ("v4", (1228e9, 275e12)),
-    ("v3", (900e9, 123e12)),
-    ("v2", (700e9, 46e12)),
+    ("v5e", ("v5 lite", "v5e"), (819e9, 197e12)),
+    ("v6e", ("v6 lite", "v6e"), (1640e9, 918e12)),
+    ("v5p", ("v5",), (2765e9, 459e12)),
+    ("v4", ("v4",), (1228e9, 275e12)),
+    ("v3", ("v3",), (900e9, 123e12)),
+    ("v2", ("v2",), (700e9, 46e12)),
 )
-DEFAULT_TPU_PEAKS = (900e9, 100e12)
 
 # CPU microbench shape: one stacked copy + one small matmul, best of
 # REPS — a stable-enough boot-time denominator, not a benchmark
@@ -90,10 +92,12 @@ def backend_peaks(device_kind: str) -> tuple:
     """(bytes_per_s, flops_per_s, source) for a device kind string."""
     kind = (device_kind or "").lower()
     if "tpu" in kind:
-        for sub, peaks in TPU_PEAKS:
-            if sub in kind:
-                return (*peaks, f"declared:{sub}")
-        return (*DEFAULT_TPU_PEAKS, "declared:tpu-default")
+        for part, subs, peaks in TPU_PEAKS:
+            if any(sub in kind for sub in subs):
+                return (*peaks, f"declared:{part}")
+        raise ValueError(
+            f"no roofline peaks declared for TPU device_kind "
+            f"{device_kind!r}: add its row to obs/roofline.TPU_PEAKS")
     global _cpu_peaks_cache
     with _cpu_mu:
         if _cpu_peaks_cache is None:

@@ -15,6 +15,7 @@ chip to a pod — DCN only carries control traffic, ICI the collectives.
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Optional
 
 import jax
@@ -26,28 +27,26 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 # programs share one symbol — TPU-SHARD-CONST lints string literals
 from .topology import SHARD_AXIS
 
-try:                                    # jax >= 0.5: public API
-    from jax import shard_map as _shard_map
-except ImportError:                     # 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
+_log = logging.getLogger(__name__)
 
 
 def shard_map(f, *, mesh: Mesh, in_specs, out_specs):
-    """Version-compat shard_map with replication checking off: the
-    public API spells the flag check_vma, 0.4.x spells it check_rep."""
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+    """jax.shard_map with replication checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 @functools.lru_cache(maxsize=8)
 def get_mesh(n_devices: Optional[int] = None) -> Mesh:
+    """The mesh over every local device (or the first ``n_devices``).
+    Resolution is lazy (first device dispatch) and says what it found:
+    serving entry points run with INFO logging on, so a process that
+    meant to hold a TPU and resolved CPU devices shows it here."""
     devs = jax.devices()
     if n_devices is not None:
         devs = devs[:n_devices]
+    _log.info("mesh resolved: platform=%s device_kind=%s devices=%d",
+              devs[0].platform, devs[0].device_kind, len(devs))
     return Mesh(np.array(devs), (SHARD_AXIS,))
 
 

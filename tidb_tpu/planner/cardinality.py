@@ -13,7 +13,8 @@ from typing import Optional
 
 from ..expr.ir import Expr
 from ..stats.handle import TableStats, encode_value
-from .ranger import _cmp_parts
+from ..types.dtypes import TypeKind as K
+from .ranger import _cmp_parts, _const_for
 
 # reference: pkg/planner/cardinality/pseudo.go
 PSEUDO_LESS_RATE = 3.0
@@ -50,7 +51,13 @@ def cond_selectivity(stats: Optional[TableStats], cond: Expr, ds) -> float:
     if cs is None or total == 0 or col_type is None:
         return (1.0 / PSEUDO_EQUAL_RATE if op == "eq"
                 else 1.0 / PSEUDO_LESS_RATE)
-    enc = encode_value(col_type, cst.value, dictionary)
+    value = cst.value
+    if col_type.kind == K.DECIMAL:
+        # the histogram holds the column's SCALED ints; a decimal const
+        # carries its own scale and an integer literal none, so bring the
+        # const to the column's scale exactly as the scan path does
+        value = _const_for(col_type, cst)
+    enc = encode_value(col_type, value, dictionary)
     if enc is None:
         return 1.0 / PSEUDO_LESS_RATE
     if op == "eq":

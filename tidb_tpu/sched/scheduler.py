@@ -72,6 +72,7 @@ period, so embedders that never touch the device pay nothing.
 
 from __future__ import annotations
 
+import logging
 import os
 import random
 import threading
@@ -127,6 +128,8 @@ DEFAULT_LAUNCH_RETRY_MS = 2000.0
 # seeded jitter for the drain's Backoffer when no FaultPlan is armed:
 # retry histories stay reproducible either way
 RETRY_JITTER_SEED = 0x5EED
+
+_log = logging.getLogger(__name__)
 
 
 def _verify_enabled() -> bool:
@@ -225,6 +228,12 @@ class DeviceScheduler:
         self.batched_rows_launches = 0    # rows-kind stacked launches
         self.fused_launches = 0           # cross-query fused launches
         self.fused_tasks = 0              # tasks served by a fused launch
+        # group launches that raised and were served apart instead: the
+        # results are the same, so only these counters (and one log line
+        # per digest) say the fused / vmapped program never ran
+        self.fused_refused = 0
+        self.batched_refused = 0
+        self._refusals_logged: set = set()
         self.window_waits = 0             # drains that held for stragglers
         self.window_hits = 0              # holds that actually gained riders
         self.busy_rejects = 0
@@ -607,7 +616,7 @@ class DeviceScheduler:
                     self.quarantined += 1
                 self._m_quar.inc()
                 self._trace_mark(task, "sched.quarantine",
-                                 digest=f"{task.key[0] & ((1 << 64) - 1):016x}")
+                                 digest=self._digest_hex(task.key[0]))
                 if task.trace is not None:
                     task.trace.tree.flag("quarantined")
                 raise
@@ -1122,10 +1131,12 @@ class DeviceScheduler:
                 prog = get_fused_program(fused, mesh)
                 prog._cached.warm(lead_sds)
                 ok = True
-            except Exception:   # noqa: BLE001 - prediction is a pure
-                # optimization: an unfusable combo or a backend refusal
-                # just means the real arrival compiles as before
-                pass
+            except Exception as e:   # noqa: BLE001 - prediction is a
+                # pure optimization: an unfusable combo or a backend
+                # refusal just means the real arrival compiles as before
+                # (counted as warm_failures below)
+                _log.warning("predicted fusion warm-up failed: %s",
+                             self._err_label(e))
             finally:
                 # counters under _mu: up to two warm threads run
                 # concurrently, so a bare += here loses updates
@@ -1142,6 +1153,11 @@ class DeviceScheduler:
     # ------------------------------------------------------------- #
     # copscope span recording (obs/): the drain's side of the trace
     # ------------------------------------------------------------- #
+
+    @staticmethod
+    def _digest_hex(dag_digest: int) -> str:
+        """A task key's (process-local) dag digest as /sched prints it."""
+        return f"{dag_digest & ((1 << 64) - 1):016x}"
 
     @staticmethod
     def _err_label(e: BaseException) -> str:
@@ -1522,9 +1538,11 @@ class DeviceScheduler:
                     fused, lead.mesh,
                     tuple(t.row_capacity for t in members))
             outs = fprog(lead.cols, lead.counts)
-        except Exception:   # noqa: BLE001 - fusion capability probe:
-            return False    # refused groups launch apart below (same
-                            # results, no fusion win)
+        except Exception as e:   # noqa: BLE001 - fusion capability probe:
+            # refused groups launch apart below (same results, no
+            # fusion win) — counted and logged, never silent
+            self._note_refusal("fused", lead, e)
+            return False
         total = sum(len(grp) for grp in programs)
         all_tasks = [t for grp in programs for t in grp]
         self._cc_note(all_tasks, cc0)
@@ -1613,9 +1631,10 @@ class DeviceScheduler:
                     self.batched_rows_launches += 1
                 self._m_launch.inc(mode="batched")
                 return
-            except Exception:   # planlint: ok - vmap capability probe;
-                pass        # op not vmappable on this backend: launch
-                            # apart below (same results, no batching win)
+            except Exception as e:   # planlint: ok - vmap capability probe;
+                # op not vmappable on this backend: launch apart below
+                # (same results, no batching win) — counted and logged
+                self._note_refusal("batched", lead, e)
         first = True
         for s in slots:
             t_s0 = t_l0 if first else time.perf_counter_ns()
@@ -1638,6 +1657,28 @@ class DeviceScheduler:
                 self.donated_launches += 1
             self._m_launch.inc(
                 mode="coalesced" if len(s) > 1 else "single")
+
+    def _note_refusal(self, kind: str, lead, err: BaseException) -> None:
+        """A fused / vmap-batched group launch raised and its members
+        are being served apart: bump ``<kind>_refused`` and log the
+        exception once per lead digest."""
+        with self._mu:
+            if kind == "fused":
+                self.fused_refused += 1
+            else:
+                self.batched_refused += 1
+            digest = lead.key[0] if lead.key is not None else None
+            first = (kind, digest) not in self._refusals_logged
+            if first:
+                if len(self._refusals_logged) > 256:
+                    self._refusals_logged.clear()
+                self._refusals_logged.add((kind, digest))
+        if first:
+            _log.warning("%s launch refused, members served apart "
+                         "(digest %s): %s", kind,
+                         "-" if digest is None
+                         else self._digest_hex(digest),
+                         self._err_label(err))
 
     def _note_coalesce(self, batch: list) -> None:
         if len(batch) > 1:
@@ -1762,8 +1803,8 @@ class DeviceScheduler:
                     # bounded + LRU (BoundedLRU, the calibration
                     # store's eviction policy) — no more unbounded
                     # per-digest growth, no more wholesale clear()
-                    dk = f"{t.key[0] & 0xffffffffffffffff:016x}"
-                    self._digest_ns.bump(dk, t.device_ns)
+                    self._digest_ns.bump(self._digest_hex(t.key[0]),
+                                         t.device_ns)
                 self._wait_ring.append(t.wait_ns)
                 self._m_wait.observe(t.wait_ns / 1e9)
                 self._m_wait_ms.observe(t.wait_ns / 1e6)
@@ -1833,6 +1874,8 @@ class DeviceScheduler:
                 "batched_rows_launches": self.batched_rows_launches,
                 "fused_launches": self.fused_launches,
                 "fused_tasks": self.fused_tasks,
+                "fused_refused": self.fused_refused,
+                "batched_refused": self.batched_refused,
                 "window_waits": self.window_waits,
                 "window_hits": self.window_hits,
                 "busy_rejects": self.busy_rejects,
@@ -1911,9 +1954,16 @@ def scheduler_for(mesh) -> DeviceScheduler:
     fp = mesh_fingerprint(mesh)
     with _REG_MU:
         s = _REGISTRY.get(fp)
-        if s is None:
-            s = _REGISTRY[fp] = DeviceScheduler()
+    if s is not None:
         return s
+    # the first dispatch onto a mesh resolves its roofline peaks HERE, in
+    # the submitting thread: a TPU whose device_kind has no declared row
+    # fails the statement with that message, instead of every launch's
+    # attribution failing unseen on the drain thread
+    from ..obs.roofline import peaks_for_mesh
+    peaks_for_mesh(mesh)
+    with _REG_MU:
+        return _REGISTRY.setdefault(fp, DeviceScheduler())
 
 
 def breaker_snapshot_all() -> dict:

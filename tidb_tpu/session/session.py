@@ -55,14 +55,12 @@ class Domain:
         self.keyspace = keyspace     # tenant prefix (pkg/keyspace analog)
         self.catalog = Catalog()
         self.catalog.domain = self          # memtable binding (infoschema)
-        # device mesh acquisition is LAZY: resolving jax.devices() under a
-        # pending TPU grant blocks for the whole backend-init timeout, so
-        # an embedder constructing a Session (or running host-only
-        # statements like SELECT 1) must not pay it.  The CopClient
-        # resolves the mesh on first device dispatch; Domain.mesh
-        # delegates there.  Explicit platform override: set
-        # TIDB_TPU_PLATFORM (e.g. "cpu") before importing tidb_tpu, or
-        # pass a concrete mesh here.
+        # device mesh acquisition is LAZY: an embedder constructing a
+        # Session (or running host-only statements like SELECT 1) must
+        # not initialize a backend.  The CopClient resolves the mesh on
+        # first device dispatch; Domain.mesh delegates there.  The
+        # platform is JAX's to choose (JAX_PLATFORMS), or pass a
+        # concrete mesh here.
         self.client = CopClient(mesh if mesh is not None else get_mesh)
         if data_dir is not None:
             # durable mode: WAL-backed native engine + catalog-on-KV, so
@@ -1274,14 +1272,6 @@ class Session:
         if v16 is not None and v16 != "":
             from ..parallel.topology import set_host_view
             set_host_view(None if int(v16) <= 0 else int(v16))
-        # SCATTER radix-partition Pallas gate (copr/radix): auto = the
-        # hand-written Pallas kernels on TPU backends, the XLA lowering
-        # elsewhere; on = Pallas everywhere (interpret mode off-TPU —
-        # the tier-1 kernel-path seam); off = XLA everywhere
-        v15 = merged.get("tidb_tpu_radix_pallas")
-        if v15 is not None and v15 != "":
-            from ..copr import radix as _radix
-            _radix.set_pallas_mode(str(v15))
         # copforge AOT compile cache (compilecache/): enable/dir/pool
         # knobs, then the idempotent boot warm-start hook — the first
         # statement after a cache dir lands kicks the background

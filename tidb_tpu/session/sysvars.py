@@ -118,11 +118,6 @@ _VARS = [
     # on the 8-vdev CPU mesh
     _v("tidb_tpu_topology_hosts", -1, kind="int", min=-1, max=4096,
        scope=SCOPE_GLOBAL),
-    # SCATTER radix-partition Pallas gate (copr/radix + copr/pallas):
-    # auto = hand-written Pallas kernels on TPU, XLA lowering elsewhere;
-    # on = Pallas everywhere (interpret mode off-TPU, the tier-1 kernel
-    # seam); off = XLA lowering everywhere
-    _v("tidb_tpu_radix_pallas", "auto", kind="str", scope=SCOPE_GLOBAL),
     # copscope (obs/): per-statement span trees with cross-thread trace
     # propagation + the flight-recorder ring.  tidb_tpu_trace off =
     # no tree is built, no span is recorded anywhere (the overhead

@@ -15,13 +15,10 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 import threading
 from dataclasses import dataclass, field
 from typing import Iterator, Optional
 
-_NATIVE_DIR = os.path.join(os.path.dirname(__file__), os.pardir, "native")
-_LIB_PATH = os.path.abspath(os.path.join(_NATIVE_DIR, "libtpukv.so"))
 _build_lock = threading.Lock()
 _lib = None
 
@@ -55,20 +52,8 @@ def _load_lib():
     with _build_lock:
         if _lib is not None:
             return _lib
-        src = os.path.join(_NATIVE_DIR, "kvstore.cpp")
-        if (not os.path.exists(_LIB_PATH)
-                or os.path.getmtime(_LIB_PATH) < os.path.getmtime(src)):
-            subprocess.run(["make", "-C", os.path.abspath(_NATIVE_DIR)],
-                           check=True, capture_output=True)
-        try:
-            lib = ctypes.CDLL(_LIB_PATH)
-        except OSError:
-            # ABI mismatch: the checked-in .so was built against a newer
-            # glibc than this host's — force a local rebuild and retry
-            subprocess.run(["make", "-B", "-C",
-                            os.path.abspath(_NATIVE_DIR)],
-                           check=True, capture_output=True)
-            lib = ctypes.CDLL(_LIB_PATH)
+        from ..native import ensure_built
+        lib = ctypes.CDLL(ensure_built("libtpukv.so", "kvstore.cpp"))
         lib.kv_open.restype = ctypes.c_void_p
         lib.kv_close.argtypes = [ctypes.c_void_p]
         lib.kv_alloc_ts.restype = ctypes.c_uint64

@@ -9,8 +9,8 @@ Usage: python -m tidb_tpu.testing.bench_kv   [BENCHKV_KEYS=2000000]
 
 import ctypes, time
 import os
-lib = ctypes.CDLL(os.path.join(os.path.dirname(__file__), "..", "native",
-                               "libtpukv.so"))
+from ..native import ensure_built
+lib = ctypes.CDLL(ensure_built("libtpukv.so", "kvstore.cpp"))
 for n,r,a in [("kv_open",ctypes.c_void_p,[]),("kv_alloc_ts",ctypes.c_uint64,[ctypes.c_void_p]),
  ("kv_flush",ctypes.c_int64,[ctypes.c_void_p]),
  ("kv_bench_gets",ctypes.c_int64,[ctypes.c_void_p,ctypes.c_int64,ctypes.c_uint64,ctypes.c_uint64]),
