@@ -186,14 +186,15 @@ def _run_twice(c, status_port: int, tag: str, sql: str, want: list) -> dict:
             raise SmokeFailure(f"{tag}: wrong answer\n got      {got[:12]}\n "
                                f"expected {want[:12]}")
     s1 = _sched(status_port)
-    d0 = s0.get("digest_device_ms", {})
+    d0 = s0.get("digest_dispatch_ms", {})
     srv_cold, srv_warm = _server_ms(c, sql)
     r = {"strategy": strategy, "cold_ms": times[0], "warm_ms": times[1],
          "server_cold_ms": srv_cold, "server_warm_ms": srv_warm,
          "rows": len(rows),
          "launches": s1.get("launches", 0) - s0.get("launches", 0),
-         # programs whose attributed device time grew meanwhile
-         "digests": sorted(k for k, v in s1.get("digest_device_ms", {}).items()
+         # programs launched meanwhile (their dispatch time grew)
+         "digests": sorted(k for k, v
+                           in s1.get("digest_dispatch_ms", {}).items()
                            if v > d0.get(k, 0)),
          "compile_ms": s1["compile_cache"]["compile_ms"]
          - s0["compile_cache"]["compile_ms"],
@@ -387,7 +388,7 @@ def main(argv=None) -> int:
     log(f"compile_ms_total={s['compile_cache']['compile_ms']:.1f} "
         f"(programs compiled {s['compile_cache']['misses']}, "
         f"set-up analyze {rep['setup']['analyze_s']:.1f}s)")
-    log(f"digest_device_ms={s['digest_device_ms']}")
+    log(f"digest_dispatch_ms={s['digest_dispatch_ms']}")
     log(f"bytes_in_use={rep['bytes_in_use']}")
     log(f"total {time.monotonic() - t0:.1f}s")
     print(json.dumps({"ok": True, "device": device}), flush=True)

@@ -165,7 +165,7 @@ def test_scheduler_digest_map_is_bounded():
         sched._digest_ns.bump(f"{i:016x}", 1_000_000)
     assert len(sched._digest_ns) <= RC_DIGEST_CAP
     # stats still renders the top-8 view off the bounded map
-    top = sched.stats()["digest_device_ms"]
+    top = sched.stats()["digest_dispatch_ms"]
     assert len(top) == 8
 
 

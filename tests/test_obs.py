@@ -171,7 +171,7 @@ def test_chrome_export_schema():
 
     def worker():
         t0 = time.perf_counter_ns()
-        ctx.add("sched.launch", t0, t0 + 5000, measured_ms=0.005)
+        ctx.add("sched.launch", t0, t0 + 5000, dispatch_ms=0.005)
         done.set()
 
     threading.Thread(target=worker, name="sched-drain").start()
@@ -303,7 +303,7 @@ def test_cross_thread_stitching_single_statement(odom):
     # statement thread
     assert launch.thread != by_name["session.ExecuteStmt"].thread
     assert launch.thread.startswith("sched-drain")
-    assert launch.attrs["measured_ms"] >= 0
+    assert launch.attrs["dispatch_ms"] >= 0
     assert "predicted_ms" in launch.attrs
     # device->host transfer + host merge recorded session-side
     assert "cop.transfer" in by_name and "cop.host_merge" in by_name
@@ -358,7 +358,7 @@ def test_trace_fused_retried_compile_missed_statement(odom):
         by_name.setdefault(sp.name, sp)
     launch = by_name["sched.launch"]
     assert launch.attrs["mode"] == "fused"
-    assert launch.attrs["measured_ms"] > 0
+    assert launch.attrs["dispatch_ms"] > 0
     assert launch.attrs["predicted_ms"] > 0
     fusion = by_name["sched.fusion"]
     assert fusion.attrs["members"] >= 2
@@ -421,6 +421,276 @@ def test_fused_count_seam_3member_regression(odom):
     res = s.execute("explain analyze " + OBS_QUERIES[0])
     text = "\n".join(str(r) for r in res.rows)
     assert "tasks: 1" in text and "fused: 0" in text, text
+
+
+# ------------------------------------------------------------------ #
+# the finished span tree (PR 23): every seam of a served statement
+# ------------------------------------------------------------------ #
+
+# span -> its parent's name (None: another root of the tree)
+SERVED_SPANS = {
+    "session.parse": "session.ExecuteStmt",
+    "session.plan": "session.ExecuteStmt",
+    "plan.gates": "session.plan",
+    "cop.dispatch": "session.ExecuteStmt",
+    "sched.admit": "cop.dispatch",
+    "sched.launch": "cop.dispatch",
+    "cop.transfer": "session.ExecuteStmt",
+    "cop.device_wait": "cop.transfer",
+    "cop.d2h": "cop.transfer",
+    "session.resultset": "session.ExecuteStmt",
+    "wire.write": None,
+}
+TOPN_QUERY = "select q, p from obs_t order by p desc, q limit 5"
+
+
+@pytest.fixture()
+def wire(odom):
+    """A MySQL-wire server over the ``odom`` domain: statements run on
+    connection threads and their results are written to a socket."""
+    from tidb_tpu.server import MySQLServer
+    dom, _s, _sched = odom
+    srv = MySQLServer(dom)
+    srv.start()
+    try:
+        yield srv
+    finally:
+        srv.close()
+
+
+def _served_tree(dom, sql_frag):
+    """The statement's tree once the connection thread has added its
+    ``wire.write`` (it does so after the client has the last row)."""
+    deadline = time.monotonic() + 5
+    while time.monotonic() < deadline:
+        tree = _trace_of(dom, sql_frag)
+        if tree is not None and any(sp.name == "wire.write"
+                                    for sp in tree.spans):
+            return tree
+        time.sleep(0.01)
+    raise AssertionError(f"no wire.write span for {sql_frag!r}")
+
+
+@pytest.mark.parametrize("shape", ["agg", "topn", "fused"])
+def test_served_statement_span_tree(odom, wire, shape):
+    """An aggregate, a rows (TopN) and a fused statement, each served
+    over the wire for the first time (plan-cache miss): every span of
+    SERVED_SPANS appears once under the stated parent, ``cop.*`` spans
+    are direct children of the root (``host_plan_ms`` subtracts them),
+    and the launch span names its program."""
+    from tidb_tpu.server.client import Client
+    dom, _s, sched = odom
+    if shape == "fused":
+        f0, out, errors = sched.fused_launches, {}, []
+
+        def run(i, qq):
+            try:
+                c = Client("127.0.0.1", wire.port, db="test")
+                out[i] = c.query(qq)
+                c.close()
+            except Exception as e:      # noqa: BLE001 surfaced via assert
+                errors.append(e)
+
+        sched.pause()
+        try:
+            threads = [threading.Thread(target=run, args=(i, qq))
+                       for i, qq in enumerate(OBS_QUERIES)]
+            for t in threads:
+                t.start()
+            deadline = time.monotonic() + 20
+            while time.monotonic() < deadline and sched.depth < 3:
+                time.sleep(0.01)
+            assert sched.depth >= 3, "tasks did not queue"
+        finally:
+            sched.resume()
+        for t in threads:
+            t.join(timeout=60)
+        assert not errors and len(out) == 3, errors
+        assert sched.fused_launches > f0, "queries did not fuse"
+        frag, program = "sum(p * p * p * d)", "cop_fused_x3_"
+    else:
+        sql, frag, program = {
+            "agg": (OBS_QUERIES[1], "sum(p * p * p * d)",
+                    "cop_solo_agg_scalar_"),
+            "topn": (TOPN_QUERY, "order by p desc", "cop_solo_topn_"),
+        }[shape]
+        c = Client("127.0.0.1", wire.port, db="test")
+        assert c.query(sql)
+        c.close()
+    tree = _served_tree(dom, frag)
+    spans = tree.spans
+    by_id = {sp.span_id: sp for sp in spans}
+    root = next(sp for sp in spans if sp.name == "session.ExecuteStmt")
+    for name, parent in SERVED_SPANS.items():
+        found = [sp for sp in spans if sp.name == name]
+        assert len(found) == 1, (name, [sp.name for sp in spans])
+        got = by_id[found[0].parent_id].name \
+            if found[0].parent_id is not None else None
+        assert got == parent, (name, got)
+    for sp in spans:
+        if sp.name.startswith("cop.") and sp.name not in (
+                "cop.device_wait", "cop.d2h"):
+            assert sp.parent_id == root.span_id, sp.name
+    launch = next(sp for sp in spans if sp.name == "sched.launch")
+    assert launch.attrs["program"].startswith(program), launch.attrs
+    assert launch.attrs["dispatch_ms"] >= 0
+    plan = next(sp for sp in spans if sp.name == "session.plan")
+    assert plan.attrs["cache"] == "miss"
+    # parsing precedes the root span; the write follows it
+    parse = next(sp for sp in spans if sp.name == "session.parse")
+    write = next(sp for sp in spans if sp.name == "wire.write")
+    assert parse.end_ns <= root.start_ns and write.start_ns >= root.end_ns
+    # transfer's children split it: the wait for the device, then the copy
+    xfer = next(sp for sp in spans if sp.name == "cop.transfer")
+    wait = next(sp for sp in spans if sp.name == "cop.device_wait")
+    d2h = next(sp for sp in spans if sp.name == "cop.d2h")
+    assert xfer.start_ns <= wait.start_ns <= wait.end_ns \
+        <= d2h.start_ns <= d2h.end_ns <= xfer.end_ns
+
+
+def test_plan_cache_hit_skips_the_gates(odom):
+    """A repeated statement text is a plan-cache hit: ``session.plan``
+    says so and no ``plan.gates`` span is recorded."""
+    dom, s, _sched = odom
+    s2 = Session(dom)
+    s2.must_query(OBS_QUERIES[2])
+    s2.must_query(OBS_QUERIES[2])
+    tree = _trace_of(dom, "min(p)")
+    names = [sp.name for sp in tree.spans]
+    plan = next(sp for sp in tree.spans if sp.name == "session.plan")
+    assert plan.attrs["cache"] == "hit" and "plan.gates" not in names
+
+
+_NAME_PROBE = """
+import numpy as np
+from tidb_tpu.session import Domain, Session
+dom = Domain(); s = Session(dom)
+s.execute("create table nm_t (q bigint, p bigint)")
+s.execute("insert into nm_t values " + ",".join(
+    f"({i % 50},{i * 7})" for i in range(500)))
+s.execute("set global tidb_tpu_result_cache_entries = 0")
+s.execute("set global tidb_tpu_trace_sample = 1")
+# no fused program predicted and compiled in the background: the thread
+# that does it would be killed inside XLA at interpreter exit
+s.execute("set global tidb_tpu_sched_fusion = 0")
+dom.client._platform = lambda: "tpu"
+for lit in (24, 25):
+    s.must_query(f"select sum(p) from nm_t where q < {lit}")
+    tree = dom.flight_recorder.get(
+        dom.flight_recorder.index()[0]["trace_id"])
+    print(next(sp.attrs["program"] for sp in tree.spans
+               if sp.name == "sched.launch"))
+"""
+
+
+def test_program_name_is_the_same_in_every_process():
+    """One DAG is jitted under the same name in two fresh interpreters
+    with different hash salts (``hash()``/``dag_digest`` would differ,
+    and JAX's persistent cache, keyed on the module, would miss on every
+    restart), and another literal gives another name."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    names = []
+    for salt in ("1", "2"):
+        env = dict(os.environ, PYTHONHASHSEED=salt, JAX_PLATFORMS="cpu",
+                   PYTHONPATH=root)
+        out = subprocess.run([sys.executable, "-c", _NAME_PROBE], env=env,
+                             capture_output=True, text=True, timeout=240)
+        assert out.returncode == 0, out.stderr[-2000:]
+        names.append(out.stdout.split()[-2:])
+    assert names[0] == names[1], names
+    a, b = names[0]
+    assert a != b, names
+    for n in (a, b):
+        assert n.startswith("cop_solo_agg_scalar_") \
+            and len(n.rsplit("_", 1)[1]) == 12, n
+
+
+class _CountingAnnotation:
+    """Stands in for the profiler's annotation, with a session active."""
+    entered = 0
+
+    def __init__(self, name, **attrs):
+        self.name = name
+
+    @staticmethod
+    def is_enabled():
+        return True
+
+    def __enter__(self):
+        type(self).entered += 1
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_trace_off_records_nothing_and_enters_no_annotation(
+        odom, wire, monkeypatch):
+    """``tidb_tpu_trace = 0``: no tree, no span, no profiler annotation
+    anywhere on the served path; on, the same statement enters them."""
+    from tidb_tpu.obs import trace as obs_trace
+    from tidb_tpu.server.client import Client
+    dom, s, _sched = odom
+    monkeypatch.setattr(obs_trace, "Annotation", _CountingAnnotation)
+    monkeypatch.setattr(_CountingAnnotation, "entered", 0)
+    c = Client("127.0.0.1", wire.port, db="test")
+    try:
+        c.query("set global tidb_tpu_trace = 0")
+        seen = dom.flight_recorder.stats()["seen"]
+        _CountingAnnotation.entered = 0
+        assert c.query(OBS_QUERIES[1]) and c.query(TOPN_QUERY)
+        assert _CountingAnnotation.entered == 0
+        assert dom.flight_recorder.stats()["seen"] == seen
+        c.query("set global tidb_tpu_trace = 1")
+        assert c.query(OBS_QUERIES[1])
+        # parse, root, plan, dispatch, admit, launch, transfer and its
+        # two children, host merge, result set, write
+        assert _CountingAnnotation.entered >= 12
+    finally:
+        s.execute("set global tidb_tpu_trace = 1")
+        c.close()
+
+
+def test_spans_land_in_the_profilers_trace(odom, tmp_path):
+    """One clock with the device: a profile of the process holds the
+    statement's spans on ``/host:CPU`` with its trace id, the drain
+    thread's ``sched.launch`` on another line with the program's name."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    dom, s, _sched = odom
+    s2 = Session(dom)
+    s2.must_query(OBS_QUERIES[1])           # compile outside the profile
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        s2.must_query(OBS_QUERIES[1])
+    finally:
+        jax.profiler.stop_trace()
+    tree = _trace_of(dom, "sum(p * p * p * d)")
+    [path] = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                           / "*.xplane.pb"))
+    host = next(p for p in ProfileData.from_file(path).planes
+                if p.name == "/host:CPU")
+    found = {}
+    for i, line in enumerate(host.lines):
+        for e in line.events:
+            stats = dict(e.stats)
+            if stats.get("trace_id") == tree.trace_id:
+                found[e.name] = (i, stats)
+    assert {"session.ExecuteStmt", "session.plan", "cop.dispatch",
+            "sched.admit", "sched.launch", "cop.transfer",
+            "cop.device_wait", "cop.d2h", "cop.host_merge",
+            "session.resultset"} <= set(found), sorted(found)
+    line, stats = found["sched.launch"]
+    assert line != found["cop.dispatch"][0]
+    assert stats["program"].startswith("cop_solo_agg_scalar_")
+    assert "sched.queue" not in found       # a wait stays tree-only
 
 
 # ------------------------------------------------------------------ #
@@ -533,7 +803,7 @@ def test_trace_statement_shows_scheduler_spans(odom):
                "sched.launch"):
         assert nm in text, text
     # launch span renders its predicted-vs-measured annotation
-    assert "predicted_ms=" in text and "measured_ms=" in text, text
+    assert "predicted_ms=" in text and "dispatch_ms=" in text, text
 
 
 # ------------------------------------------------------------------ #
@@ -570,12 +840,15 @@ def test_tracing_overhead_guard():
             s.must_query("select count(*) from ov")
         return time.monotonic() - t0
 
-    s.execute("set global tidb_tpu_trace = 0")
-    loop()
-    off = min(loop() for _ in range(3))
-    s.execute("set global tidb_tpu_trace = 1")
-    loop()
-    on = min(loop() for _ in range(3))
+    # off and on take turns, so that a burst of load on the machine
+    # (the suite runs on several workers) falls on both
+    best = {0: float("inf"), 1: float("inf")}
+    for _ in range(4):
+        for mode in (0, 1):
+            s.execute(f"set global tidb_tpu_trace = {mode}")
+            loop()
+            best[mode] = min(best[mode], loop())
+    off, on = best[0], best[1]
     assert on <= off * 1.5, f"tracing overhead {on / off - 1:.1%}"
 
 

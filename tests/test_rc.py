@@ -463,7 +463,7 @@ def test_device_time_attribution_per_group_and_digest():
     for grp in ("ga", "gb"):
         assert st["groups"][grp]["device_ms"] > 0, st["groups"][grp]
         assert st["groups"][grp]["rus"] >= MIN_TASK_RU
-    assert st["digest_device_ms"], st
+    assert st["digest_dispatch_ms"], st
 
 
 def test_resource_route_lists_groups_and_balances():

@@ -105,6 +105,43 @@ def stable_digest(dag: D.CopNode) -> str:
     return h.hexdigest()[:16]
 
 
+def _root_tag(dag) -> str:
+    """The root node's kind and strategy, as a program name spells it."""
+    if isinstance(dag, D.FusedDag):
+        return f"x{len(dag.members)}"
+    if isinstance(dag, D.ShuffleJoinSpec):
+        return _root_tag(dag.top)
+    if isinstance(dag, D.Aggregation):
+        return f"agg_{dag.strategy.value}"
+    return {D.TopN: "topn", D.Limit: "limit",
+            D.WindowShuffleSpec: "window"}.get(type(dag), "rows")
+
+
+def program_name(program: str, dag) -> str:
+    """``cop_<program>_<root>_<d12>``: the name a device program is
+    jitted under, hence its module's name in a profiler trace
+    (``jit_cop_solo_agg_scalar_<d12>``) and part of what JAX's persistent
+    cache keys on.  ``program`` is the builder (``solo``, ``fused``,
+    ``batched``, ``shuffle``, ...), ``d12`` twelve hex digits of the
+    restart-stable digest: the same DAG is named the same in every
+    process, which ``hash()``/``dag_digest`` would not give."""
+    return (f"cop_{program.replace('-', '_')}_{_root_tag(dag)}_"
+            f"{stable_digest(dag)[:12]}")
+
+
+def named_jit(fn, program: str, dag, **jit_kwargs):
+    """``jax.jit`` of a device program under ``program_name`` (read it
+    back as ``jitted.__name__``).  Every builder jits through here, so
+    no module in a profiler trace is an anonymous ``jit__device_fn``."""
+    import jax
+
+    def device_program(*args):
+        return fn(*args)
+    device_program.__name__ = device_program.__qualname__ = \
+        program_name(program, dag)
+    return jax.jit(device_program, **jit_kwargs)
+
+
 @functools.lru_cache(maxsize=2048)
 def family_digest(dag: D.CopNode) -> str:
     """Digest with regrow capacities zeroed: every capacity variant of

@@ -41,6 +41,7 @@ from typing import Optional
 
 from ..analysis.compilekey import (CompileKey, backend_fingerprint,
                                    shape_signature)
+from ..obs import trace as _obs
 from .manifest import DEFAULT_CAP_BYTES, WarmManifest
 
 ENTRY_SUFFIX = ".copforge"
@@ -375,7 +376,8 @@ class CompileCache:
             return hit[0]
         if self.cache_dir and not bad:
             t0 = time.perf_counter_ns()
-            loaded = self._load_entry(entry_hex, key.parts())
+            with _obs.live_child("sched.compile", result="load"):
+                loaded = self._load_entry(entry_hex, key.parts())
             if loaded is not None:
                 exe, nbytes = loaded
                 dt_ns = time.perf_counter_ns() - t0
@@ -416,7 +418,8 @@ class CompileCache:
         # cache we cannot serialize from
         t0 = time.perf_counter_ns()
         try:
-            exe = jit_fn.lower(*args).compile()
+            with _obs.live_child("sched.compile", result="miss"):
+                exe = jit_fn.lower(*args).compile()
         except Exception as e:   # noqa: BLE001 - AOT capability probe:
             # the plain jit path serves programs the staging API refuses
             # (and raises for real if the program cannot compile at all)

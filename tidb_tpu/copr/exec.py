@@ -163,9 +163,10 @@ def _one_agg_state(a: D.AggDesc, av, am, sel, gids, num_groups, n,
             raise OverflowError(
                 f"shard batch of {n} rows exceeds the 2^31 limb-exact "
                 "SUM bound; use more/smaller shards")
-        v = av.astype(jnp.int64)
-        hi = _reduce(v >> 32, mask, gids, num_groups, "sum")
-        lo = _reduce(v & 0xFFFFFFFF, mask, gids, num_groups, "sum")
+        with jax.named_scope("limb_split"):
+            v = av.astype(jnp.int64)
+            hi = _reduce(v >> 32, mask, gids, num_groups, "sum")
+            lo = _reduce(v & 0xFFFFFFFF, mask, gids, num_groups, "sum")
         return {"hi": hi, "lo": lo, "cnt": cnt}
     if a.func == D.AggFunc.MIN:
         return {"min": _reduce(av, mask, gids, num_groups, "min"),
@@ -194,10 +195,14 @@ def agg_states(agg: D.Aggregation, scan_cols, row_count, ev: Evaluator,
     if isinstance(ch, D.Expand) \
             and agg.strategy == D.GroupStrategy.DENSE \
             and trace_platform() == "tpu":
-        base = _exec_node(ch.child, scan_cols, row_count, ev, aux)
-        return _expand_level_states(agg, ch, base, ev), base
-    batch = _exec_node(ch, scan_cols, row_count, ev, aux)
-    return _agg_partial_states(agg, batch, ev, {}), batch
+        with jax.named_scope("scan_filter"):
+            base = _exec_node(ch.child, scan_cols, row_count, ev, aux)
+        with jax.named_scope("aggregate"):
+            return _expand_level_states(agg, ch, base, ev), base
+    with jax.named_scope("scan_filter"):
+        batch = _exec_node(ch, scan_cols, row_count, ev, aux)
+    with jax.named_scope("aggregate"):
+        return _agg_partial_states(agg, batch, ev, {}), batch
 
 
 def _expand_level_states(agg: D.Aggregation, exp: D.Expand,
@@ -337,7 +342,9 @@ def _agg_sort_states(agg: D.Aggregation, batch: DeviceBatch, ev: Evaluator,
     for _vz, _m, nullf, code in keyinfo:
         ops += [nullf, code]
     ops.append(jnp.arange(n, dtype=jnp.int64))
-    *sorted_keys, idx = lax.sort(tuple(ops), num_keys=1 + 2 * len(keyinfo))
+    with jax.named_scope("sort"):
+        *sorted_keys, idx = lax.sort(tuple(ops),
+                                     num_keys=1 + 2 * len(keyinfo))
     sel_s = sel[idx]
 
     # group boundary: live row whose key tuple differs from the previous
@@ -486,7 +493,8 @@ def _exec_node(node: D.CopNode, scan_cols: Sequence, row_count, ev: Evaluator,
         return DeviceBatch(batch.cols, keep, batch.extras)
 
     if isinstance(node, D.TopN):
-        batch = _exec_node(node.child, scan_cols, row_count, ev, aux)
+        with jax.named_scope("scan_filter"):
+            batch = _exec_node(node.child, scan_cols, row_count, ev, aux)
         return _exec_topn(node, batch, ev)
 
     if isinstance(node, D.LookupJoin):
@@ -587,8 +595,9 @@ def _exec_topn(node: D.TopN, batch: DeviceBatch, ev: Evaluator) -> DeviceBatch:
         operands.append(~key if desc else key)   # exact reversal
     operands.append(jnp.arange(
         n, dtype=jnp.int32 if n < 2 ** 31 else jnp.int64))
-    *_, idx = lax.sort(tuple(operands), num_keys=len(operands),
-                       is_stable=False)
+    with jax.named_scope("sort"):
+        *_, idx = lax.sort(tuple(operands), num_keys=len(operands),
+                           is_stable=False)
     k = min(node.limit, n)
     idx = idx[:k]
     live = jnp.sum(sel)
@@ -620,7 +629,8 @@ class CopProgram:
         # programs containing an expanding join return an extras dict
         # (true join output size) after the result, for the regrow loop
         self.has_extras = D.find_expand_join(dag_root) is not None
-        self._fn = jax.jit(self._trace)
+        from ..analysis.compilekey import named_jit
+        self._fn = named_jit(self._trace, "local", dag_root)
 
     def _trace(self, scan_cols, row_count, aux_cols=()):
         # single-device programs run on the process default backend:

@@ -159,9 +159,9 @@ class HashProgram:
     after a restart."""
 
     def __init__(self, scan: D.TableScan, group_by: tuple, mesh):
-        import jax
         from jax.sharding import PartitionSpec as P
 
+        from ..analysis.compilekey import named_jit
         from ..compilecache import cached_call
         from ..expr.compile import Evaluator
         from ..parallel.mesh import SHARD_AXIS, shard_map
@@ -187,9 +187,10 @@ class HashProgram:
             hv = key_hash(keyinfo, s * c).astype(jnp.int64)
             return hv.reshape(s, c)
 
-        self._fn = jax.jit(shard_map(
+        self._fn = named_jit(shard_map(
             device_fn, mesh=mesh, in_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
-            out_specs=P(SHARD_AXIS)))
+            out_specs=P(SHARD_AXIS)), "hash", key_dag)
+        self.name = self._fn.__name__
         self._cached = cached_call(self._fn, key_dag, mesh, "solo",
                                    extra=("keyhash",))
 

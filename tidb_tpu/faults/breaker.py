@@ -177,7 +177,7 @@ class CircuitBreaker:
 
     def snapshot(self, max_entries: int = 16) -> dict:
         """Non-trivial entries for /sched: digests with a tripped or
-        failing breaker, hex-keyed like digest_device_ms."""
+        failing breaker, hex-keyed like digest_dispatch_ms."""
         now = self.clock()
         with self._mu:
             ents = [(d, e) for d, e in self._entries.items()

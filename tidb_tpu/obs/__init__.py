@@ -31,10 +31,12 @@ from .recorder import FlightRecorder
 from .roofline import (backend_peaks, peaks_for_mesh, roofline_status,
                        roofline_store)
 from .trace import (TRACE_CTX, Span, SpanTree, TraceCtx, annotate,
-                    current, flag, new_trace_id, span)
+                    current, flag, late_span, live, live_child,
+                    new_trace_id, span)
 
 __all__ = ["Span", "SpanTree", "TraceCtx", "TRACE_CTX", "current",
-           "span", "flag", "annotate", "new_trace_id", "FlightRecorder",
+           "span", "late_span", "live", "live_child", "flag", "annotate",
+           "new_trace_id", "FlightRecorder",
            "HbmLedger", "ledger_for", "all_ledgers", "hbm_status",
            "device_memory_stats", "profiler_gate", "roofline_store",
            "roofline_status", "backend_peaks", "peaks_for_mesh"]

@@ -23,7 +23,21 @@ Propagation is contextvar + task-stamp:
 
 Recording is deliberately cheap (one tuple append under the tree lock;
 ``add`` is the only hot-path entry) so tracing can stay on in
-production — the bench's ``trace_overhead_pct`` guards it.
+production.  ``tests/test_obs.py::test_tracing_overhead_guard`` bounds
+the cost per span; what it costs a served statement on the chip
+(copscope on against ``tidb_tpu_trace = 0``) is measured per PR and
+written in PERF.md section 6.
+
+One clock with the device: every live span also enters a
+``jax.profiler.TraceAnnotation`` of the same name for its extent
+(``trace_id`` and the span's attrs as its stats), so whoever profiles
+the process — the benchmark's harness, or an operator through
+``/profile?ms=N`` — finds the spans on ``/host:CPU``, on the line of the
+thread that made them and on the clock of the device's ``XLA Ops``.
+While no profiler session records, an annotation is a flag test in C++
+(50 ns) and a shared null context.  The drain thread records its spans post hoc
+(``add_batch``), so it brackets the work itself with ``live()``; waits
+(``sched.queue``) stay tree-only.
 """
 
 from __future__ import annotations
@@ -32,8 +46,10 @@ import contextvars
 import itertools
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import ContextDecorator, contextmanager, nullcontext
 from typing import Optional
+
+from jax.profiler import TraceAnnotation as Annotation
 
 # the active statement's TraceCtx; None = tracing off / no statement
 TRACE_CTX: contextvars.ContextVar = contextvars.ContextVar(
@@ -107,6 +123,17 @@ class SpanTree:
                 sid, parent_id, name, start_ns, end_ns,
                 thread=threading.current_thread().name, attrs=attrs))
             return sid
+
+    def open(self, name: str, parent_id: Optional[int],
+             attrs: dict) -> Span:
+        """Record an OPEN span starting now and hand it back: its owner
+        sets ``end_ns`` on it (``span()``'s path: no search on exit)."""
+        sp = Span(0, parent_id, name, time.perf_counter_ns(), 0,
+                  thread=threading.current_thread().name, attrs=attrs)
+        with self._mu:
+            sp.span_id = self._next = self._next + 1
+            self.spans.append(sp)
+        return sp
 
     def add_batch(self, items: list) -> list[int]:
         """Record several completed spans in ONE lock acquisition —
@@ -290,25 +317,106 @@ def current() -> Optional[TraceCtx]:
     return TRACE_CTX.get()
 
 
-@contextmanager
-def span(name: str, **attrs):
+class span(ContextDecorator):
     """Session-side nested region: opens a child span under the active
     context and re-points ``TRACE_CTX`` at it for the dynamic extent,
-    so tasks submitted inside hang under THIS span.  A no-op (yields
-    None) when tracing is off — callers never branch."""
-    ctx = TRACE_CTX.get()
-    if ctx is None:
-        yield None
+    so tasks submitted inside hang under THIS span; while a profiler
+    session records, the annotation of the same name covers the same
+    extent.  A no-op (yields None) when tracing is off — callers never
+    branch.  Also a decorator: ``@span("session.plan")``.
+
+    A class and not a generator, and it sets the end on the ``Span`` it
+    holds: a served statement opens a dozen of these, and this form
+    costs half of what ``contextmanager`` + ``tree.end`` did."""
+
+    __slots__ = ("name", "attrs", "_sp", "_tok", "_ann")
+
+    def __init__(self, name: str, **attrs):
+        self.name = name
+        self.attrs = attrs
+        self._sp = None
+
+    def _recreate_cm(self):         # one instance per decorated call
+        return span(self.name, **self.attrs)
+
+    def __enter__(self) -> Optional[TraceCtx]:
+        ctx = TRACE_CTX.get()
+        if ctx is None:
+            return None
+        tree = ctx.tree
+        sp = self._sp = tree.open(self.name, ctx.span_id, self.attrs)
+        sub = TraceCtx(tree, sp.span_id)
+        self._tok = TRACE_CTX.set(sub)
+        self._ann = annotation(self.name, tree.trace_id, **self.attrs)
+        self._ann.__enter__()
+        return sub
+
+    def __exit__(self, *exc) -> bool:
+        sp = self._sp
+        if sp is not None:
+            self._ann.__exit__(*exc)
+            TRACE_CTX.reset(self._tok)
+            sp.end_ns = time.perf_counter_ns()
+        return False
+
+
+_NO_ANNOTATION = nullcontext()
+
+
+def annotation(name: str, trace_id: str = "", **attrs):
+    """The profiler annotation of a span, to be entered for its extent;
+    a shared null context while no profiler session records (that test
+    costs 50 ns; an annotation nobody records, half a microsecond)."""
+    if not Annotation.is_enabled():
+        return _NO_ANNOTATION
+    return Annotation(name, trace_id=trace_id, **attrs)
+
+
+@contextmanager
+def late_span(tree: Optional[SpanTree], name: str):
+    """A span on a statement's FINISHED tree, as another root: the
+    connection writes the result after ``Session.execute`` returned and
+    the recorder took the tree (it holds a reference; ``add`` is
+    lock-protected).  ``tree`` None = untraced = no-op."""
+    if tree is None:
+        yield
         return
     t0 = time.perf_counter_ns()
-    sid = ctx.tree.add(name, t0, 0, parent_id=ctx.span_id, **attrs)
-    sub = TraceCtx(ctx.tree, sid)
-    tok = TRACE_CTX.set(sub)
     try:
-        yield sub
+        with annotation(name, tree.trace_id):
+            yield
     finally:
-        TRACE_CTX.reset(tok)
-        ctx.tree.end(sid)
+        tree.add(name, t0, time.perf_counter_ns())
+
+
+# the TraceCtx whose live() annotation is open on this thread
+_LIVE = threading.local()
+
+
+@contextmanager
+def live(name: str, ctx: Optional[TraceCtx], **attrs):
+    """Profiler annotation alone, for a thread that records its tree
+    spans post hoc (the drain's ``sched.launch``): brackets the work the
+    span will describe.  ``ctx`` None = untraced = no-op."""
+    if ctx is None:
+        yield
+        return
+    prev = getattr(_LIVE, "ctx", None)
+    _LIVE.ctx = ctx
+    try:
+        with annotation(name, ctx.trace_id, **attrs):
+            yield
+    finally:
+        _LIVE.ctx = prev
+
+
+def live_child(name: str, **attrs):
+    """Annotation nested in this thread's open ``live()`` (the compile
+    or cache load inside a launch); a null context outside one."""
+    ctx = getattr(_LIVE, "ctx", None)
+    if ctx is None:
+        return _NO_ANNOTATION
+    return annotation(name, ctx.trace_id, **attrs)
 
 
 def flag(*names: str) -> None:
@@ -327,4 +435,5 @@ def annotate(**attrs) -> None:
 
 
 __all__ = ["Span", "SpanTree", "TraceCtx", "TRACE_CTX", "current",
-           "span", "flag", "annotate", "new_trace_id"]
+           "span", "late_span", "live", "live_child", "annotation",
+           "flag", "annotate", "new_trace_id"]

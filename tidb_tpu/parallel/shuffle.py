@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from ..analysis.compilekey import named_jit
 from ..copr import dag as D
 from ..copr.exec import (DeviceBatch, _agg_partial_states, _ensure_array,
                          _exec_node, _sel_array, agg_states, compact)
@@ -84,9 +85,10 @@ class ShardedShuffleJoinProgram:
             out_specs = P(SHARD_AXIS) if self.host_merge else P()
         else:
             out_specs = (P(SHARD_AXIS), P(SHARD_AXIS))
-        self._fn = jax.jit(shard_map(
+        self._fn = named_jit(shard_map(
             self._device_fn, mesh=mesh, in_specs=in_specs,
-            out_specs=(out_specs, P(SHARD_AXIS))))
+            out_specs=(out_specs, P(SHARD_AXIS))), "shuffle", spec)
+        self.name = self._fn.__name__
 
     # ------------------------------------------------------------- #
 

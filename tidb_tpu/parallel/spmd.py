@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from ..analysis.compilekey import named_jit
 from ..analysis.lifetime import donation_plan, verify_donation
 from ..compilecache import cached_call
 from ..copr import dag as D
@@ -155,9 +156,11 @@ class ShardedCopProgram:
         if self.has_extras:
             out_specs = (out_specs, P(SHARD_AXIS))
 
-        self._fn = jax.jit(shard_map(
+        self._fn = named_jit(shard_map(
             self._device_fn, mesh=mesh, in_specs=in_specs,
-            out_specs=out_specs), donate_argnums=self._donate_argnums)
+            out_specs=out_specs), "solo", dag_root,
+            donate_argnums=self._donate_argnums)
+        self.name = self._fn.__name__
         # copforge (compilecache): calls resolve through the AOT program
         # cache — warm-pool/persisted executables serve without tracing,
         # misses stage via jit.lower(...).compile() and persist.  The
@@ -181,8 +184,10 @@ class ShardedCopProgram:
                 # add a leading per-device axis; host reduces across it
                 out = jax.tree_util.tree_map(lambda a: a[None], states)
             else:
-                out = _collective_merge(states, SHARD_AXIS,
-                                        len(self.mesh.devices.reshape(-1)))
+                with jax.named_scope("merge"):
+                    out = _collective_merge(
+                        states, SHARD_AXIS,
+                        len(self.mesh.devices.reshape(-1)))
         else:
             batch = _exec_node(self.root, flat, base_sel, ev, aux)
             out_cols, n = compact(batch, self.row_capacity)
@@ -271,9 +276,11 @@ class FusedCopProgram:
         # per-device leading axis, an in-program member's are replicated
         out_specs = tuple(P(SHARD_AXIS) if p.host_merge else P()
                           for p in self.members)
-        self._fn = jax.jit(shard_map(
+        self._fn = named_jit(shard_map(
             self._device_fn, mesh=mesh, in_specs=in_specs,
-            out_specs=out_specs), donate_argnums=self._donate_argnums)
+            out_specs=out_specs), "fused", fused,
+            donate_argnums=self._donate_argnums)
+        self.name = self._fn.__name__
         self._cached = cached_call(self._fn, fused, mesh, "fused",
                                    donate_argnums=self._donate_argnums)
 
@@ -340,9 +347,11 @@ class FusedRowsProgram:
         in_specs = (P(SHARD_AXIS), P(SHARD_AXIS), P())
         out_specs = tuple((P(SHARD_AXIS), P(SHARD_AXIS))
                           for _ in self.members)
-        self._fn = jax.jit(shard_map(
+        self._fn = named_jit(shard_map(
             self._device_fn, mesh=mesh, in_specs=in_specs,
-            out_specs=out_specs), donate_argnums=self._donate_argnums)
+            out_specs=out_specs), "fused-rows", fused,
+            donate_argnums=self._donate_argnums)
+        self.name = self._fn.__name__
         # member output capacities live OUTSIDE the fused dag: they ride
         # the key's extra slot so capacity variants never collide
         self._cached = cached_call(self._fn, fused, mesh, "fused-rows",
@@ -412,9 +421,11 @@ class BatchedCopProgram:
         in_specs = (P(SHARD_AXIS), P(SHARD_AXIS), P())
         fn = jax.vmap(self.base._device_fn, in_axes=(1, 1, None),
                       out_axes=0)
-        self._fn = jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                     out_specs=P()),
-                           donate_argnums=self._donate_argnums)
+        self._fn = named_jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                       out_specs=P()),
+                             "batched", dag_root,
+                             donate_argnums=self._donate_argnums)
+        self.name = self._fn.__name__
         self._cached = cached_call(self._fn, dag_root, mesh, "batched",
                                    n_slots=n_slots,
                                    donate_argnums=self._donate_argnums)
@@ -474,10 +485,12 @@ class BatchedRowsProgram:
         # slot axis at position 1: per-device leading axis stays axis 0
         fn = jax.vmap(self.base._device_fn, in_axes=(1, 1, None),
                       out_axes=1)
-        self._fn = jax.jit(shard_map(
+        self._fn = named_jit(shard_map(
             fn, mesh=mesh, in_specs=in_specs,
             out_specs=(P(SHARD_AXIS), P(SHARD_AXIS))),
+            "batched-rows", dag_root,
             donate_argnums=self._donate_argnums)
+        self.name = self._fn.__name__
         self._cached = cached_call(
             self._fn, dag_root, mesh, "batched-rows",
             row_capacity=row_capacity, n_slots=n_slots,

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from ..analysis.compilekey import named_jit
 from ..copr import dag as D
 from ..copr.exec import (Evaluator, _ensure_array, _exec_node, _sel_array,
                          compact, set_trace_platform)
@@ -72,9 +73,10 @@ class ShardedWindowProgram:
                            + tuple(it[2] for it in spec.items))
         in_specs = (P(SHARD_AXIS), P(SHARD_AXIS), P())  # aux replicated
         out_specs = ((P(SHARD_AXIS), P(SHARD_AXIS)), P(SHARD_AXIS))
-        self._fn = jax.jit(shard_map(
+        self._fn = named_jit(shard_map(
             self._device_fn, mesh=mesh, in_specs=in_specs,
-            out_specs=out_specs))
+            out_specs=out_specs), "window", spec)
+        self.name = self._fn.__name__
 
     # -- device program ------------------------------------------------ #
 

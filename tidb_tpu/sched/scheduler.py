@@ -82,6 +82,7 @@ from typing import Optional
 
 from ..faults import plan as _faults
 from ..faults.breaker import CircuitBreaker, LaunchQuarantinedError
+from ..obs import trace as _obs
 from ..rc.controller import (DEFAULT_MAX_QUEUE_S, DEFAULT_OVERDRAFT_RU,
                              ResourceExhaustedError)
 from ..rc.pricing import split_device_time, task_rus
@@ -592,78 +593,81 @@ class DeviceScheduler:
         malformed task (capacity-shape drift, stale mesh key, invalid
         DAG) or an over-budget program is rejected with a structured
         PlanContractError/CostError HERE, in the submitting thread,
-        before the drain loop would trace/compile anything."""
-        if task.key is not None and _verify_enabled():
-            from ..analysis.contracts import verify_task
-            verify_task(task)
-            self._admit_cost(task)
-            if task.value_drift:
-                # valueflow watermark drift: the plan's declared value
-                # interval no longer contains the observed ANALYZE
-                # watermark — never wrong (proofs carry append
-                # headroom), but the operator should re-ANALYZE
-                with self._mu:
-                    self.value_drifts += task.value_drift
-        if task.key is not None:
-            # circuit breaker: a digest whose launches keep failing is
-            # quarantined HERE, in the submitting thread — fail fast
-            # with the structured error the client's host fallback
-            # understands, instead of re-crashing the device
-            try:
-                self.breaker.admit(task.key[0])
-            except LaunchQuarantinedError:
-                with self._mu:
-                    self.quarantined += 1
-                self._m_quar.inc()
-                self._trace_mark(task, "sched.quarantine",
-                                 digest=self._digest_hex(task.key[0]))
-                if task.trace is not None:
-                    task.trace.tree.flag("quarantined")
-                raise
-        # rc pricing happens HERE, in the submitting thread: structured
-        # tasks price from the LaunchCost the admission gate just
-        # computed, opaque tasks from their row estimate — the drain
-        # only compares/debits, never prices
-        task.rus = task_rus(task)
-        if self.rc_enable and task.rc_group is not None \
-                and task.rc_group.limited:
-            task.deadline_ns = task.submit_ns + \
-                int(self.rc_max_queue_s * 1e9)
-        # copmeter: the task's measured expected service time (0 when
-        # the digest was never measured) — computed OUTSIDE the lock
-        task.svc_ns = self._expected_ns(task)
-        with self._cv:
-            if self._depth >= self.max_depth:
-                self.busy_rejects += 1
-                self._m_busy.inc()
-                if task.key is not None:
-                    # an admitted HALF_OPEN probe that never queues must
-                    # release its slot or no probe could ever run
-                    self.breaker.abort_probe(task.key[0])
-                raise ServerBusyError(self.max_depth)
-            self._shed_locked(task)
-            g = self._groups.get(task.group)
-            if g is None:
-                g = self._groups[task.group] = _GroupQ(
-                    task.group, task.weight, len(self._groups),
-                    vtime=self._gvt)
-            else:
-                g.weight = max(task.weight, 1e-6)
-                if not g.queue:
-                    # re-activating group: forfeit banked idle time so it
-                    # cannot starve others (stride newcomer rule)
-                    g.vtime = max(g.vtime, self._gvt)
-            g.queue.append(task)
-            self._depth += 1
-            self._backlog_ns += task.svc_ns
-            self._note_arrival(task)
-            self._m_depth.set(self._depth)
-            self._m_tasks.inc(group=task.group)
-            if self._thread is None:
-                self._thread = threading.Thread(
-                    target=self._loop, name="sched-drain", daemon=True)
-                self._thread.start()
-            self._cv.notify_all()
+        before the drain loop would trace/compile anything.  All of it,
+        down to the enqueue, is the statement thread's ``sched.admit``
+        span (a child of its ``cop.dispatch``)."""
+        with _obs.span("sched.admit"):
+            if task.key is not None and _verify_enabled():
+                from ..analysis.contracts import verify_task
+                verify_task(task)
+                self._admit_cost(task)
+                if task.value_drift:
+                    # valueflow watermark drift: the plan's declared value
+                    # interval no longer contains the observed ANALYZE
+                    # watermark — never wrong (proofs carry append
+                    # headroom), but the operator should re-ANALYZE
+                    with self._mu:
+                        self.value_drifts += task.value_drift
+            if task.key is not None:
+                # circuit breaker: a digest whose launches keep failing is
+                # quarantined HERE, in the submitting thread — fail fast
+                # with the structured error the client's host fallback
+                # understands, instead of re-crashing the device
+                try:
+                    self.breaker.admit(task.key[0])
+                except LaunchQuarantinedError:
+                    with self._mu:
+                        self.quarantined += 1
+                    self._m_quar.inc()
+                    self._trace_mark(task, "sched.quarantine",
+                                     digest=self._digest_hex(task.key[0]))
+                    if task.trace is not None:
+                        task.trace.tree.flag("quarantined")
+                    raise
+            # rc pricing happens HERE, in the submitting thread: structured
+            # tasks price from the LaunchCost the admission gate just
+            # computed, opaque tasks from their row estimate — the drain
+            # only compares/debits, never prices
+            task.rus = task_rus(task)
+            if self.rc_enable and task.rc_group is not None \
+                    and task.rc_group.limited:
+                task.deadline_ns = task.submit_ns + \
+                    int(self.rc_max_queue_s * 1e9)
+            # copmeter: the task's measured expected service time (0 when
+            # the digest was never measured) — computed OUTSIDE the lock
+            task.svc_ns = self._expected_ns(task)
+            with self._cv:
+                if self._depth >= self.max_depth:
+                    self.busy_rejects += 1
+                    self._m_busy.inc()
+                    if task.key is not None:
+                        # an admitted HALF_OPEN probe that never queues must
+                        # release its slot or no probe could ever run
+                        self.breaker.abort_probe(task.key[0])
+                    raise ServerBusyError(self.max_depth)
+                self._shed_locked(task)
+                g = self._groups.get(task.group)
+                if g is None:
+                    g = self._groups[task.group] = _GroupQ(
+                        task.group, task.weight, len(self._groups),
+                        vtime=self._gvt)
+                else:
+                    g.weight = max(task.weight, 1e-6)
+                    if not g.queue:
+                        # re-activating group: forfeit banked idle time so it
+                        # cannot starve others (stride newcomer rule)
+                        g.vtime = max(g.vtime, self._gvt)
+                g.queue.append(task)
+                self._depth += 1
+                self._backlog_ns += task.svc_ns
+                self._note_arrival(task)
+                self._m_depth.set(self._depth)
+                self._m_tasks.inc(group=task.group)
+                if self._thread is None:
+                    self._thread = threading.Thread(
+                        target=self._loop, name="sched-drain", daemon=True)
+                    self._thread.start()
+                self._cv.notify_all()
         if task.fusion_key is not None and task.key is not None:
             # copforge: a second digest joining this fusion key predicts
             # the fused variant — warm it off-thread (lock released)
@@ -1169,6 +1173,14 @@ class DeviceScheduler:
         return getattr(s, "value", None) if s is not None else None
 
     @staticmethod
+    def _live_launch(tasks: list, mode: str, program: str = ""):
+        """The profiler annotation that brackets one launch's resolve +
+        dispatch on the drain thread, under the first traced task's
+        trace id (``_trace_launch`` records the tree spans afterwards)."""
+        ctx = next((t.trace for t in tasks if t.trace is not None), None)
+        return _obs.live("sched.launch", ctx, mode=mode, program=program)
+
+    @staticmethod
     def _trace_mark(t, name: str, **attrs) -> None:
         """Zero-duration marker span on one task's trace (oom / bisect
         / quarantine / fail seams); no-op when untraced."""
@@ -1177,7 +1189,8 @@ class DeviceScheduler:
             t.trace.add(name, now, now, **attrs)
 
     def _trace_launch(self, tasks: list, start_ns: int, end_ns: int,
-                      mode: str, fused: int = 0) -> None:
+                      mode: str, fused: int = 0,
+                      program: str = "") -> None:
         """Record one physical launch's scheduler-side span tree +
         latency histograms, on the DRAIN thread BEFORE the tasks
         finish — a waiter rendering its trace right after wait()
@@ -1185,9 +1198,11 @@ class DeviceScheduler:
 
         Per traced task: a ``sched.queue`` span (submit -> drain
         pickup; rc debit rides it as the ``ru`` attr) and a
-        ``sched.launch`` span (resolve + device execution) carrying
-        predicted_ms (calibrated LaunchCost via copmeter's predict_ms)
-        vs measured_ms, the shardflow per-link transfer breakdown,
+        ``sched.launch`` span (resolve + DISPATCH: the call returns
+        once the program is enqueued, before the device has run it)
+        carrying the program's name, predicted_ms (calibrated
+        LaunchCost via copmeter's predict_ms) next to dispatch_ms (the
+        span's own wall time), the shardflow per-link transfer breakdown,
         and — as children — the copforge ``sched.compile`` span
         (hit/miss) and the ``sched.fusion`` assembly span with the
         member count and this member's attributed share."""
@@ -1209,7 +1224,9 @@ class DeviceScheduler:
             ctx = t.trace
             if ctx is None:
                 continue
-            attrs = {"mode": mode, "measured_ms": round(wall_ms, 3)}
+            attrs = {"mode": mode, "dispatch_ms": round(wall_ms, 3)}
+            if program:
+                attrs["program"] = program
             if t.cost is not None:
                 attrs["predicted_ms"] = round(predict_ms(t.cost), 3)
                 bd = t.cost.transfer_breakdown or (0, 0, 0)
@@ -1477,10 +1494,11 @@ class DeviceScheduler:
             # on the first error
             _faults.check("launch")
             t_l0 = time.perf_counter_ns()
-            val = lead.fn()
+            with self._live_launch([lead], "opaque", lead.program):
+                val = lead.fn()
             self._mem_note([lead], lead.mesh)
             self._trace_launch([lead], t_l0, time.perf_counter_ns(),
-                               "opaque")
+                               "opaque", program=lead.program)
             lead.finish(val)
             self.launches += 1
             self._m_launch.inc(mode="single")
@@ -1513,6 +1531,7 @@ class DeviceScheduler:
                                      get_fused_rows_program,
                                      get_sharded_program)
         members = [grp[0] for grp in programs]
+        all_tasks = [t for grp in programs for t in grp]
         lead = members[0]
         cc0 = self._cc_mark()
         t_l0 = time.perf_counter_ns()     # launch span covers resolve
@@ -1528,7 +1547,7 @@ class DeviceScheduler:
             # EVERY task (riders too): a same-key rider carrying a
             # different input token must refuse the fused scan — its
             # result would come from the wrong snapshot residents
-            verify_fusion_group([t for grp in programs for t in grp])
+            verify_fusion_group(all_tasks)
             fused = D.FusedDag(tuple(t.dag for t in members))
             if isinstance(lead.dag, D.Aggregation):
                 fprog = get_fused_program(fused, lead.mesh,
@@ -1537,14 +1556,14 @@ class DeviceScheduler:
                 fprog = get_fused_rows_program(
                     fused, lead.mesh,
                     tuple(t.row_capacity for t in members))
-            outs = fprog(lead.cols, lead.counts)
+            with self._live_launch(all_tasks, "fused", fprog.name):
+                outs = fprog(lead.cols, lead.counts)
         except Exception as e:   # noqa: BLE001 - fusion capability probe:
             # refused groups launch apart below (same results, no
             # fusion win) — counted and logged, never silent
             self._note_refusal("fused", lead, e)
             return False
-        total = sum(len(grp) for grp in programs)
-        all_tasks = [t for grp in programs for t in grp]
+        total = len(all_tasks)
         self._cc_note(all_tasks, cc0)
         # fused/coalesced attrs + spans are set BEFORE finish(): the
         # waiter's _note_sched reads task.fused right after wait()
@@ -1556,7 +1575,8 @@ class DeviceScheduler:
             t.coalesced = total
         self._mem_note(all_tasks, lead.mesh)
         self._trace_launch(all_tasks, t_l0, time.perf_counter_ns(),
-                           "fused", fused=len(programs))
+                           "fused", fused=len(programs),
+                           program=fprog.name)
         for grp, out in zip(programs, outs):
             sprog = get_sharded_program(grp[0].dag, grp[0].mesh,
                                         grp[0].row_capacity)
@@ -1607,8 +1627,9 @@ class DeviceScheduler:
                 else:
                     bprog = get_batched_rows_program(
                         lead.dag, lead.mesh, lead.row_capacity, len(slots))
-                outs = bprog([s[0].cols for s in slots],
-                             [s[0].counts for s in slots])
+                with self._live_launch(batch, "batched", bprog.name):
+                    outs = bprog([s[0].cols for s in slots],
+                                 [s[0].counts for s in slots])
                 self._cc_note(batch, cc0)
                 # coalesced attr + spans BEFORE finish (waiter race,
                 # see _serve_fused)
@@ -1616,7 +1637,8 @@ class DeviceScheduler:
                     t.coalesced = len(batch)
                 self._mem_note(batch, lead.mesh)
                 self._trace_launch(batch, t_l0,
-                                   time.perf_counter_ns(), "batched")
+                                   time.perf_counter_ns(), "batched",
+                                   program=bprog.name)
                 for s, out in zip(slots, outs):
                     for t in s:
                         t.finish((prog, out))
@@ -1639,7 +1661,9 @@ class DeviceScheduler:
         for s in slots:
             t_s0 = t_l0 if first else time.perf_counter_ns()
             first = False
-            out = prog(s[0].cols, s[0].counts, s[0].aux)
+            mode = "coalesced" if len(s) > 1 else "single"
+            with self._live_launch(s, mode, prog.name):
+                out = prog(s[0].cols, s[0].counts, s[0].aux)
             # cumulative from the group's entry: a later slot DID wait
             # on the earlier slots' (and the lead's) resolve/compile
             self._cc_note(s, cc0)
@@ -1648,15 +1672,14 @@ class DeviceScheduler:
                 for t in s:
                     t.coalesced = len(batch)
             self._mem_note(s, lead.mesh)
-            self._trace_launch(s, t_s0, time.perf_counter_ns(),
-                               "coalesced" if len(s) > 1 else "single")
+            self._trace_launch(s, t_s0, time.perf_counter_ns(), mode,
+                               program=prog.name)
             for t in s:
                 t.finish((prog, out))
             self.launches += 1
             if prog._donate_argnums:
                 self.donated_launches += 1
-            self._m_launch.inc(
-                mode="coalesced" if len(s) > 1 else "single")
+            self._m_launch.inc(mode=mode)
 
     def _note_refusal(self, kind: str, lead, err: BaseException) -> None:
         """A fused / vmap-batched group launch raised and its members
@@ -1917,7 +1940,9 @@ class DeviceScheduler:
                 "oom_demuxed": self.oom_demuxed,
                 "shed_rejects": self.shed_rejects,
                 "backlog_ms": round(self._backlog_ns / 1e6, 3),
-                "digest_device_ms": {
+                # per digest, the wall time of its launches' resolve +
+                # DISPATCH (enqueue), not the device's execution time
+                "digest_dispatch_ms": {
                     dk: round(ns / 1e6, 3) for dk, ns in sorted(
                         self._digest_ns.items(),
                         key=lambda kv: -kv[1])[:8]},

@@ -116,14 +116,15 @@ class CopTask:
                  "est_rows", "cost", "cost_static", "rc_group", "rus",
                  "rus_charged", "device_ns", "deadline_ns", "svc_ns",
                  "donate", "retries", "compile_ns", "compile_miss",
-                 "hbm_predicted", "hbm_measured", "value_drift", "trace")
+                 "hbm_predicted", "hbm_measured", "value_drift", "trace",
+                 "program")
 
     def __init__(self, *, key=None, dag=None, mesh=None, row_capacity=0,
                  cols=None, counts=None, aux=(), input_token=None,
                  fusion_key=None, fn: Optional[Callable[[], Any]] = None,
                  group: Optional[str] = None,
                  weight: Optional[float] = None, est_rows: int = 0,
-                 rc_group=None, donate: bool = False):
+                 rc_group=None, donate: bool = False, program: str = ""):
         if group is None:
             group, gw, rcg = current_group()
             if weight is None:
@@ -140,6 +141,8 @@ class CopTask:
         self.input_token = input_token
         self.fusion_key = fusion_key
         self.fn = fn
+        self.program = program    # an opaque launch's program name (a
+                                  # structured task's is its builder's)
         self.group = group
         self.weight = float(weight or DEFAULT_WEIGHT)
         self.est_rows = est_rows
@@ -221,8 +224,9 @@ class CopTask:
                    donate=donate)
 
     @classmethod
-    def opaque(cls, fn: Callable[[], Any], est_rows: int = 0) -> "CopTask":
-        return cls(fn=fn, est_rows=est_rows)
+    def opaque(cls, fn: Callable[[], Any], est_rows: int = 0,
+               program: str = "") -> "CopTask":
+        return cls(fn=fn, est_rows=est_rows, program=program)
 
     # -------- completion -------- #
 

@@ -28,6 +28,7 @@ import threading
 from dataclasses import dataclass, field
 from typing import Optional
 
+from ..obs.trace import late_span
 from ..session.session import Domain, Session
 # placeholder binding is shared with the SQL-level PREPARE/EXECUTE path
 from ..sql.bind import (bind_placeholders as _bind_placeholders,
@@ -268,11 +269,17 @@ class ClientConn:
 
     def _handle_query(self, sql: str):
         rs = self.session.execute(sql)
-        if rs.names:
-            self._write_resultset(rs, binary=False)
-        else:
-            self.io.write(P.ok_packet(rs.affected, rs.last_insert_id,
-                                      status=self._status()))
+        self._write_result(rs, binary=False)
+
+    def _write_result(self, rs, binary: bool):
+        """Encode and send a statement's result set or OK packet: the
+        ``wire.write`` span, added to the statement's finished tree."""
+        with late_span(self.session.last_trace, "wire.write"):
+            if rs.names:
+                self._write_resultset(rs, binary)
+            else:
+                self.io.write(P.ok_packet(rs.affected, rs.last_insert_id,
+                                          status=self._status()))
 
     def _handle_field_list(self, body: bytes):
         table = body.split(b"\x00", 1)[0].decode()
@@ -337,11 +344,7 @@ class ClientConn:
             self.io.write(P.eof_packet(
                 self._status() | P.SERVER_STATUS_CURSOR_EXISTS))
             return
-        if rs.names:
-            self._write_resultset(rs, binary=True)
-        else:
-            self.io.write(P.ok_packet(rs.affected, rs.last_insert_id,
-                                      status=self._status()))
+        self._write_result(rs, binary=True)
 
     def _handle_stmt_fetch(self, body: bytes):
         stmt_id, count = struct.unpack_from("<II", body, 0)

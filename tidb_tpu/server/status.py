@@ -152,7 +152,7 @@ class StatusServer:
                 "rc_throttled": sched.get("rc_throttled", 0),
                 "rc_exhausted": sched.get("rc_exhausted", 0),
                 "rc_debited_ru": sched.get("rc_debited_ru", 0.0),
-                "digest_device_ms": sched.get("digest_device_ms", {}),
+                "digest_dispatch_ms": sched.get("digest_dispatch_ms", {}),
                 # copmeter (analysis/calibrate): closed-loop cost
                 # calibration state + OOM recovery / early shedding
                 "calibration": sched.get("calibration"),

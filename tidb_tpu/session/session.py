@@ -7,6 +7,7 @@ catalog + mesh + cop client per process) is session.domain.Domain.
 
 from __future__ import annotations
 
+import contextlib
 import sys
 import time
 from dataclasses import dataclass, field
@@ -16,6 +17,7 @@ import numpy as np
 
 from ..executor.physical import ExecContext, ResultChunk
 from ..executor.plan import to_physical
+from ..obs import trace as _obs_trace
 from ..parallel.mesh import get_mesh
 from ..planner.build import PlanError, build_query
 from ..planner.logical import explain_logical
@@ -329,6 +331,9 @@ class Session:
         self.temp_tables: dict = {}
         import threading as _th
         self._kill_event = _th.Event()   # KILL QUERY sets; stmt start clears
+        # copscope: the last statement's span tree (None = untraced); the
+        # connection adds its wire.write span to it after execute()
+        self.last_trace = None
 
     def close(self) -> None:
         """Drop session state that outlives no session: temporary tables
@@ -345,7 +350,18 @@ class Session:
     def execute(self, sql: str) -> ResultSet:
         qcnt, qdur = self.domain.query_metrics()
         out = ResultSet()
-        for stmt in parse_sql(sql):
+        # the statement a connection writes its result for (wire.write)
+        self.last_trace = None
+        # parsing precedes every statement's root span: it is bracketed
+        # here and added to each tree below as a completed span
+        parse_ann = (_obs_trace.annotation("session.parse") if _flag_on(
+            {**self.domain.sysvars, **self.vars}, "tidb_tpu_trace", True)
+            else contextlib.nullcontext())
+        parse_t0 = time.perf_counter_ns()
+        with parse_ann:
+            stmts = parse_sql(sql)
+        parse_t1 = time.perf_counter_ns()
+        for stmt in stmts:
             t0 = time.perf_counter_ns()
             span = getattr(stmt, "text_span", None)
             text = sql[span[0]:span[1]].strip() if span else sql
@@ -408,17 +424,22 @@ class Session:
             # into every dispatch so scheduler threads stitch their
             # spans under it.  Off (tidb_tpu_trace=0) = no tree, no
             # contextvar, zero recording anywhere.
-            from ..obs import trace as _obs_trace
             _merged_obs = {**self.domain.sysvars, **self.vars}
             trace_tree = None
             trace_root = None
             obs_tok = None
+            root_ann = contextlib.nullcontext()
             if _flag_on(_merged_obs, "tidb_tpu_trace", True):
                 trace_tree = _obs_trace.SpanTree(sql=text,
                                                  conn_id=self.conn_id)
                 trace_root = trace_tree.begin("session.ExecuteStmt")
+                trace_tree.add("session.parse", parse_t0, parse_t1,
+                               parent_id=trace_root)
                 obs_tok = _obs_trace.TRACE_CTX.set(
                     _obs_trace.TraceCtx(trace_tree, trace_root))
+                root_ann = _obs_trace.annotation(
+                    "session.ExecuteStmt", trace_tree.trace_id)
+            self.last_trace = trace_tree
             stok = SESSION_INFO.set({
                 "db": self.db, "user": self.user,
                 "conn_id": self.conn_id,
@@ -434,7 +455,8 @@ class Session:
                 lambda nm: self.domain.catalog.get_sequence(self.db, nm))
             ttok = TEMP_TABLES.set(self.temp_tables)
             try:
-                out = self._exec_stmt(stmt)
+                with root_ann:
+                    out = self._exec_stmt(stmt)
             except Exception as e:
                 qcnt.inc(type="error")
                 _plugins.fire("on_stmt_end", self, text, str(e),
@@ -1037,7 +1059,11 @@ class Session:
 
     # ------------------------------------------------------------- #
 
+    @_obs_trace.span("session.plan")
     def _plan_select(self, stmt, cache_sql: Optional[str] = None):
+        """Plan one SELECT inside a ``session.plan`` span, whose ``cache``
+        attr says whether the plan cache answered or the plan was built,
+        optimised and gated."""
         from ..planner.plan_cache import PlanCacheEntry, table_fingerprint
         from ..planner.ranger import apply_index_paths
         cache = self.domain.plan_cache
@@ -1060,6 +1086,7 @@ class Session:
         if use_cache:
             e = cache.get(cache_sql, self.db, merged, self.domain.catalog)
             if e is not None:
+                _obs_trace.annotate(cache="hit")
                 return e.built, e.phys
         # uncorrelated scalar subqueries evaluate eagerly at plan time
         # (EvalSubqueryFirstRow analog); plans that did so are not cached
@@ -1103,25 +1130,26 @@ class Session:
         # PlanContractError is a PlanError, so it surfaces like any
         # planner rejection.  tidb_tpu_verify_plan=0 opts out.
         if _flag_on(merged, "tidb_tpu_verify_plan", default=True):
-            from ..analysis.contracts import verify_plan
-            verify_plan(phys)
-            # sharding-flow pass (analysis/shardflow): layouts and
-            # collectives of every device program flowed against the
-            # mesh's typed-link topology (declared host view included)
-            # — implicit reshards, unknown axes, coordinator-routed
-            # merges, and DCI blow-ups reject HERE, pre-trace, like
-            # any other contract violation
-            from ..analysis.shardflow import verify_plan_sharding
-            verify_plan_sharding(phys, self._topology(merged))
-            # value-range pass (analysis/valueflow): every device lane
-            # flowed over stats-seeded integer intervals — silent int64
-            # wraps, unprovable SUM fences, f32 precision cliffs and
-            # div pre-scale escapes reject HERE, pre-trace; each
-            # verified digest lands in the proof registry the sched
-            # admission seam replays
-            from ..analysis.valueflow import verify_plan_values
-            verify_plan_values(phys, self.domain.stats)
-            phys._contract_ok = True
+            with _obs_trace.span("plan.gates"):
+                from ..analysis.contracts import verify_plan
+                verify_plan(phys)
+                # sharding-flow pass (analysis/shardflow): layouts and
+                # collectives of every device program flowed against the
+                # mesh's typed-link topology (declared host view included)
+                # — implicit reshards, unknown axes, coordinator-routed
+                # merges, and DCI blow-ups reject HERE, pre-trace, like
+                # any other contract violation
+                from ..analysis.shardflow import verify_plan_sharding
+                verify_plan_sharding(phys, self._topology(merged))
+                # value-range pass (analysis/valueflow): every device lane
+                # flowed over stats-seeded integer intervals — silent int64
+                # wraps, unprovable SUM fences, f32 precision cliffs and
+                # div pre-scale escapes reject HERE, pre-trace; each
+                # verified digest lands in the proof registry the sched
+                # admission seam replays
+                from ..analysis.valueflow import verify_plan_values
+                verify_plan_values(phys, self.domain.stats)
+                phys._contract_ok = True
         use_cache = use_cache and not ran_subquery
         if use_cache and _plan_cacheable(phys):
             keys = {}
@@ -1134,6 +1162,7 @@ class Session:
                 keys[(tdb, name)] = table_fingerprint(tbl)
             cache.put(cache_sql, self.db, merged,
                       PlanCacheEntry(built, phys, keys))
+        _obs_trace.annotate(cache="miss")
         return built, phys
 
     def _eval_scalar_subquery(self, sub_ast, ran: list):
@@ -1316,9 +1345,11 @@ class Session:
         chunk = phys.execute(ctx)
         n_out = len(built.output_names)
         cols = chunk.columns[:n_out]  # trim hidden ORDER BY columns
-        rows = list(zip(*[c.to_python() for c in cols])) if cols else []
-        return ResultSet(built.output_names, rows,
-                         dtypes=[c.dtype for c in cols])
+        with _obs_trace.span("session.resultset"):
+            rows = list(zip(*[c.to_python() for c in cols])) \
+                if cols else []
+            return ResultSet(built.output_names, rows,
+                             dtypes=[c.dtype for c in cols])
 
     def _exec_explain(self, stmt: A.Explain) -> ResultSet:
         if not isinstance(stmt.stmt, (A.SelectStmt, A.SetOpStmt)):

@@ -286,6 +286,24 @@ class CopClient:
         return {"intra_bytes": bd[0], "ici_bytes": bd[1],
                 "dci_bytes": bd[2]}
 
+    def _fetch(self, out, **attrs):
+        """Host copy of a launch's outputs: the ONE place this client
+        waits for the device.  ``cop.transfer`` keeps the extent it
+        always had (blocked until the values are on the host); its
+        children split it into the wait for the program to finish
+        (``cop.device_wait``) and what is left of the copy after that
+        (``cop.d2h``).  The copies are requested first, as
+        ``jax.device_get`` alone would, so they still follow the program
+        on the device without a round trip through the host."""
+        with _obs_span("cop.transfer", **attrs):
+            for leaf in jax.tree_util.tree_leaves(out):
+                if isinstance(leaf, jax.Array):
+                    leaf.copy_to_host_async()
+            with _obs_span("cop.device_wait"):
+                jax.block_until_ready(out)
+            with _obs_span("cop.d2h"):
+                return jax.device_get(out)
+
     def _note_sched(self, task) -> None:
         if task.cost is not None:
             self._obs_tl.breakdown = task.cost.transfer_breakdown
@@ -335,15 +353,17 @@ class CopClient:
             finally:
                 self._note_sched(t)
 
-    def _launch_opaque(self, fn, est_rows: int = 0):
+    def _launch_opaque(self, fn, est_rows: int = 0, program: str = ""):
         """Admission-controlled launch of a program with a non-standard
-        signature (shuffle/window): fair-ordered, never coalesced."""
+        signature (shuffle/window): fair-ordered, never coalesced.
+        ``program`` names it on the launch span."""
         sched = self._scheduler()
         if sched is None:
             return fn()
         from ..sched import CopTask
         with _obs_span("cop.dispatch", opaque=True):
-            t = sched.submit(CopTask.opaque(fn, est_rows=est_rows))
+            t = sched.submit(CopTask.opaque(fn, est_rows=est_rows,
+                                            program=program))
             try:
                 return t.wait()
             finally:
@@ -515,8 +535,7 @@ class CopClient:
                 if grown is not None:
                     agg = grown
                     continue
-            with _obs_span("cop.transfer", **self._transfer_attrs()):
-                states = jax.device_get(out)
+            states = self._fetch(out, **self._transfer_attrs())
             # faultline transfer/host-merge seam, keyed by the digest
             _faults.check("transfer", D.dag_digest(agg))
             break
@@ -570,8 +589,7 @@ class CopClient:
             if i + 1 < len(batches):
                 nxt = batches[i + 1].device_put_uncached(self.mesh)
             del cols, counts     # free the batch once its program consumed it
-        with _obs_span("cop.transfer", batches=len(outs)):
-            return [jax.device_get(o) for o in outs]
+        return self._fetch(outs, batches=len(outs))
 
     def _stream_dense_agg(self, agg, batches, key_meta) -> CopResult:
         states_list = self._stream_states(agg, batches)
@@ -613,7 +631,7 @@ class CopClient:
             for _ in range(10):
                 sized = self._with_capacity(agg, cap)
                 _prog, out = self._launch(sized, cols, counts, ())
-                states = jax.device_get(out)
+                states = self._fetch(out)
                 true_ng = int(np.max(np.asarray(states["__ngroups__"])))
                 if true_ng <= cap:
                     break
@@ -655,7 +673,7 @@ class CopClient:
         """If the expanding join overflowed its capacity, return the DAG
         rebuilt with a big-enough capacity; None when it fits (the join
         half of the paging grow-from-min discipline)."""
-        need = int(np.max(np.asarray(jax.device_get(extras["join_total"]))))
+        need = int(np.max(np.asarray(self._fetch(extras["join_total"]))))
         node = D.find_expand_join(dag)
         if node is not None and need > node.out_capacity:
             return D.rewrite_expand_capacity(dag, _pow2_at_least(need))
@@ -686,7 +704,8 @@ class CopClient:
                 hashed_dag, leaf_scan = pre
                 hprog = radix.get_hash_program(leaf_scan, agg.group_by,
                                                self.mesh)
-                hv = self._launch_opaque(lambda: hprog(cols, counts))
+                hv = self._launch_opaque(lambda: hprog(cols, counts),
+                                         program=hprog.name)
                 cols = list(cols) + [(hv, None)]
                 agg = hashed_dag
         cap = self._warm_cap(agg, agg.state_capacity
@@ -700,8 +719,7 @@ class CopClient:
                 if grown is not None:
                     agg = grown
                     continue
-            with _obs_span("cop.transfer", **self._transfer_attrs()):
-                states = jax.device_get(out)
+            states = self._fetch(out, **self._transfer_attrs())
             true_ng = int(np.max(np.asarray(states["__ngroups__"])))
             if true_ng <= cap:
                 sized = self._with_capacity(agg, cap)
@@ -751,9 +769,10 @@ class CopClient:
             prog = get_shuffle_program(spec, self.mesh, caps)
             out, extras = self._launch_opaque(
                 lambda p=prog: p(lcols, lcounts, rcols, rcounts, aux_cols),
-                est_rows=lsnap.num_rows + rsnap.num_rows)
-            extras = {k: np.asarray(jax.device_get(v))
-                      for k, v in extras.items()}
+                est_rows=lsnap.num_rows + rsnap.num_rows,
+                program=prog.name)
+            extras = {k: np.asarray(v)
+                      for k, v in self._fetch(extras).items()}
             grew = False
             need_l = int(extras["lmax"].max())
             if need_l > caps.left:
@@ -774,14 +793,14 @@ class CopClient:
             agg = spec.top if isinstance(spec.top, D.Aggregation) else None
             if agg is not None and agg.strategy in D.HOST_MERGE_STRATEGIES:
                 true_ng = int(np.max(np.asarray(
-                    jax.device_get(out["__ngroups__"]))))
+                    self._fetch(out["__ngroups__"]))))
                 if true_ng > agg.state_capacity:
                     spec = dataclasses.replace(spec, top=self._with_capacity(
                         agg, _pow2_at_least(true_ng)))
                     continue
             if agg is None:
                 _cols, counts = out
-                counts = np.asarray(jax.device_get(counts))
+                counts = np.asarray(self._fetch(counts))
                 if (counts > caps.rows).any():
                     caps = dataclasses.replace(
                         caps, rows=_pow2_at_least(int(counts.max())))
@@ -810,8 +829,8 @@ class CopClient:
             prog = get_window_program(spec, self.mesh, cap)
             (out_cols, out_counts), extras = self._launch_opaque(
                 lambda p=prog: p(cols, counts, aux_cols),
-                est_rows=snap.num_rows)
-            need = int(np.max(np.asarray(jax.device_get(extras["wmax"]))))
+                est_rows=snap.num_rows, program=prog.name)
+            need = int(np.max(np.asarray(self._fetch(extras["wmax"]))))
             if need <= cap:
                 break
             cap = _pow2_at_least(need)
@@ -830,7 +849,7 @@ class CopClient:
                                   aux_cols=()) -> CopResult:
         prog, out = self._run_shuffle(spec, lsnap, rsnap, aux_cols)
         agg = prog.spec.top
-        states = jax.device_get(out)
+        states = self._fetch(out)
         if prog.host_merge:
             per_dev = self._split_devices(states)
             if agg.strategy in D.HOST_MERGE_STRATEGIES:
@@ -868,8 +887,8 @@ class CopClient:
         """Concatenate per-device compacted outputs into host Columns."""
         _faults.check("transfer")   # faultline device->host seam
         n_dev = len(self.mesh.devices.reshape(-1))
-        out_counts = np.asarray(jax.device_get(out_counts))
-        out_cols = jax.device_get(out_cols)
+        out_cols, out_counts = self._fetch((out_cols, out_counts))
+        out_counts = np.asarray(out_counts)
         per_dev_take = np.minimum(out_counts, cap)
         result = []
         for j, t in enumerate(out_dtypes):
@@ -938,8 +957,10 @@ class CopClient:
                     root = grown
                     continue
             out_cols, out_counts = out
-            out_counts = np.asarray(jax.device_get(out_counts))
-            if is_topn or is_limit or (out_counts <= cap).all():
+            if is_topn or is_limit:
+                break       # the capacity is the limit: nothing to regrow
+            out_counts = np.asarray(self._fetch(out_counts))
+            if (out_counts <= cap).all():
                 break
             cap = self._warm_cap(root, _pow2_at_least(int(out_counts.max())))
         else:
