@@ -455,8 +455,20 @@ def _walk(node: D.CopNode, path: tuple, rows: int, layout: Layout,
         nk = max(len(keys), 1)
         for e, _desc in keys:
             acc.flops += _expr_flops(e) * rows_in
-        acc.buf("/".join(p) + ":sort", rows_in * (nk + 1) * 8)
-        acc.flops += rows_in * _log2(rows_in) * nk
+        # exec.topn_head: the rows as `blocks` blocks (the kernel's own
+        # chooser; one block = the plain full sort); a pruning TopN
+        # streams them once (one variadic minimum over the comparator
+        # lanes, no buffer), sorts the block minima, then sorts only
+        # the kept blocks' rows
+        length = D.topn_block_len(rows_in, node.limit)
+        blocks = rows_in // length if length else 1
+        kept = max(min(node.limit, blocks), 1) * length
+        if blocks > 1:
+            acc.flops += rows_in * (nk + 1)
+            acc.buf("/".join(p) + ":block-min", blocks * (nk + 1) * 8)
+            acc.flops += blocks * _log2(blocks) * nk
+        acc.buf("/".join(p) + ":sort", kept * (nk + 1) * 8)
+        acc.flops += kept * _log2(kept) * nk
         return min(max(node.limit, 0), rows_in), w_in
 
     if isinstance(node, D.Limit):

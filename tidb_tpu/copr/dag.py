@@ -240,8 +240,11 @@ class TopN(CopNode):
     """Per-shard TopN (root merges shard tops, reference cophandler/topn.go).
     `sort_key`/`desc` is the single-key form; `sort_keys` (a tuple of
     (expr, desc) pairs, priority order) carries multi-column ORDER BY —
-    the device sorts all keys in one lax.sort (cophandler/topn.go
-    multi-ByItem analog)."""
+    all keys ride one multi-key comparator (cophandler/topn.go
+    multi-ByItem analog).  The device finds the first `limit` rows of
+    that order exactly without sorting every row: per-block minima
+    prune all but `limit` blocks, and only those are sorted
+    (`topn_block_len`, copr/exec._exec_topn)."""
     child: CopNode = None  # type: ignore[assignment]
     sort_key: Expr = None  # type: ignore[assignment]
     desc: bool = False
@@ -251,6 +254,36 @@ class TopN(CopNode):
 
     def children(self):
         return (self.child,)
+
+
+# a TopN block is at least one (8, 128) int32 lane tile
+TOPN_MIN_BLOCK = 1024
+
+
+def topn_block_len(n: int, k: int) -> int:
+    """Block length `L` of the device TopN over `n` slots with limit `k`:
+    the rows are viewed as `n // L` blocks, the per-block minima are
+    sorted, and only the `min(k, n // L)` blocks that can hold the
+    answer are sorted in full.  About 8 * sqrt(n / k) rounded to a power
+    of two, floored at the lane tile: on the chip a block costs the
+    streaming pass what ~64 rows cost the final sort (each block ends in
+    a cross-lane reduce, ~85 ns against ~2 ns a sorted row, v5e
+    trace, PERF.md section 6), and n / L blocks against k * L sorted
+    rows balance there.  `L == n` is ONE block — the plain full sort —
+    returned when pruning cannot pay: `n` not divisible into such blocks
+    (toy tables, join outputs at out_capacity, streamed batches) or the
+    two sorts not under a quarter of `n` (small `n`, a large LIMIT).
+    The kernel and its admission cost (analysis/copcost) share this
+    one formula."""
+    k = min(k, n)
+    if k <= 0:
+        return n
+    bits = (n // k).bit_length() - 1
+    length = max(8 << ((bits + 1) // 2), TOPN_MIN_BLOCK)
+    blocks = n // length
+    if n % length or blocks + min(k, blocks) * length > n // 4:
+        return n
+    return length
 
 
 @dataclass(frozen=True)
@@ -513,7 +546,8 @@ __all__ = [
     "Expand", "GroupStrategy", "HOST_MERGE_STRATEGIES", "RADIX_STRATEGIES",
     "RADIX_BITS", "RADIX_RESIDUAL_BITS", "MAX_RADIX_PASSES",
     "radix_passes", "radix_key_bits", "Aggregation",
-    "TopN", "Limit", "LookupJoin",
+    "TopN", "TOPN_MIN_BLOCK", "topn_block_len",
+    "Limit", "LookupJoin",
     "FusedDag", "ShuffleJoinSpec", "output_dtypes", "dag_digest",
     "iter_nodes", "find_expand_join", "rewrite_lookup", "drop_lookup",
     "chain_str", "rewrite_expand_capacity",

@@ -45,10 +45,20 @@ class DeviceBatch:
 
     `extras` carries named traced scalars that must surface to the
     dispatcher alongside the result — today the true output size of an
-    expanding join, so the paging loop can regrow its capacity."""
+    expanding join, so the paging loop can regrow its capacity.
+
+    `stacked` says how the slot axis lies in device memory: as that many
+    equal row-major runs (the (S, C) stacked shards of one device,
+    flattened by parallel/spmd, which the TPU holds interleaved tile by
+    tile).  The scan sets it, operators that keep the slot axis keep it,
+    those that build a new one (Expand, an expanding join, TopN) drop it
+    to 1.  `topn_blocks` is set by a TopN: the blocks it viewed its
+    input as (1 = it sorted every row).  Both are static."""
     cols: list  # list[(value, valid)]
     sel: Any    # bool array | True
     extras: dict = None  # type: ignore[assignment]
+    stacked: int = 1
+    topn_blocks: int = 0
 
     def __post_init__(self):
         if self.extras is None:
@@ -427,7 +437,9 @@ def compact(batch: DeviceBatch, capacity: int):
 # --------------------------------------------------------------------- #
 
 def _exec_node(node: D.CopNode, scan_cols: Sequence, row_count, ev: Evaluator,
-               aux: Sequence = ()):
+               aux: Sequence = (), stacked: int = 1):
+    """`stacked`: the runs the flat scan columns consist of
+    (DeviceBatch.stacked)."""
     if isinstance(node, D.TableScan):
         cols = [scan_cols[off] for off in node.col_offsets]
         n = len(cols[0][0]) if cols else 0
@@ -437,10 +449,14 @@ def _exec_node(node: D.CopNode, scan_cols: Sequence, row_count, ev: Evaluator,
             # caller supplied a precomputed live-row mask (e.g. several
             # flattened shards with per-shard row counts, parallel/spmd.py)
             sel = row_count
-        return DeviceBatch(list(cols), sel)
+        return DeviceBatch(list(cols), sel, stacked=stacked)
+
+    def child():
+        return _exec_node(node.child, scan_cols, row_count, ev, aux,
+                          stacked)
 
     if isinstance(node, D.Selection):
-        batch = _exec_node(node.child, scan_cols, row_count, ev, aux)
+        batch = child()
         memo: dict = {}
         sel = batch.sel
         n = len(batch.cols[0][0])
@@ -451,20 +467,20 @@ def _exec_node(node: D.CopNode, scan_cols: Sequence, row_count, ev: Evaluator,
                 v = v != 0
             keep = v if m is True else (v & m)  # NULL -> filtered out
             sel = keep if sel is True else (sel & keep)
-        return DeviceBatch(batch.cols, sel, batch.extras)
+        return DeviceBatch(batch.cols, sel, batch.extras, batch.stacked)
 
     if isinstance(node, D.Projection):
-        batch = _exec_node(node.child, scan_cols, row_count, ev, aux)
+        batch = child()
         memo = {}
         n = len(batch.cols[0][0])
         cols = []
         for e in node.exprs:
             v, m = ev.eval(e, batch.cols, memo)
             cols.append((_ensure_array(v, n), m))
-        return DeviceBatch(cols, batch.sel, batch.extras)
+        return DeviceBatch(cols, batch.sel, batch.extras, batch.stacked)
 
     if isinstance(node, D.Expand):
-        batch = _exec_node(node.child, scan_cols, row_count, ev, aux)
+        batch = child()
         n = len(batch.cols[0][0]) if batch.cols else 0
         L = len(node.keys)
         LV = node.levels
@@ -486,19 +502,19 @@ def _exec_node(node: D.CopNode, scan_cols: Sequence, row_count, ev: Evaluator,
         return DeviceBatch(out_cols, jnp.tile(sel, LV), batch.extras)
 
     if isinstance(node, D.Limit):
-        batch = _exec_node(node.child, scan_cols, row_count, ev, aux)
+        batch = child()
         n = len(batch.cols[0][0])
         sel = _sel_array(batch.sel, n)
         keep = sel & (jnp.cumsum(sel) <= node.limit)
-        return DeviceBatch(batch.cols, keep, batch.extras)
+        return DeviceBatch(batch.cols, keep, batch.extras, batch.stacked)
 
     if isinstance(node, D.TopN):
         with jax.named_scope("scan_filter"):
-            batch = _exec_node(node.child, scan_cols, row_count, ev, aux)
+            batch = child()
         return _exec_topn(node, batch, ev)
 
     if isinstance(node, D.LookupJoin):
-        batch = _exec_node(node.child, scan_cols, row_count, ev, aux)
+        batch = child()
         return _exec_lookup_join(node, batch, ev, aux)
 
     raise TypeError(node)
@@ -532,7 +548,7 @@ def _exec_lookup_join(node: D.LookupJoin, batch: DeviceBatch, ev: Evaluator,
         sel = batch.sel
         if node.kind == "inner":
             sel = matched if sel is True else (sel & matched)
-        return DeviceBatch(out_cols, sel, batch.extras)
+        return DeviceBatch(out_cols, sel, batch.extras, batch.stacked)
 
     from .join import gather_expand, match_ranges
     sel = _sel_array(batch.sel, n)
@@ -543,7 +559,8 @@ def _exec_lookup_join(node: D.LookupJoin, batch: DeviceBatch, ev: Evaluator,
         keep = (cnt > 0) if node.kind == "semi" else (cnt == 0)
         if node.kind == "anti" and node.null_aware and km is not True:
             keep = keep & km       # NOT IN: NULL probe key -> filtered
-        return DeviceBatch(batch.cols, sel & keep, batch.extras)
+        return DeviceBatch(batch.cols, sel & keep, batch.extras,
+                           batch.stacked)
 
     oc = node.out_capacity
     assert oc > 0, "non-unique LookupJoin needs out_capacity"
@@ -555,14 +572,13 @@ def _exec_lookup_join(node: D.LookupJoin, batch: DeviceBatch, ev: Evaluator,
     return DeviceBatch(out_cols, out_sel, extras)
 
 
-def _exec_topn(node: D.TopN, batch: DeviceBatch, ev: Evaluator) -> DeviceBatch:
-    """Per-shard TopN via a multi-key lax.sort + head-k gather.
-
-    Sort keys, ascending, in priority order: (1) dead-row flag so filtered
-    rows always sort last, (2) per key, a NULL flag encoding MySQL
-    ordering (NULLs first ASC, last DESC), (3) the order-preserving
-    integer key — bitwise-NOT for DESC, an exact overflow-free order
-    reversal — and (4) the row index, which makes the order total and
+def _topn_lanes(node: D.TopN, cols, sel, rows, ev: Evaluator) -> list:
+    """The TopN comparator's lanes over one batch of rows, ascending, in
+    priority order: (1) dead-row flag so filtered rows always sort last,
+    (2) per key, a NULL flag encoding MySQL ordering (NULLs first ASC,
+    last DESC), (3) the order-preserving integer key — bitwise-NOT for
+    DESC, an exact overflow-free order reversal — and (4) `rows`, each
+    row's index in the whole input, which makes the order total and
     batch-stable.  No clamping: every distinct key value keeps its rank
     (review finding: clamping collapsed the extreme key values at the
     limit boundary).
@@ -575,39 +591,139 @@ def _exec_topn(node: D.TopN, batch: DeviceBatch, ev: Evaluator) -> DeviceBatch:
     compared at int32, and the index rides as the last key of an
     unstable sort."""
     memo: dict = {}
-    n = len(batch.cols[0][0])
-    sel = _sel_array(batch.sel, n)
-    dead = (~sel).astype(jnp.int32)  # valueflow: ok - bool lane, [0, 1]
-    operands = [dead]
+    n = len(rows)
+    lanes = [(~sel).astype(jnp.int32)]  # valueflow: ok - bool lane, [0, 1]
     for e, desc in (node.sort_keys or ((node.sort_key, node.desc),)):
-        v, m = ev.eval(e, batch.cols, memo)
+        v, m = ev.eval(e, cols, memo)
         v = _ensure_array(v, n)
         if m is not True:
             # NULL sorts first in ASC, last in DESC
             flag = jnp.where(m, 1, 0) if not desc else jnp.where(m, 0, 1)
-            operands.append(flag.astype(jnp.int32))  # valueflow: ok - literal 0/1 lanes
+            lanes.append(flag.astype(jnp.int32))  # valueflow: ok - literal 0/1 lanes
         if jnp.issubdtype(v.dtype, jnp.signedinteger) \
                 and v.dtype.itemsize <= 4:
             key = v.astype(jnp.int32)   # valueflow: ok - widening only
         else:
             key = sortable_int64(jnp, v, e.dtype.is_float,
                                  e.dtype.kind == K.UINT64)
-        operands.append(~key if desc else key)   # exact reversal
-    operands.append(jnp.arange(
-        n, dtype=jnp.int32 if n < 2 ** 31 else jnp.int64))
+        lanes.append(~key if desc else key)   # exact reversal
+    lanes.append(rows)
+    return lanes
+
+
+def _sort_lanes(lanes: Sequence) -> tuple:
+    return lax.sort(tuple(lanes), num_keys=len(lanes), is_stable=False)
+
+
+def _block_minima(lanes: Sequence) -> list:
+    """Per block of the (stacked, M, rows) views (`topn_head`), the
+    lexicographic minimum of the lane tuple: ONE variadic reduction
+    under the lexicographic comparator, which the lanes' producers fuse
+    into, so every source column is read once and no lane is written
+    out.  (On a v5e it streams at the HBM roofline; a cascade of plain
+    masked minima, lane by lane, re-reads its sources and was 1.5x
+    slower — PERF.md section 6, PR 24.)"""
+    def smaller(a, b):
+        lt = False
+        for x, y in zip(reversed(a), reversed(b)):
+            lt = (x < y) | ((x == y) & lt)
+        return tuple(jnp.where(lt, x, y) for x, y in zip(a, b))
+
+    tops = tuple(jnp.asarray(_max_of(lane.dtype), lane.dtype)
+                 for lane in lanes)
+    return list(lax.reduce(tuple(lanes), tops, smaller, (0, 2)))
+
+
+def topn_head(lanes_of, n: int, k: int, block_len: int,
+              stacked: int = 1):
+    """The first `k` rows of the total order the comparator lanes define
+    over `n` rows, found exactly without sorting them all.  Returns the
+    head `k` of every sorted lane (the last being the rows' indices) and
+    `pick`, which takes those `k` rows out of any (n,) source array.
+
+    `lanes_of(take, rows)` builds the lanes (`_topn_lanes`) over the rows
+    `take` picks out of any (n,) source array; `rows` are their indices,
+    the last lane.  The rows are viewed as M = n / block_len blocks.
+    Which rows share a block is free (the index is in the tuple), so a
+    block is block_len / stacked consecutive rows of every one of the
+    `stacked` runs the flat arrays consist of (DeviceBatch.stacked): on
+    the TPU, stacked (S, C) shards lie tile by tile across the S runs,
+    that (stacked, M, rows) view is the array's own byte order and moves
+    nothing, where M blocks of consecutive rows cost a relayout pass
+    over every column (9 of 10.6 ms a statement at 2^26 rows, PERF.md
+    section 6).
+
+    1. per block, the minimum of the lane tuple (unique: the index is
+       its last lane): M tuples;
+    2. those M tuples sorted; the first min(k, M) name the kept blocks;
+    3. the kept blocks' rows gathered from the SOURCE arrays, their
+       lanes rebuilt, sorted; the head k is the answer.
+
+    A block not kept has k blocks before it, each holding a row smaller
+    than every row of its own, so none of its rows is among the first k:
+    the kept blocks hold the whole answer and the full comparator orders
+    them — ties, NULLs, dead rows and extreme keys exactly as sorting
+    everything would.  `block_len == n` is that full sort."""
+    idx_dtype = jnp.int32 if n < 2 ** 31 else jnp.int64
+    rows = jnp.arange(n, dtype=idx_dtype)
+    if block_len == n:
+        with jax.named_scope("sort"):
+            heads = [lane[:k] for lane in
+                     _sort_lanes(lanes_of(lambda a: a, rows))]
+        return heads, lambda a: a[heads[-1]]
+    if n % stacked or block_len % stacked:
+        stacked = 1
+    m, run, per_run = n // block_len, n // stacked, block_len // stacked
+
+    def view(a):
+        return a.reshape(stacked, m, per_run)
+    with jax.named_scope("block_min"):
+        *_, first = _sort_lanes(_block_minima(
+            [view(lane) for lane in lanes_of(lambda a: a, rows)]))
+    blocks = first[:min(k, m)] % run // per_run
+
+    def take(a):
+        return view(a)[:, blocks].reshape(-1)
+    # the kept rows' indices, in take's order, without gathering an iota
+    rows = (jnp.arange(stacked, dtype=idx_dtype)[:, None, None] * run
+            + blocks[None, :, None] * per_run
+            + jnp.arange(per_run, dtype=idx_dtype)[None, None, :]).reshape(-1)
     with jax.named_scope("sort"):
-        *_, idx = lax.sort(tuple(operands), num_keys=len(operands),
-                           is_stable=False)
+        heads = [lane[:k] for lane in _sort_lanes(lanes_of(take, rows))]
+    # the head is picked out of the gathered blocks, at the position
+    # worked back from its row index: indexing the flat source by row
+    # index would relayout every stacked column (and a position lane
+    # riding the sort as payload costs 12 s more compile)
+    in_run = heads[-1] % run
+    kept = jnp.argmax(
+        (in_run // per_run)[:, None] == blocks[None, :], axis=1)
+    pos = (heads[-1] // run * len(blocks) + kept) * per_run \
+        + in_run % per_run
+    return heads, lambda a: take(a)[pos]
+
+
+def _exec_topn(node: D.TopN, batch: DeviceBatch, ev: Evaluator) -> DeviceBatch:
+    """Per-shard TopN: the comparator lanes of `_topn_lanes`, the first
+    `limit` rows of their order by `topn_head` (block-minimum pruning,
+    block length from `dag.topn_block_len`; one block — the full
+    multi-key sort — where pruning cannot pay), then a head-k gather."""
+    n = len(batch.cols[0][0])
+    sel = _sel_array(batch.sel, n)
     k = min(node.limit, n)
-    idx = idx[:k]
-    live = jnp.sum(sel)
-    out_sel = jnp.arange(k, dtype=jnp.int64) < jnp.minimum(live, k)
-    cols = []
-    for cv, cm in batch.cols:
-        cv = _ensure_array(cv, n)
-        cols.append((cv[idx],
-                     (cm[idx] if cm is not True else True)))
-    return DeviceBatch(cols, out_sel, batch.extras)
+    cols = [(_ensure_array(cv, n), cm) for cv, cm in batch.cols]
+
+    def lanes_of(take, rows):
+        return _topn_lanes(
+            node, [(take(cv), cm if cm is True else take(cm))
+                   for cv, cm in cols], take(sel), rows, ev)
+
+    block_len = D.topn_block_len(n, k)
+    (dead, *_), pick = topn_head(lanes_of, n, k, block_len, batch.stacked)
+    # dead rows sort last, so the head's live rows are its first ones
+    return DeviceBatch(
+        [(pick(cv), (pick(cm) if cm is not True else True))
+         for cv, cm in cols], dead == 0, batch.extras,
+        topn_blocks=n // block_len if n else 1)
 
 
 # --------------------------------------------------------------------- #

@@ -114,6 +114,8 @@ class ShardedCopProgram:
             dag_root, "solo", donate, donate_argnums)
         self.agg = dag_root if isinstance(dag_root, D.Aggregation) else None
         self.kind = "agg" if self.agg is not None else "rows"
+        # per-device input shape -> DeviceBatch.topn_blocks of its trace
+        self._topn_blocks: dict = {}
         # MIN/MAX merge IN-PROGRAM via _psum_gather (psum-only all_gather +
         # reduce), so the whole merge stays on device behind one kind of
         # collective.  Only SORT/SEGMENT-strategy group
@@ -189,7 +191,11 @@ class ShardedCopProgram:
                         states, SHARD_AXIS,
                         len(self.mesh.devices.reshape(-1)))
         else:
-            batch = _exec_node(self.root, flat, base_sel, ev, aux)
+            # the flat columns are the device's S stacked shards, one
+            # run each (DeviceBatch.stacked)
+            batch = _exec_node(self.root, flat, base_sel, ev, aux,
+                               stacked=cols[0][0].shape[0])
+            self._topn_blocks[cols[0][0].shape] = batch.topn_blocks
             out_cols, n = compact(batch, self.row_capacity)
             # keep a leading per-device axis so out_specs can shard it
             out = ([(v[None], m[None]) for v, m in out_cols], n[None])
@@ -197,6 +203,23 @@ class ShardedCopProgram:
             extras = {k: jnp.asarray(v)[None] for k, v in batch.extras.items()}
             return out, extras
         return out
+
+    def topn_blocks(self, stacked_cols: Sequence, counts,
+                    aux_cols=()) -> int:
+        """Blocks the TopN at this program's root viewed each device's
+        rows as when the program was traced for these inputs (`_exec_topn`
+        says, `_device_fn` keeps it by input shape): 0 = the root is no
+        TopN, 1 = it sorts every row, more = it prunes.  Where no trace
+        ran in this process (copforge served the executable from its
+        disk store) the program is traced abstractly, once."""
+        if not isinstance(self.root, D.TopN):
+            return 0
+        s, c = stacked_cols[0][0].shape[:2]
+        shape = (s // len(self.mesh.devices.reshape(-1)), c)
+        if shape not in self._topn_blocks:
+            jax.eval_shape(self._fn, tuple(stacked_cols), counts,
+                           tuple(aux_cols))
+        return self._topn_blocks[shape]
 
     def __call__(self, stacked_cols: Sequence, counts, aux_cols=()):
         if self._psum_limb_fence and stacked_cols:
@@ -361,6 +384,12 @@ class FusedRowsProgram:
     def _device_fn(self, cols, counts, aux):
         return tuple(p._device_fn(cols, counts, aux)
                      for p in self.members)
+
+    def topn_blocks(self, stacked_cols: Sequence, counts) -> int:
+        """The most blocks any member's TopN root prunes by (0: none
+        has one) — see ShardedCopProgram.topn_blocks."""
+        return max(p.topn_blocks(stacked_cols, counts)
+                   for p in self.members)
 
     def __call__(self, stacked_cols: Sequence, counts, aux_cols=()):
         return self._cached(tuple(stacked_cols), counts, tuple(aux_cols))

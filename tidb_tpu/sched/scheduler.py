@@ -228,6 +228,11 @@ class DeviceScheduler:
         self.batched_launches = 0         # stacked-slot vmap launches
         self.batched_rows_launches = 0    # rows-kind stacked launches
         self.fused_launches = 0           # cross-query fused launches
+        # launches of programs with a TopN root, and those of them whose
+        # TopN prunes by block minima instead of sorting every row
+        # (copr/exec.topn_head; the traced program's `topn_blocks` > 1)
+        self.topn_launches = 0
+        self.topn_pruned_launches = 0
         self.fused_tasks = 0              # tasks served by a fused launch
         # group launches that raised and were served apart instead: the
         # results are the same, so only these counters (and one log line
@@ -1180,6 +1185,13 @@ class DeviceScheduler:
         ctx = next((t.trace for t in tasks if t.trace is not None), None)
         return _obs.live("sched.launch", ctx, mode=mode, program=program)
 
+    def _note_topn(self, blocks: int) -> None:
+        """Count one launch by its program's ``topn_blocks``."""
+        if blocks:
+            self.topn_launches += 1
+            if blocks > 1:
+                self.topn_pruned_launches += 1
+
     @staticmethod
     def _trace_mark(t, name: str, **attrs) -> None:
         """Zero-duration marker span on one task's trace (oom / bisect
@@ -1190,7 +1202,7 @@ class DeviceScheduler:
 
     def _trace_launch(self, tasks: list, start_ns: int, end_ns: int,
                       mode: str, fused: int = 0,
-                      program: str = "") -> None:
+                      program: str = "", topn_blocks: int = 0) -> None:
         """Record one physical launch's scheduler-side span tree +
         latency histograms, on the DRAIN thread BEFORE the tasks
         finish — a waiter rendering its trace right after wait()
@@ -1200,7 +1212,8 @@ class DeviceScheduler:
         pickup; rc debit rides it as the ``ru`` attr) and a
         ``sched.launch`` span (resolve + DISPATCH: the call returns
         once the program is enqueued, before the device has run it)
-        carrying the program's name, predicted_ms (calibrated
+        carrying the program's name, topn_blocks (a TopN-rooted
+        program's block count: 1 = full sort), predicted_ms (calibrated
         LaunchCost via copmeter's predict_ms) next to dispatch_ms (the
         span's own wall time), the shardflow per-link transfer breakdown,
         and — as children — the copforge ``sched.compile`` span
@@ -1227,6 +1240,8 @@ class DeviceScheduler:
             attrs = {"mode": mode, "dispatch_ms": round(wall_ms, 3)}
             if program:
                 attrs["program"] = program
+            if topn_blocks:
+                attrs["topn_blocks"] = topn_blocks
             if t.cost is not None:
                 attrs["predicted_ms"] = round(predict_ms(t.cost), 3)
                 bd = t.cost.transfer_breakdown or (0, 0, 0)
@@ -1563,6 +1578,8 @@ class DeviceScheduler:
             # fusion win) — counted and logged, never silent
             self._note_refusal("fused", lead, e)
             return False
+        blocks = 0 if isinstance(lead.dag, D.Aggregation) \
+            else fprog.topn_blocks(lead.cols, lead.counts)
         total = len(all_tasks)
         self._cc_note(all_tasks, cc0)
         # fused/coalesced attrs + spans are set BEFORE finish(): the
@@ -1576,7 +1593,7 @@ class DeviceScheduler:
         self._mem_note(all_tasks, lead.mesh)
         self._trace_launch(all_tasks, t_l0, time.perf_counter_ns(),
                            "fused", fused=len(programs),
-                           program=fprog.name)
+                           program=fprog.name, topn_blocks=blocks)
         for grp, out in zip(programs, outs):
             sprog = get_sharded_program(grp[0].dag, grp[0].mesh,
                                         grp[0].row_capacity)
@@ -1586,6 +1603,7 @@ class DeviceScheduler:
         if fprog._donate_argnums:
             self.donated_launches += 1
         self.fused_launches += 1
+        self._note_topn(blocks)
         self.fused_tasks += total
         self._m_launch.inc(mode="fused")
         self._m_fused.inc(total)
@@ -1630,6 +1648,7 @@ class DeviceScheduler:
                 with self._live_launch(batch, "batched", bprog.name):
                     outs = bprog([s[0].cols for s in slots],
                                  [s[0].counts for s in slots])
+                blocks = prog.topn_blocks(lead.cols, lead.counts)
                 self._cc_note(batch, cc0)
                 # coalesced attr + spans BEFORE finish (waiter race,
                 # see _serve_fused)
@@ -1638,7 +1657,7 @@ class DeviceScheduler:
                 self._mem_note(batch, lead.mesh)
                 self._trace_launch(batch, t_l0,
                                    time.perf_counter_ns(), "batched",
-                                   program=bprog.name)
+                                   program=bprog.name, topn_blocks=blocks)
                 for s, out in zip(slots, outs):
                     for t in s:
                         t.finish((prog, out))
@@ -1649,6 +1668,7 @@ class DeviceScheduler:
                     # member arrays' own lifetime
                     self.donated_launches += 1
                 self.batched_launches += 1
+                self._note_topn(blocks)
                 if prog.kind == "rows":
                     self.batched_rows_launches += 1
                 self._m_launch.inc(mode="batched")
@@ -1664,6 +1684,7 @@ class DeviceScheduler:
             mode = "coalesced" if len(s) > 1 else "single"
             with self._live_launch(s, mode, prog.name):
                 out = prog(s[0].cols, s[0].counts, s[0].aux)
+            blocks = prog.topn_blocks(s[0].cols, s[0].counts, s[0].aux)
             # cumulative from the group's entry: a later slot DID wait
             # on the earlier slots' (and the lead's) resolve/compile
             self._cc_note(s, cc0)
@@ -1673,10 +1694,11 @@ class DeviceScheduler:
                     t.coalesced = len(batch)
             self._mem_note(s, lead.mesh)
             self._trace_launch(s, t_s0, time.perf_counter_ns(), mode,
-                               program=prog.name)
+                               program=prog.name, topn_blocks=blocks)
             for t in s:
                 t.finish((prog, out))
             self.launches += 1
+            self._note_topn(blocks)
             if prog._donate_argnums:
                 self.donated_launches += 1
             self._m_launch.inc(mode=mode)
@@ -1896,6 +1918,8 @@ class DeviceScheduler:
                 "batched_launches": self.batched_launches,
                 "batched_rows_launches": self.batched_rows_launches,
                 "fused_launches": self.fused_launches,
+                "topn_launches": self.topn_launches,
+                "topn_pruned_launches": self.topn_pruned_launches,
                 "fused_tasks": self.fused_tasks,
                 "fused_refused": self.fused_refused,
                 "batched_refused": self.batched_refused,
