@@ -36,6 +36,11 @@ LINEITEM_COLUMNS = ["l_orderkey", "l_partkey", "l_quantity",
                     "l_extendedprice", "l_discount", "l_returnflag",
                     "l_linestatus", "l_shipdate"]
 SMALL_ROWS = 2000
+# a broadcast lookup join with a group-by: lineitem probes, part (unique
+# key) is built, 25 brand groups.  Not TPC-H Q19, though the records
+# before PR 25 called it "Q19-shape": Q14 and Q19 themselves run in the
+# benchmark's cell tpch1x1.partjoin
+BCAST_JOIN_TAG = "bcast_join_groupby"
 HNDV_SQL = ("select l_partkey, sum(l_quantity) from lineitem "
             "group by l_partkey order by 2 desc, 1 limit 10")
 SMALL_SQL = "select count(*), sum(v) from smoke_kv where grp < 5"
@@ -44,7 +49,7 @@ CLUSTER_SQL = "select * from information_schema.cluster_info"
 # for a statement to be answered without the device doing the work
 ZERO_COUNTERS = ("quarantined", "bisected_launches", "retried_launches",
                  "warm_failures", "budget_rejects", "fused_refused",
-                 "batched_refused", "oom_faults")
+                 "batched_refused", "oom_faults", "join_host_fallbacks")
 
 
 class SmokeFailure(AssertionError):
@@ -110,7 +115,7 @@ def oracle(li: dict, part: dict, small: np.ndarray) -> dict:
     m = qty < 1000
     bsum = _group_sums(brand.data[pk[m] - 1].astype(np.int64), price[m],
                        len(brand.dictionary))
-    out["join"] = sorted((brand.dictionary.values[b], _dec(s, 2))
+    out[BCAST_JOIN_TAG] = sorted((brand.dictionary.values[b], _dec(s, 2))
                          for b, s in enumerate(bsum) if s)
 
     psum = _group_sums(pk, qty, int(pk.max()) + 1)
@@ -180,7 +185,7 @@ def _run_twice(c, status_port: int, tag: str, sql: str, want: list) -> dict:
         rows = c.query(sql)
         times.append((time.monotonic() - t) * 1e3)
         got = _typed(rows, want[0])
-        if tag == "join":
+        if tag == BCAST_JOIN_TAG:
             got = sorted(got)               # no ORDER BY
         if got != want:
             raise SmokeFailure(f"{tag}: wrong answer\n got      {got[:12]}\n "
@@ -199,7 +204,7 @@ def _run_twice(c, status_port: int, tag: str, sql: str, want: list) -> dict:
          "compile_ms": s1["compile_cache"]["compile_ms"]
          - s0["compile_cache"]["compile_ms"],
          "peak_hbm_bytes": _peak_hbm()}
-    log(f"{tag:5s} ok  {strategy or 'no agg strategy'}  "
+    log(f"{tag:18s} ok  {strategy or 'no agg strategy'}  "
         f"cold={times[0]:.1f}ms warm={times[1]:.1f}ms "
         f"(server cold={srv_cold:.1f}ms warm={srv_warm:.1f}ms)  "
         f"launches={r['launches']} compile={r['compile_ms']:.1f}ms "
@@ -222,7 +227,8 @@ def drive(sf: float, seed: int = 0) -> dict:
                                        gen_part)
 
     statements = [("q6", TPCH_PLAN_QUERIES[0]), ("q1", TPCH_PLAN_QUERIES[1]),
-                  ("join", TPCH_PLAN_QUERIES[9]), ("hndv", HNDV_SQL),
+                  (BCAST_JOIN_TAG, TPCH_PLAN_QUERIES[9]),
+                  ("hndv", HNDV_SQL),
                   ("topn", TPCH_PLAN_QUERIES[6]), ("small", SMALL_SQL)]
     setup = {}
     cfg = load_config(None)

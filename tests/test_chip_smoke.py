@@ -59,8 +59,8 @@ def test_drive_answers_equal_the_oracle_at_sf001():
     finally:
         sys.path.remove(REPO)
     rep = chip_smoke.drive(0.01, seed=3)     # raises on any wrong answer
-    assert set(rep["results"]) == {"q6", "q1", "join", "hndv", "topn",
-                                   "small"}
+    assert set(rep["results"]) == {"q6", "q1", "bcast_join_groupby",
+                                   "hndv", "topn", "small"}
     assert all(r["rows"] > 0 for r in rep["results"].values())
     assert rep["results"]["hndv"]["rows"] == 10
     assert rep["cluster_info"][0][3] == "cpu"

@@ -209,7 +209,7 @@ def test_decimal_column_selectivity_scales_the_constant():
     """A DECIMAL column's histogram holds scaled ints; `l_quantity < 10`
     compares an unscaled integer literal.  Estimated against the raw
     histogram it matched no row (selectivity 1e-9), which made the
-    60M-row table the smaller join input and sent the Q19-shape join
+    60M-row table the smaller join input and sent chip_smoke's broadcast join
     to a shuffle with lineitem as its sorted build side."""
     from tidb_tpu.testing.tpch import TPCH_PLAN_QUERIES, tpch_plan_session
     s = tpch_plan_session(sf=0.01)

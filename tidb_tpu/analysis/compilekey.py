@@ -106,11 +106,20 @@ def stable_digest(dag: D.CopNode) -> str:
 
 
 def _root_tag(dag) -> str:
-    """The root node's kind and strategy, as a program name spells it."""
+    """The root node's kind and strategy, as a program name spells it;
+    ``join_`` first where the program holds a LookupJoin, so that a
+    join-carrying scalar aggregate (TPC-H Q14, Q19) and Q6 are two names
+    in a trace."""
+    if D.lookup_joins(dag):
+        return "join_" + _plain_root_tag(dag)
+    return _plain_root_tag(dag)
+
+
+def _plain_root_tag(dag) -> str:
     if isinstance(dag, D.FusedDag):
         return f"x{len(dag.members)}"
     if isinstance(dag, D.ShuffleJoinSpec):
-        return _root_tag(dag.top)
+        return _plain_root_tag(dag.top)
     if isinstance(dag, D.Aggregation):
         return f"agg_{dag.strategy.value}"
     return {D.TopN: "topn", D.Limit: "limit",
@@ -118,8 +127,8 @@ def _root_tag(dag) -> str:
 
 
 def program_name(program: str, dag) -> str:
-    """``cop_<program>_<root>_<d12>``: the name a device program is
-    jitted under, hence its module's name in a profiler trace
+    """``cop_<program>_<root>_<d12>`` (``cop_<program>_join_<root>_<d12>``
+    with a lookup join inside): the name a device program is jitted under, hence its module's name in a profiler trace
     (``jit_cop_solo_agg_scalar_<d12>``) and part of what JAX's persistent
     cache keys on.  ``program`` is the builder (``solo``, ``fused``,
     ``batched``, ``shuffle``, ...), ``d12`` twelve hex digits of the

@@ -332,11 +332,54 @@ def _verify_dag(node: D.CopNode, path) -> None:
                   "out_capacity")
         if node.aux_slot < 0:
             _fail("capacity-shape", p, f"negative aux_slot {node.aux_slot}")
+        if node.dense:
+            _verify_packing(node, p)
+        elif node.packing:
+            _fail("capacity-shape", p,
+                  "a sorted lookup join that carries a packing")
         if node.kind in ("inner", "left"):
             for t in node.build_dtypes:
                 if t.is_host_object:
                     _fail("host-object-on-device", p,
                           f"broadcast build column of type {t}")
+
+
+def _verify_packing(node: D.LookupJoin, p) -> None:
+    """Contract of the direct-addressed build side (copr/joinbuild
+    `_dense_group` writes it, copr/join `direct_lookup` reads it): only a unique
+    inner/left join is addressed directly; one layout entry a build
+    column; inside each int32 word no two fields overlap and none
+    reaches the sign bit."""
+    from ..copr.joinbuild import APART, KEY_ITSELF, WORD_BITS
+    if not node.unique or node.kind not in ("inner", "left"):
+        _fail("capacity-shape", p,
+              "direct addressing on a join that is not a unique "
+              "inner/left lookup")
+    if len(node.packing) != 3:
+        _fail("arity", p, "direct-addressed lookup join without a packing")
+    n_words, pbit, layout = node.packing
+    if len(layout) != len(node.build_dtypes):
+        _fail("arity", p,
+              f"packing lays out {len(layout)} build columns, the join "
+              f"has {len(node.build_dtypes)}")
+    used = [0] * n_words
+    fields = [(0, pbit, 1)] if pbit >= 0 else []
+    for w, shift, bits, vbit, _wide in layout:
+        if w in (APART, KEY_ITSELF):
+            continue
+        fields.append((w, shift, bits))
+        if vbit >= 0:
+            fields.append((w, vbit, 1))
+    for w, shift, bits in fields:
+        if not 0 <= w < n_words or shift < 0 or shift + bits > WORD_BITS:
+            _fail("capacity-shape", p,
+                  f"packed field (word {w}, shift {shift}, {bits} bits) "
+                  f"outside {n_words} words of {WORD_BITS} bits")
+        mask = ((1 << bits) - 1) << shift
+        if used[w] & mask:
+            _fail("capacity-shape", p,
+                  f"packed fields overlap in word {w}")
+        used[w] |= mask
 
 
 def _verify_prehashed(node: D.Aggregation, schema, p) -> None:

@@ -476,6 +476,16 @@ def _walk(node: D.CopNode, path: tuple, rows: int, layout: Layout,
 
     if isinstance(node, D.LookupJoin):
         build_w = _schema_width(node.build_dtypes)
+        if node.dense:
+            # subtract, bounds check, one gather a word, shift + mask + add
+            # a packed column: no search
+            n_words, _pbit, layout = node.packing
+            acc.flops += (_expr_flops(node.probe_key) + 3 + n_words
+                          + 3 * len(layout)) * rows_in
+            acc.buf("/".join(p) + ":gather", rows_in * 4 * max(n_words, 1))
+            return rows_in, w_in + build_w
+        # binary search: log2 of the BUILD side, which the plan does not
+        # know; the probe's own row count bounds it from above
         acc.flops += (_expr_flops(node.probe_key) + _log2(rows_in)) * rows_in
         if node.kind in ("semi", "anti"):
             acc.buf("/".join(p) + ":mask", rows_in * _VALIDITY_BYTES)
@@ -754,14 +764,7 @@ def _cop_exec_cost(op, n_devices: int, donation=None,
     if type(op).__name__ == "CopJoinTaskExec":
         builds = (op.builds if op.builds
                   else [{"exec": op.build_exec}])
-        joins = []
-
-        def collect(n):
-            if isinstance(n, D.LookupJoin):
-                joins.append(n)
-            for k in n.children():
-                collect(k)
-        collect(dag)
+        joins = D.lookup_joins(dag)
         for i, b in enumerate(builds):
             bx = b.get("exec")
             rows = _est_rows(bx) if bx is not None else 1024

@@ -123,11 +123,6 @@ class DonationPlan:
 # DAG classification
 # ------------------------------------------------------------------ #
 
-def _lookup_joins(node: D.CopNode) -> list:
-    return [n for n in D.iter_nodes(node)
-            if isinstance(n, D.LookupJoin)]
-
-
 def scan_lifetime(dag: D.CopNode) -> Tuple[BufferClass, str]:
     """Lifetime class of a program's scan inputs (cols + counts),
     derived from the regrow disciplines in store/client.py: any DAG the
@@ -165,7 +160,7 @@ def aux_lifetime(dag: D.CopNode) -> Tuple[BufferClass, str]:
     if isinstance(dag, D.FusedDag):
         seen: set = set()
         for m in dag.members:
-            for j in _lookup_joins(m):
+            for j in D.lookup_joins(m):
                 if j.aux_slot in seen:
                     return (BufferClass.PERSISTENT,
                             f"aux slot {j.aux_slot} shared by >= 2 fused "

@@ -16,6 +16,7 @@ pkg/store/copr/coprocessor_cache.go).
 from __future__ import annotations
 
 import enum
+import functools
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
@@ -313,8 +314,26 @@ class LookupJoin(CopNode):
       probe (join/hash_join_v2.go) — range-gather beats hash tables on TPU.
 
     The build side arrives as auxiliary program inputs (host-materialized,
-    replicated to every device): aux[0] = sorted build keys (int64),
-    aux[1] = permutation into build rows, aux[2:] = build columns.
+    replicated to every device), in one of two forms that copr/joinbuild.py
+    `prepare_build` chooses from what the build side IS:
+
+    - sorted (`dense=False`): aux[0] = build keys ascending (int32 where
+      both sides prove it, else int64), aux[1] = permutation into build
+      rows (the identity: the columns come sorted too; only the expanding
+      path reads it), aux[2:] = build columns.  A probe is a binary
+      search plus one gather a column.
+    - direct-addressed (`dense=True`): the unique build keys cover a
+      range little longer than their count (every TPC-H primary key), so
+      `key - base` IS the build row.  aux[0] = base, aux[1] = each
+      column's minimum, then `packing[0]` int32 word tables over the key
+      range that hold every build column that fits (value - minimum, a
+      validity bit where it has NULLs, one presence bit where the range
+      has holes), then the columns too wide to pack.  A probe is one
+      subtraction, one bounds check and ONE gather a word: `packing` =
+      (n_words, presence bit | -1, ((word, shift, bits, validity bit |
+      -1, wide), ...) per build column); `word` -1 for a column carried
+      apart, -2 for the key column, which is not carried at all.
+
     Output schema = probe schema ++ build columns (probe schema only for
     semi/anti); `kind` inner|left|semi|anti."""
     child: CopNode = None  # type: ignore[assignment]
@@ -329,6 +348,10 @@ class LookupJoin(CopNode):
     # exchanges, physicalop/fragment.go analog) — each join level reads
     # its own (sorted keys, perm, build columns) group
     aux_slot: int = 0
+    # runtime strategy, like `unique`: set by the executor once the build
+    # side is in hand (rewrite_lookup), never by the planner
+    dense: bool = False
+    packing: tuple = ()
 
     def children(self):
         return (self.child,)
@@ -444,6 +467,16 @@ def iter_nodes(node: CopNode):
         stack.extend(n.children())
 
 
+@functools.lru_cache(maxsize=1024)
+def lookup_joins(node) -> tuple:
+    """Every LookupJoin of a pushed DAG (a FusedDag's members included),
+    root first; empty for a program that joins nothing.  Cached: the
+    scheduler asks on every launch, the program namer on every build."""
+    if not isinstance(node, CopNode):
+        return ()
+    return tuple(n for n in iter_nodes(node) if isinstance(n, LookupJoin))
+
+
 def find_expand_join(node: CopNode):
     """The (at most one) non-unique LookupJoin in a pushed DAG, or None —
     programs containing one report true join output size via extras."""
@@ -549,6 +582,7 @@ __all__ = [
     "TopN", "TOPN_MIN_BLOCK", "topn_block_len",
     "Limit", "LookupJoin",
     "FusedDag", "ShuffleJoinSpec", "output_dtypes", "dag_digest",
-    "iter_nodes", "find_expand_join", "rewrite_lookup", "drop_lookup",
+    "iter_nodes", "lookup_joins", "find_expand_join", "rewrite_lookup",
+    "drop_lookup",
     "chain_str", "rewrite_expand_capacity",
 ]

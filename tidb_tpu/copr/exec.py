@@ -522,34 +522,33 @@ def _exec_node(node: D.CopNode, scan_cols: Sequence, row_count, ev: Evaluator,
 
 def _exec_lookup_join(node: D.LookupJoin, batch: DeviceBatch, ev: Evaluator,
                       aux) -> DeviceBatch:
-    """Sorted-lookup join (see dag.LookupJoin).  aux is a tuple of GROUPS,
-    one per chained join level; group layout: [0]=(sorted build keys,),
-    [1]=(perm,), [2:]=build columns."""
+    """Broadcast lookup join (see dag.LookupJoin for the two forms a
+    build side takes).  aux is a tuple of GROUPS, one per chained join
+    level."""
     n = len(batch.cols[0][0])
     grp = aux[node.aux_slot]
-    sorted_keys = grp[0][0]
-    perm = grp[1][0]
-    build_cols = grp[2:]
     kv, km = ev.eval(node.probe_key, batch.cols, {})
-    kv = _ensure_array(kv, n).astype(jnp.int64)
+    kv = _ensure_array(kv, n)
 
     if node.unique and node.kind in ("inner", "left"):
-        idx = jnp.searchsorted(sorted_keys, kv)
-        idxc = jnp.clip(idx, 0, sorted_keys.shape[0] - 1)
-        matched = sorted_keys[idxc] == kv
+        from .join import direct_lookup, sorted_lookup
+        with jax.named_scope("join_probe"):
+            matched, build = direct_lookup(kv, grp, node.packing) \
+                if node.dense else sorted_lookup(kv, grp)
         if km is not True:
             matched = matched & km
-        brow = perm[idxc]
         out_cols = list(batch.cols)
-        for bv, bm in build_cols:
-            gv = bv[brow]
-            gm = matched if bm is True else (bm[brow] & matched)
-            out_cols.append((gv, gm))
+        for gv, gm in build:
+            out_cols.append((gv, matched if gm is True else (gm & matched)))
         sel = batch.sel
         if node.kind == "inner":
             sel = matched if sel is True else (sel & matched)
         return DeviceBatch(out_cols, sel, batch.extras, batch.stacked)
 
+    sorted_keys = grp[0][0].astype(jnp.int64)
+    kv = kv.astype(jnp.int64)
+    perm = grp[1][0]
+    build_cols = grp[2:]
     from .join import gather_expand, match_ranges
     sel = _sel_array(batch.sel, n)
     key_ok = sel if km is True else (sel & km)

@@ -16,6 +16,73 @@ grow-from-min analog, SURVEY.md §5.7).
 from __future__ import annotations
 
 import jax.numpy as jnp
+from jax import lax
+
+from .joinbuild import APART, KEY_ITSELF
+
+
+def _compare_narrow(kv, ref) -> bool:
+    """Whether a probe key and a build-side array can meet at int32: both
+    are read at 32 signed bits or fewer (an int64 lane is two emulated
+    32-bit lanes on a TPU)."""
+    return ref.dtype == jnp.int32 and kv.dtype in (
+        jnp.int8, jnp.int16, jnp.int32)
+
+
+def direct_lookup(kv, grp, packing):
+    """Probe a direct-addressed build side (dag.LookupJoin `dense`).
+    kv: probe keys; grp: the aux group [(array, mask | True)].  Returns
+    (matched, [(value, valid | True) per build column]); a column's
+    value is meaningless where `matched` is False.
+
+    `key - base` wraps for a key far outside the range; read unsigned it
+    is then never below `span` (span <= 2^24 and both operands share one
+    signed width), so one unsigned compare is the whole bounds check."""
+    n_words, pbit, layout = packing
+    meta = grp[0][0]
+    kt, ut = (jnp.int32, jnp.uint32) if _compare_narrow(kv, meta) \
+        else (jnp.int64, jnp.uint64)
+    d = kv.astype(kt) - meta[0].astype(kt)
+    matched = lax.bitcast_convert_type(d, ut) < meta[1].astype(ut)
+    idx = jnp.where(matched, d, 0).astype(jnp.int32)  # valueflow: ok - a matched row's offset is below span <= 2^24
+    words = [grp[2 + w][0].at[idx].get(mode="promise_in_bounds")
+             for w in range(n_words)]
+    if pbit >= 0:
+        matched = matched & ((words[0] >> pbit) & 1).astype(bool)
+    mins = grp[1][0]
+    apart = iter(grp[2 + n_words:])
+    out = []
+    for j, (w, shift, bits, vbit, wide) in enumerate(layout):
+        if w == KEY_ITSELF:
+            out.append((kv.astype(jnp.int64 if wide else jnp.int32), True))
+            continue
+        if w == APART:
+            tv, tm = next(apart)
+            out.append((tv.at[idx].get(mode="promise_in_bounds"),
+                        True if tm is True
+                        else tm.at[idx].get(mode="promise_in_bounds")))
+            continue
+        vt = jnp.int64 if wide else jnp.int32
+        v = ((words[w] >> shift) & ((1 << bits) - 1)).astype(vt) \
+            + mins[j].astype(vt)
+        out.append((v, True if vbit < 0
+                    else ((words[w] >> vbit) & 1).astype(bool)))
+    return matched, out
+
+
+def sorted_lookup(kv, grp):
+    """Probe a sorted unique build side: binary search, equality check,
+    one gather a column (they come in key order: no permutation).
+    Compared at the keys' own width where the probe key is as narrow."""
+    sorted_keys = grp[0][0]
+    if not _compare_narrow(kv, sorted_keys):
+        sorted_keys = sorted_keys.astype(jnp.int64)
+    kv = kv.astype(sorted_keys.dtype)
+    idx = jnp.clip(jnp.searchsorted(sorted_keys, kv), 0,
+                   sorted_keys.shape[0] - 1)
+    matched = sorted_keys[idx] == kv
+    return matched, [(bv[idx], True if bm is True else bm[idx])
+                     for bv, bm in grp[2:]]
 
 
 def match_ranges(sorted_keys, n_live, probe_keys, probe_ok):
@@ -91,4 +158,5 @@ def gather_expand(batch_cols, sel, probe_key_ok, build_cols, perm,
     return out_cols, valid_out, total
 
 
-__all__ = ["match_ranges", "expand_slots", "gather_expand"]
+__all__ = ["direct_lookup", "sorted_lookup", "match_ranges", "expand_slots",
+           "gather_expand"]

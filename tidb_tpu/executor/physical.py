@@ -512,35 +512,45 @@ class CopJoinTaskExec(PhysOp):
         """Chained broadcast joins: every level's build must be non-empty
         with unique keys (the planner only emits inner/left levels); any
         runtime anomaly falls back to the host plan whole."""
-        groups = _prep_build_groups(ctx, self.builds, self._keys_for)
-        if groups is None:
-            return self.fallback.execute(ctx)
-        return self._run(ctx, self.dag, groups)
+        bound = _prep_build_groups(ctx, self.builds, self._keys_for,
+                                   self.dag)
+        if bound is None:
+            return self._host_fallback(ctx)
+        dag, groups = bound
+        return self._run(ctx, dag, groups)
+
+    def _empty_build_result(self, ctx, bchunk) -> ResultChunk:
+        # empty build side: inner join produces nothing; left join keeps all
+        # probe rows with NULL build cols — both simplest via the fallback
+        return self._host_fallback(ctx)
+
+    def _host_fallback(self, ctx: ExecContext) -> ResultChunk:
+        sched = ctx.client._scheduler()
+        if sched is not None:
+            sched.join_host_fallbacks += 1
+        return self.fallback.execute(ctx)
 
     def _execute_single(self, ctx: ExecContext) -> ResultChunk:
-        import jax.numpy as jnp
-        bchunk = self.build_exec.execute(ctx)
-        kcol = bchunk.columns[self.build_key_index]
-        keys, ok = self._build_keys(kcol)
-        rows_idx = np.nonzero(ok)[0]           # NULL keys never join
-        keys = keys[rows_idx]
-        dag = self.dag
         semi = self.join_kind in ("semi", "anti")
-        if self.null_aware and not kcol.validity.all():
+        built = _prepared_build(ctx, self.build_exec, self.build_key_index,
+                                self._keys_for, self.build_key_dict,
+                                self.probe_key_dtype, want_cols=not semi)
+        side = built.side
+        dag = self.dag
+        if self.null_aware and built.null_key:
             # NOT IN with a NULL build key: NO probe row qualifies.  Keep
             # the fused program shape (incl. any aggregation over zero
             # joined rows): the join node becomes a constant-false filter.
             return self._run(ctx, D.drop_lookup(dag, keep=False), ())
-        if len(keys) == 0:
+        if side is None:
             if not semi:
-                return self._empty_build_result(ctx, bchunk)
+                return self._empty_build_result(ctx, None)
             # empty build side: semi matches nothing; anti keeps every
             # probe row (NOT IN of an empty set is TRUE even for NULL
             # probe keys, so no null-aware filtering either)
             return self._run(ctx, D.drop_lookup(
                 dag, keep=(self.join_kind == "anti")), ())
-        n_uniq = len(np.unique(keys))
-        if not semi and n_uniq != len(keys):
+        if not semi and not side.unique:
             # duplicate build keys: switch to the expanding multi-match
             # strategy on device (reference: NDV-driven join shape choice).
             # Initial capacity: per-device probe rows x average duplication,
@@ -548,29 +558,19 @@ class CopJoinTaskExec(PhysOp):
             snap0 = self.table.snapshot()
             n_dev = len(ctx.client.mesh.devices.reshape(-1))
             per_dev = -(-max(snap0.num_rows, 1) // n_dev)
-            avg_dup = len(keys) / max(n_uniq, 1)
             from ..store.columnar import _pow2_at_least
-            cap = _pow2_at_least(max(int(per_dev * avg_dup), 1024))
+            cap = _pow2_at_least(max(int(per_dev * side.avg_dup), 1024))
             dag = D.to_multimatch(dag, cap)
-        order = np.argsort(keys, kind="stable")
-        sorted_keys = keys[order]
-        perm = np.arange(len(keys), dtype=np.int64)[order]
-        aux = [(jnp.asarray(sorted_keys), None),
-               (jnp.asarray(perm), None)]
-        if not semi:   # semi/anti never read build columns on device
-            for c in bchunk.columns:
-                data = c.data[rows_idx]
-                valid = c.validity[rows_idx]
-                aux.append((jnp.asarray(data),
-                            None if valid.all() else jnp.asarray(valid)))
-        chunk = self._run(ctx, dag, (tuple(aux),))   # one aux group
+        elif side.dense:
+            dag = D.rewrite_lookup(dag, dense=True, packing=side.packing)
+        chunk = self._run(ctx, dag, (side.aux,))   # one aux group
         # build-side output columns keep their own dictionaries
         if not isinstance(self.dag, D.Aggregation):
             for j, c in enumerate(chunk.columns):
                 if c.dtype.is_string and c.dictionary is None:
                     bj = j - self.n_probe
-                    if 0 <= bj < len(bchunk.columns):
-                        c.dictionary = bchunk.columns[bj].dictionary
+                    if 0 <= bj < len(built.dicts):
+                        c.dictionary = built.dicts[bj]
         return chunk
 
     def _run(self, ctx, dag, aux) -> ResultChunk:
@@ -622,11 +622,6 @@ class CopJoinTaskExec(PhysOp):
                 ok = ok & (r == 0)     # non-representable: can't match
                 keys = q
         return keys, ok
-
-    def _empty_build_result(self, ctx, bchunk) -> ResultChunk:
-        # empty build side: inner join produces nothing; left join keeps all
-        # probe rows with NULL build cols — both simplest via the fallback
-        return self.fallback.execute(ctx)
 
 
 @dataclass
@@ -2161,32 +2156,99 @@ class HostApplyExec(PhysOp):
         return Column.from_values(out_t, out_vals)
 
 
-def _prep_build_groups(ctx, builds, keys_for):
-    """Materialize broadcast-join build sides into device aux groups
-    (sorted keys + permutation + columns).  None = runtime anomaly
-    (empty build / duplicate keys): the caller's host fallback runs —
-    shared by CopJoinTaskExec chains and window-over-join fragments."""
-    import jax.numpy as jnp
+_JOIN_BUILDS_KEPT = 8       # prepared build sides kept per snapshot
+
+
+@dataclass
+class _PreparedBuild:
+    """A broadcast join's build side, ready for the device."""
+    side: Any                   # copr.joinbuild.BuildSide | None: no live key
+    null_key: bool              # a build key is NULL (NOT IN reads it)
+    dicts: list                 # the build columns' dictionaries
+    key_dict: Any = None        # pins the probe dictionary the key names
+
+
+def _resident_snapshot(b_exec):
+    """The snapshot a build side is a pure function of, or None: a rows
+    CopTask over a whole resident table (no stale read, no partition
+    pruning) reads nothing but its DAG's constants and that snapshot."""
+    if type(b_exec) is not CopTaskExec \
+            or isinstance(b_exec.dag, D.Aggregation) \
+            or b_exec.as_of_ts is not None \
+            or getattr(b_exec.table, "partition", None) is not None \
+            or getattr(b_exec.table, "is_memtable", False):
+        return None
+    return b_exec.table.snapshot()
+
+
+def _prepared_build(ctx, b_exec, key_index, keys_for, key_dict,
+                    probe_key_dtype, want_cols: bool) -> _PreparedBuild:
+    """Run a build side's plan, drop NULL keys and hand the rest to
+    copr/joinbuild.prepare_build: fetch + dedup + sort/scatter + upload, all
+    inside ``cop.join_build``.  Where the build side is an unfiltered or
+    constant-filtered resident table the result stays with the table's
+    snapshot, and the span of a repeat is the lookup."""
+    from ..copr.joinbuild import prepare_build
+    from ..obs.trace import span
+    with span("cop.join_build"):
+        snap = _resident_snapshot(b_exec)
+        key = None
+        if snap is not None:
+            key = (b_exec.dag, key_index, probe_key_dtype, want_cols)
+            hit = snap._join_builds.get(key)
+            if hit is not None and hit.key_dict is key_dict:
+                snap._join_builds[key] = snap._join_builds.pop(key)  # LRU
+                _annotate_build(hit, cached=True)
+                return hit
+        bchunk = b_exec.execute(ctx)
+        kcol = bchunk.columns[key_index]
+        keys, ok = keys_for(kcol, key_dict, probe_key_dtype)
+        rows_idx = np.nonzero(ok)[0]           # NULL keys never join
+        side = None
+        if len(rows_idx):
+            cols = [(c.data[rows_idx], c.validity[rows_idx])
+                    for c in bchunk.columns] if want_cols else []
+            side = prepare_build(keys[rows_idx], cols, dense_ok=want_cols,
+                                 key_col=key_index)
+        built = _PreparedBuild(side, not kcol.validity.all(),
+                               [c.dictionary for c in bchunk.columns],
+                               key_dict)
+        if key is not None:
+            kept = snap._join_builds
+            kept[key] = built
+            while len(kept) > _JOIN_BUILDS_KEPT:
+                kept.pop(next(iter(kept)))
+        _annotate_build(built, cached=False)
+        return built
+
+
+def _annotate_build(built: _PreparedBuild, cached: bool) -> None:
+    from ..obs.trace import annotate
+    side = built.side
+    annotate(rows=side.rows if side else 0,
+             unique=bool(side and side.unique),
+             dense=bool(side and side.dense), cached=cached)
+
+
+def _prep_build_groups(ctx, builds, keys_for, dag):
+    """Materialize the build sides of a CHAIN of broadcast joins into
+    device aux groups and switch each level of ``dag`` to the form its
+    build side takes.  None = runtime anomaly (empty build / duplicate
+    keys): the caller's host fallback runs — shared by CopJoinTaskExec
+    chains and window-over-join fragments."""
     groups = []
-    for b in builds:
-        bchunk = b["exec"].execute(ctx)
-        kcol = bchunk.columns[b["key_index"]]
-        keys, ok = keys_for(kcol, b["key_dict"], b["probe_key_dtype"])
-        rows_idx = np.nonzero(ok)[0]
-        keys = keys[rows_idx]
-        if len(keys) == 0 or len(np.unique(keys)) != len(keys):
+    for slot, b in enumerate(builds):
+        side = _prepared_build(ctx, b["exec"], b["key_index"], keys_for,
+                               b["key_dict"], b["probe_key_dtype"],
+                               want_cols=True).side
+        if side is None or not side.unique:
             return None
-        order = np.argsort(keys, kind="stable")
-        grp = [(jnp.asarray(keys[order]), None),
-               (jnp.asarray(np.arange(len(keys),
-                                      dtype=np.int64)[order]), None)]
-        for c in bchunk.columns:
-            data = c.data[rows_idx]
-            valid = c.validity[rows_idx]
-            grp.append((jnp.asarray(data),
-                        None if valid.all() else jnp.asarray(valid)))
-        groups.append(tuple(grp))
-    return tuple(groups)
+        if side.dense:
+            dag = D.rewrite_lookup(
+                dag, pred=lambda j, s=slot: j.aux_slot == s,
+                dense=True, packing=side.packing)
+        groups.append(side.aux)
+    return dag, tuple(groups)
 
 
 @dataclass
@@ -2219,17 +2281,20 @@ class CopWindowExec(PhysOp):
         return f"CopWindow[{funcs}] table={self.table.name}{over} -> TPU"
 
     def execute(self, ctx: ExecContext) -> ResultChunk:
-        aux = ()
+        aux, spec = (), self.spec
         if self.builds:
-            aux = _prep_build_groups(
+            bound = _prep_build_groups(
                 ctx, self.builds,
                 lambda kcol, kd, pt: CopJoinTaskExec._keys_for(
-                    None, kcol, kd, pt))
-            if aux is None:
+                    None, kcol, kd, pt), spec.child)
+            if bound is None:
                 return self.fallback.execute(ctx)
+            import dataclasses
+            spec = dataclasses.replace(spec, child=bound[0])
+            aux = bound[1]
         # dictionaries attach inside the client's _assemble_rows
         cols = ctx.client.execute_window(
-            self.spec, self.table.snapshot(), tuple(self.out_dtypes),
+            spec, self.table.snapshot(), tuple(self.out_dtypes),
             self.out_dicts, aux_cols=aux)
         return ResultChunk(list(self.out_names), cols)
 

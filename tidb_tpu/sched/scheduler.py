@@ -233,6 +233,14 @@ class DeviceScheduler:
         # (copr/exec.topn_head; the traced program's `topn_blocks` > 1)
         self.topn_launches = 0
         self.topn_pruned_launches = 0
+        # broadcast lookup joins: launches of a program that holds one;
+        # launches of the repartition (all_to_all) join; CopJoinTaskExec
+        # runs that took their host fallback; capacity regrows of the
+        # expanding join (the last three are the client's and executor's)
+        self.join_launches = 0
+        self.join_shuffle_launches = 0
+        self.join_host_fallbacks = 0
+        self.join_regrows = 0
         self.fused_tasks = 0              # tasks served by a fused launch
         # group launches that raised and were served apart instead: the
         # results are the same, so only these counters (and one log line
@@ -1192,6 +1200,21 @@ class DeviceScheduler:
             if blocks > 1:
                 self.topn_pruned_launches += 1
 
+    def _note_join(self, task) -> dict:
+        """Count one launch of a join-carrying program; returns what its
+        ``sched.launch`` span says of the join (empty: no join)."""
+        from ..copr import dag as D
+        joins = D.lookup_joins(task.dag)
+        if not joins:
+            return {}
+        self.join_launches += 1
+        from ..copr.joinbuild import build_rows
+        return {"join": "unique" if all(j.unique for j in joins)
+                else "multimatch",
+                "probe_rows": task.est_rows,
+                "build_rows": sum(build_rows(j, task.aux[j.aux_slot])
+                                  for j in joins)}
+
     @staticmethod
     def _trace_mark(t, name: str, **attrs) -> None:
         """Zero-duration marker span on one task's trace (oom / bisect
@@ -1202,7 +1225,8 @@ class DeviceScheduler:
 
     def _trace_launch(self, tasks: list, start_ns: int, end_ns: int,
                       mode: str, fused: int = 0,
-                      program: str = "", topn_blocks: int = 0) -> None:
+                      program: str = "", topn_blocks: int = 0,
+                      join: Optional[dict] = None) -> None:
         """Record one physical launch's scheduler-side span tree +
         latency histograms, on the DRAIN thread BEFORE the tasks
         finish — a waiter rendering its trace right after wait()
@@ -1213,7 +1237,8 @@ class DeviceScheduler:
         ``sched.launch`` span (resolve + DISPATCH: the call returns
         once the program is enqueued, before the device has run it)
         carrying the program's name, topn_blocks (a TopN-rooted
-        program's block count: 1 = full sort), predicted_ms (calibrated
+        program's block count: 1 = full sort), join / probe_rows /
+        build_rows (a join-carrying program's), predicted_ms (calibrated
         LaunchCost via copmeter's predict_ms) next to dispatch_ms (the
         span's own wall time), the shardflow per-link transfer breakdown,
         and — as children — the copforge ``sched.compile`` span
@@ -1242,6 +1267,8 @@ class DeviceScheduler:
                 attrs["program"] = program
             if topn_blocks:
                 attrs["topn_blocks"] = topn_blocks
+            if join:
+                attrs.update(join)
             if t.cost is not None:
                 attrs["predicted_ms"] = round(predict_ms(t.cost), 3)
                 bd = t.cost.transfer_breakdown or (0, 0, 0)
@@ -1694,7 +1721,8 @@ class DeviceScheduler:
                     t.coalesced = len(batch)
             self._mem_note(s, lead.mesh)
             self._trace_launch(s, t_s0, time.perf_counter_ns(), mode,
-                               program=prog.name, topn_blocks=blocks)
+                               program=prog.name, topn_blocks=blocks,
+                               join=self._note_join(s[0]))
             for t in s:
                 t.finish((prog, out))
             self.launches += 1
@@ -1920,6 +1948,10 @@ class DeviceScheduler:
                 "fused_launches": self.fused_launches,
                 "topn_launches": self.topn_launches,
                 "topn_pruned_launches": self.topn_pruned_launches,
+                "join_launches": self.join_launches,
+                "join_shuffle_launches": self.join_shuffle_launches,
+                "join_host_fallbacks": self.join_host_fallbacks,
+                "join_regrows": self.join_regrows,
                 "fused_tasks": self.fused_tasks,
                 "fused_refused": self.fused_refused,
                 "batched_refused": self.batched_refused,

@@ -982,9 +982,21 @@ class Catalog:
             self.sequences[key]._purge_value_key()
             del self.sequences[key]
 
+    @staticmethod
+    def _stored_name(d: dict, name: str):
+        """The name a table of database `d` is stored under: `name`
+        itself, else the one that differs from it only in case.  Table
+        names keep the case they were created with and compare without
+        it, as TiDB's do (lower_case_table_names = 2): TPC-H's schema
+        writes LINEITEM and PART, its queries `lineitem` and `part`."""
+        if name in d:
+            return name
+        low = name.lower()
+        return next((k for k in d if k.lower() == low), None)
+
     def create_table(self, db: str, tbl: TableInfo, if_not_exists=False):
         d = self._db(db)
-        if tbl.name in d:
+        if self._stored_name(d, tbl.name) is not None:
             if if_not_exists:
                 return
             raise CatalogError(f"table {tbl.name!r} exists")
@@ -992,11 +1004,12 @@ class Catalog:
 
     def drop_table(self, db: str, name: str, if_exists=False):
         d = self._db(db)
-        if name not in d:
+        stored = self._stored_name(d, name)
+        if stored is None:
             if if_exists:
                 return
             raise CatalogError(f"unknown table {name!r}")
-        del d[name]
+        del d[stored]
 
     def get_table(self, db: str, name: str) -> TableInfo:
         from ..infoschema import get_memtable, is_system_db
@@ -1012,9 +1025,10 @@ class Catalog:
             if t is not None:
                 return t
         d = self._db(db)
-        if name not in d:
+        stored = self._stored_name(d, name)
+        if stored is None:
             raise CatalogError(f"table {db}.{name} doesn't exist")
-        return d[name]
+        return d[stored]
 
     def _db(self, db: str) -> dict:
         from ..infoschema import is_system_db

@@ -676,6 +676,9 @@ class CopClient:
         need = int(np.max(np.asarray(self._fetch(extras["join_total"]))))
         node = D.find_expand_join(dag)
         if node is not None and need > node.out_capacity:
+            sched = self._scheduler()
+            if sched is not None:
+                sched.join_regrows += 1
             return D.rewrite_expand_capacity(dag, _pow2_at_least(need))
         return None
 
@@ -765,8 +768,11 @@ class CopClient:
                 and not agg.state_capacity:
             spec = dataclasses.replace(spec, top=self._with_capacity(
                 agg, DEFAULT_GROUP_CAPACITY))
+        sched = self._scheduler()
         for _ in range(12):
             prog = get_shuffle_program(spec, self.mesh, caps)
+            if sched is not None:
+                sched.join_shuffle_launches += 1
             out, extras = self._launch_opaque(
                 lambda p=prog: p(lcols, lcounts, rcols, rcounts, aux_cols),
                 est_rows=lsnap.num_rows + rsnap.num_rows,

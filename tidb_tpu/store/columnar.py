@@ -50,6 +50,10 @@ class ColumnarSnapshot:
     placement: Any = None
 
     _device_cache: dict = field(default_factory=dict, repr=False)
+    # prepared broadcast-join build sides read from THIS snapshot
+    # (executor/physical._prepared_build): a new epoch is a new object
+    _join_builds: dict = field(default_factory=dict, repr=False)
+    _unique_keys: dict = field(default_factory=dict, repr=False)
 
     @property
     def num_rows(self) -> int:
@@ -59,6 +63,23 @@ class ColumnarSnapshot:
     def dictionaries(self) -> dict[int, StringDict]:
         return {i: c.dictionary for i, c in enumerate(self.columns)
                 if c.dictionary is not None}
+
+    def key_is_unique(self, offset: int) -> bool:
+        """No value of column `offset` occurs twice among its non-NULL
+        rows (what lets a lookup join build this table by that key).
+        Worked out once a snapshot: a strictly ascending column, as a
+        generated or bulk-loaded primary key is, costs one pass."""
+        known = self._unique_keys.get(offset)
+        if known is None:
+            c = self.columns[offset]
+            data = c.data if c.validity.all() else c.data[c.validity]
+            if data.dtype == object:
+                known = False
+            else:
+                known = bool((data[1:] > data[:-1]).all()) \
+                    or len(np.unique(data)) == len(data)
+            self._unique_keys[offset] = known
+        return known
 
     # ---------------- shard plan ---------------- #
 

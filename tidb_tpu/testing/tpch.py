@@ -201,7 +201,7 @@ TPCH_PLAN_QUERIES = [
     # row-returning scan chain with projection arithmetic
     """select l_orderkey, l_extendedprice * (1 - l_discount)
        from lineitem where l_quantity < 5""",
-    # broadcast lookup join, aggregated (Q19 shape without OR-chains)
+    # broadcast lookup join with a group-by (lineitem probes, part builds)
     """select p_brand, sum(l_extendedprice) from lineitem, part
        where l_partkey = p_partkey and l_quantity < 10
        group by p_brand""",
