@@ -38,6 +38,18 @@ K = dt.TypeKind
 # compare (VPU-friendly, fuses into the scan); above, scatter-add.
 DENSE_BROADCAST_MAX_GROUPS = 64
 
+# The dense SUM/COUNT reduction (`_dense_limb_states`): integer lanes are
+# split into int32 limbs of LIMB_BITS bits and every accumulator sums at
+# most ACC_RUN of them, so no sum leaves 32 bits.  22 is the least width
+# at which an int64 takes three limbs; rows are viewed as whole
+# (sublane, lane) tiles of LANES lanes, ACC_RUN tiles to an accumulator
+# tile.  One variadic reduce takes at most REDUCE_OPERANDS operands
+# (more groups x lanes than that take several passes).
+LIMB_BITS = 22
+ACC_RUN = 1 << (31 - LIMB_BITS)
+LANES = 128
+REDUCE_OPERANDS = 128
+
 
 @dataclass
 class DeviceBatch:
@@ -53,12 +65,16 @@ class DeviceBatch:
     tile).  The scan sets it, operators that keep the slot axis keep it,
     those that build a new one (Expand, an expanding join, TopN) drop it
     to 1.  `topn_blocks` is set by a TopN: the blocks it viewed its
-    input as (1 = it sorted every row).  Both are static."""
+    input as (1 = it sorted every row).  `agg_limbs` is set by a DENSE
+    aggregation over this batch: the int32 lanes a row its SUM and COUNT
+    states were reduced as (0 = not in that form).  All three are
+    static."""
     cols: list  # list[(value, valid)]
     sel: Any    # bool array | True
     extras: dict = None  # type: ignore[assignment]
     stacked: int = 1
     topn_blocks: int = 0
+    agg_limbs: int = 0
 
     def __post_init__(self):
         if self.extras is None:
@@ -99,18 +115,16 @@ def _reduce(vals, mask, gids, num_groups, how: str):
 
     how: 'sum' | 'min' | 'max'.  gids None => scalar reduction.
     Grouped: dense (G,) output.  Strategy is PER-PLATFORM: on TPU a
-    broadcast one-hot compare for small G fuses into the streaming scan
-    pass (scatter lowering on TPU can serialize); on CPU the (G, N)
-    broadcast costs G x the scan traffic per aggregate and XLA's
-    scatter-add is cheap — measured 14x on TPC-H Q1 — so CPU always
-    scatters."""
+    broadcast one-hot compare for small G (scatter lowering on TPU can
+    serialize); on CPU the (G, N) broadcast costs G x the scan traffic
+    per aggregate and XLA's scatter-add is cheap, so CPU always
+    scatters.  The integer SUM and COUNT states of a DENSE aggregation
+    on a TPU do not come here: `_dense_limb_states`."""
     neutral = {"sum": 0, "min": _max_of(vals.dtype), "max": _min_of(vals.dtype)}[how]
     v = jnp.where(mask, vals, jnp.asarray(neutral, vals.dtype))
     if gids is None:
         return getattr(jnp, how)(v)
-    broadcast_max = (0 if trace_platform() == "cpu"
-                     else DENSE_BROADCAST_MAX_GROUPS)
-    if num_groups <= broadcast_max:
+    if _onehot_form(num_groups):
         onehot = gids[None, :] == jnp.arange(num_groups, dtype=gids.dtype)[:, None]
         vv = jnp.where(onehot, v[None, :], jnp.asarray(neutral, vals.dtype))
         return getattr(jnp, how)(vv, axis=1)
@@ -118,6 +132,13 @@ def _reduce(vals, mask, gids, num_groups, how: str):
     if how == "sum":
         return out.at[gids].add(v, mode="drop")
     return getattr(out.at[gids], how)(v, mode="drop")
+
+
+def _onehot_form(num_groups: int) -> bool:
+    """A grouped reduction as a one-hot compare (small G, not the CPU)
+    or as a scatter?  `_reduce`'s docstring says why."""
+    return trace_platform() != "cpu" \
+        and num_groups <= DENSE_BROADCAST_MAX_GROUPS
 
 
 def _max_of(dtype):
@@ -133,8 +154,10 @@ def _min_of(dtype):
 
 
 def _one_agg_state(a: D.AggDesc, av, am, sel, gids, num_groups, n,
-                   narrow: bool = False) -> dict:
+                   narrow: bool = False, cnt=None) -> dict:
     """Partial state for one AggDesc over (possibly grouped) rows.
+    `cnt`: the count of the argument's non-NULL live rows a group, where
+    the caller has it already.
 
     Layout (all named arrays so psum/pmin/pmax merges are mechanical —
     see parallel/collectives.py MERGE_SPECS):
@@ -147,10 +170,10 @@ def _one_agg_state(a: D.AggDesc, av, am, sel, gids, num_groups, n,
     """
     av = _ensure_array(av, n)
     mask = sel if am is True else (sel & am)
+    if cnt is None:
+        cnt = _reduce(mask.astype(jnp.int64), mask, gids, num_groups, "sum")
     if a.func == D.AggFunc.COUNT:
-        return {"count": _reduce(mask.astype(jnp.int64), mask, gids,
-                                 num_groups, "sum")}
-    cnt = _reduce(mask.astype(jnp.int64), mask, gids, num_groups, "sum")
+        return {"count": cnt}
     if a.func == D.AggFunc.SUM:
         kind = a.arg.dtype.kind
         if kind in (K.FLOAT64, K.FLOAT32):
@@ -169,10 +192,7 @@ def _one_agg_state(a: D.AggDesc, av, am, sel, gids, num_groups, n,
         # lo < 2^32, so with n < 2^31 rows per batch neither limb sum can
         # wrap int64; recombination is exact.  n is a static shape, so
         # this fence is free.
-        if n >= 2 ** 31:
-            raise OverflowError(
-                f"shard batch of {n} rows exceeds the 2^31 limb-exact "
-                "SUM bound; use more/smaller shards")
+        _limb_row_fence(n)
         with jax.named_scope("limb_split"):
             v = av.astype(jnp.int64)
             hi = _reduce(v >> 32, mask, gids, num_groups, "sum")
@@ -187,8 +207,15 @@ def _one_agg_state(a: D.AggDesc, av, am, sel, gids, num_groups, n,
     raise NotImplementedError(a.func)
 
 
+def _limb_row_fence(n: int) -> None:
+    if n >= 2 ** 31:
+        raise OverflowError(
+            f"shard batch of {n} rows exceeds the 2^31 limb-exact "
+            "SUM bound; use more/smaller shards")
+
+
 def agg_states(agg: D.Aggregation, scan_cols, row_count, ev: Evaluator,
-               aux) -> tuple:
+               aux, stacked: int = 1) -> tuple:
     """Execute agg.child and build partial states.
 
     An Expand child (WITH ROLLUP) aggregates LEVEL BY LEVEL over the
@@ -197,7 +224,9 @@ def agg_states(agg: D.Aggregation, scan_cols, row_count, ev: Evaluator,
     n-row child batch, builds DENSE partial states, and merges them with
     the shard-merge combiners — identical math, 1/levels the peak HBM
     (the levels×n materialization OOM-crashed the v5e worker at SF=10).
-    Returns (states, child_batch-for-extras).
+    Returns (states, child_batch-for-extras); the batch carries
+    `agg_limbs`.  `stacked`: the runs the flat scan columns consist of
+    (DeviceBatch.stacked).
 
     TPU-only: on CPU the materialized expand fuses into one pass and
     measures slightly faster; on TPU the replication is what OOMs."""
@@ -206,11 +235,12 @@ def agg_states(agg: D.Aggregation, scan_cols, row_count, ev: Evaluator,
             and agg.strategy == D.GroupStrategy.DENSE \
             and trace_platform() == "tpu":
         with jax.named_scope("scan_filter"):
-            base = _exec_node(ch.child, scan_cols, row_count, ev, aux)
+            base = _exec_node(ch.child, scan_cols, row_count, ev, aux,
+                              stacked)
         with jax.named_scope("aggregate"):
             return _expand_level_states(agg, ch, base, ev), base
     with jax.named_scope("scan_filter"):
-        batch = _exec_node(ch, scan_cols, row_count, ev, aux)
+        batch = _exec_node(ch, scan_cols, row_count, ev, aux, stacked)
     with jax.named_scope("aggregate"):
         return _agg_partial_states(agg, batch, ev, {}), batch
 
@@ -242,8 +272,9 @@ def _expand_level_states(agg: D.Aggregation, exp: D.Expand,
             else:                      # rolled: NULL for every row
                 cols.append((v, jnp.zeros(n, bool)))
         cols.append((jnp.full(n, lvl, jnp.int64), True))
-        st = _agg_partial_states(
-            agg, DeviceBatch(cols, base.sel, base.extras), ev, {})
+        level = DeviceBatch(cols, base.sel, base.extras, base.stacked)
+        st = _agg_partial_states(agg, level, ev, {})
+        base.agg_limbs = level.agg_limbs
         if not merged:
             merged = st
         else:
@@ -292,6 +323,8 @@ def _agg_partial_states(agg: D.Aggregation, batch: DeviceBatch, ev: Evaluator,
     if agg.strategy == D.GroupStrategy.DENSE:
         gids = _dense_group_ids(agg, batch, ev, memo)
         num_groups = agg.num_groups
+        if dense_limb_form(agg):
+            return _dense_limb_states(agg, batch, ev, memo, gids, sel, n)
 
     states: dict[str, Any] = {}
     states["__rows__"] = _reduce(sel.astype(jnp.int64), sel, gids, num_groups, "sum")
@@ -302,6 +335,180 @@ def _agg_partial_states(agg: D.Aggregation, batch: DeviceBatch, ev: Evaluator,
         av, am = ev.eval(a.arg, batch.cols, memo)
         states[f"a{i}"] = _one_agg_state(a, av, am, sel, gids, num_groups, n,
                                          narrow=(i in agg.narrow_sums))
+    return states
+
+
+def dense_limb_form(agg: D.Aggregation) -> bool:
+    """Does this aggregation's SUM and COUNT reduction take the limb form
+    (`_dense_limb_states`) on the platform being traced for?  Where the
+    dense one-hot form ends and scatter begins is `_reduce`'s rule."""
+    return agg.strategy == D.GroupStrategy.DENSE \
+        and _onehot_form(agg.num_groups)
+
+
+def dense_view(n: int, stacked: int = 1) -> tuple:
+    """((runs, blocks, tiles, LANES), pad): the view of `n` flat rows the
+    dense SUM/COUNT reduction reduces over its `tiles` axis, at most
+    ACC_RUN of them.  Flat rows made of `stacked` equal runs of whole
+    tiles are viewed run by run: on the TPU, where stacked (S, C) shards
+    lie tile by tile across the S runs, that is the arrays' own byte
+    order, and the reduce adds tile upon tile, element by element, with
+    no cross-lane step in the pass.  Any other batch is one run, padded
+    with `pad` dead rows."""
+    stacked = max(stacked, 1)
+    tiles = n // stacked // LANES
+    acc = min(ACC_RUN, tiles)
+    if tiles and n == stacked * tiles * LANES and tiles % acc == 0:
+        return (stacked, tiles // acc, acc, LANES), 0
+    tiles = max(-(-n // LANES), 1)
+    acc = min(ACC_RUN, tiles)
+    blocks = -(-tiles // acc)
+    return (1, blocks, acc, LANES), blocks * acc * LANES - n
+
+
+def _limbs(v) -> list:
+    """An integer (or boolean) array as int32 limbs of LIMB_BITS bits,
+    as few as its dtype needs: the low ones masked (unsigned), the top
+    one shifted arithmetically (signed), so that sum(limb_i <<
+    (LIMB_BITS * i)) == v for every value of the dtype.  (uint64 reads
+    as the int64 of the same bits, as the (hi, lo) split always has.)"""
+    if v.dtype == bool or v.dtype.itemsize < 4:
+        return [v.astype(jnp.int32)]    # valueflow: ok - widening only
+    if jnp.issubdtype(v.dtype, jnp.unsignedinteger):
+        v = v.astype(jnp.int64)
+    k = -(-8 * v.dtype.itemsize // LIMB_BITS)
+    mask = (1 << LIMB_BITS) - 1
+    return [((v >> (LIMB_BITS * i)) & mask).astype(jnp.int32)  # valueflow: ok - masked to LIMB_BITS bits
+            for i in range(k - 1)] \
+        + [(v >> (LIMB_BITS * (k - 1))).astype(jnp.int32)]  # valueflow: ok - at most 32 - LIMB_BITS bits left
+
+
+def _add_tuples(a, b):
+    return tuple(x + y for x, y in zip(a, b))
+
+
+def _words(sums: Sequence) -> tuple:
+    """Limb sums s_i (int64 per group) as the (hi, lo) words of the SUM
+    state: hi * 2^32 + lo == sum(s_i << (LIMB_BITS * i)), both words as
+    far inside int64 as sum(v >> 32) and sum(v & 0xFFFFFFFF) are."""
+    hi = lo = jnp.zeros_like(sums[0])
+    for i, s in enumerate(sums):
+        shift = LIMB_BITS * i
+        if shift >= 32:
+            hi = hi + (s << (shift - 32))
+        else:
+            hi = hi + (s >> (32 - shift))
+            lo = lo + ((s & ((1 << (32 - shift)) - 1)) << shift)
+    return hi, lo
+
+
+def _dense_limb_states(agg: D.Aggregation, batch: DeviceBatch, ev: Evaluator,
+                       memo: dict, gids, sel, n: int) -> dict:
+    """The partial states of a DENSE aggregation (`_one_agg_state`'s
+    layouts, '__rows__' included) with every integer SUM and every COUNT
+    reduced in ONE pass over the rows.
+
+    Lanes are collected once: each distinct NULL mask (the selection
+    alone is one) is a COUNT lane, each distinct (argument, mask) is
+    split into int32 limb lanes (`_limbs`): `sum(x)` and `avg(x)` share
+    theirs.  The group one-hot is built once a group and mask, and all
+    lanes of all groups go through one variadic reduce over the tiles
+    axis of `dense_view` (the products and limbs have that one consumer
+    and are never written out), whose int32 partial sums a second,
+    small reduce adds up at int64.  The limb sums are put together
+    again on the device into {hi, lo, cnt} or, for a `narrow_sums` slot,
+    {sum, cnt}.  Float SUMs, MIN and MAX keep `_reduce` and take only
+    their `cnt` from here."""
+    G = agg.num_groups
+    shape, pad = dense_view(n, batch.stacked)
+
+    def view(a, fill=0):
+        if pad:
+            a = jnp.pad(a, (0, pad), constant_values=fill)
+        return a.reshape(shape)
+
+    masks: list = []    # (NULL mask | True, group ids (dead rows: G), COUNT lane)
+    lanes: list = []    # an int32 lane a row: (mask, limb; None: the mask's count)
+    sums: dict = {}     # (argument, COUNT lane) -> (first limb's lane, limbs)
+
+    def count_lane(am) -> int:
+        for seen, _g, lane in masks:
+            if seen is am:
+                return lane
+        live = sel if am is True else (sel & am)
+        masks.append((am, view(jnp.where(live, gids, G), G), len(lanes)))
+        lanes.append((len(masks) - 1, None))
+        return len(lanes) - 1
+
+    def sum_lanes(a, av, cnt: int) -> tuple:
+        try:
+            key = (a.arg, cnt)
+            hash(key)
+        except TypeError:       # an argument that holds an array constant
+            key = (len(lanes), cnt)
+        if key not in sums:
+            limbs = _limbs(_ensure_array(av, n))
+            sums[key] = (len(lanes), len(limbs))
+            lanes.extend((lanes[cnt][0], view(limb)) for limb in limbs)
+        return sums[key]
+
+    rows = count_lane(True)
+    plan = []           # an aggregate: (value, mask, COUNT lane, first, limbs)
+    for a in agg.aggs:
+        if a.func == D.AggFunc.COUNT and a.arg is None:
+            plan.append(None)
+            continue
+        av, am = ev.eval(a.arg, batch.cols, memo)
+        cnt = count_lane(am)
+        limb = a.func == D.AggFunc.SUM \
+            and a.arg.dtype.kind not in (K.FLOAT64, K.FLOAT32)
+        plan.append((av, am, cnt) + (sum_lanes(a, av, cnt) if limb
+                                     else (0, 0)))
+
+    width = len(lanes)
+    step = max(1, REDUCE_OPERANDS // width)
+    parts = []          # a group: its lanes' int32 partial sums
+    for g0 in range(0, G, step):
+        ops = []
+        for grp in range(g0, min(G, g0 + step)):
+            hits = [g == grp for _am, g, _lane in masks]
+            ops += [hits[m].astype(jnp.int32) if limb is None  # valueflow: ok - bool lane, [0, 1]
+                    else jnp.where(hits[m], limb, 0) for m, limb in lanes]
+        part = lax.reduce(tuple(ops), tuple(jnp.zeros((), jnp.int32)
+                                            for _ in ops),
+                          _add_tuples, (2,))
+        parts += [part[at:at + width] for at in range(0, len(part), width)]
+    # the second level: a lane's partial sums of every group, stacked,
+    # summed at int64 into the (G,) array the states are made of.  (With
+    # the sums stacked as scalars into a (G, lanes) table and a state its
+    # column, one element came out wrong behind the psum of a one-device
+    # mesh on a v5e: PERF.md section 7.)
+    wide = tuple(jnp.stack([p[c] for p in parts]).astype(jnp.int64)
+                 for c in range(width))
+    table = lax.reduce(wide, tuple(jnp.zeros((), jnp.int64) for _ in wide),
+                       _add_tuples, (1, 2, 3))
+
+    batch.agg_limbs = width
+    states: dict[str, Any] = {"__rows__": table[rows]}
+    for i, (a, lane) in enumerate(zip(agg.aggs, plan)):
+        if lane is None:
+            states[f"a{i}"] = {"count": table[rows]}
+            continue
+        av, am, cnt, first, k = lane
+        if not k:
+            states[f"a{i}"] = _one_agg_state(a, av, am, sel, gids, G, n,
+                                             cnt=table[cnt])
+        elif i in agg.narrow_sums:
+            # the total fits one word (valueflow), so adding the shifted
+            # limb sums modulo 2^64 gives it exactly
+            total = table[first]
+            for j in range(1, k):
+                total = total + (table[first + j] << (LIMB_BITS * j))
+            states[f"a{i}"] = {"sum": total, "cnt": table[cnt]}
+        else:
+            _limb_row_fence(n)
+            hi, lo = _words(table[first:first + k])
+            states[f"a{i}"] = {"hi": hi, "lo": lo, "cnt": table[cnt]}
     return states
 
 

@@ -30,7 +30,7 @@ from ..compilecache import cached_call
 from ..copr import dag as D
 from ..copr.aggregate import _MERGE
 from ..copr.exec import (DeviceBatch, _agg_partial_states, _exec_node,
-                         agg_states, compact)
+                         agg_states, compact, dense_limb_form, dense_view)
 from ..expr.compile import Evaluator
 from .mesh import SHARD_AXIS, shard_map
 
@@ -82,14 +82,36 @@ def _collective_merge(states: dict, axis: str, n_dev: int) -> dict:
     return out
 
 
-def _flatten_block(cols, counts):
-    """(S_local, C) blocks -> one (S_local*C,) batch + live-row mask."""
+def _flatten_block(cols, counts, view=None):
+    """(S_local, C) blocks -> one (S_local*C,) batch + live-row mask.
+
+    `view`: the (S_local, blocks, tiles, lanes) shape the program's
+    reduction will view the flat rows as again (copr/exec.dense_view).
+    Every column is then pinned to that view where it enters the
+    program, by a select on the live-row mask built in that shape: XLA
+    moves a reshape across element-wise operations but not across that
+    select, so the whole row pipeline is computed in the view and fuses
+    into the reduction.  (Reshaped only where the reduction starts, the
+    first 32-bit value of every column is written to HBM and read back:
+    10.4 ms against 3.3 for TPC-H Q1 at SF10, PERF.md section 6.)"""
     s, c = cols[0][0].shape
-    base_sel = (jnp.arange(c, dtype=jnp.int64)[None, :]
-                < counts[:, None]).reshape(-1)
-    flat = [(v.reshape(-1), None if m is None else m.reshape(-1))
+    if view is None:
+        base_sel = (jnp.arange(c, dtype=jnp.int64)[None, :]
+                    < counts[:, None]).reshape(-1)
+        flat = [(v.reshape(-1), None if m is None else m.reshape(-1))
+                for v, m in cols]
+        return flat, base_sel
+    _s, blocks, tiles, lanes = view
+    idx = jnp.int32 if c < 2 ** 31 else jnp.int64
+    row = ((jnp.arange(blocks, dtype=idx)[:, None, None] * tiles
+            + jnp.arange(tiles, dtype=idx)[None, :, None]) * lanes
+           + jnp.arange(lanes, dtype=idx)[None, None, :])
+    live = row[None] < counts.astype(idx)[:, None, None, None]
+    flat = [(jnp.where(live, v.reshape(view),
+                       jnp.zeros((), v.dtype)).reshape(-1),
+             None if m is None else (live & m.reshape(view)).reshape(-1))
             for v, m in cols]
-    return flat, base_sel
+    return flat, live.reshape(-1)
 
 
 class ShardedCopProgram:
@@ -114,8 +136,9 @@ class ShardedCopProgram:
             dag_root, "solo", donate, donate_argnums)
         self.agg = dag_root if isinstance(dag_root, D.Aggregation) else None
         self.kind = "agg" if self.agg is not None else "rows"
-        # per-device input shape -> DeviceBatch.topn_blocks of its trace
-        self._topn_blocks: dict = {}
+        # per-device input shape -> (DeviceBatch.topn_blocks,
+        # DeviceBatch.agg_limbs) of its trace
+        self._traced: dict = {}
         # MIN/MAX merge IN-PROGRAM via _psum_gather (psum-only all_gather +
         # reduce), so the whole merge stays on device behind one kind of
         # collective.  Only SORT/SEGMENT-strategy group
@@ -175,13 +198,26 @@ class ShardedCopProgram:
         from ..copr.exec import set_trace_platform
         set_trace_platform(self.mesh.devices.reshape(-1)[0].platform)
         cols = [(v, m) for v, m in cols]
-        flat, base_sel = _flatten_block(cols, counts)
+        # the flat columns are the device's S stacked shards, one run
+        # each (DeviceBatch.stacked)
+        stacked, cap = cols[0][0].shape
+        view = None
+        # a join's gather writes its columns out whatever the view, and
+        # XLA:TPU compiles a gather pinned to it 14x as long (90 s
+        # against 6 for chip_smoke's join with a group-by)
+        if self.agg is not None and dense_limb_form(self.agg) \
+                and not D.lookup_joins(self.agg):
+            view, pad = dense_view(stacked * cap, stacked)
+            if pad:
+                view = None
+        flat, base_sel = _flatten_block(cols, counts, view)
         flat = [(v, True if m is None else m) for v, m in flat]
         aux = tuple(tuple((v, True if m is None else m) for v, m in grp)
                     for grp in aux)
         ev = Evaluator(jnp)
         if self.agg is not None:
-            states, batch = agg_states(self.agg, flat, base_sel, ev, aux)
+            states, batch = agg_states(self.agg, flat, base_sel, ev, aux,
+                                       stacked)
             if self.host_merge:
                 # add a leading per-device axis; host reduces across it
                 out = jax.tree_util.tree_map(lambda a: a[None], states)
@@ -191,35 +227,46 @@ class ShardedCopProgram:
                         states, SHARD_AXIS,
                         len(self.mesh.devices.reshape(-1)))
         else:
-            # the flat columns are the device's S stacked shards, one
-            # run each (DeviceBatch.stacked)
-            batch = _exec_node(self.root, flat, base_sel, ev, aux,
-                               stacked=cols[0][0].shape[0])
-            self._topn_blocks[cols[0][0].shape] = batch.topn_blocks
+            batch = _exec_node(self.root, flat, base_sel, ev, aux, stacked)
             out_cols, n = compact(batch, self.row_capacity)
             # keep a leading per-device axis so out_specs can shard it
             out = ([(v[None], m[None]) for v, m in out_cols], n[None])
+        self._traced[(stacked, cap)] = (batch.topn_blocks, batch.agg_limbs)
         if self.has_extras:
             extras = {k: jnp.asarray(v)[None] for k, v in batch.extras.items()}
             return out, extras
         return out
 
+    def _traced_fact(self, stacked_cols: Sequence, counts, aux_cols) -> tuple:
+        """(topn_blocks, agg_limbs) of the batch this program's trace
+        for these inputs ended with (`_device_fn` keeps them by input
+        shape).  Where no trace ran in this process (copforge served the
+        executable from its disk store) the program is traced
+        abstractly, once."""
+        s, c = stacked_cols[0][0].shape[:2]
+        shape = (s // len(self.mesh.devices.reshape(-1)), c)
+        if shape not in self._traced:
+            jax.eval_shape(self._fn, tuple(stacked_cols), counts,
+                           tuple(aux_cols))
+        return self._traced[shape]
+
     def topn_blocks(self, stacked_cols: Sequence, counts,
                     aux_cols=()) -> int:
         """Blocks the TopN at this program's root viewed each device's
-        rows as when the program was traced for these inputs (`_exec_topn`
-        says, `_device_fn` keeps it by input shape): 0 = the root is no
-        TopN, 1 = it sorts every row, more = it prunes.  Where no trace
-        ran in this process (copforge served the executable from its
-        disk store) the program is traced abstractly, once."""
+        rows as (`_exec_topn` says): 0 = the root is no TopN, 1 = it
+        sorts every row, more = it prunes."""
         if not isinstance(self.root, D.TopN):
             return 0
-        s, c = stacked_cols[0][0].shape[:2]
-        shape = (s // len(self.mesh.devices.reshape(-1)), c)
-        if shape not in self._topn_blocks:
-            jax.eval_shape(self._fn, tuple(stacked_cols), counts,
-                           tuple(aux_cols))
-        return self._topn_blocks[shape]
+        return self._traced_fact(stacked_cols, counts, aux_cols)[0]
+
+    def agg_limbs(self, stacked_cols: Sequence, counts, aux_cols=()):
+        """int32 lanes a row the SUM and COUNT states of the DENSE
+        aggregation at this program's root are reduced as
+        (`_dense_limb_states` says; 0 = not in that form); None = the
+        root is no DENSE aggregation."""
+        if self.agg is None or self.agg.strategy != D.GroupStrategy.DENSE:
+            return None
+        return self._traced_fact(stacked_cols, counts, aux_cols)[1]
 
     def __call__(self, stacked_cols: Sequence, counts, aux_cols=()):
         if self._psum_limb_fence and stacked_cols:
@@ -312,6 +359,16 @@ class FusedCopProgram:
         # common-subexpression-eliminates the shared scan/flatten work
         return tuple(p._device_fn(cols, counts, aux)
                      for p in self.members)
+
+    def agg_limbs(self, stacked_cols: Sequence, counts):
+        """The members' `ShardedCopProgram.agg_limbs` as one launch's:
+        None = no member has a DENSE aggregation, 0 = one of those is
+        not in the limb form, else their lanes a row together."""
+        limbs = [n for n in (p.agg_limbs(stacked_cols, counts)
+                             for p in self.members) if n is not None]
+        if not limbs:
+            return None
+        return 0 if 0 in limbs else sum(limbs)
 
     def __call__(self, stacked_cols: Sequence, counts, aux_cols=()):
         if self._psum_limb_fence and stacked_cols:

@@ -233,6 +233,12 @@ class DeviceScheduler:
         # (copr/exec.topn_head; the traced program's `topn_blocks` > 1)
         self.topn_launches = 0
         self.topn_pruned_launches = 0
+        # launches of a program whose root is a DENSE aggregation; those
+        # whose SUM and COUNT states were all reduced as int32 limb lanes
+        # in one pass (copr/exec._dense_limb_states; the traced program's
+        # `agg_limbs` > 0)
+        self.dense_agg_launches = 0
+        self.dense_agg_limb_launches = 0
         # broadcast lookup joins: launches of a program that holds one;
         # launches of the repartition (all_to_all) join; CopJoinTaskExec
         # runs that took their host fallback; capacity regrows of the
@@ -1200,6 +1206,13 @@ class DeviceScheduler:
             if blocks > 1:
                 self.topn_pruned_launches += 1
 
+    def _note_dense_agg(self, limbs) -> None:
+        """Count one launch by its program's ``agg_limbs``."""
+        if limbs is not None:
+            self.dense_agg_launches += 1
+            if limbs:
+                self.dense_agg_limb_launches += 1
+
     def _note_join(self, task) -> dict:
         """Count one launch of a join-carrying program; returns what its
         ``sched.launch`` span says of the join (empty: no join)."""
@@ -1226,7 +1239,8 @@ class DeviceScheduler:
     def _trace_launch(self, tasks: list, start_ns: int, end_ns: int,
                       mode: str, fused: int = 0,
                       program: str = "", topn_blocks: int = 0,
-                      join: Optional[dict] = None) -> None:
+                      join: Optional[dict] = None,
+                      agg_limbs: Optional[int] = None) -> None:
         """Record one physical launch's scheduler-side span tree +
         latency histograms, on the DRAIN thread BEFORE the tasks
         finish — a waiter rendering its trace right after wait()
@@ -1237,7 +1251,9 @@ class DeviceScheduler:
         ``sched.launch`` span (resolve + DISPATCH: the call returns
         once the program is enqueued, before the device has run it)
         carrying the program's name, topn_blocks (a TopN-rooted
-        program's block count: 1 = full sort), join / probe_rows /
+        program's block count: 1 = full sort), agg_limbs (the int32
+        lanes a row a DENSE aggregation's SUM and COUNT states are
+        reduced as), join / probe_rows /
         build_rows (a join-carrying program's), predicted_ms (calibrated
         LaunchCost via copmeter's predict_ms) next to dispatch_ms (the
         span's own wall time), the shardflow per-link transfer breakdown,
@@ -1267,6 +1283,8 @@ class DeviceScheduler:
                 attrs["program"] = program
             if topn_blocks:
                 attrs["topn_blocks"] = topn_blocks
+            if agg_limbs:
+                attrs["agg_limbs"] = agg_limbs
             if join:
                 attrs.update(join)
             if t.cost is not None:
@@ -1605,8 +1623,11 @@ class DeviceScheduler:
             # fusion win) — counted and logged, never silent
             self._note_refusal("fused", lead, e)
             return False
-        blocks = 0 if isinstance(lead.dag, D.Aggregation) \
-            else fprog.topn_blocks(lead.cols, lead.counts)
+        blocks, limbs = 0, None
+        if isinstance(lead.dag, D.Aggregation):
+            limbs = fprog.agg_limbs(lead.cols, lead.counts)
+        else:
+            blocks = fprog.topn_blocks(lead.cols, lead.counts)
         total = len(all_tasks)
         self._cc_note(all_tasks, cc0)
         # fused/coalesced attrs + spans are set BEFORE finish(): the
@@ -1620,7 +1641,8 @@ class DeviceScheduler:
         self._mem_note(all_tasks, lead.mesh)
         self._trace_launch(all_tasks, t_l0, time.perf_counter_ns(),
                            "fused", fused=len(programs),
-                           program=fprog.name, topn_blocks=blocks)
+                           program=fprog.name, topn_blocks=blocks,
+                           agg_limbs=limbs)
         for grp, out in zip(programs, outs):
             sprog = get_sharded_program(grp[0].dag, grp[0].mesh,
                                         grp[0].row_capacity)
@@ -1631,6 +1653,7 @@ class DeviceScheduler:
             self.donated_launches += 1
         self.fused_launches += 1
         self._note_topn(blocks)
+        self._note_dense_agg(limbs)
         self.fused_tasks += total
         self._m_launch.inc(mode="fused")
         self._m_fused.inc(total)
@@ -1676,6 +1699,7 @@ class DeviceScheduler:
                     outs = bprog([s[0].cols for s in slots],
                                  [s[0].counts for s in slots])
                 blocks = prog.topn_blocks(lead.cols, lead.counts)
+                limbs = prog.agg_limbs(lead.cols, lead.counts)
                 self._cc_note(batch, cc0)
                 # coalesced attr + spans BEFORE finish (waiter race,
                 # see _serve_fused)
@@ -1684,7 +1708,8 @@ class DeviceScheduler:
                 self._mem_note(batch, lead.mesh)
                 self._trace_launch(batch, t_l0,
                                    time.perf_counter_ns(), "batched",
-                                   program=bprog.name, topn_blocks=blocks)
+                                   program=bprog.name, topn_blocks=blocks,
+                                   agg_limbs=limbs)
                 for s, out in zip(slots, outs):
                     for t in s:
                         t.finish((prog, out))
@@ -1696,6 +1721,7 @@ class DeviceScheduler:
                     self.donated_launches += 1
                 self.batched_launches += 1
                 self._note_topn(blocks)
+                self._note_dense_agg(limbs)
                 if prog.kind == "rows":
                     self.batched_rows_launches += 1
                 self._m_launch.inc(mode="batched")
@@ -1712,6 +1738,7 @@ class DeviceScheduler:
             with self._live_launch(s, mode, prog.name):
                 out = prog(s[0].cols, s[0].counts, s[0].aux)
             blocks = prog.topn_blocks(s[0].cols, s[0].counts, s[0].aux)
+            limbs = prog.agg_limbs(s[0].cols, s[0].counts, s[0].aux)
             # cumulative from the group's entry: a later slot DID wait
             # on the earlier slots' (and the lead's) resolve/compile
             self._cc_note(s, cc0)
@@ -1722,11 +1749,12 @@ class DeviceScheduler:
             self._mem_note(s, lead.mesh)
             self._trace_launch(s, t_s0, time.perf_counter_ns(), mode,
                                program=prog.name, topn_blocks=blocks,
-                               join=self._note_join(s[0]))
+                               join=self._note_join(s[0]), agg_limbs=limbs)
             for t in s:
                 t.finish((prog, out))
             self.launches += 1
             self._note_topn(blocks)
+            self._note_dense_agg(limbs)
             if prog._donate_argnums:
                 self.donated_launches += 1
             self._m_launch.inc(mode=mode)
@@ -1948,6 +1976,8 @@ class DeviceScheduler:
                 "fused_launches": self.fused_launches,
                 "topn_launches": self.topn_launches,
                 "topn_pruned_launches": self.topn_pruned_launches,
+                "dense_agg_launches": self.dense_agg_launches,
+                "dense_agg_limb_launches": self.dense_agg_limb_launches,
                 "join_launches": self.join_launches,
                 "join_shuffle_launches": self.join_shuffle_launches,
                 "join_host_fallbacks": self.join_host_fallbacks,
