@@ -1,6 +1,6 @@
 """The TPU's dense grouped SUM/COUNT reduction (copr/exec._dense_limb_states),
-which no statement reaches on the CPU mesh (there `_reduce` scatters): traced
-under `set_trace_platform("tpu")`, its states have to equal, word for word
+which no statement reaches on the CPU mesh (there `_reduce` scatters): lowered
+for `Evaluator(jnp, platform="tpu")`, its states have to equal, word for word
 after recombination, the scatter branch's and a Python-int oracle's."""
 
 import datetime
@@ -36,17 +36,14 @@ def _states(agg, cols, sel, platform, stacked=1):
     limbs = []
 
     def fn(cols, sel):
-        X.set_trace_platform(platform)
         batch = X.DeviceBatch(
             [(v, True if m is None else m) for v, m in cols], sel,
             stacked=stacked)
-        out = X._agg_partial_states(agg, batch, Evaluator(jnp), {})
-        limbs.append(batch.agg_limbs)
+        out = X._agg_partial_states(
+            agg, batch, Evaluator(jnp, platform=platform), {})
+        limbs.append(batch.facts["agg_limbs"])
         return out
-    try:
-        out = jax.jit(fn)(cols, sel)
-    finally:
-        X.set_trace_platform(None)
+    out = jax.jit(fn)(cols, sel)
     return jax.tree_util.tree_map(np.asarray, out), limbs[0]
 
 
@@ -259,6 +256,43 @@ def test_int64_extremes(narrow):
     assert max(abs(t) for t in want) > 2 ** 62
 
 
+def test_two_threads_lowering_for_two_platforms_get_their_own_forms():
+    """One DENSE aggregation lowered for "tpu" on one thread and for
+    "cpu" on another, in step (both are inside their trace, evaluator in
+    hand, before either lowers): each gets its own platform's form every
+    time.  A platform kept beside the lowering, for the whole process,
+    could not promise that."""
+    import threading
+    cols, sel, agg = _table(1024, 6, seed=3)
+    rounds, in_step = 6, threading.Barrier(2)
+    seen, errors = {"tpu": [], "cpu": []}, []
+
+    def lower(platform):
+        def fn(cols, sel):
+            ev = Evaluator(jnp, platform=platform)
+            batch = X.DeviceBatch(
+                [(v, True if m is None else m) for v, m in cols], sel)
+            in_step.wait(timeout=60)
+            out = X._agg_partial_states(agg, batch, ev, {})
+            seen[platform].append(batch.facts["agg_limbs"])
+            return out
+        try:
+            for _ in range(rounds):
+                # a new function object a round: traced anew
+                jax.eval_shape(lambda c, s: fn(c, s), cols, sel)
+        except Exception as e:  # noqa: BLE001 - surfaced by the assert
+            in_step.abort()
+            errors.append(e)
+    threads = [threading.Thread(target=lower, args=(p,)) for p in seen]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+        assert not t.is_alive()
+    assert not errors, errors
+    assert seen == {"tpu": [13] * rounds, "cpu": [0] * rounds}
+
+
 def test_under_vmap_as_the_batched_program_runs_it():
     n, slots = 4 * 1024, 3
     tables = [_table(n, 6, seed=s) for s in range(slots)]
@@ -270,14 +304,11 @@ def test_under_vmap_as_the_batched_program_runs_it():
     sel = np.stack([t[1] for t in tables])
 
     def one(cols, sel):
-        X.set_trace_platform("tpu")
         batch = X.DeviceBatch(
             [(v, True if m is None else m) for v, m in cols], sel, stacked=4)
-        return X._agg_partial_states(agg, batch, Evaluator(jnp), {})
-    try:
-        got = jax.jit(jax.vmap(one))(cols, sel)
-    finally:
-        X.set_trace_platform(None)
+        return X._agg_partial_states(
+            agg, batch, Evaluator(jnp, platform="tpu"), {})
+    got = jax.jit(jax.vmap(one))(cols, sel)
     got = jax.tree_util.tree_map(np.asarray, got)
     for s, (c, sl, _agg) in enumerate(tables):
         want, _ = _states(agg, c, sl, "cpu")
@@ -301,22 +332,19 @@ def test_over_a_four_device_mesh_with_psum():
     seen = {}
 
     def device_fn(cols, counts):
-        X.set_trace_platform("tpu")
         view, pad = X.dense_view(s_local * cap, s_local)
         assert not pad
         flat, base = spmd._flatten_block(list(cols), counts, view)
         batch = X.DeviceBatch(
             [(v, True if m is None else m) for v, m in flat[:-1]],
             base & flat[-1][0], stacked=s_local)
-        states = X._agg_partial_states(agg, batch, Evaluator(jnp), {})
-        seen["limbs"] = batch.agg_limbs
+        states = X._agg_partial_states(
+            agg, batch, Evaluator(jnp, platform="tpu"), {})
+        seen["limbs"] = batch.facts["agg_limbs"]
         return spmd._collective_merge(states, SHARD_AXIS, devs)
-    try:
-        got = jax.jit(shard_map(
-            device_fn, mesh=mesh, in_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
-            out_specs=P()))(stacked, counts)
-    finally:
-        X.set_trace_platform(None)
+    got = jax.jit(shard_map(
+        device_fn, mesh=mesh, in_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
+        out_specs=P()))(stacked, counts)
     assert seen["limbs"] == 13
     want, _ = _states(agg, cols, sel & live, "cpu")
     _assert_same(jax.tree_util.tree_map(np.asarray, got), want, agg.aggs)
@@ -367,17 +395,13 @@ def test_rollup_levels():
         limbs = []
 
         def fn(cols, sel):
-            X.set_trace_platform(platform)
             scan_cols = [(v, True if m is None else m) for v, m in cols]
-            states, batch = X.agg_states(agg, scan_cols, sel, Evaluator(jnp),
-                                         ())
-            limbs.append(batch.agg_limbs)
+            states, batch = X.agg_states(
+                agg, scan_cols, sel, Evaluator(jnp, platform=platform), ())
+            limbs.append(batch.facts["agg_limbs"])
             return states
-        try:
-            return (jax.tree_util.tree_map(np.asarray,
-                                           jax.jit(fn)(cols, sel)), limbs[0])
-        finally:
-            X.set_trace_platform(None)
+        return (jax.tree_util.tree_map(np.asarray, jax.jit(fn)(cols, sel)),
+                limbs[0])
     (got, limbs), (want, none) = run("tpu"), run("cpu")
     assert (limbs, none) == (2 + 2, 0)
     _assert_same(got, want, aggs)
@@ -416,17 +440,13 @@ def test_tpch_q1_against_a_python_int_oracle():
         limbs = []
 
         def fn(scan_cols):
-            X.set_trace_platform(platform)
             states, batch = X.agg_states(
                 agg, [(v, True) for v, _m in scan_cols], jnp.int64(n),
-                Evaluator(jnp), (), 2)
-            limbs.append(batch.agg_limbs)
+                Evaluator(jnp, platform=platform), (), 2)
+            limbs.append(batch.facts["agg_limbs"])
             return states
-        try:
-            return (jax.tree_util.tree_map(
-                np.asarray, jax.jit(fn)(scan_cols)), limbs[0])
-        finally:
-            X.set_trace_platform(None)
+        return (jax.tree_util.tree_map(
+            np.asarray, jax.jit(fn)(scan_cols)), limbs[0])
     (got, limbs), (want, _none) = run("tpu"), run("cpu")
     # quantity 1 limb, price 2, discount 1, the two products 3 each, count
     assert limbs == 1 + 2 + 1 + 3 + 3 + 1
@@ -487,8 +507,7 @@ def test_whole_statement_through_the_sharded_program(monkeypatch, sql,
     dom = sess.domain
     dom.client._platform = lambda: "tpu"    # the device path
     sess.execute("set global tidb_tpu_trace_sample = 1")
-    monkeypatch.setattr(X, "set_trace_platform",
-                        lambda _p, real=X.set_trace_platform: real(platform))
+    monkeypatch.setattr(spmd, "mesh_platform", lambda _mesh: platform)
     views = []
     monkeypatch.setattr(
         spmd, "_flatten_block",
@@ -501,7 +520,6 @@ def test_whole_statement_through_the_sharded_program(monkeypatch, sql,
         got = sorted(sess.execute(sql).rows)
         after = sched.stats()
     finally:
-        X._TRACE_PLATFORM[0] = None
         spmd._cached.cache_clear()
     assert got == want
     assert views and {v is not None for v in views} == {pinned}
@@ -523,8 +541,7 @@ def test_batched_and_fused_programs_equal_solo(monkeypatch):
     from tidb_tpu.parallel.mesh import sharded
     mesh = get_mesh()
     s, cap = 2 * len(mesh.devices.reshape(-1)), 1024
-    monkeypatch.setattr(X, "set_trace_platform",
-                        lambda _p, real=X.set_trace_platform: real("tpu"))
+    monkeypatch.setattr(spmd, "mesh_platform", lambda _mesh: "tpu")
     inputs, want = [], []
     for seed in (1, 2):
         cols, _sel, agg = _table(s * cap, 6, seed=seed)
@@ -542,15 +559,14 @@ def test_batched_and_fused_programs_equal_solo(monkeypatch):
         solo = spmd.ShardedCopProgram(agg, mesh)
         got = [jax.tree_util.tree_map(np.asarray, solo(c, k))
                for c, k in inputs]
-        assert solo.agg_limbs(*inputs[0]) == 13
+        assert solo.facts(*inputs[0]) == {"agg_limbs": 13}
         batched = spmd.BatchedCopProgram(agg, mesh, 2)(
             [c for c, _k in inputs], [k for _c, k in inputs])
         fused = spmd.FusedCopProgram(D.FusedDag((agg, other)), mesh)
         members = fused(*inputs[0])
         # the second member: the row count, an argument's count, one limb
-        assert fused.agg_limbs(*inputs[0]) == 13 + 3
+        assert fused.facts(*inputs[0]) == {"agg_limbs": 13 + 3}
     finally:
-        X._TRACE_PLATFORM[0] = None
         spmd._cached.cache_clear()
     for g, w, b in zip(got, want, batched):
         _assert_same(g, w, agg.aggs)

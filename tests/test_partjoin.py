@@ -31,6 +31,16 @@ def _bench(kind: str, name: str):
     return mod
 
 
+@pytest.fixture(autouse=True)
+def _broadcast_cap_put_back(monkeypatch):
+    """SET tidb_tpu_broadcast_build_max_rows moves the planner's module
+    value for the whole process, and its -1 means "leave it": a test
+    that SETs it would hand its value to every later test of the worker."""
+    from tidb_tpu.executor import plan
+    monkeypatch.setattr(plan, "BROADCAST_BUILD_MAX_ROWS",
+                        plan.BROADCAST_BUILD_MAX_ROWS)
+
+
 @pytest.fixture(scope="module")
 def tpch():
     """(session, {class: (module, oracle state)}): LINEITEM and PART from

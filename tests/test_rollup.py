@@ -138,21 +138,36 @@ def test_rollup_parse_error_without_rollup_word():
         s.execute("SELECT a FROM p GROUP BY a WITH CUBE")
 
 
-def test_rollup_level_by_level_states_match(sess):
+def test_rollup_level_by_level_states_match(sess, monkeypatch):
     """The TPU per-level Expand aggregation (copr/exec.py agg_states)
-    must produce identical results to the fused materialized expand —
-    forced via the trace-platform knob under the CPU mesh."""
+    must produce identical results to the fused materialized expand:
+    the statement's program over the CPU mesh, lowered as for a TPU,
+    aggregates level by level.  (Grouped by the dictionary column: a
+    DENSE aggregation, the only strategy that has the form.)"""
+    from tidb_tpu.compilecache import compile_cache
     from tidb_tpu.copr import exec as X
-    q = ("SELECT a, b, COUNT(*), SUM(v), MIN(v), MAX(v) FROM t "
-         "GROUP BY a, b WITH ROLLUP")
+    from tidb_tpu.parallel import spmd
+    q = ("SELECT b, COUNT(*), SUM(v), MIN(v), MAX(v) FROM t "
+         "GROUP BY b WITH ROLLUP")
 
     def norm(rows):
         return sorted((tuple((x is None, x) for x in r) for r in rows))
-    want = norm(sess.execute(q).rows)
-    X.set_trace_platform("tpu")
+    sess.execute("set global tidb_tpu_result_cache_entries = 0")
+    want = norm(sess.execute(q).rows)               # the host engine's
+    sess.domain.client._platform = lambda: "tpu"    # the device path
+    forms = []
+    monkeypatch.setattr(spmd, "mesh_platform", lambda _mesh: "tpu")
+    monkeypatch.setattr(
+        X, "_expand_level_states",
+        lambda *a, real=X._expand_level_states:
+        forms.append("levels") or real(*a))
+    # no program object and no executable an earlier statement traced
+    # for the CPU
+    spmd._cached.cache_clear()
+    compile_cache().clear_pool()
     try:
-        s2 = Session(sess.domain)
-        got = norm(s2.execute(q).rows)
+        got = norm(Session(sess.domain).execute(q).rows)
     finally:
-        X.set_trace_platform(None)
-    assert got == want
+        spmd._cached.cache_clear()
+        compile_cache().clear_pool()
+    assert got == want and forms == ["levels"]

@@ -101,7 +101,7 @@ def _run(monkeypatch, cols, sel, keys, limit, block_len, stacked=1):
     out = X._exec_topn(node, X.DeviceBatch(list(cols), jnp.asarray(sel),
                                            stacked=stacked),
                        Evaluator(jnp))
-    assert out.topn_blocks == N // block_len and out.stacked == 1
+    assert out.facts == {"topn_blocks": N // block_len} and out.stacked == 1
     return ([(np.asarray(v), True if m is True else np.asarray(m))
              for v, m in out.cols], np.asarray(out.sel))
 
@@ -180,14 +180,14 @@ def test_layout_and_blocks_ride_the_batch():
         return X._exec_node(node, flat, live, Evaluator(jnp), (), stacked=8)
     kept = D.Limit(D.Projection(D.Selection(
         scan, (B.compare("ge", r, B.lit(5, I64)),)), (r,)), 100)
-    assert run(kept).stacked == 8 and run(kept).topn_blocks == 0
+    assert run(kept).stacked == 8 and run(kept).facts == {}
     rolled = D.Expand(scan, (r,), 2)
     assert run(rolled).stacked == 1
-    assert run(D.TopN(scan, sort_key=r, limit=10)).topn_blocks \
+    assert run(D.TopN(scan, sort_key=r, limit=10)).facts["topn_blocks"] \
         == n // D.topn_block_len(n, 10) == 64
-    assert run(D.TopN(rolled, sort_key=r, limit=10)).topn_blocks \
+    assert run(D.TopN(rolled, sort_key=r, limit=10)).facts["topn_blocks"] \
         == 2 * n // D.topn_block_len(2 * n, 10) == 128
-    assert run(D.TopN(scan, sort_key=r, limit=n)).topn_blocks == 1
+    assert run(D.TopN(scan, sort_key=r, limit=n)).facts["topn_blocks"] == 1
 
 
 # ------------------------------------------------------------------ #

@@ -19,7 +19,7 @@ dispatcher retries with a larger capacity — the paging analog
 from __future__ import annotations
 
 import functools
-from dataclasses import dataclass
+from dataclasses import dataclass, field, replace
 from typing import Any, Optional, Sequence
 
 import jax
@@ -64,21 +64,17 @@ class DeviceBatch:
     flattened by parallel/spmd, which the TPU holds interleaved tile by
     tile).  The scan sets it, operators that keep the slot axis keep it,
     those that build a new one (Expand, an expanding join, TopN) drop it
-    to 1.  `topn_blocks` is set by a TopN: the blocks it viewed its
-    input as (1 = it sorted every row).  `agg_limbs` is set by a DENSE
-    aggregation over this batch: the int32 lanes a row its SUM and COUNT
-    states were reduced as (0 = not in that form).  All three are
-    static."""
+    to 1.
+
+    `facts` is the one dict of a trace: a lowering writes there, under
+    the name `copr/facts.py` gives it, what it decided (static values
+    only).  An operator makes its output with `replace(batch, ...)`,
+    naming what it changes, so extras and facts go from batch to batch."""
     cols: list  # list[(value, valid)]
     sel: Any    # bool array | True
-    extras: dict = None  # type: ignore[assignment]
+    extras: dict = field(default_factory=dict)
     stacked: int = 1
-    topn_blocks: int = 0
-    agg_limbs: int = 0
-
-    def __post_init__(self):
-        if self.extras is None:
-            self.extras = {}
+    facts: dict = field(default_factory=dict)
 
 
 def _ensure_array(v, n):
@@ -95,26 +91,12 @@ def _sel_array(sel, n):
 # Aggregation partial states (the psum seam, SURVEY.md §A.4)
 # --------------------------------------------------------------------- #
 
-# platform the program being TRACED will run on — set by the program
-# builders from their actual device placement (a CPU mesh on a TPU host,
-# e.g. dryrun_multichip, must still take the CPU strategy); falls back
-# to the process default backend
-_TRACE_PLATFORM: list = [None]
-
-
-def set_trace_platform(platform):
-    _TRACE_PLATFORM[0] = platform
-
-
-def trace_platform() -> str:
-    return _TRACE_PLATFORM[0] or jax.default_backend()
-
-
-def _reduce(vals, mask, gids, num_groups, how: str):
+def _reduce(vals, mask, gids, num_groups, how: str, platform: str):
     """Masked (optionally grouped) reduction.
 
     how: 'sum' | 'min' | 'max'.  gids None => scalar reduction.
-    Grouped: dense (G,) output.  Strategy is PER-PLATFORM: on TPU a
+    Grouped: dense (G,) output.  Strategy is PER-PLATFORM (`platform`:
+    the one the program is traced for, `Evaluator.platform`): on TPU a
     broadcast one-hot compare for small G (scatter lowering on TPU can
     serialize); on CPU the (G, N) broadcast costs G x the scan traffic
     per aggregate and XLA's scatter-add is cheap, so CPU always
@@ -124,7 +106,7 @@ def _reduce(vals, mask, gids, num_groups, how: str):
     v = jnp.where(mask, vals, jnp.asarray(neutral, vals.dtype))
     if gids is None:
         return getattr(jnp, how)(v)
-    if _onehot_form(num_groups):
+    if _onehot_form(num_groups, platform):
         onehot = gids[None, :] == jnp.arange(num_groups, dtype=gids.dtype)[:, None]
         vv = jnp.where(onehot, v[None, :], jnp.asarray(neutral, vals.dtype))
         return getattr(jnp, how)(vv, axis=1)
@@ -134,11 +116,13 @@ def _reduce(vals, mask, gids, num_groups, how: str):
     return getattr(out.at[gids], how)(v, mode="drop")
 
 
-def _onehot_form(num_groups: int) -> bool:
+def _onehot_form(num_groups: int, platform: str) -> bool:
     """A grouped reduction as a one-hot compare (small G, not the CPU)
     or as a scatter?  `_reduce`'s docstring says why."""
-    return trace_platform() != "cpu" \
-        and num_groups <= DENSE_BROADCAST_MAX_GROUPS
+    if platform is None:
+        raise ValueError("an aggregation is lowered for a platform: "
+                         "Evaluator(jnp, platform=...)")
+    return platform != "cpu" and num_groups <= DENSE_BROADCAST_MAX_GROUPS
 
 
 def _max_of(dtype):
@@ -154,10 +138,10 @@ def _min_of(dtype):
 
 
 def _one_agg_state(a: D.AggDesc, av, am, sel, gids, num_groups, n,
-                   narrow: bool = False, cnt=None) -> dict:
+                   platform: str, narrow: bool = False, cnt=None) -> dict:
     """Partial state for one AggDesc over (possibly grouped) rows.
-    `cnt`: the count of the argument's non-NULL live rows a group, where
-    the caller has it already.
+    `platform`: `_reduce`'s.  `cnt`: the count of the argument's
+    non-NULL live rows a group, where the caller has it already.
 
     Layout (all named arrays so psum/pmin/pmax merges are mechanical —
     see parallel/collectives.py MERGE_SPECS):
@@ -170,23 +154,25 @@ def _one_agg_state(a: D.AggDesc, av, am, sel, gids, num_groups, n,
     """
     av = _ensure_array(av, n)
     mask = sel if am is True else (sel & am)
+
+    def reduce(vals, how):
+        return _reduce(vals, mask, gids, num_groups, how, platform)
     if cnt is None:
-        cnt = _reduce(mask.astype(jnp.int64), mask, gids, num_groups, "sum")
+        cnt = reduce(mask.astype(jnp.int64), "sum")
     if a.func == D.AggFunc.COUNT:
         return {"count": cnt}
     if a.func == D.AggFunc.SUM:
         kind = a.arg.dtype.kind
         if kind in (K.FLOAT64, K.FLOAT32):
-            return {"sum": _reduce(av.astype(jnp.float64), mask, gids,
-                                   num_groups, "sum"), "cnt": cnt}
+            return {"sum": reduce(av.astype(jnp.float64), "sum"),
+                    "cnt": cnt}
         if narrow:
             # valueflow proved Σv over the WHOLE table (all shards, all
             # batches, with headroom) stays inside int64, so the per-batch
             # sum and every psum/host partial can't wrap either: one int64
             # word, half the state bytes, no limb fence.  Bit-identical to
             # the limb path (Σhi<<32 + Σlo == Σv in two's complement).
-            return {"sum": _reduce(av.astype(jnp.int64), mask, gids,
-                                   num_groups, "sum"), "cnt": cnt}
+            return {"sum": reduce(av.astype(jnp.int64), "sum"), "cnt": cnt}
         # decimal AND integer sums accumulate as (hi, lo) int64 limbs.
         # Exactness argument (types/decimal.py): per row |hi| < 2^32 and
         # lo < 2^32, so with n < 2^31 rows per batch neither limb sum can
@@ -195,15 +181,13 @@ def _one_agg_state(a: D.AggDesc, av, am, sel, gids, num_groups, n,
         _limb_row_fence(n)
         with jax.named_scope("limb_split"):
             v = av.astype(jnp.int64)
-            hi = _reduce(v >> 32, mask, gids, num_groups, "sum")
-            lo = _reduce(v & 0xFFFFFFFF, mask, gids, num_groups, "sum")
+            hi = reduce(v >> 32, "sum")
+            lo = reduce(v & 0xFFFFFFFF, "sum")
         return {"hi": hi, "lo": lo, "cnt": cnt}
     if a.func == D.AggFunc.MIN:
-        return {"min": _reduce(av, mask, gids, num_groups, "min"),
-                "cnt": cnt}
+        return {"min": reduce(av, "min"), "cnt": cnt}
     if a.func == D.AggFunc.MAX:
-        return {"max": _reduce(av, mask, gids, num_groups, "max"),
-                "cnt": cnt}
+        return {"max": reduce(av, "max"), "cnt": cnt}
     raise NotImplementedError(a.func)
 
 
@@ -224,16 +208,16 @@ def agg_states(agg: D.Aggregation, scan_cols, row_count, ev: Evaluator,
     n-row child batch, builds DENSE partial states, and merges them with
     the shard-merge combiners — identical math, 1/levels the peak HBM
     (the levels×n materialization OOM-crashed the v5e worker at SF=10).
-    Returns (states, child_batch-for-extras); the batch carries
-    `agg_limbs`.  `stacked`: the runs the flat scan columns consist of
-    (DeviceBatch.stacked).
+    Returns (states, child_batch-for-extras-and-facts).  `stacked`: the
+    runs the flat scan columns consist of (DeviceBatch.stacked).
 
-    TPU-only: on CPU the materialized expand fuses into one pass and
-    measures slightly faster; on TPU the replication is what OOMs."""
+    TPU-only (`ev.platform`): on CPU the materialized expand fuses into
+    one pass and measures slightly faster; on TPU the replication is
+    what OOMs."""
     ch = agg.child
     if isinstance(ch, D.Expand) \
             and agg.strategy == D.GroupStrategy.DENSE \
-            and trace_platform() == "tpu":
+            and ev.platform == "tpu":
         with jax.named_scope("scan_filter"):
             base = _exec_node(ch.child, scan_cols, row_count, ev, aux,
                               stacked)
@@ -272,9 +256,7 @@ def _expand_level_states(agg: D.Aggregation, exp: D.Expand,
             else:                      # rolled: NULL for every row
                 cols.append((v, jnp.zeros(n, bool)))
         cols.append((jnp.full(n, lvl, jnp.int64), True))
-        level = DeviceBatch(cols, base.sel, base.extras, base.stacked)
-        st = _agg_partial_states(agg, level, ev, {})
-        base.agg_limbs = level.agg_limbs
+        st = _agg_partial_states(agg, replace(base, cols=cols), ev, {})
         if not merged:
             merged = st
         else:
@@ -323,27 +305,30 @@ def _agg_partial_states(agg: D.Aggregation, batch: DeviceBatch, ev: Evaluator,
     if agg.strategy == D.GroupStrategy.DENSE:
         gids = _dense_group_ids(agg, batch, ev, memo)
         num_groups = agg.num_groups
-        if dense_limb_form(agg):
+        if dense_limb_form(agg, ev.platform):
             return _dense_limb_states(agg, batch, ev, memo, gids, sel, n)
+        batch.facts["agg_limbs"] = 0
 
     states: dict[str, Any] = {}
-    states["__rows__"] = _reduce(sel.astype(jnp.int64), sel, gids, num_groups, "sum")
+    states["__rows__"] = _reduce(sel.astype(jnp.int64), sel, gids,
+                                 num_groups, "sum", ev.platform)
     for i, a in enumerate(agg.aggs):
         if a.func == D.AggFunc.COUNT and a.arg is None:
             states[f"a{i}"] = {"count": states["__rows__"]}
             continue
         av, am = ev.eval(a.arg, batch.cols, memo)
         states[f"a{i}"] = _one_agg_state(a, av, am, sel, gids, num_groups, n,
+                                         ev.platform,
                                          narrow=(i in agg.narrow_sums))
     return states
 
 
-def dense_limb_form(agg: D.Aggregation) -> bool:
+def dense_limb_form(agg: D.Aggregation, platform: str) -> bool:
     """Does this aggregation's SUM and COUNT reduction take the limb form
-    (`_dense_limb_states`) on the platform being traced for?  Where the
-    dense one-hot form ends and scatter begins is `_reduce`'s rule."""
+    (`_dense_limb_states`) in a program traced for `platform`?  Where
+    the dense one-hot form ends and scatter begins is `_reduce`'s rule."""
     return agg.strategy == D.GroupStrategy.DENSE \
-        and _onehot_form(agg.num_groups)
+        and _onehot_form(agg.num_groups, platform)
 
 
 def dense_view(n: int, stacked: int = 1) -> tuple:
@@ -488,7 +473,7 @@ def _dense_limb_states(agg: D.Aggregation, batch: DeviceBatch, ev: Evaluator,
     table = lax.reduce(wide, tuple(jnp.zeros((), jnp.int64) for _ in wide),
                        _add_tuples, (1, 2, 3))
 
-    batch.agg_limbs = width
+    batch.facts["agg_limbs"] = width
     states: dict[str, Any] = {"__rows__": table[rows]}
     for i, (a, lane) in enumerate(zip(agg.aggs, plan)):
         if lane is None:
@@ -497,7 +482,7 @@ def _dense_limb_states(agg: D.Aggregation, batch: DeviceBatch, ev: Evaluator,
         av, am, cnt, first, k = lane
         if not k:
             states[f"a{i}"] = _one_agg_state(a, av, am, sel, gids, G, n,
-                                             cnt=table[cnt])
+                                             ev.platform, cnt=table[cnt])
         elif i in agg.narrow_sums:
             # the total fits one word (valueflow), so adding the shifted
             # limb sums modulo 2^64 gives it exactly
@@ -575,7 +560,8 @@ def _agg_sort_states(agg: D.Aggregation, batch: DeviceBatch, ev: Evaluator,
     gids = jnp.where(sel_s, gid, G)        # dead rows -> dropped scatter
 
     states: dict[str, Any] = {"__ngroups__": ngroups}
-    states["__rows__"] = _reduce(sel_s.astype(jnp.int64), sel_s, gids, G, "sum")
+    states["__rows__"] = _reduce(sel_s.astype(jnp.int64), sel_s, gids, G,
+                                 "sum", ev.platform)
     for j, (vz, m, _nf, _cd) in enumerate(keyinfo):
         val = jnp.zeros((G,), vz.dtype).at[gids].set(vz[idx], mode="drop")
         valid = jnp.zeros((G,), bool).at[gids].set(
@@ -591,7 +577,8 @@ def _agg_sort_states(agg: D.Aggregation, batch: DeviceBatch, ev: Evaluator,
             states[f"a{i}"] = {"count": states["__rows__"]}
             continue
         av, am = ev.eval(a.arg, pcols, pmemo)
-        states[f"a{i}"] = _one_agg_state(a, av, am, sel_s, gids, G, n)
+        states[f"a{i}"] = _one_agg_state(a, av, am, sel_s, gids, G, n,
+                                         ev.platform)
     return states
 
 
@@ -674,7 +661,7 @@ def _exec_node(node: D.CopNode, scan_cols: Sequence, row_count, ev: Evaluator,
                 v = v != 0
             keep = v if m is True else (v & m)  # NULL -> filtered out
             sel = keep if sel is True else (sel & keep)
-        return DeviceBatch(batch.cols, sel, batch.extras, batch.stacked)
+        return replace(batch, sel=sel)
 
     if isinstance(node, D.Projection):
         batch = child()
@@ -684,7 +671,7 @@ def _exec_node(node: D.CopNode, scan_cols: Sequence, row_count, ev: Evaluator,
         for e in node.exprs:
             v, m = ev.eval(e, batch.cols, memo)
             cols.append((_ensure_array(v, n), m))
-        return DeviceBatch(cols, batch.sel, batch.extras, batch.stacked)
+        return replace(batch, cols=cols)
 
     if isinstance(node, D.Expand):
         batch = child()
@@ -706,14 +693,15 @@ def _exec_node(node: D.CopNode, scan_cols: Sequence, row_count, ev: Evaluator,
             mj = keep if m is True else (jnp.tile(m, LV) & keep)
             out_cols.append((v, mj))
         out_cols.append((lvl, True))
-        return DeviceBatch(out_cols, jnp.tile(sel, LV), batch.extras)
+        return replace(batch, cols=out_cols, sel=jnp.tile(sel, LV),
+                       stacked=1)
 
     if isinstance(node, D.Limit):
         batch = child()
         n = len(batch.cols[0][0])
         sel = _sel_array(batch.sel, n)
         keep = sel & (jnp.cumsum(sel) <= node.limit)
-        return DeviceBatch(batch.cols, keep, batch.extras, batch.stacked)
+        return replace(batch, sel=keep)
 
     if isinstance(node, D.TopN):
         with jax.named_scope("scan_filter"):
@@ -750,7 +738,7 @@ def _exec_lookup_join(node: D.LookupJoin, batch: DeviceBatch, ev: Evaluator,
         sel = batch.sel
         if node.kind == "inner":
             sel = matched if sel is True else (sel & matched)
-        return DeviceBatch(out_cols, sel, batch.extras, batch.stacked)
+        return replace(batch, cols=out_cols, sel=sel)
 
     sorted_keys = grp[0][0].astype(jnp.int64)
     kv = kv.astype(jnp.int64)
@@ -765,17 +753,15 @@ def _exec_lookup_join(node: D.LookupJoin, batch: DeviceBatch, ev: Evaluator,
         keep = (cnt > 0) if node.kind == "semi" else (cnt == 0)
         if node.kind == "anti" and node.null_aware and km is not True:
             keep = keep & km       # NOT IN: NULL probe key -> filtered
-        return DeviceBatch(batch.cols, sel & keep, batch.extras,
-                           batch.stacked)
+        return replace(batch, sel=sel & keep)
 
     oc = node.out_capacity
     assert oc > 0, "non-unique LookupJoin needs out_capacity"
     probe = [(_ensure_array(v, n), m) for v, m in batch.cols]
     out_cols, out_sel, total = gather_expand(
         probe, sel, key_ok, list(build_cols), perm, lo, cnt, node.kind, oc)
-    extras = dict(batch.extras)
-    extras["join_total"] = total
-    return DeviceBatch(out_cols, out_sel, extras)
+    return replace(batch, cols=out_cols, sel=out_sel, stacked=1,
+                   extras={**batch.extras, "join_total": total})
 
 
 def _topn_lanes(node: D.TopN, cols, sel, rows, ev: Evaluator) -> list:
@@ -925,11 +911,11 @@ def _exec_topn(node: D.TopN, batch: DeviceBatch, ev: Evaluator) -> DeviceBatch:
 
     block_len = D.topn_block_len(n, k)
     (dead, *_), pick = topn_head(lanes_of, n, k, block_len, batch.stacked)
+    batch.facts["topn_blocks"] = n // block_len if n else 1
     # dead rows sort last, so the head's live rows are its first ones
-    return DeviceBatch(
-        [(pick(cv), (pick(cm) if cm is not True else True))
-         for cv, cm in cols], dead == 0, batch.extras,
-        topn_blocks=n // block_len if n else 1)
+    return replace(
+        batch, cols=[(pick(cv), (pick(cm) if cm is not True else True))
+                     for cv, cm in cols], sel=dead == 0, stacked=1)
 
 
 # --------------------------------------------------------------------- #
@@ -955,9 +941,6 @@ class CopProgram:
         self._fn = named_jit(self._trace, "local", dag_root)
 
     def _trace(self, scan_cols, row_count, aux_cols=()):
-        # single-device programs run on the process default backend:
-        # reset any platform a prior CPU-mesh trace left sticky
-        set_trace_platform(None)
         # At the jit boundary "all valid" is encoded as None (a pytree node,
         # hence static structure); inside the trace it becomes the literal
         # True the Evaluator's fast paths key on.
@@ -965,7 +948,8 @@ class CopProgram:
         aux_cols = tuple(
             tuple((v, True if m is None else m) for v, m in grp)
             for grp in aux_cols)
-        ev = Evaluator(jnp)
+        # single-device programs run on the process default backend
+        ev = Evaluator(jnp, platform=jax.default_backend())
         if self.agg is not None:
             states, batch = agg_states(self.agg, scan_cols, row_count, ev,
                                        aux_cols)

@@ -1,0 +1,102 @@
+"""What a device program says of itself, and what that means outside
+`copr/`.
+
+A lowering writes what it decided, as a static value under a name, into
+the `facts` of the batch it works on (`exec.DeviceBatch.facts`, one dict
+a trace); a program adds what its DAG and a launch's inputs say without
+a trace (`parallel/spmd.ShardedCopProgram.facts`).  The table below is
+all the rest of the system knows of a fact: the scheduler bumps the
+`/sched` counters a row names and puts on the `sched.launch` span what
+the row lets through, and knows no fact by name.  A kernel that wants a
+new counter or span attribute writes its fact and adds a row here.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Callable, Optional
+
+from . import dag as D
+
+
+def _present(_value) -> bool:
+    return True
+
+
+@dataclass(frozen=True)
+class Fact:
+    """counters: (`/sched` counter, test of the value) pairs: a launch of
+        a program that has the fact bumps those whose test it passes.
+    merge: the values of the members of a fused program that have the
+        fact -> the fused launch's.
+    on_span: does this value go on the `sched.launch` span?
+    root: of a fact a lowering writes in the trace (None: the program
+        adds it itself): test of a DAG's root, the programs it counts
+        for.  A TopN under an aggregation was never counted; and only
+        such a program is traced abstractly for its facts where copforge
+        serves its executable untraced."""
+    counters: tuple = ()
+    merge: Callable = max
+    on_span: Callable = bool
+    root: Optional[Callable] = None
+
+
+FACTS = {
+    # `exec._exec_topn`: the blocks it viewed its input as (1: it sorted
+    # every row)
+    "topn_blocks": Fact(
+        counters=(("topn_launches", _present),
+                  ("topn_pruned_launches", lambda n: n > 1)),
+        root=lambda r: isinstance(r, D.TopN)),
+    # the DENSE branch of `exec._agg_partial_states`: the int32 lanes a
+    # row its SUM and COUNT states were reduced as (0: not the limb form)
+    "agg_limbs": Fact(
+        counters=(("dense_agg_launches", _present),
+                  ("dense_agg_limb_launches", lambda n: n > 0)),
+        merge=lambda ns: 0 if 0 in ns else sum(ns),
+        root=lambda r: isinstance(r, D.Aggregation)
+        and r.strategy == D.GroupStrategy.DENSE),
+    # a program with lookup joins (it takes aux inputs, so it is launched
+    # alone): "unique" | "multimatch", the slots probed with, the build
+    # sides' rows (slots, where direct-addressed)
+    "join": Fact(counters=(("join_launches", _present),), on_span=_present),
+    "probe_rows": Fact(on_span=_present),
+    "build_rows": Fact(on_span=_present),
+}
+
+# counted by name (`DeviceScheduler.count`) by whoever sees it happen:
+# no launch carries these
+EVENTS = ("join_shuffle_launches", "join_host_fallbacks", "join_regrows")
+
+
+def counter_names() -> tuple:
+    """Every counter above: `/sched` shows them from the start, at zero."""
+    return tuple(name for fact in FACTS.values()
+                 for name, _test in fact.counters) + EVENTS
+
+
+def says_in_trace(root) -> bool:
+    """Can a trace of a program with this root hold a fact of its?"""
+    return any(f.root is not None and f.root(root) for f in FACTS.values())
+
+
+def of_program(traced: dict, root) -> dict:
+    """Those of a trace's facts that count as the program's."""
+    return {k: v for k, v in traced.items() if FACTS[k].root(root)}
+
+
+def merged(members: list) -> dict:
+    """The facts of a fused launch from its member programs'."""
+    return {k: FACTS[k].merge([m[k] for m in members if k in m])
+            for k in dict.fromkeys(k for m in members for k in m)}
+
+
+def counters(facts: dict) -> list:
+    """The `/sched` counters one launch with these facts bumps."""
+    return [name for k, v in facts.items()
+            for name, test in FACTS[k].counters if test(v)]
+
+
+def span_attrs(facts: dict) -> dict:
+    """What the `sched.launch` span says of these facts."""
+    return {k: v for k, v in facts.items() if FACTS[k].on_span(v)}

@@ -134,7 +134,7 @@ def states_from_partition(agg: D.Aggregation, batch, ev, keyinfo,
 
     states: dict = {"__ngroups__": ngroups}
     states["__rows__"] = _reduce(sel_s.astype(jnp.int64), sel_s, gids, B,
-                                 "sum")
+                                 "sum", ev.platform)
     for j, (vz, m, _nf, _cd) in enumerate(keyinfo):
         val = jnp.zeros((B,), vz.dtype).at[gids].set(vz[idx], mode="drop")
         valid = jnp.zeros((B,), bool).at[gids].set(
@@ -150,7 +150,8 @@ def states_from_partition(agg: D.Aggregation, batch, ev, keyinfo,
             states[f"a{i}"] = {"count": states["__rows__"]}
             continue
         av, am = ev.eval(a.arg, pcols, pmemo)
-        states[f"a{i}"] = _one_agg_state(a, av, am, sel_s, gids, B, n)
+        states[f"a{i}"] = _one_agg_state(a, av, am, sel_s, gids, B, n,
+                                         ev.platform)
     return states
 
 

@@ -525,9 +525,7 @@ class CopJoinTaskExec(PhysOp):
         return self._host_fallback(ctx)
 
     def _host_fallback(self, ctx: ExecContext) -> ResultChunk:
-        sched = ctx.client._scheduler()
-        if sched is not None:
-            sched.join_host_fallbacks += 1
+        ctx.client._scheduler().count("join_host_fallbacks")
         return self.fallback.execute(ctx)
 
     def _execute_single(self, ctx: ExecContext) -> ResultChunk:

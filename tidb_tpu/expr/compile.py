@@ -62,11 +62,17 @@ class Evaluator:
     `dicts` (host evaluation only) maps column index -> StringDict; with
     it, string functions that dictionary lowering could not rewrite fall
     back to per-row python evaluation — the residual row-wise builtin
-    path of the reference (builtin_string.go evalString loops)."""
+    path of the reference (builtin_string.go evalString loops).
 
-    def __init__(self, xp, dicts=None):
+    `platform` (device programs only) is the platform of the devices the
+    program being traced will run on: the program builders know it from
+    their mesh, and the aggregation lowerings of copr/exec, which all
+    receive the evaluator, choose their kernel form by it."""
+
+    def __init__(self, xp, dicts=None, platform=None):
         self.xp = xp
         self.dicts = dicts
+        self.platform = platform
 
     # -- public entry ---------------------------------------------------- #
 

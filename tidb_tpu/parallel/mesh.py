@@ -50,6 +50,12 @@ def get_mesh(n_devices: Optional[int] = None) -> Mesh:
     return Mesh(np.array(devs), (SHARD_AXIS,))
 
 
+def mesh_platform(mesh: Mesh) -> str:
+    """What a program over `mesh` is lowered for (`Evaluator.platform`):
+    a CPU mesh on a TPU host (dryrun_multichip) takes the CPU's forms."""
+    return mesh.devices.reshape(-1)[0].platform
+
+
 def shard_spec() -> P:
     return P(SHARD_AXIS)
 
@@ -64,5 +70,5 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
-__all__ = ["SHARD_AXIS", "get_mesh", "shard_spec", "sharded", "replicated",
-           "shard_map"]
+__all__ = ["SHARD_AXIS", "get_mesh", "mesh_platform", "shard_spec", "sharded",
+           "replicated", "shard_map"]

@@ -36,7 +36,7 @@ from ..copr.join import gather_expand, match_ranges
 from ..expr.compile import Evaluator
 from ..ops.sortkeys import INT64_MAX
 from .exchange import all_to_all_exchange
-from .mesh import SHARD_AXIS, shard_map
+from .mesh import SHARD_AXIS, mesh_platform, shard_map
 from .spmd import _collective_merge, _flatten_block
 
 
@@ -114,9 +114,7 @@ class ShardedShuffleJoinProgram:
         return out_cols[:-1], recv_valid, rkeys, rkey_ok, max_count
 
     def _device_fn(self, lcols, lcounts, rcols, rcounts, aux):
-        from ..copr.exec import set_trace_platform
-        set_trace_platform(self.mesh.devices.reshape(-1)[0].platform)
-        ev = Evaluator(jnp)
+        ev = Evaluator(jnp, platform=mesh_platform(self.mesh))
         aux = tuple(tuple((v, True if m is None else m) for v, m in grp)
                     for grp in aux)
         spec, caps = self.spec, self.caps

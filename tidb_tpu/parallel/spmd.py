@@ -28,11 +28,13 @@ from ..analysis.compilekey import named_jit
 from ..analysis.lifetime import donation_plan, verify_donation
 from ..compilecache import cached_call
 from ..copr import dag as D
+from ..copr import facts as F
 from ..copr.aggregate import _MERGE
 from ..copr.exec import (DeviceBatch, _agg_partial_states, _exec_node,
                          agg_states, compact, dense_limb_form, dense_view)
+from ..copr.joinbuild import build_rows
 from ..expr.compile import Evaluator
-from .mesh import SHARD_AXIS, shard_map
+from .mesh import SHARD_AXIS, mesh_platform, shard_map
 
 
 def _donation_argnums(dag, program: str, donate: bool,
@@ -126,6 +128,8 @@ class ShardedCopProgram:
                  donate: bool = False, donate_argnums=None):
         self.root = dag_root
         self.mesh = mesh
+        # what the lowerings are traced for (Evaluator.platform)
+        self.platform = mesh_platform(mesh)
         self.row_capacity = row_capacity
         # buffer donation (analysis/lifetime): the donating variant is
         # requested only for launch-unique inputs (streamed HBM batches);
@@ -136,8 +140,8 @@ class ShardedCopProgram:
             dag_root, "solo", donate, donate_argnums)
         self.agg = dag_root if isinstance(dag_root, D.Aggregation) else None
         self.kind = "agg" if self.agg is not None else "rows"
-        # per-device input shape -> (DeviceBatch.topn_blocks,
-        # DeviceBatch.agg_limbs) of its trace
+        # per-device input shape -> what its trace's lowerings wrote
+        # into `DeviceBatch.facts`, as far as it counts as this program's
         self._traced: dict = {}
         # MIN/MAX merge IN-PROGRAM via _psum_gather (psum-only all_gather +
         # reduce), so the whole merge stays on device behind one kind of
@@ -195,8 +199,6 @@ class ShardedCopProgram:
                                    donate_argnums=self._donate_argnums)
 
     def _device_fn(self, cols, counts, aux):
-        from ..copr.exec import set_trace_platform
-        set_trace_platform(self.mesh.devices.reshape(-1)[0].platform)
         cols = [(v, m) for v, m in cols]
         # the flat columns are the device's S stacked shards, one run
         # each (DeviceBatch.stacked)
@@ -205,7 +207,8 @@ class ShardedCopProgram:
         # a join's gather writes its columns out whatever the view, and
         # XLA:TPU compiles a gather pinned to it 14x as long (90 s
         # against 6 for chip_smoke's join with a group-by)
-        if self.agg is not None and dense_limb_form(self.agg) \
+        if self.agg is not None \
+                and dense_limb_form(self.agg, self.platform) \
                 and not D.lookup_joins(self.agg):
             view, pad = dense_view(stacked * cap, stacked)
             if pad:
@@ -214,7 +217,7 @@ class ShardedCopProgram:
         flat = [(v, True if m is None else m) for v, m in flat]
         aux = tuple(tuple((v, True if m is None else m) for v, m in grp)
                     for grp in aux)
-        ev = Evaluator(jnp)
+        ev = Evaluator(jnp, platform=self.platform)
         if self.agg is not None:
             states, batch = agg_states(self.agg, flat, base_sel, ev, aux,
                                        stacked)
@@ -231,42 +234,34 @@ class ShardedCopProgram:
             out_cols, n = compact(batch, self.row_capacity)
             # keep a leading per-device axis so out_specs can shard it
             out = ([(v[None], m[None]) for v, m in out_cols], n[None])
-        self._traced[(stacked, cap)] = (batch.topn_blocks, batch.agg_limbs)
+        self._traced[(stacked, cap)] = F.of_program(batch.facts, self.root)
         if self.has_extras:
             extras = {k: jnp.asarray(v)[None] for k, v in batch.extras.items()}
             return out, extras
         return out
 
-    def _traced_fact(self, stacked_cols: Sequence, counts, aux_cols) -> tuple:
-        """(topn_blocks, agg_limbs) of the batch this program's trace
-        for these inputs ended with (`_device_fn` keeps them by input
-        shape).  Where no trace ran in this process (copforge served the
-        executable from its disk store) the program is traced
+    def facts(self, stacked_cols: Sequence, counts, aux_cols=()) -> dict:
+        """What one launch with these inputs says of itself
+        (copr/facts.py): what the lowerings wrote in the trace for this
+        input shape (`_device_fn` keeps it), and what the DAG and the
+        inputs say of its lookup joins.  Where no trace ran in this
+        process (copforge served the executable from its disk store) a
+        program that can have a fact of the first kind is traced
         abstractly, once."""
         s, c = stacked_cols[0][0].shape[:2]
         shape = (s // len(self.mesh.devices.reshape(-1)), c)
-        if shape not in self._traced:
+        if shape not in self._traced and F.says_in_trace(self.root):
             jax.eval_shape(self._fn, tuple(stacked_cols), counts,
                            tuple(aux_cols))
-        return self._traced[shape]
-
-    def topn_blocks(self, stacked_cols: Sequence, counts,
-                    aux_cols=()) -> int:
-        """Blocks the TopN at this program's root viewed each device's
-        rows as (`_exec_topn` says): 0 = the root is no TopN, 1 = it
-        sorts every row, more = it prunes."""
-        if not isinstance(self.root, D.TopN):
-            return 0
-        return self._traced_fact(stacked_cols, counts, aux_cols)[0]
-
-    def agg_limbs(self, stacked_cols: Sequence, counts, aux_cols=()):
-        """int32 lanes a row the SUM and COUNT states of the DENSE
-        aggregation at this program's root are reduced as
-        (`_dense_limb_states` says; 0 = not in that form); None = the
-        root is no DENSE aggregation."""
-        if self.agg is None or self.agg.strategy != D.GroupStrategy.DENSE:
-            return None
-        return self._traced_fact(stacked_cols, counts, aux_cols)[1]
+        out = dict(self._traced.get(shape, ()))
+        joins = D.lookup_joins(self.root)
+        if joins:
+            out["join"] = "unique" if all(j.unique for j in joins) \
+                else "multimatch"
+            out["probe_rows"] = s * c
+            out["build_rows"] = sum(build_rows(j, aux_cols[j.aux_slot])
+                                    for j in joins)
+        return out
 
     def __call__(self, stacked_cols: Sequence, counts, aux_cols=()):
         if self._psum_limb_fence and stacked_cols:
@@ -291,6 +286,12 @@ def get_sharded_program(dag_root: D.CopNode, mesh, row_capacity: int = 0,
     # the donating variant caches apart: donation is baked into the
     # jitted executable's input aliasing
     return _cached(dag_root, mesh, row_capacity, True if donate else False)
+
+
+def _members_facts(self, stacked_cols: Sequence, counts) -> dict:
+    """`ShardedCopProgram.facts` of a fused program: its members', merged
+    as copr/facts.py says of each."""
+    return F.merged([p.facts(stacked_cols, counts) for p in self.members])
 
 
 class FusedCopProgram:
@@ -360,15 +361,7 @@ class FusedCopProgram:
         return tuple(p._device_fn(cols, counts, aux)
                      for p in self.members)
 
-    def agg_limbs(self, stacked_cols: Sequence, counts):
-        """The members' `ShardedCopProgram.agg_limbs` as one launch's:
-        None = no member has a DENSE aggregation, 0 = one of those is
-        not in the limb form, else their lanes a row together."""
-        limbs = [n for n in (p.agg_limbs(stacked_cols, counts)
-                             for p in self.members) if n is not None]
-        if not limbs:
-            return None
-        return 0 if 0 in limbs else sum(limbs)
+    facts = _members_facts
 
     def __call__(self, stacked_cols: Sequence, counts, aux_cols=()):
         if self._psum_limb_fence and stacked_cols:
@@ -442,11 +435,7 @@ class FusedRowsProgram:
         return tuple(p._device_fn(cols, counts, aux)
                      for p in self.members)
 
-    def topn_blocks(self, stacked_cols: Sequence, counts) -> int:
-        """The most blocks any member's TopN root prunes by (0: none
-        has one) — see ShardedCopProgram.topn_blocks."""
-        return max(p.topn_blocks(stacked_cols, counts)
-                   for p in self.members)
+    facts = _members_facts
 
     def __call__(self, stacked_cols: Sequence, counts, aux_cols=()):
         return self._cached(tuple(stacked_cols), counts, tuple(aux_cols))

@@ -31,11 +31,11 @@ from jax.sharding import PartitionSpec as P
 from ..analysis.compilekey import named_jit
 from ..copr import dag as D
 from ..copr.exec import (Evaluator, _ensure_array, _exec_node, _sel_array,
-                         compact, set_trace_platform)
+                         compact)
 from ..ops.sortkeys import sortable_int64
 from ..types import dtypes as dt
 from .exchange import all_to_all_exchange
-from .mesh import SHARD_AXIS, shard_map
+from .mesh import SHARD_AXIS, mesh_platform, shard_map
 from .spmd import _flatten_block
 
 K = dt.TypeKind
@@ -81,9 +81,8 @@ class ShardedWindowProgram:
     # -- device program ------------------------------------------------ #
 
     def _device_fn(self, cols, counts, aux):
-        set_trace_platform(self.mesh.devices.reshape(-1)[0].platform)
         spec = self.spec
-        ev = Evaluator(jnp)
+        ev = Evaluator(jnp, platform=mesh_platform(self.mesh))
         flat, base_sel = _flatten_block([(v, m) for v, m in cols], counts)
         flat = [(v, True if m is None else m) for v, m in flat]
         aux = tuple(tuple((v, True if m is None else m) for v, m in grp)

@@ -77,7 +77,7 @@ import os
 import random
 import threading
 import time
-from collections import deque
+from collections import Counter, deque
 from typing import Optional
 
 from ..faults import plan as _faults
@@ -228,25 +228,10 @@ class DeviceScheduler:
         self.batched_launches = 0         # stacked-slot vmap launches
         self.batched_rows_launches = 0    # rows-kind stacked launches
         self.fused_launches = 0           # cross-query fused launches
-        # launches of programs with a TopN root, and those of them whose
-        # TopN prunes by block minima instead of sorting every row
-        # (copr/exec.topn_head; the traced program's `topn_blocks` > 1)
-        self.topn_launches = 0
-        self.topn_pruned_launches = 0
-        # launches of a program whose root is a DENSE aggregation; those
-        # whose SUM and COUNT states were all reduced as int32 limb lanes
-        # in one pass (copr/exec._dense_limb_states; the traced program's
-        # `agg_limbs` > 0)
-        self.dense_agg_launches = 0
-        self.dense_agg_limb_launches = 0
-        # broadcast lookup joins: launches of a program that holds one;
-        # launches of the repartition (all_to_all) join; CopJoinTaskExec
-        # runs that took their host fallback; capacity regrows of the
-        # expanding join (the last three are the client's and executor's)
-        self.join_launches = 0
-        self.join_shuffle_launches = 0
-        self.join_host_fallbacks = 0
-        self.join_regrows = 0
+        # launches by what their programs say of themselves, and the
+        # kernels' events (`count`): names and rules are copr/facts.py's
+        from ..copr.facts import counter_names
+        self.kernel_counts = Counter(dict.fromkeys(counter_names(), 0))
         self.fused_tasks = 0              # tasks served by a fused launch
         # group launches that raised and were served apart instead: the
         # results are the same, so only these counters (and one log line
@@ -1199,34 +1184,11 @@ class DeviceScheduler:
         ctx = next((t.trace for t in tasks if t.trace is not None), None)
         return _obs.live("sched.launch", ctx, mode=mode, program=program)
 
-    def _note_topn(self, blocks: int) -> None:
-        """Count one launch by its program's ``topn_blocks``."""
-        if blocks:
-            self.topn_launches += 1
-            if blocks > 1:
-                self.topn_pruned_launches += 1
-
-    def _note_dense_agg(self, limbs) -> None:
-        """Count one launch by its program's ``agg_limbs``."""
-        if limbs is not None:
-            self.dense_agg_launches += 1
-            if limbs:
-                self.dense_agg_limb_launches += 1
-
-    def _note_join(self, task) -> dict:
-        """Count one launch of a join-carrying program; returns what its
-        ``sched.launch`` span says of the join (empty: no join)."""
-        from ..copr import dag as D
-        joins = D.lookup_joins(task.dag)
-        if not joins:
-            return {}
-        self.join_launches += 1
-        from ..copr.joinbuild import build_rows
-        return {"join": "unique" if all(j.unique for j in joins)
-                else "multimatch",
-                "probe_rows": task.est_rows,
-                "build_rows": sum(build_rows(j, task.aux[j.aux_slot])
-                                  for j in joins)}
+    def count(self, name: str) -> None:
+        """Bump one of `kernel_counts` for an event no launch carries
+        (copr/facts.EVENTS), from whichever thread sees it happen."""
+        with self._mu:
+            self.kernel_counts[name] += 1
 
     @staticmethod
     def _trace_mark(t, name: str, **attrs) -> None:
@@ -1237,10 +1199,8 @@ class DeviceScheduler:
             t.trace.add(name, now, now, **attrs)
 
     def _trace_launch(self, tasks: list, start_ns: int, end_ns: int,
-                      mode: str, fused: int = 0,
-                      program: str = "", topn_blocks: int = 0,
-                      join: Optional[dict] = None,
-                      agg_limbs: Optional[int] = None) -> None:
+                      mode: str, fused: int = 0, program: str = "",
+                      said: Optional[dict] = None) -> None:
         """Record one physical launch's scheduler-side span tree +
         latency histograms, on the DRAIN thread BEFORE the tasks
         finish — a waiter rendering its trace right after wait()
@@ -1250,11 +1210,9 @@ class DeviceScheduler:
         pickup; rc debit rides it as the ``ru`` attr) and a
         ``sched.launch`` span (resolve + DISPATCH: the call returns
         once the program is enqueued, before the device has run it)
-        carrying the program's name, topn_blocks (a TopN-rooted
-        program's block count: 1 = full sort), agg_limbs (the int32
-        lanes a row a DENSE aggregation's SUM and COUNT states are
-        reduced as), join / probe_rows /
-        build_rows (a join-carrying program's), predicted_ms (calibrated
+        carrying the program's name, ``said`` (what the program says
+        of itself, as far as copr/facts.py puts it on the span),
+        predicted_ms (calibrated
         LaunchCost via copmeter's predict_ms) next to dispatch_ms (the
         span's own wall time), the shardflow per-link transfer breakdown,
         and — as children — the copforge ``sched.compile`` span
@@ -1281,12 +1239,7 @@ class DeviceScheduler:
             attrs = {"mode": mode, "dispatch_ms": round(wall_ms, 3)}
             if program:
                 attrs["program"] = program
-            if topn_blocks:
-                attrs["topn_blocks"] = topn_blocks
-            if agg_limbs:
-                attrs["agg_limbs"] = agg_limbs
-            if join:
-                attrs.update(join)
+            attrs.update(said or {})
             if t.cost is not None:
                 attrs["predicted_ms"] = round(predict_ms(t.cost), 3)
                 bd = t.cost.transfer_breakdown or (0, 0, 0)
@@ -1623,41 +1576,54 @@ class DeviceScheduler:
             # fusion win) — counted and logged, never silent
             self._note_refusal("fused", lead, e)
             return False
-        blocks, limbs = 0, None
-        if isinstance(lead.dag, D.Aggregation):
-            limbs = fprog.agg_limbs(lead.cols, lead.counts)
-        else:
-            blocks = fprog.topn_blocks(lead.cols, lead.counts)
-        total = len(all_tasks)
-        self._cc_note(all_tasks, cc0)
-        # fused/coalesced attrs + spans are set BEFORE finish(): the
-        # waiter's _note_sched reads task.fused right after wait()
-        # returns, so setting them after finish raced the waiter and
-        # undercounted `fused`/`coalesced` in EXPLAIN ANALYZE and
-        # statements_summary (copscope satellite: the note_sched seam)
-        for t in all_tasks:
-            t.fused = len(programs)
-            t.coalesced = total
-        self._mem_note(all_tasks, lead.mesh)
-        self._trace_launch(all_tasks, t_l0, time.perf_counter_ns(),
-                           "fused", fused=len(programs),
-                           program=fprog.name, topn_blocks=blocks,
-                           agg_limbs=limbs)
+        served = []
         for grp, out in zip(programs, outs):
             sprog = get_sharded_program(grp[0].dag, grp[0].mesh,
                                         grp[0].row_capacity)
-            for t in grp:
-                t.finish((sprog, out))
-        self.launches += 1
-        if fprog._donate_argnums:
-            self.donated_launches += 1
-        self.fused_launches += 1
-        self._note_topn(blocks)
-        self._note_dense_agg(limbs)
-        self.fused_tasks += total
-        self._m_launch.inc(mode="fused")
-        self._m_fused.inc(total)
+            served += [(t, (sprog, out)) for t in grp]
+        self._launched(served, fprog, "fused",
+                       fprog.facts(lead.cols, lead.counts), t_l0, cc0,
+                       coalesced=len(all_tasks), fused=len(programs))
         return True
+
+    def _launched(self, served: list, program, mode: str, facts: dict,
+                  t0: int, cc0: tuple, coalesced: int,
+                  fused: int = 0) -> None:
+        """The epilogue of every structured launch: `served` is its
+        (task, the task's result) pairs, `facts` the program's
+        ``facts()`` for these inputs, counted and put on the span as
+        copr/facts.py says.  What a waiter reads of its task is set
+        BEFORE finish(): its _note_sched reads task.fused right after
+        wait() returns, so setting it after finish raced the waiter and
+        undercounted `fused`/`coalesced` in EXPLAIN ANALYZE and
+        statements_summary (copscope satellite: the note_sched seam)."""
+        from ..copr import facts as F
+        tasks = [t for t, _val in served]
+        self._cc_note(tasks, cc0)
+        for t in tasks:
+            t.fused, t.coalesced = fused, coalesced
+        self._mem_note(tasks, tasks[0].mesh)
+        self._trace_launch(tasks, t0, time.perf_counter_ns(), mode,
+                           fused=fused, program=program.name,
+                           said=F.span_attrs(facts))
+        for name in F.counters(facts):
+            self.kernel_counts[name] += 1
+        for t, val in served:
+            t.finish(val)
+        self.launches += 1
+        if program._donate_argnums:
+            # (a batched program's: its per-launch stacked copies,
+            # whatever the member arrays' own lifetime)
+            self.donated_launches += 1
+        if mode == "fused":
+            self.fused_launches += 1
+            self.fused_tasks += len(tasks)
+            self._m_fused.inc(len(tasks))
+        elif mode == "batched":
+            self.batched_launches += 1
+            if program.base.kind == "rows":
+                self.batched_rows_launches += 1
+        self._m_launch.inc(mode=mode)
 
     def _serve_program(self, batch: list) -> None:
         """Launch ONE program's tasks: in-flight dedup by input token,
@@ -1698,33 +1664,12 @@ class DeviceScheduler:
                 with self._live_launch(batch, "batched", bprog.name):
                     outs = bprog([s[0].cols for s in slots],
                                  [s[0].counts for s in slots])
-                blocks = prog.topn_blocks(lead.cols, lead.counts)
-                limbs = prog.agg_limbs(lead.cols, lead.counts)
-                self._cc_note(batch, cc0)
-                # coalesced attr + spans BEFORE finish (waiter race,
-                # see _serve_fused)
-                for t in batch:
-                    t.coalesced = len(batch)
-                self._mem_note(batch, lead.mesh)
-                self._trace_launch(batch, t_l0,
-                                   time.perf_counter_ns(), "batched",
-                                   program=bprog.name, topn_blocks=blocks,
-                                   agg_limbs=limbs)
-                for s, out in zip(slots, outs):
-                    for t in s:
-                        t.finish((prog, out))
-                self.launches += 1
-                if bprog._donate_argnums:
-                    # the per-launch stacked copies were donated (the
-                    # lifetime plan's batched class), whatever the
-                    # member arrays' own lifetime
-                    self.donated_launches += 1
-                self.batched_launches += 1
-                self._note_topn(blocks)
-                self._note_dense_agg(limbs)
-                if prog.kind == "rows":
-                    self.batched_rows_launches += 1
-                self._m_launch.inc(mode="batched")
+                # a slot's facts are the solo program's
+                self._launched(
+                    [(t, (prog, out)) for s, out in zip(slots, outs)
+                     for t in s], bprog, "batched",
+                    prog.facts(lead.cols, lead.counts), t_l0, cc0,
+                    coalesced=len(batch))
                 return
             except Exception as e:   # planlint: ok - vmap capability probe;
                 # op not vmappable on this backend: launch apart below
@@ -1737,27 +1682,12 @@ class DeviceScheduler:
             mode = "coalesced" if len(s) > 1 else "single"
             with self._live_launch(s, mode, prog.name):
                 out = prog(s[0].cols, s[0].counts, s[0].aux)
-            blocks = prog.topn_blocks(s[0].cols, s[0].counts, s[0].aux)
-            limbs = prog.agg_limbs(s[0].cols, s[0].counts, s[0].aux)
-            # cumulative from the group's entry: a later slot DID wait
-            # on the earlier slots' (and the lead's) resolve/compile
-            self._cc_note(s, cc0)
-            if len(batch) > 1:
-                # BEFORE finish (waiter race, see _serve_fused)
-                for t in s:
-                    t.coalesced = len(batch)
-            self._mem_note(s, lead.mesh)
-            self._trace_launch(s, t_s0, time.perf_counter_ns(), mode,
-                               program=prog.name, topn_blocks=blocks,
-                               join=self._note_join(s[0]), agg_limbs=limbs)
-            for t in s:
-                t.finish((prog, out))
-            self.launches += 1
-            self._note_topn(blocks)
-            self._note_dense_agg(limbs)
-            if prog._donate_argnums:
-                self.donated_launches += 1
-            self._m_launch.inc(mode=mode)
+            # cc0 is the group's entry: a later slot DID wait on the
+            # earlier slots' (and the lead's) resolve/compile
+            self._launched(
+                [(t, (prog, out)) for t in s], prog, mode,
+                prog.facts(s[0].cols, s[0].counts, s[0].aux), t_s0, cc0,
+                coalesced=len(batch))
 
     def _note_refusal(self, kind: str, lead, err: BaseException) -> None:
         """A fused / vmap-batched group launch raised and its members
@@ -1974,14 +1904,7 @@ class DeviceScheduler:
                 "batched_launches": self.batched_launches,
                 "batched_rows_launches": self.batched_rows_launches,
                 "fused_launches": self.fused_launches,
-                "topn_launches": self.topn_launches,
-                "topn_pruned_launches": self.topn_pruned_launches,
-                "dense_agg_launches": self.dense_agg_launches,
-                "dense_agg_limb_launches": self.dense_agg_limb_launches,
-                "join_launches": self.join_launches,
-                "join_shuffle_launches": self.join_shuffle_launches,
-                "join_host_fallbacks": self.join_host_fallbacks,
-                "join_regrows": self.join_regrows,
+                **self.kernel_counts,
                 "fused_tasks": self.fused_tasks,
                 "fused_refused": self.fused_refused,
                 "batched_refused": self.batched_refused,

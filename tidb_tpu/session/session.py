@@ -1237,11 +1237,10 @@ class Session:
         rc = -1 if v1 is None or v1 == "" else int(v1)
         if rc >= 0:
             client._result_cache_cap = rc
-        # device admission scheduler knobs (sched/): 0 queue depth
-        # bypasses admission entirely
+        # device admission scheduler knobs (sched/)
         v2 = merged.get("tidb_tpu_sched_queue_depth")
         qd = -1 if v2 is None or v2 == "" else int(v2)
-        if qd >= 0:
+        if qd > 0:
             client.sched_queue_depth = qd
         v3 = merged.get("tidb_tpu_sched_max_coalesce")
         mc = -1 if v3 is None or v3 == "" else int(v3)

@@ -49,11 +49,11 @@ _VARS = [
        max=1 << 20),
     _v("tidb_tpu_result_cache_entries", -1, kind="int", min=-1,
        max=4096, scope=SCOPE_GLOBAL),
-    # device admission scheduler (sched/): bounded queue depth (0 =
-    # bypass admission, dispatch direct) and the max tasks one launch
-    # may coalesce
+    # device admission scheduler (sched/): bounded queue depth (every
+    # launch is admitted through it, so 0 is refused) and the max tasks
+    # one launch may coalesce
     _v("tidb_tpu_sched_queue_depth", -1, kind="int", min=-1,
-       max=1 << 16, scope=SCOPE_GLOBAL),
+       max=1 << 16, scope=SCOPE_GLOBAL, validator=lambda v: v != 0),
     _v("tidb_tpu_sched_max_coalesce", -1, kind="int", min=-1, max=64,
        scope=SCOPE_GLOBAL),
     # cross-query kernel fusion (one scan, many payloads) and the
@@ -477,6 +477,8 @@ def validate_set(name: str, value: Any,
             iv = sv.min           # MySQL clamps with a warning
         if sv.max is not None and iv > sv.max:
             iv = sv.max
+        if sv.validator is not None and not sv.validator(iv):
+            raise SysVarError(f"{name}: {iv} is not a value it takes")
         return iv
     if sv.kind == "float":
         try:

@@ -29,7 +29,6 @@ from ..faults import plan as _faults
 from ..faults.breaker import LaunchQuarantinedError
 from ..obs.trace import flag as _obs_flag
 from ..obs.trace import span as _obs_span
-from ..parallel.spmd import get_sharded_program
 from .columnar import ColumnarSnapshot, _pow2_at_least
 
 # initial fraction of table rows assumed to survive a row-returning plan
@@ -110,10 +109,7 @@ class CopClient:
         self.result_cache_misses = 0
         # device admission scheduler (sched/): every launch onto the mesh
         # goes through a bounded weighted-fair queue that coalesces
-        # concurrent compatible tasks.  -1 = scheduler defaults; queue
-        # depth 0 (or TIDB_TPU_SCHED_DISABLE=1) bypasses admission.
-        self.sched_enable = os.environ.get(
-            "TIDB_TPU_SCHED_DISABLE", "") != "1"
+        # concurrent compatible tasks.  -1 = scheduler defaults.
         self.sched_queue_depth = -1
         self.sched_max_coalesce = -1
         # cross-query fusion + adaptive micro-batch window knobs
@@ -231,9 +227,7 @@ class CopClient:
     # ------------------------------------------------------------- #
 
     def _scheduler(self):
-        """This mesh's admission scheduler; None = direct dispatch."""
-        if not self.sched_enable or self.sched_queue_depth == 0:
-            return None
+        """This mesh's admission scheduler, with the client's knobs."""
         s = self._sched_obj
         if s is None:
             from ..sched import scheduler_for
@@ -270,9 +264,9 @@ class CopClient:
         from ..compilecache import compile_cache
         cc = {"compile_cache": compile_cache().stats()}
         if self._sched_obj is None:
-            return {"enabled": self.sched_enable, "started": False,
+            return {"enabled": True, "started": False,
                     "client": client, **cc}
-        return {"enabled": self.sched_enable, "started": True,
+        return {"enabled": True, "started": True,
                 "client": client, **cc, **self._sched_obj.stats()}
 
     def _transfer_attrs(self) -> dict:
@@ -331,11 +325,6 @@ class CopClient:
         batches): the DonationPlan-derived program variant aliases them
         into outputs (analysis/lifetime) — never set it for snapshot
         residents or regrow-loop inputs.  Returns (program, out)."""
-        sched = self._scheduler()
-        if sched is None:
-            prog = get_sharded_program(dag, self.mesh, row_capacity,
-                                       donate=donate)
-            return prog, prog(cols, counts, aux)
         from ..sched import CopTask
         est = 0
         if cols:
@@ -345,7 +334,7 @@ class CopClient:
         # thread span (queue/compile/launch/retry) stitches under — the
         # CopTask captures the child TraceCtx at construction
         with _obs_span("cop.dispatch"):
-            t = sched.submit(CopTask.structured(
+            t = self._scheduler().submit(CopTask.structured(
                 dag, self.mesh, row_capacity, cols, counts, tuple(aux),
                 est_rows=est, donate=donate))
             try:
@@ -357,13 +346,10 @@ class CopClient:
         """Admission-controlled launch of a program with a non-standard
         signature (shuffle/window): fair-ordered, never coalesced.
         ``program`` names it on the launch span."""
-        sched = self._scheduler()
-        if sched is None:
-            return fn()
         from ..sched import CopTask
         with _obs_span("cop.dispatch", opaque=True):
-            t = sched.submit(CopTask.opaque(fn, est_rows=est_rows,
-                                            program=program))
+            t = self._scheduler().submit(CopTask.opaque(
+                fn, est_rows=est_rows, program=program))
             try:
                 return t.wait()
             finally:
@@ -676,9 +662,7 @@ class CopClient:
         need = int(np.max(np.asarray(self._fetch(extras["join_total"]))))
         node = D.find_expand_join(dag)
         if node is not None and need > node.out_capacity:
-            sched = self._scheduler()
-            if sched is not None:
-                sched.join_regrows += 1
+            self._scheduler().count("join_regrows")
             return D.rewrite_expand_capacity(dag, _pow2_at_least(need))
         return None
 
@@ -768,11 +752,9 @@ class CopClient:
                 and not agg.state_capacity:
             spec = dataclasses.replace(spec, top=self._with_capacity(
                 agg, DEFAULT_GROUP_CAPACITY))
-        sched = self._scheduler()
         for _ in range(12):
             prog = get_shuffle_program(spec, self.mesh, caps)
-            if sched is not None:
-                sched.join_shuffle_launches += 1
+            self._scheduler().count("join_shuffle_launches")
             out, extras = self._launch_opaque(
                 lambda p=prog: p(lcols, lcounts, rcols, rcounts, aux_cols),
                 est_rows=lsnap.num_rows + rsnap.num_rows,

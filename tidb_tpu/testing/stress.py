@@ -99,7 +99,6 @@ def run_stress_harness(dom, n_sessions: int = 64,
     the mixed corpus, compare against the pre-chaos oracle."""
     queries = STRESS_QUERIES if queries is None else queries
     sched = dom.client._scheduler()
-    assert sched is not None, "scheduler did not engage"
     # zeroed broadcast threshold for the duration of the run: the join
     # statement plans as a CopShuffleJoin (exchange path).  Scoped
     # save/restore of the MODULE global (the built_tpch_plans idiom) —
