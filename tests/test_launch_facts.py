@@ -167,6 +167,8 @@ DENSE2 = "select g, sum(q) from {} where p > 5000 group by g"
 ROWS = "select p from {} where q = 3"
 JOIN = "select sum(v * w), count(*) from fact, {0} where fact.k = {0}.k"
 SHUFFLE = "select sum(v), count(*) from fact join dup on fact.k = dup.k"
+COMPACT = ("select sum(p * w), count(*) from big, dim "
+           "where big.q = dim.k and p < 200000")
 
 
 def _solo(program, **facts):
@@ -205,6 +207,22 @@ KINDS = {
         [_solo("cop_solo_rows"),
          _solo("cop_solo_join_agg_scalar", join="multimatch", build_rows=10,
                probe_rows=8192)]),
+    # a filter beneath the join, statistics to size it from, a platform
+    # whose gather costs its indices: the probe rows are compacted first
+    # (2 % of 524,288 rows estimated, a device's share, a quarter more and
+    # six deviations of one of the compaction's columns)
+    "join_compact": (
+        [COMPACT], False, "tpu",
+        {"launches": 2, "join_launches": 1, "join_compact_launches": 1},
+        [_solo("cop_solo_rows"),
+         _solo("cop_solo_join_agg_scalar", join="unique", build_rows=8,
+               probe_rows=BIG, probe_capacity=4096)]),
+    # (the build side, prepared above, is kept with `dim`'s snapshot)
+    "join_filtered_on_the_cpu_mesh": (
+        [COMPACT], False, "cpu",
+        {"launches": 1, "join_launches": 1},
+        [_solo("cop_solo_join_agg_scalar", join="unique", build_rows=8,
+               probe_rows=BIG)]),
     "fused_aggs": (
         [DENSE.format("t1"), DENSE2.format("t1")], True, "tpu",
         {"launches": 1, "fused_launches": 1, "dense_agg_launches": 1,

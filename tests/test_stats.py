@@ -231,3 +231,73 @@ def test_decimal_column_selectivity_scales_the_constant():
         card.cond_selectivity = orig
     sel = [r for c, r in seen if "l_quantity" in c]
     assert sel and all(abs(r - true / n) < 0.03 for r in sel), (sel, true / n)
+
+
+@pytest.fixture(scope="module")
+def flags():
+    """A table whose columns are what a star schema's fact table filters
+    on: a date-like range column, two low-NDV string flags, a quantity."""
+    s = make_session()
+    s.execute("create table f (d bigint, mode varchar(10), "
+              "instr varchar(20), q bigint)")
+    rng = np.random.default_rng(5)
+    n = 20000
+    modes = ["AIR", "FOB", "MAIL", "RAIL", "REG AIR", "SHIP", "TRUCK"]
+    instrs = ["COLLECT COD", "DELIVER IN PERSON", "NONE", "TAKE BACK RETURN"]
+    d, m, i, q = (rng.integers(8000, 10500, n), rng.integers(0, 7, n),
+                  rng.integers(0, 4, n), rng.integers(1, 51, n))
+    for at in range(0, n, 2000):
+        s.execute("insert into f values " + ", ".join(
+            f"({d[k]}, '{modes[m[k]]}', '{instrs[i[k]]}', {q[k]})"
+            for k in range(at, at + 2000)))
+    s.execute("analyze table f")
+    return s, n
+
+
+def _estimate(s, where):
+    """The planner's estimate of the rows `where` leaves of `f`."""
+    from tidb_tpu.planner import join_reorder
+    seen = []
+    orig = join_reorder.est_scan_rows
+
+    def spy(stats, conds, ds):
+        seen.append(orig(stats, conds, ds))
+        return seen[-1]
+    join_reorder.est_scan_rows = spy
+    try:
+        s.must_query("explain select count(*) from f, f g where f.q = g.q "
+                     "and g.d = 8000 and " + where)
+    finally:
+        join_reorder.est_scan_rows = orig
+    return max(seen)
+
+
+@pytest.mark.parametrize("where,true_where", [
+    # both ends of one column: one interval, not two independent halves
+    ("f.d >= 9000 and f.d < 9030", None),
+    ("f.d > 8100 and f.d <= 8200 and f.d >= 8150", "f.d >= 8150 and f.d <= 8200"),
+    # an IN list sums its values; one the dictionary does not hold is none
+    ("f.mode in ('AIR', 'AIR REG')", None),
+    ("f.mode in ('AIR', 'SHIP', 'TRUCK')", None),
+    # a flag's every value is counted exactly (no more values than TopN slots)
+    ("f.instr = 'DELIVER IN PERSON'", None),
+    ("f.instr = 'DELIVER IN PERSON' and f.mode in ('AIR', 'AIR REG')", None),
+    ("f.instr = 'NO SUCH INSTRUCTION'", None)])
+def test_filter_estimates_of_a_fact_table(flags, where, true_where):
+    s, n = flags
+    true = s.must_query("select count(*) from f where "
+                        + (true_where or where))[0][0]
+    est = _estimate(s, where)
+    assert abs(est - true) <= max(0.2 * true, 0.005 * n), (est, true)
+
+
+def test_equal_row_count_of_a_value_that_bounds_several_buckets():
+    """Four values in 64 buckets: every bucket's bound repeats; the count
+    of a value is its last bucket's repeat, not its first's."""
+    x = np.repeat(np.arange(4), [3000, 1000, 5000, 1000]).astype(np.int64)
+    out = build_column_stats(x, np.ones(len(x), bool))
+    h = Histogram(out["bounds"], out["cum_counts"], out["repeats"],
+                  ndv=int(out["ndv"]))
+    for v, want in enumerate([3000, 1000, 5000, 1000]):
+        assert abs(h.equal_row_count(v) - want) <= len(x) / 64 + 1, v
+    assert h.equal_row_count(7) == 0.0

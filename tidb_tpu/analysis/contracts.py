@@ -337,11 +337,39 @@ def _verify_dag(node: D.CopNode, path) -> None:
         elif node.packing:
             _fail("capacity-shape", p,
                   "a sorted lookup join that carries a packing")
+        if node.probe_capacity:
+            _verify_probe_capacity(node, p)
         if node.kind in ("inner", "left"):
             for t in node.build_dtypes:
                 if t.is_host_object:
                     _fail("host-object-on-device", p,
                           f"broadcast build column of type {t}")
+
+
+def _verify_probe_capacity(node: D.LookupJoin, p) -> None:
+    """Contract of the probe compaction (executor/physical `_compacted`
+    sets it, copr/exec `_compact_probe` reads it): only a unique
+    inner/left lookup keeps one output row a probe row, so only it can
+    look up its live rows alone; the capacity is whole rows of the
+    compaction's column view; the kept rows come in no order, so the
+    chain ends in an aggregation; and one join of a chain compacts, the
+    lowest: the joins above it run on its slots already."""
+    if node.probe_capacity < 0 or node.probe_capacity % D.COMPACT_COLUMNS:
+        _fail("capacity-shape", p,
+              f"probe_capacity {node.probe_capacity} is not a positive "
+              f"multiple of {D.COMPACT_COLUMNS}")
+    if not node.unique or node.kind not in ("inner", "left"):
+        _fail("capacity-shape", p,
+              "probe compaction on a join that is not a unique "
+              "inner/left lookup")
+    if next(name for name in p if name != "FusedDag") != "Aggregation":
+        _fail("capacity-shape", p,
+              "probe compaction under a root that reads the order of its "
+              "rows: only an aggregation may sit above")
+    if D.lookup_joins(node.child):
+        _fail("capacity-shape", p,
+              "probe compaction above another lookup join: the lowest "
+              "join of a chain compacts")
 
 
 def _verify_packing(node: D.LookupJoin, p) -> None:

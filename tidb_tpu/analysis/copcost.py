@@ -476,6 +476,14 @@ def _walk(node: D.CopNode, path: tuple, rows: int, layout: Layout,
 
     if isinstance(node, D.LookupJoin):
         build_w = _schema_width(node.build_dtypes)
+        if 0 < node.probe_capacity < rows_in:
+            # exec._compact_probe: one single-lane sort of the row words,
+            # one gather of the probe columns a kept slot; the lookup and
+            # everything above run on the kept slots
+            acc.flops += rows_in * _log2(rows_in)
+            acc.buf("/".join(p) + ":compact",
+                    rows_in * 4 + node.probe_capacity * w_in)
+            rows_in = node.probe_capacity
         if node.dense:
             # subtract, bounds check, one gather a word, shift + mask + add
             # a packed column: no search

@@ -138,6 +138,10 @@ def scan_lifetime(dag: D.CopNode) -> Tuple[BufferClass, str]:
         return (BufferClass.LOOP_CARRIED,
                 "expanding-join capacity regrow re-feeds the inputs "
                 "(store/client._grown_join_dag loop)")
+    if D.compacting_join(dag) is not None:
+        return (BufferClass.LOOP_CARRIED,
+                "a probe compaction that overflows re-feeds the inputs "
+                "to the exact program (store/client._uncompacted)")
     if not isinstance(dag, D.Aggregation):
         return (BufferClass.LOOP_CARRIED,
                 "rows paging loop re-feeds the inputs on overflow "

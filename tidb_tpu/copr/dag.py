@@ -287,6 +287,33 @@ def topn_block_len(n: int, k: int) -> int:
     return length
 
 
+# the probe compaction (copr/join.live_rows) views a batch's slots as
+# this many interleaved columns; a capacity is whole rows of that view
+COMPACT_COLUMNS = 128
+
+
+def probe_capacity_for(est_rows: float, rows: int) -> int:
+    """The slots a device compacts the live probe rows of a lookup join
+    to (LookupJoin.probe_capacity), from the `est_rows` of its `rows`
+    the filters beneath the join are estimated to leave; 0 where
+    compacting does not pay.  Pure, like `topn_block_len`.
+
+    The estimate, a quarter more for its error, and six standard
+    deviations of what one of the compaction's interleaved columns gets
+    of rows that fall at random (the fullest column decides whether a
+    launch fits), rounded up to an eighth of its power of two, not to
+    the power: the lookup costs its slots, and an estimate near a power
+    of two would double it from one ANALYZE to the next.  At most an
+    eighth of the rows: above that the compaction's own pass over every
+    slot is no longer small beside the lookups it saves."""
+    if est_rows <= 0:
+        return 0
+    need = int(1.25 * est_rows + 6 * (COMPACT_COLUMNS * est_rows) ** 0.5) + 1
+    step = max(1 << max(need.bit_length() - 3, 0), 8 * COMPACT_COLUMNS)
+    cap = -(-need // step) * step
+    return cap if cap * 8 <= rows else 0
+
+
 @dataclass(frozen=True)
 class Limit(CopNode):
     child: CopNode = None  # type: ignore[assignment]
@@ -352,6 +379,15 @@ class LookupJoin(CopNode):
     # side is in hand (rewrite_lookup), never by the planner
     dense: bool = False
     packing: tuple = ()
+    # unique inner/left under an aggregation only, set by the executor
+    # from the planner's row estimate of the probe child: the slots a
+    # device compacts its live probe rows to before the lookup, in no
+    # order (copr/join.live_rows), so the gather costs the rows a filter
+    # left and not the rows scanned.  0 = every slot is looked up.  Live
+    # rows that do not fit are never dropped: the program reports the
+    # capacity they take (extras `join_need`) and the dispatcher reruns
+    # the statement at 0.
+    probe_capacity: int = 0
 
     def children(self):
         return (self.child,)
@@ -490,6 +526,33 @@ def find_expand_join(node: CopNode):
     return None
 
 
+def compacting_join(node: CopNode):
+    """The LookupJoin of a pushed DAG that compacts its probe rows
+    (`probe_capacity` > 0; the executor sets it on one join, the lowest
+    of a chain), or None."""
+    # walked, not `lookup_joins`: its cache hashes the whole DAG, and the
+    # dispatcher asks this of a DAG the executor has just rebuilt
+    return next((n for n in iter_nodes(node) if isinstance(n, LookupJoin)
+                 and n.probe_capacity), None)
+
+
+def uncompacted(node: CopNode) -> CopNode:
+    """The DAG with its compacting join, wherever in a chain it sits,
+    looking up every slot: today's exact program."""
+    return rewrite_lookup(node, pred=lambda j: j.probe_capacity > 0,
+                          probe_capacity=0)
+
+
+def has_extras(node: CopNode) -> bool:
+    """Does a program of this DAG return an extras dict after its result
+    (DeviceBatch.extras): the true size of an expanding join's output
+    (`join_total`), the live rows a compacting join found and the
+    capacity they take (`join_live`, `join_need`)?  The dispatcher
+    reruns the statement where a size exceeds its capacity."""
+    return find_expand_join(node) is not None \
+        or compacting_join(node) is not None
+
+
 def to_multimatch(node: CopNode, out_capacity: int) -> CopNode:
     """Rebuild the DAG with its LookupJoin switched to the non-unique
     (expanding) strategy — the dispatcher's runtime answer to discovering
@@ -498,7 +561,8 @@ def to_multimatch(node: CopNode, out_capacity: int) -> CopNode:
     import dataclasses
     if isinstance(node, LookupJoin):
         return dataclasses.replace(node, unique=False,
-                                   out_capacity=out_capacity)
+                                   out_capacity=out_capacity,
+                                   probe_capacity=0)
     if not node.children():
         return node
     kids = tuple(to_multimatch(c, out_capacity) for c in node.children())
@@ -580,9 +644,12 @@ __all__ = [
     "RADIX_BITS", "RADIX_RESIDUAL_BITS", "MAX_RADIX_PASSES",
     "radix_passes", "radix_key_bits", "Aggregation",
     "TopN", "TOPN_MIN_BLOCK", "topn_block_len",
+    "COMPACT_COLUMNS", "probe_capacity_for",
     "Limit", "LookupJoin",
     "FusedDag", "ShuffleJoinSpec", "output_dtypes", "dag_digest",
-    "iter_nodes", "lookup_joins", "find_expand_join", "rewrite_lookup",
+    "iter_nodes", "lookup_joins", "find_expand_join", "compacting_join",
+    "uncompacted", "has_extras",
+    "rewrite_lookup",
     "drop_lookup",
     "chain_str", "rewrite_expand_capacity",
 ]

@@ -715,11 +715,38 @@ def _exec_node(node: D.CopNode, scan_cols: Sequence, row_count, ev: Evaluator,
     raise TypeError(node)
 
 
+def _compact_probe(batch: DeviceBatch, capacity: int) -> DeviceBatch:
+    """The batch's live rows in a batch of `capacity` slots
+    (dag.LookupJoin.probe_capacity; in no order: copr/join.live_rows).
+    Extras: `join_live`, the live rows the device found, and
+    `join_need`, the capacity they take: where that exceeds `capacity`
+    rows are missing and the dispatcher reruns the statement
+    uncompacted."""
+    from .join import gather_rows, live_rows
+    n = len(batch.cols[0][0])
+    sel = _sel_array(batch.sel, n)
+    with jax.named_scope("join_compact"):
+        rows, ok, need = live_rows(sel, capacity, batch.stacked)
+        cols = gather_rows([(_ensure_array(v, n), m) for v, m in batch.cols],
+                           rows, batch.stacked)
+    return replace(batch, cols=cols, sel=ok, stacked=1, extras={
+        **batch.extras, "join_live": jnp.sum(sel, dtype=jnp.int32),
+        "join_need": need})
+
+
 def _exec_lookup_join(node: D.LookupJoin, batch: DeviceBatch, ev: Evaluator,
                       aux) -> DeviceBatch:
     """Broadcast lookup join (see dag.LookupJoin for the two forms a
     build side takes).  aux is a tuple of GROUPS, one per chained join
     level."""
+    if node.probe_capacity:
+        n = len(batch.cols[0][0])
+        if node.probe_capacity < n and not n % D.COMPACT_COLUMNS:
+            batch = _compact_probe(batch, node.probe_capacity)
+        else:       # nothing to gain: every slot is looked up, none is lost
+            zero = jnp.zeros((), jnp.int32)
+            batch = replace(batch, extras={
+                **batch.extras, "join_live": zero, "join_need": zero})
     n = len(batch.cols[0][0])
     grp = aux[node.aux_slot]
     kv, km = ev.eval(node.probe_key, batch.cols, {})
@@ -936,7 +963,7 @@ class CopProgram:
         self.kind = "agg" if self.agg is not None else "rows"
         # programs containing an expanding join return an extras dict
         # (true join output size) after the result, for the regrow loop
-        self.has_extras = D.find_expand_join(dag_root) is not None
+        self.has_extras = D.has_extras(dag_root)
         from ..analysis.compilekey import named_jit
         self._fn = named_jit(self._trace, "local", dag_root)
 

@@ -62,11 +62,19 @@ FACTS = {
     "join": Fact(counters=(("join_launches", _present),), on_span=_present),
     "probe_rows": Fact(on_span=_present),
     "build_rows": Fact(on_span=_present),
+    # of a program whose lookup joins are all unique: the slots a device
+    # compacts its live probe rows to before the lookup (0: it looks up
+    # every slot)
+    "probe_capacity": Fact(
+        counters=(("join_compact_launches", lambda c: c > 0),)),
 }
 
 # counted by name (`DeviceScheduler.count`) by whoever sees it happen:
 # no launch carries these
-EVENTS = ("join_shuffle_launches", "join_host_fallbacks", "join_regrows")
+# (`join_compact_overflows`: a compacting join found more live rows than
+# its capacity and the statement was rerun uncompacted)
+EVENTS = ("join_shuffle_launches", "join_host_fallbacks", "join_regrows",
+          "join_compact_overflows")
 
 
 def counter_names() -> tuple:

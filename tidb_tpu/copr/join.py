@@ -15,9 +15,12 @@ grow-from-min analog, SURVEY.md §5.7).
 
 from __future__ import annotations
 
+from typing import Sequence
+
 import jax.numpy as jnp
 from jax import lax
 
+from .dag import COMPACT_COLUMNS
 from .joinbuild import APART, KEY_ITSELF
 
 
@@ -68,6 +71,158 @@ def direct_lookup(kv, grp, packing):
         out.append((v, True if vbit < 0
                     else ((words[w] >> vbit) & 1).astype(bool)))
     return matched, out
+
+
+def _tile_order(x, stacked: int):
+    """The flat slots `x` of `stacked` equal runs in the order they lie
+    in a TPU's memory: tile by tile across the runs (a (runs, slots)
+    array is tiled (8, 128)), not run by run.  There the view is the
+    array's own bytes; read run by run instead, eight stacked shards
+    cost a relayout of every array so read, 0.13 ms and 0.2 MB of a
+    program's executable each (PERF.md section 6, PR 28).  Which slots a
+    column of `live_rows` holds does not change."""
+    n = x.shape[0]
+    if stacked == 1 or n % (stacked * COMPACT_COLUMNS):
+        return x
+    return x.reshape(stacked, n // stacked // COMPACT_COLUMNS,
+                     COMPACT_COLUMNS).transpose(1, 0, 2).reshape(n)
+
+
+def live_rows(sel, capacity: int, stacked: int = 1):
+    """(rows, ok, need): `capacity` slots that hold the place of every
+    live row of `sel` (`ok`: the slot holds one; the others hold places
+    of dead rows, in bounds), provided `need` <= `capacity`; `need` is
+    the capacity this selection takes, at least its live count.  Where
+    `need` exceeds `capacity` live rows are missing (the caller reports
+    it and the statement is rerun uncompacted).  A place is an index
+    into the slots in `_tile_order`, which `gather_rows` reads.
+
+    The n slots are viewed as COMPACT_COLUMNS interleaved columns (slot
+    i in column i mod COMPACT_COLUMNS, so a run of live rows spreads
+    over all of them) and every column is sorted on its own, ONE
+    single-lane unstable `lax.sort` along the long axis of `place | dead
+    bit` words: a column's live rows come first, and its first
+    capacity / COMPACT_COLUMNS slots are kept.  The kept slots are in no
+    order between columns: only a consumer that does not read the order
+    of its rows (an aggregation) may sit above.  `need` is the fullest
+    column's live rows times the columns.
+
+    `stacked`: the equal runs the flat slots consist of
+    (exec.DeviceBatch.stacked).
+
+    (On a v5e, 2^23 slots, one row in 84 live, four probe columns: this
+    compaction with its gather 4.1 ms; with one sort of all slots, which
+    also keeps the row order, 7.6 ms; a variadic sort that carries the
+    columns 37 ms and a minute to compile; a scatter (`nonzero`) 751 ms:
+    PERF.md section 6, PR 28.)"""
+    n = sel.shape[0]
+    cols = COMPACT_COLUMNS
+    assert n % cols == 0 and capacity % cols == 0, (n, capacity)
+    bit = max(n - 1, 1).bit_length()
+    wt = jnp.int32 if bit < 31 else jnp.int64
+    if stacked == 1 or n % (stacked * cols):
+        stacked = 1
+    # a slot's place, computed where the slot is (the words, not the
+    # narrower `sel`, are what `_tile_order` views for free)
+    run, tile, lane = (lax.broadcasted_iota(
+        wt, (stacked, n // stacked // cols, cols), d) for d in range(3))
+    places = ((tile * stacked + run) * cols + lane).reshape(n)
+    words = _tile_order(jnp.where(sel, places, places | (1 << bit)),
+                        stacked).reshape(n // cols, cols)
+    # the barrier keeps the flat form: without it XLA moves the reshape
+    # below the masks that follow and pays three relayouts for it
+    top = lax.optimization_barrier(lax.sort(
+        words, dimension=0, is_stable=False)[:capacity // cols].reshape(-1))
+    need = jnp.max(jnp.sum((words >> bit) == 0, axis=0,
+                           dtype=jnp.int32)) * cols
+    return top & ((1 << bit) - 1), (top >> bit) == 0, need
+
+
+def _word_layout(cols: Sequence) -> tuple:
+    """How the columns pack into 32-bit words (`gather_rows`): (placed,
+    words, apart).  `placed`: (column, part, bits, word, shift) a field,
+    part "v" the value (a 64-bit one as "lo" and "hi"), "m" its validity
+    bit; first fit, widest first.  `apart`: columns of a dtype with no
+    32-bit view, gathered each on its own."""
+    fields, apart = [], []
+    for i, (v, m) in enumerate(cols):
+        if not (v.dtype == bool or jnp.issubdtype(v.dtype, jnp.integer)
+                or v.dtype == jnp.float32):
+            apart.append(i)
+            continue
+        if v.dtype == bool:
+            fields.append((i, "v", 1))
+        elif v.dtype.itemsize == 8:
+            fields += [(i, "lo", 32), (i, "hi", 32)]
+        else:
+            fields.append((i, "v", 8 * v.dtype.itemsize))
+        if m is not True:
+            fields.append((i, "m", 1))
+    placed, free = [], []           # free bits a word
+    for i, part, bits in sorted(fields, key=lambda f: -f[2]):
+        w = next((k for k, left in enumerate(free) if left >= bits), None)
+        if w is None:
+            free.append(32)
+            w = len(free) - 1
+        placed.append((i, part, bits, w, 32 - free[w]))
+        free[w] -= bits
+    return placed, len(free), apart
+
+
+def _field_bits(v, m, part):
+    """One field's bits as uint32, zero-extended."""
+    if part == "m":
+        return m.astype(jnp.uint32)  # valueflow: ok - bool lane, [0, 1]
+    if part == "hi":
+        return (v.astype(jnp.int64) >> 32).astype(jnp.uint32)  # valueflow: ok - the wrap IS the field: a word's 32 bits
+    if v.dtype == jnp.float32:
+        return lax.bitcast_convert_type(v, jnp.uint32)
+    u = v.astype(jnp.uint32)  # valueflow: ok - the wrap IS the field: the value's low 32 bits
+    bits = 1 if v.dtype == bool else 8 * v.dtype.itemsize
+    return u if bits >= 32 else u & jnp.uint32((1 << bits) - 1)
+
+
+def gather_rows(cols: Sequence, rows, stacked: int = 1) -> list:
+    """`cols` [(value, mask | True)] at the places `rows` (`live_rows`:
+    indices into the slots in `_tile_order`, in bounds).  The columns
+    are packed into as few uint32 words a row as their dtypes take (a
+    validity mask is one bit), the words stacked (n, W) and gathered
+    ONCE: a gather on a TPU costs its indices, not its bytes.  (Every
+    column of the probe child is packed: the planner has pruned the scan
+    to the columns the statement reads, and Q14's and Q19's programs are
+    the same bytes with the unread ones left out.)"""
+    def at(x):
+        return _tile_order(x, stacked).at[rows].get(
+            mode="promise_in_bounds")
+    placed, n_words, apart = _word_layout(cols)
+    words = [jnp.uint32(0)] * n_words
+    for i, part, _bits, w, shift in placed:
+        words[w] = words[w] | (_field_bits(*cols[i], part) << shift)
+    got = []
+    if n_words == 1:
+        got = [at(words[0])]
+    elif words:
+        hit = jnp.stack([_tile_order(w, stacked) for w in words],
+                        axis=1).at[rows].get(mode="promise_in_bounds")
+        got = [hit[:, w] for w in range(n_words)]
+    parts = {}
+    for i, part, bits, w, shift in placed:
+        f = got[w] >> shift
+        parts[i, part] = f if bits == 32 else f & jnp.uint32((1 << bits) - 1)
+    out = []
+    for i, (v, m) in enumerate(cols):
+        if i in apart:
+            out.append((at(v), True if m is True else at(m)))
+            continue
+        if v.dtype.itemsize == 8:
+            gv = ((parts[i, "hi"].astype(jnp.int64) << 32)
+                  | parts[i, "lo"].astype(jnp.int64)).astype(v.dtype)
+        elif v.dtype == jnp.float32:
+            gv = lax.bitcast_convert_type(parts[i, "v"], jnp.float32)
+        else:
+            gv = parts[i, "v"].astype(v.dtype)      # wraps: sign restored
+        out.append((gv, True if m is True else parts[i, "m"].astype(bool)))
+    return out
 
 
 def sorted_lookup(kv, grp):
@@ -158,5 +313,5 @@ def gather_expand(batch_cols, sel, probe_key_ok, build_cols, perm,
     return out_cols, valid_out, total
 
 
-__all__ = ["direct_lookup", "sorted_lookup", "match_ranges", "expand_slots",
-           "gather_expand"]
+__all__ = ["direct_lookup", "sorted_lookup", "live_rows", "gather_rows",
+           "match_ranges", "expand_slots", "gather_expand"]

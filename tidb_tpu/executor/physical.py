@@ -492,6 +492,9 @@ class CopJoinTaskExec(PhysOp):
     # {exec, key_index, key_dict, probe_key_dtype}; entry i feeds aux
     # group i (LookupJoin.aux_slot).  None = legacy single-join fields.
     builds: list = None
+    # the planner's estimate of the rows the filters beneath the (lowest)
+    # join leave of the probe table; 0 = no statistics (`_compacted`)
+    probe_est_rows: float = 0.0
 
     def __post_init__(self):
         self.children = ([b["exec"] for b in self.builds] if self.builds
@@ -517,7 +520,7 @@ class CopJoinTaskExec(PhysOp):
         if bound is None:
             return self._host_fallback(ctx)
         dag, groups = bound
-        return self._run(ctx, dag, groups)
+        return self._run(ctx, self._compacted(ctx, dag), groups)
 
     def _empty_build_result(self, ctx, bchunk) -> ResultChunk:
         # empty build side: inner join produces nothing; left join keeps all
@@ -559,8 +562,12 @@ class CopJoinTaskExec(PhysOp):
             from ..store.columnar import _pow2_at_least
             cap = _pow2_at_least(max(int(per_dev * side.avg_dup), 1024))
             dag = D.to_multimatch(dag, cap)
-        elif side.dense:
-            dag = D.rewrite_lookup(dag, dense=True, packing=side.packing)
+        else:
+            if side.dense:
+                dag = D.rewrite_lookup(dag, dense=True,
+                                       packing=side.packing)
+            if not semi:
+                dag = self._compacted(ctx, dag)
         chunk = self._run(ctx, dag, (side.aux,))   # one aux group
         # build-side output columns keep their own dictionaries
         if not isinstance(self.dag, D.Aggregation):
@@ -570,6 +577,32 @@ class CopJoinTaskExec(PhysOp):
                     if 0 <= bj < len(built.dicts):
                         c.dictionary = built.dicts[bj]
         return chunk
+
+    def _compacted(self, ctx, dag):
+        """`dag` with its lowest (unique inner/left) join told to compact
+        its live probe rows before the lookup (dag.LookupJoin
+        `probe_capacity`), where that is exact and pays: the rows feed
+        an aggregation (the compacted rows come in no order); the
+        program is lowered for a platform whose gather costs its indices
+        (not the CPU mesh); the planner had statistics to estimate the
+        probe rows the filters leave; and `dag.probe_capacity_for` finds
+        a capacity for a device's share of them.  Otherwise `dag` as it
+        is: today's program, digest and all.  A capacity that falls
+        short costs one rerun of the exact program (store/client
+        `_uncompacted`)."""
+        from ..parallel import spmd
+        if not isinstance(dag, D.Aggregation) \
+                or spmd.mesh_platform(ctx.client.mesh) == "cpu":
+            return dag
+        n_dev = len(ctx.client.mesh.devices.reshape(-1))
+        per_dev = -(-max(self.table.snapshot().num_rows, 1) // n_dev)
+        cap = D.probe_capacity_for(self.probe_est_rows / n_dev, per_dev)
+        if not cap:
+            return dag
+        *_above, lowest = (n for n in D.iter_nodes(dag)
+                           if isinstance(n, D.LookupJoin))
+        return D.rewrite_lookup(dag, pred=lambda j: j is lowest,
+                                probe_capacity=cap)
 
     def _run(self, ctx, dag, aux) -> ResultChunk:
         """Dispatch the fused program and decode with output dicts."""

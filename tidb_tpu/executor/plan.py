@@ -760,6 +760,7 @@ def _try_cop_join(p: LogicalPlan, top, mids, join: LogicalJoin) -> Optional[Phys
             if c.dtype.is_string and j not in (build_out_dicts or {}):
                 return None
     fallback = to_physical(p, no_device_join=True)
+    probe_est = 0.0 if semi else _probe_rows_estimate(join.left)
     if builds:
         # fragment chain: nested builds + this join's own build, in aux
         # slot order; runtime anomalies fall back to the host plan whole
@@ -769,20 +770,42 @@ def _try_cop_join(p: LogicalPlan, top, mids, join: LogicalJoin) -> Optional[Phys
         exec_ = CopJoinTaskExec(
             nodew, ds.table, join_kind=join.kind, n_probe=n_probe,
             out_names=out_names, out_dtypes=out_dtypes, key_meta=key_meta,
-            out_dicts=out_dicts, fallback=fallback, builds=builds)
+            out_dicts=out_dicts, fallback=fallback, builds=builds,
+            probe_est_rows=probe_est)
     else:
         exec_ = CopJoinTaskExec(
             nodew, ds.table, build_exec=build_exec, build_key_index=ri,
             build_key_dict=key_dict, probe_key_dtype=probe_key.dtype,
             join_kind=join.kind, null_aware=join.null_aware, n_probe=n_probe,
             out_names=out_names, out_dtypes=out_dtypes, key_meta=key_meta,
-            out_dicts=out_dicts, fallback=fallback)
+            out_dicts=out_dicts, fallback=fallback, probe_est_rows=probe_est)
     if host_top is not None and host_top[0] == "topn":
         return HostTopN(exec_, list(host_top[1].keys), host_top[1].limit,
                         host_top[1].offset)
     if host_top is not None:
         return HostLimit(exec_, host_top[1].limit, host_top[1].offset)
     return exec_
+
+
+def _probe_rows_estimate(plan: LogicalPlan) -> float:
+    """The rows the filters beneath the LOWEST join of a broadcast join
+    (tree) are estimated to leave of its probe table, from the statistics
+    ANALYZE left; 0.0 where the table has none (a guess sizes nothing).
+    The executor sizes the join's probe compaction from it
+    (CopJoinTaskExec.probe_est_rows)."""
+    leaf = cur = plan
+    while True:
+        while isinstance(cur, (LogicalSelection, LogicalProjection)):
+            cur = cur.child
+        if not isinstance(cur, LogicalJoin):
+            break
+        leaf = cur = cur.left
+    handle = STATS_HANDLE.get()
+    if handle is None or not isinstance(cur, DataSource) \
+            or handle.get(cur.table) is None:
+        return 0.0
+    from ..planner.join_reorder import leaf_rows
+    return leaf_rows(leaf, handle)
 
 
 def _unique_build_key(plan: LogicalPlan, key: int) -> Optional[int]:

@@ -164,9 +164,10 @@ class ShardedCopProgram:
                 and i not in self.agg.narrow_sums
                 for i, a in enumerate(self.agg.aggs)))
 
-        # programs containing an expanding join also return a per-device
-        # extras dict (true join output size) for the dispatcher's regrow
-        self.has_extras = D.find_expand_join(dag_root) is not None
+        # programs containing an expanding or a compacting join also
+        # return a per-device extras dict (the true join output size, the
+        # live probe rows) for the dispatcher's rerun
+        self.has_extras = D.has_extras(dag_root)
 
         # shardflow introspection: which collective the merge rides and
         # over which axis — the layout facts the out_specs below encode,
@@ -261,6 +262,8 @@ class ShardedCopProgram:
             out["probe_rows"] = s * c
             out["build_rows"] = sum(build_rows(j, aux_cols[j.aux_slot])
                                     for j in joins)
+            if out["join"] == "unique":
+                out["probe_capacity"] = max(j.probe_capacity for j in joins)
         return out
 
     def __call__(self, stacked_cols: Sequence, counts, aux_cols=()):

@@ -35,8 +35,8 @@ from dataclasses import dataclass, field
 from typing import Optional
 
 from ...expr.ir import ColumnRef, Func, referenced_columns
-from ..join_reorder import (_as_local_eq, _col_ndv, _flatten, _leaf_rows,
-                            _refs_leaves, _reorder_group)
+from ..join_reorder import (_as_local_eq, _col_ndv, _flatten, _refs_leaves,
+                            _reorder_group, leaf_rows)
 from ..logical import (DataSource, LogicalAggregate, LogicalExpand,
                        LogicalJoin, LogicalLimit, LogicalPlan,
                        LogicalProjection, LogicalSelection, LogicalSetOp,
@@ -108,7 +108,7 @@ def _dp_join_alternative(root: LogicalJoin, stats_handle):
     if n > DP_MAX_LEAVES:
         # greedy fallback produces one alternative tree (shares leaves)
         return _reorder_group(copy.copy(root), stats_handle)
-    rows = [_leaf_rows(l, stats_handle) for l in leaves]
+    rows = [leaf_rows(l, stats_handle) for l in leaves]
     cond_sets = [_refs_leaves(c, spans) for c in conds]
 
     def _eq_ndv(j: int) -> Optional[float]:

@@ -65,8 +65,10 @@ def _flatten(p: LogicalPlan, leaves: list, conds: list, offset: int) -> int:
     return len(p.schema)
 
 
-def _leaf_rows(leaf: LogicalPlan, stats_handle) -> float:
-    """Estimated post-filter cardinality of a join-group leaf."""
+def leaf_rows(leaf: LogicalPlan, stats_handle) -> float:
+    """Estimated post-filter cardinality of a join-group leaf: the rows
+    its filters leave of its table (the join orderers size their search
+    with it, the executor a lookup join's probe compaction)."""
     conds: list = []
     cur = leaf
     while isinstance(cur, (LogicalSelection, LogicalProjection)):
@@ -150,7 +152,7 @@ def _reorder_group(root: LogicalJoin, stats_handle) -> LogicalPlan:
     # reorder each leaf's own interior first
     leaves = [reorder_joins(l, stats_handle) for l in leaves]
 
-    rows = [_leaf_rows(l, stats_handle) for l in leaves]
+    rows = [leaf_rows(l, stats_handle) for l in leaves]
     cond_leafsets = [_refs_leaves(c, spans) for c in conds]
 
     def eq_edge(placed: set, cand: int):
@@ -276,4 +278,4 @@ def _as_local_eq(e: Expr, n_left: int, n_right: int):
     return None
 
 
-__all__ = ["reorder_joins"]
+__all__ = ["reorder_joins", "leaf_rows"]

@@ -278,11 +278,13 @@ class StatsHandle:
                          min_val=(int(raw["min_val"])
                                   if int(raw["count"]) else None))
         # keep only TopN entries that are genuinely frequent (count > 1
-        # and above the uniform expectation), like cmsketch.go TopN pruning
+        # and above the uniform expectation), like cmsketch.go TopN pruning;
+        # a column with no more distinct values than slots keeps them all:
+        # every value's count is then exact (a ship mode, a flag)
         tv, tc = raw["top_vals"], raw["top_counts"]
         uniform = max(int(raw["count"]) / max(ndv, 1), 1.0)
         topn = TopN({int(v): int(c) for v, c in zip(tv, tc)
-                     if c > 0 and c >= uniform})
+                     if c > 0 and (c >= uniform or ndv <= len(tv))})
         return ColumnStats(name=name, hist=hist, topn=topn,
                            cms=CMSketch(raw["cm"]),
                            fms=FMSketch(raw["kmv"].astype(np.uint64)),

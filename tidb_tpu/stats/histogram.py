@@ -69,7 +69,11 @@ class Histogram:
         if j >= len(self.bounds):
             return 0.0          # out of range
         if v == int(self.bounds[j]):
-            return float(self.repeats[j])
+            # a frequent value bounds several buckets; a bucket's repeat
+            # counts the value's rows up to that bucket's end, so the
+            # last of them has them all
+            last = int(np.searchsorted(self.bounds, v, side="right")) - 1
+            return float(self.repeats[last])
         lo0 = self._bucket_lo(0)
         if j == 0 and lo0 is not None and v < lo0:
             return 0.0          # below the histogram's min value

@@ -27,6 +27,7 @@ from ..copr.aggregate import (GroupKeyMeta, finalize, finalize_sorted,
                               merge_sorted_states, merge_states)
 from ..faults import plan as _faults
 from ..faults.breaker import LaunchQuarantinedError
+from ..obs.trace import annotate as _obs_annotate
 from ..obs.trace import flag as _obs_flag
 from ..obs.trace import span as _obs_span
 from .columnar import ColumnarSnapshot, _pow2_at_least
@@ -95,6 +96,11 @@ class CopClient:
         # get/assign/move_to_end/popitem sequence (ADVICE r2: a concurrent
         # eviction between get and move_to_end raised KeyError)
         self._pf_mu = threading.Lock()
+        # digests of compacting-join programs that found more live probe
+        # rows than their capacity (`_uncompacted`): the next statement
+        # with that digest launches the exact form at once.  Guarded by
+        # _pf_mu, LRU-capped like the paging feedback.
+        self._compact_overflowed: OrderedDict[int, None] = OrderedDict()
         # coprocessor RESULT cache (copr/coprocessor_cache.go analog):
         # key = (dag digest, snapshot epoch, placement epoch, shard
         # layout); a table write creates a new snapshot + epoch, so stale
@@ -280,7 +286,7 @@ class CopClient:
         return {"intra_bytes": bd[0], "ici_bytes": bd[1],
                 "dci_bytes": bd[2]}
 
-    def _fetch(self, out, **attrs):
+    def _fetch(self, out, probe=None, **attrs):
         """Host copy of a launch's outputs: the ONE place this client
         waits for the device.  ``cop.transfer`` keeps the extent it
         always had (blocked until the values are on the host); its
@@ -288,15 +294,36 @@ class CopClient:
         (``cop.device_wait``) and what is left of the copy after that
         (``cop.d2h``).  The copies are requested first, as
         ``jax.device_get`` alone would, so they still follow the program
-        on the device without a round trip through the host."""
+        on the device without a round trip through the host.
+
+        ``probe``: a compacting join's per-device (live rows, capacity
+        they take), fetched with the outputs, in the same round trip; -> (outputs, the largest capacity a device needs),
+        and ``probe_live`` (the live rows of all devices) on the span."""
         with _obs_span("cop.transfer", **attrs):
-            for leaf in jax.tree_util.tree_leaves(out):
+            for leaf in jax.tree_util.tree_leaves((out, probe)):
                 if isinstance(leaf, jax.Array):
                     leaf.copy_to_host_async()
             with _obs_span("cop.device_wait"):
-                jax.block_until_ready(out)
+                jax.block_until_ready((out, probe))
             with _obs_span("cop.d2h"):
-                return jax.device_get(out)
+                if probe is None:
+                    return jax.device_get(out)
+                out, (live, need) = jax.device_get((out, probe))
+            _obs_annotate(probe_live=int(np.sum(live)))
+            return out, int(np.max(need))
+
+    def _fetch_states(self, dag, out, extras: dict):
+        """(a launch's aggregate states on the host; the DAG to rerun the
+        statement with, or None).  Where the program's join compacted
+        its probe rows, what it reports beside its outputs (copr/exec
+        `_compact_probe`) comes in the same fetch, and rows that did not
+        fit mean a rerun (`_uncompacted`)."""
+        if "join_need" not in extras:
+            return self._fetch(out, **self._transfer_attrs()), None
+        states, need = self._fetch(
+            out, (extras["join_live"], extras["join_need"]),
+            **self._transfer_attrs())
+        return states, self._uncompacted(dag, need)
 
     def _note_sched(self, task) -> None:
         if task.cost is not None:
@@ -513,15 +540,21 @@ class CopClient:
         if batches is not None:
             return self._stream_dense_agg(agg, batches, key_meta)
         cols, counts = snap.device_cols(self.mesh)
+        if aux_cols:
+            agg = self._join_form(agg)
         for _ in range(8):
             prog, out = self._launch(agg, cols, counts, tuple(aux_cols))
+            extras = {}
             if prog.has_extras:
                 out, extras = out
                 grown = self._grown_join_dag(agg, extras)
                 if grown is not None:
                     agg = grown
                     continue
-            states = self._fetch(out, **self._transfer_attrs())
+            states, exact = self._fetch_states(agg, out, extras)
+            if exact is not None:
+                agg = exact
+                continue
             # faultline transfer/host-merge seam, keyed by the digest
             _faults.check("transfer", D.dag_digest(agg))
             break
@@ -655,10 +688,37 @@ class CopClient:
         key_cols, agg_cols = finalize_sorted(agg, merged, key_meta)
         return CopResult(agg_cols, key_cols)
 
+    def _join_form(self, dag):
+        """`dag` (of a program that joins), or with its compacting join
+        switched to the exact form where a program of this digest has
+        overflowed before."""
+        if not self._compact_overflowed or D.compacting_join(dag) is None:
+            return dag
+        with self._pf_mu:
+            known = D.dag_digest(dag) in self._compact_overflowed
+        return D.uncompacted(dag) if known else dag
+
+    def _uncompacted(self, dag, need: int) -> Optional[D.CopNode]:
+        """If a device's live probe rows take more than the compacting
+        join's capacity (some are missing from this launch's result),
+        the DAG with every slot looked up, which the statement is rerun
+        with; the digest is remembered (`_join_form`).  None when they
+        fit."""
+        if need <= D.compacting_join(dag).probe_capacity:
+            return None
+        self._scheduler().count("join_compact_overflows")
+        with self._pf_mu:
+            self._compact_overflowed[D.dag_digest(dag)] = None
+            while len(self._compact_overflowed) > self._page_feedback_cap:
+                self._compact_overflowed.popitem(last=False)
+        return D.uncompacted(dag)
+
     def _grown_join_dag(self, dag, extras) -> Optional[D.CopNode]:
         """If the expanding join overflowed its capacity, return the DAG
         rebuilt with a big-enough capacity; None when it fits (the join
         half of the paging grow-from-min discipline)."""
+        if "join_total" not in extras:
+            return None
         need = int(np.max(np.asarray(self._fetch(extras["join_total"]))))
         node = D.find_expand_join(dag)
         if node is not None and need > node.out_capacity:
@@ -697,16 +757,22 @@ class CopClient:
                 agg = hashed_dag
         cap = self._warm_cap(agg, agg.state_capacity
                              or DEFAULT_GROUP_CAPACITY)
+        if aux_cols:
+            agg = self._join_form(agg)
         for _ in range(10):
             sized = self._with_capacity(agg, cap)
             prog, out = self._launch(sized, cols, counts, tuple(aux_cols))
+            extras = {}
             if prog.has_extras:
                 out, extras = out
                 grown = self._grown_join_dag(sized, extras)
                 if grown is not None:
                     agg = grown
                     continue
-            states = self._fetch(out, **self._transfer_attrs())
+            states, exact = self._fetch_states(agg, out, extras)
+            if exact is not None:
+                agg = exact
+                continue
             true_ng = int(np.max(np.asarray(states["__ngroups__"])))
             if true_ng <= cap:
                 sized = self._with_capacity(agg, cap)
