@@ -179,11 +179,12 @@ def _run_twice(c, status_port: int, tag: str, sql: str, want: list) -> dict:
     strategy = next((r[0] for r in c.query("explain " + sql)
                      if r[0].startswith("agg strategy")), None)
     s0 = _sched(status_port)
-    times = []
+    times, regrows = [], []
     for _ in range(2):
         t = time.monotonic()
         rows = c.query(sql)
         times.append((time.monotonic() - t) * 1e3)
+        regrows.append(_sched(status_port).get("hndv_agg_regrows", 0))
         got = _typed(rows, want[0])
         if tag == BCAST_JOIN_TAG:
             got = sorted(got)               # no ORDER BY
@@ -196,6 +197,9 @@ def _run_twice(c, status_port: int, tag: str, sql: str, want: list) -> dict:
     r = {"strategy": strategy, "cold_ms": times[0], "warm_ms": times[1],
          "server_cold_ms": srv_cold, "server_warm_ms": srv_warm,
          "rows": len(rows),
+         # reruns of the warm statement at a larger group table or a
+         # wider sort record: what the first one learned has to hold
+         "warm_regrows": regrows[1] - regrows[0],
          "launches": s1.get("launches", 0) - s0.get("launches", 0),
          # programs launched meanwhile (their dispatch time grew)
          "digests": sorted(k for k, v
@@ -326,6 +330,13 @@ def check_device_path(rep: dict) -> None:
                        "warm run")
     if not rep["results"]["hndv"]["digests"]:
         bad.append("hndv: no program digest gained device time")
+    if not s.get("hndv_agg_launches"):
+        bad.append("hndv_agg_launches = 0: no launch had a high-NDV "
+                   "GROUP BY at its root")
+    for tag, r in rep["results"].items():
+        if r["warm_regrows"]:
+            bad.append(f"{tag}: hndv_agg_regrows moved by "
+                       f"{r['warm_regrows']} in the warm run")
     for k in ("degraded", "oom_recovered"):
         if s["client"][k]:
             bad.append(f"client.{k} = {s['client'][k]}")
@@ -389,7 +400,9 @@ def main(argv=None) -> int:
         "uncacheable=%d breaker=%s" % (
             s["launches"], s["client"]["degraded"],
             s["client"]["oom_recovered"],
-            " ".join(f"{k}={s[k]}" for k in ZERO_COUNTERS),
+            " ".join(f"{k}={s[k]}" for k in ZERO_COUNTERS + (
+                "hndv_agg_launches", "hndv_agg_regrows",
+                "hndv_host_topn_launches")),
             s["compile_cache"]["uncacheable"], s["breaker"]))
     log(f"compile_ms_total={s['compile_cache']['compile_ms']:.1f} "
         f"(programs compiled {s['compile_cache']['misses']}, "

@@ -63,6 +63,10 @@ def test_drive_answers_equal_the_oracle_at_sf001():
                                    "hndv", "topn", "small"}
     assert all(r["rows"] > 0 for r in rep["results"].values())
     assert rep["results"]["hndv"]["rows"] == 10
+    assert chip_smoke.HNDV_SQL == (
+        "select l_partkey, sum(l_quantity) from lineitem "
+        "group by l_partkey order by 2 desc, 1 limit 10")
+    assert all(r["warm_regrows"] == 0 for r in rep["results"].values())
     assert rep["cluster_info"][0][3] == "cpu"
     assert rep["native_hostops"]
     # a CPU mesh answers aggregates from the host engine: the device-path

@@ -1,0 +1,448 @@
+"""The TPU's lowering of a SORT aggregation (copr/runagg), which no
+statement reaches on the CPU mesh (there the host engine answers): traced
+for `Evaluator(jnp, platform="tpu")`, its group tables have to hold what a
+plain reference (tidb_tpu/testing/groupref: sort, `np.add.reduceat`, Python
+ints) finds, in every record form, and keep the contract the host merge
+and the regrow loop rely on."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh
+
+from tidb_tpu.copr import dag as D
+from tidb_tpu.copr import exec as X
+from tidb_tpu.copr import runagg, segment
+from tidb_tpu.copr.aggregate import merge_sorted_states, sum_out_dtype
+from tidb_tpu.expr import ColumnRef
+from tidb_tpu.expr.compile import Evaluator
+from tidb_tpu.parallel import spmd
+from tidb_tpu.parallel.mesh import SHARD_AXIS, sharded
+from tidb_tpu.testing.groupref import group_by
+from tidb_tpu.types import dtypes as dt
+
+I64, I64N = dt.bigint(False), dt.bigint(True)
+SUM, COUNT = D.AggFunc.SUM, D.AggFunc.COUNT
+FORMS = (1, 2, 0)       # `pack_words`: the exact forms and the wide one
+
+
+def _agg(n_keys=1, nullable=True, words=2, cap=1024, topn=None, aggs=None):
+    t = I64N if nullable else I64
+    scan = D.TableScan(tuple(range(n_keys + 1)), (t,) * (n_keys + 1))
+    arg = ColumnRef(t, n_keys)
+    if aggs is None:
+        aggs = (D.AggDesc(SUM, arg, sum_out_dtype(t)),
+                D.AggDesc(COUNT, None, I64), D.AggDesc(COUNT, arg, I64))
+    return D.Aggregation(
+        scan, tuple(ColumnRef(t, j) for j in range(n_keys)), aggs,
+        D.GroupStrategy.SORT, group_capacity=cap, pack_words=words,
+        topn=topn)
+
+
+def _states(agg, cols, sel, stacked=1):
+    """`_agg_partial_states` traced for a TPU -> (states, facts)."""
+    facts = {}
+
+    def fn(cols, sel):
+        batch = X.DeviceBatch(
+            [(v, True if m is None else m) for v, m in cols], sel,
+            stacked=stacked)
+        out = X._agg_partial_states(
+            agg, batch, Evaluator(jnp, platform="tpu"), {})
+        facts.update(batch.facts)
+        return out
+    out = jax.jit(fn)(cols, sel)
+    return jax.tree_util.tree_map(np.asarray, out), facts
+
+
+def _regrown(agg, cols, sel, stacked=1):
+    """As the dispatcher: rerun wider, then larger, until it fits.
+    -> (states, the aggregation that fit, reruns)."""
+    for reruns in range(8):
+        st, _facts = _states(agg, cols, sel, stacked)
+        if "__bits__" in st and st["__bits__"] > 32 * agg.pack_words:
+            agg = dataclasses.replace(
+                agg, pack_words=2 if st["__bits__"] <= 64 else 0)
+        elif st["__ngroups__"] > agg.group_capacity:
+            agg = dataclasses.replace(agg, group_capacity=1 << int(
+                st["__ngroups__"] - 1).bit_length())
+        else:
+            return st, agg, reruns
+    raise AssertionError("did not converge")
+
+
+def _groups(agg, st, duplicates=False) -> dict:
+    """{key tuple: [value an aggregate]} of one table (as the reference's).
+    A key in two slots is an error unless `duplicates`, which adds them
+    up as the host merge does."""
+    out: dict = {}
+    for g in np.nonzero(st["__rows__"] > 0)[0]:
+        key = tuple(st[f"k{j}"]["val"][g].item()
+                    if st[f"k{j}"]["valid"][g] else None
+                    for j in range(len(agg.group_by)))
+        vals = []
+        for i, a in enumerate(agg.aggs):
+            s = st[f"a{i}"]
+            if a.func == COUNT:
+                vals.append(int(s["count"][g]))
+            else:
+                vals.append((int(s["hi"][g]) << 32) + int(s["lo"][g])
+                            if s["cnt"][g] else None)
+        if key in out:
+            assert duplicates, f"group {key} is in two slots"
+            vals = [b if a is None else a if b is None else a + b
+                    for a, b in zip(out[key], vals)]
+        out[key] = vals
+    return out
+
+
+def _want(agg, cols, sel) -> dict:
+    k = len(agg.group_by)
+    return group_by(
+        cols[:k], sel,
+        [(("count", None) if a.arg is None else
+          ("count" if a.func == COUNT else "sum", cols[a.arg.index]))
+         for a in agg.aggs])
+
+
+def _table(n, ndv, seed, n_keys=1, nulls=True, live=0.7, spread=10 ** 5):
+    rng = np.random.default_rng(seed)
+    cols = []
+    for j in range(n_keys):
+        k = rng.integers(-(ndv // 3), ndv - ndv // 3, n) if j == 0 \
+            else rng.integers(0, 3, n)
+        cols.append((k, rng.random(n) > 0.05 if nulls else None))
+    cols.append((rng.integers(-spread // 100, spread, n),
+                 rng.random(n) > 0.2 if nulls else None))
+    return cols, rng.random(n) < live
+
+
+@pytest.mark.parametrize("words", FORMS)
+@pytest.mark.parametrize("n,ndv", [(1000, 1), (1000, 7), (5000, 700),
+                                   (1 << 17, 40000)])
+def test_tables_equal_the_reference(n, ndv, words):
+    """NDV from one group to above `SEGMENT_MIN_NDV`, NULL keys (one
+    group) and NULL arguments, three in ten rows dead."""
+    from tidb_tpu.executor.plan import SEGMENT_MIN_NDV
+    cols, sel = _table(n, ndv, seed=ndv)
+    st, agg, _ = _regrown(_agg(words=words, cap=1 << 16), cols, sel)
+    want = _want(agg, cols, sel)
+    assert _groups(agg, st) == want
+    assert int(st["__ngroups__"]) == len(want)
+    assert ndv < 40000 or len(want) > SEGMENT_MIN_NDV
+
+
+@pytest.mark.parametrize("words", FORMS)
+@pytest.mark.parametrize("live", [0.0, 0.001, 0.5, 1.0])
+def test_every_live_dead_mix(live, words):
+    cols, sel = _table(3000, 50, seed=3, live=live)
+    st, agg, _ = _regrown(_agg(words=words), cols, sel)
+    assert _groups(agg, st) == _want(agg, cols, sel)
+    if not live:
+        assert int(st["__ngroups__"]) == 0 and not st["__rows__"].any()
+
+
+@pytest.mark.parametrize("words", FORMS)
+def test_two_column_keys_one_of_them_null(words):
+    cols, sel = _table(4000, 300, seed=5, n_keys=2)
+    st, agg, _ = _regrown(_agg(n_keys=2, words=words), cols, sel)
+    want = _want(agg, cols, sel)
+    assert _groups(agg, st) == want
+    assert any(k[0] is None for k in want) and any(k[1] is None for k in want)
+
+
+@pytest.mark.parametrize("stacked", [1, 8])
+def test_stacked_shards_and_a_batch_that_is_no_whole_column(stacked):
+    """Eight stacked shards (the sort is fed in the order they lie in a
+    TPU's memory) and a row count no multiple of 128 (padded dead)."""
+    n = 8 * 1024 if stacked == 8 else 1001
+    cols, sel = _table(n, 90, seed=9)
+    st, agg, _ = _regrown(_agg(words=2), cols, sel, stacked)
+    assert _groups(agg, st) == _want(agg, cols, sel)
+
+
+@pytest.mark.parametrize("words", FORMS)
+def test_sums_at_the_ends_of_the_limb_fence(words):
+    """Values at both ends of int64 in one group: the sum passes int64
+    and comes back exact in its two words; the exact forms say the
+    record does not fit and the wide form answers."""
+    top, bot = 2 ** 63 - 1, -(2 ** 63)
+    k = np.array([1, 1, 1, 2, 2, 3, 3, 3] * 16)
+    x = np.array([top, top, top, bot, bot, top, bot, 5] * 16)
+    cols, sel = [(k, None), (x, None)], np.ones(len(k), bool)
+    st, agg, reruns = _regrown(_agg(nullable=False, words=words), cols, sel)
+    assert _groups(agg, st) == {(1,): [48 * top, 48, 48],
+                                (2,): [32 * bot, 32, 32],
+                                (3,): [16 * (top + bot + 5), 48, 48]}
+    assert agg.pack_words == 0 and reruns == (1 if words else 0)
+    # and a sum whose rows all hold the largest value: distances are 0
+    x = np.full(len(k), top)
+    st, agg, _ = _regrown(_agg(nullable=False, words=words),
+                          [(k, None), (x, None)], sel)
+    assert _groups(agg, st)[(1,)] == [48 * top, 48, 48]
+
+
+def test_a_record_that_does_not_fit_says_so_and_is_rerun_wider():
+    """One word holds a dead bit, 10 bits of key and 17 of argument
+    here; a key 2^20 wide takes the second word; one 2^40 wide has its
+    key part pass the first word, which is all the sort compares."""
+    for span, fits in ((1 << 10, 1), (1 << 20, 2), (1 << 40, 0)):
+        rng = np.random.default_rng(1)
+        k = rng.integers(0, 1 << 10, 4000) * (span >> 10)
+        x = rng.integers(0, 1 << 17, 4000)
+        cols, sel = [(k, None), (x, None)], np.ones(4000, bool)
+        st, agg, reruns = _regrown(_agg(nullable=False, words=1, cap=2048),
+                                   cols, sel)
+        assert (agg.pack_words, reruns) == (fits, fits != 1)
+        assert _groups(agg, st) == _want(agg, cols, sel)
+
+
+def test_a_capacity_that_overflows_regrows_once():
+    """`__ngroups__` passes the capacity where groups are missing, by
+    the distinct count or by what the compaction's fullest column takes,
+    and the capacity it names holds them all."""
+    cols, sel = _table(1 << 14, 3000, seed=11)
+    small = _agg(words=2, cap=1024)
+    st, _ = _states(small, cols, sel)
+    want = _want(small, cols, sel)
+    assert int(st["__ngroups__"]) >= len(want) > 1024
+    st, agg, reruns = _regrown(small, cols, sel)
+    assert reruns == 1 and agg.group_capacity >= len(want)
+    assert _groups(agg, st) == want
+
+
+def test_keys_that_collide_in_the_hash_are_never_merged(monkeypatch):
+    """THE CONTRACT: two keys with one hash become partial groups of
+    each (their rows interleave in the sort), never one group: the
+    table's slots still add up to the reference per true key, and the
+    host merge (`merge_sorted_states`) makes each key one group."""
+    real = segment.key_hash
+    monkeypatch.setattr(
+        segment, "key_hash",
+        lambda keyinfo, n: real(
+            [(vz, m, nf, code // 4) for vz, m, nf, code in keyinfo], n))
+    cols, sel = _table(2000, 40, seed=13)
+    st, agg, _ = _regrown(_agg(words=0, cap=4096), cols, sel)
+    want = _want(agg, cols, sel)
+    slots = int((st["__rows__"] > 0).sum())
+    assert slots > len(want), "no collision split a group: test is void"
+    assert int(st["__ngroups__"]) == slots
+    assert _groups(agg, st, duplicates=True) == want
+    merged = merge_sorted_states(agg, [st])
+    assert len(merged["__rows__"]) == len(want)
+    assert int(sum(merged["__rows__"])) == int(sel.sum())
+
+
+@pytest.mark.parametrize("words", (1, 2))
+def test_first_groups_ranked_on_the_device(words):
+    """`Aggregation.topn`: SUM descending (a NULL sum last), then the key
+    ascending (the NULL key first), exactly the reference's first ten;
+    the slots past the groups there are hold none."""
+    cols, sel = _table(5000, 700, seed=17)
+    aggs = _agg().aggs[:2]
+    for limit, desc in ((10, True), (10, False), (1000, True)):
+        topn = D.GroupTopN((("agg", 0, desc), ("key", 0, False)), limit)
+        agg = _agg(words=words, cap=2048, topn=topn, aggs=aggs)
+        st, facts = _states(agg, cols, sel)
+        want = _want(agg, cols, sel)
+
+        def rank(item, desc=desc):
+            (key,), (s, _c) = item
+            null_sum = (s is None) if desc else (s is not None)
+            return (null_sum, -(s or 0) if desc else (s or 0),
+                    key is not None, key or 0)
+        first = sorted(want.items(), key=rank)[:limit]
+        if limit > D.GROUP_TOPN_MAX:        # the host ranks: whole table
+            assert facts["group_topn"] == "host"
+            assert _groups(agg, st) == want
+            continue
+        assert facts["group_topn"] == "device"
+        assert len(st["__rows__"]) == limit
+        assert list(_groups(agg, st).items()) == [(k, v) for k, v in first]
+        assert int(st["__ngroups__"]) == len(want)
+    # fewer groups than the limit
+    few = [(c[0][:40], c[1][:40]) for c in cols]
+    agg = _agg(words=words, cap=128, aggs=aggs, topn=D.GroupTopN(
+        (("agg", 1, True), ("key", 0, True)), 64))
+    st, _ = _states(agg, few, sel[:40])
+    want = _want(agg, few, sel[:40])
+    assert _groups(agg, st) == want and (st["__rows__"] > 0).sum() == len(want)
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_through_the_sharded_program_on_one_and_four_devices(monkeypatch,
+                                                             n_dev):
+    """`ShardedCopProgram` over a mesh traced as for a TPU, two stacked
+    shards a device: the per-device tables, merged by the host, equal
+    the reference; the launch says its strategy and its capacity, and a
+    device that holds every row of its groups ranks them."""
+    mesh = Mesh(np.array(jax.devices()[:n_dev]), (SHARD_AXIS,))
+    monkeypatch.setattr(spmd, "mesh_platform", lambda _mesh: "tpu")
+    s, cap = 2 * n_dev, 1024
+    cols, _sel = _table(s * cap, 500, seed=19)
+    counts = np.array([cap - 7 * i for i in range(s)], np.int64)
+    live = (np.arange(cap)[None, :] < counts[:, None]).reshape(-1)
+
+    def put(a):
+        return jax.device_put(a.reshape(s, cap), sharded(mesh))
+    args = ([(put(v), None if m is None else put(m)) for v, m in cols],
+            jax.device_put(counts, sharded(mesh)))
+    agg = _agg(words=2, cap=2048)
+    try:
+        prog = spmd.ShardedCopProgram(agg, mesh)
+        states = jax.tree_util.tree_map(np.asarray, prog(*args))
+        assert (states["__ngroups__"] <= 2048).all()
+        assert prog.facts(*args) == {"agg_strategy": "sort",
+                                     "group_capacity": 2048}
+        per_dev = [jax.tree_util.tree_map(lambda a, d=d: a[d], states)
+                   for d in range(n_dev)]
+        merged = merge_sorted_states(agg, per_dev)
+        merged = {k: v for k, v in merged.items()}
+        merged["__ngroups__"] = 0
+        assert _groups(agg, merged) == _want(agg, cols, live)
+        if n_dev == 1:
+            ranked = dataclasses.replace(agg, topn=D.GroupTopN(
+                (("agg", 1, True), ("key", 0, False)), 5))
+            prog = spmd.ShardedCopProgram(ranked, mesh)
+            top = jax.tree_util.tree_map(lambda a: np.asarray(a)[0],
+                                         prog(*args))
+            assert prog.facts(*args)["group_topn"] == "device"
+            want = sorted(_want(agg, cols, live).items(), key=lambda kv: (
+                -kv[1][1], kv[0][0] is not None, kv[0][0] or 0))[:5]
+            assert list(_groups(ranked, top).items()) == want
+    finally:
+        spmd._cached.cache_clear()
+
+
+QTY_SQL = ("select l_partkey, sum(l_quantity) from lineitem "
+           "group by l_partkey order by 2 desc, 1 limit 10")
+REV_SQL = ("select l_partkey, sum(l_extendedprice * (1 - l_discount)) as "
+           "revenue, count(*) from lineitem where l_shipdate >= date "
+           "'1994-01-01' and l_shipdate < date '1994-01-01' + interval '1' "
+           "year group by l_partkey order by revenue desc, l_partkey "
+           "limit 10")
+
+
+@pytest.mark.parametrize("sql,words", [(QTY_SQL, 1), (REV_SQL, 2)])
+@pytest.mark.parametrize("n_dev", [1, 8])
+def test_whole_statements_through_the_normal_path(monkeypatch, sql, words,
+                                                  n_dev):
+    """Both statement classes of `tpch1x1.hndv`, SQL text to rows, over
+    a mesh traced as for a TPU: the answer is the host engine's; one
+    launch a statement; `/sched` counts it, no regrow, and the host
+    ranks only where a group's rows lie on several devices; the span
+    says strategy, capacity and where the groups were ranked, and
+    `cop.transfer` the groups found; EXPLAIN says what will run."""
+    from tidb_tpu.parallel import get_mesh
+    from tidb_tpu.sched import scheduler_for
+    from tidb_tpu.testing.tpch import tpch_plan_session
+    want = tpch_plan_session(0.002).execute(sql).rows
+
+    sess = tpch_plan_session(0.002)
+    dom = sess.domain
+    mesh = get_mesh(n_dev)
+    dom.client.mesh = mesh
+    dom.client._platform = lambda: "tpu"    # the device path
+    monkeypatch.setattr(spmd, "mesh_platform", lambda _mesh: "tpu")
+    sess.execute("set global tidb_tpu_trace_sample = 1")
+    sess.execute("set global tidb_tpu_result_cache_entries = 0")
+    sess.execute("analyze table lineitem")
+    spmd._cached.cache_clear()
+    sched = scheduler_for(mesh)
+    names = ("launches", "hndv_agg_launches", "hndv_agg_regrows",
+             "hndv_host_topn_launches")
+    where = "device" if n_dev == 1 else "host"
+    try:
+        footer = [r[0] for r in sess.execute("explain " + sql).rows
+                  if r[0].startswith("agg strategy")]
+        before = sched.stats()
+        got = [sess.execute(sql).rows for _ in range(2)]
+        after = sched.stats()
+    finally:
+        spmd._cached.cache_clear()
+    assert got == [want, want]
+    assert footer == [
+        f"agg strategy: sort (capacity 1024; one sort of {words}-word "
+        f"records, first 10 groups ranked on the {where})"]
+    assert [after[k] - before[k] for k in names] \
+        == [2, 2, 0, 2 * (n_dev > 1)]
+    spans = [sp for ent in dom.flight_recorder.index()
+             for sp in dom.flight_recorder.get(ent["trace_id"]).spans]
+    launches = [sp.attrs for sp in spans if sp.name == "sched.launch"
+                and "_agg_sort_" in sp.attrs.get("program", "")]
+    assert len(launches) == 2
+    assert all((a["agg_strategy"], a["group_capacity"], a["group_topn"])
+               == ("sort", 1024, where) for a in launches)
+    found = [sp.attrs["ngroups"] for sp in spans
+             if sp.name == "cop.transfer" and "ngroups" in sp.attrs]
+    assert len(found) == 2 and found[0] >= 300 * (1 if words == 2 else 1)
+
+
+def test_the_planner_keeps_its_choice_on_a_cpu_mesh():
+    """No program is lowered for a TPU here, so nothing of this changes
+    a plan: no record words, the old strategies by their old rule."""
+    from tidb_tpu.testing.tpch import tpch_plan_session
+    sess = tpch_plan_session(0.002)
+    sess.execute("analyze table lineitem")
+    footer = [r[0] for r in sess.execute("explain " + QTY_SQL).rows
+              if r[0].startswith("agg strategy")]
+    assert footer == ["agg strategy: sort (capacity 1024)"]
+
+
+def test_run_form_is_counts_and_integer_sums():
+    arg = ColumnRef(dt.double(), 1)
+    assert runagg.run_form(_agg())
+    assert not runagg.run_form(_agg(aggs=(
+        D.AggDesc(D.AggFunc.MIN, ColumnRef(I64, 1), I64),)))
+    assert not runagg.run_form(_agg(aggs=(
+        D.AggDesc(SUM, arg, dt.double()),)))
+
+
+@pytest.mark.parametrize("change,ok", [
+    ({}, True),
+    ({"pack_words": 3}, False),
+    ({"strategy": D.GroupStrategy.SEGMENT, "num_buckets": 1024,
+      "pack_words": 1}, False),
+    ({"topn": D.GroupTopN((("agg", 0, True), ("key", 0, False)), 10)}, True),
+    ({"topn": D.GroupTopN((("agg", 5, True),), 10)}, False),
+    ({"topn": D.GroupTopN((("key", 0, True),), 0)}, False),
+])
+def test_the_contract_of_the_new_fields(change, ok):
+    """`pack_words` is a SORT record's and 0, 1 or 2; `topn` names keys
+    and COUNTs or SUMs of its own aggregation.  And a SORT aggregation
+    with an exact record has no fusion class: every member sorts its own
+    records, so a fused program only compiles them again."""
+    from tidb_tpu.analysis.contracts import (PlanContractError,
+                                             fusion_signature, verify_dag)
+    agg = dataclasses.replace(_agg(words=1, nullable=False), **change)
+    if ok:
+        verify_dag(agg)
+        assert fusion_signature(agg) is None
+        assert fusion_signature(dataclasses.replace(agg, pack_words=0)) \
+            == ("sort-agg", 1024)
+    else:
+        with pytest.raises(PlanContractError):
+            verify_dag(agg)
+
+
+def test_programs_that_use_neither_field_keep_their_names():
+    """`pack_words` and `topn` came after programs were named by their
+    DAG's digest (`dag.DIGEST_IF_SET`): at their defaults they are no
+    part of it, so a DENSE or SCALAR program's name, and its place in
+    every compile cache, is what the commit before them gave (the
+    literals are that commit's)."""
+    from tidb_tpu.analysis.compilekey import stable_digest
+    scan = D.TableScan((0, 1), (I64, I64))
+    aggs = (D.AggDesc(SUM, ColumnRef(I64, 1), sum_out_dtype(I64)),)
+    dense = D.Aggregation(scan, (ColumnRef(I64, 0),), aggs,
+                          D.GroupStrategy.DENSE, domain_sizes=(6,))
+    sort = D.Aggregation(scan, (ColumnRef(I64, 0),), aggs,
+                         D.GroupStrategy.SORT, group_capacity=1024)
+    assert stable_digest(dense) == "256d398a896ddb8d"
+    assert stable_digest(sort) == "49a8ced7c75f597f"
+    assert len({stable_digest(sort), stable_digest(dataclasses.replace(
+        sort, pack_words=1)), stable_digest(dataclasses.replace(
+            sort, topn=D.GroupTopN((("key", 0, False),), 3)))}) == 3

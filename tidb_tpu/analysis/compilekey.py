@@ -89,6 +89,9 @@ def _encode(obj, h, skip_capacity: bool) -> None:
         for f in dataclasses.fields(obj):
             if skip_capacity and f.name in _CAPACITY_FIELDS:
                 continue
+            if f.metadata.get("digest") == "if_set" \
+                    and getattr(obj, f.name) == f.default:
+                continue    # dag.DIGEST_IF_SET: older programs keep names
             h.update(b"." + f.name.encode())
             _encode(getattr(obj, f.name), h, skip_capacity)
     else:

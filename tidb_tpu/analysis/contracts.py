@@ -279,6 +279,29 @@ def _verify_dag(node: D.CopNode, path) -> None:
                       "space")
             if node.prehashed:
                 _verify_prehashed(node, schema, p)
+        if node.pack_words not in ((0, 1, 2) if node.strategy
+                                   == D.GroupStrategy.SORT else (0,)):
+            _fail("capacity-shape", p,
+                  f"pack_words {node.pack_words} on a "
+                  f"{node.strategy.value} aggregation: a SORT record is "
+                  "one or two words, or wide (0)")
+        if node.topn is not None:
+            # which groups the consumer keeps: read by the lowering of a
+            # host-merged table alone, over its own keys and aggregates
+            if node.strategy not in D.HOST_MERGE_STRATEGIES:
+                _fail("capacity-shape", p,
+                      f"topn on a {node.strategy.value} aggregation")
+            for kind, i, _desc in node.topn.keys:
+                n = len(node.group_by if kind == "key" else node.aggs)
+                if kind not in ("key", "agg") or not 0 <= i < n or (
+                        kind == "agg" and node.aggs[i].func not in (
+                            D.AggFunc.COUNT, D.AggFunc.SUM)):
+                    _fail("capacity-shape", p,
+                          f"topn key ({kind}, {i}) names no group key "
+                          "and no COUNT or SUM of this aggregation")
+            if node.topn.limit <= 0:
+                _fail("capacity-shape", p,
+                      f"topn limit {node.topn.limit}")
         if node.prehashed and node.strategy not in D.RADIX_STRATEGIES:
             _fail("capacity-shape", p,
                   f"prehashed set on a {node.strategy.value} "
@@ -685,7 +708,13 @@ def fusion_signature(dag: D.CopNode) -> Optional[tuple]:
       client's regrow discipline only ever produces pow2 capacities,
       so regrow-sized tasks land in shared classes instead of none).
       Capacity 0 (planner left sizing to the client) or a non-pow2
-      capacity has no static shape class and stays unfusable.
+      capacity has no static shape class and stays unfusable.  So does
+      one with an exact sort record (`pack_words`, a TPU's planner
+      alone sets it: copr/runagg): every member sorts its own records,
+      so a fused program shares a scan of under a millisecond, takes
+      the members' 25 ms each all the same, and compiles every sort
+      again, tens of seconds a member in the serving path's
+      background (PERF.md section 6, PR 29).
     - ``('rows',)`` — an extras-free pure scan chain returning rows
       (fusion-breadth follow-on): members fuse with per-member output
       capacities (spmd.FusedRowsProgram)."""
@@ -701,7 +730,7 @@ def fusion_signature(dag: D.CopNode) -> Optional[tuple]:
         return None
     if dag.strategy == D.GroupStrategy.SORT:
         cap = dag.group_capacity
-        if cap <= 0 or (cap & (cap - 1)) != 0:
+        if cap <= 0 or (cap & (cap - 1)) != 0 or dag.pack_words:
             return None     # no static shape class to share
     try:
         verify_dag(dag)

@@ -639,6 +639,27 @@ def prove_narrow_sums(agg: D.Aggregation, table, handle) -> tuple:
     return tuple(proved)
 
 
+def observed_spans(child: D.CopNode, exprs, table, handle) -> Optional[list]:
+    """Per expression over `child`'s output, hi - lo of its flowed
+    interval where ANALYZE observed both ends of every column it reads
+    (the width a value's distance from the least takes); None where
+    statistics are absent or any of them is not so observed.  A guess
+    for a planner to size by (executor/plan `_pack_words`), never a
+    proof: what relies on it checks on the device."""
+    scan = _scan_of(child)
+    seed = scan_stats_env(scan, table, handle) if scan is not None else ()
+    if not seed:
+        return None
+    try:
+        env, _rows = _flow_cached(child, seed, 1, False, ("narrow",))
+        ivs = [expr_interval(e, env, ("narrow",)) for e in exprs]
+    except PlanContractError:
+        return None
+    if any(iv is None or not iv.proven for iv in ivs):
+        return None
+    return [iv.hi - iv.lo for iv in ivs]
+
+
 # ------------------------------------------------------------------ #
 # per-digest proof registry (plan-verify time -> sched submit time)
 # ------------------------------------------------------------------ #

@@ -93,17 +93,19 @@ def _np_key_code(val: np.ndarray, valid: np.ndarray,
 
 def merge_sorted_states(agg: D.Aggregation,
                         per_dev: Sequence[dict]) -> dict:
-    """Merge SORT-strategy per-device group tables: trim each to its live
-    group count, concatenate, and re-group by key equality (np.unique) —
-    the root-side final-HashAgg-worker role for unbounded key domains.
-    Sums merge in object ints (exact)."""
+    """Merge SORT-strategy per-device group tables: keep each one's slots
+    that hold a group (`__rows__` > 0: a table's groups need not lie at
+    its front, copr/runagg), concatenate, and re-group by key equality
+    (np.unique) — the root-side final-HashAgg-worker role for unbounded
+    key domains.  Sums merge in object ints (exact)."""
     k = len(agg.group_by)
     tables: list[dict] = []
     for st in per_dev:
-        g = int(st["__ngroups__"])
-        trimmed = {name: {f: np.asarray(a)[:g] for f, a in v.items()}
-                   if isinstance(v, dict) else np.asarray(v)[:g]
-                   for name, v in st.items() if name != "__ngroups__"}
+        held = np.nonzero(np.asarray(st["__rows__"]) > 0)[0]
+        trimmed = {name: {f: np.asarray(a)[held] for f, a in v.items()}
+                   if isinstance(v, dict) else np.asarray(v)[held]
+                   for name, v in st.items()
+                   if name not in ("__ngroups__", "__bits__")}
         tables.append(trimmed)
 
     def cat(path):

@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import enum
 import functools
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Optional, Tuple
 
 from ..expr.ir import Expr
@@ -176,6 +176,33 @@ def radix_passes(num_buckets: int) -> int:
     return -(-(log2b + RADIX_RESIDUAL_BITS + 1) // RADIX_BITS)
 
 
+# metadata of a node's field that came after programs were named by their
+# DAG's digest: at its default it is no part of `compilekey.stable_digest`,
+# so every program that does not use it keeps the name it had
+DIGEST_IF_SET = {"digest": "if_set"}
+
+# the most groups a device ranks for a `GroupTopN` (a reduce a group:
+# copr/runagg._first_groups); a longer LIMIT is ranked by the host
+GROUP_TOPN_MAX = 64
+
+
+@dataclass(frozen=True)
+class GroupTopN:
+    """What the consumer of a host-merged aggregation will keep of its
+    groups: the first `limit` in the order `keys` give, ("key", j, desc)
+    a group key and ("agg", i, desc) a COUNT's or a SUM's value, MySQL's
+    NULL order (first ascending, last descending).  The planner writes
+    it where a TopN sits above the aggregation and reads nothing else
+    (`executor/plan._push_group_topn`); the host still ranks what comes
+    back.  `on_device`: the device's groups are whole (no group has rows
+    on another device or in another batch), so it may rank its table and
+    send the first `limit` groups alone; the dispatcher clears it where
+    they are not (`store/client`)."""
+    keys: Tuple = ()
+    limit: int = 0
+    on_device: bool = True
+
+
 @dataclass(frozen=True)
 class Aggregation(CopNode):
     """Partial (per-shard) hash aggregation.
@@ -204,6 +231,12 @@ class Aggregation(CopNode):
     never escape int64 across the whole table — those states accumulate
     a single int64 word instead of (hi, lo) limbs.  Part of the frozen
     hash, so narrow and limb programs key, cache, and fuse apart.
+    `pack_words` (SORT, read by the TPU's lowering alone: copr/runagg):
+    the 32-bit words of a row's sort record in the exact form, 1 or 2;
+    0 is the wide form, which always fits.  The planner's guess from the
+    columns' statistics; a launch whose record did not fit says the bits
+    it takes (`__bits__`) and the dispatcher reruns the statement wider.
+    `topn` (host-merged strategies): see `GroupTopN`.
     """
     child: CopNode = None  # type: ignore[assignment]
     group_by: Tuple[Expr, ...] = ()
@@ -217,6 +250,10 @@ class Aggregation(CopNode):
                                          # is the hoisted int64 key hash
     narrow_sums: Tuple[int, ...] = ()    # SCALAR/DENSE: agg indexes with a
                                          # valueflow-proven single-word SUM
+    pack_words: int = field(             # SORT: words of the exact record
+        default=0, metadata=DIGEST_IF_SET)
+    topn: Optional[GroupTopN] = field(   # the groups the consumer keeps
+        default=None, metadata=DIGEST_IF_SET)
 
     def children(self):
         return (self.child,)
@@ -234,6 +271,16 @@ class Aggregation(CopNode):
         return (self.num_buckets
                 if self.strategy in RADIX_STRATEGIES
                 else self.group_capacity)
+
+
+def wide_groups(agg: Aggregation) -> Aggregation:
+    """`agg` for a dispatcher that reruns nothing for a record that did
+    not fit and whose launches do not hold a group's rows whole (a
+    streamed batch, an exchange's output): the wide record, which always
+    fits, and its groups ranked by the host."""
+    import dataclasses
+    topn = agg.topn and dataclasses.replace(agg.topn, on_device=False)
+    return dataclasses.replace(agg, pack_words=0, topn=topn)
 
 
 @dataclass(frozen=True)

@@ -278,7 +278,9 @@ def _agg_partial_states(agg: D.Aggregation, batch: DeviceBatch, ev: Evaluator,
     a fixed-capacity group table (host merge across shards) — the TPU
     answer to the reference's high-NDV parallel HashAgg
     (pkg/executor/aggregate/agg_hash_executor.go:94); hash tables lose to
-    sort+segment ops on TPU (SURVEY.md §7 hard part 4).
+    sort+segment ops on TPU (SURVEY.md §7 hard part 4).  On a TPU its
+    COUNTs and integer SUMs are lowered by copr/runagg (the aggregates'
+    inputs travel with the sort; no gather or scatter a slot).
     SEGMENT: the high-NDV refinement — keys avalanche-hash into one
     uint64 radix space, a SINGLE-key partition pass buckets rows, and
     each bucket's runs segment-reduce (copr/segment.py).
@@ -288,6 +290,12 @@ def _agg_partial_states(agg: D.Aggregation, batch: DeviceBatch, ev: Evaluator,
     (copr/radix.py).
     Adds '__rows__' (COUNT(*) per group) for occupancy.
     """
+    if agg.strategy in D.HOST_MERGE_STRATEGIES:
+        # what the launch says of itself (copr/facts.py)
+        batch.facts["agg_strategy"] = agg.strategy.value
+        batch.facts["group_capacity"] = agg.state_capacity
+        if agg.topn is not None:    # copr/runagg alone ranks on the device
+            batch.facts["group_topn"] = "host"
     if agg.strategy == D.GroupStrategy.SCATTER:
         from .radix import agg_scatter_states
         return agg_scatter_states(agg, batch, ev, memo)
@@ -295,6 +303,9 @@ def _agg_partial_states(agg: D.Aggregation, batch: DeviceBatch, ev: Evaluator,
         from .segment import agg_segment_states
         return agg_segment_states(agg, batch, ev, memo)
     if agg.strategy == D.GroupStrategy.SORT:
+        from .runagg import agg_run_states, run_form
+        if ev.platform == "tpu" and run_form(agg):
+            return agg_run_states(agg, batch, ev, memo)
         return _agg_sort_states(agg, batch, ev, memo)
 
     n = len(batch.cols[0][0]) if batch.cols else 0
@@ -834,6 +845,21 @@ def _sort_lanes(lanes: Sequence) -> tuple:
     return lax.sort(tuple(lanes), num_keys=len(lanes), is_stable=False)
 
 
+def _lex_smaller(a, b):
+    """Of two lane tuples the lexicographically smaller: the comparator
+    of a variadic `lax.reduce` that takes a minimum tuple."""
+    lt = False
+    for x, y in zip(reversed(a), reversed(b)):
+        lt = (x < y) | ((x == y) & lt)
+    return tuple(jnp.where(lt, x, y) for x, y in zip(a, b))
+
+
+def _lane_tops(lanes: Sequence) -> tuple:
+    """That reduce's initial values: every lane's largest."""
+    return tuple(jnp.asarray(_max_of(lane.dtype), lane.dtype)
+                 for lane in lanes)
+
+
 def _block_minima(lanes: Sequence) -> list:
     """Per block of the (stacked, M, rows) views (`topn_head`), the
     lexicographic minimum of the lane tuple: ONE variadic reduction
@@ -842,15 +868,8 @@ def _block_minima(lanes: Sequence) -> list:
     out.  (On a v5e it streams at the HBM roofline; a cascade of plain
     masked minima, lane by lane, re-reads its sources and was 1.5x
     slower — PERF.md section 6, PR 24.)"""
-    def smaller(a, b):
-        lt = False
-        for x, y in zip(reversed(a), reversed(b)):
-            lt = (x < y) | ((x == y) & lt)
-        return tuple(jnp.where(lt, x, y) for x, y in zip(a, b))
-
-    tops = tuple(jnp.asarray(_max_of(lane.dtype), lane.dtype)
-                 for lane in lanes)
-    return list(lax.reduce(tuple(lanes), tops, smaller, (0, 2)))
+    return list(lax.reduce(tuple(lanes), _lane_tops(lanes), _lex_smaller,
+                           (0, 2)))
 
 
 def topn_head(lanes_of, n: int, k: int, block_len: int,

@@ -23,6 +23,15 @@ def _present(_value) -> bool:
     return True
 
 
+def _first(values):
+    return values[0]
+
+
+def _host_merged(root) -> bool:
+    return isinstance(root, D.Aggregation) \
+        and root.strategy in D.HOST_MERGE_STRATEGIES
+
+
 @dataclass(frozen=True)
 class Fact:
     """counters: (`/sched` counter, test of the value) pairs: a launch of
@@ -67,14 +76,26 @@ FACTS = {
     # every slot)
     "probe_capacity": Fact(
         counters=(("join_compact_launches", lambda c: c > 0),)),
+    # copr/runagg, a TPU's lowering of a SORT aggregation root: the
+    # strategy's name, the table's slots a device, and where the groups
+    # a TopN above it keeps are ranked, "device" | "host" (absent: none
+    # is above it).  "host": the table came back whole for its sake
+    "agg_strategy": Fact(counters=(("hndv_agg_launches", _present),),
+                         merge=_first, on_span=_present, root=_host_merged),
+    "group_capacity": Fact(on_span=_present, root=_host_merged),
+    "group_topn": Fact(
+        counters=(("hndv_host_topn_launches", lambda w: w == "host"),),
+        merge=_first, on_span=_present, root=_host_merged),
 }
 
 # counted by name (`DeviceScheduler.count`) by whoever sees it happen:
 # no launch carries these
 # (`join_compact_overflows`: a compacting join found more live rows than
-# its capacity and the statement was rerun uncompacted)
+# its capacity and the statement was rerun uncompacted;
+# `hndv_agg_regrows`: a host-merged aggregation was rerun with a larger
+# table or a wider record)
 EVENTS = ("join_shuffle_launches", "join_host_fallbacks", "join_regrows",
-          "join_compact_overflows")
+          "join_compact_overflows", "hndv_agg_regrows")
 
 
 def counter_names() -> tuple:

@@ -182,47 +182,64 @@ def _field_bits(v, m, part):
     return u if bits >= 32 else u & jnp.uint32((1 << bits) - 1)
 
 
-def gather_rows(cols: Sequence, rows, stacked: int = 1) -> list:
-    """`cols` [(value, mask | True)] at the places `rows` (`live_rows`:
-    indices into the slots in `_tile_order`, in bounds).  The columns
-    are packed into as few uint32 words a row as their dtypes take (a
-    validity mask is one bit), the words stacked (n, W) and gathered
-    ONCE: a gather on a TPU costs its indices, not its bytes.  (Every
-    column of the probe child is packed: the planner has pruned the scan
-    to the columns the statement reads, and Q14's and Q19's programs are
-    the same bytes with the unread ones left out.)"""
-    def at(x):
-        return _tile_order(x, stacked).at[rows].get(
-            mode="promise_in_bounds")
+def pack_rows(cols: Sequence) -> tuple:
+    """`cols` [(value, mask | True)] as (words, apart, unpack): as few
+    uint32 words a row as their dtypes take (`_word_layout`; a validity
+    mask is one bit); `apart`, the columns with no 32-bit view; and
+    `unpack(words, apart_col)`, which reads [(value, mask | True)] back
+    out of the words, wherever their rows have gone since (a gather, a
+    sort), with `apart_col(i)` giving column i of `apart` there."""
     placed, n_words, apart = _word_layout(cols)
     words = [jnp.uint32(0)] * n_words
     for i, part, _bits, w, shift in placed:
         words[w] = words[w] | (_field_bits(*cols[i], part) << shift)
+
+    def unpack(got, apart_col=None) -> list:
+        parts = {}
+        for i, part, bits, w, shift in placed:
+            f = got[w] >> shift
+            parts[i, part] = f if bits == 32 \
+                else f & jnp.uint32((1 << bits) - 1)
+        out = []
+        for i, (v, m) in enumerate(cols):
+            if i in apart:
+                out.append(apart_col(i))
+                continue
+            if v.dtype.itemsize == 8:
+                gv = ((parts[i, "hi"].astype(jnp.int64) << 32)
+                      | parts[i, "lo"].astype(jnp.int64)).astype(v.dtype)
+            elif v.dtype == jnp.float32:
+                gv = lax.bitcast_convert_type(parts[i, "v"], jnp.float32)
+            else:
+                gv = parts[i, "v"].astype(v.dtype)      # wraps: sign restored
+            out.append((gv, True if m is True
+                        else parts[i, "m"].astype(bool)))
+        return out
+    return words, apart, unpack
+
+
+def gather_rows(cols: Sequence, rows, stacked: int = 1) -> list:
+    """`cols` [(value, mask | True)] at the places `rows` (`live_rows`:
+    indices into the slots in `_tile_order`, in bounds).  The columns
+    are packed into as few uint32 words a row as their dtypes take
+    (`pack_rows`), the words stacked (n, W) and gathered ONCE: a gather
+    on a TPU costs its indices, not its bytes.  (Every column of the
+    probe child is packed: the planner has pruned the scan to the
+    columns the statement reads, and Q14's and Q19's programs are the
+    same bytes with the unread ones left out.)"""
+    def at(x):
+        return _tile_order(x, stacked).at[rows].get(
+            mode="promise_in_bounds")
+    words, _apart, unpack = pack_rows(cols)
     got = []
-    if n_words == 1:
+    if len(words) == 1:
         got = [at(words[0])]
     elif words:
         hit = jnp.stack([_tile_order(w, stacked) for w in words],
                         axis=1).at[rows].get(mode="promise_in_bounds")
-        got = [hit[:, w] for w in range(n_words)]
-    parts = {}
-    for i, part, bits, w, shift in placed:
-        f = got[w] >> shift
-        parts[i, part] = f if bits == 32 else f & jnp.uint32((1 << bits) - 1)
-    out = []
-    for i, (v, m) in enumerate(cols):
-        if i in apart:
-            out.append((at(v), True if m is True else at(m)))
-            continue
-        if v.dtype.itemsize == 8:
-            gv = ((parts[i, "hi"].astype(jnp.int64) << 32)
-                  | parts[i, "lo"].astype(jnp.int64)).astype(v.dtype)
-        elif v.dtype == jnp.float32:
-            gv = lax.bitcast_convert_type(parts[i, "v"], jnp.float32)
-        else:
-            gv = parts[i, "v"].astype(v.dtype)      # wraps: sign restored
-        out.append((gv, True if m is True else parts[i, "m"].astype(bool)))
-    return out
+        got = [hit[:, w] for w in range(len(words))]
+    return unpack(got, lambda i: (
+        at(cols[i][0]), True if cols[i][1] is True else at(cols[i][1])))
 
 
 def sorted_lookup(kv, grp):
@@ -314,4 +331,5 @@ def gather_expand(batch_cols, sel, probe_key_ok, build_cols, perm,
 
 
 __all__ = ["direct_lookup", "sorted_lookup", "live_rows", "gather_rows",
+           "pack_rows",
            "match_ranges", "expand_slots", "gather_expand"]

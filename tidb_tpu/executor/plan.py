@@ -137,8 +137,8 @@ def to_physical(p: LogicalPlan, no_device_join: bool = False) -> PhysOp:
     if isinstance(p, LogicalSort):
         return HostSort(to_physical(p.child, ndj), list(p.keys))
     if isinstance(p, LogicalTopN):
-        return HostTopN(to_physical(p.child, ndj), list(p.keys), p.limit,
-                        p.offset)
+        return HostTopN(_push_group_topn(p, to_physical(p.child, ndj)),
+                        list(p.keys), p.limit, p.offset)
     if isinstance(p, LogicalLimit):
         return HostLimit(to_physical(p.child, ndj), p.limit, p.offset)
     if isinstance(p, LogicalSetOp):
@@ -265,6 +265,38 @@ def _try_index_ordered_topn(p) -> Optional[PhysOp]:
                                   out_names=proj.schema.names())
     return None
 
+
+
+def _push_group_topn(top: LogicalTopN, child: PhysOp) -> PhysOp:
+    """`child`, the physical plan under the HostTopN of `top`; where it
+    is a host-merged device aggregation, alone or under one projection,
+    and every ORDER BY key is one of its group keys or a COUNT's or
+    SUM's value, its DAG says which groups the statement keeps
+    (`dag.GroupTopN`), so a device whose groups are whole sends those
+    and not its table.  The HostTopN stays: it ranks what comes back."""
+    import dataclasses
+    proj = child if isinstance(child, HostProjection) else None
+    cop = proj.child if proj is not None else child
+    dag = getattr(cop, "dag", None)
+    if not isinstance(cop, CopTaskExec) or not isinstance(dag, D.Aggregation) \
+            or dag.strategy not in D.HOST_MERGE_STRATEGIES:
+        return child
+    keys = []
+    for e, desc in top.keys:
+        if proj is not None and isinstance(e, ColumnRef):
+            e = proj.exprs[e.index]
+        if not isinstance(e, ColumnRef):
+            return child
+        i = e.index - len(dag.group_by)
+        if i < 0:
+            keys.append(("key", e.index, bool(desc)))
+        elif dag.aggs[i].func in (D.AggFunc.COUNT, D.AggFunc.SUM):
+            keys.append(("agg", i, bool(desc)))
+        else:
+            return child
+    cop.dag = dataclasses.replace(dag, topn=D.GroupTopN(
+        tuple(keys), top.limit + top.offset))
+    return child
 
 
 def _scan_device_ok(ds) -> bool:
@@ -1376,23 +1408,77 @@ def _bind_agg(agg: LogicalAggregate, child: D.CopNode, dicts,
     cap = _ndv_capacity(agg, ds)
     if cap == 0 and known_total:
         cap = _cap_pow2(known_total)
+    sort = D.Aggregation(child, tuple(lowered), tuple(descs),
+                         D.GroupStrategy.SORT, group_capacity=cap)
+    from ..copr.runagg import run_form
+    if _mesh_platform() == "tpu" and run_form(sort):
+        # a TPU reduces the runs of ONE sort whose records carry what
+        # the aggregates read (copr/runagg), whatever the NDV: SORT, in
+        # as few words as the columns' statistics say a record takes.
+        # Nothing is priced: the other forms gather and scatter a slot
+        # (seconds a statement at 2^23 slots: PERF.md section 6, PR 29)
+        import dataclasses
+        return dataclasses.replace(
+            sort, pack_words=_pack_words(sort, ds))
     if cap >= SEGMENT_MIN_NDV:
         candidates = (
             D.Aggregation(child, tuple(lowered), tuple(descs),
                           D.GroupStrategy.SCATTER, num_buckets=cap),
             D.Aggregation(child, tuple(lowered), tuple(descs),
                           D.GroupStrategy.SEGMENT, num_buckets=cap),
-            D.Aggregation(child, tuple(lowered), tuple(descs),
-                          D.GroupStrategy.SORT, group_capacity=cap),
+            sort,
         )
         return _arbitrate_strategy(candidates, ds)
-    return D.Aggregation(child, tuple(lowered), tuple(descs),
-                         D.GroupStrategy.SORT,
-                         group_capacity=cap)
+    return sort
 
 
-# plan-time device count for strategy arbitration: the same 8-vdev
-# convention every plan-level copcost consumer uses (plan_cost default)
+def _mesh_platform() -> str:
+    """The platform of the mesh the programs of this process run on
+    (what `parallel/spmd` traces their lowerings for)."""
+    from ..parallel import get_mesh, spmd
+    return spmd.mesh_platform(get_mesh())
+
+
+def _pack_words(agg: D.Aggregation, ds) -> int:
+    """`dag.Aggregation.pack_words` for a SORT aggregation on a TPU: the
+    32-bit words the exact record of copr/runagg takes, from the
+    intervals ANALYZE observed for the columns its keys and SUMs read
+    (`analysis/valueflow`): 1 or 2, or 0 (the wide form) where the key
+    part passes a word, the record two, or nothing is known.  A guess:
+    the device works the layout out from the values it holds and a
+    record that does not fit is rerun wider (`store/client`)."""
+    handle = STATS_HANDLE.get()
+    if handle is None or ds is None:
+        return 0
+    from ..analysis import valueflow
+    sums = [a.arg for a in agg.aggs if a.func == D.AggFunc.SUM]
+    try:
+        spans = valueflow.observed_spans(
+            agg.child, list(agg.group_by) + sums, ds.table, handle)
+        # a NULL bit rides only beside a value that has a mask on the
+        # device: none does where no column scanned held a NULL
+        ts, names = handle.get(ds.table), ds.table.col_names
+        nulls = any(ts.col(names[off]) is None
+                    or ts.col(names[off]).null_count > 0
+                    for off in valueflow._scan_of(agg.child).col_offsets)
+    except (AttributeError, TypeError, ValueError, IndexError):
+        return 0
+    if spans is None:
+        return 0
+    null_bits = [nulls and e.dtype.nullable for e in list(agg.group_by)
+                 + [a.arg for a in agg.aggs if a.arg is not None]]
+    k = len(agg.group_by)
+    key = 1 + sum(int(s).bit_length() for s in spans[:k]) + sum(null_bits[:k])
+    rest = sum(int(s).bit_length() for s in spans[k:]) + sum(null_bits[k:])
+    if key > 32 or key + rest > 64:
+        return 0
+    return 1 if key + rest <= 32 else 2
+
+
+# device count the arbitration prices the CPU mesh's candidates for: the
+# 8-vdev convention every plan-level copcost consumer uses (plan_cost
+# default).  A TPU's choice is not priced (`_bind_agg`); what it reads
+# of the mesh, it reads of the program's (`_mesh_platform`)
 _ARBITRATE_DEVICES = 8
 
 

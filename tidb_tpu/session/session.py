@@ -1483,6 +1483,24 @@ class Session:
         except (AttributeError, TypeError, ValueError, ImportError):
             return None
 
+    def _run_form_footer(self, dag) -> str:
+        """What this server's mesh makes of a SORT aggregation: on a
+        TPU, copr/runagg's form and where the groups are ranked."""
+        from ..copr import dag as Dg
+        from ..copr.runagg import run_form
+        from ..executor.plan import _mesh_platform
+        if _mesh_platform() != "tpu" or not run_form(dag):
+            return ""
+        out = "; one sort of " + (f"{dag.pack_words}-word records"
+                                  if dag.pack_words else "hashed records")
+        if dag.topn is not None:
+            on_device = dag.pack_words and dag.topn.on_device \
+                and dag.topn.limit <= Dg.GROUP_TOPN_MAX \
+                and self.domain.client.mesh.devices.size == 1
+            out += (f", first {dag.topn.limit} groups ranked on the "
+                    + ("device" if on_device else "host"))
+        return out
+
     def _agg_strategy_footer(self, phys) -> Optional[str]:
         """EXPLAIN ``agg strategy:`` tag: which device group-by strategy
         the pushed aggregation takes, with its capacity knob — dense
@@ -1508,7 +1526,8 @@ class Session:
                                 f"({dag.num_buckets} buckets)")
                     if dag.strategy is Dg.GroupStrategy.SORT:
                         return (f"agg strategy: sort (capacity "
-                                f"{dag.group_capacity or 'auto'})")
+                                f"{dag.group_capacity or 'auto'}"
+                                f"{self._run_form_footer(dag)})")
                     return (f"agg strategy: dense "
                             f"({dag.num_groups} groups)")
                 for c in getattr(op, "children", []) or []:

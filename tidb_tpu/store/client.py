@@ -101,6 +101,10 @@ class CopClient:
         # with that digest launches the exact form at once.  Guarded by
         # _pf_mu, LRU-capped like the paging feedback.
         self._compact_overflowed: OrderedDict[int, None] = OrderedDict()
+        # SORT aggregations whose exact record took more words than the
+        # planner guessed (`_wider_record`): digest -> the words the next
+        # statement starts with.  Guarded by _pf_mu, capped alike.
+        self._record_words: OrderedDict[int, int] = OrderedDict()
         # coprocessor RESULT cache (copr/coprocessor_cache.go analog):
         # key = (dag digest, snapshot epoch, placement epoch, shard
         # layout); a table write creates a new snapshot + epoch, so stale
@@ -298,7 +302,8 @@ class CopClient:
 
         ``probe``: a compacting join's per-device (live rows, capacity
         they take), fetched with the outputs, in the same round trip; -> (outputs, the largest capacity a device needs),
-        and ``probe_live`` (the live rows of all devices) on the span."""
+        and ``probe_live`` (the live rows of all devices) on the span.
+        The states of a host-merged aggregation put ``ngroups`` there."""
         with _obs_span("cop.transfer", **attrs):
             for leaf in jax.tree_util.tree_leaves((out, probe)):
                 if isinstance(leaf, jax.Array):
@@ -306,9 +311,13 @@ class CopClient:
             with _obs_span("cop.device_wait"):
                 jax.block_until_ready((out, probe))
             with _obs_span("cop.d2h"):
-                if probe is None:
-                    return jax.device_get(out)
-                out, (live, need) = jax.device_get((out, probe))
+                out, probe = jax.device_get((out, probe))
+            if isinstance(out, dict) and "__ngroups__" in out:
+                # a host-merged aggregation: the groups the devices found
+                _obs_annotate(ngroups=int(np.sum(out["__ngroups__"])))
+            if probe is None:
+                return out
+            live, need = probe
             _obs_annotate(probe_live=int(np.sum(live)))
             return out, int(np.max(need))
 
@@ -642,6 +651,7 @@ class CopClient:
                                    group_capacity=_pow2_at_least(cap))
 
     def _stream_sort_agg(self, agg, batches, key_meta) -> CopResult:
+        agg = D.wide_groups(agg)    # a group's rows are in many batches
         cap = self._warm_cap(agg, agg.state_capacity
                              or DEFAULT_GROUP_CAPACITY)
         per_dev_all = []
@@ -687,6 +697,50 @@ class CopClient:
         merged = {k: v for k, v in states.items() if k != "__ngroups__"}
         key_cols, agg_cols = finalize_sorted(agg, merged, key_meta)
         return CopResult(agg_cols, key_cols)
+
+    @staticmethod
+    def _record_key(agg: D.Aggregation) -> int:
+        import dataclasses
+        return D.dag_digest(dataclasses.replace(
+            agg, pack_words=0, group_capacity=0))
+
+    def _group_form(self, agg: D.Aggregation) -> D.Aggregation:
+        """`agg` as this mesh launches it: its groups ranked by the host
+        where a group's rows lie on several devices, and its exact
+        record (copr/runagg) as wide as a statement of this digest has
+        needed before."""
+        import dataclasses
+        if agg.topn is not None and agg.topn.on_device \
+                and self.mesh.devices.size > 1:
+            agg = dataclasses.replace(agg, topn=dataclasses.replace(
+                agg.topn, on_device=False))
+        if agg.pack_words and self._record_words:
+            with self._pf_mu:
+                words = self._record_words.get(self._record_key(agg))
+            if words is not None:
+                agg = dataclasses.replace(agg, pack_words=words)
+        return agg
+
+    def _wider_record(self, agg: D.Aggregation,
+                      states) -> Optional[D.Aggregation]:
+        """If a device's exact record took more bits than `agg` has
+        words for (the states are then not the groups'), `agg` with as
+        many as it takes, or the wide form, which the statement is rerun
+        with; the digest is remembered (`_group_form`).  None when it
+        fit."""
+        import dataclasses
+        if "__bits__" not in states:
+            return None
+        bits = int(np.max(np.asarray(states["__bits__"])))
+        if bits <= 32 * agg.pack_words:
+            return None
+        self._scheduler().count("hndv_agg_regrows")
+        words = 2 if bits <= 64 else 0
+        with self._pf_mu:
+            self._record_words[self._record_key(agg)] = words
+            while len(self._record_words) > self._page_feedback_cap:
+                self._record_words.popitem(last=False)
+        return dataclasses.replace(agg, pack_words=words)
 
     def _join_form(self, dag):
         """`dag` (of a program that joins), or with its compacting join
@@ -755,6 +809,7 @@ class CopClient:
                                          program=hprog.name)
                 cols = list(cols) + [(hv, None)]
                 agg = hashed_dag
+        agg = self._group_form(agg)
         cap = self._warm_cap(agg, agg.state_capacity
                              or DEFAULT_GROUP_CAPACITY)
         if aux_cols:
@@ -773,10 +828,15 @@ class CopClient:
             if exact is not None:
                 agg = exact
                 continue
+            wider = self._wider_record(agg, states)
+            if wider is not None:
+                agg = wider
+                continue
             true_ng = int(np.max(np.asarray(states["__ngroups__"])))
             if true_ng <= cap:
                 sized = self._with_capacity(agg, cap)
                 break
+            self._scheduler().count("hndv_agg_regrows")
             cap = self._warm_cap(agg, _pow2_at_least(true_ng))
         else:
             raise RuntimeError("group-capacity regrow did not converge")
@@ -814,10 +874,13 @@ class CopClient:
         rcols, rcounts = rsnap.device_cols(self.mesh)
         caps = self._shuffle_initial_caps(lsnap, rsnap, row_cap)
         agg = spec.top if isinstance(spec.top, D.Aggregation) else None
-        if agg is not None and agg.strategy in D.HOST_MERGE_STRATEGIES \
-                and not agg.state_capacity:
-            spec = dataclasses.replace(spec, top=self._with_capacity(
-                agg, DEFAULT_GROUP_CAPACITY))
+        if agg is not None and agg.strategy in D.HOST_MERGE_STRATEGIES:
+            # the exchange's output is no resident launch's: the wide
+            # record, which always fits, and the host ranks the groups
+            agg = D.wide_groups(agg)
+            spec = dataclasses.replace(spec, top=agg if agg.state_capacity
+                                       else self._with_capacity(
+                                           agg, DEFAULT_GROUP_CAPACITY))
         for _ in range(12):
             prog = get_shuffle_program(spec, self.mesh, caps)
             self._scheduler().count("join_shuffle_launches")
