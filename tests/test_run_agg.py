@@ -164,6 +164,78 @@ def test_stacked_shards_and_a_batch_that_is_no_whole_column(stacked):
     assert _groups(agg, st) == _want(agg, cols, sel)
 
 
+def _runs(lengths, seed, spread=10 ** 5, nulls=False):
+    """One key a run, the runs in key order as long as `lengths` say:
+    the exact forms sort by the key, so a run lies where its length and
+    those before it put it (the wide form sorts by a hash of the key: the
+    same runs, in another order)."""
+    rng = np.random.default_rng(seed)
+    n = sum(lengths)
+    k = np.repeat(np.arange(len(lengths)), lengths)
+    order = rng.permutation(n)
+    x = rng.integers(0, spread, n)
+    return [(k[order], None),
+            (x, rng.random(n) > 0.3 if nulls else None)], np.ones(n, bool)
+
+
+def _largest(words, n):
+    """(least, largest) argument whose distance is the widest a record
+    of this form admits: 2^31 - 1 fills a one-word record under the dead
+    bit (one group: a key of no bits); in a two-word one what n of them
+    leave of int64 (2^40 - 1 at 2^23 slots); wide, both halves 2^32 - 1."""
+    if not words:
+        return -(2 ** 63), 2 ** 63 - 1
+    room = min(32 * words - 1, 63 - (n - 1).bit_length())
+    return -5, 2 ** room - 1 - 5
+
+
+SCAN_CASES = ["block_edges", "one_run", "largest", "all_dead", "null_arg",
+              "stacked4"]
+
+
+@pytest.mark.parametrize("words", FORMS)
+@pytest.mark.parametrize("case", SCAN_CASES)
+def test_prefix_sums_across_blocks_and_limbs(case, words):
+    """What the two-level prefix sum (`ops/limbscan`) has to carry: runs
+    that begin and end at the last slot of a block of 128, the first of
+    the next and the one after; one run over every slot; every distance
+    the largest its form admits, so every limb is full and the int64
+    scan over the block totals carries; no live row; NULL arguments (a
+    one-limb lane beside the distances'); four stacked shards."""
+    nullable, stacked, n = False, 1, 5 * 128
+    if case == "block_edges":
+        # slots 127, 128 and 129 are runs of their own, then runs that
+        # end at 255 and at 256, begin at 257, and end at 383 and 384
+        cols, sel = _runs([127, 1, 1, 1, 126, 1, 127, 1, 255], seed=1)
+    elif case == "one_run":
+        cols, sel = _runs([n], seed=2)
+    elif case == "largest":
+        lo, hi = _largest(words, n)
+        k = np.arange(n) % 2 * (words != 1)
+        x = np.where(np.arange(n) == 7, lo, hi)
+        cols, sel = [(k, None), (x, None)], np.ones(n, bool)
+    elif case == "all_dead":
+        cols, sel = _runs([300, 340], seed=4)
+        sel = np.zeros(n, bool)
+    elif case == "null_arg":
+        nullable = True
+        cols, sel = _runs([127, 2, 300, 211], seed=5, nulls=True)
+    else:
+        stacked = 4
+        cols, sel = _runs([127, 2, 127, 129, 127], seed=6)
+        assert len(sel) == stacked * 128
+    agg = _agg(nullable=nullable, words=words)
+    st, got_agg, reruns = _regrown(agg, cols, sel, stacked)
+    assert (got_agg.pack_words, reruns) == (words, 0), "it fits its form"
+    assert _groups(agg, st) == _want(agg, cols, sel)
+    assert int(st["__ngroups__"]) == len(_want(agg, cols, sel))
+    _st, facts = _states(agg, cols, sel, stacked)
+    # the limb lanes: a NULL bit is one, a distance what its bound takes
+    bound = {1: 31, 2: 63 - 10, 0: 32}[words]
+    lanes = (2 if not words else 1) * -(-bound // 8)
+    assert facts["scan_limbs"] == lanes + 2 * nullable
+
+
 @pytest.mark.parametrize("words", FORMS)
 def test_sums_at_the_ends_of_the_limb_fence(words):
     """Values at both ends of int64 in one group: the sum passes int64
@@ -295,8 +367,10 @@ def test_through_the_sharded_program_on_one_and_four_devices(monkeypatch,
         prog = spmd.ShardedCopProgram(agg, mesh)
         states = jax.tree_util.tree_map(np.asarray, prog(*args))
         assert (states["__ngroups__"] <= 2048).all()
+        # two NULL lanes of one limb; distances below 2^52 (2,048 slots)
         assert prog.facts(*args) == {"agg_strategy": "sort",
-                                     "group_capacity": 2048}
+                                     "group_capacity": 2048,
+                                     "scan_limbs": 1 + 7 + 1}
         per_dev = [jax.tree_util.tree_map(lambda a, d=d: a[d], states)
                    for d in range(n_dev)]
         merged = merge_sorted_states(agg, per_dev)
@@ -353,7 +427,7 @@ def test_whole_statements_through_the_normal_path(monkeypatch, sql, words,
     spmd._cached.cache_clear()
     sched = scheduler_for(mesh)
     names = ("launches", "hndv_agg_launches", "hndv_agg_regrows",
-             "hndv_host_topn_launches")
+             "hndv_host_topn_launches", "hndv_limb_scan_launches")
     where = "device" if n_dev == 1 else "host"
     try:
         footer = [r[0] for r in sess.execute("explain " + sql).rows
@@ -368,7 +442,7 @@ def test_whole_statements_through_the_normal_path(monkeypatch, sql, words,
         f"agg strategy: sort (capacity 1024; one sort of {words}-word "
         f"records, first 10 groups ranked on the {where})"]
     assert [after[k] - before[k] for k in names] \
-        == [2, 2, 0, 2 * (n_dev > 1)]
+        == [2, 2, 0, 2 * (n_dev > 1), 2]
     spans = [sp for ent in dom.flight_recorder.index()
              for sp in dom.flight_recorder.get(ent["trace_id"]).spans]
     launches = [sp.attrs for sp in spans if sp.name == "sched.launch"
@@ -376,6 +450,11 @@ def test_whole_statements_through_the_normal_path(monkeypatch, sql, words,
     assert len(launches) == 2
     assert all((a["agg_strategy"], a["group_capacity"], a["group_topn"])
                == ("sort", 1024, where) for a in launches)
+    # the prefix sums' limb lanes: a distance is below 2^31 in a one-word
+    # record; in a two-word one below 2^49 or 2^52 (what 12,032 or 1,536
+    # slots a device leave of int64)
+    assert [a["scan_limbs"] for a in launches] \
+        == [4 if words == 1 else 7] * 2
     found = [sp.attrs["ngroups"] for sp in spans
              if sp.name == "cop.transfer" and "ngroups" in sp.attrs]
     assert len(found) == 2 and found[0] >= 300 * (1 if words == 2 else 1)
@@ -446,3 +525,119 @@ def test_programs_that_use_neither_field_keep_their_names():
     assert len({stable_digest(sort), stable_digest(dataclasses.replace(
         sort, pack_words=1)), stable_digest(dataclasses.replace(
             sort, topn=D.GroupTopN((("key", 0, False),), 3)))}) == 3
+
+
+# what the statements of the cells that never reach `agg_run_states`
+# launch, planned and lowered as for a TPU over `tpch_plan_session(0.002)`
+# (the class files' own text; Q14 against a stand-in for `part` with a
+# `p_type`): the program names, digest and all, of the commit before the
+# prefix sums changed
+OTHER_CELLS = {
+    "q6": ({"year": 1994, "discount": 6, "quantity": 24},
+           ["cop_solo_agg_scalar_d9813c8a8dcf"]),
+    "q1": ({"delta": 90}, ["cop_solo_agg_dense_976556d4f313"]),
+    "topn": ({}, ["cop_solo_topn_16ce5d827856"]),
+    "part_agg": ({"size": 25}, ["cop_solo_agg_dense_6dc1e6a2f9bf"]),
+    "kv_agg": ({"grp": 5}, ["cop_solo_agg_scalar_ac64dc605029"]),
+    "q14": ({"year": 1995, "month": 9},
+            ["cop_solo_join_agg_scalar_4660eae87699",
+             "cop_solo_rows_adc818226355"]),
+    "q19": ({"quantity": [1, 10, 20],
+             "brand": ["Brand#12", "Brand#23", "Brand#34"]},
+            ["cop_solo_join_agg_scalar_66af6b26d000",
+             "cop_solo_rows_dc761e59f787"]),
+}
+
+
+@pytest.fixture(scope="module")
+def tpu_planned():
+    """-> run(sql): the names of the programs one statement builds."""
+    from tidb_tpu.parallel import get_mesh
+    from tidb_tpu.testing.tpch import tpch_plan_session
+    built = []
+    real = spmd.ShardedCopProgram.__init__
+
+    def init(self, *args, **kwargs):
+        real(self, *args, **kwargs)
+        built.append(self.name)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(spmd, "mesh_platform", lambda _mesh: "tpu")
+        mp.setattr(spmd.ShardedCopProgram, "__init__", init)
+        sess = tpch_plan_session(0.002)
+        sess.domain.client.mesh = get_mesh(1)
+        sess.domain.client._platform = lambda: "tpu"
+        sess.execute("set global tidb_tpu_result_cache_entries = 0")
+        sess.execute("create table bench_kv (k bigint primary key, "
+                     "grp bigint, v bigint)")
+        sess.execute("insert into bench_kv values " + ",".join(
+            f"({i},{i % 10},{i * 7})" for i in range(200)))
+        sess.execute("create table part_t (p_partkey bigint primary key, "
+                     "p_type varchar(25))")
+        sess.execute("insert into part_t values " + ",".join(
+            f"({i},'{'PROMO' if i % 3 else 'STANDARD'} BRUSHED TIN')"
+            for i in range(1, 401)))
+        spmd._cached.cache_clear()
+
+        def run(sql):
+            del built[:]
+            sess.execute(sql)
+            return sorted(set(built))
+        try:
+            yield run
+        finally:
+            spmd._cached.cache_clear()
+
+
+@pytest.mark.parametrize("cls", list(OTHER_CELLS))
+def test_the_other_cells_statements_keep_their_programs(tpu_planned, cls,
+                                                        monkeypatch):
+    """`tpch10x1.power`, `tpch10x1.small` and `tpch1x1.partjoin` plan
+    DENSE or SCALAR (or a TopN, or a lookup join under a scalar
+    aggregation): their programs are named as the parent named them, so
+    they are the parent's programs, and none says `scan_limbs`."""
+    import importlib.util
+    import os
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    monkeypatch.syspath_prepend(bench)      # the class files' `harness`
+    spec = importlib.util.spec_from_file_location(
+        f"bench_class_{cls}", os.path.join(bench, "classes", f"{cls}.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    params, names = OTHER_CELLS[cls]
+    sql = mod.sql(params)
+    if cls == "q14":
+        sql = sql.replace("lineitem, part ", "lineitem, part_t ")
+    assert tpu_planned(sql) == names
+    assert "_agg_sort_" not in " ".join(names)
+
+
+def test_the_scan_limbs_fact_is_a_row_of_the_table_and_nothing_of_sched():
+    """`agg_run_states` writes `scan_limbs`; `copr/facts.py` alone says
+    what it counts (`hndv_limb_scan_launches`, where it is over 0), that
+    it goes on the `sched.launch` span, and that it is a host-merged
+    aggregation root's.  The scheduler knows neither name
+    (`test_whole_statements_through_the_normal_path` sees both arrive)."""
+    import os
+    from tidb_tpu import sched
+    from tidb_tpu.copr import facts as F
+    assert "hndv_limb_scan_launches" in F.counter_names()
+    assert F.counters({"agg_strategy": "sort", "scan_limbs": 5}) \
+        == ["hndv_agg_launches", "hndv_limb_scan_launches"]
+    assert F.counters({"agg_strategy": "sort", "scan_limbs": 0}) \
+        == ["hndv_agg_launches"]
+    assert F.span_attrs({"scan_limbs": 5}) == {"scan_limbs": 5}
+    assert F.merged([{"scan_limbs": 4}, {}, {"scan_limbs": 9}]) \
+        == {"scan_limbs": 9}
+    sort = _agg(words=1)
+    dense = dataclasses.replace(sort, strategy=D.GroupStrategy.DENSE,
+                                group_capacity=0, pack_words=0,
+                                domain_sizes=(6,))
+    assert F.of_program({"scan_limbs": 4}, sort) == {"scan_limbs": 4}
+    assert F.of_program({"scan_limbs": 4}, dense) == {}
+    folder = os.path.dirname(sched.__file__)
+    for name in os.listdir(folder):
+        if name.endswith(".py"):
+            with open(os.path.join(folder, name)) as f:
+                text = f.read()
+            assert "scan_limbs" not in text and "limb_scan" not in text, name

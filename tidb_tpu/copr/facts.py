@@ -86,6 +86,11 @@ FACTS = {
     "group_topn": Fact(
         counters=(("hndv_host_topn_launches", lambda w: w == "host"),),
         merge=_first, on_span=_present, root=_host_merged),
+    # `runagg.agg_run_states`: the 8-bit limb lanes its prefix sums were
+    # made of, in blocks on the MXU (0: an int64 scan over every slot)
+    "scan_limbs": Fact(
+        counters=(("hndv_limb_scan_launches", lambda n: n > 0),),
+        root=_host_merged),
 }
 
 # counted by name (`DeviceScheduler.count`) by whoever sees it happen:
