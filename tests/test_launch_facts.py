@@ -150,7 +150,10 @@ def _run(dom, sqls, together=False):
                 spans.append(
                     {"mode": a["mode"],
                      "program": a.get("program", "").rsplit("_", 1)[0],
-                     **{k: a[k] for k in F.FACTS if k in a}})
+                     # (a rows root's capacity is the paging's, which
+                     # remembers what the statements before it found)
+                     **{k: a[k] if k != "rows_capacity" else a[k] > 0
+                        for k in F.FACTS if k in a}})
     _wait_until(lambda: sched.stats()["tasks_done"]
                 >= before["tasks_done"] + len(spans),
                 msg="the drain's counts")
@@ -175,6 +178,11 @@ def _solo(program, **facts):
     return {"mode": "single", "program": program, **facts}
 
 
+# a rows root says the capacity its live rows leave in; by the column
+# sort only where the program is lowered for a TPU
+ROWS_ROOT = _solo("cop_solo_rows", rows_capacity=True)
+
+
 # kind: (statements, queued together?, the platform the programs are
 # lowered for, `/sched` deltas, the `sched.launch` spans)
 KINDS = {
@@ -197,32 +205,35 @@ KINDS = {
         [_solo("cop_solo_agg_dense", agg_limbs=3)]),
     "join_unique": (
         [JOIN.format("dim")], False, "cpu",
-        {"launches": 2, "join_launches": 1},
-        [_solo("cop_solo_rows"),
+        {"launches": 2, "join_launches": 1, "join_direct_launches": 1,
+         "rows_launches": 1},
+        [ROWS_ROOT,
          _solo("cop_solo_join_agg_scalar", join="unique", build_rows=8,
-               probe_rows=8192)]),
+               probe_rows=8192, join_form="direct")]),
     "join_multimatch": (
         [JOIN.format("dup")], False, "cpu",
-        {"launches": 2, "join_launches": 1},
-        [_solo("cop_solo_rows"),
+        {"launches": 2, "join_launches": 1, "rows_launches": 1},
+        [ROWS_ROOT,
          _solo("cop_solo_join_agg_scalar", join="multimatch", build_rows=10,
-               probe_rows=8192)]),
+               probe_rows=8192, join_form="expanding")]),
     # a filter beneath the join, statistics to size it from, a platform
     # whose gather costs its indices: the probe rows are compacted first
     # (2 % of 524,288 rows estimated, a device's share, a quarter more and
     # six deviations of one of the compaction's columns)
     "join_compact": (
         [COMPACT], False, "tpu",
-        {"launches": 2, "join_launches": 1, "join_compact_launches": 1},
-        [_solo("cop_solo_rows"),
+        {"launches": 2, "join_launches": 1, "join_compact_launches": 1,
+         "join_direct_launches": 1, "rows_launches": 1,
+         "rows_compact_launches": 1},
+        [dict(ROWS_ROOT, rows_compact=1),
          _solo("cop_solo_join_agg_scalar", join="unique", build_rows=8,
-               probe_rows=BIG, probe_capacity=4096)]),
+               probe_rows=BIG, probe_capacity=4096, join_form="direct")]),
     # (the build side, prepared above, is kept with `dim`'s snapshot)
     "join_filtered_on_the_cpu_mesh": (
         [COMPACT], False, "cpu",
-        {"launches": 1, "join_launches": 1},
+        {"launches": 1, "join_launches": 1, "join_direct_launches": 1},
         [_solo("cop_solo_join_agg_scalar", join="unique", build_rows=8,
-               probe_rows=BIG)]),
+               probe_rows=BIG, join_form="direct")]),
     "fused_aggs": (
         [DENSE.format("t1"), DENSE2.format("t1")], True, "tpu",
         {"launches": 1, "fused_launches": 1, "dense_agg_launches": 1,
@@ -231,9 +242,10 @@ KINDS = {
         * 2),
     "fused_rows": (
         [ROWS.format("t1"), TOPN.format("t1")], True, "cpu",
-        {"launches": 1, "fused_launches": 1, "topn_launches": 1},
+        {"launches": 1, "fused_launches": 1, "topn_launches": 1,
+         "rows_launches": 1},
         [{"mode": "fused", "program": "cop_fused_rows_x2",
-          "topn_blocks": 1}] * 2),
+          "topn_blocks": 1, "rows_capacity": True}] * 2),
     "batched": (
         [DENSE.format("t1"), DENSE.format("t2")], True, "tpu",
         {"launches": 1, "batched_launches": 1, "coalesced_launches": 1,
@@ -258,8 +270,8 @@ KINDS = {
     # an empty build side: the join's two sides and its fallback scan
     "join_host_fallback": (
         [JOIN.format("none")], False, "cpu",
-        {"launches": 3, "join_host_fallbacks": 1},
-        [_solo("cop_solo_rows")] * 3),
+        {"launches": 3, "join_host_fallbacks": 1, "rows_launches": 3},
+        [ROWS_ROOT] * 3),
 }
 
 
@@ -286,13 +298,14 @@ def test_a_new_fact_needs_nothing_of_the_scheduler(dom, lowered_for,
                   ("compact_wide_launches", lambda n: n > 1 << 30)),
         root=lambda _root: True))
 
-    def compact(batch, capacity, real=spmd.compact):
+    def compact(batch, capacity, platform, real=spmd.compact_root):
         batch.facts["compact_rows"] = capacity
-        return real(batch, capacity)
-    monkeypatch.setattr(spmd, "compact", compact)
+        return real(batch, capacity, platform)
+    monkeypatch.setattr(spmd, "compact_root", compact)
     lowered_for("cpu")
     delta, (span,) = _run(dom, [ROWS.format("t2")])
-    assert delta == {"launches": 1, "compact_launches": 1}
+    assert delta == {"launches": 1, "compact_launches": 1,
+                     "rows_launches": 1}
     # (the capacity the client's paging chose: a power of two)
     assert span["compact_rows"] >= 256 and span["mode"] == "single"
     # the names the scheduler shows from its start, at zero

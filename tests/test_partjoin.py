@@ -176,7 +176,9 @@ def _join_build_spans(sess):
 
 
 DENSE_DIM = [(k, 100 + k, None if k == 3 else k * k) for k in range(1, 9)]
-SPARSE_DIM = [(k, 100 + k, k + 1) for k in (2, 5, 1000, 70_000, 9_000_000)]
+# a range no table can span (copr/joinbuild.build_form): the sorted form
+SPARSE_DIM = [(k, 100 + k, k + 1) for k in (2, 5, 1000, 70_000,
+                                             9_000_000_000)]
 DUP_DIM = DENSE_DIM + [(2, 777, 4), (5, 778, None)]
 
 

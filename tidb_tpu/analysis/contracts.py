@@ -362,6 +362,8 @@ def _verify_dag(node: D.CopNode, path) -> None:
                   "a sorted lookup join that carries a packing")
         if node.probe_capacity:
             _verify_probe_capacity(node, p)
+        if node.match_capacity:
+            _verify_match_capacity(node, p)
         if node.kind in ("inner", "left"):
             for t in node.build_dtypes:
                 if t.is_host_object:
@@ -395,13 +397,38 @@ def _verify_probe_capacity(node: D.LookupJoin, p) -> None:
               "join of a chain compacts")
 
 
+def _verify_match_capacity(node: D.LookupJoin, p) -> None:
+    """Contract of the compaction of a join's matched rows (executor/
+    physical `_compacted` sets it, copr/exec `_exec_lookup_join` reads
+    it): only an inner unique lookup drops the rows that found nothing;
+    whole rows of the compaction's column view; the kept rows come in
+    no order, so the chain ends in an aggregation; and a program
+    compacts once, so its reports name one capacity."""
+    if node.match_capacity < 0 or node.match_capacity % D.COMPACT_COLUMNS:
+        _fail("capacity-shape", p,
+              f"match_capacity {node.match_capacity} is not a positive "
+              f"multiple of {D.COMPACT_COLUMNS}")
+    if not node.unique or node.kind != "inner":
+        _fail("capacity-shape", p,
+              "match compaction on a join that is not a unique inner "
+              "lookup")
+    if next(name for name in p if name != "FusedDag") != "Aggregation":
+        _fail("capacity-shape", p,
+              "match compaction under a root that reads the order of its "
+              "rows: only an aggregation may sit above")
+    if node.probe_capacity or D.compacting_join(node.child) is not None:
+        _fail("capacity-shape", p,
+              "multiple compactions in one program: one join compacts, "
+              "before its lookup or after it")
+
+
 def _verify_packing(node: D.LookupJoin, p) -> None:
     """Contract of the direct-addressed build side (copr/joinbuild
     `_dense_group` writes it, copr/join `direct_lookup` reads it): only a unique
     inner/left join is addressed directly; one layout entry a build
     column; inside each int32 word no two fields overlap and none
     reaches the sign bit."""
-    from ..copr.joinbuild import APART, KEY_ITSELF, WORD_BITS
+    from ..copr.joinbuild import APART, KEY_ITSELF, UNREAD, WORD_BITS
     if not node.unique or node.kind not in ("inner", "left"):
         _fail("capacity-shape", p,
               "direct addressing on a join that is not a unique "
@@ -416,7 +443,7 @@ def _verify_packing(node: D.LookupJoin, p) -> None:
     used = [0] * n_words
     fields = [(0, pbit, 1)] if pbit >= 0 else []
     for w, shift, bits, vbit, _wide in layout:
-        if w in (APART, KEY_ITSELF):
+        if w in (APART, KEY_ITSELF, UNREAD):
             continue
         fields.append((w, shift, bits))
         if vbit >= 0:

@@ -491,6 +491,12 @@ def _walk(node: D.CopNode, path: tuple, rows: int, layout: Layout,
             acc.flops += (_expr_flops(node.probe_key) + 3 + n_words
                           + 3 * len(layout)) * rows_in
             acc.buf("/".join(p) + ":gather", rows_in * 4 * max(n_words, 1))
+            if 0 < node.match_capacity < rows_in:
+                # the same compaction after the lookup, of the joined row
+                acc.flops += rows_in * _log2(rows_in)
+                acc.buf("/".join(p) + ":compact", rows_in * 4
+                        + node.match_capacity * (w_in + build_w))
+                rows_in = node.match_capacity
             return rows_in, w_in + build_w
         # binary search: log2 of the BUILD side, which the plan does not
         # know; the probe's own row count bounds it from above

@@ -406,7 +406,8 @@ class LookupJoin(CopNode):
       subtraction, one bounds check and ONE gather a word: `packing` =
       (n_words, presence bit | -1, ((word, shift, bits, validity bit |
       -1, wide), ...) per build column); `word` -1 for a column carried
-      apart, -2 for the key column, which is not carried at all.
+      apart, -2 for the key column and -3 for a column nothing above the
+      join reads, which are not carried at all.
 
     Output schema = probe schema ++ build columns (probe schema only for
     semi/anti); `kind` inner|left|semi|anti."""
@@ -435,6 +436,16 @@ class LookupJoin(CopNode):
     # capacity they take (extras `join_need`) and the dispatcher reruns
     # the statement at 0.
     probe_capacity: int = 0
+    # unique inner under an aggregation only, set by the executor where
+    # the filters beneath the join keep too much for `probe_capacity`
+    # but the build side keeps little (a filtered dimension: TPC-H Q3
+    # looks up 54 % of `lineitem` in a tenth of `orders`): the slots a
+    # device compacts the join's OUTPUT, its matched rows, to, after
+    # the lookup, so that what is above (the next filter, a sorted
+    # GROUP BY) costs the rows that joined and not the rows scanned.
+    # The same compaction, reports and rerun as `probe_capacity`; a
+    # program has one or the other.
+    match_capacity: int = field(default=0, metadata=DIGEST_IF_SET)
 
     def children(self):
         return (self.child,)
@@ -560,6 +571,53 @@ def lookup_joins(node) -> tuple:
     return tuple(n for n in iter_nodes(node) if isinstance(n, LookupJoin))
 
 
+def build_columns_read(root: CopNode, join: LookupJoin):
+    """Which of `join`'s build columns the DAG above it reads, as a
+    tuple of bools a build column; None where all of them leave the
+    program (a rows root, or nothing above the join that names its
+    columns).  What is not read need not ride in a build side's words
+    (copr/joinbuild `UNREAD`): TPC-H Q3's `orders` build comes with the
+    two customer keys it was joined on, which nothing above reads."""
+    from ..expr.ir import referenced_columns
+    path = []                   # root .. the join's parent
+
+    def find(node):
+        if node == join:        # (`lookup_joins` hands out equal nodes)
+            return True
+        if isinstance(node, FusedDag):
+            return False
+        for c in node.children():
+            path.append(node)
+            if find(c):
+                return True
+            path.pop()
+        return False
+    if not find(root) or join.kind in ("semi", "anti"):
+        return None
+    if not path or not isinstance(path[0], Aggregation):
+        return None             # rows leave the program as they are
+    # liveness, from the root down to the join: the columns of each
+    # node's input that what is above it reads
+    root_agg = path[0]
+    read = set()
+    for e in tuple(root_agg.group_by) + tuple(
+            a.arg for a in root_agg.aggs if a.arg is not None):
+        read |= referenced_columns(e)
+    for node in path[1:]:
+        if isinstance(node, Selection):
+            for e in node.conditions:
+                read |= referenced_columns(e)
+        elif isinstance(node, Projection):
+            below: set = set()
+            for i in read:
+                below |= referenced_columns(node.exprs[i])
+            read = below
+        else:                   # a join above, a TopN, a Limit, an Expand
+            return None
+    n_probe = len(output_dtypes(join.child))
+    return tuple(n_probe + j in read for j in range(len(join.build_dtypes)))
+
+
 def find_expand_join(node: CopNode):
     """The (at most one) non-unique LookupJoin in a pushed DAG, or None —
     programs containing one report true join output size via extras."""
@@ -574,20 +632,26 @@ def find_expand_join(node: CopNode):
 
 
 def compacting_join(node: CopNode):
-    """The LookupJoin of a pushed DAG that compacts its probe rows
-    (`probe_capacity` > 0; the executor sets it on one join, the lowest
-    of a chain), or None."""
+    """The LookupJoin of a pushed DAG that compacts its probe rows or
+    its matched rows (`probe_capacity` or `match_capacity` > 0; the
+    executor sets one, on one join), or None."""
     # walked, not `lookup_joins`: its cache hashes the whole DAG, and the
     # dispatcher asks this of a DAG the executor has just rebuilt
     return next((n for n in iter_nodes(node) if isinstance(n, LookupJoin)
-                 and n.probe_capacity), None)
+                 and compact_capacity(n)), None)
+
+
+def compact_capacity(join: LookupJoin) -> int:
+    """The slots the join compacts to, before or after its lookup."""
+    return join.probe_capacity or join.match_capacity
 
 
 def uncompacted(node: CopNode) -> CopNode:
     """The DAG with its compacting join, wherever in a chain it sits,
-    looking up every slot: today's exact program."""
-    return rewrite_lookup(node, pred=lambda j: j.probe_capacity > 0,
-                          probe_capacity=0)
+    looking up every slot and keeping every slot: today's exact
+    program."""
+    return rewrite_lookup(node, pred=lambda j: compact_capacity(j) > 0,
+                          probe_capacity=0, match_capacity=0)
 
 
 def has_extras(node: CopNode) -> bool:
@@ -609,7 +673,7 @@ def to_multimatch(node: CopNode, out_capacity: int) -> CopNode:
     if isinstance(node, LookupJoin):
         return dataclasses.replace(node, unique=False,
                                    out_capacity=out_capacity,
-                                   probe_capacity=0)
+                                   probe_capacity=0, match_capacity=0)
     if not node.children():
         return node
     kids = tuple(to_multimatch(c, out_capacity) for c in node.children())
@@ -695,6 +759,7 @@ __all__ = [
     "Limit", "LookupJoin",
     "FusedDag", "ShuffleJoinSpec", "output_dtypes", "dag_digest",
     "iter_nodes", "lookup_joins", "find_expand_join", "compacting_join",
+    "compact_capacity", "build_columns_read",
     "uncompacted", "has_extras",
     "rewrite_lookup",
     "drop_lookup",

@@ -637,6 +637,38 @@ def compact(batch: DeviceBatch, capacity: int):
     return out_cols, jnp.sum(sel)
 
 
+def compact_root(batch: DeviceBatch, capacity: int, platform: str):
+    """The live rows of a rows-returning program's root in `capacity`
+    slots a device, for the host: (cols + [the slots' live mask], need).
+    `need` is the capacity the live rows take; above `capacity` rows are
+    missing and the dispatcher reruns with more (the paging loop).
+
+    Where a gather costs its indices and a scatter 90 ns an update (a
+    TPU: PERF.md, PR 28) the rows leave as a lookup join's live probe
+    rows do, by ONE column sort and ONE stacked gather
+    (copr/join.compact_rows): the live rows then lie in no order and not
+    at the front, which is what the mask says.  Elsewhere, and where the
+    slots do not divide into the compaction's columns or all fit, by
+    `compact`, whose rows lie at the front.  The batch's facts say which
+    (copr/facts.py `rows_capacity`, `rows_compact`)."""
+    from .join import compact_rows
+    n = len(batch.cols[0][0]) if batch.cols else 0
+    by_sort = platform != "cpu" and n > capacity \
+        and not n % D.COMPACT_COLUMNS and not capacity % D.COMPACT_COLUMNS
+    batch.facts["rows_capacity"] = capacity
+    batch.facts["rows_compact"] = 1 if by_sort else 0
+    if not by_sort:
+        out_cols, need = compact(batch, capacity)
+        live = jnp.arange(capacity, dtype=jnp.int64) < need
+        return out_cols + [(live, live)], need
+    with jax.named_scope("rows_compact"):
+        cols, ok, need = compact_rows(
+            [(_ensure_array(v, n), m) for v, m in batch.cols],
+            _sel_array(batch.sel, n), capacity, batch.stacked)
+    out_cols = [(v, ok if m is True else m) for v, m in cols]
+    return out_cols + [(ok, ok)], need
+
+
 # --------------------------------------------------------------------- #
 # Node execution (traced)
 # --------------------------------------------------------------------- #
@@ -728,18 +760,19 @@ def _exec_node(node: D.CopNode, scan_cols: Sequence, row_count, ev: Evaluator,
 
 def _compact_probe(batch: DeviceBatch, capacity: int) -> DeviceBatch:
     """The batch's live rows in a batch of `capacity` slots
-    (dag.LookupJoin.probe_capacity; in no order: copr/join.live_rows).
+    (dag.LookupJoin.probe_capacity before the lookup, `match_capacity`
+    after it; in no order: copr/join.live_rows).
     Extras: `join_live`, the live rows the device found, and
     `join_need`, the capacity they take: where that exceeds `capacity`
     rows are missing and the dispatcher reruns the statement
     uncompacted."""
-    from .join import gather_rows, live_rows
+    from .join import compact_rows
     n = len(batch.cols[0][0])
     sel = _sel_array(batch.sel, n)
     with jax.named_scope("join_compact"):
-        rows, ok, need = live_rows(sel, capacity, batch.stacked)
-        cols = gather_rows([(_ensure_array(v, n), m) for v, m in batch.cols],
-                           rows, batch.stacked)
+        cols, ok, need = compact_rows(
+            [(_ensure_array(v, n), m) for v, m in batch.cols], sel,
+            capacity, batch.stacked)
     return replace(batch, cols=cols, sel=ok, stacked=1, extras={
         **batch.extras, "join_live": jnp.sum(sel, dtype=jnp.int32),
         "join_need": need})
@@ -750,14 +783,17 @@ def _exec_lookup_join(node: D.LookupJoin, batch: DeviceBatch, ev: Evaluator,
     """Broadcast lookup join (see dag.LookupJoin for the two forms a
     build side takes).  aux is a tuple of GROUPS, one per chained join
     level."""
-    if node.probe_capacity:
+    def compacted(batch, capacity):
         n = len(batch.cols[0][0])
-        if node.probe_capacity < n and not n % D.COMPACT_COLUMNS:
-            batch = _compact_probe(batch, node.probe_capacity)
-        else:       # nothing to gain: every slot is looked up, none is lost
-            zero = jnp.zeros((), jnp.int32)
-            batch = replace(batch, extras={
-                **batch.extras, "join_live": zero, "join_need": zero})
+        if capacity < n and not n % D.COMPACT_COLUMNS:
+            return _compact_probe(batch, capacity)
+        # nothing to gain: every slot is looked up, none is lost
+        zero = jnp.zeros((), jnp.int32)
+        return replace(batch, extras={
+            **batch.extras, "join_live": zero, "join_need": zero})
+
+    if node.probe_capacity:
+        batch = compacted(batch, node.probe_capacity)
     n = len(batch.cols[0][0])
     grp = aux[node.aux_slot]
     kv, km = ev.eval(node.probe_key, batch.cols, {})
@@ -776,7 +812,9 @@ def _exec_lookup_join(node: D.LookupJoin, batch: DeviceBatch, ev: Evaluator,
         sel = batch.sel
         if node.kind == "inner":
             sel = matched if sel is True else (sel & matched)
-        return replace(batch, cols=out_cols, sel=sel)
+        out = replace(batch, cols=out_cols, sel=sel)
+        return compacted(out, node.match_capacity) if node.match_capacity \
+            else out
 
     sorted_keys = grp[0][0].astype(jnp.int64)
     kv = kv.astype(jnp.int64)
@@ -1024,4 +1062,4 @@ def get_program(dag_root: D.CopNode, row_capacity: int = 0) -> CopProgram:
 
 
 __all__ = ["DeviceBatch", "CopProgram", "get_program", "compact",
-           "group_keyinfo"]
+           "compact_root", "group_keyinfo"]

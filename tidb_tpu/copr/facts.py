@@ -27,6 +27,12 @@ def _first(values):
     return values[0]
 
 
+def _rows_root(root) -> bool:
+    """A root whose live rows `exec.compact_root` hands to the host."""
+    return not isinstance(root, (D.Aggregation, D.TopN, D.Limit,
+                                 D.FusedDag))
+
+
 def _host_merged(root) -> bool:
     return isinstance(root, D.Aggregation) \
         and root.strategy in D.HOST_MERGE_STRATEGIES
@@ -71,11 +77,30 @@ FACTS = {
     "join": Fact(counters=(("join_launches", _present),), on_span=_present),
     "probe_rows": Fact(on_span=_present),
     "build_rows": Fact(on_span=_present),
+    # the form each lookup of the program took, in the order of its
+    # joins, joined by commas: "direct" | "sorted" | "expanding"
+    # (copr/joinbuild.py)
+    "join_form": Fact(
+        counters=(("join_direct_launches",
+                   lambda f: set(f.split(",")) == {"direct"}),),
+        merge=",".join, on_span=_present),
     # of a program whose lookup joins are all unique: the slots a device
     # compacts its live probe rows to before the lookup (0: it looks up
     # every slot)
     "probe_capacity": Fact(
         counters=(("join_compact_launches", lambda c: c > 0),)),
+    # of the same: the slots a device compacts its matched rows to after
+    # the lookup (0: what is above the join runs on every slot)
+    "match_capacity": Fact(
+        counters=(("join_match_compact_launches", lambda c: c > 0),)),
+    # `exec.compact_root`, the root of a rows-returning program: the
+    # slots a device hands its live rows to the host in, and whether
+    # they got there by the column sort (1) or by the scatter (0)
+    "rows_capacity": Fact(on_span=_present, root=_rows_root),
+    "rows_compact": Fact(
+        counters=(("rows_launches", _present),
+                  ("rows_compact_launches", lambda c: c > 0)),
+        merge=min, root=_rows_root),
     # copr/runagg, a TPU's lowering of a SORT aggregation root: the
     # strategy's name, the table's slots a device, and where the groups
     # a TopN above it keeps are ranked, "device" | "host" (absent: none
@@ -98,9 +123,10 @@ FACTS = {
 # (`join_compact_overflows`: a compacting join found more live rows than
 # its capacity and the statement was rerun uncompacted;
 # `hndv_agg_regrows`: a host-merged aggregation was rerun with a larger
-# table or a wider record)
+# table or a wider record; `rows_regrows`: a rows-returning program's
+# live rows did not fit its capacity and it was rerun with more)
 EVENTS = ("join_shuffle_launches", "join_host_fallbacks", "join_regrows",
-          "join_compact_overflows", "hndv_agg_regrows")
+          "join_compact_overflows", "hndv_agg_regrows", "rows_regrows")
 
 
 def counter_names() -> tuple:

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .dag import COMPACT_COLUMNS
-from .joinbuild import APART, KEY_ITSELF
+from .joinbuild import APART, KEY_ITSELF, UNREAD
 
 
 def _compare_narrow(kv, ref) -> bool:
@@ -39,15 +39,16 @@ def direct_lookup(kv, grp, packing):
     value is meaningless where `matched` is False.
 
     `key - base` wraps for a key far outside the range; read unsigned it
-    is then never below `span` (span <= 2^24 and both operands share one
-    signed width), so one unsigned compare is the whole bounds check."""
+    is then never below `span` (base + span fits the signed width both
+    operands share, so a wrapped difference is at least span), so one
+    unsigned compare is the whole bounds check."""
     n_words, pbit, layout = packing
     meta = grp[0][0]
     kt, ut = (jnp.int32, jnp.uint32) if _compare_narrow(kv, meta) \
         else (jnp.int64, jnp.uint64)
     d = kv.astype(kt) - meta[0].astype(kt)
     matched = lax.bitcast_convert_type(d, ut) < meta[1].astype(ut)
-    idx = jnp.where(matched, d, 0).astype(jnp.int32)  # valueflow: ok - a matched row's offset is below span <= 2^24
+    idx = jnp.where(matched, d, 0).astype(jnp.int32)  # valueflow: ok - a matched row's offset is below span < 2^31
     words = [grp[2 + w][0].at[idx].get(mode="promise_in_bounds")
              for w in range(n_words)]
     if pbit >= 0:
@@ -58,6 +59,9 @@ def direct_lookup(kv, grp, packing):
     for j, (w, shift, bits, vbit, wide) in enumerate(layout):
         if w == KEY_ITSELF:
             out.append((kv.astype(jnp.int64 if wide else jnp.int32), True))
+            continue
+        if w == UNREAD:         # nothing reads it: any value will do
+            out.append((jnp.zeros(kv.shape, bool), True))
             continue
         if w == APART:
             tv, tm = next(apart)
@@ -242,6 +246,18 @@ def gather_rows(cols: Sequence, rows, stacked: int = 1) -> list:
         at(cols[i][0]), True if cols[i][1] is True else at(cols[i][1])))
 
 
+def compact_rows(cols: Sequence, sel, capacity: int, stacked: int = 1):
+    """The one compaction of live rows on a device where a scatter costs
+    90 ns an update: (`cols` at `capacity` slots that hold every live
+    row of `sel` in no order, which of the slots hold one, the capacity
+    the live rows take).  One column sort (`live_rows`), one stacked
+    gather (`gather_rows`).  A lookup join's probe rows
+    (exec._compact_probe) and a rows-returning program's result
+    (exec.compact_root) both leave through it."""
+    rows, ok, need = live_rows(sel, capacity, stacked)
+    return gather_rows(cols, rows, stacked), ok, need
+
+
 def sorted_lookup(kv, grp):
     """Probe a sorted unique build side: binary search, equality check,
     one gather a column (they come in key order: no permutation).
@@ -331,5 +347,5 @@ def gather_expand(batch_cols, sel, probe_key_ok, build_cols, perm,
 
 
 __all__ = ["direct_lookup", "sorted_lookup", "live_rows", "gather_rows",
-           "pack_rows",
+           "pack_rows", "compact_rows",
            "match_ranges", "expand_slots", "gather_expand"]

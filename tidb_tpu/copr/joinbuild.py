@@ -5,34 +5,86 @@ half that probes them).
 
 Reference analog: the build phase of the hash join (hash_join_v2.go
 build workers).  A hash table is hostile to a TPU; what the build side IS
-decides instead: unique keys over a range little longer than their count
-(every TPC-H primary key) are addressed directly, `key - base` being the
-build row, with every column that fits packed into one int32 word, so
+decides instead: unique keys are addressed directly, `key - base` being
+the slot, with every column that fits packed into one int32 word, so
 that ONE gather a probe row fetches the whole build row (an XLA gather on
-a v5e costs about 7 ns a row whatever it fetches: PERF.md, PR 25); other
-keys are sorted and binary-searched; duplicate keys make the join an
-expanding one.
+a v5e costs about 7 ns an index whatever the table and whatever it
+fetches, so a table is paid in bytes and a probe in gathers: PERF.md,
+PR 25); the range may be as sparse as the device's memory lets a table
+be (`build_form`: TPC-H's `o_orderkey` is 1.5M keys over a range of 6M,
+one market segment's orders before a date 145,000 over the same 6M, 24 MB
+a word); keys over a range no table can span are sorted and
+binary-searched, about log2(rows) dependent gathers a probe row;
+duplicate keys make the join an expanding one.
 """
 
 from __future__ import annotations
 
+import threading
 from dataclasses import dataclass
+from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 # direct addressing (dag.LookupJoin `dense`): usable bits of one int32
 # word (the sign bit stays clear, so an arithmetic shift is a logical
-# one), and how sparse a key range may be before the sorted form is the
-# smaller table
+# one)
 WORD_BITS = 31
-DENSE_MAX_SPAN = 1 << 24
-DENSE_MAX_SPREAD = 4
-DENSE_MIN_SPAN = 1 << 16
+# the share of one device's memory one build side's tables may take, and
+# the memory of a device that does not say (the CPU mesh): a v5e's
+DIRECT_MEMORY_SHARE = 64
+DEFAULT_DEVICE_BYTES = 16 << 30
+# the forms a lookup takes, as a launch reports them (copr/facts.py)
+DIRECT, SORTED, EXPANDING = "direct", "sorted", "expanding"
 _I32 = np.iinfo(np.int32)
 # a build column's `word` in the packing where it rides in no word
 APART = -1          # gathered by itself: a float, or wider than a word
 KEY_ITSELF = -2     # the build key: a matched row's probe key is it
+UNREAD = -3         # nothing above the join reads it: not carried at all
+
+
+# Word tables lent to `_dense_group`: a table over a sparse range is
+# megabytes of zeros (24 MB for TPC-H's order keys at SF1) of which a
+# build made anew by every statement writes a few percent, and what a
+# fresh one costs is its pages' first touch (20 ms of a 29 ms build on a
+# v5e's host: PERF.md, PR 31), not the writes.  A table comes back with
+# the slots it was given zeroed again, once its copy is on the device.
+_POOL_BYTES = 256 << 20         # kept at most, all lengths together
+_pool: dict = {}                # length -> [zeroed int32 arrays]
+_pool_mu = threading.Lock()
+
+
+def table_slots(span: int) -> int:
+    """The length of a word table over a range of `span` with holes in
+    it: `span` rounded up to five significant bits (at most 1/16 more).
+    The table is a program's input, so its length is part of the
+    program: the orders of one market segment before one date span
+    5,999,777 or 5,999,968 keys by the segment, the date and the data,
+    and every one of them would be a compile of its own (85 s for TPC-H
+    Q3's); rounded they are all 6,029,312.  The bounds check reads the
+    exact range; the slots past it hold no presence bit."""
+    step = max(1 << max(span.bit_length() - 5, 0), 1024)
+    return -(-span // step) * step
+
+
+def _borrow_table(slots: int) -> np.ndarray:
+    with _pool_mu:
+        free = _pool.get(slots)
+        if free:
+            return free.pop()
+    return np.zeros(slots, np.int32)
+
+
+def _return_table(table: np.ndarray, pos) -> None:
+    """Hand back a table whose slots `pos` were written; the caller has
+    seen its copy arrive on the device."""
+    table[pos] = 0
+    with _pool_mu:
+        held = sum(size * 4 * len(free) for size, free in _pool.items())
+        if held + table.nbytes <= _POOL_BYTES:
+            _pool.setdefault(len(table), []).append(table)
 
 
 @dataclass
@@ -51,19 +103,58 @@ class BuildSide:
     def avg_dup(self) -> float:
         return self.rows / max(self.n_unique, 1)
 
+    @property
+    def form(self) -> str:
+        return DIRECT if self.dense else SORTED if self.unique else EXPANDING
+
+    @property
+    def slots(self) -> int:
+        """The length of a direct-addressed side's word tables (its key
+        range, rounded: `table_slots`); the rows of another form."""
+        return int(self.aux[2][0].shape[0]) \
+            if self.dense and len(self.aux) > 2 else self.rows
+
 
 def _fits_i32(lo: int, hi: int) -> bool:
     return _I32.min <= lo and hi <= _I32.max
 
 
+def build_form(rows: int, span: int, columns: int,
+               device_bytes: int = DEFAULT_DEVICE_BYTES) -> str:
+    """The form the build side of a unique lookup takes: DIRECT or
+    SORTED.  Pure: `rows` unique keys over a range of `span`, `columns`
+    build columns beside the key, a device of `device_bytes`.
+
+    What each form costs on a TPU: a direct-addressed table its bytes
+    (a slot is 4 bytes a word; a column takes a word at most, and is
+    carried apart where it does not pack) and a probe ONE gather a word;
+    the sorted form next to no bytes and a probe a binary search, about
+    log2(rows) gathers that wait for each other, every one the price of
+    the direct form's one (2^23 probes in 2^17 keys: seconds against
+    59 ms).  So a table is made wherever one can be: its bytes, were
+    every column to take a word of its own, within a
+    1/DIRECT_MEMORY_SHARE of the device's memory (256 MB of a v5e's
+    16 GB: 67M slots of one word), and its slots indexed by an int32.
+    How sparse the range is does not enter: the sorted form is for
+    ranges no table can span."""
+    del rows                    # a table costs its range, not its keys
+    words = max(columns, 1)     # the presence bit rides in a word
+    if span < 1 << 31 and 4 * span * words \
+            <= device_bytes // DIRECT_MEMORY_SHARE:
+        return DIRECT
+    return SORTED
+
+
 def prepare_build(keys: np.ndarray, cols: list, dense_ok: bool = True,
-                  key_col: int = -1) -> BuildSide:
+                  key_col: int = -1,
+                  device_bytes: int = DEFAULT_DEVICE_BYTES,
+                  read=None) -> BuildSide:
     """Host half of the lookup join: build keys (int64, NULLs already
     dropped, at least one) and the row-aligned build columns [(data,
     validity)] -> the aux group in the form the keys allow.
 
-    Unique keys over a range at most DENSE_MAX_SPREAD times their count
-    are addressed directly (`_dense_group`); anything else is sorted and
+    Unique keys over a range a table can span (`build_form`) are
+    addressed directly (`_dense_group`); anything else is sorted and
     binary-searched, and duplicate keys make the join an expanding one
     (`unique` False: the caller switches the DAG with to_multimatch).
     Keys that fit int32 go up as int32: the program compares at that
@@ -72,18 +163,22 @@ def prepare_build(keys: np.ndarray, cols: list, dense_ok: bool = True,
     `dense_ok` False keeps the sorted form (semi/anti joins read no
     build column).  `key_col`: which of `cols` the keys were taken from,
     if any: where its values ARE the keys a direct-addressed side does
-    not carry it, the probe key of a matched row is the same number."""
+    not carry it, the probe key of a matched row is the same number.
+    `read`: a bool a column, whether the program above the join reads it
+    (dag.build_columns_read; None: all): a direct-addressed side carries
+    no bit of a column nothing reads, so a join's result that comes with
+    its own join keys still fits one word."""
     n = len(keys)
     lo, hi = int(keys.min()), int(keys.max())
     span = hi - lo + 1
-    if span <= max(DENSE_MAX_SPREAD * n, DENSE_MIN_SPAN) \
-            and span <= DENSE_MAX_SPAN:
-        pos = (keys - lo).astype(np.intp)
-        n_unique = int(np.count_nonzero(np.bincount(pos, minlength=span)))
-        if n_unique == n and dense_ok:
-            return _dense_group(pos, lo, span, cols, key_col, keys)
-    else:
-        n_unique = len(np.unique(keys))
+    read = tuple(read) if read is not None else (True,) * len(cols)
+    carried = sum(r for j, r in enumerate(read) if j != key_col)
+    if dense_ok and build_form(n, span, carried, device_bytes) == DIRECT:
+        side = _dense_group((keys - lo).astype(np.intp), lo, span, cols,
+                            key_col, keys, read)
+        if side is not None:        # None: a key comes twice
+            return side
+    n_unique = len(np.unique(keys))
     kdt = np.int32 if _fits_i32(lo, hi) else np.int64
     order = np.argsort(keys, kind="stable")
     aux = [(jnp.asarray(keys[order].astype(kdt)), None),
@@ -95,24 +190,38 @@ def prepare_build(keys: np.ndarray, cols: list, dense_ok: bool = True,
 
 
 def _dense_group(pos, lo: int, span: int, cols: list, key_col: int,
-                 keys) -> BuildSide:
+                 keys, read: tuple) -> Optional[BuildSide]:
     """Scatter the build columns over the key range and pack those that
-    fit into int32 words (dag.LookupJoin has the layout)."""
+    fit into int32 words (dag.LookupJoin has the layout).  None where a
+    key comes twice: what the presence bits count (a range with holes),
+    or the slots hit (a range as long as the keys)."""
     n = len(pos)
     room: list = []                 # free bits of each word
     pbit = -1
     if n != span:                   # holes: one presence bit
         room.append(WORD_BITS - 1)
         pbit = 0
+    else:
+        seen = np.zeros(span, bool)
+        seen[pos] = True
+        if not seen.all():
+            return None
+    # holes: the tables' length is rounded (`table_slots`); none: the
+    # keys' count is the tables', as it always was
+    slots = table_slots(span) if pbit >= 0 else span
     layout, mins, apart = [], [], []
     for j, (data, valid) in enumerate(cols):
-        all_valid = bool(valid.all())
-        if j == key_col and all_valid and data.dtype.kind in "iu" \
+        if j == key_col and data.dtype.kind in "iu" and valid.all() \
                 and np.array_equal(data, keys):
             layout.append((KEY_ITSELF, 0, 0, -1,
                            not _fits_i32(lo, lo + span)))
             mins.append(0)
             continue
+        if not read[j]:
+            layout.append((UNREAD, 0, 0, -1, False))
+            mins.append(0)
+            continue
+        all_valid = bool(valid.all())
         packable = data.dtype.kind in "ib" or (
             data.dtype.kind == "u" and data.dtype.itemsize < 8)
         vmin = vmax = 0
@@ -122,11 +231,11 @@ def _dense_group(pos, lo: int, span: int, cols: list, key_col: int,
         bits = (vmax - vmin).bit_length()
         need = bits + (0 if all_valid else 1)
         if not packable or need > WORD_BITS:
-            table = np.zeros(span, data.dtype)
+            table = np.zeros(slots, data.dtype)
             table[pos] = data
             vtab = None
             if not all_valid:
-                vtab = np.zeros(span, bool)
+                vtab = np.zeros(slots, bool)
                 vtab[pos] = valid
             apart.append((table, vtab))
             layout.append((APART, 0, 0, -1, True))
@@ -140,26 +249,41 @@ def _dense_group(pos, lo: int, span: int, cols: list, key_col: int,
         vbit = -1 if all_valid else shift + bits
         layout.append((w, shift, bits, vbit, not _fits_i32(vmin, vmax)))
         mins.append(vmin)
-    words = [np.zeros(span, np.int64) for _ in room]
+    # a row's words first (n values), then ONE int32 scatter a word over
+    # the range: a sparse range is paid in its bytes once
+    row_words = [np.zeros(n, np.int64) for _ in room]
     if pbit >= 0:
-        words[0][pos] = 1
+        row_words[0][:] = 1
     for (w, shift, bits, vbit, _wide), vmin, (data, valid) in zip(
             layout, mins, cols):
         if w < 0:
             continue
         if bits:
             v = data.astype(np.int64) - vmin
-            words[w][pos] |= (v if vbit < 0 else np.where(valid, v, 0)) << shift
+            row_words[w] |= (v if vbit < 0 else np.where(valid, v, 0)) << shift
         if vbit >= 0:
-            words[w][pos] |= valid.astype(np.int64) << vbit
+            row_words[w] |= valid.astype(np.int64) << vbit
+    tables = []
+    for rw in row_words:
+        table = _borrow_table(slots)
+        table[pos] = rw             # below 2^31: WORD_BITS
+        tables.append(table)
+    if pbit >= 0 and np.count_nonzero(tables[0]) != n:
+        for t in tables:
+            _return_table(t, pos)
+        return None                 # two rows wrote one slot's presence
     kdt = np.int32 if _fits_i32(lo, lo + span) else np.int64
     aux = [(jnp.asarray(np.array([lo, span], kdt)), None),
            (jnp.asarray(np.array(mins, np.int64).reshape(len(cols))), None)]
-    aux += [(jnp.asarray(w.astype(np.int32)), None) for w in words]
+    on_device = [jnp.array(t) for t in tables]     # a copy, never a view
+    jax.block_until_ready(on_device)    # then the tables can go back
+    for t in tables:
+        _return_table(t, pos)
+    aux += [(w, None) for w in on_device]
     aux += [(jnp.asarray(t), None if v is None else jnp.asarray(v))
             for t, v in apart]
     return BuildSide(tuple(aux), n, True, n, dense=True,
-                     packing=(len(words), pbit, tuple(layout)))
+                     packing=(len(tables), pbit, tuple(layout)))
 
 
 def build_rows(node, grp) -> int:
@@ -170,5 +294,6 @@ def build_rows(node, grp) -> int:
     return int(grp[2][0].shape[0]) if len(grp) > 2 else 0
 
 
-__all__ = ["BuildSide", "prepare_build", "build_rows", "WORD_BITS", "APART",
-           "KEY_ITSELF"]
+__all__ = ["BuildSide", "prepare_build", "build_form", "table_slots",
+           "build_rows", "WORD_BITS", "APART", "KEY_ITSELF", "UNREAD",
+           "DIRECT", "SORTED", "EXPANDING"]

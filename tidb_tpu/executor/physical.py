@@ -478,6 +478,10 @@ class CopJoinTaskExec(PhysOp):
     build_key_index: int = 0
     build_key_dict: Any = None     # probe-side StringDict for string keys
     probe_key_dtype: Any = None    # for decimal scale alignment
+    # (table, column offset) the build key is a base column of, or None:
+    # EXPLAIN's `join forms:` reads it (each entry of `builds` has its
+    # own under "key_source")
+    build_key_source: Any = None
     join_kind: str = "inner"
     null_aware: bool = False
     n_probe: int = 0
@@ -495,6 +499,10 @@ class CopJoinTaskExec(PhysOp):
     # the planner's estimate of the rows the filters beneath the (lowest)
     # join leave of the probe table; 0 = no statistics (`_compacted`)
     probe_est_rows: float = 0.0
+    # a single inner join: the distinct values ANALYZE found in the
+    # probe key's column; 0 = no statistics.  With the build side's rows
+    # it says which share of the probe rows can find a match
+    probe_key_ndv: float = 0.0
 
     def __post_init__(self):
         self.children = ([b["exec"] for b in self.builds] if self.builds
@@ -510,6 +518,41 @@ class CopJoinTaskExec(PhysOp):
         if self.builds:
             return self._execute_tree(ctx)
         return self._execute_single(ctx)
+
+    def build_forms(self, device_bytes: int) -> list:
+        """(table.column, form, slots) a build side, lowest join first:
+        the form the key's whole range in its table allows on a device
+        of that memory (copr/joinbuild.build_form; a filter beneath the
+        build can only narrow the range), "expanding" where the column
+        holds a value twice; (None, "?", 0) for a computed key."""
+        from ..copr.joinbuild import (DIRECT, EXPANDING, SORTED, build_form,
+                                      table_slots)
+        sides = [(b["exec"], b.get("key_source")) for b in self.builds] \
+            if self.builds else [(self.build_exec, self.build_key_source)]
+        semi = self.join_kind in ("semi", "anti")
+        out = []
+        for b_exec, source in sides:
+            if source is None:
+                out.append((None, "?", 0))
+                continue
+            table, offset = source
+            snap = table.snapshot()
+            lo, hi = snap.key_range(offset)
+            name = f"{table.name}.{snap.names[offset]}"
+            if hi < lo:
+                out.append((name, "none", 0))
+            elif semi:
+                out.append((name, SORTED, snap.num_rows))
+            elif not snap.key_is_unique(offset):
+                out.append((name, EXPANDING, snap.num_rows))
+            else:
+                span = hi - lo + 1
+                form = build_form(snap.num_rows, span,
+                                  len(b_exec.out_names) - 1, device_bytes)
+                holes = snap.num_rows != span
+                out.append((name, form, snap.num_rows if form != DIRECT
+                            else table_slots(span) if holes else span))
+        return out
 
     def _execute_tree(self, ctx: ExecContext) -> ResultChunk:
         """Chained broadcast joins: every level's build must be non-empty
@@ -533,9 +576,12 @@ class CopJoinTaskExec(PhysOp):
 
     def _execute_single(self, ctx: ExecContext) -> ResultChunk:
         semi = self.join_kind in ("semi", "anti")
-        built = _prepared_build(ctx, self.build_exec, self.build_key_index,
-                                self._keys_for, self.build_key_dict,
-                                self.probe_key_dtype, want_cols=not semi)
+        (join,) = D.lookup_joins(self.dag) or (None,)
+        built = _prepared_build(
+            ctx, self.build_exec, self.build_key_index, self._keys_for,
+            self.build_key_dict, self.probe_key_dtype, want_cols=not semi,
+            read=None if semi or join is None
+            else D.build_columns_read(self.dag, join))
         side = built.side
         dag = self.dag
         if self.null_aware and built.null_key:
@@ -567,7 +613,7 @@ class CopJoinTaskExec(PhysOp):
                 dag = D.rewrite_lookup(dag, dense=True,
                                        packing=side.packing)
             if not semi:
-                dag = self._compacted(ctx, dag)
+                dag = self._compacted(ctx, dag, side.rows)
         chunk = self._run(ctx, dag, (side.aux,))   # one aux group
         # build-side output columns keep their own dictionaries
         if not isinstance(self.dag, D.Aggregation):
@@ -578,7 +624,7 @@ class CopJoinTaskExec(PhysOp):
                         c.dictionary = built.dicts[bj]
         return chunk
 
-    def _compacted(self, ctx, dag):
+    def _compacted(self, ctx, dag, build_rows: int = 0):
         """`dag` with its lowest (unique inner/left) join told to compact
         its live probe rows before the lookup (dag.LookupJoin
         `probe_capacity`), where that is exact and pays: the rows feed
@@ -586,23 +632,34 @@ class CopJoinTaskExec(PhysOp):
         program is lowered for a platform whose gather costs its indices
         (not the CPU mesh); the planner had statistics to estimate the
         probe rows the filters leave; and `dag.probe_capacity_for` finds
-        a capacity for a device's share of them.  Otherwise `dag` as it
-        is: today's program, digest and all.  A capacity that falls
-        short costs one rerun of the exact program (store/client
-        `_uncompacted`)."""
+        a capacity for a device's share of them.  Where the filters keep
+        too much for that but the build side keeps little (`build_rows`
+        of a single inner join's, against the distinct values of the
+        probe key: the share of the probe rows that can find a match),
+        the join compacts its matched rows after the lookup instead
+        (`match_capacity`), and what is above it runs on those.
+        Otherwise `dag` as it is: today's program, digest and all.  A
+        capacity that falls short costs one rerun of the exact program
+        (store/client `_uncompacted`)."""
         from ..parallel import spmd
         if not isinstance(dag, D.Aggregation) \
                 or spmd.mesh_platform(ctx.client.mesh) == "cpu":
             return dag
         n_dev = len(ctx.client.mesh.devices.reshape(-1))
         per_dev = -(-max(self.table.snapshot().num_rows, 1) // n_dev)
+        *above, lowest = (n for n in D.iter_nodes(dag)
+                          if isinstance(n, D.LookupJoin))
         cap = D.probe_capacity_for(self.probe_est_rows / n_dev, per_dev)
-        if not cap:
+        if cap:
+            return D.rewrite_lookup(dag, pred=lambda j: j is lowest,
+                                    probe_capacity=cap)
+        if above or lowest.kind != "inner" or not build_rows \
+                or not self.probe_key_ndv:
             return dag
-        *_above, lowest = (n for n in D.iter_nodes(dag)
-                           if isinstance(n, D.LookupJoin))
-        return D.rewrite_lookup(dag, pred=lambda j: j is lowest,
-                                probe_capacity=cap)
+        matched = self.probe_est_rows \
+            * min(build_rows / self.probe_key_ndv, 1.0)
+        cap = D.probe_capacity_for(matched / n_dev, per_dev)
+        return D.rewrite_lookup(dag, match_capacity=cap) if cap else dag
 
     def _run(self, ctx, dag, aux) -> ResultChunk:
         """Dispatch the fused program and decode with output dicts."""
@@ -2213,34 +2270,40 @@ def _resident_snapshot(b_exec):
 
 
 def _prepared_build(ctx, b_exec, key_index, keys_for, key_dict,
-                    probe_key_dtype, want_cols: bool) -> _PreparedBuild:
+                    probe_key_dtype, want_cols: bool,
+                    read=None) -> _PreparedBuild:
     """Run a build side's plan, drop NULL keys and hand the rest to
     copr/joinbuild.prepare_build: fetch + dedup + sort/scatter + upload, all
     inside ``cop.join_build``.  Where the build side is an unfiltered or
     constant-filtered resident table the result stays with the table's
-    snapshot, and the span of a repeat is the lookup."""
+    snapshot, and the span of a repeat is the lookup.  `read`: which
+    build columns the program above the join reads (None: all)."""
     from ..copr.joinbuild import prepare_build
     from ..obs.trace import span
     with span("cop.join_build"):
         snap = _resident_snapshot(b_exec)
+        source = "join" if any(isinstance(op, CopJoinTaskExec)
+                               for op in _walk(b_exec)) else "table"
         key = None
         if snap is not None:
-            key = (b_exec.dag, key_index, probe_key_dtype, want_cols)
+            key = (b_exec.dag, key_index, probe_key_dtype, want_cols, read)
             hit = snap._join_builds.get(key)
             if hit is not None and hit.key_dict is key_dict:
                 snap._join_builds[key] = snap._join_builds.pop(key)  # LRU
-                _annotate_build(hit, cached=True)
+                _annotate_build(hit, source, cached=True)
                 return hit
         bchunk = b_exec.execute(ctx)
         kcol = bchunk.columns[key_index]
         keys, ok = keys_for(kcol, key_dict, probe_key_dtype)
-        rows_idx = np.nonzero(ok)[0]           # NULL keys never join
+        rows_idx = slice(None) if ok.all() \
+            else np.nonzero(ok)[0]             # NULL keys never join
         side = None
-        if len(rows_idx):
+        if len(keys[rows_idx]):
             cols = [(c.data[rows_idx], c.validity[rows_idx])
                     for c in bchunk.columns] if want_cols else []
             side = prepare_build(keys[rows_idx], cols, dense_ok=want_cols,
-                                 key_col=key_index)
+                                 key_col=key_index, read=read,
+                                 device_bytes=_device_bytes(ctx.client.mesh))
         built = _PreparedBuild(side, not kcol.validity.all(),
                                [c.dictionary for c in bchunk.columns],
                                key_dict)
@@ -2249,16 +2312,40 @@ def _prepared_build(ctx, b_exec, key_index, keys_for, key_dict,
             kept[key] = built
             while len(kept) > _JOIN_BUILDS_KEPT:
                 kept.pop(next(iter(kept)))
-        _annotate_build(built, cached=False)
+        _annotate_build(built, source, cached=False)
         return built
 
 
-def _annotate_build(built: _PreparedBuild, cached: bool) -> None:
+def _walk(op):
+    """A physical operator and everything beneath it."""
+    yield op
+    for child in getattr(op, "children", None) or ():
+        yield from _walk(child)
+
+
+def _device_bytes(mesh) -> int:
+    """The memory of one of the mesh's devices, as the build-form rule
+    reads it (copr/joinbuild.build_form); a device that does not say
+    (the CPU mesh) counts as the default there."""
+    from ..copr.joinbuild import DEFAULT_DEVICE_BYTES
+    from ..obs.hbm import device_memory_stats
+    stats = device_memory_stats(mesh) or {}
+    return int(stats.get("bytes_limit") or DEFAULT_DEVICE_BYTES)
+
+
+def _annotate_build(built: _PreparedBuild, source: str,
+                    cached: bool) -> None:
+    """What the span says of the build side: its rows, the form it took
+    (copr/joinbuild: direct | sorted | expanding; "none": no live key),
+    the slots of that form (the key range of a direct-addressed side),
+    and whether it was made from a table's rows or from a join's."""
     from ..obs.trace import annotate
     side = built.side
     annotate(rows=side.rows if side else 0,
              unique=bool(side and side.unique),
-             dense=bool(side and side.dense), cached=cached)
+             dense=bool(side and side.dense), cached=cached,
+             form=side.form if side else "none",
+             slots=side.slots if side else 0, source=source)
 
 
 def _prep_build_groups(ctx, builds, keys_for, dag):

@@ -793,12 +793,17 @@ def _try_cop_join(p: LogicalPlan, top, mids, join: LogicalJoin) -> Optional[Phys
                 return None
     fallback = to_physical(p, no_device_join=True)
     probe_est = 0.0 if semi else _probe_rows_estimate(join.left)
+    key_ndv = 0.0
+    if join.kind == "inner" and not builds and probe_est:
+        from ..planner.join_reorder import _col_ndv
+        key_ndv = _col_ndv(join.left, li, STATS_HANDLE.get(), 0.0)
     if builds:
         # fragment chain: nested builds + this join's own build, in aux
         # slot order; runtime anomalies fall back to the host plan whole
         builds.append({"exec": build_exec, "key_index": ri,
                        "key_dict": key_dict,
-                       "probe_key_dtype": probe_key.dtype})
+                       "probe_key_dtype": probe_key.dtype,
+                       "key_source": _base_column(join.right, ri)})
         exec_ = CopJoinTaskExec(
             nodew, ds.table, join_kind=join.kind, n_probe=n_probe,
             out_names=out_names, out_dtypes=out_dtypes, key_meta=key_meta,
@@ -808,9 +813,11 @@ def _try_cop_join(p: LogicalPlan, top, mids, join: LogicalJoin) -> Optional[Phys
         exec_ = CopJoinTaskExec(
             nodew, ds.table, build_exec=build_exec, build_key_index=ri,
             build_key_dict=key_dict, probe_key_dtype=probe_key.dtype,
+            build_key_source=_base_column(join.right, ri),
             join_kind=join.kind, null_aware=join.null_aware, n_probe=n_probe,
             out_names=out_names, out_dtypes=out_dtypes, key_meta=key_meta,
-            out_dicts=out_dicts, fallback=fallback, probe_est_rows=probe_est)
+            out_dicts=out_dicts, fallback=fallback, probe_est_rows=probe_est,
+            probe_key_ndv=key_ndv)
     if host_top is not None and host_top[0] == "topn":
         return HostTopN(exec_, list(host_top[1].keys), host_top[1].limit,
                         host_top[1].offset)
@@ -866,6 +873,31 @@ def _unique_build_key(plan: LogicalPlan, key: int) -> Optional[int]:
     if declared or table.snapshot().key_is_unique(cur.col_offsets[key]):
         return table.num_rows
     return None
+
+
+def _base_column(plan: LogicalPlan, key: int):
+    """(table, column offset) of the base-table column that output
+    column `key` of `plan` is, through Selections, ColumnRef Projections
+    and joins; None where it is computed.  EXPLAIN reads it to say which
+    form a build side's key range allows (copr/joinbuild.build_form)."""
+    cur = plan
+    while not isinstance(cur, DataSource):
+        if isinstance(cur, LogicalSelection):
+            cur = cur.child
+        elif isinstance(cur, LogicalProjection):
+            e = cur.exprs[key]
+            if not isinstance(e, ColumnRef):
+                return None
+            key, cur = e.index, cur.child
+        elif isinstance(cur, LogicalJoin):
+            n_left = len(cur.left.schema)
+            if key < n_left:
+                cur = cur.left
+            else:
+                key, cur = key - n_left, cur.right
+        else:
+            return None
+    return cur.table, cur.col_offsets[key]
 
 
 def _build_the_unique_side(join: LogicalJoin, mids: list):
@@ -986,7 +1018,8 @@ def _bind_join_tree(join: LogicalJoin, builds: list):
                          aux_slot=slot)
     builds.append({"exec": to_physical(join.right), "key_index": ri,
                    "key_dict": key_dict,
-                   "probe_key_dtype": probe_key.dtype})
+                   "probe_key_dtype": probe_key.dtype,
+                   "key_source": _base_column(join.right, ri)})
     all_dicts = dict(cur_dicts)
     for j, d in (_subtree_output_dicts(join.right) or {}).items():
         all_dicts[n_probe + j] = d

@@ -31,8 +31,9 @@ from ..copr import dag as D
 from ..copr import facts as F
 from ..copr.aggregate import _MERGE
 from ..copr.exec import (DeviceBatch, _agg_partial_states, _exec_node,
-                         agg_states, compact, dense_limb_form, dense_view)
-from ..copr.joinbuild import build_rows
+                         agg_states, compact, compact_root, dense_limb_form,
+                         dense_view)
+from ..copr.joinbuild import DIRECT, EXPANDING, SORTED, build_rows
 from ..expr.compile import Evaluator
 from .mesh import SHARD_AXIS, mesh_platform, shard_map
 
@@ -232,7 +233,15 @@ class ShardedCopProgram:
                         len(self.mesh.devices.reshape(-1)))
         else:
             batch = _exec_node(self.root, flat, base_sel, ev, aux, stacked)
-            out_cols, n = compact(batch, self.row_capacity)
+            # a TopN's or a Limit's few rows leave in their order, at
+            # the front; any other root's by `compact_root`, which adds
+            # the slots' live mask as a last column (store/client
+            # `_assemble_rows` reads it)
+            if isinstance(self.root, (D.TopN, D.Limit)):
+                out_cols, n = compact(batch, self.row_capacity)
+            else:
+                out_cols, n = compact_root(batch, self.row_capacity,
+                                           self.platform)
             # keep a leading per-device axis so out_specs can shard it
             out = ([(v[None], m[None]) for v, m in out_cols], n[None])
         self._traced[(stacked, cap)] = F.of_program(batch.facts, self.root)
@@ -259,11 +268,16 @@ class ShardedCopProgram:
         if joins:
             out["join"] = "unique" if all(j.unique for j in joins) \
                 else "multimatch"
+            out["join_form"] = ",".join(
+                DIRECT if j.dense else EXPANDING if not j.unique
+                and j.kind in ("inner", "left") else SORTED
+                for j in joins)
             out["probe_rows"] = s * c
             out["build_rows"] = sum(build_rows(j, aux_cols[j.aux_slot])
                                     for j in joins)
             if out["join"] == "unique":
                 out["probe_capacity"] = max(j.probe_capacity for j in joins)
+                out["match_capacity"] = max(j.match_capacity for j in joins)
         return out
 
     def __call__(self, stacked_cols: Sequence, counts, aux_cols=()):

@@ -1383,6 +1383,9 @@ class Session:
             strat = self._agg_strategy_footer(phys)
             if strat is not None:
                 rows.append((strat,))
+            forms = self._join_forms_footer(phys)
+            if forms is not None:
+                rows.append((forms,))
         return ResultSet(["plan"], rows)
 
     def _cost_footer(self, phys) -> Optional[str]:
@@ -1481,6 +1484,24 @@ class Session:
                 return "cost: static"
             return f"cost: calibrated (err {ent.err * 100:.0f}%)"
         except (AttributeError, TypeError, ValueError, ImportError):
+            return None
+
+    def _join_forms_footer(self, phys) -> Optional[str]:
+        """EXPLAIN ``join forms:`` tag: the form each lookup join's
+        build side will take on this server's devices, top join first
+        and a chain's lowest level first (CopJoinTaskExec.build_forms).
+        None for a plan without a lookup join; must never break
+        EXPLAIN."""
+        try:
+            from ..executor.physical import (CopJoinTaskExec, _device_bytes,
+                                             _walk)
+            memory = _device_bytes(self.domain.client.mesh)
+            said = [f"{name or 'a computed key'} {form}"
+                    + (f" ({slots} slots)" if form == "direct" else "")
+                    for op in _walk(phys) if isinstance(op, CopJoinTaskExec)
+                    for name, form, slots in op.build_forms(memory)]
+            return "join forms: " + ", ".join(said) if said else None
+        except (AttributeError, TypeError, KeyError, ValueError):
             return None
 
     def _run_form_footer(self, dag) -> str:

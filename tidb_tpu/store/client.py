@@ -105,6 +105,11 @@ class CopClient:
         # planner guessed (`_wider_record`): digest -> the words the next
         # statement starts with.  Guarded by _pf_mu, capped alike.
         self._record_words: OrderedDict[int, int] = OrderedDict()
+        # a host-merged aggregation whose group table was regrown:
+        # digest -> the capacity the next statement of it starts with
+        # (`_execute_sort_agg`; a GROUP BY above a join has no column
+        # statistics to size its table from).  Guarded and capped alike.
+        self._group_caps: OrderedDict[int, int] = OrderedDict()
         # coprocessor RESULT cache (copr/coprocessor_cache.go analog):
         # key = (dag digest, snapshot epoch, placement epoch, shard
         # layout); a table write creates a new snapshot + epoch, so stale
@@ -758,7 +763,7 @@ class CopClient:
         the DAG with every slot looked up, which the statement is rerun
         with; the digest is remembered (`_join_form`).  None when they
         fit."""
-        if need <= D.compacting_join(dag).probe_capacity:
+        if need <= D.compact_capacity(D.compacting_join(dag)):
             return None
         self._scheduler().count("join_compact_overflows")
         with self._pf_mu:
@@ -810,8 +815,10 @@ class CopClient:
                 cols = list(cols) + [(hv, None)]
                 agg = hashed_dag
         agg = self._group_form(agg)
-        cap = self._warm_cap(agg, agg.state_capacity
-                             or DEFAULT_GROUP_CAPACITY)
+        with self._pf_mu:
+            regrown = self._group_caps.get(self._record_key(agg), 0)
+        cap = self._warm_cap(agg, max(agg.state_capacity
+                                      or DEFAULT_GROUP_CAPACITY, regrown))
         if aux_cols:
             agg = self._join_form(agg)
         for _ in range(10):
@@ -838,6 +845,10 @@ class CopClient:
                 break
             self._scheduler().count("hndv_agg_regrows")
             cap = self._warm_cap(agg, _pow2_at_least(true_ng))
+            with self._pf_mu:
+                self._group_caps[self._record_key(agg)] = cap
+                while len(self._group_caps) > self._page_feedback_cap:
+                    self._group_caps.popitem(last=False)
         else:
             raise RuntimeError("group-capacity regrow did not converge")
         with _obs_span("cop.host_merge", kind="sorted"):
@@ -1001,20 +1012,32 @@ class CopClient:
 
     def _assemble_rows(self, out_cols, out_counts, cap, out_dtypes,
                        dictionaries) -> list[Column]:
-        """Concatenate per-device compacted outputs into host Columns."""
+        """Concatenate per-device compacted outputs into host Columns.
+        A device's rows are its first `count` slots, or, where the
+        program put out its slots' live mask as one more column than the
+        schema has (copr/exec `compact_root`), the slots that says."""
         _faults.check("transfer")   # faultline device->host seam
         n_dev = len(self.mesh.devices.reshape(-1))
         out_cols, out_counts = self._fetch((out_cols, out_counts))
         out_counts = np.asarray(out_counts)
         per_dev_take = np.minimum(out_counts, cap)
+        if len(out_cols) > len(out_dtypes):
+            live = np.asarray(out_cols[len(out_dtypes)][0])
+            take = [np.nonzero(live[d])[0] for d in range(n_dev)]
+        else:
+            take = [slice(0, per_dev_take[d]) for d in range(n_dev)]
+
+        def rows_of(a):
+            a = np.asarray(a)
+            if n_dev == 1:
+                return a[0][take[0]]
+            return np.concatenate([a[d][take[d]] for d in range(n_dev)])
         result = []
         for j, t in enumerate(out_dtypes):
-            data = np.concatenate([np.asarray(out_cols[j][0])[d, :per_dev_take[d]]
-                                   for d in range(n_dev)])
-            valid = np.concatenate([np.asarray(out_cols[j][1])[d, :per_dev_take[d]]
-                                    for d in range(n_dev)])
+            data, valid = rows_of(out_cols[j][0]), rows_of(out_cols[j][1])
             dic = dictionaries.get(j) if dictionaries else None
-            result.append(Column(t, data.astype(t.np_dtype()), valid, dic))
+            result.append(Column(t, data.astype(t.np_dtype(), copy=False),
+                                 valid, dic))
         return result
 
     # ------------------------------------------------------------- #
@@ -1044,15 +1067,18 @@ class CopClient:
         fb_key = D.dag_digest(root)
         per_shard = -(-snap.num_rows // max(snap.n_shards, 1)) \
             if snap.num_rows else 1
+        # a capacity is a device's, and a device holds several shards
+        per_dev = per_shard * -(-max(snap.n_shards, 1) // n_dev)
         if is_topn or is_limit:
             cap = max(root.limit, 16)
         else:
             with self._pf_mu:
                 fb = self._page_feedback.get(fb_key)
             if fb is not None:
-                # prior observation + 50% headroom, clamped to the shard
+                # prior observation + 50% headroom, clamped to the
+                # device's rows
                 cap = _pow2_at_least(
-                    max(int(per_shard * min(fb * 1.5, 1.0)) + 1, 256))
+                    max(int(per_dev * min(fb * 1.5, 1.0)) + 1, 256))
             else:
                 cap = max(_pow2_at_least(
                     max(per_shard // INITIAL_SELECTIVITY, 1)), 1024)
@@ -1079,14 +1105,15 @@ class CopClient:
             out_counts = np.asarray(self._fetch(out_counts))
             if (out_counts <= cap).all():
                 break
+            self._scheduler().count("rows_regrows")
             cap = self._warm_cap(root, _pow2_at_least(int(out_counts.max())))
         else:
             raise RuntimeError("paging loop did not converge")
         with self._stat_mu:
             self.last_page_iters = page_iters
 
-        if not (is_topn or is_limit) and per_shard > 0:
-            frac = float(out_counts.max()) / per_shard
+        if not (is_topn or is_limit) and per_dev > 0:
+            frac = float(out_counts.max()) / per_dev
             with self._pf_mu:
                 old = self._page_feedback.get(fb_key, frac)
                 self._page_feedback[fb_key] = 0.5 * old + 0.5 * frac

@@ -54,6 +54,7 @@ class ColumnarSnapshot:
     # (executor/physical._prepared_build): a new epoch is a new object
     _join_builds: dict = field(default_factory=dict, repr=False)
     _unique_keys: dict = field(default_factory=dict, repr=False)
+    _key_ranges: dict = field(default_factory=dict, repr=False)
 
     @property
     def num_rows(self) -> int:
@@ -79,6 +80,19 @@ class ColumnarSnapshot:
                 known = bool((data[1:] > data[:-1]).all()) \
                     or len(np.unique(data)) == len(data)
             self._unique_keys[offset] = known
+        return known
+
+    def key_range(self, offset: int) -> tuple[int, int]:
+        """(least, largest) non-NULL value of integer column `offset`;
+        (0, -1) where it has none or is no integer column.  Kept with
+        the snapshot, as `key_is_unique` is."""
+        known = self._key_ranges.get(offset)
+        if known is None:
+            c = self.columns[offset]
+            data = c.data if c.validity.all() else c.data[c.validity]
+            known = (int(data.min()), int(data.max())) \
+                if len(data) and data.dtype.kind in "iu" else (0, -1)
+            self._key_ranges[offset] = known
         return known
 
     # ---------------- shard plan ---------------- #
