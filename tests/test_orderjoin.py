@@ -25,7 +25,7 @@ from tidb_tpu.copr import dag as D
 from tidb_tpu.copr import exec as X
 from tidb_tpu.copr import facts as F
 from tidb_tpu.copr import joinbuild as JB
-from tidb_tpu.expr import ColumnRef
+from tidb_tpu.expr import ColumnRef, Const, Func
 from tidb_tpu.expr.compile import Evaluator
 from tidb_tpu.parallel import get_mesh, spmd
 from tidb_tpu.sched import scheduler_for
@@ -290,6 +290,116 @@ LIVES = {"none": 0, "one": 1, "C-1": C - 1, "C": C, "C+1": C + 1,
          "run": C, "all": N}
 
 
+# --------------------------------------------------------------------- #
+# which group keys the others determine: a pure function of the DAG
+# --------------------------------------------------------------------- #
+
+def _c(i, t=I64, name=""):
+    return ColumnRef(t, i, name)
+
+
+def _fact_scan():
+    return D.TableScan((0, 1, 2), (I64N, I64, I64))     # k, a, v
+
+
+def _lookup_node(child, key, kind="inner", unique=True, slot=0):
+    """A join that brings two build columns (none for a semi join)."""
+    semi = kind in ("semi", "anti")
+    t = I64N if kind == "left" else I64
+    return D.LookupJoin(child, probe_key=key, kind=kind, unique=unique,
+                        build_dtypes=() if semi else (t, t), aux_slot=slot)
+
+
+def _sum(i=2):
+    return (D.AggDesc(D.AggFunc.SUM, _c(i), dt.decimal(38, 0)),)
+
+
+def _grouped(child, keys):
+    return D.Aggregation(child, tuple(keys), _sum(), D.GroupStrategy.SORT,
+                         group_capacity=1024)
+
+
+_K1000A = Func(I64, "add", (Func(I64, "mul", (_c(0, I64N), Const(I64, 1000))),
+                            _c(1)))
+_ONE = _lookup_node(_fact_scan(), _c(0, I64N))
+_CHAIN = _lookup_node(_ONE, _c(1), slot=1)          # builds: 3, 4 then 5, 6
+_CHAIN_ON_BUILD = _lookup_node(_ONE, _c(3), slot=1)
+
+RULE = {
+    "a unique inner join": (_ONE, [_c(0, I64N), _c(3), _c(4)], (1, 2)),
+    "a unique left join": (
+        _lookup_node(_fact_scan(), _c(0, I64N), "left"),
+        [_c(0, I64N), _c(3, I64N), _c(4, I64N)], (1, 2)),
+    "the names of the columns do not matter": (
+        _ONE, [_c(0, I64N, "fact.k"), _c(4, I64, "head.g")], (1,)),
+    "a multimatch build": (
+        _lookup_node(_fact_scan(), _c(0, I64N), unique=False),
+        [_c(0, I64N), _c(3), _c(4)], ()),
+    "a semi join brings no column": (
+        _lookup_node(_fact_scan(), _c(0, I64N), "semi"),
+        [_c(0, I64N), _c(1)], ()),
+    "a probe key that is not grouped": (_ONE, [_c(1), _c(3)], ()),
+    "a composite key half grouped": (
+        _lookup_node(_fact_scan(), _K1000A), [_c(0, I64N), _c(3)], ()),
+    "a computed key grouped as it is probed": (
+        _lookup_node(_fact_scan(), _K1000A), [_K1000A, _c(3), _c(1)], (1,)),
+    "an expression over build columns": (
+        _ONE, [_c(0, I64N), Func(I64, "add", (_c(3), _c(4)))], (1,)),
+    "an expression over a build and a probe column": (
+        _ONE, [_c(0, I64N), Func(I64, "add", (_c(3), _c(1)))], ()),
+    "a constant": (_ONE, [_c(0, I64N), Const(I64, 7), _c(3)], (2,)),
+    "under a filter and a projection": (
+        D.Projection(D.Selection(_ONE, (Func(I64, "lt", (
+            _c(3), Const(I64, 9))),)), (_c(4), _c(2), _c(0, I64N))),
+        [_c(2, I64N), _c(0)], (1,)),
+    "a chain, each level's key grouped": (
+        _CHAIN, [_c(0, I64N), _c(1), _c(3), _c(6)], (2, 3)),
+    "a chain, one level's key not grouped": (
+        _CHAIN, [_c(0, I64N), _c(3), _c(6)], (1,)),
+    "a chain over both levels' columns": (
+        _CHAIN, [_c(0, I64N), _c(1), Func(I64, "add", (_c(4), _c(5)))],
+        (2,)),
+    "a chain probed with a dependent key": (
+        _CHAIN_ON_BUILD, [_c(0, I64N), _c(3), _c(5)], (1,)),
+    "a chain probed with a build column of a multimatch level": (
+        _lookup_node(_lookup_node(_fact_scan(), _c(0, I64N), unique=False),
+                     _c(3), slot=1),
+        [_c(0, I64N), _c(3), _c(5)], (2,)),
+    "a limit between": (D.Limit(_ONE, 5), [_c(0, I64N), _c(3)], ()),
+    "no join": (_fact_scan(), [_c(0, I64N), _c(1)], ()),
+}
+
+
+@pytest.mark.parametrize("case", list(RULE))
+def test_which_group_keys_the_others_determine(case):
+    """`dag.with_dependent_keys`: a key is dependent when all it reads
+    are build columns of unique inner or left joins whose probe keys are
+    group keys that are not dependent themselves; whatever else leaves
+    the aggregation as it was given, the same object."""
+    child, keys, want = RULE[case]
+    agg = _grouped(child, keys)
+    got = D.with_dependent_keys(agg)
+    assert got.dependent == want
+    assert dataclasses.replace(got, dependent=()) == agg
+    if not want:
+        assert got is agg
+    assert D.with_dependent_keys(got) is got
+    from tidb_tpu.analysis.compilekey import stable_digest
+    assert (stable_digest(got) == stable_digest(agg)) == (not want)
+
+
+def test_dependence_is_asked_of_grouped_aggregations_alone():
+    scalar = D.Aggregation(_ONE, (), _sum(), D.GroupStrategy.SCALAR)
+    assert D.with_dependent_keys(scalar) is scalar
+    assert D.with_dependent_keys(_ONE) is _ONE
+    from tidb_tpu.analysis.contracts import PlanContractError, verify_dag
+    flat = _grouped(_fact_scan(), [_c(0, I64N), _c(1)])
+    verify_dag(dataclasses.replace(flat, dependent=(1,)))
+    for bad in ((0, 1), (2,), (1, 1)):
+        with pytest.raises(PlanContractError):
+            verify_dag(dataclasses.replace(flat, dependent=bad))
+
+
 def _sel(case: str, seed=1):
     sel = np.zeros(N, bool)
     grid = sel.reshape(N // COLS, COLS)
@@ -470,7 +580,11 @@ def _spans(sess, name):
 COUNTERS = ("join_launches", "join_direct_launches", "rows_launches",
             "rows_compact_launches", "rows_regrows", "join_host_fallbacks",
             "join_shuffle_launches", "join_compact_overflows",
-            "join_match_compact_launches", "hndv_agg_regrows")
+            "join_match_compact_launches", "hndv_agg_regrows",
+            "hndv_agg_launches", "agg_dependent_key_launches",
+            "group_topn_device_launches", "hndv_host_topn_launches")
+Q3_FORM = ("one sort of 2-word records, o_orderdate, o_shippriority riding "
+           "as dependents of the join's key, first 10 groups ranked on the ")
 
 
 @pytest.mark.parametrize("devices", [1, 4])
@@ -479,7 +593,10 @@ def test_spec_text_equals_the_reference(tpch, lowered_for, name, devices):
     """Both spec texts, on one and on four devices, every program lowered
     as for a TPU: the oracle's rows text for text (Q3's ten in its
     order); every lookup direct-addressed; no fallback, no repartition
-    join."""
+    join.  Q3's GROUP BY keeps `o_orderdate` and `o_shippriority`, which
+    the unique `orders` build brings, out of its two-word record; one
+    device ranks its groups, four leave it to the host, which merges
+    their tables; Q12's DENSE root knows none of it."""
     dom, classes = tpch
     mod, state = classes[name]
     lowered_for("tpu")
@@ -501,12 +618,31 @@ def test_spec_text_equals_the_reference(tpch, lowered_for, name, devices):
                                     for a in launches)
             for b in _spans(sess, "cop.join_build"):
                 assert b["form"] == "direct" and b["slots"] >= b["rows"]
+            grouped = [a for a in launches if "agg_strategy" in a]
+            if name == "q3":
+                assert [(a["dependent_keys"], a["group_topn"])
+                        for a in grouped] \
+                    == [(2, "device" if devices == 1 else "host")]
+                (form,) = [r[0] for r in sess.execute(
+                    "explain " + mod.sql(p)).rows
+                    if r[0].startswith("agg strategy")]
+                assert Q3_FORM + ("device" if devices == 1 else "host") \
+                    in form
+            else:
+                assert not grouped and not any(
+                    "dependent_keys" in a for a in launches)
         after = sched.stats()
     finally:
         dom.client.mesh = mesh
     moved = {k: after[k] - before[k] for k in COUNTERS}
     assert moved["join_direct_launches"] == moved["join_launches"] > 0
     assert not moved["join_host_fallbacks"] + moved["join_shuffle_launches"]
+    n = moved["hndv_agg_launches"]
+    assert (n > 0) == (name == "q3")
+    assert moved["agg_dependent_key_launches"] == n
+    assert (moved["group_topn_device_launches"],
+            moved["hndv_host_topn_launches"]) \
+        == ((n, 0) if devices == 1 else (0, n))
 
 
 def test_q3_makes_its_orders_build_anew_with_every_statement(tpch,
@@ -546,6 +682,8 @@ def test_q3_makes_its_orders_build_anew_with_every_statement(tpch,
             assert grouped["program"].startswith("cop_solo_join_agg_sort_")
             assert grouped["match_capacity"] > 0
             assert grouped["match_capacity"] * 8 <= grouped["probe_rows"]
+            assert (grouped["dependent_keys"], grouped["group_topn"]) \
+                == (2, "device")
         after = sched.stats()
     finally:
         dom.client.mesh = mesh
@@ -562,6 +700,11 @@ def test_q3_makes_its_orders_build_anew_with_every_statement(tpch,
     # the repeats start with the table and the capacity the first found
     seen = after["hndv_agg_regrows"] - before["hndv_agg_regrows"]
     assert seen <= 3, "a repeated statement regrew its group table again"
+    # every statement's groups ranked by the device: ten slots crossed
+    assert after["group_topn_device_launches"] \
+        - before["group_topn_device_launches"] >= len(params)
+    assert after["hndv_host_topn_launches"] \
+        == before["hndv_host_topn_launches"]
 
 
 def test_q12_compacts_its_probe_and_keeps_its_build(tpch, lowered_for):
@@ -618,6 +761,40 @@ def test_explain_names_the_form_of_every_build_side(tpch):
     assert not any(line.startswith("join forms") for line in plan)
 
 
+def test_explain_names_the_dependent_keys_once_a_statement_has_run(
+        tpch, lowered_for):
+    """Which keys ride as dependents is a run's finding (a build side's
+    uniqueness is counted, not declared): before a statement of the
+    digest has run the aggregation's line has the planner's form (a
+    record above a join is hashed, its groups the host's to rank);
+    after, what that run launched."""
+    dom, classes = tpch
+    mod, _state = classes["q3"]
+    lowered_for("tpu")
+    mesh = dom.client.mesh
+    dom.client.mesh = get_mesh(1)
+    try:
+        sess = Session(dom)
+        sql = mod.sql({"segment": "FURNITURE", "day": 11})
+
+        def line():
+            (said,) = [r[0] for r in sess.execute("explain " + sql).rows
+                       if r[0].startswith("agg strategy")]
+            return said
+        assert line() == ("agg strategy: sort (capacity auto; one sort of "
+                          "hashed records, first 10 groups ranked on the "
+                          "host)")
+        sess.execute(sql)
+        assert line() == ("agg strategy: sort (capacity auto; "
+                          + Q3_FORM + "device)")
+        # another digest (another literal) has not run
+        assert "hashed" in [r[0] for r in sess.execute(
+            "explain " + mod.sql({"segment": "FURNITURE", "day": 12})).rows
+            if r[0].startswith("agg strategy")][0]
+    finally:
+        dom.client.mesh = mesh
+
+
 # --------------------------------------------------------------------- #
 # smaller statements: the edges, against a nested loop
 # --------------------------------------------------------------------- #
@@ -655,6 +832,7 @@ def star():
     s.execute("set global tidb_tpu_result_cache_entries = 0")
     s.execute("set global tidb_tpu_trace_sample = 1")
     dom.client._platform = lambda: "tpu"
+    dom.client._scheduler()     # this mesh's, whatever a test swaps in
     yield dom, (k, kvalid, a, v), head, cust
     _forget_programs()
 
@@ -671,16 +849,20 @@ def _chain_rows(fact, head, cust, seg, below, keep):
 @pytest.mark.parametrize("seg,below", [(0, 400), (1, 200), (2, 400),
                                        (0, 100), (1, 0)])
 def test_a_chained_build_under_a_group_by_with_dependent_keys(
-        star, lowered_for, seg, below):
+        star, lowered_for, monkeypatch, seg, below):
     """Q3's shape in small: the build is `head` joined to a segment of
     `cust`, the probe keeps over an eighth (no probe compaction), the
     GROUP BY has a key of the probe and a key that depends on it through
     the unique build.  Each parameter set its own answer; an empty build
-    (nothing below 0) is the host fallback's and is counted."""
+    (nothing below 0) is the host fallback's and is counted.  On one
+    device: `g` rides as a dependent of `fact.k` beside an exact record
+    (one word: the statistics of `fact` say so), the device ranks the
+    groups, the host ranks none."""
     dom, fact, head, cust = star
     lowered_for("tpu")
+    monkeypatch.setattr(dom.client, "mesh", get_mesh(1))
     sess = Session(dom)
-    sched = scheduler_for(dom.client.mesh)
+    sched = dom.client._scheduler()
     sql = ("select fact.k, sum(v), g from cust, head, fact "
            f"where seg = {seg} and cust.c = head.c and fact.k = head.k "
            f"and head.k < {below} and a >= 300 "
@@ -702,6 +884,151 @@ def test_a_chained_build_under_a_group_by_with_dependent_keys(
                   if b["source"] == "join"]
         assert joined and joined[0]["form"] == "direct" \
             and not joined[0]["cached"]
+        (grouped,) = [a for a in _spans(sess, "sched.launch")
+                      if "agg_strategy" in a]
+        assert (grouped["dependent_keys"], grouped["group_topn"]) \
+            == (1, "device")
+        assert "one sort of 1-word records, g riding as dependents" \
+            in "\n".join(r[0] for r in sess.execute("explain " + sql).rows)
+    moved = {k: after[k] - before[k] for k in (
+        "agg_dependent_key_launches", "group_topn_device_launches",
+        "hndv_host_topn_launches", "hndv_agg_regrows")}
+    assert moved == {"agg_dependent_key_launches": int(below > 0),
+                     "group_topn_device_launches": int(below > 0),
+                     "hndv_host_topn_launches": 0, "hndv_agg_regrows": 0}, \
+        moved
+
+
+LEFT_SQL = ("select fact.k, g, c, sum(v), count(*) from fact left join head "
+            "on fact.k = head.k where a >= {a} group by fact.k, g, c "
+            "order by 4 desc, 1 limit {limit}")
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+@pytest.mark.parametrize("a,limit", [(300, 10), (900, 10), (300, 70)])
+def test_a_left_join_s_unmatched_rows_group_under_a_null_dependent(
+        star, lowered_for, monkeypatch, devices, a, limit):
+    """A unique LEFT join under the GROUP BY: a probe key the build does
+    not hold is one group, its dependent keys NULL; the NULL probe keys
+    are one group too.  Ranked by the device where it holds its groups
+    whole and the LIMIT is at most `GROUP_TOPN_MAX`, else by the host
+    (four devices: which merges their tables by true key)."""
+    dom, (k, kvalid, av, v), head, _cust = star
+    lowered_for("tpu")
+    monkeypatch.setattr(dom.client, "mesh", get_mesh(devices))
+    sess = Session(dom)
+    sched = dom.client._scheduler()
+    heads = {h[0]: h for h in head}
+    groups: dict = {}
+    for i in np.nonzero(av >= a)[0]:
+        key = int(k[i]) if kvalid[i] else None
+        h = heads.get(key, (None, None, None))
+        s, n = groups.get((key, h[1], h[2]), (0, 0))
+        groups[key, h[1], h[2]] = (s + int(v[i]), n + 1)
+    want = sorted(((key, g, c, s, n) for (key, g, c), (s, n)
+                   in groups.items()),
+                  key=lambda r: (-r[3], r[0] is not None, r[0] or 0))[:limit]
+    assert any(r[0] is not None and r[1] is None for r in want)
+    before = sched.stats()
+    assert sess.execute(LEFT_SQL.format(a=a, limit=limit)).rows == want
+    after = sched.stats()
+    (grouped,) = [s for s in _spans(sess, "sched.launch")
+                  if "agg_strategy" in s]
+    on_device = devices == 1 and limit <= D.GROUP_TOPN_MAX
+    assert (grouped["dependent_keys"], grouped["group_topn"]) \
+        == (2, "device" if on_device else "host")
+    assert (after["group_topn_device_launches"]
+            - before["group_topn_device_launches"],
+            after["hndv_host_topn_launches"]
+            - before["hndv_host_topn_launches"]) \
+        == ((1, 0) if on_device else (0, 1))
+
+
+def test_a_multimatch_build_leaves_the_group_by_as_it_was(star, lowered_for,
+                                                         monkeypatch):
+    """Dependence is used only where this run found the build unique: a
+    build key held twice switches the join to the expanding form and
+    the GROUP BY keeps all its keys in the record."""
+    dom, (k, kvalid, av, v), _head, _cust = star
+    lowered_for("tpu")
+    monkeypatch.setattr(dom.client, "mesh", get_mesh(1))
+    sess = Session(dom)
+    sess.execute("create table twice (k bigint, g bigint)")
+    sess.execute("insert into twice values (1, 10), (1, 11), (2, 20), "
+                 "(400, 30)")
+    try:
+        sql = ("select fact.k, g, sum(v) from fact, twice where fact.k = "
+               "twice.k group by fact.k, g order by 3 desc, 1, 2 limit 5")
+        want: dict = {}
+        for key, g in ((1, 10), (1, 11), (2, 20), (400, 30)):
+            rows = kvalid & (k == key)
+            if rows.any():
+                want[key, g] = int(v[rows].sum())
+        assert sess.execute(sql).rows == sorted(
+            ((key, g, s) for (key, g), s in want.items()),
+            key=lambda r: (-r[2], r[0], r[1]))[:5]
+        (grouped,) = [s for s in _spans(sess, "sched.launch")
+                      if "agg_strategy" in s]
+        assert grouped["join"] == "multimatch"
+        assert "dependent_keys" not in grouped
+    finally:
+        sess.execute("drop table twice")
+
+
+def test_the_record_s_words_are_a_guess_the_device_corrects(star, lowered_for,
+                                                            monkeypatch):
+    """Tables nobody ANALYZEd: two words are guessed for the record
+    without its dependent key.  A probe key 2^40 wide does not fit them:
+    the device says so, the statement is rerun once with hashed records
+    (the dependent key still out of the hash; the host ranks what a
+    hash may have split), the digest is remembered and the repeat starts
+    there; the answers are the same."""
+    dom, _fact, _head, _cust = star
+    lowered_for("tpu")
+    monkeypatch.setattr(dom.client, "mesh", get_mesh(1))
+    sess = Session(dom)
+    sched = dom.client._scheduler()
+    rng = np.random.default_rng(3)
+    keys = rng.integers(0, 50, 600)
+    vals = rng.integers(-1000, 1000, 600)
+    sess.execute("create table raw_head (k bigint, g bigint)")
+    sess.execute("create table raw_fact (k bigint, v bigint)")
+    try:
+        for scale, regrows in ((1, 0), (1 << 34, 1)):
+            sess.execute("delete from raw_head")
+            sess.execute("delete from raw_fact")
+            sess.execute("insert into raw_head values " + ", ".join(
+                f"({k * scale}, {k % 7})" for k in range(0, 50, 2)))
+            sess.execute("insert into raw_fact values " + ", ".join(
+                f"({k * scale}, {v})" for k, v in zip(keys, vals)))
+            sql = ("select raw_fact.k, g, sum(v) from raw_fact, raw_head "
+                   "where raw_fact.k = raw_head.k group by raw_fact.k, g "
+                   "order by 3 desc, 1 limit 10")
+            sums: dict = {}
+            for k, v in zip(keys, vals):
+                if k % 2 == 0:
+                    sums[k] = sums.get(k, 0) + int(v)
+            want = sorted(((k * scale, k % 7, s) for k, s in sums.items()),
+                          key=lambda r: (-r[2], r[0]))[:10]
+            for again in (False, True):
+                before = sched.stats()
+                assert sess.execute(sql).rows == want
+                after = sched.stats()
+                assert after["hndv_agg_regrows"] \
+                    - before["hndv_agg_regrows"] \
+                    == (0 if again else regrows)
+                *_first, last = [a for a in _spans(sess, "sched.launch")
+                                 if "agg_strategy" in a]
+                assert last["dependent_keys"] == 1
+                assert last["group_topn"] \
+                    == ("device" if regrows == 0 else "host")
+            # EXPLAIN says what the executor handed the dispatcher
+            (said,) = [r[0] for r in sess.execute("explain " + sql).rows
+                       if r[0].startswith("agg strategy")]
+            assert "2-word records, g riding as dependents" in said
+    finally:
+        sess.execute("drop table raw_head")
+        sess.execute("drop table raw_fact")
 
 
 def test_a_rows_root_past_its_capacity_is_rerun(star, lowered_for):
@@ -750,6 +1077,20 @@ def test_the_new_facts_are_rows_of_the_table():
     assert F.counters({"match_capacity": 1024}) \
         == ["join_match_compact_launches"]
     assert F.counters({"match_capacity": 0}) == []
+    # PR 32: the keys that rode as dependents; where the groups were ranked
+    assert {"agg_dependent_key_launches", "group_topn_device_launches",
+            "hndv_host_topn_launches"} <= set(F.counter_names())
+    assert F.counters({"dependent_keys": 2}) \
+        == ["agg_dependent_key_launches"]
+    assert F.counters({"group_topn": "device"}) \
+        == ["group_topn_device_launches"]
+    assert F.counters({"group_topn": "host"}) == ["hndv_host_topn_launches"]
+    assert F.span_attrs({"dependent_keys": 2, "group_topn": "device"}) \
+        == {"dependent_keys": 2, "group_topn": "device"}
+    grouped = _grouped(_ONE, [_c(0, I64N), _c(3)])
+    assert F.of_program({"dependent_keys": 1}, grouped) \
+        == {"dependent_keys": 1}
+    assert F.of_program({"dependent_keys": 1}, _ONE) == {}
     assert F.span_attrs({"join_form": "direct", "rows_capacity": 256,
                          "rows_compact": 0, "match_capacity": 0}) \
         == {"join_form": "direct", "rows_capacity": 256}
@@ -764,3 +1105,4 @@ def test_the_new_facts_are_rows_of_the_table():
         if f.endswith(".py"):
             text = open(os.path.join(sched_dir, f)).read()
             assert "join_form" not in text and "rows_compact" not in text
+            assert "dependent" not in text and "group_topn" not in text

@@ -344,17 +344,116 @@ def test_first_groups_ranked_on_the_device(words):
     assert _groups(agg, st) == want and (st["__rows__"] > 0).sum() == len(want)
 
 
+def _with_dependents(n, ndv, seed, live=0.7):
+    """A table (k nullable, d1 = a NULL for one k in five else k // 7,
+    d2 = -3 * k, x): d1 and d2 are functions of k, as the columns a
+    unique left join brings are of its probe key (an unmatched probe
+    row: the NULL)."""
+    (k, x), sel = _table(n, ndv, seed, live=live)
+    d1 = (k[0] // 7, (k[0] % 5 != 0) & k[1])
+    d2 = (np.where(k[1], -3 * k[0], 0), None)
+    return [k, d1, d2, x], sel
+
+
+@pytest.mark.parametrize("words", FORMS)
+@pytest.mark.parametrize("n,ndv", [(1000, 7), (5000, 700)])
+def test_dependent_keys_ride_as_payload(n, ndv, words):
+    """`Aggregation.dependent`: the groups are the reference's over all
+    three keys, a dependent key NULL where its rows hold a NULL, in
+    every record form; the launch says how many keys rode."""
+    cols, sel = _with_dependents(n, ndv, seed=41)
+    agg = dataclasses.replace(_agg(n_keys=3, words=words, cap=2048),
+                              dependent=(1, 2))
+    st, facts = _states(agg, cols, sel)
+    assert facts["dependent_keys"] == 2
+    want = _want(agg, cols, sel)
+    assert any(k[0] is not None and k[1] is None for k in want)
+    assert _groups(agg, st) == want
+    assert int(st["__ngroups__"]) == len(want)
+    plain, plain_facts = _states(dataclasses.replace(agg, dependent=()),
+                                 cols, sel)
+    assert "dependent_keys" not in plain_facts
+    if words:       # the keys alone fit no word here: 65 says so
+        assert plain["__bits__"] > st["__bits__"]
+
+
+@pytest.mark.parametrize("words", FORMS)
+def test_a_dependent_key_decides_no_group(words):
+    """THE CONTRACT of the record: a key marked dependent is no part of
+    the key part (the exact record's first-word bits, the wide form's
+    hash, a run's boundary).  Shown by breaking the promise: where the
+    marked key does change inside a group of the others, the table still
+    has ONE slot a determining key, with the rows of all of them."""
+    cols, sel = _with_dependents(4000, 60, seed=43, live=1.0)
+    cols[2] = (np.arange(4000), None)       # no function of k at all
+    agg = dataclasses.replace(_agg(n_keys=3, words=words, cap=2048),
+                              dependent=(1, 2))
+    st, _ = _states(agg, cols, sel)
+    by_k = _want(_agg(n_keys=1), [cols[0], cols[3]], sel)
+    assert int(st["__ngroups__"]) == len(by_k) \
+        == int((st["__rows__"] > 0).sum())
+    got = {key[0]: vals for key, vals in _groups(agg, st).items()}
+    assert got == {key[0]: vals for key, vals in by_k.items()}
+
+
+def test_a_record_too_wide_for_two_words_is_rerun_wide_with_its_dependents_out():
+    """A determining key 2^40 wide passes the first word: the dispatcher
+    goes to the wide form, whose hash and run boundaries still leave the
+    dependent keys out, and the answer is the same."""
+    cols, sel = _with_dependents(4000, 300, seed=47)
+    cols[0] = (cols[0][0] * (1 << 30), cols[0][1])
+    start = dataclasses.replace(_agg(n_keys=3, words=2, cap=2048),
+                                dependent=(1, 2))
+    st, agg, reruns = _regrown(start, cols, sel)
+    assert (agg.pack_words, agg.dependent, reruns) == (0, (1, 2), 1)
+    assert _groups(agg, st) == _want(agg, cols, sel)
+    _st, facts = _states(agg, cols, sel)
+    assert facts["dependent_keys"] == 2
+
+
+@pytest.mark.parametrize("words", (1, 2))
+def test_groups_ranked_on_the_device_by_a_dependent_key(words):
+    """A dependent key is a `("key", j, desc)` of `GroupTopN` like any
+    other: COUNT descending, then the dependent key ascending (its NULL
+    first), then the determining key; a limit past `GROUP_TOPN_MAX` is
+    the host's."""
+    cols, sel = _with_dependents(5000, 300, seed=53)
+    aggs = _agg().aggs[1:2]                  # count(*)
+    keys = (("agg", 0, True), ("key", 1, False), ("key", 0, False))
+    base = dataclasses.replace(
+        _agg(n_keys=3, words=words, cap=2048, aggs=aggs), dependent=(1, 2))
+    want = _want(base, cols, sel)
+
+    def rank(item):
+        (k, d1, _d2), (c,) = item
+        return (-c, d1 is not None, d1 or 0, k is not None, k or 0)
+    for limit in (10, D.GROUP_TOPN_MAX + 1):
+        agg = dataclasses.replace(base, topn=D.GroupTopN(keys, limit))
+        st, facts = _states(agg, cols, sel)
+        if limit > D.GROUP_TOPN_MAX:
+            assert facts["group_topn"] == "host"
+            assert _groups(agg, st) == want
+            continue
+        assert facts["group_topn"] == "device"
+        assert list(_groups(agg, st).items()) \
+            == sorted(want.items(), key=rank)[:limit]
+
+
+@pytest.mark.parametrize("dependents", [False, True])
 @pytest.mark.parametrize("n_dev", [1, 4])
 def test_through_the_sharded_program_on_one_and_four_devices(monkeypatch,
-                                                             n_dev):
+                                                             n_dev,
+                                                             dependents):
     """`ShardedCopProgram` over a mesh traced as for a TPU, two stacked
     shards a device: the per-device tables, merged by the host, equal
     the reference; the launch says its strategy and its capacity, and a
-    device that holds every row of its groups ranks them."""
+    device that holds every row of its groups ranks them.  With two of
+    three keys riding as dependents: the same, and the launch says so."""
     mesh = Mesh(np.array(jax.devices()[:n_dev]), (SHARD_AXIS,))
     monkeypatch.setattr(spmd, "mesh_platform", lambda _mesh: "tpu")
     s, cap = 2 * n_dev, 1024
-    cols, _sel = _table(s * cap, 500, seed=19)
+    cols, _sel = _with_dependents(s * cap, 500, seed=19) if dependents \
+        else _table(s * cap, 500, seed=19)
     counts = np.array([cap - 7 * i for i in range(s)], np.int64)
     live = (np.arange(cap)[None, :] < counts[:, None]).reshape(-1)
 
@@ -362,15 +461,18 @@ def test_through_the_sharded_program_on_one_and_four_devices(monkeypatch,
         return jax.device_put(a.reshape(s, cap), sharded(mesh))
     args = ([(put(v), None if m is None else put(m)) for v, m in cols],
             jax.device_put(counts, sharded(mesh)))
-    agg = _agg(words=2, cap=2048)
+    agg = dataclasses.replace(_agg(n_keys=3, words=2, cap=2048),
+                              dependent=(1, 2)) if dependents \
+        else _agg(words=2, cap=2048)
     try:
         prog = spmd.ShardedCopProgram(agg, mesh)
         states = jax.tree_util.tree_map(np.asarray, prog(*args))
         assert (states["__ngroups__"] <= 2048).all()
         # two NULL lanes of one limb; distances below 2^52 (2,048 slots)
-        assert prog.facts(*args) == {"agg_strategy": "sort",
-                                     "group_capacity": 2048,
-                                     "scan_limbs": 1 + 7 + 1}
+        assert prog.facts(*args) == dict(
+            {"agg_strategy": "sort", "group_capacity": 2048,
+             "scan_limbs": 1 + 7 + 1},
+            **({"dependent_keys": 2} if dependents else {}))
         per_dev = [jax.tree_util.tree_map(lambda a, d=d: a[d], states)
                    for d in range(n_dev)]
         merged = merge_sorted_states(agg, per_dev)

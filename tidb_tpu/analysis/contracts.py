@@ -285,6 +285,14 @@ def _verify_dag(node: D.CopNode, path) -> None:
                   f"pack_words {node.pack_words} on a "
                   f"{node.strategy.value} aggregation: a SORT record is "
                   "one or two words, or wide (0)")
+        if node.dependent and not (
+                set(node.dependent) < set(range(len(node.group_by)))
+                and tuple(sorted(set(node.dependent))) == node.dependent):
+            # a key the others determine: some key is left to determine
+            _fail("capacity-shape", p,
+                  f"dependent {node.dependent} of "
+                  f"{len(node.group_by)} group keys: ascending indexes "
+                  "of some of them, never all")
         if node.topn is not None:
             # which groups the consumer keeps: read by the lowering of a
             # host-merged table alone, over its own keys and aggregates

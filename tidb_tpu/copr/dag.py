@@ -237,6 +237,12 @@ class Aggregation(CopNode):
     columns' statistics; a launch whose record did not fit says the bits
     it takes (`__bits__`) and the dispatcher reruns the statement wider.
     `topn` (host-merged strategies): see `GroupTopN`.
+    `dependent` (indexes into `group_by`): group keys that are functions
+    of the other group keys, so every row of a group holds the same
+    value: they decide no group (copr/runagg leaves them out of the sort
+    record's key part and reads them at a run's end).  A finding of the
+    run, written by the executor (`with_dependent_keys`), never by the
+    planner.
     """
     child: CopNode = None  # type: ignore[assignment]
     group_by: Tuple[Expr, ...] = ()
@@ -254,6 +260,8 @@ class Aggregation(CopNode):
         default=0, metadata=DIGEST_IF_SET)
     topn: Optional[GroupTopN] = field(   # the groups the consumer keeps
         default=None, metadata=DIGEST_IF_SET)
+    dependent: Tuple[int, ...] = field(  # keys the other keys determine
+        default=(), metadata=DIGEST_IF_SET)
 
     def children(self):
         return (self.child,)
@@ -618,6 +626,89 @@ def build_columns_read(root: CopNode, join: LookupJoin):
     return tuple(n_probe + j in read for j in range(len(join.build_dtypes)))
 
 
+def _same_expr(a: Expr, b: Expr) -> bool:
+    """Are the two expressions the same structure over the same columns
+    (a ColumnRef's name is for reading, and its dtype's NULL flag is
+    what the node beneath it says)?"""
+    from ..expr.ir import ColumnRef, Func
+    if isinstance(a, ColumnRef) or isinstance(b, ColumnRef):
+        return isinstance(a, ColumnRef) and isinstance(b, ColumnRef) \
+            and a.index == b.index
+    if isinstance(a, Func) and isinstance(b, Func):
+        return a.op == b.op and a.dtype == b.dtype \
+            and len(a.args) == len(b.args) \
+            and all(_same_expr(x, y) for x, y in zip(a.args, b.args))
+    return type(a) is type(b) and a == b
+
+
+def with_dependent_keys(agg: CopNode) -> CopNode:
+    """`agg` with the group keys marked that the other group keys
+    determine (`Aggregation.dependent`), or `agg` itself where there is
+    none or it is no grouped aggregation.  Pure, like `topn_block_len`.
+
+    Group key j is dependent when every column it reads is a build
+    column of a LookupJoin beneath the aggregation that is unique (inner
+    or left: a probe row meets one build row or none, and then a NULL)
+    and whose probe key is, structurally, another group key that is not
+    itself dependent: TPC-H Q3 groups by `l_orderkey, o_orderdate,
+    o_shippriority` above `l_orderkey = o_orderkey`.  An expression over
+    such columns is dependent too.  A multimatch, semi or anti join, a
+    probe key that is not grouped, and anything but Selections,
+    Projections and joins between the aggregation and its scan mark
+    nothing.  `unique` has to be what the run found of the build side
+    (`copr/joinbuild`), not a schema's word: the executor calls this
+    once it knows."""
+    import dataclasses
+
+    from ..expr.ir import referenced_columns, substitute_columns
+    if not isinstance(agg, Aggregation) or not agg.group_by:
+        return agg
+    # walking down from the aggregation: a key as an expression over the
+    # node's output until it has read a build column (None from then
+    # on), the columns of the node's output it still reads, the joins
+    # (as places in `joins`) whose build columns it read; a join: itself
+    # and the keys that are its probe key
+    exprs: list = list(agg.group_by)
+    cols = [referenced_columns(e) for e in exprs]
+    reads: list = [[] for _ in exprs]
+    joins: list = []
+    node = agg.child
+    while not isinstance(node, TableScan):
+        if isinstance(node, Projection):
+            cols = [set().union(*(referenced_columns(node.exprs[i])
+                                  for i in c)) for c in cols]
+            exprs = [None if e is None
+                     else substitute_columns(e, node.exprs)
+                     for e in exprs]
+        elif isinstance(node, LookupJoin):
+            n_probe = len(output_dtypes(node.child))
+            for j, c in enumerate(cols):
+                if any(i >= n_probe for i in c):
+                    reads[j].append(len(joins))
+                    exprs[j] = None
+                    cols[j] = {i for i in c if i < n_probe}
+            joins.append((node, [j for j, e in enumerate(exprs)
+                                 if e is not None
+                                 and _same_expr(e, node.probe_key)]))
+        elif not isinstance(node, Selection):
+            return agg
+        node = node.child
+
+    known: dict = {}
+
+    def dependent(j: int) -> bool:
+        if j not in known:
+            known[j] = bool(reads[j]) and not cols[j] and all(
+                join.unique and join.kind in ("inner", "left")
+                and any(not dependent(m) for m in probed)
+                for join, probed in (joins[at] for at in reads[j]))
+        return known[j]
+    marked = tuple(j for j in range(len(exprs)) if dependent(j))
+    if marked == agg.dependent:
+        return agg
+    return dataclasses.replace(agg, dependent=marked)
+
+
 def find_expand_join(node: CopNode):
     """The (at most one) non-unique LookupJoin in a pushed DAG, or None —
     programs containing one report true join output size via extras."""
@@ -759,7 +850,7 @@ __all__ = [
     "Limit", "LookupJoin",
     "FusedDag", "ShuffleJoinSpec", "output_dtypes", "dag_digest",
     "iter_nodes", "lookup_joins", "find_expand_join", "compacting_join",
-    "compact_capacity", "build_columns_read",
+    "compact_capacity", "build_columns_read", "with_dependent_keys",
     "uncompacted", "has_extras",
     "rewrite_lookup",
     "drop_lookup",

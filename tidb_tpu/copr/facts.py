@@ -104,17 +104,24 @@ FACTS = {
     # copr/runagg, a TPU's lowering of a SORT aggregation root: the
     # strategy's name, the table's slots a device, and where the groups
     # a TopN above it keeps are ranked, "device" | "host" (absent: none
-    # is above it).  "host": the table came back whole for its sake
+    # is above it).  "host": the table came back whole for its sake;
+    # "device": the first groups alone crossed
     "agg_strategy": Fact(counters=(("hndv_agg_launches", _present),),
                          merge=_first, on_span=_present, root=_host_merged),
     "group_capacity": Fact(on_span=_present, root=_host_merged),
     "group_topn": Fact(
-        counters=(("hndv_host_topn_launches", lambda w: w == "host"),),
+        counters=(("hndv_host_topn_launches", lambda w: w == "host"),
+                  ("group_topn_device_launches", lambda w: w == "device")),
         merge=_first, on_span=_present, root=_host_merged),
     # `runagg.agg_run_states`: the 8-bit limb lanes its prefix sums were
     # made of, in blocks on the MXU (0: an int64 scan over every slot)
     "scan_limbs": Fact(
         counters=(("hndv_limb_scan_launches", lambda n: n > 0),),
+        root=_host_merged),
+    # the same: the group keys that rode its sort as payload because
+    # the other keys determine them (`dag.Aggregation.dependent`)
+    "dependent_keys": Fact(
+        counters=(("agg_dependent_key_launches", _present),),
         root=_host_merged),
 }
 

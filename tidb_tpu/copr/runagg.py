@@ -21,7 +21,15 @@ aggregates read travel with the sort, as one record a row:
    (`__bits__`) and the dispatcher reruns the statement wider.  In the
    **wide** form (`pack_words` 0) the layout is static, from the
    dtypes: the sort key is 31 bits of a hash of the key tuple
-   (`segment.key_hash`) and the keys ride as payload.
+   (`segment.key_hash`) and the keys ride as payload.  A **dependent**
+   key (`dag.Aggregation.dependent`: a function of the other keys, as
+   the columns a unique lookup join brings are of its probe key) is in
+   neither form part of the key: every row of a run holds the same
+   value, so it decides no run.  It rides the sort as payload at its
+   dtype's width (`join.pack_rows`), NULL bit included, and is read at
+   the run's end like any key.  (Not inside the exact record's words,
+   whose layout the device works out: whether it has room there is not
+   known when the sort's lanes are counted.)
 2. *One sort* by the record's first word (`lax.sort`, unstable, the
    other words its payload: a second key lane costs XLA:TPU half as
    much compile time again): equal keys become runs, dead rows sort
@@ -157,15 +165,32 @@ def _summand_bits(words: int, n: int) -> int:
     return min(32 * words - 1, _sum_room(n)) if words else 32
 
 
-def _exact_record(keys, aggs, sel, n, words: int):
-    """The exact form.  -> (words, bits, read): `bits` the record takes
-    (more than 32 * `words`, or 65 where the key part passes a word or a
-    SUM's distances could pass int64 summed over n rows: it did not
-    fit)."""
+def _payload_keys(keys, sel):
+    """Keys that ride as payload, as columns of `join.pack_rows` (their
+    bits at their dtypes' widths).  -> (their inverses, the columns)."""
+    backs, cols = [], []
+    for vz, m in keys:
+        bits, back = _as_bits(vz)
+        if vz.dtype.itemsize <= 4:
+            bits = bits.astype(jnp.int32)  # valueflow: ok - the value's own 32 bits or fewer
+        backs.append(back)
+        cols.append((bits, True if m is True else (sel & m)))
+    return backs, cols
+
+
+def _exact_record(keys, aggs, sel, n, words: int, riders=frozenset()):
+    """The exact form; the keys `riders` (indexes) ride behind the
+    record's words as payload.  -> (words, bits, read): `bits` the
+    record takes (more than 32 * `words`, or 65 where the key part
+    passes a word or a SUM's distances could pass int64 summed over n
+    rows: it did not fit)."""
+    from .join import pack_rows
     ut = jnp.uint32 if words == 1 else _U64
     fields = [((~sel).astype(ut), 1)]   # (bits as `ut`, their number)
     key_slots = []
-    for vz, m in keys:
+    for j, (vz, m) in enumerate(keys):
+        if j in riders:
+            continue
         bits, back = _as_bits(vz)
         if m is not True:
             fields.append(((sel & ~m).astype(ut), 1))
@@ -202,6 +227,10 @@ def _exact_record(keys, aggs, sel, n, words: int):
              for i in range(len(fields))]
     out_words = [rec] if words == 1 else [
         (rec >> _U64(32)).astype(jnp.uint32), rec.astype(jnp.uint32)]  # valueflow: ok - the record's two words
+    backs, carried = _payload_keys(
+        [keys[j] for j in sorted(riders)], sel)
+    payload, apart, unpack = pack_rows(carried)
+    assert not apart, "keys are integers, floats or bits"
 
     def read(sorted_words):
         r = sorted_words[0] if words == 1 else (
@@ -211,32 +240,32 @@ def _exact_record(keys, aggs, sel, n, words: int):
         def field(i):
             mask = (ut(1) << widths[i].astype(ut)) - ut(1)
             return (r >> below[i].astype(ut)) & mask
-        key_out = [
+        in_record = iter([
             (back(field(at).astype(jnp.int64) + lo),  # valueflow: ok - a distance below 2^63 or the wrap that undoes one
              (field(at - 1) == 0) if nullable else True)
-            for at, lo, back, nullable in key_slots]
+            for at, lo, back, nullable in key_slots])
+        behind = iter([(back(bits), valid) for back, (bits, valid)
+                       in zip(backs, unpack(sorted_words[words:]))])
+        key_out = [next(behind if j in riders else in_record)
+                   for j in range(len(keys))]
         agg_out = [(None if av is None else field(av) != 0,
                     [] if ao is None else [field(ao).astype(jnp.int64)],  # valueflow: ok - sum_room bits at most
                     lo) for av, ao, lo in agg_slots]
         return (field(0) != 0, [r >> below[n_key - 1].astype(ut)],
                 key_out, agg_out)
-    return out_words, jnp.where(too_wide, jnp.int32(65), total), read
+    return (out_words + payload,
+            jnp.where(too_wide, jnp.int32(65), total), read)
 
 
-def _wide_record(keys, aggs, sel, hashed):
-    """The wide form: a word of hash under a dead bit, then the keys
-    (as their bits) and the aggregates' distances in as few words as
-    their dtypes take (`join.pack_rows`).  -> (words, read)."""
+def _wide_record(keys, aggs, sel, hashed, riders=frozenset()):
+    """The wide form: a word of hash (of the keys that are not `riders`)
+    under a dead bit, then the keys (as their bits) and the aggregates'
+    distances in as few words as their dtypes take (`join.pack_rows`).
+    -> (words, read)."""
     from .join import pack_rows
     top = jnp.where(sel, (hashed >> _U64(33)).astype(jnp.uint32),  # valueflow: ok - 31 bits are left
                     jnp.uint32(1 << 31))
-    backs, cols = [], []
-    for vz, m in keys:
-        bits, back = _as_bits(vz)
-        if vz.dtype.itemsize <= 4:
-            bits = bits.astype(jnp.int32)  # valueflow: ok - the value's own 32 bits or fewer
-        backs.append(back)
-        cols.append((bits, True if m is True else (sel & m)))
+    backs, cols = _payload_keys(keys, sel)
     agg_slots = []
     for valid, dist in aggs:
         at_valid = at_off = lo = None
@@ -255,8 +284,9 @@ def _wide_record(keys, aggs, sel, hashed):
     def read(sorted_words):
         got = unpack(sorted_words[1:])
         shared = [sorted_words[0]]
-        for bits, valid in got[:len(keys)]:
-            shared += [bits] if valid is True else [bits, valid]
+        for j, (bits, valid) in enumerate(got[:len(keys)]):
+            if j not in riders:     # a rider changes with no run
+                shared += [bits] if valid is True else [bits, valid]
         key_out = [(back(bits), valid)
                    for back, (bits, valid) in zip(backs, got)]
         agg_out = [(None if av is None else got[av][0],
@@ -297,13 +327,19 @@ def agg_run_states(agg: D.Aggregation, batch, ev, memo: dict) -> dict:
     keyinfo = group_keyinfo(agg, batch, ev, memo, n)
     keys = [(vz, m) for vz, m, _nullf, _code in keyinfo]
     aggs = _agg_fields(agg, batch, ev, memo, sel, n)
+    # the keys the others determine decide no run: payload in both forms
+    riders = frozenset(agg.dependent)
+    if riders:
+        batch.facts["dependent_keys"] = len(riders)
     states: dict[str, Any] = {}
     if agg.pack_words:
-        words, bits, read = _exact_record(keys, aggs, sel, n, agg.pack_words)
+        words, bits, read = _exact_record(keys, aggs, sel, n,
+                                          agg.pack_words, riders)
         states["__bits__"] = bits.astype(jnp.int64)
     else:
-        words, read = _wide_record(keys, aggs, sel,
-                                   batch_hash(agg, batch, keyinfo, n))
+        hashed = batch_hash(agg, batch, [k for j, k in enumerate(keyinfo)
+                                         if j not in riders], n)
+        words, read = _wide_record(keys, aggs, sel, hashed, riders)
     with jax.named_scope("sort"):
         words = lax.sort(tuple(_tile_order(w, stacked) for w in words),
                          num_keys=1, is_stable=False)

@@ -503,6 +503,11 @@ class CopJoinTaskExec(PhysOp):
     # probe key's column; 0 = no statistics.  With the build side's rows
     # it says which share of the probe rows can find a match
     probe_key_ndv: float = 0.0
+    # a SORT aggregation root some of whose group keys depend on the
+    # others if every build turns out unique: the planner's guess of
+    # `dag.Aggregation.pack_words` without them, from the probe table's
+    # statistics; 0 = the wide form, or no such root (`_grouped`)
+    record_words: int = 0
 
     def __post_init__(self):
         self.children = ([b["exec"] for b in self.builds] if self.builds
@@ -563,7 +568,8 @@ class CopJoinTaskExec(PhysOp):
         if bound is None:
             return self._host_fallback(ctx)
         dag, groups = bound
-        return self._run(ctx, self._compacted(ctx, dag), groups)
+        return self._run(ctx, self._compacted(
+            ctx, self._grouped(ctx, dag)), groups)
 
     def _empty_build_result(self, ctx, bchunk) -> ResultChunk:
         # empty build side: inner join produces nothing; left join keeps all
@@ -613,7 +619,8 @@ class CopJoinTaskExec(PhysOp):
                 dag = D.rewrite_lookup(dag, dense=True,
                                        packing=side.packing)
             if not semi:
-                dag = self._compacted(ctx, dag, side.rows)
+                dag = self._compacted(ctx, self._grouped(ctx, dag),
+                                      side.rows)
         chunk = self._run(ctx, dag, (side.aux,))   # one aux group
         # build-side output columns keep their own dictionaries
         if not isinstance(self.dag, D.Aggregation):
@@ -623,6 +630,23 @@ class CopJoinTaskExec(PhysOp):
                     if 0 <= bj < len(built.dicts):
                         c.dictionary = built.dicts[bj]
         return chunk
+
+    def _grouped(self, ctx, dag):
+        """`dag`, every build of which this run found unique, with the
+        group keys of its GROUP BY marked that the others determine
+        (dag.with_dependent_keys: the columns a unique build brings are
+        functions of its probe key), and then the exact sort record the
+        planner guessed for the keys that are left.  EXPLAIN reads what
+        was found from the client (`dependent_keys_found`)."""
+        import dataclasses
+        marked = D.with_dependent_keys(dag)
+        if marked is dag:
+            return dag
+        if self.record_words and not marked.pack_words:
+            marked = dataclasses.replace(marked,
+                                         pack_words=self.record_words)
+        ctx.client.found_dependent_keys(self.dag, marked)
+        return marked
 
     def _compacted(self, ctx, dag, build_rows: int = 0):
         """`dag` with its lowest (unique inner/left) join told to compact

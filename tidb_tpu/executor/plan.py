@@ -269,16 +269,19 @@ def _try_index_ordered_topn(p) -> Optional[PhysOp]:
 
 def _push_group_topn(top: LogicalTopN, child: PhysOp) -> PhysOp:
     """`child`, the physical plan under the HostTopN of `top`; where it
-    is a host-merged device aggregation, alone or under one projection,
-    and every ORDER BY key is one of its group keys or a COUNT's or
-    SUM's value, its DAG says which groups the statement keeps
-    (`dag.GroupTopN`), so a device whose groups are whole sends those
-    and not its table.  The HostTopN stays: it ranks what comes back."""
+    is a host-merged device aggregation (over a table or over a lookup
+    join's output), alone or under one projection, and every ORDER BY
+    key is one of its group keys or a COUNT's or SUM's value, its DAG
+    says which groups the statement keeps (`dag.GroupTopN`), so a device
+    whose groups are whole sends those and not its table.  The HostTopN
+    stays: it ranks what comes back."""
     import dataclasses
+    from .physical import CopJoinTaskExec
     proj = child if isinstance(child, HostProjection) else None
     cop = proj.child if proj is not None else child
     dag = getattr(cop, "dag", None)
-    if not isinstance(cop, CopTaskExec) or not isinstance(dag, D.Aggregation) \
+    if not isinstance(cop, (CopTaskExec, CopJoinTaskExec)) \
+            or not isinstance(dag, D.Aggregation) \
             or dag.strategy not in D.HOST_MERGE_STRATEGIES:
         return child
     keys = []
@@ -792,6 +795,17 @@ def _try_cop_join(p: LogicalPlan, top, mids, join: LogicalJoin) -> Optional[Phys
             if c.dtype.is_string and j not in (build_out_dicts or {}):
                 return None
     fallback = to_physical(p, no_device_join=True)
+    # a GROUP BY above the join: the words of its sort record should the
+    # run find its builds unique, so that some of its keys depend on the
+    # others and stay out of the record (`CopJoinTaskExec._grouped`)
+    record_words = 0
+    if isinstance(nodew, D.Aggregation) \
+            and nodew.strategy == D.GroupStrategy.SORT:
+        from ..copr.runagg import run_form
+        marked = D.with_dependent_keys(nodew)
+        if marked.dependent and _mesh_platform() == "tpu" \
+                and run_form(marked):
+            record_words = _pack_words(marked, ds, unknown=2)
     probe_est = 0.0 if semi else _probe_rows_estimate(join.left)
     key_ndv = 0.0
     if join.kind == "inner" and not builds and probe_est:
@@ -808,7 +822,7 @@ def _try_cop_join(p: LogicalPlan, top, mids, join: LogicalJoin) -> Optional[Phys
             nodew, ds.table, join_kind=join.kind, n_probe=n_probe,
             out_names=out_names, out_dtypes=out_dtypes, key_meta=key_meta,
             out_dicts=out_dicts, fallback=fallback, builds=builds,
-            probe_est_rows=probe_est)
+            probe_est_rows=probe_est, record_words=record_words)
     else:
         exec_ = CopJoinTaskExec(
             nodew, ds.table, build_exec=build_exec, build_key_index=ri,
@@ -817,7 +831,7 @@ def _try_cop_join(p: LogicalPlan, top, mids, join: LogicalJoin) -> Optional[Phys
             join_kind=join.kind, null_aware=join.null_aware, n_probe=n_probe,
             out_names=out_names, out_dtypes=out_dtypes, key_meta=key_meta,
             out_dicts=out_dicts, fallback=fallback, probe_est_rows=probe_est,
-            probe_key_ndv=key_ndv)
+            probe_key_ndv=key_ndv, record_words=record_words)
     if host_top is not None and host_top[0] == "topn":
         return HostTopN(exec_, list(host_top[1].keys), host_top[1].limit,
                         host_top[1].offset)
@@ -1472,22 +1486,27 @@ def _mesh_platform() -> str:
     return spmd.mesh_platform(get_mesh())
 
 
-def _pack_words(agg: D.Aggregation, ds) -> int:
+def _pack_words(agg: D.Aggregation, ds, unknown: int = 0) -> int:
     """`dag.Aggregation.pack_words` for a SORT aggregation on a TPU: the
     32-bit words the exact record of copr/runagg takes, from the
     intervals ANALYZE observed for the columns its keys and SUMs read
-    (`analysis/valueflow`): 1 or 2, or 0 (the wide form) where the key
-    part passes a word, the record two, or nothing is known.  A guess:
-    the device works the layout out from the values it holds and a
-    record that does not fit is rerun wider (`store/client`)."""
+    (`analysis/valueflow`; a dependent key is no part of the record):
+    1 or 2, or 0 (the wide form) where the key part passes a word or
+    the record two; `unknown` where nothing is known (no statistics of
+    `ds`, the scanned table, or a value that comes from a join's build
+    side).  A guess: the device works the layout out from the values it
+    holds and a record that does not fit is rerun wider
+    (`store/client`)."""
     handle = STATS_HANDLE.get()
     if handle is None or ds is None:
-        return 0
+        return unknown
     from ..analysis import valueflow
     sums = [a.arg for a in agg.aggs if a.func == D.AggFunc.SUM]
+    group_by = [e for j, e in enumerate(agg.group_by)
+                if j not in agg.dependent]
     try:
         spans = valueflow.observed_spans(
-            agg.child, list(agg.group_by) + sums, ds.table, handle)
+            agg.child, group_by + sums, ds.table, handle)
         # a NULL bit rides only beside a value that has a mask on the
         # device: none does where no column scanned held a NULL
         ts, names = handle.get(ds.table), ds.table.col_names
@@ -1495,12 +1514,12 @@ def _pack_words(agg: D.Aggregation, ds) -> int:
                     or ts.col(names[off]).null_count > 0
                     for off in valueflow._scan_of(agg.child).col_offsets)
     except (AttributeError, TypeError, ValueError, IndexError):
-        return 0
+        return unknown
     if spans is None:
-        return 0
-    null_bits = [nulls and e.dtype.nullable for e in list(agg.group_by)
+        return unknown
+    null_bits = [nulls and e.dtype.nullable for e in group_by
                  + [a.arg for a in agg.aggs if a.arg is not None]]
-    k = len(agg.group_by)
+    k = len(group_by)
     key = 1 + sum(int(s).bit_length() for s in spans[:k]) + sum(null_bits[:k])
     rest = sum(int(s).bit_length() for s in spans[k:]) + sum(null_bits[k:])
     if key > 32 or key + rest > 64:

@@ -113,5 +113,15 @@ def map_column_indices(e: Expr, mapping: dict[int, int]) -> Expr:
     return e
 
 
+def substitute_columns(e: Expr, exprs) -> Expr:
+    """Replace ColumnRef i with exprs[i]: `e` over a projection's output
+    as an expression over its input."""
+    if isinstance(e, ColumnRef):
+        return exprs[e.index]
+    if isinstance(e, Func):
+        return clone_func(e, (substitute_columns(a, exprs) for a in e.args))
+    return e
+
+
 __all__ = ["Expr", "ColumnRef", "Const", "Func", "walk", "clone_func",
-           "referenced_columns", "map_column_indices"]
+           "referenced_columns", "map_column_indices", "substitute_columns"]

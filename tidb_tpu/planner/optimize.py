@@ -16,7 +16,8 @@ from __future__ import annotations
 import numpy as np
 
 from ..expr.compile import eval_expr
-from ..expr.ir import clone_func, ColumnRef, Const, Expr, Func, referenced_columns
+from ..expr.ir import (clone_func, ColumnRef, Const, Expr, Func,
+                       referenced_columns, substitute_columns)
 from ..types import dtypes as dt
 from .build import _split_cnf
 from .logical import (DataSource, LogicalAggregate, LogicalJoin, LogicalLimit,
@@ -127,13 +128,7 @@ def _and_all(conds: list[Expr]) -> Expr:
 # predicate pushdown
 # --------------------------------------------------------------------- #
 
-def _subst(e: Expr, exprs: list[Expr]) -> Expr:
-    """Replace ColumnRef i with exprs[i] (pushing through a projection)."""
-    if isinstance(e, ColumnRef):
-        return exprs[e.index]
-    if isinstance(e, Func):
-        return clone_func(e, (_subst(a, exprs) for a in e.args))
-    return e
+_subst = substitute_columns     # pushing through a projection
 
 
 def _remap(e: Expr, offset: int) -> Expr:

@@ -1506,14 +1506,27 @@ class Session:
 
     def _run_form_footer(self, dag) -> str:
         """What this server's mesh makes of a SORT aggregation: on a
-        TPU, copr/runagg's form and where the groups are ranked."""
+        TPU, copr/runagg's form, the group keys that ride as dependents
+        of the others (what the last statement of this digest found of
+        its joins' builds: none before one has run) and where the
+        groups are ranked."""
+        import dataclasses
+
         from ..copr import dag as Dg
         from ..copr.runagg import run_form
         from ..executor.plan import _mesh_platform
         if _mesh_platform() != "tpu" or not run_form(dag):
             return ""
+        found = self.domain.client.dependent_keys_found(dag)
+        if found is not None:
+            dag = dataclasses.replace(dag, dependent=found[0],
+                                      pack_words=found[1])
         out = "; one sort of " + (f"{dag.pack_words}-word records"
                                   if dag.pack_words else "hashed records")
+        if dag.dependent:
+            out += (", " + ", ".join(str(dag.group_by[j])
+                                     for j in dag.dependent)
+                    + " riding as dependents of the join's key")
         if dag.topn is not None:
             on_device = dag.pack_words and dag.topn.on_device \
                 and dag.topn.limit <= Dg.GROUP_TOPN_MAX \

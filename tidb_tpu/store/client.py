@@ -110,6 +110,11 @@ class CopClient:
         # (`_execute_sort_agg`; a GROUP BY above a join has no column
         # statistics to size its table from).  Guarded and capped alike.
         self._group_caps: OrderedDict[int, int] = OrderedDict()
+        # a GROUP BY above lookup joins whose builds a run found unique:
+        # the plan's digest -> (the group keys that rode as dependents,
+        # the record's words), for EXPLAIN (`dependent_keys_found`).
+        # Guarded and capped alike.
+        self._dependents: OrderedDict[int, tuple] = OrderedDict()
         # coprocessor RESULT cache (copr/coprocessor_cache.go analog):
         # key = (dag digest, snapshot epoch, placement epoch, shard
         # layout); a table write creates a new snapshot + epoch, so stale
@@ -746,6 +751,22 @@ class CopClient:
             while len(self._record_words) > self._page_feedback_cap:
                 self._record_words.popitem(last=False)
         return dataclasses.replace(agg, pack_words=words)
+
+    def found_dependent_keys(self, planned, marked: D.Aggregation) -> None:
+        """A run of the plan's DAG `planned` found its builds unique and
+        launched `marked` (executor/physical `_grouped`)."""
+        with self._pf_mu:
+            self._dependents[D.dag_digest(planned)] = (
+                marked.dependent, marked.pack_words)
+            while len(self._dependents) > self._page_feedback_cap:
+                self._dependents.popitem(last=False)
+
+    def dependent_keys_found(self, planned) -> Optional[tuple]:
+        """(`dependent`, `pack_words`) of what the last run of the
+        plan's DAG launched, or None: no statement of it has run, or
+        none found dependent keys."""
+        with self._pf_mu:
+            return self._dependents.get(D.dag_digest(planned))
 
     def _join_form(self, dag):
         """`dag` (of a program that joins), or with its compacting join
