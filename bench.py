@@ -477,8 +477,7 @@ def _sched_memwatch_scenario(dom, s, sched, queries, n=32, rounds=2):
     """memwatch rung (copgauge, ISSUE 14): the device-memory plane
     under the mixed query loop — ledger watermark vs the admission
     budget, per-digest HBM prediction error p50/p99 (the mem_factor
-    calibration state), roofline classification of the corpus digests,
-    and the ledger-overhead guard: the same loop with the ledger off vs
+    calibration state), and the ledger-overhead guard: the same loop with the ledger off vs
     on, acceptance <= 5% (ledger accounting is weakref bookkeeping +
     one memoized memory-analysis lookup per launch)."""
     def run_loop():
@@ -510,8 +509,6 @@ def _sched_memwatch_scenario(dom, s, sched, queries, n=32, rounds=2):
         if p.get("mem_samples", 0) > 0)
     def _pct_of(v, q):
         return round(v[min(int(q * len(v)), len(v) - 1)], 2) if v else None
-    from tidb_tpu.obs.roofline import roofline_store
-    roof = roofline_store().stats()
     return {
         "stmts_per_round": n,
         "ledger_off_s": round(off, 4),
@@ -528,11 +525,6 @@ def _sched_memwatch_scenario(dom, s, sched, queries, n=32, rounds=2):
         "mem_err_digests": len(errs),
         "mem_err_p50_pct": _pct_of(errs, 0.50),
         "mem_err_p99_pct": _pct_of(errs, 0.99),
-        "roofline": {
-            "peak_source": roof.get("peak_source"),
-            "bounds": roof.get("bounds"),
-            "entries": roof.get("entries"),
-        },
     }
 
 

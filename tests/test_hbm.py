@@ -1,6 +1,6 @@
-"""copgauge (obs/hbm + obs/roofline, ISSUE 14): the live HBM ledger,
-measured memory watermarks feeding continuous mem_factor calibration,
-per-digest roofline attribution, the /hbm + /profile routes, the
+"""copgauge (obs/hbm, ISSUE 14): the live HBM ledger, measured memory
+watermarks feeding continuous mem_factor calibration, the /hbm +
+/profile routes, the
 TPU-MEM-SOURCE lint rule, and the prometheus label-escaping satellite.
 
 Device-path tests pin `_platform` -> "tpu" (the tests/test_copcost.py
@@ -21,8 +21,6 @@ from tidb_tpu.analysis.calibrate import (CALIB_CLAMP_MAX,
                                          correction_store)
 from tidb_tpu.analysis.copcost import COST_TOLERANCE, LaunchCost
 from tidb_tpu.obs.hbm import HbmLedger, ledger_for, profiler_gate
-from tidb_tpu.obs.roofline import (LAUNCH_BOUND_MS, RoofStat,
-                                   backend_peaks, roofline_store)
 from tidb_tpu.session import Domain, Session
 
 
@@ -161,44 +159,6 @@ def test_corrected_cost_flips_admission_decision_both_ways():
         store.observe_mem("gauge/flip", cost, measured_bytes=1)
     lo = store.corrected_cost("gauge/flip", cost).peak_hbm_bytes
     assert lo <= budget                             # deflated: admit
-
-
-# ------------------------------------------------------------------ #
-# unit: roofline classification + peak table
-# ------------------------------------------------------------------ #
-
-def test_backend_peaks_declared_for_tpu_microbench_for_cpu():
-    bw, fl, src = backend_peaks("TPU v4")
-    assert (bw, fl) == (1228e9, 275e12) and src == "declared:v4"
-    bw, fl, src = backend_peaks("cpu")
-    assert src == "microbench:cpu"
-    assert bw > 1e8 and fl > 1e8        # calibrated-at-boot, not zero
-
-
-def test_backend_peaks_v5_lite_is_v5e_and_unknown_tpu_raises():
-    """jax calls a v5e chip "TPU v5 lite": it takes the v5e row by name,
-    not a catch-all, and a TPU with no row is an error, not a default."""
-    bw, fl, src = backend_peaks("TPU v5 lite")
-    assert (bw, fl) == (819e9, 197e12) and src == "declared:v5e"
-    assert backend_peaks("TPU v5e")[2] == "declared:v5e"
-    assert backend_peaks("TPU v5p")[:2] == (2765e9, 459e12)
-    with pytest.raises(ValueError, match="TPU v9"):
-        backend_peaks("TPU v9")
-
-
-def test_roofline_classification_three_bounds():
-    peaks = (100e9, 100e9)              # 100 GB/s, 100 GFLOP/s
-    mem = RoofStat(ewma_ms=10.0, transfer_bytes=800_000_000,
-                   flops=1_000_000)
-    assert mem.attribution(peaks)["bound"] == "memory-bound"
-    cpu = RoofStat(ewma_ms=10.0, transfer_bytes=1_000_000,
-                   flops=900_000_000)
-    assert cpu.attribution(peaks)["bound"] == "compute-bound"
-    tiny = RoofStat(ewma_ms=LAUNCH_BOUND_MS / 5, transfer_bytes=1_000,
-                    flops=1_000)
-    att = tiny.attribution(peaks)
-    assert att["bound"] == "launch-bound"
-    assert 0.0 <= att["gap_pct"] <= 100.0
 
 
 # ------------------------------------------------------------------ #
@@ -402,7 +362,6 @@ def test_ledger_off_is_byte_identical_static_model(monkeypatch):
     dom, s = _device_session(monkeypatch, rows=3000, name="toff")
     store = correction_store()
     store.reset()
-    roofline_store().reset()
     sched0 = dom.client._scheduler()
     led_launches0 = sched0._ledger_obj.launches \
         if sched0 is not None and sched0._ledger_obj is not None else 0
@@ -424,7 +383,6 @@ def test_ledger_off_is_byte_identical_static_model(monkeypatch):
         for _d, p in store.entries_payload().items():
             assert p["mem_factor"] == 1.0
             assert p["mem_samples"] == 0
-        assert roofline_store().observed == 0
         rows = s.must_query("explain analyze " + q)
         assert not any("hbm:" in str(r) for r in rows)
     finally:
@@ -442,7 +400,7 @@ def test_explain_analyze_reports_hbm_detail(monkeypatch):
 
 
 def test_hbm_and_profile_routes(monkeypatch):
-    """/hbm serves the ledger + roofline payload; /profile is gated by
+    """/hbm serves the ledger payload; /profile is gated by
     the sysvar and refuses while a capture is active."""
     from tidb_tpu.server.status import StatusServer
     dom, s = _device_session(monkeypatch, rows=3000, name="troute")
@@ -458,7 +416,7 @@ def test_hbm_and_profile_routes(monkeypatch):
         assert out["resident_bytes"] > 0
         assert out["watermark_bytes"] >= out["resident_bytes"] \
             or out["watermark_bytes"] > 0
-        assert "roofline" in out and "calibration" in out
+        assert "roofline" not in out and "calibration" in out
         assert isinstance(out["ledgers"], list) and out["ledgers"]
         # /profile: sysvar-gated
         ref = json.loads(urllib.request.urlopen(
@@ -484,7 +442,7 @@ def test_hbm_and_profile_routes(monkeypatch):
         srv.close()
 
 
-def test_hbm_gauges_and_roofline_gauges_in_prometheus_text(monkeypatch):
+def test_hbm_gauges_in_prometheus_text(monkeypatch):
     from tidb_tpu.utils.metrics import global_registry
     dom, s = _device_session(monkeypatch, rows=3000, name="tgauge")
     assert s.must_query("select sum(b) from tgauge where a > 1")
@@ -494,8 +452,8 @@ def test_hbm_gauges_and_roofline_gauges_in_prometheus_text(monkeypatch):
     assert "tidb_tpu_hbm_resident_bytes" in text
     assert "tidb_tpu_hbm_watermark_bytes" in text
     assert "tidb_tpu_hbm_budget_bytes" in text
-    assert "tidb_tpu_roofline_bytes_pct" in text
-    assert "tidb_tpu_roofline_flops_pct" in text
+    # the per-digest roofline gauges went with obs/roofline.py (PR 33)
+    assert "tidb_tpu_roofline" not in text
 
 
 # ------------------------------------------------------------------ #

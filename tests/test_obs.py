@@ -22,7 +22,6 @@ from tidb_tpu.obs import FlightRecorder, SpanTree, TraceCtx
 from tidb_tpu.obs.trace import TRACE_CTX, span
 from tidb_tpu.session import Domain, Session
 from tidb_tpu.utils.metrics import Histogram
-from tidb_tpu.utils.tracing import Tracer
 
 
 # ------------------------------------------------------------------ #
@@ -97,23 +96,6 @@ def test_span_context_manager_nests_and_restores():
     # untraced: span() is a no-op yielding None
     with span("ghost") as g:
         assert g is None
-
-
-def test_tracer_shim_back_compat():
-    """The legacy utils/tracing surface (region/spans/depth/rows) rides
-    the explicit-parent tree."""
-    tr = Tracer()
-    with tr.region("a"):
-        with tr.region("b"):
-            pass
-        with tr.region("c"):
-            pass
-    spans = tr.spans
-    assert [s.name for s in spans] == ["a", "b", "c"]
-    assert [s.depth for s in spans] == [0, 1, 1]
-    assert all(s.end_ns >= s.start_ns for s in spans)
-    rows = tr.rows()
-    assert rows[0][0] == "a" and rows[1][0].startswith("  ")
 
 
 # ------------------------------------------------------------------ #
@@ -429,17 +411,28 @@ def test_fused_count_seam_3member_regression(odom):
 
 # span -> its parent's name (None: another root of the tree)
 SERVED_SPANS = {
-    "session.parse": "session.ExecuteStmt",
+    "session.parse": "wire.stmt",
+    "session.begin": "wire.stmt",
+    "session.ExecuteStmt": "wire.stmt",
+    "session.enter": "session.ExecuteStmt",
     "session.plan": "session.ExecuteStmt",
+    "session.inputs": "session.ExecuteStmt",
     "plan.gates": "session.plan",
     "cop.dispatch": "session.ExecuteStmt",
+    "sched.task": "cop.dispatch",
     "sched.admit": "cop.dispatch",
     "sched.launch": "cop.dispatch",
+    "sched.epilogue": "cop.dispatch",
+    "sched.wake": "cop.dispatch",
     "cop.transfer": "session.ExecuteStmt",
+    "cop.d2h_issue": "cop.transfer",
     "cop.device_wait": "cop.transfer",
     "cop.d2h": "cop.transfer",
+    "session.outputs": "session.ExecuteStmt",
     "session.resultset": "session.ExecuteStmt",
-    "wire.write": None,
+    "session.finish": "wire.stmt",
+    "wire.write": "wire.stmt",
+    "wire.stmt": None,
 }
 TOPN_QUERY = "select q, p from obs_t order by p desc, q limit 5"
 
@@ -529,7 +522,7 @@ def test_served_statement_span_tree(odom, wire, shape):
         assert got == parent, (name, got)
     for sp in spans:
         if sp.name.startswith("cop.") and sp.name not in (
-                "cop.device_wait", "cop.d2h"):
+                "cop.device_wait", "cop.d2h", "cop.d2h_issue"):
             assert sp.parent_id == root.span_id, sp.name
     launch = next(sp for sp in spans if sp.name == "sched.launch")
     assert launch.attrs["program"].startswith(program), launch.attrs
@@ -546,6 +539,290 @@ def test_served_statement_span_tree(odom, wire, shape):
     d2h = next(sp for sp in spans if sp.name == "cop.d2h")
     assert xfer.start_ns <= wait.start_ns <= wait.end_ns \
         <= d2h.start_ns <= d2h.end_ns <= xfer.end_ns
+
+
+# ------------------------------------------------------------------ #
+# the closed tree: wire.stmt at the connection, named stretches
+# ------------------------------------------------------------------ #
+
+CONTAINERS = ("wire.stmt", "session.ExecuteStmt", "cop.dispatch",
+              "cop.transfer")
+
+
+def _closed_tree(dom, sql_frag):
+    """The served statement's tree once the connection has ended its
+    ``wire.stmt`` (after the client has the last row)."""
+    deadline = time.monotonic() + 5
+    while time.monotonic() < deadline:
+        tree = _trace_of(dom, sql_frag)
+        if tree is not None and tree.spans[0].name == "wire.stmt" \
+                and tree.spans[0].end_ns:
+            return tree
+        time.sleep(0.01)
+    raise AssertionError(f"no closed wire.stmt for {sql_frag!r}")
+
+
+@pytest.mark.parametrize("how", ["query", "topn", "prepared", "packet"])
+def test_served_tree_is_closed_under_wire_stmt(odom, wire, how):
+    """A SELECT served as COM_QUERY (an aggregate, a rows plan), as
+    COM_STMT_EXECUTE and as the second statement of a packet of two: one
+    tree, rooted at ``wire.stmt``; every span lies inside the root;
+    under each container the children of one thread do not overlap, so
+    a container's self-time is what has no name."""
+    from tidb_tpu.server.client import Client
+    dom, _s, _sched = odom
+    c = Client("127.0.0.1", wire.port, db="test")
+    try:
+        if how == "prepared":
+            frag = "sum(p) from obs_t where q < 31"
+            st = c.prepare("select sum(p) from obs_t where q < ?")
+            assert st.execute(31)
+        elif how == "packet":
+            frag = "max(p) from obs_t where q < 7"
+            assert c.query("select min(d) from obs_t; "
+                           "select max(p) from obs_t where q < 7")
+        elif how == "topn":
+            frag = "order by p desc"
+            assert c.query(TOPN_QUERY)
+        else:
+            frag = "sum(p * p * p * d)"
+            assert c.query(OBS_QUERIES[1])
+    finally:
+        c.close()
+    tree = _closed_tree(dom, frag)
+    spans = tree.spans
+    roots = [sp for sp in spans if sp.parent_id is None]
+    assert [sp.name for sp in roots] == ["wire.stmt"]
+    root = roots[0]
+    by_id = {sp.span_id: sp for sp in spans}
+    for sp in spans:
+        assert sp.end_ns, f"{sp.name} never ended"
+        assert root.start_ns <= sp.start_ns <= sp.end_ns <= root.end_ns, \
+            sp.name
+    top = sorted((sp for sp in spans if sp.parent_id == root.span_id),
+                 key=lambda sp: sp.start_ns)
+    want = ["session.begin", "session.ExecuteStmt", "session.finish",
+            "wire.write"]
+    if how != "packet":         # the packet's first statement has it
+        want.insert(0, "session.parse")
+    assert [sp.name for sp in top] == want
+    for box in (sp for sp in spans if sp.name in CONTAINERS):
+        by_thread: dict = {}
+        for sp in spans:
+            if sp.parent_id == box.span_id:
+                by_thread.setdefault(sp.thread, []).append(sp)
+        for kids in by_thread.values():
+            kids.sort(key=lambda sp: sp.start_ns)
+            for a, b in zip(kids, kids[1:]):
+                assert a.end_ns <= b.start_ns, (box.name, a.name, b.name)
+    # the stretches that had no name: each once, where it belongs
+    for name, parent in (("session.enter", "session.ExecuteStmt"),
+                         ("session.inputs", "session.ExecuteStmt"),
+                         ("session.outputs", "session.ExecuteStmt"),
+                         ("sched.task", "cop.dispatch"),
+                         ("sched.pickup", "cop.dispatch"),
+                         ("sched.epilogue", "cop.dispatch"),
+                         ("sched.wake", "cop.dispatch")):
+        (sp,) = [x for x in spans if x.name == name]
+        assert by_id[sp.parent_id].name == parent
+    # session.inputs ends where the first cop.* span begins, and
+    # session.outputs runs from the last one to the result set
+    inputs = next(sp for sp in spans if sp.name == "session.inputs")
+    outputs = next(sp for sp in spans if sp.name == "session.outputs")
+    cops = [sp for sp in spans if sp.name.startswith("cop.")]
+    assert inputs.end_ns <= min(sp.start_ns for sp in cops)
+    assert outputs.start_ns >= max(sp.end_ns for sp in cops)
+    assert outputs.end_ns <= next(
+        sp.start_ns for sp in spans if sp.name == "session.resultset")
+    # the task is built inside sched.task and still hangs its drain
+    # spans under cop.dispatch
+    task = next(sp for sp in spans if sp.name == "sched.task")
+    assert task.end_ns <= next(
+        sp.start_ns for sp in spans if sp.name == "sched.admit")
+    # the extent statements_summary and wire_ms read has not moved: the
+    # statement's loop start (where session.begin starts) -> the root's end
+    exe = next(sp for sp in spans if sp.name == "session.ExecuteStmt")
+    begin = next(sp for sp in spans if sp.name == "session.begin")
+    assert tree.latency_ms * 1e6 == pytest.approx(
+        exe.end_ns - begin.start_ns, abs=1.0)
+
+
+@pytest.mark.parametrize("shape", ["agg", "topn"])
+def test_session_without_connection_keeps_its_root(odom, shape):
+    """No connection, no ``wire.stmt``: ``session.ExecuteStmt`` is the
+    root, with ``session.parse`` and its ``cop.*`` children under it as
+    before; ``session.finish`` follows it as ``wire.write`` used to."""
+    dom, _s, _sched = odom
+    s2 = Session(dom)
+    s2.must_query(OBS_QUERIES[2] if shape == "agg" else TOPN_QUERY)
+    tree = s2.last_trace
+    assert s2.wire_span is None
+    spans = tree.spans
+    assert "wire.stmt" not in {sp.name for sp in spans}
+    roots = sorted((sp for sp in spans if sp.parent_id is None),
+                   key=lambda sp: sp.start_ns)
+    assert [sp.name for sp in roots] == ["session.ExecuteStmt",
+                                         "session.finish"]
+    root = roots[0]
+    kids = {sp.name for sp in spans if sp.parent_id == root.span_id}
+    assert {"session.parse", "session.begin", "session.enter",
+            "session.plan", "session.inputs", "cop.dispatch",
+            "session.outputs", "session.resultset"} <= kids
+    assert all(sp.parent_id == root.span_id for sp in spans
+               if sp.name in ("cop.dispatch", "cop.transfer",
+                              "cop.host_merge"))
+    assert roots[1].start_ns == root.end_ns
+
+
+@pytest.mark.parametrize("served", [False, True], ids=["session", "wire"])
+def test_sched_wake_runs_from_the_drains_finish_stamp(odom, wire, served):
+    """``sched.epilogue`` is the drain's and ends at the stamp it took
+    before ``finish()``; ``sched.wake`` starts at that stamp and is
+    recorded by the waiter, on the statement's thread."""
+    from tidb_tpu.server.client import Client
+    dom, _s, _sched = odom
+    if served:
+        c = Client("127.0.0.1", wire.port, db="test")
+        assert c.query("select sum(p) from obs_t where d < 4")
+        c.close()
+        tree = _closed_tree(dom, "where d < 4")
+    else:
+        s2 = Session(dom)
+        s2.must_query("select sum(p) from obs_t where d < 3")
+        tree = s2.last_trace
+    by = {sp.name: sp for sp in tree.spans}
+    launch, epi, wake = by["sched.launch"], by["sched.epilogue"], \
+        by["sched.wake"]
+    assert epi.thread.startswith("sched-drain") == launch.thread \
+        .startswith("sched-drain")
+    assert epi.start_ns == launch.end_ns
+    assert wake.start_ns == epi.end_ns > epi.start_ns
+    assert wake.thread == by["session.ExecuteStmt"].thread != epi.thread
+    assert wake.end_ns <= by["cop.dispatch"].end_ns
+    # the queue's span starts where admission put the task in the queue
+    assert by["sched.queue"].start_ns >= by["sched.admit"].start_ns
+    assert by["sched.queue"].end_ns <= launch.start_ns
+
+
+# ------------------------------------------------------------------ #
+# flight recorder: a sample of every digest, and a digest's outliers
+# ------------------------------------------------------------------ #
+
+def _feed(fr, summary, sql: str, ms: float):
+    """One statement as ``Session.execute`` offers it: counted by its
+    digest first, then offered with its place and the digest's mean."""
+    tree = SpanTree(sql=sql)
+    tree.end(tree.begin("wire.stmt"))
+    tree.latency_ms = ms
+    seen = summary.record(sql, int(ms * 1e6), 0)
+    return tree, fr.record(tree, nth=seen.nth, mean_ms=seen.mean_ms)
+
+
+def _mix(period: int) -> list[str]:
+    """A statement sequence that repeats after ``period`` places."""
+    rng = np.random.default_rng(period)
+    if period == 2:
+        seq = ["a", "b"]
+    elif period == 13:                  # coprime to the cadence
+        seq = list(rng.permutation(["a"] * 6 + ["b"] * 4 + ["c"] * 3))
+    elif period == 16:                  # a rare digest behind a common
+        seq = ["a"] * 16                # one, never at a kept place of
+        seq[5] = "rare"                 # the process-wide count
+    else:                               # tpch1x1.orderjoin's: sixteen
+        seq = [x for _ in range(16)     # shuffled cycles of two
+               for x in rng.permutation(["q3", "q12"])]
+    assert len(seq) == period
+    return [f"select {name} from t where x = 1" for name in seq]
+
+
+@pytest.mark.parametrize("period", [2, 13, 16, 32])
+def test_recorder_samples_every_digest(period):
+    """Whatever the mix's period, each digest is kept one time in
+    ``sample_every``, within one trace: the place is the digest's own,
+    not the process's."""
+    from tidb_tpu.utils.stmtsummary import StmtSummary
+    fr = FlightRecorder(capacity=4096, sample_every=16)
+    summary = StmtSummary()
+    seq = _mix(period)
+    sent: dict = {}
+    for i in range(1600):
+        sql = seq[i % period]
+        _feed(fr, summary, sql, 5.0)
+        sent[sql] = sent.get(sql, 0) + 1
+    kept: dict = {}
+    for ent in fr.index():
+        assert ent["flags"] == [] and ent["nth"] % 16 == 1
+        kept[ent["sql"]] = kept.get(ent["sql"], 0) + 1
+    assert set(kept) == set(sent)
+    for sql, n in sent.items():
+        assert abs(kept[sql] - n / 16) <= 1, (sql, n, kept[sql])
+    st = fr.stats()
+    assert st["seen"] == 1600 and st["outliers"] == 0
+    assert st["recorded"] + st["sampled_out"] == st["seen"]
+
+
+@pytest.mark.parametrize("times,execs,outlier", [
+    (2.0, 70, True), (1.2, 70, False), (2.0, 20, False),
+    (1.6, 70, False), (2.0, 40, False)],
+    ids=["twice", "a_fifth_over", "young_digest", "under_a_ms_over",
+         "mean_holds_a_compile"])
+def test_recorder_keeps_a_digests_outliers(times, execs, outlier):
+    """Over 1.5 times its digest's running mean (its last complete
+    block of 32 executions) and a millisecond over it: flagged
+    ``outlier``, kept whatever its place, counted; otherwise it is one
+    of the sampled.  The text's compile is the digest's first
+    statement: it spoils the first block's mean and no later one's."""
+    from tidb_tpu.utils.stmtsummary import StmtSummary
+    fr = FlightRecorder(capacity=64, sample_every=16)
+    summary = StmtSummary()
+    sql = "select sum(v) from t where k = 3"
+    mean = 1.2 if times == 1.6 else 10.0   # 1.6 x 1.2 ms: +0.72 ms only
+    _feed(fr, summary, sql, 60_000.0)
+    for _ in range(execs - 1):
+        _feed(fr, summary, sql, mean)
+    assert (execs + 1) % 16 != 1           # sampling would not keep it
+    tree, kept = _feed(fr, summary, sql, mean * times)
+    assert kept is outlier
+    assert ("outlier" in tree.flags) is outlier
+    assert fr.stats()["outliers"] == int(outlier)
+    if outlier:
+        ent = fr.index()[0]
+        assert ent["flags"] == ["outlier"] and ent["nth"] == 0
+
+
+@pytest.mark.parametrize("kind", ["outlier", "slow", "sampled"])
+def test_gc_ms_rides_the_trees_kept_as_slow(kind):
+    """A collector run of generation 2 inside the statement shows as
+    ``gc_ms`` on the root of a tree kept as ``outlier`` or ``slow``; a
+    sampled tree carries none (nothing reads the ring for it)."""
+    import gc
+    from tidb_tpu.utils.stmtsummary import StmtSummary
+    fr = FlightRecorder(capacity=64, sample_every=1)
+    summary = StmtSummary()
+    sql = "select count(*) from t where k = 4"
+    for _ in range(40):
+        _feed(fr, summary, sql, 10.0)
+    tree = SpanTree(sql=sql)
+    root = tree.begin("wire.stmt")
+    junk = [[i] for i in range(50_000)]     # something to traverse
+    gc.collect()
+    del junk
+    tree.end(root)
+    tree.latency_ms = 10.0 if kind == "sampled" else 30.0
+    if kind == "slow":
+        tree.flag("slow")
+        assert fr.record(tree)
+    else:
+        seen = summary.record(sql, int(tree.latency_ms * 1e6), 0)
+        assert fr.record(tree, nth=seen.nth, mean_ms=seen.mean_ms)
+    attrs = tree.spans[0].attrs
+    if kind == "sampled":
+        assert "gc_ms" not in attrs and not tree.flags
+    else:
+        assert kind in tree.flags
+        span_ms = (tree.spans[0].end_ns - tree.spans[0].start_ns) / 1e6
+        assert 0 < attrs["gc_ms"] <= span_ms
+        assert tree.to_dict()["spans"][0]["attrs"]["gc_ms"] > 0
 
 
 def test_plan_cache_hit_skips_the_gates(odom):
@@ -686,11 +963,17 @@ def test_spans_land_in_the_profilers_trace(odom, tmp_path):
     assert {"session.ExecuteStmt", "session.plan", "cop.dispatch",
             "sched.admit", "sched.launch", "cop.transfer",
             "cop.device_wait", "cop.d2h", "cop.host_merge",
-            "session.resultset"} <= set(found), sorted(found)
+            "session.resultset", "session.inputs", "session.outputs",
+            "session.finish", "sched.task",
+            "sched.epilogue"} <= set(found), sorted(found)
     line, stats = found["sched.launch"]
     assert line != found["cop.dispatch"][0]
+    assert found["sched.epilogue"][0] == line   # the drain's, both
     assert stats["program"].startswith("cop_solo_agg_scalar_")
-    assert "sched.queue" not in found       # a wait stays tree-only
+    # waits and containers stay tree-only, and so does the stretch that
+    # hostspans.phase would not know for a transfer span
+    assert not {"sched.queue", "sched.pickup", "sched.wake", "wire.stmt",
+                "cop.d2h_issue"} & set(found)
 
 
 # ------------------------------------------------------------------ #
@@ -768,7 +1051,12 @@ def test_status_trace_routes(odom):
         idx = json.loads(urllib.request.urlopen(
             f"http://127.0.0.1:{port}/trace", timeout=5).read())
         assert idx["stats"]["size"] >= 1
+        assert {"seen", "recorded", "sampled_out",
+                "outliers"} <= set(idx["stats"])
         ent = next(e for e in idx["traces"] if "count(*)" in e["sql"])
+        # why it was kept: its flags, or the digest's place that
+        # admitted it (the fixture keeps every place)
+        assert ent["flags"] == [] and ent["nth"] >= 1
         tid = ent["trace_id"]
         full = json.loads(urllib.request.urlopen(
             f"http://127.0.0.1:{port}/trace/{tid}", timeout=5).read())

@@ -152,7 +152,6 @@ SEAM_GETTERS = {
     "compile_cache": "compilecache/cache.py",
     "global_registry": "utils/metrics.py",
     "ledger_for": "obs/hbm.py",
-    "roofline_store": "obs/roofline.py",
     "scheduler_for": "sched/scheduler.py",
     "current_recorder": "obs/recorder.py",
 }

@@ -27,6 +27,7 @@ from ..copr.aggregate import GroupKeyMeta, sum_out_dtype
 from ..expr.compile import eval_expr
 from ..expr.ir import ColumnRef, Const, Expr, Func, referenced_columns
 from ..expr.lower_strings import lower_strings
+from ..obs.trace import until_next as _obs_until_next
 from ..planner.logical import (AggItem, DataSource, LogicalAggregate,
                                LogicalJoin, LogicalLimit, LogicalPlan,
                                LogicalProjection, LogicalSelection,
@@ -388,6 +389,9 @@ class CopTaskExec(PhysOp):
             cols = ctx.client.execute_rows(self.dag, snap,
                                            tuple(self.out_dtypes),
                                            self.out_dicts)
+        # session.outputs: the operators above this cop task, from its
+        # columns to the statement's next span
+        _obs_until_next("session.outputs", root_only=True)
         # NOTE: scan output is NOT charged to the statement quota — the
         # columns are the device-resident data plane (HBM residency is the
         # TPU analog of the reference's paging, SURVEY.md §5.7); the quota
@@ -588,6 +592,8 @@ class CopJoinTaskExec(PhysOp):
             self.build_key_dict, self.probe_key_dtype, want_cols=not semi,
             read=None if semi or join is None
             else D.build_columns_read(self.dag, join))
+        # from the build to the probe's dispatch: its inputs again
+        _obs_until_next("session.inputs", root_only=True)
         side = built.side
         dag = self.dag
         if self.null_aware and built.null_key:
@@ -696,6 +702,7 @@ class CopJoinTaskExec(PhysOp):
             cols = ctx.client.execute_rows(dag, snap,
                                            tuple(self.out_dtypes),
                                            self.out_dicts, aux_cols=aux)
+        _obs_until_next("session.outputs", root_only=True)
         for j, d in self.out_dicts.items():
             if j < len(cols) and cols[j].dictionary is None:
                 cols[j].dictionary = d
@@ -2383,6 +2390,7 @@ def _prep_build_groups(ctx, builds, keys_for, dag):
         side = _prepared_build(ctx, b["exec"], b["key_index"], keys_for,
                                b["key_dict"], b["probe_key_dtype"],
                                want_cols=True).side
+        _obs_until_next("session.inputs", root_only=True)
         if side is None or not side.unique:
             return None
         if side.dense:

@@ -6,8 +6,8 @@ stack.
   explicit parent ids — the scheduler drain, copforge resolve, and
   client transfer/merge seams record real spans from their own threads.
 - ``recorder``: bounded flight-recorder ring of completed query traces
-  (failed/degraded/quarantined/retried/slow always kept, the rest
-  sampled), served at ``/trace`` + ``/trace/<id>`` with Chrome
+  (failed/degraded/quarantined/retried/slow and a digest's outliers
+  always kept, the rest sampled per digest), served at ``/trace`` + ``/trace/<id>`` with Chrome
   trace-event export (``?fmt=chrome``).
 
 Latency histograms ride ``utils/metrics`` (label-aware prometheus-text
@@ -20,16 +20,13 @@ copgauge (ISSUE 14) adds the memory/throughput axis:
   the PR 7 weakref registry, launch-scoped bytes at admission/finish),
   measured launch watermarks, bounded device ``memory_stats``
   reconciliation, and the on-demand ``/profile`` capture gate.
-- ``roofline``: per-program-digest achieved-vs-peak bytes/s and
-  FLOPs/s attribution (memory-/compute-/launch-bound) against a
-  declared per-backend peak table (CPU: boot-time microbench).
+  (A kernel's share of its roofline is the benchmark's to read, from
+  the device's trace: ``benchmark/layer_metrics/*_roofline.py``.)
 """
 
 from .hbm import (HbmLedger, all_ledgers, device_memory_stats,
                   hbm_status, ledger_for, profiler_gate)
 from .recorder import FlightRecorder
-from .roofline import (backend_peaks, peaks_for_mesh, roofline_status,
-                       roofline_store)
 from .trace import (TRACE_CTX, Span, SpanTree, TraceCtx, annotate,
                     current, flag, late_span, live, live_child,
                     new_trace_id, span)
@@ -38,5 +35,4 @@ __all__ = ["Span", "SpanTree", "TraceCtx", "TRACE_CTX", "current",
            "span", "late_span", "live", "live_child", "flag", "annotate",
            "new_trace_id", "FlightRecorder",
            "HbmLedger", "ledger_for", "all_ledgers", "hbm_status",
-           "device_memory_stats", "profiler_gate", "roofline_store",
-           "roofline_status", "backend_peaks", "peaks_for_mesh"]
+           "device_memory_stats", "profiler_gate"]

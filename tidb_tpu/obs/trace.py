@@ -5,8 +5,8 @@ Reference analog: pkg/util/tracing's StartRegionEx regions rendered by
 the TRACE statement (executor/trace.go), grown to the Canopy/Dapper
 shape the async stack needs — the statement path crosses seven thread
 seams (admission queue, rc throttle, fusion window, copforge compile,
-supervised launch, transfer, host merge) so the depth-counter Tracer of
-``utils/tracing`` cannot attribute them.  Here every span carries an
+supervised launch, transfer, host merge) so a depth counter cannot
+attribute them.  Here every span carries an
 EXPLICIT parent id and the per-statement tree is lock-protected, so the
 scheduler drain, copforge resolve, and client transfer seams record
 real spans from their own threads and the session renderer stitches one
@@ -37,15 +37,30 @@ thread that made them and on the clock of the device's ``XLA Ops``.
 While no profiler session records, an annotation is a flag test in C++
 (50 ns) and a shared null context.  The drain thread records its spans post hoc
 (``add_batch``), so it brackets the work itself with ``live()``; waits
-(``sched.queue``) stay tree-only.
+(``sched.queue``, ``sched.wake``) and containers that are all children
+(``wire.stmt``) stay tree-only.
+
+The tree is closed: a statement served over the wire is rooted at
+``wire.stmt`` (the command's payload read -> the last ``sendall`` of its
+result), and every stretch of the statement's own threads has a name
+(``until_next`` brackets the ones that end where a callee's first span
+begins).  What a container's children do not cover is its self-time;
+the benchmark's ``server_unnamed_ms`` sums it.
+
+``gc_ms``: the collector's runs of generation 1 and 2 are kept in a ring
+of 64 (``gc.callbacks``); ``note_gc`` puts the milliseconds of those
+that overlap a statement on its root span, and is called only for a
+tree the recorder keeps as ``slow`` or ``outlier``.
 """
 
 from __future__ import annotations
 
 import contextvars
+import gc
 import itertools
 import threading
 import time
+from collections import deque
 from contextlib import ContextDecorator, contextmanager, nullcontext
 from typing import Optional
 
@@ -105,8 +120,16 @@ class SpanTree:
         self.wall_start = time.time()
         self.latency_ms = 0.0
         self.flags: set = set()       # failed/degraded/quarantined/
-                                      # retried/slow — recorder retention
+                                      # retried/slow/outlier — recorder
+                                      # retention
+        self.nth = 0                  # the statement's place in its
+                                      # digest's count, where that is
+                                      # what admitted it to the recorder
         self.spans: list[Span] = []
+        # the session.ExecuteStmt span's id (``until_next(root_only=)``)
+        self.exec_root: Optional[int] = None
+        # an ``until_next`` span still open: (span, annotation, thread id)
+        self.pending: Optional[tuple] = None
         self._mu = threading.Lock()
         self._next = 0
 
@@ -125,10 +148,12 @@ class SpanTree:
             return sid
 
     def open(self, name: str, parent_id: Optional[int],
-             attrs: dict) -> Span:
-        """Record an OPEN span starting now and hand it back: its owner
-        sets ``end_ns`` on it (``span()``'s path: no search on exit)."""
-        sp = Span(0, parent_id, name, time.perf_counter_ns(), 0,
+             attrs: dict, start_ns: int = 0) -> Span:
+        """Record an OPEN span starting now (or at ``start_ns``) and
+        hand it back: its owner sets ``end_ns`` on it (``span()``'s
+        path: no search on exit)."""
+        sp = Span(0, parent_id, name,
+                  start_ns or time.perf_counter_ns(), 0,
                   thread=threading.current_thread().name, attrs=attrs)
         with self._mu:
             sp.span_id = self._next = self._next + 1
@@ -240,6 +265,7 @@ class SpanTree:
             "start_ts": self.wall_start,
             "latency_ms": round(self.latency_ms, 3),
             "flags": sorted(self.flags),
+            "nth": self.nth,
             "spans": [{
                 "id": sp.span_id, "parent": sp.parent_id,
                 "name": sp.name, "thread": sp.thread,
@@ -344,6 +370,8 @@ class span(ContextDecorator):
         if ctx is None:
             return None
         tree = ctx.tree
+        if tree.pending is not None:
+            close_pending(tree)
         sp = self._sp = tree.open(self.name, ctx.span_id, self.attrs)
         sub = TraceCtx(tree, sp.span_id)
         self._tok = TRACE_CTX.set(sub)
@@ -372,12 +400,63 @@ def annotation(name: str, trace_id: str = "", **attrs):
     return Annotation(name, trace_id=trace_id, **attrs)
 
 
+def until_next(name: str, root_only: bool = False) -> None:
+    """Open a live span under the active context that ends where the
+    next ``span()`` of this thread begins (or at ``close_pending``): a
+    stretch of the caller's own work whose end is a callee's first span
+    (``session.inputs``: plan built -> the first ``cop.*``;
+    ``session.outputs``: a cop task's columns returned -> the next
+    span).  It does not become the parent of what follows.
+    ``root_only``: only directly under ``session.ExecuteStmt`` (a cop
+    task run inside ``cop.join_build`` leaves that span's self-time
+    alone).  No-op when untraced."""
+    ctx = TRACE_CTX.get()
+    if ctx is None:
+        return
+    tree = ctx.tree
+    if root_only and ctx.span_id != tree.exec_root:
+        return
+    if tree.pending is not None:
+        close_pending(tree)
+        if tree.pending is not None:    # another thread's: leave both
+            return
+    ann = annotation(name, tree.trace_id)
+    sp = tree.open(name, ctx.span_id, {})
+    ann.__enter__()
+    tree.pending = (sp, ann, threading.get_ident())
+
+
+def end_pending() -> None:
+    """End the active tree's ``until_next`` span here, where no span
+    begins (``session.enter`` ends at the statement's dispatch)."""
+    ctx = TRACE_CTX.get()
+    if ctx is not None and ctx.tree.pending is not None:
+        close_pending(ctx.tree)
+
+
+def close_pending(tree: SpanTree, force: bool = False) -> None:
+    """End the tree's ``until_next`` span, on the thread that opened it
+    (another thread's spans leave it open: an annotation is left where
+    it was entered).  ``force``: the statement is over, end it whoever
+    opened it (a pool worker's is left to its annotation's destructor)."""
+    p = tree.pending
+    if p is None:
+        return
+    mine = p[2] == threading.get_ident()
+    if mine or force:
+        tree.pending = None
+        if mine:
+            p[1].__exit__(None, None, None)
+        p[0].end_ns = time.perf_counter_ns()
+
+
 @contextmanager
-def late_span(tree: Optional[SpanTree], name: str):
-    """A span on a statement's FINISHED tree, as another root: the
-    connection writes the result after ``Session.execute`` returned and
-    the recorder took the tree (it holds a reference; ``add`` is
-    lock-protected).  ``tree`` None = untraced = no-op."""
+def late_span(tree: Optional[SpanTree], name: str,
+              parent_id: Optional[int] = None):
+    """A span on a statement's tree after ``Session.execute`` returned
+    and the recorder took the tree (it holds a reference; ``add`` is
+    lock-protected): the connection's ``wire.write``, under the
+    statement's ``wire.stmt``.  ``tree`` None = untraced = no-op."""
     if tree is None:
         yield
         return
@@ -386,7 +465,17 @@ def late_span(tree: Optional[SpanTree], name: str):
         with annotation(name, tree.trace_id):
             yield
     finally:
-        tree.add(name, t0, time.perf_counter_ns())
+        tree.add(name, t0, time.perf_counter_ns(), parent_id=parent_id)
+
+
+def leaf(name: str):
+    """A live span under the active context that does NOT re-point
+    ``TRACE_CTX``: nothing nests under it, and a ``CopTask`` built
+    inside still captures the enclosing span (``sched.task``)."""
+    ctx = TRACE_CTX.get()
+    if ctx is None:
+        return _NO_ANNOTATION
+    return late_span(ctx.tree, name, ctx.span_id)
 
 
 # the TraceCtx whose live() annotation is open on this thread
@@ -434,6 +523,53 @@ def annotate(**attrs) -> None:
         ctx.tree.annotate(ctx.span_id, **attrs)
 
 
+# ---- the collector's runs, for the trees that are kept as slow ---- #
+
+GC_RING = 64
+# a tree kept for being slow carries the collector's share of its time
+GC_FLAGS = frozenset({"slow", "outlier"})
+# (start_ns, end_ns, generation) of the last runs of generation 1 or 2
+_GC_RUNS: deque = deque(maxlen=GC_RING)
+_gc_t0 = [0]
+
+
+def _gc_callback(phase: str, info: dict) -> None:
+    if info["generation"] == 0:     # a young run: microseconds, and
+        return                      # several a statement
+    if phase == "start":
+        _gc_t0[0] = time.perf_counter_ns()
+    elif _gc_t0[0]:
+        _GC_RUNS.append((_gc_t0[0], time.perf_counter_ns(),
+                         info["generation"]))
+        _gc_t0[0] = 0
+
+
+gc.callbacks.append(_gc_callback)
+
+
+def gc_overlap_ms(start_ns: int, end_ns: int) -> float:
+    """Milliseconds of the remembered collector runs inside
+    [start_ns, end_ns]."""
+    return sum(max(min(b, end_ns) - max(a, start_ns), 0)
+               for a, b, _gen in list(_GC_RUNS)) / 1e6
+
+
+def note_gc(tree: SpanTree) -> None:
+    """``gc_ms`` on the root span of a tree flagged slow or outlier (any
+    other tree is left alone: nothing reads the ring for it): the
+    collector's runs from the root's start until now.  The recorder
+    calls it when it keeps the tree, the connection again when the
+    result is written."""
+    with tree._mu:
+        root = tree.spans[0] if tree.spans and tree.flags & GC_FLAGS \
+            else None
+    if root is not None:
+        root.attrs["gc_ms"] = round(
+            gc_overlap_ms(root.start_ns, time.perf_counter_ns()), 3)
+
+
 __all__ = ["Span", "SpanTree", "TraceCtx", "TRACE_CTX", "current",
-           "span", "late_span", "live", "live_child", "annotation",
-           "flag", "annotate", "new_trace_id"]
+           "span", "late_span", "leaf", "until_next", "end_pending",
+           "close_pending", "live",
+           "live_child", "annotation", "flag", "annotate",
+           "new_trace_id", "note_gc", "gc_overlap_ms", "GC_FLAGS"]

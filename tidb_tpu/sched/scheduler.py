@@ -72,6 +72,7 @@ period, so embedders that never touch the device pay nothing.
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 import random
@@ -188,8 +189,8 @@ class DeviceScheduler:
         self.calibration_enable = True
         # copgauge (obs/hbm, tidb_tpu_hbm_ledger sysvar): live HBM
         # ledger accounting at launch begin/finish, measured launch
-        # watermarks feeding mem_factor calibration, and per-digest
-        # roofline attribution.  Off = the static model byte-identical
+        # watermarks feeding mem_factor calibration.  Off = the static
+        # model byte-identical
         # to the pre-copgauge behavior (mem_factor moves only on OOM).
         self.hbm_enable = True
         self._ledger_obj = None
@@ -672,6 +673,12 @@ class DeviceScheduler:
                         target=self._loop, name="sched-drain", daemon=True)
                     self._thread.start()
                 self._cv.notify_all()
+        # the tree's sched.queue span runs from the end of sched.admit,
+        # so that the two share no time (the drain cannot have the
+        # interpreter before this thread blocks; where it did, the span
+        # starts at its pick-up); wait_ns (/sched wait_p50_ms) keeps
+        # counting from submit_ns
+        task.enqueue_ns = time.perf_counter_ns()
         if task.fusion_key is not None and task.key is not None:
             # copforge: a second digest joining this fusion key predicts
             # the fused variant — warm it off-thread (lock released)
@@ -777,7 +784,8 @@ class DeviceScheduler:
                     if t.trace is not None:
                         # the waiter never launched: its whole life was
                         # queue wait — record it with the expiry marked
-                        t.trace.add("sched.queue", t.submit_ns, now,
+                        t.trace.add("sched.queue",
+                                    min(t.enqueue_ns, now), now,
                                     group=g.name, expired=True)
                     t.fail(ResourceExhaustedError(
                         t.group, (now - t.submit_ns) / 1e9, t.rus))
@@ -1184,6 +1192,24 @@ class DeviceScheduler:
         ctx = next((t.trace for t in tasks if t.trace is not None), None)
         return _obs.live("sched.launch", ctx, mode=mode, program=program)
 
+    @contextlib.contextmanager
+    def _epilogue(self, tasks: list):
+        """``sched.epilogue``: what the drain does between a launch
+        span's end and ``finish()`` (the launch's spans and histograms,
+        its facts' counters).  Entered where ``sched.launch`` ends:
+        ``_trace_launch`` opens the tree's span there, for each traced
+        task, and this stamps its end, and the finish the waiter's
+        ``sched.wake`` starts from, just before the caller finishes
+        the tasks."""
+        ctx = next((t.trace for t in tasks if t.trace is not None), None)
+        with _obs.live("sched.epilogue", ctx):
+            yield
+            if ctx is not None:
+                now = time.perf_counter_ns()
+                for t in tasks:
+                    if t.epilogue is not None:
+                        t.epilogue.end_ns = t.finish_ns = now
+
     def count(self, name: str) -> None:
         """Bump one of `kernel_counts` for an event no launch carries
         (copr/facts.EVENTS), from whichever thread sees it happen."""
@@ -1206,7 +1232,7 @@ class DeviceScheduler:
         finish — a waiter rendering its trace right after wait()
         always sees these spans (no post-finish race).
 
-        Per traced task: a ``sched.queue`` span (submit -> drain
+        Per traced task: a ``sched.queue`` span (enqueue -> drain
         pickup; rc debit rides it as the ``ru`` attr) and a
         ``sched.launch`` span (resolve + DISPATCH: the call returns
         once the program is enqueued, before the device has run it)
@@ -1261,10 +1287,19 @@ class DeviceScheduler:
             if t.retries:
                 attrs["retries"] = t.retries
             items = [
-                ("sched.queue", t.submit_ns, t.start_ns, ctx.span_id,
+                ("sched.queue", min(t.enqueue_ns, t.start_ns),
+                 t.start_ns, ctx.span_id,
                  {"group": t.group, "ru": round(t.rus_charged, 2)}),
                 ("sched.launch", start_ns, end_ns, ctx.span_id, attrs),
             ]
+            if fused <= 1 and start_ns > t.start_ns:
+                # the drain between picking the batch up and the launch
+                # span: the ledger, the supervisor, the grouping by key
+                # (tree only: the statement's cop.dispatch annotation
+                # is the innermost in flight; a fused launch's assembly
+                # is sched.fusion)
+                items.append(("sched.pickup", t.start_ns, start_ns,
+                              ctx.span_id, {}))
             if t.compile_ns:
                 items.append((
                     "sched.compile", start_ns, start_ns + t.compile_ns,
@@ -1277,6 +1312,9 @@ class DeviceScheduler:
                 items.append(("sched.fusion", t.start_ns, start_ns,
                               ("rel", 1), fat))
             ctx.tree.add_batch(items)
+            # open until _epilogue stamps its end, before finish()
+            t.epilogue = ctx.tree.open("sched.epilogue", ctx.span_id, {},
+                                       start_ns=end_ns)
 
     def _trace_retry(self, tasks: list, err: BaseException,
                      start_ns: int, end_ns: int) -> None:
@@ -1510,8 +1548,10 @@ class DeviceScheduler:
             with self._live_launch([lead], "opaque", lead.program):
                 val = lead.fn()
             self._mem_note([lead], lead.mesh)
-            self._trace_launch([lead], t_l0, time.perf_counter_ns(),
-                               "opaque", program=lead.program)
+            t_l1 = time.perf_counter_ns()
+            with self._epilogue([lead]):
+                self._trace_launch([lead], t_l0, t_l1, "opaque",
+                                   program=lead.program)
             lead.finish(val)
             self.launches += 1
             self._m_launch.inc(mode="single")
@@ -1603,11 +1643,13 @@ class DeviceScheduler:
         for t in tasks:
             t.fused, t.coalesced = fused, coalesced
         self._mem_note(tasks, tasks[0].mesh)
-        self._trace_launch(tasks, t0, time.perf_counter_ns(), mode,
-                           fused=fused, program=program.name,
-                           said=F.span_attrs(facts))
-        for name in F.counters(facts):
-            self.kernel_counts[name] += 1
+        t1 = time.perf_counter_ns()
+        with self._epilogue(tasks):
+            self._trace_launch(tasks, t0, t1, mode, fused=fused,
+                               program=program.name,
+                               said=F.span_attrs(facts))
+            for name in F.counters(facts):
+                self.kernel_counts[name] += 1
         for t, val in served:
             t.finish(val)
         self.launches += 1
@@ -1733,13 +1775,6 @@ class DeviceScheduler:
             t.device_ns = ns
         if self.calibration_enable:
             self._observe_launch(batch)
-        if self.hbm_enable:
-            try:
-                self._observe_roofline(batch)
-            except Exception:   # noqa: BLE001 - pure observability: a
-                # failed attribution (exotic backend, microbench
-                # refusal) must never kill the drain thread
-                pass
 
     def _observe_launch(self, batch: list) -> None:
         """copmeter feedback: each SERVED member's attributed wall time
@@ -1779,24 +1814,6 @@ class DeviceScheduler:
                 fed = True
         if fed:
             store.sync_manifest()
-
-    def _observe_roofline(self, batch: list) -> None:
-        """copgauge roofline feedback: each warm measured member's
-        attributed wall time + static work terms land in the per-digest
-        utilization store (obs/roofline), classifying the digest
-        memory-/compute-/launch-bound against the backend peak table."""
-        from ..obs.roofline import peaks_for_mesh, roofline_store
-        roof = roofline_store()
-        for t in batch:
-            if t.failed or t.device_ns <= 0 or t.cost_static is None \
-                    or t.compile_miss:
-                continue
-            digest = self._stable_digest(t)
-            if digest is None:
-                continue
-            roof.observe(digest, t.cost_static, t.device_ns,
-                         peaks_for_mesh(t.mesh),
-                         measured_hbm=t.hbm_measured)
 
     def _account(self, batch: list) -> None:
         """Post-launch bookkeeping.  RUs were PRICED at submit and
@@ -1990,12 +2007,6 @@ def scheduler_for(mesh) -> DeviceScheduler:
         s = _REGISTRY.get(fp)
     if s is not None:
         return s
-    # the first dispatch onto a mesh resolves its roofline peaks HERE, in
-    # the submitting thread: a TPU whose device_kind has no declared row
-    # fails the statement with that message, instead of every launch's
-    # attribution failing unseen on the drain thread
-    from ..obs.roofline import peaks_for_mesh
-    peaks_for_mesh(mesh)
     with _REG_MU:
         return _REGISTRY.setdefault(fp, DeviceScheduler())
 

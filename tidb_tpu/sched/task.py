@@ -117,7 +117,8 @@ class CopTask:
                  "rus_charged", "device_ns", "deadline_ns", "svc_ns",
                  "donate", "retries", "compile_ns", "compile_miss",
                  "hbm_predicted", "hbm_measured", "value_drift", "trace",
-                 "program")
+                 "program", "epilogue", "finish_ns",
+                 "enqueue_ns")
 
     def __init__(self, *, key=None, dag=None, mesh=None, row_capacity=0,
                  cols=None, counts=None, aux=(), input_token=None,
@@ -147,6 +148,7 @@ class CopTask:
         self.weight = float(weight or DEFAULT_WEIGHT)
         self.est_rows = est_rows
         self.submit_ns = time.perf_counter_ns()
+        self.enqueue_ns = self.submit_ns    # restamped after admission
         self.start_ns = 0
         self.wait_ns = 0
         self.coalesced = 1        # tasks served by this task's launch
@@ -184,6 +186,11 @@ class CopTask:
         # thread records queue/compile/launch/retry spans under the
         # statement's dispatch span — None = untraced, zero overhead
         self.trace = _TRACE_CTX.get()
+        # set by the drain for a traced task: its open sched.epilogue
+        # span, and the stamp it took just before finish(), from which
+        # the waiter's sched.wake runs
+        self.epilogue = None
+        self.finish_ns = 0
         self.cancelled = False
         self._done = threading.Event()
         self._value = None
@@ -265,6 +272,12 @@ class CopTask:
             except QueryInterrupted:
                 self.cancelled = True
                 raise
+        if self.finish_ns:
+            # sched.wake (tree only: a wait): the drain's finish stamp
+            # -> this thread running again
+            self.trace.add("sched.wake", self.finish_ns,
+                           time.perf_counter_ns())
+            self.finish_ns = 0      # once, whoever waits again
         if self._exc is not None:
             raise self._exc
         return self._value

@@ -25,10 +25,11 @@ import struct
 import subprocess
 import tempfile
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Optional
 
-from ..obs.trace import late_span
+from ..obs.trace import late_span, note_gc
 from ..session.session import Domain, Session
 # placeholder binding is shared with the SQL-level PREPARE/EXECUTE path
 from ..sql.bind import (bind_placeholders as _bind_placeholders,
@@ -127,6 +128,10 @@ class ClientConn:
                     return
                 if not payload:
                     continue
+                # copscope: the statement this command runs is rooted
+                # at wire.stmt, from here to the last sendall of its
+                # result
+                self.session.wire_read_ns = time.perf_counter_ns()
                 cmd, body = payload[0], payload[1:]
                 if cmd == P.COM_QUIT:
                     return
@@ -136,6 +141,8 @@ class ClientConn:
                     return
                 except Exception as e:  # statement errors -> ERR packet
                     self.io.write(P.err_packet(_errno_for(e), str(e)))
+                finally:
+                    self._end_wire_stmt()
         finally:
             try:
                 self.session.close()   # drop temp tables' KV rows
@@ -271,10 +278,25 @@ class ClientConn:
         rs = self.session.execute(sql)
         self._write_result(rs, binary=False)
 
+    def _end_wire_stmt(self):
+        """The command's answer is on the socket: end the ``wire.stmt``
+        span of the statement it ran (the last of a packet of several;
+        ``Session.execute`` ended the others')."""
+        sess = self.session
+        sess.wire_read_ns = None
+        if sess.wire_span is not None:
+            tree, root = sess.wire_span
+            sess.wire_span = None
+            root.end_ns = time.perf_counter_ns()
+            note_gc(tree)   # kept as slow: the write's runs count too
+
     def _write_result(self, rs, binary: bool):
         """Encode and send a statement's result set or OK packet: the
-        ``wire.write`` span, added to the statement's finished tree."""
-        with late_span(self.session.last_trace, "wire.write"):
+        ``wire.write`` span, added under the statement's ``wire.stmt``
+        on its finished tree."""
+        tree, root = self.session.wire_span or (None, None)
+        with late_span(tree, "wire.write",
+                       root.span_id if root is not None else None):
             if rs.names:
                 self._write_resultset(rs, binary)
             else:

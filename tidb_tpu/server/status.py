@@ -180,16 +180,13 @@ class StatusServer:
                 out["this_domain"] = coord.stats()
             return json.dumps(out), "application/json"
         if path == "/hbm":
-            # copgauge (obs/hbm + obs/roofline): the device-memory and
-            # utilization plane — live ledger balances (persistent
-            # residents + in-flight launch bytes), measured watermarks,
-            # bounded device memory_stats reconciliation, per-digest
-            # HBM prediction error (mem_factor calibration state), and
-            # the roofline attribution tables (top-N digests by
-            # residency and by gap, memory-/compute-/launch-bound)
+            # copgauge (obs/hbm): the device-memory plane — live ledger
+            # balances (persistent residents + in-flight launch bytes),
+            # measured watermarks, bounded device memory_stats
+            # reconciliation, per-digest HBM prediction error
+            # (mem_factor calibration state)
             from ..analysis.calibrate import correction_store
             from ..obs.hbm import hbm_status, profiler_gate
-            from ..obs.roofline import roofline_status
             sched = self.domain.client.sched_stats()
             ledgers = hbm_status()
             mesh = self.domain.client._mesh     # never force device init
@@ -211,7 +208,6 @@ class StatusServer:
                     "mean_mem_err_pct": cal.get("mean_mem_err_pct"),
                     "oom_events": cal.get("oom_events", 0),
                 },
-                "roofline": roofline_status(),
                 "profiler": profiler_gate().stats(),
             }), "application/json"
         if path == "/locksan":

@@ -331,9 +331,14 @@ class Session:
         self.temp_tables: dict = {}
         import threading as _th
         self._kill_event = _th.Event()   # KILL QUERY sets; stmt start clears
-        # copscope: the last statement's span tree (None = untraced); the
-        # connection adds its wire.write span to it after execute()
+        # copscope: the last statement's span tree (None = untraced)
         self.last_trace = None
+        # set by a connection when a command's payload has been read
+        # (perf_counter_ns): the next execute() roots its statements'
+        # trees at ``wire.stmt`` and leaves the last one's (tree, span)
+        # in ``wire_span`` for the connection to write under and end
+        self.wire_read_ns: Optional[int] = None
+        self.wire_span: Optional[tuple] = None
 
     def close(self) -> None:
         """Drop session state that outlives no session: temporary tables
@@ -350,19 +355,31 @@ class Session:
     def execute(self, sql: str) -> ResultSet:
         qcnt, qdur = self.domain.query_metrics()
         out = ResultSet()
-        # the statement a connection writes its result for (wire.write)
         self.last_trace = None
+        # served over the wire: the connection stamped the command's read
+        wire_t0, self.wire_read_ns = self.wire_read_ns, None
+        if wire_t0 is not None:     # (a nested execute leaves the
+            self.wire_span = None   # served statement's where it is)
         # parsing precedes every statement's root span: it is bracketed
         # here and added to each tree below as a completed span
-        parse_ann = (_obs_trace.annotation("session.parse") if _flag_on(
-            {**self.domain.sysvars, **self.vars}, "tidb_tpu_trace", True)
-            else contextlib.nullcontext())
+        tracing = _flag_on({**self.domain.sysvars, **self.vars},
+                           "tidb_tpu_trace", True)
+        parse_ann = (_obs_trace.annotation("session.parse") if tracing
+                     else contextlib.nullcontext())
         parse_t0 = time.perf_counter_ns()
         with parse_ann:
             stmts = parse_sql(sql)
         parse_t1 = time.perf_counter_ns()
-        for stmt in stmts:
+        for nstmt, stmt in enumerate(stmts):
             t0 = time.perf_counter_ns()
+            # session.begin: from here to the root span (bindings, the
+            # plugins' on_stmt_begin, the coordinator, the resource
+            # group, the statement's contextvars, the tree itself).  An
+            # exception on the way leaves the annotation to its
+            # destructor, which ends it
+            begin_ann = (_obs_trace.annotation("session.begin") if tracing
+                         else contextlib.nullcontext())
+            begin_ann.__enter__()
             span = getattr(stmt, "text_span", None)
             text = sql[span[0]:span[1]].strip() if span else sql
             self._cur_sql = text
@@ -426,19 +443,46 @@ class Session:
             # contextvar, zero recording anywhere.
             _merged_obs = {**self.domain.sysvars, **self.vars}
             trace_tree = None
-            trace_root = None
+            trace_root = None       # the session.ExecuteStmt span
+            wire_root = None        # the wire.stmt span above it
+            top = None              # wire_root's id: the tree's root
             obs_tok = None
+            fin_t0, fin_ann = 0, None
             root_ann = contextlib.nullcontext()
             if _flag_on(_merged_obs, "tidb_tpu_trace", True):
                 trace_tree = _obs_trace.SpanTree(sql=text,
                                                  conn_id=self.conn_id)
-                trace_root = trace_tree.begin("session.ExecuteStmt")
-                trace_tree.add("session.parse", parse_t0, parse_t1,
-                               parent_id=trace_root)
+                if wire_t0 is not None:
+                    # a container, tree only: from the command's read
+                    # (a packet's first statement carries it) to the
+                    # last sendall of the result; the connection ends
+                    # the last statement's, this loop the others'
+                    wire_root = trace_tree.open(
+                        "wire.stmt", None, {},
+                        start_ns=wire_t0 if nstmt == 0 else t0)
+                    top = wire_root.span_id
+                    self.wire_span = (trace_tree, wire_root)
+                begin_ann.__exit__(None, None, None)
+                trace_root = trace_tree.open("session.ExecuteStmt", top, {})
+                trace_tree.exec_root = trace_root.span_id
+                # what preceded the root: its children where no
+                # wire.stmt holds them (as parsing always was)
+                before = top or trace_root.span_id
+                trace_tree.add("session.begin", t0, trace_root.start_ns,
+                               parent_id=before)
+                if wire_root is None or nstmt == 0:
+                    trace_tree.add("session.parse", parse_t0, parse_t1,
+                                   parent_id=before)
                 obs_tok = _obs_trace.TRACE_CTX.set(
-                    _obs_trace.TraceCtx(trace_tree, trace_root))
+                    _obs_trace.TraceCtx(trace_tree, trace_root.span_id))
                 root_ann = _obs_trace.annotation(
                     "session.ExecuteStmt", trace_tree.trace_id)
+                # session.enter: the root span's first stretch, to the
+                # dispatch by statement type (the statement's
+                # contextvars, the privilege check over its tables)
+                _obs_trace.until_next("session.enter")
+            else:
+                begin_ann.__exit__(None, None, None)
             self.last_trace = trace_tree
             stok = SESSION_INFO.set({
                 "db": self.db, "user": self.user,
@@ -471,9 +515,9 @@ class Session:
                 KILL_EVENT.reset(ktok)
                 if obs_tok is not None:
                     _obs_trace.TRACE_CTX.reset(obs_tok)
-                    trace_tree.end(trace_root)
-                    trace_tree.latency_ms = \
-                        (time.perf_counter_ns() - t0) / 1e6
+                    _obs_trace.close_pending(trace_tree, force=True)
+                    fin_t0 = trace_root.end_ns = time.perf_counter_ns()
+                    trace_tree.latency_ms = (fin_t0 - t0) / 1e6
                     if handle.degraded:
                         trace_tree.flag("degraded")
                     if handle.sched_retried:
@@ -484,58 +528,86 @@ class Session:
                         # verdict below
                         trace_tree.flag("failed")
                         self.domain.flight_recorder.record(trace_tree)
+                    else:
+                        # session.finish: the root span's end -> this
+                        # statement's last line
+                        fin_ann = _obs_trace.annotation(
+                            "session.finish", trace_tree.trace_id)
+                        fin_ann.__enter__()
                 self.domain.coordinator.end(self.conn_id)
                 self._cur_sql = None
-            dt_ns = time.perf_counter_ns() - t0
-            qcnt.inc(type=type(stmt).__name__)
-            qdur.observe(dt_ns / 1e9)
-            # slow-log threshold is live sysvar state (session scope
-            # shadows global), plumbed session -> Domain on each record
             try:
-                self.domain.stmt_summary.slow_threshold_ms = float(
-                    _merged_obs.get("tidb_tpu_slow_threshold_ms", 300)
-                    or 0)
-                self.domain.flight_recorder.sample_every = max(int(
-                    _merged_obs.get("tidb_tpu_trace_sample", 16) or 16),
-                    1)
-            except (TypeError, ValueError):
-                pass
-            was_slow = self.domain.stmt_summary.record(
-                text, dt_ns, len(out.rows),
-                cpu_ns=time.thread_time_ns() - cpu0,
-                plan_text=self._last_plan_text,
-                sched_wait_ns=handle.sched_wait_ns,
-                rus=handle.sched_rus,
-                compile_ns=handle.compile_ns,
-                sched_tasks=handle.sched_tasks,
-                fused=handle.sched_fused,
-                retried=handle.sched_retried,
-                trace_id=trace_tree.trace_id
-                if trace_tree is not None else "")
-            if trace_tree is not None:
-                if was_slow:
-                    trace_tree.flag("slow")
-                self.domain.flight_recorder.record(trace_tree)
-            try:
-                # runaway KILL must fire before the success audit hook:
-                # a killed statement is an error to the client
-                self._charge_resource_group(stmt, out, dt_ns / 1e9,
-                                            handle)
-            except Exception as e:
-                _plugins.fire("on_stmt_end", self, text, str(e),
-                              dt_ns / 1e9, 0)
-                raise
-            _plugins.fire("on_stmt_end", self, text, None, dt_ns / 1e9,
-                          len(out.rows) + out.affected)
-            # ROW_COUNT()/FOUND_ROWS() state (executor/adapter.go
-            # affectedRows analogs): ROW_COUNT is -1 for result-set
-            # statements, FOUND_ROWS is the last result-set size
-            if out.names:
-                self._found_rows = len(out.rows)
-                self._row_count = -1
-            else:
-                self._row_count = out.affected
+                self._finish_stmt(stmt, text, out, t0, cpu0, handle,
+                                  _merged_obs, trace_tree)
+            finally:
+                if fin_ann is not None:
+                    fin_ann.__exit__(None, None, None)
+                    fin_t1 = time.perf_counter_ns()
+                    trace_tree.add("session.finish", fin_t0, fin_t1,
+                                   parent_id=top)
+                    if wire_root is not None and nstmt < len(stmts) - 1:
+                        wire_root.end_ns = fin_t1
         return out
+
+    def _finish_stmt(self, stmt, text: str, out: ResultSet, t0: int,
+                     cpu0: int, handle, merged: dict,
+                     trace_tree) -> None:
+        """What a statement does after its root span has ended (the
+        ``session.finish`` span): metrics, the statement summary, the
+        flight recorder's offer, the resource group's charge, the
+        plugins' ``on_stmt_end``."""
+        from ..plugin import registry as _plugins
+        qcnt, qdur = self.domain.query_metrics()
+        dt_ns = time.perf_counter_ns() - t0
+        qcnt.inc(type=type(stmt).__name__)
+        qdur.observe(dt_ns / 1e9)
+        # slow-log threshold is live sysvar state (session scope
+        # shadows global), plumbed session -> Domain on each record
+        try:
+            self.domain.stmt_summary.slow_threshold_ms = float(
+                merged.get("tidb_tpu_slow_threshold_ms", 300)
+                or 0)
+            self.domain.flight_recorder.sample_every = max(int(
+                merged.get("tidb_tpu_trace_sample", 16) or 16),
+                1)
+        except (TypeError, ValueError):
+            pass
+        seen = self.domain.stmt_summary.record(
+            text, dt_ns, len(out.rows),
+            cpu_ns=time.thread_time_ns() - cpu0,
+            plan_text=self._last_plan_text,
+            sched_wait_ns=handle.sched_wait_ns,
+            rus=handle.sched_rus,
+            compile_ns=handle.compile_ns,
+            sched_tasks=handle.sched_tasks,
+            fused=handle.sched_fused,
+            retried=handle.sched_retried,
+            trace_id=trace_tree.trace_id
+            if trace_tree is not None else "")
+        if trace_tree is not None:
+            if seen.slow:
+                trace_tree.flag("slow")
+            self.domain.flight_recorder.record(
+                trace_tree, nth=seen.nth, mean_ms=seen.mean_ms)
+        try:
+            # runaway KILL must fire before the success audit hook:
+            # a killed statement is an error to the client
+            self._charge_resource_group(stmt, out, dt_ns / 1e9,
+                                        handle)
+        except Exception as e:
+            _plugins.fire("on_stmt_end", self, text, str(e),
+                          dt_ns / 1e9, 0)
+            raise
+        _plugins.fire("on_stmt_end", self, text, None, dt_ns / 1e9,
+                      len(out.rows) + out.affected)
+        # ROW_COUNT()/FOUND_ROWS() state (executor/adapter.go
+        # affectedRows analogs): ROW_COUNT is -1 for result-set
+        # statements, FOUND_ROWS is the last result-set size
+        if out.names:
+            self._found_rows = len(out.rows)
+            self._row_count = -1
+        else:
+            self._row_count = out.affected
 
     def _exec_kill(self, stmt) -> ResultSet:
         """KILL [QUERY|CONNECTION] <id>: set the victim's kill event;
@@ -638,6 +710,7 @@ class Session:
         return self._dispatch_stmt(stmt)
 
     def _dispatch_stmt(self, stmt: A.Node) -> ResultSet:
+        _obs_trace.end_pending()        # session.enter ends here
         if isinstance(stmt, (A.CreateUser, A.AlterUser, A.DropUser,
                              A.GrantStmt, A.RevokeStmt, A.FlushStmt)):
             return self._exec_user_admin(stmt)
@@ -1277,7 +1350,7 @@ class Session:
         v14 = merged.get("tidb_tpu_cost_calibration")
         if v14 is not None and v14 != "":
             client.calibration = bool(int(v14))
-        # copgauge live HBM ledger + measured watermarks + roofline
+        # copgauge live HBM ledger + measured watermarks
         # (obs/hbm): off = the static memory model byte-identical to
         # the pre-copgauge engine
         v17 = merged.get("tidb_tpu_hbm_ledger")
@@ -1340,6 +1413,10 @@ class Session:
         if getattr(stmt, "for_update", False):
             self._lock_for_update(stmt)
         built, phys = self._plan_select(stmt, cache_sql)
+        # session.inputs: the execution context, the executor's walk
+        # down to the client, snapshot and shards, the task: from here
+        # to the statement's first cop.* span
+        _obs_trace.until_next("session.inputs")
         ctx = self._exec_ctx()
         chunk = phys.execute(ctx)
         n_out = len(built.output_names)
@@ -1625,20 +1702,27 @@ class Session:
     def _exec_trace(self, stmt: A.TraceStmt) -> ResultSet:
         """TRACE <stmt>: span tree of the statement's phases
         (executor/trace.go analog)."""
-        from ..utils.tracing import Tracer
-        tracer = Tracer()
-        with tracer.region("session.ExecuteStmt"):
-            if isinstance(stmt.stmt, (A.SelectStmt, A.SetOpStmt)):
-                with tracer.region("planner.Optimize"):
-                    built, phys = self._plan_select(stmt.stmt)
-                with tracer.region("executor.Run"):
-                    ctx = self._exec_ctx()
-                    phys.execute(ctx)
-            else:
-                with tracer.region("executor.Run"):
-                    self._exec_stmt(stmt.stmt)
+        span = _obs_trace.span
+        # a tree of its own, so that it is made whatever tidb_tpu_trace
+        # says and renders the traced statement alone
+        tree = _obs_trace.SpanTree(sql=self._cur_sql or "",
+                                   conn_id=self.conn_id)
+        tok = _obs_trace.TRACE_CTX.set(_obs_trace.TraceCtx(tree))
+        try:
+            with span("session.ExecuteStmt"):
+                if isinstance(stmt.stmt, (A.SelectStmt, A.SetOpStmt)):
+                    with span("planner.Optimize"):
+                        built, phys = self._plan_select(stmt.stmt)
+                    with span("executor.Run"):
+                        ctx = self._exec_ctx()
+                        phys.execute(ctx)
+                else:
+                    with span("executor.Run"):
+                        self._exec_stmt(stmt.stmt)
+        finally:
+            _obs_trace.TRACE_CTX.reset(tok)
         return ResultSet(["operation", "startTS_us", "duration_us"],
-                         tracer.rows())
+                         tree.rows())
 
     def _exec_txn(self, stmt: A.TxnStmt) -> ResultSet:
         """Explicit transactions over the native MVCC store.

@@ -122,14 +122,15 @@ _VARS = [
     # propagation + the flight-recorder ring.  tidb_tpu_trace off =
     # no tree is built, no span is recorded anywhere (the overhead
     # guard's baseline); tidb_tpu_trace_sample = keep 1-in-N ordinary
-    # traces (failed/degraded/quarantined/retried/slow always kept)
+    # traces (failed/degraded/quarantined/retried/slow and a digest's
+    # outliers always kept; the rest one in tidb_tpu_trace_sample of
+    # each digest)
     _v("tidb_tpu_trace", 1, kind="bool"),
     _v("tidb_tpu_trace_sample", 16, kind="int", min=1, max=65536,
        scope=SCOPE_GLOBAL),
-    # copgauge (obs/hbm + obs/roofline): the live HBM ledger, measured
-    # launch watermarks feeding continuous mem_factor calibration, and
-    # per-digest roofline attribution.  Off = no ledger accounting, no
-    # measured watermarks, no roofline feed — the static cost model
+    # copgauge (obs/hbm): the live HBM ledger and measured launch
+    # watermarks feeding continuous mem_factor calibration.  Off = no
+    # ledger accounting, no measured watermarks — the static cost model
     # behaves byte-identically to the pre-copgauge engine (mem_factor
     # moves only on OOM).
     _v("tidb_tpu_hbm_ledger", 1, kind="bool", scope=SCOPE_GLOBAL),

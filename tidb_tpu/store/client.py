@@ -15,6 +15,7 @@ copIterator worker pool → per-region RPCs, with backoff/paging/retry
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass
 from typing import Optional
 
@@ -29,7 +30,9 @@ from ..faults import plan as _faults
 from ..faults.breaker import LaunchQuarantinedError
 from ..obs.trace import annotate as _obs_annotate
 from ..obs.trace import flag as _obs_flag
+from ..obs.trace import leaf as _obs_leaf
 from ..obs.trace import span as _obs_span
+from ..obs.trace import until_next as _obs_until_next
 from .columnar import ColumnarSnapshot, _pow2_at_least
 
 # initial fraction of table rows assumed to survive a row-returning plan
@@ -149,7 +152,7 @@ class CopClient:
         # copmeter closed-loop cost calibration
         # (tidb_tpu_cost_calibration): None = keep scheduler state
         self.calibration = None
-        # copgauge live HBM ledger + measured watermarks + roofline
+        # copgauge live HBM ledger + measured watermarks
         # (tidb_tpu_hbm_ledger): None = keep scheduler state
         self.hbm_ledger = None
         # coplace coordination plane (tidb_tpu_pd): None = keep
@@ -306,7 +309,8 @@ class CopClient:
         always had (blocked until the values are on the host); its
         children split it into the wait for the program to finish
         (``cop.device_wait``) and what is left of the copy after that
-        (``cop.d2h``).  The copies are requested first, as
+        (``cop.d2h``), after the requests for the copies
+        (``cop.d2h_issue``).  The copies are requested first, as
         ``jax.device_get`` alone would, so they still follow the program
         on the device without a round trip through the host.
 
@@ -314,10 +318,15 @@ class CopClient:
         they take), fetched with the outputs, in the same round trip; -> (outputs, the largest capacity a device needs),
         and ``probe_live`` (the live rows of all devices) on the span.
         The states of a host-merged aggregation put ``ngroups`` there."""
-        with _obs_span("cop.transfer", **attrs):
+        with _obs_span("cop.transfer", **attrs) as xfer:
+            t_issue = time.perf_counter_ns()
             for leaf in jax.tree_util.tree_leaves((out, probe)):
                 if isinstance(leaf, jax.Array):
                     leaf.copy_to_host_async()
+            if xfer is not None:
+                # tree only: under cop.transfer's annotation the
+                # stretch stays the profile's ``transfer`` phase
+                xfer.add("cop.d2h_issue", t_issue, time.perf_counter_ns())
             with _obs_span("cop.device_wait"):
                 jax.block_until_ready((out, probe))
             with _obs_span("cop.d2h"):
@@ -338,11 +347,16 @@ class CopClient:
         `_compact_probe`) comes in the same fetch, and rows that did not
         fit mean a rerun (`_uncompacted`)."""
         if "join_need" not in extras:
-            return self._fetch(out, **self._transfer_attrs()), None
-        states, need = self._fetch(
-            out, (extras["join_live"], extras["join_need"]),
-            **self._transfer_attrs())
-        return states, self._uncompacted(dag, need)
+            states, need = self._fetch(out, **self._transfer_attrs()), None
+        else:
+            states, need = self._fetch(
+                out, (extras["join_live"], extras["join_need"]),
+                **self._transfer_attrs())
+        # session.settle: from the fetch to the merge (or the rerun):
+        # did the compaction fit, the fault seam keyed by the DAG's
+        # digest.  Not a cop.* name: host_plan_ms subtracts those
+        _obs_until_next("session.settle", root_only=True)
+        return states, None if need is None else self._uncompacted(dag, need)
 
     def _note_sched(self, task) -> None:
         if task.cost is not None:
@@ -380,9 +394,13 @@ class CopClient:
         # thread span (queue/compile/launch/retry) stitches under — the
         # CopTask captures the child TraceCtx at construction
         with _obs_span("cop.dispatch"):
-            t = self._scheduler().submit(CopTask.structured(
-                dag, self.mesh, row_capacity, cols, counts, tuple(aux),
-                est_rows=est, donate=donate))
+            # sched.task: the task's key (the DAG's digest, the inputs'
+            # shape signature, the fusion signature)
+            with _obs_leaf("sched.task"):
+                task = CopTask.structured(
+                    dag, self.mesh, row_capacity, cols, counts, tuple(aux),
+                    est_rows=est, donate=donate)
+            t = self._scheduler().submit(task)
             try:
                 return t.wait()
             finally:

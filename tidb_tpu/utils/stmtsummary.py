@@ -12,6 +12,7 @@ import re
 import threading
 import time
 from dataclasses import dataclass, field
+from typing import NamedTuple
 
 _NUM = re.compile(r"\b\d+(?:\.\d+)?\b")
 _WS = re.compile(r"\s+")
@@ -103,6 +104,15 @@ class StmtStats:
     # columns so EXPLAIN ANALYZE and statements_summary tell one story
     sum_sched_tasks: int = 0
     sum_fused: int = 0
+    # the digest's running mean, for the flight recorder's outliers: the
+    # mean of its last COMPLETE block of MEAN_BLOCK executions (0 until
+    # there is one).  Not sum_latency_ns / exec_count: that mean holds
+    # every compile the digest ever paid (55-85 s a statement text,
+    # four texts a class in the benchmark's cells) and would hide a
+    # +100 ms statement for hours
+    block_n: int = 0
+    block_sum_ns: int = 0
+    block_mean_ns: float = 0.0
 
     @property
     def avg_latency_ms(self) -> float:
@@ -138,6 +148,22 @@ class SlowQuery:
     trace_id: str = ""
 
 
+MEAN_BLOCK = 32
+
+
+class Recorded(NamedTuple):
+    """What ``StmtSummary.record`` found of one statement, under the
+    lock it counts the digest under: whether it crossed the slow
+    threshold, its place in its digest's count (from 1), and the
+    digest's running mean before it (ms; the last complete block of
+    ``MEAN_BLOCK`` executions, 0 while there is none) — the flight
+    recorder samples and finds outliers by the last two."""
+
+    slow: bool
+    nth: int
+    mean_ms: float
+
+
 class StmtSummary:
     """Per-Domain workload summary + slow log ring.
 
@@ -160,9 +186,10 @@ class StmtSummary:
                sched_wait_ns: int = 0, rus: float = 0.0,
                compile_ns: int = 0, sched_tasks: int = 0,
                fused: int = 0, retried: int = 0,
-               trace_id: str = "") -> bool:
-        """Returns True when the statement crossed the slow threshold
-        (the caller flags its trace ``slow`` for the flight recorder)."""
+               trace_id: str = "") -> Recorded:
+        """Returns ``Recorded``: ``slow`` when the statement crossed
+        the slow threshold (the caller flags its trace ``slow`` for
+        the flight recorder), and the digest's place and mean."""
         digest = normalize_sql(sql)
         now = time.time()
         with self._lock:
@@ -170,6 +197,12 @@ class StmtSummary:
             if st is None:
                 st = StmtStats(digest, sql, first_seen=now)
                 self._stats[digest] = st
+            mean_ms = st.block_mean_ns / 1e6
+            st.block_n += 1
+            st.block_sum_ns += latency_ns
+            if st.block_n == MEAN_BLOCK:
+                st.block_mean_ns = st.block_sum_ns / MEAN_BLOCK
+                st.block_n = st.block_sum_ns = 0
             st.exec_count += 1
             st.sum_latency_ns += latency_ns
             st.max_latency_ns = max(st.max_latency_ns, latency_ns)
@@ -195,7 +228,7 @@ class StmtSummary:
                     retried=int(retried), trace_id=trace_id))
                 if len(self._slow) > self.max_slow:
                     self._slow.pop(0)
-            return slow
+            return Recorded(slow, st.exec_count, mean_ms)
 
     def summary_rows(self) -> list[tuple]:
         with self._lock:
