@@ -241,7 +241,7 @@ def test_columns_nothing_reads_ride_in_no_word():
     probe = jnp.asarray(np.array([1, 2, 9, 33, 500], np.int32))
     for side in (full, lean):
         grp = [(v, True if m is None else m) for v, m in side.aux]
-        matched, out = jax.jit(
+        matched, out, _miss = jax.jit(
             lambda kv, grp, p=side.packing: direct_lookup(kv, grp, p))(
                 probe, grp)
         assert np.asarray(matched).tolist() == [True, True, False, True,

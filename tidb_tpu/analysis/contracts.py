@@ -372,6 +372,8 @@ def _verify_dag(node: D.CopNode, path) -> None:
             _verify_probe_capacity(node, p)
         if node.match_capacity:
             _verify_match_capacity(node, p)
+        if node.probe_window:
+            _verify_probe_window(node, p)
         if node.kind in ("inner", "left"):
             for t in node.build_dtypes:
                 if t.is_host_object:
@@ -428,6 +430,23 @@ def _verify_match_capacity(node: D.LookupJoin, p) -> None:
         _fail("capacity-shape", p,
               "multiple compactions in one program: one join compacts, "
               "before its lookup or after it")
+
+
+def _verify_probe_window(node: D.LookupJoin, p) -> None:
+    """Contract of the window form (executor/physical `_windowed` sets
+    it, copr/join `_window_reader` reads it): whole lanes, none above
+    the cap; and only a lookup that `dag.window_ok` lets read by
+    windows: unique, direct-addressed, probed with a column of the scan
+    whose rows nothing has compacted out of their order."""
+    if node.probe_window < 0 or node.probe_window % D.COMPACT_COLUMNS \
+            or node.probe_window > D.PROBE_WINDOW_MAX:
+        _fail("capacity-shape", p,
+              f"probe_window {node.probe_window} is not a multiple of "
+              f"{D.COMPACT_COLUMNS} up to {D.PROBE_WINDOW_MAX}")
+    if not D.window_ok(node):
+        _fail("capacity-shape", p,
+              "a window-form lookup that is not a unique direct-addressed "
+              "inner/left join probed, in scan order, with a scan column")
 
 
 def _verify_packing(node: D.LookupJoin, p) -> None:

@@ -491,6 +491,13 @@ def _walk(node: D.CopNode, path: tuple, rows: int, layout: Layout,
             acc.flops += (_expr_flops(node.probe_key) + 3 + n_words
                           + 3 * len(layout)) * rows_in
             acc.buf("/".join(p) + ":gather", rows_in * 4 * max(n_words, 1))
+            if node.probe_window:
+                # join._window_reader: a compare, a select and an add a
+                # slot of the window a row a word, and the fetched rows
+                held = node.probe_window + D.COMPACT_COLUMNS
+                acc.flops += 3 * held * max(n_words, 1) * rows_in
+                acc.buf("/".join(p) + ":windows",
+                        rows_in // D.COMPACT_COLUMNS * held * 4)
             if 0 < node.match_capacity < rows_in:
                 # the same compaction after the lookup, of the joined row
                 acc.flops += rows_in * _log2(rows_in)

@@ -142,6 +142,10 @@ def scan_lifetime(dag: D.CopNode) -> Tuple[BufferClass, str]:
         return (BufferClass.LOOP_CARRIED,
                 "a probe compaction that overflows re-feeds the inputs "
                 "to the exact program (store/client._uncompacted)")
+    if D.windowed_join(dag) is not None:
+        return (BufferClass.LOOP_CARRIED,
+                "a window-form lookup that misses a row re-feeds the "
+                "inputs to the gather form (store/client._unwindowed)")
     if not isinstance(dag, D.Aggregation):
         return (BufferClass.LOOP_CARRIED,
                 "rows paging loop re-feeds the inputs on overflow "

@@ -369,6 +369,69 @@ def probe_capacity_for(est_rows: float, rows: int) -> int:
     return cap if cap * 8 <= rows else 0
 
 
+# a lookup read by windows fetches no more than this many table slots a
+# block of COMPACT_COLUMNS probe rows (`probe_window_for`)
+PROBE_WINDOW_MAX = 512
+
+
+def probe_window_for(rows: int, span: int, ordered: bool) -> int:
+    """The table slots a block of COMPACT_COLUMNS probe rows is sure to
+    find its matches within (LookupJoin.probe_window), for a probe key
+    column of `rows` rows over a value range of `span` that ANALYZE
+    found `ordered` (its values never decrease in storage order); 0
+    where the lookup should gather an index a slot.  Pure, like
+    `probe_capacity_for`.
+
+    Rows in key order read the table front to back, so a block's
+    offsets lie within about COMPACT_COLUMNS * span / rows slots of its
+    least: twice that mean, in whole lanes, is the window (TPC-H's
+    `l_orderkey`, 1..7 rows for 8 keys of every 32: 128.0 slots a block
+    on average, 191 at most, a window of 256).  Rounded up from a
+    sixty-fourth less: a key with as many rows as its range, give or
+    take the data, must not flip between two windows, two programs,
+    from one ANALYZE to the next.  The select-reduce that reads a
+    window costs its length a row (2.3 ms a 128 slots at 2^23 rows on a
+    v5e, the gather 60): none above PROBE_WINDOW_MAX."""
+    if not ordered or rows <= 0 or span <= 0:
+        return 0
+    twice = 2 * COMPACT_COLUMNS * span / rows * (1 - 1 / 64)
+    window = max(-(-int(twice) // COMPACT_COLUMNS), 1) * COMPACT_COLUMNS
+    return window if window <= PROBE_WINDOW_MAX else 0
+
+
+def probe_scan_column(join: "LookupJoin") -> Optional[int]:
+    """Which column of its `TableScan` the probe key of `join` IS (the
+    index into `col_offsets`), through the Selections, ColumnRef
+    Projections and joins beneath it; None where it is computed or a
+    build side's column.  Rows of such a key lie in the order the scan
+    stores them: what `probe_window_for` asks of."""
+    from ..expr.ir import ColumnRef
+    e, cur = join.probe_key, join.child
+    while isinstance(e, ColumnRef):
+        if isinstance(cur, TableScan):
+            return e.index if e.index < len(cur.col_offsets) else None
+        if isinstance(cur, Projection):
+            e = cur.exprs[e.index] if e.index < len(cur.exprs) else None
+        elif isinstance(cur, LookupJoin):
+            if e.index >= len(output_dtypes(cur.child)):
+                return None             # a column the join brought
+        elif not isinstance(cur, Selection):
+            return None
+        cur = cur.child
+    return None
+
+
+def window_ok(join: "LookupJoin") -> bool:
+    """May `join` read its table by windows: a unique direct-addressed
+    inner/left lookup whose probe key is a column of the scan and whose
+    rows reach it in the scan's order (nothing beneath has compacted
+    them: `live_rows` leaves its rows in no order)."""
+    return join.unique and join.dense and join.kind in ("inner", "left") \
+        and not join.probe_capacity \
+        and compacting_join(join.child) is None \
+        and probe_scan_column(join) is not None
+
+
 @dataclass(frozen=True)
 class Limit(CopNode):
     child: CopNode = None  # type: ignore[assignment]
@@ -454,6 +517,16 @@ class LookupJoin(CopNode):
     # The same compaction, reports and rerun as `probe_capacity`; a
     # program has one or the other.
     match_capacity: int = field(default=0, metadata=DIGEST_IF_SET)
+    # unique direct-addressed inner/left only, set by the executor where
+    # ANALYZE found the probe key's column in key order and nothing has
+    # compacted the probe rows (`probe_window_for`): the table is read
+    # by windows of this many slots (and a lane), one a block of
+    # COMPACT_COLUMNS probe rows, not by an index a row
+    # (copr/join._window_reader).  0 = the gather.  The order is a hint:
+    # the program counts the rows whose offset fell outside their window
+    # (extras `join_window_miss`) and the dispatcher reruns the
+    # statement at 0 where there is one.
+    probe_window: int = field(default=0, metadata=DIGEST_IF_SET)
 
     def children(self):
         return (self.child,)
@@ -745,14 +818,33 @@ def uncompacted(node: CopNode) -> CopNode:
                           probe_capacity=0, match_capacity=0)
 
 
+def windowed_join(node: CopNode):
+    """A LookupJoin of a pushed DAG that reads its table by windows
+    (`probe_window` > 0), or None."""
+    return next((n for n in iter_nodes(node) if isinstance(n, LookupJoin)
+                 and n.probe_window), None)
+
+
+def unwindowed(node: CopNode) -> CopNode:
+    """The DAG with every lookup a gather: the exact program whatever
+    order the probe rows lie in."""
+    while windowed_join(node) is not None:      # one join a rewrite
+        node = rewrite_lookup(node, pred=lambda j: j.probe_window > 0,
+                              probe_window=0)
+    return node
+
+
 def has_extras(node: CopNode) -> bool:
     """Does a program of this DAG return an extras dict after its result
     (DeviceBatch.extras): the true size of an expanding join's output
     (`join_total`), the live rows a compacting join found and the
-    capacity they take (`join_live`, `join_need`)?  The dispatcher
-    reruns the statement where a size exceeds its capacity."""
+    capacity they take (`join_live`, `join_need`), the rows a lookup
+    read by windows found outside theirs (`join_window_miss`)?  The
+    dispatcher reruns the statement where a size exceeds its capacity
+    or a row was missed."""
     return find_expand_join(node) is not None \
-        or compacting_join(node) is not None
+        or compacting_join(node) is not None \
+        or windowed_join(node) is not None
 
 
 def to_multimatch(node: CopNode, out_capacity: int) -> CopNode:
@@ -764,7 +856,8 @@ def to_multimatch(node: CopNode, out_capacity: int) -> CopNode:
     if isinstance(node, LookupJoin):
         return dataclasses.replace(node, unique=False,
                                    out_capacity=out_capacity,
-                                   probe_capacity=0, match_capacity=0)
+                                   probe_capacity=0, match_capacity=0,
+                                   probe_window=0)
     if not node.children():
         return node
     kids = tuple(to_multimatch(c, out_capacity) for c in node.children())
@@ -847,6 +940,8 @@ __all__ = [
     "radix_passes", "radix_key_bits", "Aggregation",
     "TopN", "TOPN_MIN_BLOCK", "topn_block_len",
     "COMPACT_COLUMNS", "probe_capacity_for",
+    "PROBE_WINDOW_MAX", "probe_window_for", "probe_scan_column",
+    "window_ok", "windowed_join", "unwindowed",
     "Limit", "LookupJoin",
     "FusedDag", "ShuffleJoinSpec", "output_dtypes", "dag_digest",
     "iter_nodes", "lookup_joins", "find_expand_join", "compacting_join",

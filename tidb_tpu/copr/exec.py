@@ -802,8 +802,20 @@ def _exec_lookup_join(node: D.LookupJoin, batch: DeviceBatch, ev: Evaluator,
     if node.unique and node.kind in ("inner", "left"):
         from .join import direct_lookup, sorted_lookup
         with jax.named_scope("join_probe"):
-            matched, build = direct_lookup(kv, grp, node.packing) \
-                if node.dense else sorted_lookup(kv, grp)
+            if node.dense:
+                # the rows whose result something reads: the window form
+                # places its windows by them and counts its misses there
+                live = batch.sel if km is True else km if batch.sel is True \
+                    else batch.sel & km
+                matched, build, miss = direct_lookup(
+                    kv, grp, node.packing, node.probe_window, live,
+                    batch.stacked)
+                if node.probe_window:
+                    batch = replace(batch, extras={
+                        **batch.extras, "join_window_miss":
+                        batch.extras.get("join_window_miss", 0) + miss})
+            else:
+                matched, build = sorted_lookup(kv, grp)
         if km is not True:
             matched = matched & km
         out_cols = list(batch.cols)

@@ -93,6 +93,10 @@ FACTS = {
     # the lookup (0: what is above the join runs on every slot)
     "match_capacity": Fact(
         counters=(("join_match_compact_launches", lambda c: c > 0),)),
+    # of the same: the widest window a lookup of it reads its table by
+    # (`dag.LookupJoin.probe_window`; 0: every lookup is a gather)
+    "probe_window": Fact(
+        counters=(("join_window_launches", lambda w: w > 0),)),
     # `exec.compact_root`, the root of a rows-returning program: the
     # slots a device hands its live rows to the host in, and whether
     # they got there by the column sort (1) or by the scatter (0)
@@ -129,11 +133,14 @@ FACTS = {
 # no launch carries these
 # (`join_compact_overflows`: a compacting join found more live rows than
 # its capacity and the statement was rerun uncompacted;
+# `join_window_overflows`: a lookup read by windows found a live row
+# outside its window and the statement was rerun with the gather;
 # `hndv_agg_regrows`: a host-merged aggregation was rerun with a larger
 # table or a wider record; `rows_regrows`: a rows-returning program's
 # live rows did not fit its capacity and it was rerun with more)
 EVENTS = ("join_shuffle_launches", "join_host_fallbacks", "join_regrows",
-          "join_compact_overflows", "hndv_agg_regrows", "rows_regrows")
+          "join_compact_overflows", "join_window_overflows",
+          "hndv_agg_regrows", "rows_regrows")
 
 
 def counter_names() -> tuple:

@@ -32,16 +32,24 @@ def _compare_narrow(kv, ref) -> bool:
         jnp.int8, jnp.int16, jnp.int32)
 
 
-def direct_lookup(kv, grp, packing):
+def direct_lookup(kv, grp, packing, window: int = 0, live=True,
+                  stacked: int = 1):
     """Probe a direct-addressed build side (dag.LookupJoin `dense`).
     kv: probe keys; grp: the aux group [(array, mask | True)].  Returns
-    (matched, [(value, valid | True) per build column]); a column's
-    value is meaningless where `matched` is False.
+    (matched, [(value, valid | True) per build column], miss); a
+    column's value is meaningless where `matched` is False.
 
     `key - base` wraps for a key far outside the range; read unsigned it
     is then never below `span` (base + span fits the signed width both
     operands share, so a wrapped difference is at least span), so one
-    unsigned compare is the whole bounds check."""
+    unsigned compare is the whole bounds check.
+
+    `window` (dag.LookupJoin `probe_window`; 0: one gather a table, an
+    index a slot): the tables are read by windows (`_window_reader`),
+    `live` the rows whose result is read, `stacked` the runs the slots
+    consist of; `miss` then counts the live rows whose offset fell
+    outside their block's window and whose result is therefore wrong
+    (the dispatcher reruns the statement at 0); else it is 0."""
     n_words, pbit, layout = packing
     meta = grp[0][0]
     kt, ut = (jnp.int32, jnp.uint32) if _compare_narrow(kv, meta) \
@@ -49,8 +57,16 @@ def direct_lookup(kv, grp, packing):
     d = kv.astype(kt) - meta[0].astype(kt)
     matched = lax.bitcast_convert_type(d, ut) < meta[1].astype(ut)
     idx = jnp.where(matched, d, 0).astype(jnp.int32)  # valueflow: ok - a matched row's offset is below span < 2^31
-    words = [grp[2 + w][0].at[idx].get(mode="promise_in_bounds")
-             for w in range(n_words)]
+    miss = jnp.zeros((), jnp.int32)
+    slots = grp[2][0].shape[0] if len(grp) > 2 else 0
+    if window and window + COMPACT_COLUMNS <= slots \
+            and not idx.shape[0] % COMPACT_COLUMNS:
+        read, miss = _window_reader(idx, matched & live, slots, window,
+                                    stacked)
+    else:
+        def read(table):
+            return table.at[idx].get(mode="promise_in_bounds")
+    words = [read(grp[2 + w][0]) for w in range(n_words)]
     if pbit >= 0:
         matched = matched & ((words[0] >> pbit) & 1).astype(bool)
     mins = grp[1][0]
@@ -65,16 +81,78 @@ def direct_lookup(kv, grp, packing):
             continue
         if w == APART:
             tv, tm = next(apart)
-            out.append((tv.at[idx].get(mode="promise_in_bounds"),
-                        True if tm is True
-                        else tm.at[idx].get(mode="promise_in_bounds")))
+            out.append((read(tv), True if tm is True else read(tm)))
             continue
         vt = jnp.int64 if wide else jnp.int32
         v = ((words[w] >> shift) & ((1 << bits) - 1)).astype(vt) \
             + mins[j].astype(vt)
         out.append((v, True if vbit < 0
                     else ((words[w] >> vbit) & 1).astype(bool)))
-    return matched, out
+    return matched, out, miss
+
+
+def _window_reader(idx, ok, slots: int, window: int, stacked: int):
+    """(read, miss) for a probe whose offsets `idx` never decrease along
+    a run of slots (the probe key is stored in key order: ANALYZE's
+    `ColumnStats.ordered`, a hint): `read(table)` is `table[idx]` at
+    every row of `ok` whose offset lies inside its block's window, and
+    `miss` counts the rows of `ok` where it does not (their values are
+    wrong: zero).
+
+    The slots are viewed as blocks of COMPACT_COLUMNS in the order they
+    lie in memory (`_tile_order`: a block never straddles two stacked
+    runs), and a block of consecutive rows in key order reads a
+    contiguous stretch of the table.  The table is viewed as rows of
+    COMPACT_COLUMNS words; the row that holds the block's least offset
+    over its `ok` rows and the `window / COMPACT_COLUMNS` rows after it
+    (clamped to the table) are the block's window, which holds any
+    stretch of `window` + 1 slots however it is aligned; ONE gather of
+    whole rows fetches the windows, and which word of its window a slot
+    takes is arithmetic: `sum_w select(idx - start == w, window[w], 0)`,
+    one reduce whose terms are all zero but one, exact at any width.
+    (On a v5e a gather of single words costs 7.1 ns an index: 2^23 of
+    them 59.8 ms; 196,608 whole rows 0.36 and 384 selects a slot 6.8;
+    windows fetched as slices of any alignment become a `while` of
+    65,536 steps, 119 ms: PERF.md sections 5 and 6, PR 34.)"""
+    cols = COMPACT_COLUMNS
+    n, k = idx.shape[0], window // cols + 1
+    at = _tile_order(idx, stacked).reshape(n // cols, cols)
+    okb = _tile_order(ok, stacked).reshape(n // cols, cols)
+    rows = -(-slots // cols)
+    row0 = jnp.clip(jnp.min(jnp.where(okb, at, slots), axis=1) // cols,
+                    0, rows - k)
+    local = at - (row0 * cols)[:, None]
+    miss = jnp.sum(okb & ((local < 0) | (local >= k * cols)),
+                   dtype=jnp.int32)
+    fetch = row0[None, :] + jnp.arange(k, dtype=jnp.int32)[:, None]
+    # (k, 1, w, 1) against (1, blocks, 1, slot): a slot's word is the one
+    # whose place in the window is the slot's offset from its start
+    place = lax.broadcasted_iota(jnp.int32, (k, 1, cols, 1), 0) * cols \
+        + lax.broadcasted_iota(jnp.int32, (k, 1, cols, 1), 2)
+    pick = local[None, :, None, :] == place
+
+    def read(table):
+        bits = _as_bits(table)
+        if slots % cols:
+            bits = jnp.pad(bits, (0, rows * cols - slots))
+        fetched = bits.reshape(rows, cols).at[fetch].get(
+            mode="promise_in_bounds")
+        got = jnp.sum(jnp.where(pick, fetched[:, :, :, None],
+                                jnp.zeros((), bits.dtype)),
+                      axis=(0, 2), dtype=bits.dtype)
+        got = _run_order(got.reshape(n), stacked)
+        return got.astype(bool) if table.dtype == bool \
+            else got.view(table.dtype)
+    return read, miss
+
+
+def _as_bits(x):
+    """`x` as unsigned integers of its own width (a bool as 0 / 1): the
+    form in which a sum of zeros and one value is that value, bit for
+    bit (a float's -0.0 and NaNs too)."""
+    if x.dtype == bool:
+        return x.astype(jnp.uint8)  # valueflow: ok - bool lane, [0, 1]
+    return x.view(jnp.dtype(f"uint{8 * x.dtype.itemsize}"))
 
 
 def _tile_order(x, stacked: int):
@@ -89,6 +167,15 @@ def _tile_order(x, stacked: int):
     if stacked == 1 or n % (stacked * COMPACT_COLUMNS):
         return x
     return x.reshape(stacked, n // stacked // COMPACT_COLUMNS,
+                     COMPACT_COLUMNS).transpose(1, 0, 2).reshape(n)
+
+
+def _run_order(x, stacked: int):
+    """`_tile_order`'s inverse: slots in memory order back run by run."""
+    n = x.shape[0]
+    if stacked == 1 or n % (stacked * COMPACT_COLUMNS):
+        return x
+    return x.reshape(n // stacked // COMPACT_COLUMNS, stacked,
                      COMPACT_COLUMNS).transpose(1, 0, 2).reshape(n)
 
 

@@ -512,6 +512,12 @@ class CopJoinTaskExec(PhysOp):
     # `dag.Aggregation.pack_words` without them, from the probe table's
     # statistics; 0 = the wide form, or no such root (`_grouped`)
     record_words: int = 0
+    # (aux slot, window, "table.column") of each join whose probe key is
+    # a column of the probe table that ANALYZE found in key order: the
+    # slots a block of probe rows finds its matches within
+    # (`dag.probe_window_for`, from the planner: plan._probe_windows);
+    # () = no such join, or no statistics (`_windowed`)
+    probe_windows: tuple = ()
 
     def __post_init__(self):
         self.children = ([b["exec"] for b in self.builds] if self.builds
@@ -572,8 +578,8 @@ class CopJoinTaskExec(PhysOp):
         if bound is None:
             return self._host_fallback(ctx)
         dag, groups = bound
-        return self._run(ctx, self._compacted(
-            ctx, self._grouped(ctx, dag)), groups)
+        return self._run(ctx, self._windowed(ctx, self._compacted(
+            ctx, self._grouped(ctx, dag))), groups)
 
     def _empty_build_result(self, ctx, bchunk) -> ResultChunk:
         # empty build side: inner join produces nothing; left join keeps all
@@ -625,8 +631,8 @@ class CopJoinTaskExec(PhysOp):
                 dag = D.rewrite_lookup(dag, dense=True,
                                        packing=side.packing)
             if not semi:
-                dag = self._compacted(ctx, self._grouped(ctx, dag),
-                                      side.rows)
+                dag = self._windowed(ctx, self._compacted(
+                    ctx, self._grouped(ctx, dag), side.rows))
         chunk = self._run(ctx, dag, (side.aux,))   # one aux group
         # build-side output columns keep their own dictionaries
         if not isinstance(self.dag, D.Aggregation):
@@ -690,6 +696,32 @@ class CopJoinTaskExec(PhysOp):
             * min(build_rows / self.probe_key_ndv, 1.0)
         cap = D.probe_capacity_for(matched / n_dev, per_dev)
         return D.rewrite_lookup(dag, match_capacity=cap) if cap else dag
+
+    def _windowed(self, ctx, dag):
+        """`dag` with each join the planner found probed in key order
+        (`probe_windows`) told to read its table by windows
+        (dag.LookupJoin `probe_window`), where the run's build sides
+        and compactions allow it (`dag.window_ok`: unique, direct-
+        addressed, its probe rows still in the scan's order) and the
+        program is lowered for a platform whose gather costs its indices
+        (not the CPU mesh).  Otherwise `dag` as it is: today's program,
+        digest and all.  The order is ANALYZE's hint: a row outside its
+        window costs one rerun with the gather (store/client
+        `_unwindowed`), never an answer."""
+        from ..parallel import spmd
+        if not self.probe_windows \
+                or spmd.mesh_platform(ctx.client.mesh) == "cpu":
+            return dag
+        for slot, window, _name in self.probe_windows:
+            dag = D.rewrite_lookup(
+                dag, pred=lambda j, s=slot: j.aux_slot == s
+                and D.window_ok(j), probe_window=window)
+        return dag
+
+    def probe_orders(self) -> list:
+        """(probe table.column, window) a join of `probe_windows`, for
+        EXPLAIN: the lookups ANALYZE found probed in key order."""
+        return [(name, window) for _slot, window, name in self.probe_windows]
 
     def _run(self, ctx, dag, aux) -> ResultChunk:
         """Dispatch the fused program and decode with output dicts."""

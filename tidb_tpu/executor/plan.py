@@ -811,6 +811,7 @@ def _try_cop_join(p: LogicalPlan, top, mids, join: LogicalJoin) -> Optional[Phys
     if join.kind == "inner" and not builds and probe_est:
         from ..planner.join_reorder import _col_ndv
         key_ndv = _col_ndv(join.left, li, STATS_HANDLE.get(), 0.0)
+    windows = () if semi else _probe_windows(nodew, ds.table)
     if builds:
         # fragment chain: nested builds + this join's own build, in aux
         # slot order; runtime anomalies fall back to the host plan whole
@@ -822,7 +823,8 @@ def _try_cop_join(p: LogicalPlan, top, mids, join: LogicalJoin) -> Optional[Phys
             nodew, ds.table, join_kind=join.kind, n_probe=n_probe,
             out_names=out_names, out_dtypes=out_dtypes, key_meta=key_meta,
             out_dicts=out_dicts, fallback=fallback, builds=builds,
-            probe_est_rows=probe_est, record_words=record_words)
+            probe_est_rows=probe_est, record_words=record_words,
+            probe_windows=windows)
     else:
         exec_ = CopJoinTaskExec(
             nodew, ds.table, build_exec=build_exec, build_key_index=ri,
@@ -831,13 +833,43 @@ def _try_cop_join(p: LogicalPlan, top, mids, join: LogicalJoin) -> Optional[Phys
             join_kind=join.kind, null_aware=join.null_aware, n_probe=n_probe,
             out_names=out_names, out_dtypes=out_dtypes, key_meta=key_meta,
             out_dicts=out_dicts, fallback=fallback, probe_est_rows=probe_est,
-            probe_key_ndv=key_ndv, record_words=record_words)
+            probe_key_ndv=key_ndv, record_words=record_words,
+            probe_windows=windows)
     if host_top is not None and host_top[0] == "topn":
         return HostTopN(exec_, list(host_top[1].keys), host_top[1].limit,
                         host_top[1].offset)
     if host_top is not None:
         return HostLimit(exec_, host_top[1].limit, host_top[1].offset)
     return exec_
+
+
+def _probe_windows(dag, table) -> tuple:
+    """((aux slot, window, "table.column"), ...): the lookup joins of
+    `dag` whose probe key is a column of the scanned `table` that
+    ANALYZE found in key order (`ColumnStats.ordered`), each with the
+    table slots a block of its probe rows finds its matches within
+    (`dag.probe_window_for`, from the rows and the column's range at
+    ANALYZE) and the column's name for EXPLAIN; () without statistics.
+    The executor applies it once the builds are in hand
+    (CopJoinTaskExec._windowed)."""
+    handle = STATS_HANDLE.get()
+    stats = handle.get(table) if handle is not None else None
+    if stats is None:
+        return ()
+    scan = next(n for n in D.iter_nodes(dag) if isinstance(n, D.TableScan))
+    names = table.col_names
+    out = []
+    for join in D.lookup_joins(dag):
+        col = D.probe_scan_column(join)
+        if col is None or join.kind not in ("inner", "left"):
+            continue
+        name = names[scan.col_offsets[col]]
+        cs = stats.col(name)
+        window = cs is not None and D.probe_window_for(
+            stats.count, cs.span, cs.ordered)
+        if window:
+            out.append((join.aux_slot, window, f"{table.name}.{name}"))
+    return tuple(out)
 
 
 def _probe_rows_estimate(plan: LogicalPlan) -> float:

@@ -278,6 +278,7 @@ class ShardedCopProgram:
             if out["join"] == "unique":
                 out["probe_capacity"] = max(j.probe_capacity for j in joins)
                 out["match_capacity"] = max(j.match_capacity for j in joins)
+                out["probe_window"] = max(j.probe_window for j in joins)
         return out
 
     def __call__(self, stacked_cols: Sequence, counts, aux_cols=()):

@@ -1463,6 +1463,9 @@ class Session:
             forms = self._join_forms_footer(phys)
             if forms is not None:
                 rows.append((forms,))
+            order = self._probe_order_footer(phys)
+            if order is not None:
+                rows.append((order,))
         return ResultSet(["plan"], rows)
 
     def _cost_footer(self, phys) -> Optional[str]:
@@ -1578,6 +1581,21 @@ class Session:
                     for op in _walk(phys) if isinstance(op, CopJoinTaskExec)
                     for name, form, slots in op.build_forms(memory)]
             return "join forms: " + ", ".join(said) if said else None
+        except (AttributeError, TypeError, KeyError, ValueError):
+            return None
+
+    def _probe_order_footer(self, phys) -> Optional[str]:
+        """EXPLAIN ``probe order:`` tag: the probe keys of the plan's
+        lookup joins that ANALYZE found stored in key order, each with
+        the window a direct-addressed lookup probed with it reads its
+        table by on a TPU (CopJoinTaskExec.probe_orders).  None where
+        there is none; must never break EXPLAIN."""
+        try:
+            from ..executor.physical import CopJoinTaskExec, _walk
+            said = [f"{name} in key order (windows of {window} slots)"
+                    for op in _walk(phys) if isinstance(op, CopJoinTaskExec)
+                    for name, window in op.probe_orders()]
+            return "probe order: " + ", ".join(said) if said else None
         except (AttributeError, TypeError, KeyError, ValueError):
             return None
 

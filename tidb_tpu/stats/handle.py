@@ -44,6 +44,21 @@ def encode_value(col_type: dt.DataType, v, dictionary=None) -> Optional[int]:
         return None
 
 
+def never_decreases(col: Column, head: int = 4096) -> bool:
+    """Whether the valid values of an integer column never decrease in
+    the order they are stored.  On the host, beside the device's sort
+    kernel and not inside it (that program's compile is a minute a row
+    count); the first `head` rows send an unordered column home."""
+    data = col.data
+    if data.dtype.kind not in "iu" or not len(data):
+        return False
+    if not col.validity.all():
+        data = data[col.validity]
+    first = data[:head]
+    return bool(np.all(first[1:] >= first[:-1])
+                and np.all(data[1:] >= data[:-1]))
+
+
 @dataclass
 class ColumnStats:
     name: str
@@ -54,6 +69,20 @@ class ColumnStats:
     ndv: int
     null_count: int
     count: int
+    # do the column's values never decrease in storage order (NULLs
+    # aside)?  Of an integer column, over every row whatever ANALYZE
+    # sampled (`never_decreases`): `Histogram.Correlation` in the
+    # reference, cut to the one bit that is read.  A lookup join whose
+    # probe key is such a column reads its table front to back
+    # (copr/dag.probe_window_for); a hint, checked by the program.
+    ordered: bool = False
+
+    @property
+    def span(self) -> int:
+        """The length of the range the column's values were seen in."""
+        if self.hist.min_val is None or not len(self.hist.bounds):
+            return 0
+        return int(self.hist.bounds[-1]) - int(self.hist.min_val) + 1
 
     def equal_rows(self, enc: int) -> float:
         c = self.topn.count_of(enc)
@@ -231,6 +260,7 @@ class StatsHandle:
             cs = self._analyze_column(name, c, n_buckets, n_top,
                                       scale=scale)
             if cs is not None:
+                cs.ordered = never_decreases(col)
                 ts.cols[name.lower()] = cs
         with self._lock:
             self._cache[ts.table_id] = ts

@@ -41,6 +41,10 @@ INITIAL_SELECTIVITY = 4  # capacity = max(rows/shards/4, 1024)
 # SORT-agg group-table sizing: first guess when the planner supplies no
 # NDV estimate, and the regrow ceiling
 DEFAULT_GROUP_CAPACITY = 4096
+# what a join program says of a launch beside its outputs (the extras
+# of copr/exec `_compact_probe` and `_exec_lookup_join`), fetched with
+# the outputs (`_fetch_states`)
+_JOIN_REPORTS = ("join_live", "join_need", "join_window_miss")
 
 
 @dataclass
@@ -99,11 +103,15 @@ class CopClient:
         # get/assign/move_to_end/popitem sequence (ADVICE r2: a concurrent
         # eviction between get and move_to_end raised KeyError)
         self._pf_mu = threading.Lock()
-        # digests of compacting-join programs that found more live probe
-        # rows than their capacity (`_uncompacted`): the next statement
-        # with that digest launches the exact form at once.  Guarded by
-        # _pf_mu, LRU-capped like the paging feedback.
-        self._compact_overflowed: OrderedDict[int, None] = OrderedDict()
+        # digests of join programs whose shortcut did not hold: a
+        # compacting join that found more live probe rows than its
+        # capacity (`_uncompacted`), a lookup read by windows that found
+        # a live row outside its window (`_unwindowed`) -> what makes
+        # the DAG exact again (`dag.uncompacted`, `dag.unwindowed`): the
+        # next statement with that digest launches that form at once
+        # (`_join_form`).  Guarded by _pf_mu, LRU-capped like the
+        # paging feedback.
+        self._exact_forms: OrderedDict[int, object] = OrderedDict()
         # SORT aggregations whose exact record took more words than the
         # planner guessed (`_wider_record`): digest -> the words the next
         # statement starts with.  Guarded by _pf_mu, capped alike.
@@ -314,10 +322,12 @@ class CopClient:
         ``jax.device_get`` alone would, so they still follow the program
         on the device without a round trip through the host.
 
-        ``probe``: a compacting join's per-device (live rows, capacity
-        they take), fetched with the outputs, in the same round trip; -> (outputs, the largest capacity a device needs),
-        and ``probe_live`` (the live rows of all devices) on the span.
-        The states of a host-merged aggregation put ``ngroups`` there."""
+        ``probe``: what a join program reports beside its outputs, a
+        dict of per-device arrays (copr/exec: ``join_live``,
+        ``join_need``, ``join_window_miss``), fetched with the outputs,
+        in the same round trip; -> (outputs, the dict on the host), and
+        ``probe_live`` (the live rows of all devices) on the span.  The
+        states of a host-merged aggregation put ``ngroups`` there."""
         with _obs_span("cop.transfer", **attrs) as xfer:
             t_issue = time.perf_counter_ns()
             for leaf in jax.tree_util.tree_leaves((out, probe)):
@@ -336,27 +346,27 @@ class CopClient:
                 _obs_annotate(ngroups=int(np.sum(out["__ngroups__"])))
             if probe is None:
                 return out
-            live, need = probe
-            _obs_annotate(probe_live=int(np.sum(live)))
-            return out, int(np.max(need))
+            if "join_live" in probe:
+                _obs_annotate(probe_live=int(np.sum(probe["join_live"])))
+            return out, probe
 
     def _fetch_states(self, dag, out, extras: dict):
         """(a launch's aggregate states on the host; the DAG to rerun the
         statement with, or None).  Where the program's join compacted
-        its probe rows, what it reports beside its outputs (copr/exec
-        `_compact_probe`) comes in the same fetch, and rows that did not
-        fit mean a rerun (`_uncompacted`)."""
-        if "join_need" not in extras:
-            states, need = self._fetch(out, **self._transfer_attrs()), None
+        its rows or read its table by windows, what it reports beside
+        its outputs (copr/exec `_compact_probe`, `_exec_lookup_join`)
+        comes in the same fetch; rows that did not fit, or fell outside
+        their window, mean a rerun (`_exact_form`)."""
+        said = {k: v for k, v in extras.items() if k in _JOIN_REPORTS}
+        if not said:
+            states = self._fetch(out, **self._transfer_attrs())
         else:
-            states, need = self._fetch(
-                out, (extras["join_live"], extras["join_need"]),
-                **self._transfer_attrs())
+            states, said = self._fetch(out, said, **self._transfer_attrs())
         # session.settle: from the fetch to the merge (or the rerun):
         # did the compaction fit, the fault seam keyed by the DAG's
         # digest.  Not a cop.* name: host_plan_ms subtracts those
         _obs_until_next("session.settle", root_only=True)
-        return states, None if need is None else self._uncompacted(dag, need)
+        return states, self._exact_form(dag, said)
 
     def _note_sched(self, task) -> None:
         if task.cost is not None:
@@ -787,29 +797,54 @@ class CopClient:
             return self._dependents.get(D.dag_digest(planned))
 
     def _join_form(self, dag):
-        """`dag` (of a program that joins), or with its compacting join
-        switched to the exact form where a program of this digest has
-        overflowed before."""
-        if not self._compact_overflowed or D.compacting_join(dag) is None:
-            return dag
+        """`dag` (of a program that joins), or in the exact form a
+        program of this digest had to be rerun in before: its lookups a
+        gather where a window missed a row, its compacting join looking
+        up every slot where the live rows did not fit."""
+        while self._exact_forms:
+            with self._pf_mu:
+                exact = self._exact_forms.get(D.dag_digest(dag))
+            if exact is None:
+                break
+            dag = exact(dag)
+        return dag
+
+    def _exact_form(self, dag, said: dict) -> Optional[D.CopNode]:
+        """The DAG to rerun a statement with after what its launch
+        `said` of its joins (`_fetch_states`), or None: nothing was
+        lost.  A window's miss first: what a compaction counted above a
+        wrong lookup is wrong too."""
+        if "join_window_miss" in said \
+                and int(np.sum(said["join_window_miss"])):
+            return self._unwindowed(dag)
+        if "join_need" in said:
+            return self._uncompacted(dag, int(np.max(said["join_need"])))
+        return None
+
+    def _remembered(self, dag, event: str, exact) -> D.CopNode:
+        """`exact(dag)`, which the statement is rerun with, `event`
+        counted and the digest remembered (`_join_form`)."""
+        self._scheduler().count(event)
         with self._pf_mu:
-            known = D.dag_digest(dag) in self._compact_overflowed
-        return D.uncompacted(dag) if known else dag
+            self._exact_forms[D.dag_digest(dag)] = exact
+            while len(self._exact_forms) > self._page_feedback_cap:
+                self._exact_forms.popitem(last=False)
+        return exact(dag)
 
     def _uncompacted(self, dag, need: int) -> Optional[D.CopNode]:
         """If a device's live probe rows take more than the compacting
         join's capacity (some are missing from this launch's result),
-        the DAG with every slot looked up, which the statement is rerun
-        with; the digest is remembered (`_join_form`).  None when they
-        fit."""
+        the DAG with every slot looked up; None when they fit."""
         if need <= D.compact_capacity(D.compacting_join(dag)):
             return None
-        self._scheduler().count("join_compact_overflows")
-        with self._pf_mu:
-            self._compact_overflowed[D.dag_digest(dag)] = None
-            while len(self._compact_overflowed) > self._page_feedback_cap:
-                self._compact_overflowed.popitem(last=False)
-        return D.uncompacted(dag)
+        return self._remembered(dag, "join_compact_overflows", D.uncompacted)
+
+    def _unwindowed(self, dag) -> D.CopNode:
+        """A lookup read by windows found a live row outside its window
+        (the probe key was not in the order ANALYZE saw, or its blocks
+        span more than the window): the DAG with every lookup a
+        gather."""
+        return self._remembered(dag, "join_window_overflows", D.unwindowed)
 
     def _grown_join_dag(self, dag, extras) -> Optional[D.CopNode]:
         """If the expanding join overflowed its capacity, return the DAG
@@ -1127,18 +1162,27 @@ class CopClient:
             cap = self._warm_cap(root, cap)
 
         cols, counts = snap.device_cols(self.mesh)
+        if aux_cols:
+            root = self._join_form(root)
         page_iters = 0       # published once, under _stat_mu, at the end
         for _ in range(10):  # paging: grow until fits
             page_iters += 1
             prog, out = self._launch(root, cols, counts, tuple(aux_cols),
                                      row_capacity=cap)
+            missed = None
             if prog.has_extras:
                 out, extras = out
                 grown = self._grown_join_dag(root, extras)
                 if grown is not None:
                     root = grown
                     continue
+                missed = extras.get("join_window_miss")
             out_cols, out_counts = out
+            if missed is not None \
+                    and int(np.sum(np.asarray(self._fetch(missed)))):
+                # a lookup read by windows lost a row: the gather form
+                root = self._unwindowed(root)
+                continue
             if is_topn or is_limit:
                 break       # the capacity is the limit: nothing to regrow
             out_counts = np.asarray(self._fetch(out_counts))
