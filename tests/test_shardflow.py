@@ -204,7 +204,7 @@ def test_shuffle_plan_dci_dominates_ici_under_two_host_view(corpus):
     is per-link, not a relabeled total."""
     _s, plans = corpus
     topo2 = T.MeshTopology((T.SHARD_AXIS,), N_DEV, 2)
-    shuffle = next(p for q, p in plans if "o_orderkey" in q
+    shuffle = next(p for q, p in plans if "o_custkey" in q
                    and _find(p, "CopShuffleJoinExec") is not None)
     bd = SF.plan_transfer(shuffle, topo2)
     assert bd.ici > 0 and bd.dci > 0

@@ -399,6 +399,37 @@ def probe_window_for(rows: int, span: int, ordered: bool) -> int:
     return window if window <= PROBE_WINDOW_MAX else 0
 
 
+def exchange_capacity_for(est_rows: float, n_dev: int,
+                          colocated: bool) -> int:
+    """The slots of one bucket of a lookup join's exchange
+    (LookupJoin.exchange): `est_rows` live probe rows a device, `n_dev`
+    devices, `colocated` whether the probe key is stored in key order as
+    the build key is (then a device's probe rows and the build rows they
+    match lie on the same device but for the stragglers where the two
+    tables' shards end at different keys).  Pure, like
+    `probe_capacity_for`; 0 on one device.
+
+    Keys that lie anywhere send a device's rows evenly: a bucket takes
+    1/n_dev of them, a quarter more and six standard deviations.
+    Colocated keys send next to nothing: a sixty-fourth of the rows.
+    Whole rows of the compaction's view, rounded up to an eighth of the
+    power of two.  A guess either way: a bucket that does not fit costs
+    one rerun with what the devices found, and the digest remembers
+    (store/client `_exchange_regrown`)."""
+    if n_dev <= 1:
+        return 0
+    share = est_rows / 64 if colocated else est_rows / n_dev
+    return exchange_capacity_round(
+        int(1.25 * share + 6 * (COMPACT_COLUMNS * share) ** 0.5) + 1)
+
+
+def exchange_capacity_round(need: int) -> int:
+    """`need` slots as a bucket's capacity: whole rows of the
+    compaction's view, up to an eighth of its power of two."""
+    step = max(1 << max(int(need).bit_length() - 3, 0), 8 * COMPACT_COLUMNS)
+    return max(-(-int(need) // step), 1) * step
+
+
 def probe_scan_column(join: "LookupJoin") -> Optional[int]:
     """Which column of its `TableScan` the probe key of `join` IS (the
     index into `col_offsets`), through the Selections, ColumnRef
@@ -527,6 +558,24 @@ class LookupJoin(CopNode):
     # (extras `join_window_miss`) and the dispatcher reruns the
     # statement at 0 where there is one.
     probe_window: int = field(default=0, metadata=DIGEST_IF_SET)
+    # a build past the planner's broadcast cap that stays where it lives,
+    # set by the executor (unique direct-addressed inner/left only): the
+    # aux group has a leading device axis and is sharded over the mesh,
+    # each device holding the table of the keys it owns, and after the
+    # tables the partition that says which device owns a key and where
+    # its slot is (copr/joinbuild.key_partition,
+    # `parallel/exchange.key_places`).  False = the group is replicated.
+    sharded: bool = field(default=False, metadata=DIGEST_IF_SET)
+    # `sharded` on a mesh of several devices: the slots of one bucket a
+    # destination.  A live probe row whose key another device owns
+    # travels there (`parallel/exchange.exchange_rows`: one column sort
+    # and one gather a destination, one all-to-all) and is looked up
+    # where the table is; a row whose key the device owns itself is
+    # looked up in place.  Rows that do not fit are never dropped: the
+    # program reports the capacity they take (extras `exchange_need`)
+    # and the dispatcher reruns the statement with it.  0 = nothing is
+    # exchanged (one device).
+    exchange: int = field(default=0, metadata=DIGEST_IF_SET)
 
     def children(self):
         return (self.child,)
@@ -782,6 +831,41 @@ def with_dependent_keys(agg: CopNode) -> CopNode:
     return dataclasses.replace(agg, dependent=marked)
 
 
+def groups_whole(agg: CopNode) -> bool:
+    """Does every device of a launch of `agg` hold its groups whole (no
+    group has rows on another device), because the one join of the DAG
+    that exchanges its probe rows sends every row to the device that
+    owns its probe key, and that key is, structurally, a group key?
+    TPC-H Q3 groups by `l_orderkey` above `l_orderkey = o_orderkey`
+    with `orders` sharded: a device may then rank its own groups
+    (`GroupTopN.on_device`) and the host merges the devices' first
+    groups.  An inner join only: a left join's NULL keys stay where
+    they were scanned.  Pure."""
+    from ..expr.ir import referenced_columns, substitute_columns
+    if not isinstance(agg, Aggregation) or not agg.group_by \
+            or sum(1 for j in lookup_joins(agg) if j.exchange) != 1:
+        return False
+    exprs: list = list(agg.group_by)
+    node = agg.child
+    while not isinstance(node, TableScan):
+        if isinstance(node, Projection):
+            exprs = [None if e is None else substitute_columns(e, node.exprs)
+                     for e in exprs]
+        elif isinstance(node, LookupJoin):
+            n_probe = len(output_dtypes(node.child))
+            exprs = [None if e is None or any(
+                i >= n_probe for i in referenced_columns(e)) else e
+                for e in exprs]
+            if node.exchange:
+                return node.kind == "inner" and any(
+                    e is not None and _same_expr(e, node.probe_key)
+                    for e in exprs)
+        elif not isinstance(node, Selection):
+            return False
+        node = node.child
+    return False
+
+
 def find_expand_join(node: CopNode):
     """The (at most one) non-unique LookupJoin in a pushed DAG, or None —
     programs containing one report true join output size via extras."""
@@ -834,17 +918,27 @@ def unwindowed(node: CopNode) -> CopNode:
     return node
 
 
+def exchanging_join(node: CopNode):
+    """A LookupJoin of a pushed DAG whose probe rows travel to the
+    device that owns their key (`exchange` > 0), or None."""
+    return next((n for n in iter_nodes(node) if isinstance(n, LookupJoin)
+                 and n.exchange), None)
+
+
 def has_extras(node: CopNode) -> bool:
     """Does a program of this DAG return an extras dict after its result
     (DeviceBatch.extras): the true size of an expanding join's output
     (`join_total`), the live rows a compacting join found and the
     capacity they take (`join_live`, `join_need`), the rows a lookup
-    read by windows found outside theirs (`join_window_miss`)?  The
+    read by windows found outside theirs (`join_window_miss`), the slots
+    an exchange's fullest bucket takes (`exchange_need`, with
+    `exchange_sent`, the rows a device sent)?  The
     dispatcher reruns the statement where a size exceeds its capacity
     or a row was missed."""
     return find_expand_join(node) is not None \
         or compacting_join(node) is not None \
-        or windowed_join(node) is not None
+        or windowed_join(node) is not None \
+        or exchanging_join(node) is not None
 
 
 def to_multimatch(node: CopNode, out_capacity: int) -> CopNode:
@@ -939,7 +1033,8 @@ __all__ = [
     "RADIX_BITS", "RADIX_RESIDUAL_BITS", "MAX_RADIX_PASSES",
     "radix_passes", "radix_key_bits", "Aggregation",
     "TopN", "TOPN_MIN_BLOCK", "topn_block_len",
-    "COMPACT_COLUMNS", "probe_capacity_for",
+    "COMPACT_COLUMNS", "probe_capacity_for", "exchange_capacity_for",
+    "exchange_capacity_round", "exchanging_join", "groups_whole",
     "PROBE_WINDOW_MAX", "probe_window_for", "probe_scan_column",
     "window_ok", "windowed_join", "unwindowed",
     "Limit", "LookupJoin",

@@ -801,6 +801,12 @@ def _exec_lookup_join(node: D.LookupJoin, batch: DeviceBatch, ev: Evaluator,
 
     if node.unique and node.kind in ("inner", "left"):
         from .join import direct_lookup, sorted_lookup
+        if node.sharded:
+            # the device's own tables, and after them the partition that
+            # says which device owns a key and where its slot is
+            # (dag.LookupJoin.sharded)
+            return _sharded_lookup(node, batch, ev, grp[:-1], grp[-1][0],
+                                   kv, km, compacted)
         with jax.named_scope("join_probe"):
             if node.dense:
                 # the rows whose result something reads: the window form
@@ -850,6 +856,94 @@ def _exec_lookup_join(node: D.LookupJoin, batch: DeviceBatch, ev: Evaluator,
         probe, sel, key_ok, list(build_cols), perm, lo, cnt, node.kind, oc)
     return replace(batch, cols=out_cols, sel=out_sel, stacked=1,
                    extras={**batch.extras, "join_total": total})
+
+
+def _sharded_lookup(node: D.LookupJoin, batch: DeviceBatch, ev: Evaluator,
+                    grp, part, kv, km, compacted) -> DeviceBatch:
+    """A unique direct-addressed lookup in a build that stays sharded
+    where it lives (dag.LookupJoin `sharded`, `exchange`), inside a
+    shard_map program: a live probe row whose key this device owns is
+    looked up in place, by windows where the plan says so; one whose key
+    another device owns travels there (parallel/exchange.exchange_rows),
+    is looked up where the table is and goes on from there.  The output
+    is the device's own slots and then the slots it received: every live
+    row of the mesh is in exactly one device's output, and every row of
+    one key on one device (a GROUP BY of the key above has its groups
+    whole).  Extras: `exchange_need`, the slots the fullest bucket
+    takes (rows are missing above the capacity), and `exchange_sent`.
+    With `exchange` 0 (one device) nothing travels."""
+    from ..parallel.exchange import exchange_rows, key_places
+    from ..parallel.mesh import SHARD_AXIS
+    from .join import _compare_narrow, direct_lookup
+    n = len(batch.cols[0][0])
+    kt = jnp.int32 if _compare_narrow(kv, part) else jnp.int64
+    part = part.astype(kt)
+    sel = _sel_array(batch.sel, n)
+    live = sel if km is True else sel & km
+
+    def lookup(keys, window=0, ok=True, stacked=1):
+        dest, at = key_places(keys.astype(kt), part)
+        return dest, direct_lookup(keys, grp, node.packing, window, ok,
+                                   stacked, offset=at)
+    if not node.exchange:
+        with jax.named_scope("join_probe"):
+            dest, (matched, build, miss) = lookup(
+                kv, node.probe_window, live, batch.stacked)
+            # (nothing travels: a key another device owns finds no row)
+            matched = matched & (dest == lax.axis_index(SHARD_AXIS))
+        extras, away, cap = dict(batch.extras), False, 0
+    else:
+        n_dev = lax.axis_size(SHARD_AXIS)
+        cap = min(node.exchange, n)     # a bucket never holds more than all
+        probe = [(_ensure_array(v, n), m) for v, m in batch.cols]
+        with jax.named_scope("join_exchange"):
+            dest, at = key_places(kv.astype(kt), part)
+            rcols, rok, need, sent = exchange_rows(
+                probe, live, dest, n_dev, cap, batch.stacked)
+        away = live & (dest != lax.axis_index(SHARD_AXIS))
+        with jax.named_scope("join_probe"):
+            matched, build, miss = direct_lookup(
+                kv, grp, node.packing, node.probe_window, live & ~away,
+                batch.stacked, offset=at)
+            matched = matched & ~away
+            rkv, rkm = ev.eval(node.probe_key, rcols, {})
+            rkv = _ensure_array(rkv, n_dev * cap)
+            _rdest, (rmatched, rbuild, _) = lookup(rkv)
+        extras = {**batch.extras, "exchange_need": need,
+                  "exchange_sent": sent}
+    if node.probe_window:
+        extras["join_window_miss"] = \
+            batch.extras.get("join_window_miss", 0) + miss
+    if km is not True:
+        matched = matched & km
+    out_cols = list(batch.cols)
+    for gv, gm in build:
+        out_cols.append((gv, matched if gm is True else (gm & matched)))
+    here = sel if away is False else sel & ~away
+    if node.kind == "inner":
+        here = here & matched
+    stacked = batch.stacked
+    if node.exchange:
+        # a row that left is live where it went, not here
+        if rkm is not True:
+            rmatched = rmatched & rkm
+        m = n_dev * cap
+
+        def both(a, b):
+            if a is True and b is True:
+                return True
+            return jnp.concatenate([_sel_array(a, n), _sel_array(b, m)])
+        rout = list(rcols) + [(gv, rmatched if gm is True else gm & rmatched)
+                              for gv, gm in rbuild]
+        out_cols = [(jnp.concatenate([_ensure_array(v, n), rv]),
+                     both(vm, rvm))
+                    for (v, vm), (rv, rvm) in zip(out_cols, rout)]
+        there = rok & rmatched if node.kind == "inner" else rok
+        here, stacked = jnp.concatenate([here, there]), 1
+    out = replace(batch, cols=out_cols, sel=here, stacked=stacked,
+                  extras=extras)
+    return compacted(out, node.match_capacity) if node.match_capacity \
+        else out
 
 
 def _topn_lanes(node: D.TopN, cols, sel, rows, ev: Evaluator) -> list:

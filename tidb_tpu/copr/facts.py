@@ -97,6 +97,20 @@ FACTS = {
     # (`dag.LookupJoin.probe_window`; 0: every lookup is a gather)
     "probe_window": Fact(
         counters=(("join_window_launches", lambda w: w > 0),)),
+    # of a program with a lookup whose build side stays sharded where it
+    # lives (`dag.LookupJoin.sharded`): how many of its lookups
+    "build_sharded": Fact(
+        counters=(("join_sharded_build_launches", _present),),
+        on_span=_present),
+    # of a program whose join moved rows between devices: which side
+    # travelled ("probe_to_build": the live probe rows, to the device
+    # that owns their key; "both": parallel/shuffle.py re-buckets both
+    # sides) and the slots of one bucket a destination.  What a device
+    # sent is known once the outputs are fetched: `cop.transfer`
+    # {`exchange_rows_sent`}
+    "exchange": Fact(counters=(("join_exchange_launches", _present),),
+                     merge=_first, on_span=_present),
+    "exchange_capacity": Fact(on_span=_present),
     # `exec.compact_root`, the root of a rows-returning program: the
     # slots a device hands its live rows to the host in, and whether
     # they got there by the column sort (1) or by the scatter (0)
@@ -137,10 +151,13 @@ FACTS = {
 # outside its window and the statement was rerun with the gather;
 # `hndv_agg_regrows`: a host-merged aggregation was rerun with a larger
 # table or a wider record; `rows_regrows`: a rows-returning program's
-# live rows did not fit its capacity and it was rerun with more)
+# live rows did not fit its capacity and it was rerun with more;
+# `exchange_overflows`: a bucket of a join's exchange did not hold the
+# rows a device had for one destination and the statement was rerun with
+# the capacity the devices found)
 EVENTS = ("join_shuffle_launches", "join_host_fallbacks", "join_regrows",
           "join_compact_overflows", "join_window_overflows",
-          "hndv_agg_regrows", "rows_regrows")
+          "hndv_agg_regrows", "rows_regrows", "exchange_overflows")
 
 
 def counter_names() -> tuple:

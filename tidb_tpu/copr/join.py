@@ -33,7 +33,7 @@ def _compare_narrow(kv, ref) -> bool:
 
 
 def direct_lookup(kv, grp, packing, window: int = 0, live=True,
-                  stacked: int = 1):
+                  stacked: int = 1, offset=None):
     """Probe a direct-addressed build side (dag.LookupJoin `dense`).
     kv: probe keys; grp: the aux group [(array, mask | True)].  Returns
     (matched, [(value, valid | True) per build column], miss); a
@@ -49,12 +49,18 @@ def direct_lookup(kv, grp, packing, window: int = 0, live=True,
     `live` the rows whose result is read, `stacked` the runs the slots
     consist of; `miss` then counts the live rows whose offset fell
     outside their block's window and whose result is therefore wrong
-    (the dispatcher reruns the statement at 0); else it is 0."""
+    (the dispatcher reruns the statement at 0); else it is 0.
+
+    `offset`: the keys' slots where they are not `key - base` (a
+    sharded side's, `parallel/exchange.key_places`), checked against
+    the span alike."""
     n_words, pbit, layout = packing
     meta = grp[0][0]
-    kt, ut = (jnp.int32, jnp.uint32) if _compare_narrow(kv, meta) \
+    kt, ut = (jnp.int32, jnp.uint32) \
+        if _compare_narrow(kv if offset is None else offset, meta) \
         else (jnp.int64, jnp.uint64)
-    d = kv.astype(kt) - meta[0].astype(kt)
+    d = kv.astype(kt) - meta[0].astype(kt) if offset is None \
+        else offset.astype(kt)
     matched = lax.bitcast_convert_type(d, ut) < meta[1].astype(ut)
     idx = jnp.where(matched, d, 0).astype(jnp.int32)  # valueflow: ok - a matched row's offset is below span < 2^31
     miss = jnp.zeros((), jnp.int32)

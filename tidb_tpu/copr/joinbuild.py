@@ -98,6 +98,10 @@ class BuildSide:
     n_unique: int
     dense: bool = False
     packing: tuple = ()
+    # the group has a leading device axis and is sharded over the mesh:
+    # each device holds the table of the keys it owns (`sharded_build`,
+    # `parallel/shuffle.ShardedTableProgram`)
+    sharded: bool = False
 
     @property
     def avg_dup(self) -> float:
@@ -111,7 +115,7 @@ class BuildSide:
     def slots(self) -> int:
         """The length of a direct-addressed side's word tables (its key
         range, rounded: `table_slots`); the rows of another form."""
-        return int(self.aux[2][0].shape[0]) \
+        return int(self.aux[2][0].shape[-1]) \
             if self.dense and len(self.aux) > 2 else self.rows
 
 
@@ -189,6 +193,73 @@ def prepare_build(keys: np.ndarray, cols: list, dense_ok: bool = True,
     return BuildSide(tuple(aux), n, n_unique == n, n_unique)
 
 
+def _pack_plan(cols: list, key_col: int, keys, read: tuple, room: list,
+               key_fits_i32: bool, ranges=None):
+    """How the build columns [(data, validity)] pack into int32 words
+    (dag.LookupJoin has the layout): (layout, mins, room).  `room`: the
+    free bits of the words there are already (a presence bit's word);
+    first fit.  `ranges`: (least, largest, all valid) a column where the
+    rows are not at hand (a table a device makes: `table_layout`), else
+    read from the data."""
+    layout, mins = [], []
+    for j, (data, valid) in enumerate(cols):
+        if j == key_col and (ranges is not None or (
+                data.dtype.kind in "iu" and valid.all()
+                and np.array_equal(data, keys))):
+            layout.append((KEY_ITSELF, 0, 0, -1, not key_fits_i32))
+            mins.append(0)
+            continue
+        if not read[j]:
+            layout.append((UNREAD, 0, 0, -1, False))
+            mins.append(0)
+            continue
+        if ranges is not None:
+            vmin, vmax, all_valid = ranges[j]
+            packable = True
+        else:
+            all_valid = bool(valid.all())
+            packable = data.dtype.kind in "ib" or (
+                data.dtype.kind == "u" and data.dtype.itemsize < 8)
+            vmin = vmax = 0
+            if packable and valid.any():
+                live = data if all_valid else data[valid]
+                vmin, vmax = int(live.min()), int(live.max())
+        bits = (vmax - vmin).bit_length()
+        need = bits + (0 if all_valid else 1)
+        if not packable or need > WORD_BITS:
+            layout.append((APART, 0, 0, -1, True))
+            mins.append(0)
+            continue
+        w = next((i for i, r in enumerate(room) if r >= need), len(room))
+        if w == len(room):
+            room.append(WORD_BITS)
+        shift = WORD_BITS - room[w]
+        room[w] -= need
+        vbit = -1 if all_valid else shift + bits
+        layout.append((w, shift, bits, vbit, not _fits_i32(vmin, vmax)))
+        mins.append(vmin)
+    return layout, mins, room
+
+
+def _row_words(layout, mins, cols: list, n_words: int, pbit: int,
+               n: int) -> list:
+    """A build row's words (n values a word), before they are scattered
+    over the key range."""
+    row_words = [np.zeros(n, np.int64) for _ in range(n_words)]
+    if pbit >= 0:
+        row_words[0][:] = 1
+    for (w, shift, bits, vbit, _wide), vmin, (data, valid) in zip(
+            layout, mins, cols):
+        if w < 0:
+            continue
+        if bits:
+            v = data.astype(np.int64) - vmin
+            row_words[w] |= (v if vbit < 0 else np.where(valid, v, 0)) << shift
+        if vbit >= 0:
+            row_words[w] |= valid.astype(np.int64) << vbit
+    return row_words
+
+
 def _dense_group(pos, lo: int, span: int, cols: list, key_col: int,
                  keys, read: tuple) -> Optional[BuildSide]:
     """Scatter the build columns over the key range and pack those that
@@ -209,60 +280,20 @@ def _dense_group(pos, lo: int, span: int, cols: list, key_col: int,
     # holes: the tables' length is rounded (`table_slots`); none: the
     # keys' count is the tables', as it always was
     slots = table_slots(span) if pbit >= 0 else span
-    layout, mins, apart = [], [], []
-    for j, (data, valid) in enumerate(cols):
-        if j == key_col and data.dtype.kind in "iu" and valid.all() \
-                and np.array_equal(data, keys):
-            layout.append((KEY_ITSELF, 0, 0, -1,
-                           not _fits_i32(lo, lo + span)))
-            mins.append(0)
+    layout, mins, room = _pack_plan(cols, key_col, keys, read, room,
+                                    _fits_i32(lo, lo + span))
+    apart = []
+    for (w, *_rest), (data, valid) in zip(layout, cols):
+        if w != APART:
             continue
-        if not read[j]:
-            layout.append((UNREAD, 0, 0, -1, False))
-            mins.append(0)
-            continue
-        all_valid = bool(valid.all())
-        packable = data.dtype.kind in "ib" or (
-            data.dtype.kind == "u" and data.dtype.itemsize < 8)
-        vmin = vmax = 0
-        if packable and valid.any():
-            live = data if all_valid else data[valid]
-            vmin, vmax = int(live.min()), int(live.max())
-        bits = (vmax - vmin).bit_length()
-        need = bits + (0 if all_valid else 1)
-        if not packable or need > WORD_BITS:
-            table = np.zeros(slots, data.dtype)
-            table[pos] = data
-            vtab = None
-            if not all_valid:
-                vtab = np.zeros(slots, bool)
-                vtab[pos] = valid
-            apart.append((table, vtab))
-            layout.append((APART, 0, 0, -1, True))
-            mins.append(0)
-            continue
-        w = next((i for i, r in enumerate(room) if r >= need), len(room))
-        if w == len(room):
-            room.append(WORD_BITS)
-        shift = WORD_BITS - room[w]
-        room[w] -= need
-        vbit = -1 if all_valid else shift + bits
-        layout.append((w, shift, bits, vbit, not _fits_i32(vmin, vmax)))
-        mins.append(vmin)
-    # a row's words first (n values), then ONE int32 scatter a word over
-    # the range: a sparse range is paid in its bytes once
-    row_words = [np.zeros(n, np.int64) for _ in room]
-    if pbit >= 0:
-        row_words[0][:] = 1
-    for (w, shift, bits, vbit, _wide), vmin, (data, valid) in zip(
-            layout, mins, cols):
-        if w < 0:
-            continue
-        if bits:
-            v = data.astype(np.int64) - vmin
-            row_words[w] |= (v if vbit < 0 else np.where(valid, v, 0)) << shift
-        if vbit >= 0:
-            row_words[w] |= valid.astype(np.int64) << vbit
+        table = np.zeros(slots, data.dtype)
+        table[pos] = data
+        vtab = None
+        if not valid.all():
+            vtab = np.zeros(slots, bool)
+            vtab[pos] = valid
+        apart.append((table, vtab))
+    row_words = _row_words(layout, mins, cols, len(room), pbit, n)
     tables = []
     for rw in row_words:
         table = _borrow_table(slots)
@@ -286,14 +317,142 @@ def _dense_group(pos, lo: int, span: int, cols: list, key_col: int,
                      packing=(len(tables), pbit, tuple(layout)))
 
 
+def key_partition(firsts, devices, n_dev: int, top: int):
+    """The range partition of a sharded build side over `n_dev` devices
+    (`parallel/exchange.key_places` reads it): (part, slots).  The build
+    rows lie in stripes of ascending keys, `firsts[s]` the least key of
+    stripe s (None: it holds none) and `devices[s]` the device it lies
+    on (a table's shards in row order under its placement, where the
+    table is stored by the key; one stripe a device where the host deals
+    the rows out); `top` a key above every build key.  Stripe s owns the
+    keys from its first up to the next stripe's first (the last: up to
+    `top`) and its device gives them that many slots, after the slots of
+    its earlier stripes; `slots[d]` is what device d's stripes take in
+    all.  `part` as `key_places` wants it: the splits, and the steps of
+    the owner and of `key - slot` from one stripe to the next.  Pure."""
+    n = len(firsts)
+    splits, nxt = [0] * n, int(top)
+    for s in range(n - 1, -1, -1):
+        nxt = nxt if firsts[s] is None else int(firsts[s])
+        splits[s] = nxt
+    if any(a > b for a, b in zip(splits, splits[1:])):
+        raise ValueError("key_partition: the stripes' keys overlap")
+    slots = [0] * n_dev
+    owner, adjust = [], []
+    for s in range(n):
+        width = (splits[s + 1] if s + 1 < n else int(top)) - splits[s]
+        owner.append(int(devices[s]))
+        adjust.append(splits[s] - slots[devices[s]])
+        slots[devices[s]] += width
+    part = np.array([splits,
+                     np.diff(owner, prepend=0),
+                     np.diff(adjust, prepend=0)], np.int64)
+    return part, slots
+
+
+def sharded_form(slots, columns: int,
+                 device_bytes: int = DEFAULT_DEVICE_BYTES) -> bool:
+    """May a unique build past the broadcast cap stay sharded, each
+    device holding the direct-addressed table of the keys it owns
+    (`slots`: the slots a device, `key_partition`)?  Where every
+    device's table fits as `build_form` asks of one.  Pure."""
+    return all(build_form(0, int(n), columns, device_bytes) == DIRECT
+               for n in slots)
+
+
+def _sharded_group(slots, mins, tables, part, n_dev: int, put) -> tuple:
+    """The aux group of a sharded side: every array with a leading
+    device axis (a device's table starts at its slot 0 and has `slots[d]`
+    of them), the partition (the same on every device) last."""
+    fits = _fits_i32(int(part.min()), int(part.max()))
+    kdt = np.int32 if fits and max(slots) < _I32.max else np.int64
+    meta = np.stack([np.zeros(n_dev, np.int64),
+                     np.asarray(slots, np.int64)], axis=1).astype(kdt)
+    return ((put(meta), None),
+            (put(np.tile(np.asarray(mins, np.int64), (n_dev, 1))), None),
+            *((t if isinstance(t, jax.Array) else put(t), None)
+              for t in tables),
+            (put(np.tile(part.astype(kdt), (n_dev, 1, 1))), None))
+
+
+def table_length(slots) -> int:
+    """The length of every device's word tables of a sharded side: the
+    most slots a device takes, rounded as `table_slots` rounds."""
+    return max(table_slots(int(max(slots))), 1024)
+
+
+def sharded_build(keys: np.ndarray, cols: list, part: np.ndarray, slots,
+                  put, key_col: int = -1, read=None,
+                  device_bytes: int = DEFAULT_DEVICE_BYTES
+                  ) -> Optional[BuildSide]:
+    """Host half of a lookup join whose build stays sharded: unique keys
+    (int64, NULLs dropped, at least one) and the row-aligned columns ->
+    an aux group with a leading device axis, each device holding the
+    direct-addressed table of the keys it owns under `part`
+    (`key_partition`, `parallel/exchange.key_places`).  `put`: host
+    array with a leading device axis -> device array sharded along it.
+    None where a key comes twice, a device's table does not fit
+    (`sharded_form`) or a column does not pack into a word: the caller
+    takes another plan.  One packing for every device (it is part of the
+    program); the presence bit is always there."""
+    from ..parallel.exchange import key_places
+    n, n_dev = len(keys), len(slots)
+    read = tuple(read) if read is not None else (True,) * len(cols)
+    carried = sum(r for j, r in enumerate(read) if j != key_col)
+    if not sharded_form(slots, carried, device_bytes):
+        return None
+    own, pos = key_places(keys, part, np)
+    if (pos < 0).any() or (pos >= np.asarray(slots)[own]).any():
+        raise ValueError("sharded_build: a key outside the partition")
+    layout, mins, room = _pack_plan(
+        cols, key_col, keys, read, [WORD_BITS - 1],
+        _fits_i32(int(keys.min()), int(keys.max()) + 1))
+    if any(w == APART for w, *_ in layout):
+        return None
+    length = table_length(slots)
+    tables = []
+    for rw in _row_words(layout, mins, cols, len(room), 0, n):
+        table = np.zeros((n_dev, length), np.int32)
+        table[own, pos] = rw
+        tables.append(table)
+    if np.count_nonzero(tables[0]) != n:
+        return None                 # two rows wrote one slot's presence
+    aux = _sharded_group(slots, mins, tables, part, n_dev, put)
+    return BuildSide(aux, n, True, n, dense=True, sharded=True,
+                     packing=(len(tables), 0, tuple(layout)))
+
+
+def table_layout(ranges: list, key_col: int, read: tuple,
+                 key_fits_i32: bool) -> Optional[tuple]:
+    """(packing, mins) of a sharded side whose tables a device program
+    makes from a join's rows (`parallel/shuffle.ShardedTableProgram`):
+    the rows are not at hand, so a column packs by the range its base
+    column has in its table's snapshot, `ranges[j]` = (least, largest,
+    no NULL) or None for a column that is no integer base column.  None
+    where a column that is read has no range or does not pack."""
+    if any(r is None and read[j] and j != key_col
+           for j, r in enumerate(ranges)):
+        return None
+    layout, mins, room = _pack_plan(
+        [(None, None)] * len(ranges), key_col, None, read,
+        [WORD_BITS - 1], key_fits_i32,
+        ranges=[r or (0, 0, True) for r in ranges])
+    if any(w == APART for w, *_ in layout):
+        return None
+    return (len(room), 0, tuple(layout)), mins
+
+
 def build_rows(node, grp) -> int:
     """Rows (slots, for a direct-addressed side) of the build side a
     launch carries in `grp` for the LookupJoin `node`."""
     if not node.dense:
         return int(grp[0][0].shape[0])
-    return int(grp[2][0].shape[0]) if len(grp) > 2 else 0
+    # a sharded side's tables have a leading device axis: all of them
+    return int(grp[2][0].size) if len(grp) > 2 else 0
 
 
 __all__ = ["BuildSide", "prepare_build", "build_form", "table_slots",
+           "key_partition", "sharded_form", "sharded_build", "table_layout",
+           "table_length",
            "build_rows", "WORD_BITS", "APART", "KEY_ITSELF", "UNREAD",
            "DIRECT", "SORTED", "EXPANDING"]

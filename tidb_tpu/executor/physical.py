@@ -518,6 +518,12 @@ class CopJoinTaskExec(PhysOp):
     # (`dag.probe_window_for`, from the planner: plan._probe_windows);
     # () = no such join, or no statistics (`_windowed`)
     probe_windows: tuple = ()
+    # a build side past the broadcast cap that stays on its devices
+    # (plan.which_side_moves says PROBE_TO_BUILD; plan._sharded_build
+    # has the fields): each device holds the table of the keys it owns
+    # and the probe's live rows travel to their keys' owners
+    # (`_sharded_side`); None = the build is replicated
+    sharded_build: dict = None
 
     def __post_init__(self):
         self.children = ([b["exec"] for b in self.builds] if self.builds
@@ -526,13 +532,32 @@ class CopJoinTaskExec(PhysOp):
     def describe(self):
         kind = "agg" if isinstance(self.dag, D.Aggregation) else "rows"
         lvl = f" x{len(self.builds)} levels" if self.builds else ""
+        how = "sharded-build" if self.sharded_build else "broadcast-build"
         return (f"CopJoinTask[{kind},{self.join_kind}] probe={self.table.name}"
-                f" broadcast-build{lvl} -> TPU")
+                f" {how}{lvl} -> TPU")
 
-    def execute(self, ctx: ExecContext) -> ResultChunk:
+    def execute(self, ctx: ExecContext, resident: bool = False):
+        """`resident`: the rows stay on their devices (store/client
+        `execute_rows_resident`: (output columns, capacity)) for the
+        join above to make its sharded build side of; None where this
+        run cannot (a chain, an anomaly that takes the host's plan)."""
         if self.builds:
-            return self._execute_tree(ctx)
-        return self._execute_single(ctx)
+            return None if resident else self._execute_tree(ctx)
+        return self._execute_single(ctx, resident)
+
+    def exchanges(self, n_dev: int) -> list:
+        """(build table.column, which side moves, what stays) for
+        EXPLAIN's `join exchange:` line; [] for a replicated build."""
+        if not self.sharded_build:
+            return []
+        table, offset = self.sharded_build["key_source"]
+        name = f"{table.name}.{table.snapshot().names[offset]}"
+        if n_dev <= 1:
+            return [(name, "nothing moves (one device)",
+                     "the build stays on its device")]
+        return [(name, "probe_to_build: the probe's live rows travel to "
+                 "the device that owns their key",
+                 f"the build stays sharded over {n_dev} devices")]
 
     def build_forms(self, device_bytes: int) -> list:
         """(table.column, form, slots) a build side, lowest join first:
@@ -562,8 +587,12 @@ class CopJoinTaskExec(PhysOp):
                 out.append((name, EXPANDING, snap.num_rows))
             else:
                 span = hi - lo + 1
-                form = build_form(snap.num_rows, span,
-                                  len(b_exec.out_names) - 1, device_bytes)
+                # a side that stays sharded is direct-addressed, a
+                # device's share of the range each: the planner's rule
+                # (plan.which_side_moves) asked it of every device
+                form = DIRECT if self.sharded_build is not None \
+                    else build_form(snap.num_rows, span,
+                                    len(b_exec.out_names) - 1, device_bytes)
                 holes = snap.num_rows != span
                 out.append((name, form, snap.num_rows if form != DIRECT
                             else table_slots(span) if holes else span))
@@ -581,6 +610,42 @@ class CopJoinTaskExec(PhysOp):
         return self._run(ctx, self._windowed(ctx, self._compacted(
             ctx, self._grouped(ctx, dag))), groups)
 
+    def _sharded_side(self, ctx, read):
+        """The build side as it stays on its devices (`sharded_build`):
+        a _PreparedBuild whose side's aux group has a leading device
+        axis, or None where the run cannot make one (the host's plan
+        answers).  A resident table's rows are dealt to the devices that
+        own their keys by the host and kept with the snapshot, as
+        `_prepared_build` keeps a replicated side; a join's result is
+        made into tables where it lies, by every statement."""
+        from ..obs.trace import span
+        with span("cop.join_build"):
+            if type(self.build_exec) is CopTaskExec:
+                source = "table"
+                built, cached = _sharded_from_table(ctx, self, read)
+            else:
+                source = "join"
+                built, cached = _sharded_from_join(ctx, self, read), False
+            if built is not None:
+                _annotate_build(built, source, cached, sharded=True)
+            return built
+
+    def _exchanged(self, ctx, dag):
+        """`dag`, whose one join's build side stays sharded, with the
+        slots of a bucket of its exchange (dag.LookupJoin `exchange`,
+        from `dag.exchange_capacity_for`): the live probe rows a device
+        the planner estimated, or every row it holds where there are no
+        statistics; colocated where ANALYZE found the probe key stored
+        in key order and the build's table is stored by its key.  On
+        one device nothing is exchanged."""
+        n_dev = len(ctx.client.mesh.devices.reshape(-1))
+        per_dev = -(-max(self.table.snapshot().num_rows, 1) // n_dev)
+        est = self.probe_est_rows / n_dev if self.probe_est_rows else per_dev
+        cap = D.exchange_capacity_for(
+            min(est, per_dev), n_dev,
+            bool(self.probe_windows) and self.sharded_build["by_key"])
+        return D.rewrite_lookup(dag, exchange=cap) if cap else dag
+
     def _empty_build_result(self, ctx, bchunk) -> ResultChunk:
         # empty build side: inner join produces nothing; left join keeps all
         # probe rows with NULL build cols — both simplest via the fallback
@@ -590,18 +655,26 @@ class CopJoinTaskExec(PhysOp):
         ctx.client._scheduler().count("join_host_fallbacks")
         return self.fallback.execute(ctx)
 
-    def _execute_single(self, ctx: ExecContext) -> ResultChunk:
+    def _execute_single(self, ctx: ExecContext, resident: bool = False):
         semi = self.join_kind in ("semi", "anti")
         (join,) = D.lookup_joins(self.dag) or (None,)
-        built = _prepared_build(
-            ctx, self.build_exec, self.build_key_index, self._keys_for,
-            self.build_key_dict, self.probe_key_dtype, want_cols=not semi,
-            read=None if semi or join is None
-            else D.build_columns_read(self.dag, join))
+        read = None if semi or join is None \
+            else D.build_columns_read(self.dag, join)
+        if self.sharded_build is not None:
+            built = self._sharded_side(ctx, read)
+            if built is None:
+                return None if resident else self._host_fallback(ctx)
+        else:
+            built = _prepared_build(
+                ctx, self.build_exec, self.build_key_index, self._keys_for,
+                self.build_key_dict, self.probe_key_dtype,
+                want_cols=not semi, read=read)
         # from the build to the probe's dispatch: its inputs again
         _obs_until_next("session.inputs", root_only=True)
         side = built.side
         dag = self.dag
+        if resident and (semi or side is None or not side.unique):
+            return None
         if self.null_aware and built.null_key:
             # NOT IN with a NULL build key: NO probe row qualifies.  Keep
             # the fused program shape (incl. any aggregation over zero
@@ -629,10 +702,15 @@ class CopJoinTaskExec(PhysOp):
         else:
             if side.dense:
                 dag = D.rewrite_lookup(dag, dense=True,
-                                       packing=side.packing)
+                                       packing=side.packing,
+                                       sharded=side.sharded)
             if not semi:
                 dag = self._windowed(ctx, self._compacted(
                     ctx, self._grouped(ctx, dag), side.rows))
+            if side.sharded:
+                dag = self._exchanged(ctx, dag)
+        if resident:
+            return self._run(ctx, dag, (side.aux,), resident=True)
         chunk = self._run(ctx, dag, (side.aux,))   # one aux group
         # build-side output columns keep their own dictionaries
         if not isinstance(self.dag, D.Aggregation):
@@ -723,9 +801,11 @@ class CopJoinTaskExec(PhysOp):
         EXPLAIN: the lookups ANALYZE found probed in key order."""
         return [(name, window) for _slot, window, name in self.probe_windows]
 
-    def _run(self, ctx, dag, aux) -> ResultChunk:
+    def _run(self, ctx, dag, aux, resident: bool = False):
         """Dispatch the fused program and decode with output dicts."""
         snap = self.table.snapshot()
+        if resident:
+            return ctx.client.execute_rows_resident(dag, snap, aux_cols=aux)
         if isinstance(dag, D.Aggregation):
             res = ctx.client.execute_agg(dag, snap, self.key_meta,
                                          aux_cols=aux)
@@ -2371,12 +2451,166 @@ def _prepared_build(ctx, b_exec, key_index, keys_for, key_dict,
                                [c.dictionary for c in bchunk.columns],
                                key_dict)
         if key is not None:
-            kept = snap._join_builds
-            kept[key] = built
-            while len(kept) > _JOIN_BUILDS_KEPT:
-                kept.pop(next(iter(kept)))
+            _keep_build(snap, key, built)
         _annotate_build(built, source, cached=False)
         return built
+
+
+def _scan_columns(b_exec):
+    """The snapshot columns a build side's plan hands on as they are (a
+    bare scan, or ColumnRef projections of one): [Column] in its output
+    order, or None where it filters or computes.  A sharded build of
+    all of a resident table reads the host's copy: no launch."""
+    from ..expr.ir import ColumnRef
+    node, picks = b_exec.dag, None
+    while isinstance(node, D.Projection):
+        if not all(isinstance(e, ColumnRef) for e in node.exprs):
+            return None
+        idx = [e.index for e in node.exprs]
+        picks = idx if picks is None else [idx[i] for i in picks]
+        node = node.child
+    if not isinstance(node, D.TableScan):
+        return None
+    snap = b_exec.table.snapshot()
+    cols = [snap.columns[off] for off in node.col_offsets]
+    return cols if picks is None else [cols[i] for i in picks]
+
+
+def _key_partition(info: dict, keys, n_dev: int):
+    """(part, slots) of a sharded build side (copr/joinbuild
+    `key_partition`): the stripes are the shards of the build key's
+    table where it is stored by that key (a device's rows then lie
+    where their keys are owned), else one stripe a device that deals
+    `keys` out evenly (the host's to deal: a resident table's rows)."""
+    from ..copr.joinbuild import key_partition
+    table, offset = info["key_source"]
+    snap = table.snapshot()
+    data = snap.columns[offset].data
+    if info["by_key"]:
+        stripes = snap.device_stripes(n_dev)
+        return key_partition(
+            [int(data[r0]) if r1 > r0 else None for r0, r1, _d in stripes],
+            [d for _r0, _r1, d in stripes], n_dev,
+            int(data[-1]) + 1 if len(data) else 1)
+    ordered = np.sort(keys)
+    return key_partition(
+        [int(ordered[len(ordered) * d // n_dev]) if len(ordered) else None
+         for d in range(n_dev)], list(range(n_dev)), n_dev,
+        int(ordered[-1]) + 1 if len(ordered) else 1)
+
+
+def _put_sharded(mesh):
+    from ..parallel.mesh import sharded
+    import jax
+    return lambda a: jax.device_put(a, sharded(mesh))
+
+
+def _sharded_from_table(ctx, op, read):
+    """(`_PreparedBuild` | None, was it kept) of a sharded build side
+    that is a resident table's rows (CopJoinTaskExec `_sharded_side`)."""
+    from ..copr.joinbuild import sharded_build
+    from ..sched.task import mesh_fingerprint
+    b_exec, mesh = op.build_exec, ctx.client.mesh
+    n_dev = len(mesh.devices.reshape(-1))
+    snap = _resident_snapshot(b_exec)
+    key = None
+    if snap is not None:
+        key = (b_exec.dag, op.build_key_index, "sharded",
+               mesh_fingerprint(mesh), read)
+        hit = snap._join_builds.get(key)
+        if hit is not None:
+            snap._join_builds[key] = snap._join_builds.pop(key)  # LRU
+            return hit, True
+    columns = _scan_columns(b_exec) if snap is not None else None
+    if columns is None:
+        columns = b_exec.execute(ctx).columns
+    kcol = columns[op.build_key_index]
+    keys, ok = kcol.data.astype(np.int64), kcol.validity
+    rows_idx = slice(None) if ok.all() else np.nonzero(ok)[0]
+    keys = keys[rows_idx]
+    side = None
+    if len(keys):
+        part, slots = _key_partition(op.sharded_build, keys, n_dev)
+        side = sharded_build(
+            keys, [(c.data[rows_idx], c.validity[rows_idx])
+                   for c in columns], part, slots,
+            _put_sharded(mesh), key_col=op.build_key_index, read=read,
+            device_bytes=_device_bytes(mesh))
+        if side is None:
+            return None, False
+    built = _PreparedBuild(side, not ok.all(),
+                           [c.dictionary for c in columns], None)
+    if key is not None:
+        _keep_build(snap, key, built)
+    return built, False
+
+
+def _sharded_from_join(ctx, op, read):
+    """The `_PreparedBuild` of a sharded build side that is a join's
+    result (CopJoinTaskExec `_sharded_side`): the build's own join
+    leaves its rows on their devices and a device program scatters
+    them into each device's word tables (parallel/shuffle
+    `ShardedTableProgram`); None where the run cannot.  Nothing of it
+    is kept: it carries the statement's parameters."""
+    from ..copr.joinbuild import (BuildSide, _fits_i32, _sharded_group,
+                                  sharded_form, table_layout, table_length)
+    from ..parallel.shuffle import TableSpec
+    b_exec, mesh, info = op.build_exec, ctx.client.mesh, op.sharded_build
+    n_dev = len(mesh.devices.reshape(-1))
+    table, offset = info["key_source"]
+    lo, hi = table.snapshot().key_range(offset)
+    if hi < lo:
+        return None
+    part, slots = _key_partition(info, None, n_dev)
+    ranges = []
+    for source in info["col_sources"]:
+        csnap = source and source[0].snapshot()
+        col = csnap and csnap.columns[source[1]]
+        if col is None or col.data.dtype.kind not in "iub":
+            ranges.append(None)
+            continue
+        cmin, cmax = csnap.key_range(source[1])
+        ranges.append((cmin, max(cmax, cmin), bool(col.validity.all())))
+    n_cols = len(info["col_sources"])
+    read = tuple(read) if read is not None else (True,) * n_cols
+    laid = table_layout(ranges, op.build_key_index, read,
+                        _fits_i32(lo, hi + 1))
+    carried = sum(r for j, r in enumerate(read) if j != op.build_key_index)
+    if laid is None or not sharded_form(slots, carried, _device_bytes(mesh)):
+        return None
+    packing, mins = laid
+    out = b_exec.execute(ctx, resident=True)
+    if out is None:
+        return None
+    out_cols, _cap = out
+    length = table_length(slots)
+    spec = TableSpec(op.build_key_index, packing,
+                     tuple(int(m) for m in mins), length)
+    # the group first, with tables still to come: its meta and its
+    # partition are the table program's inputs too
+    group = _sharded_group(slots, mins, (), part, n_dev, _put_sharded(mesh))
+    tables, said = ctx.client.sharded_tables(
+        out_cols, spec, group[0][0], group[-1][0])
+    wrote, held, stray = (int(said[:, i].sum()) for i in range(3))
+    if held != wrote or stray:
+        return None         # a key came twice, or a row lies elsewhere
+    side = None
+    if wrote:
+        aux = group[:2] + tuple((t, None) for t in tables) + group[-1:]
+        side = BuildSide(aux, wrote, True, wrote, dense=True, sharded=True,
+                         packing=packing)
+    n_b = len(b_exec.out_dtypes)
+    return _PreparedBuild(side, False,
+                          [b_exec.out_dicts.get(j) for j in range(n_b)], None)
+
+
+def _keep_build(snap, key, built: _PreparedBuild) -> None:
+    """Keep a prepared build side with the snapshot it was read from,
+    the oldest of more than `_JOIN_BUILDS_KEPT` going."""
+    kept = snap._join_builds
+    kept[key] = built
+    while len(kept) > _JOIN_BUILDS_KEPT:
+        kept.pop(next(iter(kept)))
 
 
 def _walk(op):
@@ -2397,7 +2631,7 @@ def _device_bytes(mesh) -> int:
 
 
 def _annotate_build(built: _PreparedBuild, source: str,
-                    cached: bool) -> None:
+                    cached: bool, sharded: bool = False) -> None:
     """What the span says of the build side: its rows, the form it took
     (copr/joinbuild: direct | sorted | expanding; "none": no live key),
     the slots of that form (the key range of a direct-addressed side),
@@ -2408,7 +2642,8 @@ def _annotate_build(built: _PreparedBuild, source: str,
              unique=bool(side and side.unique),
              dense=bool(side and side.dense), cached=cached,
              form=side.form if side else "none",
-             slots=side.slots if side else 0, source=source)
+             slots=side.slots if side else 0, source=source,
+             **({"sharded": True} if sharded else {}))
 
 
 def _prep_build_groups(ctx, builds, keys_for, dag):

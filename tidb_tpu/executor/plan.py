@@ -709,6 +709,97 @@ def _try_inl_join(p: LogicalJoin, ndj: bool) -> Optional[PhysOp]:
 
 BROADCAST_BUILD_MAX_ROWS = 1 << 22     # broadcast-join build-side cap
 
+# what a lookup join's sides do (`which_side_moves`)
+REPLICATE, PROBE_TO_BUILD, BOTH, HOST = \
+    "replicate", "probe_to_build", "both", "host"
+
+
+def which_side_moves(rows: int, span: int, columns: int, n_dev: int,
+                     unique: bool, by_key: bool, from_table: bool,
+                     device_bytes: int = 0, cap: int = -1) -> str:
+    """Which side of a device join travels.  Pure: `rows` base rows
+    beneath the build side, its key over a range of `span`, `columns`
+    build columns beside the key, `n_dev` devices of `device_bytes`
+    each; `unique`: no build key comes twice; `by_key`: the build key's
+    table is stored in key order (a device's rows cover a key range no
+    other's do); `from_table`: the build is a resident table's rows
+    (else a join's result).
+
+    - REPLICATE: the build is under the broadcast cap: every device
+      gets all of it and nothing else moves (the lookup join every
+      one-chip cell runs).
+    - PROBE_TO_BUILD: past the cap, but a direct-addressed table of a
+      device's share of the key range fits (copr/joinbuild.build_form
+      of `span / n_dev`): the build stays sharded, each device holding
+      the table of the keys it owns, and only the probe's live rows
+      travel, to the device that owns their key (on one device: nowhere).
+      A table's rows are dealt to their owners by the host, once a
+      snapshot, whatever order they are stored in; a join's result is
+      made on the devices by every statement and has to lie where its
+      keys are owned already: `by_key`.
+    - BOTH: past the cap and no table to own (duplicate keys, a range no
+      table spans): both sides are re-bucketed by a hash of the key
+      (parallel/shuffle.py), a resident table's rows only.
+    - HOST: none of these: the host joins."""
+    from ..copr.joinbuild import DEFAULT_DEVICE_BYTES, DIRECT, build_form
+    if rows <= (BROADCAST_BUILD_MAX_ROWS if cap < 0 else cap):
+        return REPLICATE
+    share = -(-max(span, 1) // max(n_dev, 1))
+    if unique and (from_table or by_key) and span > 0 and build_form(
+            rows, share, columns,
+            device_bytes or DEFAULT_DEVICE_BYTES) == DIRECT:
+        return PROBE_TO_BUILD
+    return BOTH if from_table else HOST
+
+
+def _base_rows(plan: LogicalPlan) -> int:
+    """The base rows beneath a subtree (what `_broadcastable` bounds)."""
+    if isinstance(plan, DataSource):
+        return plan.table.num_rows
+    return sum(_base_rows(c) for c in plan.children)
+
+
+def _sharded_build(plan: LogicalPlan, key: int, probe_key_dtype,
+                   columns: int = 1):
+    """How a build side past the broadcast cap stays on its devices
+    (`which_side_moves` says PROBE_TO_BUILD), as CopJoinTaskExec takes
+    it (`sharded_build`), or None: {"by_key", "key_source" (table,
+    column offset), "col_sources" (the same a build column, None where
+    computed)}.  An integer key that is a base column, compared with an
+    integer probe key as it is.  `columns`: the build columns beside the
+    key that the statement reads; before the plan above the join is
+    bound, one (the least a table takes: `_try_cop_join` asks again
+    with what `dag.build_columns_read` finds)."""
+    source = _base_column(plan, key)
+    if source is None or probe_key_dtype.kind not in _SHUFFLE_KEY_KINDS \
+            or probe_key_dtype.kind in (K.DATE, K.DATETIME, K.TIME):
+        return None
+    table, offset = source
+    if getattr(table, "is_memtable", False) \
+            or getattr(table, "partition", None) is not None:
+        return None
+    snap = table.snapshot()
+    col = snap.columns[offset]
+    if col.data.dtype.kind not in "iu" or col.dtype.kind == K.DECIMAL:
+        return None
+    cur = plan
+    while isinstance(cur, (LogicalSelection, LogicalProjection)):
+        cur = cur.child
+    lo, hi = snap.key_range(offset)
+    from ..parallel import get_mesh
+    from .physical import _device_bytes
+    mesh = get_mesh()
+    by_key = snap.key_is_ascending(offset)
+    form = which_side_moves(
+        _base_rows(plan), hi - lo + 1, columns, mesh.devices.size,
+        snap.key_is_unique(offset), by_key, isinstance(cur, DataSource),
+        _device_bytes(mesh))
+    if form != PROBE_TO_BUILD:
+        return None
+    return {"by_key": by_key, "key_source": source,
+            "col_sources": [_base_column(plan, j)
+                            for j in range(len(plan.schema))]}
+
 
 def _try_cop_join(p: LogicalPlan, top, mids, join: LogicalJoin) -> Optional[PhysOp]:
     """Device broadcast-lookup join: probe chain (left) stays sharded on
@@ -737,13 +828,25 @@ def _try_cop_join(p: LogicalPlan, top, mids, join: LogicalJoin) -> Optional[Phys
     # host-materialized FRAGMENT (fragment.go cut: the build subtree's
     # root is a Broadcast exchange).  Oversized single-table builds take
     # the cross-device repartition join instead.
-    if not _broadcastable(join.right):
+    # A build past the cap stays sharded where a table of a device's
+    # keys fits (`which_side_moves`): the lookup join below, its build
+    # never replicated.
+    sharded = None
+
+    def rebucketed():
+        # neither replicated nor sharded in place: a resident table's
+        # rows are re-bucketed with the probe's, a join's go to the host
         bcur = join.right
         while isinstance(bcur, (LogicalSelection, LogicalProjection)):
             bcur = bcur.child
-        if isinstance(bcur, DataSource):
-            return _try_shuffle_join(p, top, mids, join)
-        return None
+        return _try_shuffle_join(p, top, mids, join) \
+            if isinstance(bcur, DataSource) else None
+    if not _broadcastable(join.right):
+        if join.kind in ("inner", "left") and not join.null_aware:
+            sharded = _sharded_build(join.right, ri,
+                                     join.left.schema.cols[li].dtype)
+        if sharded is None:
+            return rebucketed()
 
     # probe = left subtree: Selection/Projection chain over a DataSource,
     # OR a nested broadcast-joinable join tree (the fragment chain —
@@ -758,6 +861,9 @@ def _try_cop_join(p: LogicalPlan, top, mids, join: LogicalJoin) -> Optional[Phys
 
     # build side: its own (recursive) physical plan, host-materialized
     build_exec = to_physical(join.right)
+    if sharded is not None and not _stays_sharded(build_exec, sharded,
+                                                  builds):
+        return rebucketed()
     bsch = join.right.schema
     build_out_dicts = _subtree_output_dicts(join.right)
 
@@ -787,6 +893,15 @@ def _try_cop_join(p: LogicalPlan, top, mids, join: LogicalJoin) -> Optional[Phys
     if bound is None:
         return None  # generic path handles host agg over host join
     nodew, out_names, out_dtypes, out_dicts, key_meta, host_top = bound
+    if sharded is not None:
+        # with the columns the plan above really reads of the build
+        read = D.build_columns_read(nodew, jnode)
+        carried = sum(r for j, r in enumerate(
+            read if read is not None else (True,) * len(bsch.cols))
+            if j != ri)
+        if _sharded_build(join.right, ri, join.left.schema.cols[li].dtype,
+                          max(carried, 1)) is None:
+            return rebucketed()
 
     if builds and not semi:
         # chain mode has no runtime dictionary reattachment: every string
@@ -834,13 +949,36 @@ def _try_cop_join(p: LogicalPlan, top, mids, join: LogicalJoin) -> Optional[Phys
             out_names=out_names, out_dtypes=out_dtypes, key_meta=key_meta,
             out_dicts=out_dicts, fallback=fallback, probe_est_rows=probe_est,
             probe_key_ndv=key_ndv, record_words=record_words,
-            probe_windows=windows)
+            probe_windows=windows, sharded_build=sharded)
     if host_top is not None and host_top[0] == "topn":
         return HostTopN(exec_, list(host_top[1].keys), host_top[1].limit,
                         host_top[1].offset)
     if host_top is not None:
         return HostLimit(exec_, host_top[1].limit, host_top[1].offset)
     return exec_
+
+
+def _stays_sharded(build_exec, sharded: dict, builds: list) -> bool:
+    """May the build side planned as `build_exec` stay on its devices:
+    a single join (no chain) whose build is a plain rows task over the
+    resident table (the host deals its rows to their owners once a
+    snapshot) or a lookup join's rows over the table the build key is a
+    column of, stored by that key (its result is made into tables where
+    it lies, by every statement)."""
+    from .physical import CopJoinTaskExec
+    if builds:
+        return False
+    table = sharded["key_source"][0]
+    if type(build_exec) is CopTaskExec:
+        return build_exec.table is table \
+            and not isinstance(build_exec.dag, (D.Aggregation, D.TopN,
+                                                D.Limit)) \
+            and getattr(build_exec, "as_of_ts", None) is None
+    return isinstance(build_exec, CopJoinTaskExec) \
+        and build_exec.table is table and sharded["by_key"] \
+        and not build_exec.builds \
+        and build_exec.join_kind in ("inner", "left") \
+        and not isinstance(build_exec.dag, (D.Aggregation, D.TopN, D.Limit))
 
 
 def _probe_windows(dag, table) -> tuple:
@@ -899,8 +1037,9 @@ def _unique_build_key(plan: LogicalPlan, key: int) -> Optional[int]:
     DataSource, the key a plain column of it that a single-column
     primary key or unique index declares unique or that holds no value
     twice in the table's snapshot (ColumnarSnapshot.key_is_unique, kept
-    with the snapshot)."""
-    cur = plan
+    with the snapshot).  A table past the broadcast cap counts where it
+    may stay sharded as a build side (`_sharded_build`)."""
+    cur, key0 = plan, key
     while isinstance(cur, (LogicalSelection, LogicalProjection)):
         if isinstance(cur, LogicalProjection):
             e = cur.exprs[key]
@@ -908,9 +1047,13 @@ def _unique_build_key(plan: LogicalPlan, key: int) -> Optional[int]:
                 return None
             key = e.index
         cur = cur.child
-    if not isinstance(cur, DataSource) or not _broadcastable(cur) \
+    if not isinstance(cur, DataSource) \
+            or getattr(cur.table, "is_memtable", False) \
             or getattr(cur, "as_of_ts", None) is not None:
         return None
+    if not _broadcastable(cur) and _sharded_build(
+            plan, key0, plan.schema.cols[key0].dtype) is None:
+        return None     # past the cap, and no table of it may stay sharded
     table = cur.table
     name = cur.schema.cols[key].name
     declared = list(getattr(table, "primary_key", None) or []) == [name] \

@@ -123,6 +123,86 @@ def all_to_all_exchange(cols: Sequence, valid, keys, n_dev: int,
     return out_cols, recv_valid, overflow, max_count
 
 
+def key_places(keys, part, xp=jnp):
+    """(owner, offset) of each key of `keys` under a range partition of
+    a sharded build side (copr/joinbuild.key_partition): the device that
+    owns the key and the key's slot in that device's table.  `part`:
+    (3, stripes) integers; the key range is cut into stripes at
+    `part[0][1:]` (keys that never decrease: the least build key each
+    stripe holds; a stripe that holds none repeats the next one's),
+    stripe s lies on the device `sum(part[1][:s + 1])` and its keys take
+    the slots from `key - sum(part[2][:s + 1])` on.  Every key has
+    exactly one owner, whether a build row holds it or not: a key in
+    the gap between two stripes' rows is its lower stripe's and finds an
+    empty slot there; a key below the first stripe or past the last
+    lands outside its owner's table (`offset` negative, or at least the
+    table's length: the lookup's bounds check).  Both are sums of
+    `key >= split` terms, a compare and two multiply-adds a stripe and
+    no gather.  Pure; `xp` numpy or jax.numpy, one definition for the
+    host that deals a table's rows out, the device program that makes a
+    join's result into tables and the probe that looks both up."""
+    keys = xp.asarray(keys)
+    own = xp.zeros(keys.shape, keys.dtype) + part[1][0]
+    adj = xp.zeros(keys.shape, keys.dtype) + part[2][0]
+    for s in range(1, part.shape[-1]):
+        ge = (keys >= part[0][s]).astype(keys.dtype)
+        own = own + ge * part[1][s]
+        adj = adj + ge * part[2][s]
+    return own.astype(xp.int32), keys - adj  # valueflow: ok - a device's index, below the mesh's size
+
+
+def exchange_rows(cols: Sequence, live, dest, n_dev: int, capacity: int,
+                  stacked: int = 1, axis: str = SHARD_AXIS):
+    """Inside a shard_map program: the live rows of `cols` [(value,
+    mask | True)] whose `dest` is another device travel there.  Returns
+    (recv_cols, recv_ok, need, sent): `n_dev * capacity` slots of rows
+    the other devices sent this one and which of them hold one; `need`,
+    the capacity this device's fullest bucket takes (above `capacity`
+    rows are missing: the caller reports it and the statement is
+    rerun); `sent`, the rows this device sent.
+
+    A bucket a destination, each filled as a lookup join's live probe
+    rows are compacted (copr/join.live_rows: one column sort of `place |
+    dead` words), the columns packed into 32-bit words once and gathered
+    at every bucket's places in ONE stacked gather (copr/join.pack_rows),
+    the buckets swapped by ONE `lax.all_to_all` of the words and one of
+    the slots' live bits.  No n-sized scatter, no `cumsum` over a
+    one-hot: on a v5e a scatter costs 90 ns an update and the column
+    sort of 2^23 slots 1.13 ms (PERF.md, PR 28).  The bucket to the
+    device itself stays empty: its rows are looked up where they are."""
+    from ..copr.join import _tile_order, live_rows, pack_rows
+    me = lax.axis_index(axis)
+    remote = live & (dest != me)
+    places, oks, need = [], [], jnp.zeros((), jnp.int32)
+    for d in range(n_dev):
+        rows, ok, need_d = live_rows(remote & (dest == d), capacity, stacked)
+        places.append(rows)
+        oks.append(ok)
+        need = jnp.maximum(need, need_d.astype(jnp.int32))  # valueflow: ok - at most the device's slots, below 2^31
+    at = jnp.concatenate(places)
+
+    def taken(x):
+        return x.at[at].get(mode="promise_in_bounds")
+
+    def tiled(x):
+        return _tile_order(x, stacked)
+
+    def swapped(x):
+        x = x.reshape((n_dev, capacity) + x.shape[1:])
+        return lax.all_to_all(x, axis, split_axis=0, concat_axis=0,
+                              tiled=False).reshape((-1,) + x.shape[2:])
+
+    words, _apart, unpack = pack_rows(cols)
+    recv = swapped(taken(jnp.stack([tiled(w) for w in words], axis=1))) \
+        if words else None
+    recv_ok = swapped(jnp.concatenate(oks))
+    _note_payload(n_dev, capacity, n_dev * capacity * (4 * len(words) + 1))
+    got = unpack([recv[:, w] for w in range(len(words))], lambda i: (
+        swapped(taken(tiled(cols[i][0]))),
+        True if cols[i][1] is True else swapped(taken(tiled(cols[i][1])))))
+    return got, recv_ok, need, jnp.sum(remote, dtype=jnp.int32)
+
+
 def broadcast_gather(cols: Sequence, valid, axis: str = SHARD_AXIS):
     """Broadcast exchange: every device receives all rows (lax.all_gather),
     the TPU analog of ExchangeType_Broadcast for small build sides."""
@@ -137,4 +217,4 @@ def broadcast_gather(cols: Sequence, valid, axis: str = SHARD_AXIS):
 
 
 __all__ = ["hash_partition_ids", "all_to_all_exchange", "broadcast_gather",
-           "record_exchange"]
+           "record_exchange", "key_places", "exchange_rows"]

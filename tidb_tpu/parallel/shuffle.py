@@ -209,4 +209,88 @@ def get_shuffle_program(spec: D.ShuffleJoinSpec, mesh,
     return _cached(spec, mesh, caps)
 
 
-__all__ = ["ShuffleCaps", "ShardedShuffleJoinProgram", "get_shuffle_program"]
+@dataclass(frozen=True)
+class TableSpec:
+    """What a `ShardedTableProgram` is compiled for: the rows program's
+    output columns (`key_col` the build key among them, the slots' live
+    mask last), the words' `packing` and the columns' `mins`
+    (copr/joinbuild.table_layout), the tables' `slots`."""
+    key_col: int
+    packing: tuple
+    mins: tuple
+    slots: int
+
+    def children(self):
+        return ()
+
+
+class ShardedTableProgram:
+    """The sharded build side of a lookup join from a join's result that
+    never leaves its devices: the compacted rows a rows-returning
+    program put out on each device (`copr/exec.compact_root`: columns
+    with a leading device axis, the slots' live mask last) -> that
+    device's direct-addressed word tables over the keys it owns (`meta`:
+    (devices, 2), a table's first slot and its slots; `part`: the
+    partition, `parallel/exchange.key_places`), packed as
+    copr/joinbuild.`_row_words` packs a host-made side.
+
+    ONE scatter a word, of the rows the join kept and not of the rows
+    scanned (a scatter on a v5e costs 90 ns an update: PERF.md, PR 28).
+    Beside the tables, per device: the rows written, the slots that hold
+    one (fewer: a key came twice) and the live rows whose key lies
+    outside the device's range (the dispatcher takes another plan where
+    either is off)."""
+
+    def __init__(self, spec: TableSpec, mesh):
+        self.spec = spec
+        self.mesh = mesh
+        self._fn = named_jit(shard_map(
+            self._device_fn, mesh=mesh,
+            in_specs=(P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS)),
+            out_specs=(P(SHARD_AXIS), P(SHARD_AXIS))), "table", spec)
+        self.name = self._fn.__name__
+
+    def _device_fn(self, cols, meta, part):
+        from .exchange import key_places
+        spec = self.spec
+        n_words, _pbit, layout = spec.packing
+        cols = [(v[0], m[0]) for v, m in cols]
+        live = cols[-1][0].astype(bool)
+        kv, km = cols[spec.key_col]
+        kt = jnp.int32 if part.dtype == jnp.int32 \
+            and kv.dtype.itemsize <= 4 else jnp.int64
+        own, d = key_places(kv.astype(kt), part[0].astype(kt))
+        inside = (own == lax.axis_index(SHARD_AXIS)) & (d >= 0) \
+            & (d < meta[0, 1].astype(kt))
+        ok = live & km & inside
+        at = jnp.where(ok, d, spec.slots).astype(jnp.int32)  # valueflow: ok - a written row's offset is below the table's length < 2^31
+        words = [jnp.zeros(kv.shape, jnp.int32) for _ in range(n_words)]
+        words[0] = words[0] | 1             # the presence bit
+        for (w, shift, bits, vbit, _wide), vmin, (v, m) in zip(
+                layout, spec.mins, cols):
+            if w < 0:
+                continue
+            if bits:
+                f = (v.astype(jnp.int64) - vmin).astype(jnp.int32) & ((1 << bits) - 1)  # valueflow: ok - the column's range in its table takes `bits`
+                words[w] = words[w] | (jnp.where(m, f, 0) << shift)
+            if vbit >= 0:
+                words[w] = words[w] | (m.astype(jnp.int32) << vbit)  # valueflow: ok - bool lane, [0, 1]
+        tables = [jnp.zeros((spec.slots,), jnp.int32).at[at].set(
+            word, mode="drop") for word in words]
+        said = jnp.stack([
+            jnp.sum(ok, dtype=jnp.int32),
+            jnp.sum(tables[0] & 1, dtype=jnp.int32),
+            jnp.sum(live & km & ~inside, dtype=jnp.int32)])
+        return tuple(t[None] for t in tables), said[None]
+
+    def __call__(self, out_cols, meta, part):
+        return self._fn(tuple(out_cols), meta, part)
+
+
+@functools.lru_cache(maxsize=64)
+def get_table_program(spec: TableSpec, mesh) -> ShardedTableProgram:
+    return ShardedTableProgram(spec, mesh)
+
+
+__all__ = ["ShuffleCaps", "ShardedShuffleJoinProgram", "get_shuffle_program",
+           "TableSpec", "ShardedTableProgram", "get_table_program"]

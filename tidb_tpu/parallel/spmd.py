@@ -177,7 +177,14 @@ class ShardedCopProgram:
         self.collective_axis = SHARD_AXIS
         self.merge_kind = "host" if self.host_merge else "psum"
 
-        in_specs = (P(SHARD_AXIS), P(SHARD_AXIS), P())  # aux replicated
+        # aux replicated, but the group of a build that stays sharded
+        # where it lives (dag.LookupJoin.sharded): a leading device axis
+        joins = D.lookup_joins(dag_root)
+        self._sharded_aux = frozenset(j.aux_slot for j in joins if j.sharded)
+        aux_specs = P() if not self._sharded_aux else tuple(
+            P(SHARD_AXIS) if slot in self._sharded_aux else P()
+            for slot in range(max(j.aux_slot for j in joins) + 1))
+        in_specs = (P(SHARD_AXIS), P(SHARD_AXIS), aux_specs)
         if self.kind == "agg":
             # per-device states when min/max present; replicated post-psum
             # otherwise
@@ -219,6 +226,11 @@ class ShardedCopProgram:
         flat = [(v, True if m is None else m) for v, m in flat]
         aux = tuple(tuple((v, True if m is None else m) for v, m in grp)
                     for grp in aux)
+        if self._sharded_aux:       # the device's own group: its one row
+            aux = tuple(tuple((v[0], m if m is True else m[0])
+                              for v, m in grp)
+                        if slot in self._sharded_aux else grp
+                        for slot, grp in enumerate(aux))
         ev = Evaluator(jnp, platform=self.platform)
         if self.agg is not None:
             states, batch = agg_states(self.agg, flat, base_sel, ev, aux,
@@ -279,6 +291,15 @@ class ShardedCopProgram:
                 out["probe_capacity"] = max(j.probe_capacity for j in joins)
                 out["match_capacity"] = max(j.match_capacity for j in joins)
                 out["probe_window"] = max(j.probe_window for j in joins)
+            if self._sharded_aux:
+                out["build_sharded"] = len(self._sharded_aux)
+            moved = [j for j in joins if j.exchange]
+            if moved:
+                # only the probe's rows travel: the build stays where
+                # it lives (a program that re-buckets both sides is
+                # parallel/shuffle.py's, and says "both")
+                out["exchange"] = "probe_to_build"
+                out["exchange_capacity"] = max(j.exchange for j in moved)
         return out
 
     def __call__(self, stacked_cols: Sequence, counts, aux_cols=()):

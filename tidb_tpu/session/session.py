@@ -1466,6 +1466,9 @@ class Session:
             order = self._probe_order_footer(phys)
             if order is not None:
                 rows.append((order,))
+            moved = self._join_exchange_footer(phys)
+            if moved is not None:
+                rows.append((moved,))
         return ResultSet(["plan"], rows)
 
     def _cost_footer(self, phys) -> Optional[str]:
@@ -1584,6 +1587,22 @@ class Session:
         except (AttributeError, TypeError, KeyError, ValueError):
             return None
 
+    def _join_exchange_footer(self, phys) -> Optional[str]:
+        """EXPLAIN ``join exchange:`` tag: of each lookup join whose
+        build side is past the broadcast cap and stays on its devices,
+        which side's rows are exchanged and which side stays
+        (CopJoinTaskExec.exchanges).  None where every build is
+        replicated; must never break EXPLAIN."""
+        try:
+            from ..executor.physical import CopJoinTaskExec, _walk
+            n_dev = self.domain.client.mesh.devices.size
+            said = [f"{name} {moves}; {stays}"
+                    for op in _walk(phys) if isinstance(op, CopJoinTaskExec)
+                    for name, moves, stays in op.exchanges(n_dev)]
+            return "join exchange: " + ", ".join(said) if said else None
+        except (AttributeError, TypeError, KeyError, ValueError):
+            return None
+
     def _probe_order_footer(self, phys) -> Optional[str]:
         """EXPLAIN ``probe order:`` tag: the probe keys of the plan's
         lookup joins that ANALYZE found stored in key order, each with
@@ -1599,12 +1618,15 @@ class Session:
         except (AttributeError, TypeError, KeyError, ValueError):
             return None
 
-    def _run_form_footer(self, dag) -> str:
+    def _run_form_footer(self, dag, sharded: bool = False) -> str:
         """What this server's mesh makes of a SORT aggregation: on a
         TPU, copr/runagg's form, the group keys that ride as dependents
         of the others (what the last statement of this digest found of
         its joins' builds: none before one has run) and where the
-        groups are ranked."""
+        groups are ranked: on the device where it holds its groups
+        whole (one device, or `sharded`: the plan's join sends every
+        row to its key's owner and the key is a group key,
+        `dag.groups_whole`)."""
         import dataclasses
 
         from ..copr import dag as Dg
@@ -1625,7 +1647,9 @@ class Session:
         if dag.topn is not None:
             on_device = dag.pack_words and dag.topn.on_device \
                 and dag.topn.limit <= Dg.GROUP_TOPN_MAX \
-                and self.domain.client.mesh.devices.size == 1
+                and (self.domain.client.mesh.devices.size == 1
+                     or (sharded and Dg.groups_whole(
+                         Dg.rewrite_lookup(dag, exchange=1))))
             out += (f", first {dag.topn.limit} groups ranked on the "
                     + ("device" if on_device else "host"))
         return out
@@ -1656,7 +1680,8 @@ class Session:
                     if dag.strategy is Dg.GroupStrategy.SORT:
                         return (f"agg strategy: sort (capacity "
                                 f"{dag.group_capacity or 'auto'}"
-                                f"{self._run_form_footer(dag)})")
+                                + self._run_form_footer(dag, bool(getattr(
+                                    op, "sharded_build", None))) + ")")
                     return (f"agg strategy: dense "
                             f"({dag.num_groups} groups)")
                 for c in getattr(op, "children", []) or []:

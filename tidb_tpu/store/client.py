@@ -44,7 +44,8 @@ DEFAULT_GROUP_CAPACITY = 4096
 # what a join program says of a launch beside its outputs (the extras
 # of copr/exec `_compact_probe` and `_exec_lookup_join`), fetched with
 # the outputs (`_fetch_states`)
-_JOIN_REPORTS = ("join_live", "join_need", "join_window_miss")
+_JOIN_REPORTS = ("join_live", "join_need", "join_window_miss",
+                 "exchange_need", "exchange_sent")
 
 
 @dataclass
@@ -348,6 +349,11 @@ class CopClient:
                 return out
             if "join_live" in probe:
                 _obs_annotate(probe_live=int(np.sum(probe["join_live"])))
+            if "exchange_sent" in probe:
+                # the most any device sent to the devices that own its
+                # probe rows' keys (copr/exec `_sharded_lookup`)
+                _obs_annotate(exchange_rows_sent=int(
+                    np.max(probe["exchange_sent"])))
             return out, probe
 
     def _fetch_states(self, dag, out, extras: dict):
@@ -744,12 +750,14 @@ class CopClient:
 
     def _group_form(self, agg: D.Aggregation) -> D.Aggregation:
         """`agg` as this mesh launches it: its groups ranked by the host
-        where a group's rows lie on several devices, and its exact
+        where a group's rows lie on several devices (they do not where a
+        join beneath sends every row to the device that owns its group
+        key: `dag.groups_whole`), and its exact
         record (copr/runagg) as wide as a statement of this digest has
         needed before."""
         import dataclasses
         if agg.topn is not None and agg.topn.on_device \
-                and self.mesh.devices.size > 1:
+                and self.mesh.devices.size > 1 and not D.groups_whole(agg):
             agg = dataclasses.replace(agg, topn=dataclasses.replace(
                 agg.topn, on_device=False))
         if agg.pack_words and self._record_words:
@@ -817,6 +825,11 @@ class CopClient:
         if "join_window_miss" in said \
                 and int(np.sum(said["join_window_miss"])):
             return self._unwindowed(dag)
+        if "exchange_need" in said:
+            grown = self._exchange_regrown(
+                dag, int(np.max(said["exchange_need"])))
+            if grown is not None:
+                return grown
         if "join_need" in said:
             return self._uncompacted(dag, int(np.max(said["join_need"])))
         return None
@@ -838,6 +851,21 @@ class CopClient:
         if need <= D.compact_capacity(D.compacting_join(dag)):
             return None
         return self._remembered(dag, "join_compact_overflows", D.uncompacted)
+
+    def _exchange_regrown(self, dag, need: int) -> Optional[D.CopNode]:
+        """If a device had more rows for one destination than a bucket
+        of the join's exchange holds (some are missing from this
+        launch's result), the DAG with buckets of what the devices
+        found and a quarter more; None when they fit.  The digest
+        remembers its last finding (`_join_form`), as a group table's
+        capacity is remembered (`_group_caps`)."""
+        join = D.exchanging_join(dag)
+        if join is None or need <= join.exchange:
+            return None
+        cap = D.exchange_capacity_round(need + need // 4)
+        return self._remembered(
+            dag, "exchange_overflows", lambda d: D.rewrite_lookup(
+                d, pred=lambda j: 0 < j.exchange < cap, exchange=cap))
 
     def _unwindowed(self, dag) -> D.CopNode:
         """A lookup read by windows found a live row outside its window
@@ -1121,11 +1149,38 @@ class CopClient:
         return self._retry(lambda: self._execute_rows_once(
             root, snap, out_dtypes, dictionaries, aux_cols), snap=snap)
 
+    def execute_rows_resident(self, root: D.CopNode, snap: ColumnarSnapshot,
+                              aux_cols=()):
+        """A rows-returning plan whose rows stay on their devices: the
+        program's output columns as it put them out (a leading device
+        axis, `capacity` slots a device, the slots' live mask last:
+        copr/exec `compact_root`), unfetched; None where the table is
+        streamed in batches.  The paging loop fetches the live counts
+        alone.  A lookup join's sharded build side is made from them
+        (`sharded_tables`)."""
+        return self._retry(lambda: self._execute_rows_once(
+            root, snap, None, None, aux_cols, resident=True), snap=snap)
+
+    def sharded_tables(self, out_cols, spec, meta, part):
+        """The direct-addressed word tables of a lookup join's sharded
+        build side, made on the devices from the rows
+        `execute_rows_resident` left there (parallel/shuffle
+        `ShardedTableProgram`): (tables with a leading device axis, per
+        device [rows written, slots that hold one, live rows outside the
+        device's key range])."""
+        from ..parallel.shuffle import get_table_program
+        prog = get_table_program(spec, self.mesh)
+        tables, said = self._launch_opaque(
+            lambda: prog(out_cols, meta, part), program=prog.name)
+        return tables, np.asarray(self._fetch(said))
+
     def _execute_rows_once(self, root: D.CopNode, snap: ColumnarSnapshot,
                            out_dtypes, dictionaries=None,
-                           aux_cols=()) -> list[Column]:
+                           aux_cols=(), resident: bool = False):
         """Row-returning plan with the paging loop."""
         batches = self._stream_batches(root, snap, aux_cols)
+        if batches is not None and resident:
+            return None
         if batches is not None:
             # per-batch results concatenate; TopN/Limit callers already
             # re-trim the multi-device candidate union, batches just widen
@@ -1183,6 +1238,16 @@ class CopClient:
                 # a lookup read by windows lost a row: the gather form
                 root = self._unwindowed(root)
                 continue
+            if prog.has_extras and "exchange_need" in extras:
+                # a bucket of the join's exchange did not fit: larger
+                _n, said = self._fetch(None, {
+                    k: extras[k] for k in ("exchange_need",
+                                           "exchange_sent")})
+                grown = self._exchange_regrown(
+                    root, int(np.max(said["exchange_need"])))
+                if grown is not None:
+                    root = grown
+                    continue
             if is_topn or is_limit:
                 break       # the capacity is the limit: nothing to regrow
             out_counts = np.asarray(self._fetch(out_counts))
@@ -1203,6 +1268,8 @@ class CopClient:
                 self._page_feedback.move_to_end(fb_key)
                 while len(self._page_feedback) > self._page_feedback_cap:
                     self._page_feedback.popitem(last=False)
+        if resident:
+            return out_cols, cap
         return self._assemble_rows(out_cols, out_counts, cap, out_dtypes,
                                    dictionaries)
 

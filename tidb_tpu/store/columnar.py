@@ -82,6 +82,35 @@ class ColumnarSnapshot:
             self._unique_keys[offset] = known
         return known
 
+    def key_is_ascending(self, offset: int) -> bool:
+        """Column `offset` has no NULL and every row's value is above
+        the row's before it: the table is stored by that key, so the
+        rows a device holds cover a key range no other device's do (a
+        lookup join's build side may then stay where it lives:
+        executor/plan.which_side_moves).  Kept with the snapshot."""
+        key = ("asc", offset)
+        known = self._unique_keys.get(key)
+        if known is None:
+            c = self.columns[offset]
+            known = bool(c.data.dtype.kind in "iu" and c.validity.all()
+                         and (c.data[1:] > c.data[:-1]).all())
+            self._unique_keys[key] = known
+        return known
+
+    def device_stripes(self, n_dev: int) -> list:
+        """(first row, end row, device) of each shard, in row order, as
+        `_put` lays the table out over `n_dev` devices: under a
+        placement a shard lies on its store's device (`store % n_dev`),
+        else the shards are dealt in order, as many a device.  A table
+        stored by a key is so many stripes of ascending keys
+        (copr/joinbuild.key_partition)."""
+        if self.placement is not None:
+            return sorted((s.lo, s.hi, s.store % n_dev)
+                          for s in self.placement.shards)
+        ranges = self._even_ranges()
+        per = -(-len(ranges) // n_dev)
+        return [(lo, hi, i // per) for i, (lo, hi) in enumerate(ranges)]
+
     def key_range(self, offset: int) -> tuple[int, int]:
         """(least, largest) non-NULL value of integer column `offset`;
         (0, -1) where it has none or is no integer column.  Kept with

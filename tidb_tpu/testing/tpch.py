@@ -261,11 +261,14 @@ def tpch_plan_session(sf: float = 0.001, n_orders: int = 512):
 
 # planned with the broadcast threshold forced to 0 so the repartition
 # (all_to_all shuffle) join path is exercised by the gate too
+# (a key that comes twice on both sides: no table of the build to own,
+# so under a zeroed broadcast cap both sides are re-bucketed; a unique
+# build key past the cap stays sharded instead: plan.which_side_moves)
 TPCH_SHUFFLE_QUERIES = [
     """select count(*), sum(l_quantity + o_totalprice) from lineitem
-       join orders on l_orderkey = o_orderkey""",
+       join orders on l_linenumber = o_custkey""",
     """select o_custkey, sum(l_quantity) from lineitem join orders
-       on l_orderkey = o_orderkey group by o_custkey""",
+       on l_linenumber = o_custkey group by o_custkey""",
 ]
 
 
@@ -312,7 +315,7 @@ def built_multichip_plans(session):
         yield from built_tpch_plans(
             session, ["""select count(*), sum(l_extendedprice)
                          from lineitem, part
-                         where p_partkey = l_partkey and p_size < 25"""])
+                         where p_size = l_linenumber and p_size < 25"""])
     finally:
         planmod.BROADCAST_BUILD_MAX_ROWS = saved
 
