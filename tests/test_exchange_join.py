@@ -159,9 +159,15 @@ def test_the_sharded_form_asks_a_table_of_every_device():
 # --------------------------------------------------------------------- #
 
 N_DEV, SLOTS = 4, 2048
+# a device's slots and a bucket's capacity under the half-of-n rule (the
+# buckets to the other devices take 6,144 of 16,384 slots: two levels,
+# ONE sort of all slots) and over it (every bucket as long as the
+# slots: a sort of all slots a destination, the form before PR 36)
+FORMS = {"two_levels": (16384, 2048), "sort_a_destination": (SLOTS, SLOTS)}
+COLS = D.COMPACT_COLUMNS
 
 
-def _exchange(vals, nullable, live, dest, capacity):
+def _exchange(vals, nullable, live, dest, capacity, stacked=1):
     """Run `exchange_rows` over the four-device mesh: `vals` (devices,
     slots) int64, `nullable` the same shape or None, `live`, `dest`;
     -> what each device received, per device."""
@@ -171,7 +177,7 @@ def _exchange(vals, nullable, live, dest, capacity):
         cols = [(v[0], True if m is None else m[0]),
                 ((v[0] * 3).astype(jnp.int32), True)]
         got, ok, need, sent = E.exchange_rows(
-            cols, lv[0], ds[0], N_DEV, capacity)
+            cols, lv[0], ds[0], N_DEV, capacity, stacked)
         return ([(g[None], jnp.ones(g.shape, bool)[None]
                   if gm is True else gm[None]) for g, gm in got],
                 ok[None], need[None], sent[None])
@@ -183,30 +189,19 @@ def _exchange(vals, nullable, live, dest, capacity):
     return jax.tree_util.tree_map(np.asarray, out)
 
 
-@pytest.mark.parametrize("mix", ["some", "none", "all", "one_takes_all",
-                                 "all_stay"])
-def test_the_exchange_equals_a_numpy_partition(mix):
-    """Every live/dead mix: device d receives exactly the live rows the
-    other devices hold with destination d (as a multiset: a bucket's
-    rows lie in no order), NULL masks and both columns with them; rows
-    whose destination is their own device do not travel."""
-    rng = np.random.default_rng(35)
-    vals = rng.integers(-2 ** 40, 2 ** 40, (N_DEV, SLOTS))
-    nullable = rng.random((N_DEV, SLOTS)) < 0.9
-    dest = rng.integers(0, N_DEV, (N_DEV, SLOTS)).astype(np.int32)
-    live = {"some": rng.random((N_DEV, SLOTS)) < 0.3,
-            "none": np.zeros((N_DEV, SLOTS), bool),
-            "all": np.ones((N_DEV, SLOTS), bool),
-            "one_takes_all": np.ones((N_DEV, SLOTS), bool),
-            "all_stay": np.ones((N_DEV, SLOTS), bool)}[mix]
-    if mix == "one_takes_all":
-        dest[:] = 2
-    if mix == "all_stay":
-        dest[:] = np.arange(N_DEV)[:, None]
-    got, ok, need, sent = _exchange(vals, nullable, live, dest, SLOTS)
-    away = live & (dest != np.arange(N_DEV)[:, None])
-    assert sent.tolist() == away.sum(axis=1).tolist()
-    assert (need <= SLOTS).all()
+def _need(away, dest):
+    """What `need` has to say, by numpy: a device's fullest (column,
+    destination) count times the columns; a slot's column is its index
+    mod COMPACT_COLUMNS, however the slots are stacked."""
+    lane = np.arange(away.shape[1]) % COLS
+    return [max(int(np.bincount(lane[away[s] & (dest[s] == d)],
+                                minlength=COLS).max())
+                for d in range(N_DEV)) * COLS for s in range(N_DEV)]
+
+
+def _arrived(got, ok, vals, nullable, away, dest):
+    """Device d holds exactly the rows the others had for it, each in
+    one slot (as a multiset: a bucket's rows lie in no order)."""
     for d in range(N_DEV):
         want = sorted(
             (int(vals[s, i]), bool(nullable[s, i]),
@@ -216,23 +211,194 @@ def test_the_exchange_equals_a_numpy_partition(mix):
         take = np.nonzero(ok[d])[0]
         have = sorted((int(got[0][0][d][i]), bool(got[0][1][d][i]),
                        int(got[1][0][d][i])) for i in take)
-        assert have == want, (mix, d)
+        assert have == want, d
 
 
-def test_a_bucket_past_its_capacity_says_what_it_takes():
-    """Rows that do not fit are reported, not silently dropped: `need`
-    is above the capacity, and at the capacity it names every row
-    arrives."""
+@pytest.mark.parametrize("mix, form, stacked", [
+    ("some", "sort_a_destination", 1), ("none", "sort_a_destination", 1),
+    ("all", "sort_a_destination", 1),
+    ("one_takes_all", "sort_a_destination", 1),
+    ("all_stay", "sort_a_destination", 1),
+    ("some", "sort_a_destination", 2),
+    ("few", "two_levels", 1), ("few", "two_levels", 2),
+    ("few", "two_levels", 8), ("none", "two_levels", 1),
+    ("all_stay", "two_levels", 2), ("one_takes_few", "two_levels", 1)])
+def test_the_exchange_equals_a_numpy_partition(mix, form, stacked):
+    """Every live/dead mix, in both forms the buckets are made in and
+    with the slots in stacked runs: device d receives exactly the live
+    rows the other devices hold with destination d, each in exactly one
+    slot, NULL masks and both columns with them; rows whose destination
+    is their own device do not travel; `need` and `sent` are numpy's
+    counts."""
+    slots, capacity = FORMS[form]
+    assert E.exchange_passes(slots, N_DEV, capacity) \
+        == (1 if form == "two_levels" else N_DEV)
+    rng = np.random.default_rng(35)
+    vals = rng.integers(-2 ** 40, 2 ** 40, (N_DEV, slots))
+    nullable = rng.random((N_DEV, slots)) < 0.9
+    dest = rng.integers(0, N_DEV, (N_DEV, slots)).astype(np.int32)
+    live = {"some": rng.random((N_DEV, slots)) < 0.3,
+            "few": rng.random((N_DEV, slots)) < 0.12,
+            "one_takes_few": rng.random((N_DEV, slots)) < 0.04,
+            "none": np.zeros((N_DEV, slots), bool),
+            "all": np.ones((N_DEV, slots), bool),
+            "one_takes_all": np.ones((N_DEV, slots), bool),
+            "all_stay": np.ones((N_DEV, slots), bool)}[mix]
+    if mix in ("one_takes_all", "one_takes_few"):
+        dest[:] = 2
+    if mix == "all_stay":
+        dest[:] = np.arange(N_DEV)[:, None]
+    got, ok, need, sent = _exchange(vals, nullable, live, dest, capacity,
+                                    stacked)
+    away = live & (dest != np.arange(N_DEV)[:, None])
+    assert sent.tolist() == away.sum(axis=1).tolist()
+    assert need.tolist() == _need(away, dest) and (need <= capacity).all()
+    _arrived(got, ok, vals, nullable, away, dest)
+
+
+def _past(level):
+    """(slots, capacity, live, dest): a bucket past its capacity where
+    `level` says.  `first`: two levels, a column holds more remote rows
+    than the first sort keeps; `second`: two levels, the first sort
+    keeps every remote row (20 of a column's 128, 48 are kept) and one
+    destination's do not fit its bucket (16 a column); `sort_a_
+    destination`: the form over the half-of-n rule."""
+    slots, capacity = {"first": (SLOTS, 256), "second": (16384, 2048),
+                       "sort_a_destination": (SLOTS, 512)}[level]
+    live = np.ones((N_DEV, slots), bool)
+    if level == "second":
+        live[:, 20 * COLS:] = False
+    return slots, capacity, live, np.full((N_DEV, slots), 1, np.int32)
+
+
+@pytest.mark.parametrize("level", ["first", "second", "sort_a_destination"])
+def test_a_bucket_past_its_capacity_says_what_it_takes(level):
+    """Rows that do not fit are reported, not silently dropped, at
+    either level of the two-level form and in the other: `need` is
+    above the capacity and exact, and at the capacity it names every
+    row arrives."""
+    slots, capacity, live, dest = _past(level)
+    assert E.exchange_passes(slots, N_DEV, capacity) \
+        == (N_DEV if level == "sort_a_destination" else 1)
     rng = np.random.default_rng(36)
-    vals = rng.integers(0, 2 ** 31, (N_DEV, SLOTS))
-    live = np.ones((N_DEV, SLOTS), bool)
-    dest = np.full((N_DEV, SLOTS), 1, np.int32)
-    _got, ok, need, sent = _exchange(vals, None, live, dest, 256)
-    assert need.max() > 256 and ok[1].sum() < 3 * SLOTS
+    vals = rng.integers(0, 2 ** 31, (N_DEV, slots))
+    rows = int(live[0].sum())
+    away = live & (dest != np.arange(N_DEV)[:, None])
+    _got, ok, need, sent = _exchange(vals, None, live, dest, capacity)
+    assert need.max() > capacity and ok[1].sum() < 3 * rows
+    assert need.tolist() == _need(away, dest)
     exact = D.exchange_capacity_round(int(need.max()))
-    _got, ok, need2, _sent = _exchange(vals, None, live, dest, exact)
-    assert need2.max() <= exact and ok[1].sum() == 3 * SLOTS
-    assert sent.tolist() == [SLOTS, 0, SLOTS, SLOTS]
+    got, ok, need2, _sent = _exchange(vals, None, live, dest, exact)
+    assert need2.tolist() == need.tolist() and need2.max() <= exact \
+        and ok[1].sum() == 3 * rows
+    assert sent.tolist() == [rows, 0, rows, rows]
+    _arrived(got, ok, vals, np.ones(vals.shape, bool), away, dest)
+
+
+def _live_rows_pr28(sel, capacity, stacked=1):
+    """`copr/join.live_rows` as PR 28 wrote it and every compaction
+    that does not exchange still has to trace it, word for word."""
+    from jax import lax
+    from tidb_tpu.copr.join import _tile_order
+    n = sel.shape[0]
+    cols = COLS
+    assert n % cols == 0 and capacity % cols == 0, (n, capacity)
+    bit = max(n - 1, 1).bit_length()
+    wt = jnp.int32 if bit < 31 else jnp.int64
+    if stacked == 1 or n % (stacked * cols):
+        stacked = 1
+    run, tile, lane = (lax.broadcasted_iota(
+        wt, (stacked, n // stacked // cols, cols), d) for d in range(3))
+    places = ((tile * stacked + run) * cols + lane).reshape(n)
+    words = _tile_order(jnp.where(sel, places, places | (1 << bit)),
+                        stacked).reshape(n // cols, cols)
+    top = lax.optimization_barrier(lax.sort(
+        words, dimension=0, is_stable=False)[:capacity // cols].reshape(-1))
+    need = jnp.max(jnp.sum((words >> bit) == 0, axis=0,
+                           dtype=jnp.int32)) * cols
+    return top & ((1 << bit) - 1), (top >> bit) == 0, need
+
+
+def _sorts(jaxpr) -> list:
+    """The operand shape of every `sort` of a jaxpr, inner ones too."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "sort":
+            out.append(tuple(eqn.invars[0].aval.shape))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += _sorts(sub)
+    return sorted(out)
+
+
+@pytest.mark.parametrize("slots, capacity, stacked, share", [
+    (16384, 2048, 1, 0.1), (16384, 2048, 2, 0.1), (16384, 2048, 8, 0.02),
+    (16384, 1024, 1, 0.2),      # past the capacity at the second level
+    (16384, 1024, 2, 0.9),      # ... and at the first
+    (1 << 17, 4096, 4, 0.05),
+    (SLOTS, SLOTS, 1, 0.5)])    # over the rule: the same code as before
+def test_a_bucket_holds_the_same_place_in_the_same_slot_in_both_forms(
+        slots, capacity, stacked, share):
+    """Bit for bit against a sort of all slots a destination
+    (`live_rows` as it was): `need` always; where it is within the
+    capacity, which slots hold a row and the place each holds; every
+    other slot holds a place in bounds."""
+    rng = np.random.default_rng(slots + stacked)
+    remote = rng.random(slots) < share
+    dest = rng.integers(0, N_DEV, slots).astype(np.int32)
+    remote &= dest != 3         # the device's own bucket stays empty
+    places, oks, need = jax.jit(
+        lambda r, d: E._bucket_places(r, d, N_DEV, capacity, stacked))(
+        remote, dest)
+    then = [tuple(np.asarray(x) for x in _live_rows_pr28(
+        jnp.asarray(remote & (dest == d)), capacity, stacked))
+        for d in range(N_DEV)]
+    assert int(need) == max(int(need_d) for _at, _ok, need_d in then)
+    assert (int(need) > capacity) == (share >= 0.2 and slots == 16384)
+    for got, got_ok, (at, ok, _need_d) in zip(places, oks, then):
+        got = np.asarray(got)
+        assert ((got >= 0) & (got < slots)).all()
+        if int(need) <= capacity:
+            assert (np.asarray(got_ok) == ok).all()
+            assert (got[ok] == at[ok]).all()
+
+
+def test_a_colocated_exchange_sorts_its_slots_once():
+    """What is traced: under the half-of-n rule ONE sort over a device's
+    n slots, and one a destination over the slots it kept; over the
+    rule one over all slots a destination.  `live_rows`, the compaction
+    of every program that does not exchange (Q14's, Q19's and Q12's
+    probe rows, the matched rows, a rows-returning root), traces what
+    it traced before, word for word."""
+    from tidb_tpu.copr.join import live_rows
+
+    def buckets(slots, capacity):
+        return _sorts(jax.make_jaxpr(lambda r, d: E._bucket_places(
+            r, d, N_DEV, capacity, 2))(
+            jnp.zeros(slots, bool), jnp.zeros(slots, jnp.int32)).jaxpr)
+    rows = 16384 // COLS
+    assert buckets(16384, 2048) == [(3 * 2048 // COLS, COLS)] * N_DEV \
+        + [(rows, COLS)]
+    # at three quarters of the slots the two forms cost the same: the
+    # choice is made at a half
+    assert buckets(16384, 16384 // 6 // COLS * COLS) \
+        == [(3 * 2688 // COLS, COLS)] * N_DEV + [(rows, COLS)]
+    assert buckets(16384, 2816) == [(rows, COLS)] * N_DEV
+    for stacked in (1, 8):
+        now, then = (str(jax.make_jaxpr(lambda s: fn(s, 1024, stacked))(
+            jnp.zeros(8192, bool))) for fn in (live_rows, _live_rows_pr28))
+        assert now == then
+
+
+@pytest.mark.parametrize("n, n_dev, capacity, want", [
+    (1 << 24, 4, 196_608, 1),       # `q3_x4`: 589,824 of 2^24 slots kept
+    (655_360, 4, 16_384, 1),        # `q12_x4`, after its probe compaction
+    (1 << 24, 4, 2_621_440, 1),     # keys that lie anywhere: 47 %
+    (1 << 24, 4, 2_796_202, 1), (1 << 24, 4, 2_796_203, 4),
+    (3072, 4, 1024, 4),             # the least bucket, a small table
+    (1 << 24, 8, 196_608, 1), (1 << 20, 2, 1 << 19, 1),
+    (1 << 20, 2, (1 << 19) + 128, 2)])
+def test_the_form_is_chosen_from_two_shapes(n, n_dev, capacity, want):
+    assert E.exchange_passes(n, n_dev, capacity) == want
 
 
 # --------------------------------------------------------------------- #
@@ -326,8 +492,9 @@ def past_the_cap(monkeypatch):
 
 
 COUNTERS = ("join_launches", "join_direct_launches", "join_exchange_launches",
-            "join_sharded_build_launches", "exchange_overflows",
-            "join_host_fallbacks", "join_shuffle_launches",
+            "join_exchange_onepass_launches", "join_sharded_build_launches",
+            "exchange_overflows", "join_host_fallbacks",
+            "join_shuffle_launches",
             "join_compact_overflows", "join_window_overflows",
             "hndv_agg_regrows", "hndv_agg_launches", "rows_regrows",
             "group_topn_device_launches", "hndv_host_topn_launches")
@@ -379,12 +546,20 @@ def test_spec_text_equals_the_reference(tpch, lowered_for, past_the_cap,
             if devices > 1:
                 assert a["exchange"] == "probe_to_build" \
                     and a["exchange_capacity"] % D.COMPACT_COLUMNS == 0
+                # Q3 exchanges a device's 32,768 scanned slots in
+                # buckets of 2,048: one sort of them; Q12 the 3,072
+                # slots it compacted its probe rows to, of which three
+                # of the least bucket (1,024) are all: a sort a bucket
+                assert a["exchange_passes"] == E.exchange_passes(
+                    a.get("probe_capacity") or a["probe_rows"] // devices,
+                    devices, a["exchange_capacity"]) \
+                    == (1 if name == "q3" else devices)
                 sent = [t["exchange_rows_sent"]
                         for t in _spans(sess, "cop.transfer")
                         if "exchange_rows_sent" in t]
                 assert sent and sent[-1] <= a["exchange_capacity"] * devices
             else:
-                assert "exchange" not in a
+                assert "exchange" not in a and "exchange_passes" not in a
             builds = _spans(sess, "cop.join_build")
             assert [b for b in builds if b.get("sharded")] and all(
                 b["form"] == "direct" for b in builds)
@@ -406,6 +581,8 @@ def test_spec_text_equals_the_reference(tpch, lowered_for, past_the_cap,
     assert moved["join_direct_launches"] == moved["join_launches"] > 0
     assert moved["join_sharded_build_launches"] == 3
     assert moved["join_exchange_launches"] == (3 if devices > 1 else 0)
+    assert moved["join_exchange_onepass_launches"] \
+        == (3 if devices > 1 and name == "q3" else 0)
     assert not moved["join_host_fallbacks"] + moved["join_shuffle_launches"]
     if name == "q3":
         assert moved["group_topn_device_launches"] == 3 \
@@ -628,9 +805,11 @@ def test_a_bucket_that_overflows_costs_one_rerun_and_is_remembered(
         out = []
         for _ in range(2):
             rows = sess.execute(sql).rows
-            out.append((tuple(int(x) for x in rows[0]), [
-                a["exchange_capacity"] for a in _spans(sess, "sched.launch")
-                if "exchange" in a]))
+            launches = [a for a in _spans(sess, "sched.launch")
+                        if "exchange" in a]
+            out.append((tuple(int(x) for x in rows[0]),
+                        [a["exchange_capacity"] for a in launches],
+                        [a["exchange_passes"] for a in launches]))
         return out
     (first, second), moved = _run(dom, 4, twice)
     assert first[0] == want and second[0] == want
@@ -638,6 +817,11 @@ def test_a_bucket_that_overflows_costs_one_rerun_and_is_remembered(
         and first[1][1] > 1024
     assert second[1] == [first[1][1]]
     assert moved["exchange_overflows"] == 1
+    # the guess is under the half-of-n rule (3 x 1,024 of 16,384 slots),
+    # what the devices found is over it: each run in its own form
+    assert first[2] == [1, 4] and second[2] == [4]
+    assert (moved["join_exchange_launches"],
+            moved["join_exchange_onepass_launches"]) == (3, 1)
 
 
 def test_the_exchange_s_buffers_are_what_the_trace_records():
@@ -658,15 +842,19 @@ def test_the_exchange_s_buffers_are_what_the_trace_records():
 
 
 def test_the_new_facts_are_rows_of_the_table():
-    assert {"exchange", "exchange_capacity", "build_sharded"} <= set(F.FACTS)
-    assert {"join_exchange_launches", "join_sharded_build_launches",
-            "exchange_overflows", "join_shuffle_launches"} \
-        <= set(F.counter_names())
+    assert {"exchange", "exchange_capacity", "exchange_passes",
+            "build_sharded"} <= set(F.FACTS)
+    assert {"join_exchange_launches", "join_exchange_onepass_launches",
+            "join_sharded_build_launches", "exchange_overflows",
+            "join_shuffle_launches"} <= set(F.counter_names())
     said = {"exchange": "probe_to_build", "exchange_capacity": 4096,
-            "build_sharded": 1}
+            "build_sharded": 1, "exchange_passes": 4}
     assert set(F.counters(said)) == {"join_exchange_launches",
                                      "join_sharded_build_launches"}
     assert F.span_attrs(said) == said
+    assert set(F.counters({**said, "exchange_passes": 1})) == {
+        "join_exchange_launches", "join_exchange_onepass_launches",
+        "join_sharded_build_launches"}
     fields = {f.name: f for f in dataclasses.fields(D.LookupJoin)}
     for name in ("sharded", "exchange"):
         assert fields[name].metadata == D.DIGEST_IF_SET

@@ -569,12 +569,15 @@ class LookupJoin(CopNode):
     # `sharded` on a mesh of several devices: the slots of one bucket a
     # destination.  A live probe row whose key another device owns
     # travels there (`parallel/exchange.exchange_rows`: one column sort
-    # and one gather a destination, one all-to-all) and is looked up
-    # where the table is; a row whose key the device owns itself is
-    # looked up in place.  Rows that do not fit are never dropped: the
-    # program reports the capacity they take (extras `exchange_need`)
-    # and the dispatcher reruns the statement with it.  0 = nothing is
-    # exchanged (one device).
+    # of the device's slots where the buckets together take at most half
+    # of them, 3.6 ms for 2^24 slots on a v5e, else one a destination,
+    # 40.8; then a short sort a destination over the slots kept, one
+    # stacked gather, one all-to-all) and is looked up where the table
+    # is; a row whose key the device owns itself is looked up in place.
+    # Rows that do not fit are never dropped: the program reports the
+    # capacity they take (extras `exchange_need`) and the dispatcher
+    # reruns the statement with it.  0 = nothing is exchanged (one
+    # device).
     exchange: int = field(default=0, metadata=DIGEST_IF_SET)
 
     def children(self):

@@ -872,7 +872,8 @@ def _sharded_lookup(node: D.LookupJoin, batch: DeviceBatch, ev: Evaluator,
     whole).  Extras: `exchange_need`, the slots the fullest bucket
     takes (rows are missing above the capacity), and `exchange_sent`.
     With `exchange` 0 (one device) nothing travels."""
-    from ..parallel.exchange import exchange_rows, key_places
+    from ..parallel.exchange import (exchange_passes, exchange_rows,
+                                     key_places)
     from ..parallel.mesh import SHARD_AXIS
     from .join import _compare_narrow, direct_lookup
     n = len(batch.cols[0][0])
@@ -896,6 +897,7 @@ def _sharded_lookup(node: D.LookupJoin, batch: DeviceBatch, ev: Evaluator,
         n_dev = lax.axis_size(SHARD_AXIS)
         cap = min(node.exchange, n)     # a bucket never holds more than all
         probe = [(_ensure_array(v, n), m) for v, m in batch.cols]
+        batch.facts["exchange_passes"] = exchange_passes(n, n_dev, cap)
         with jax.named_scope("join_exchange"):
             dest, at = key_places(kv.astype(kt), part)
             rcols, rok, need, sent = exchange_rows(

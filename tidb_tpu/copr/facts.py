@@ -33,6 +33,10 @@ def _rows_root(root) -> bool:
                                  D.FusedDag))
 
 
+def _exchanges(root) -> bool:
+    return D.exchanging_join(root) is not None
+
+
 def _host_merged(root) -> bool:
     return isinstance(root, D.Aggregation) \
         and root.strategy in D.HOST_MERGE_STRATEGIES
@@ -111,6 +115,12 @@ FACTS = {
     "exchange": Fact(counters=(("join_exchange_launches", _present),),
                      merge=_first, on_span=_present),
     "exchange_capacity": Fact(on_span=_present),
+    # `exec._sharded_lookup`, written where the exchange is traced: the
+    # column sorts over all of a device's slots its buckets are made
+    # with (`parallel/exchange.exchange_passes`: 1, or one a device)
+    "exchange_passes": Fact(
+        counters=(("join_exchange_onepass_launches", lambda p: p == 1),),
+        on_span=_present, root=_exchanges),
     # `exec.compact_root`, the root of a rows-returning program: the
     # slots a device hands its live rows to the host in, and whether
     # they got there by the column sort (1) or by the scatter (0)

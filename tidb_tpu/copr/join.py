@@ -185,6 +185,19 @@ def _run_order(x, stacked: int):
                      COMPACT_COLUMNS).transpose(1, 0, 2).reshape(n)
 
 
+def _slot_places(n: int, stacked: int, wt):
+    """Every slot's place (its index among the n slots in `_tile_order`)
+    as `wt`, slot by slot in the order the callers' masks come in (run by
+    run), computed where the slot is: the words made of it, not a
+    narrower mask, are what `_tile_order` then views for free."""
+    cols = COMPACT_COLUMNS
+    if stacked == 1 or n % (stacked * cols):
+        stacked = 1
+    run, tile, lane = (lax.broadcasted_iota(
+        wt, (stacked, n // stacked // cols, cols), d) for d in range(3))
+    return ((tile * stacked + run) * cols + lane).reshape(n)
+
+
 def live_rows(sel, capacity: int, stacked: int = 1):
     """(rows, ok, need): `capacity` slots that hold the place of every
     live row of `sel` (`ok`: the slot holds one; the others hold places
@@ -217,13 +230,7 @@ def live_rows(sel, capacity: int, stacked: int = 1):
     assert n % cols == 0 and capacity % cols == 0, (n, capacity)
     bit = max(n - 1, 1).bit_length()
     wt = jnp.int32 if bit < 31 else jnp.int64
-    if stacked == 1 or n % (stacked * cols):
-        stacked = 1
-    # a slot's place, computed where the slot is (the words, not the
-    # narrower `sel`, are what `_tile_order` views for free)
-    run, tile, lane = (lax.broadcasted_iota(
-        wt, (stacked, n // stacked // cols, cols), d) for d in range(3))
-    places = ((tile * stacked + run) * cols + lane).reshape(n)
+    places = _slot_places(n, stacked, wt)
     words = _tile_order(jnp.where(sel, places, places | (1 << bit)),
                         stacked).reshape(n // cols, cols)
     # the barrier keeps the flat form: without it XLA moves the reshape

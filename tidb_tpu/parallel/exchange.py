@@ -151,6 +151,76 @@ def key_places(keys, part, xp=jnp):
     return own.astype(xp.int32), keys - adj  # valueflow: ok - a device's index, below the mesh's size
 
 
+def exchange_passes(n: int, n_dev: int, capacity: int) -> int:
+    """The column sorts over all n slots of a device that
+    `exchange_rows` makes its `n_dev` buckets of `capacity` slots with:
+    1 where the buckets to the other devices together take at most half
+    of the slots (the two levels of `_bucket_places`), else one a
+    destination.  Two levels cost one sort of n slots and `n_dev` of
+    `(n_dev - 1) * capacity`: as much as `n_dev` sorts of n at three
+    quarters of n.  Pure: shapes only, known when the program is
+    traced."""
+    return 1 if 2 * (n_dev - 1) * capacity <= n else n_dev
+
+
+def _bucket_places(remote, dest, n_dev: int, capacity: int, stacked: int):
+    """(places, oks, need): for each destination `capacity` slots that
+    hold the place (copr/join.live_rows: an index into the slots in
+    `_tile_order`) of every row of `remote` with that `dest`, and which
+    of the slots hold one, provided `need` <= `capacity`; `need`, the
+    fullest (column, destination) count times the columns.  A slot
+    whose `ok` is set holds the same place whichever form fills it.
+
+    Where the buckets are a small share of the slots
+    (`exchange_passes` 1) the n slots are sorted ONCE: a slot's word is
+    `place | dest << bit | dead << (bit + bits(n_dev))`, so one
+    single-lane unstable column sort puts every column's remote rows
+    first, grouped by destination, in place order.  The first
+    `(n_dev - 1) * capacity / COMPACT_COLUMNS` rows of every column are
+    kept: whenever `need` <= `capacity` a column holds no more remote
+    rows than that (the device's own bucket is empty).  Over the kept
+    slots alone each destination marks the other destinations' words
+    dead and sorts the columns again; the words carry the original
+    place, so nothing is gathered to compose the two levels.  Else
+    `live_rows` once a destination, each over all n slots."""
+    from ..copr.dag import COMPACT_COLUMNS as cols
+    from ..copr.join import _slot_places, _tile_order, live_rows
+    n = remote.shape[0]
+    if exchange_passes(n, n_dev, capacity) != 1:
+        places, oks, need = [], [], jnp.zeros((), jnp.int32)
+        for d in range(n_dev):
+            rows, ok, need_d = live_rows(remote & (dest == d), capacity,
+                                         stacked)
+            places.append(rows)
+            oks.append(ok)
+            need = jnp.maximum(need, need_d.astype(jnp.int32))  # valueflow: ok - at most the device's slots, below 2^31
+        return places, oks, need
+    assert n % cols == 0 and capacity % cols == 0, (n, capacity)
+    bit = max(n - 1, 1).bit_length()
+    dead = bit + max(n_dev - 1, 1).bit_length()
+    wt = jnp.int32 if dead < 31 else jnp.int64
+    at = _slot_places(n, stacked, wt)
+    words = _tile_order(
+        jnp.where(remote, at | (dest.astype(wt) << bit), at | (1 << dead)),
+        stacked).reshape(n // cols, cols)
+    kept = lax.sort(words, dimension=0,
+                    is_stable=False)[:(n_dev - 1) * capacity // cols]
+    to = words >> bit           # a dead word's is no device's
+    need = jnp.zeros((), jnp.int32)
+    places, oks = [], []
+    for d in range(n_dev):
+        need = jnp.maximum(need, jnp.max(jnp.sum(
+            to == d, axis=0, dtype=jnp.int32)) * cols)
+        mine = jnp.where((kept >> bit) == d, kept, kept | (1 << dead))
+        # the barrier keeps the flat form, as in `live_rows`
+        top = lax.optimization_barrier(lax.sort(
+            mine, dimension=0, is_stable=False)[:capacity // cols]
+            .reshape(-1))
+        places.append(top & ((1 << bit) - 1))
+        oks.append((top >> dead) == 0)
+    return places, oks, need
+
+
 def exchange_rows(cols: Sequence, live, dest, n_dev: int, capacity: int,
                   stacked: int = 1, axis: str = SHARD_AXIS):
     """Inside a shard_map program: the live rows of `cols` [(value,
@@ -161,24 +231,30 @@ def exchange_rows(cols: Sequence, live, dest, n_dev: int, capacity: int,
     rows are missing: the caller reports it and the statement is
     rerun); `sent`, the rows this device sent.
 
-    A bucket a destination, each filled as a lookup join's live probe
-    rows are compacted (copr/join.live_rows: one column sort of `place |
-    dead` words), the columns packed into 32-bit words once and gathered
-    at every bucket's places in ONE stacked gather (copr/join.pack_rows),
-    the buckets swapped by ONE `lax.all_to_all` of the words and one of
-    the slots' live bits.  No n-sized scatter, no `cumsum` over a
-    one-hot: on a v5e a scatter costs 90 ns an update and the column
-    sort of 2^23 slots 1.13 ms (PERF.md, PR 28).  The bucket to the
-    device itself stays empty: its rows are looked up where they are."""
-    from ..copr.join import _tile_order, live_rows, pack_rows
+    A bucket a destination, filled as a lookup join's live probe rows
+    are compacted, by column sorts of `place | dead` words and no
+    n-sized scatter or `cumsum` over a one-hot (`_bucket_places`: one
+    sort of the device's n slots where the buckets are a small share of
+    them, one a destination else), the columns packed into 32-bit words
+    once and gathered at every bucket's places in ONE stacked gather
+    (copr/join.pack_rows), the buckets swapped by ONE `lax.all_to_all`
+    of the words and one of the slots' live bits.  The bucket to the
+    device itself stays empty: its rows are looked up where they are.
+
+    (On a v5e, 2^24 slots in two stacked runs, four destinations: the
+    buckets' places for colocated keys, 196,608 slots a bucket and one
+    row in 4,000 remote, 3.56 ms in two levels (the one sort of 2^24
+    slots in fast memory), 40.8 ms with a sort of all slots a
+    destination, 19.3 ms with one keyed sort and a gather along the
+    columns for the destinations' slices; with the stacked gather of
+    three words 16.4 ms against 53.8.  Keys that lie anywhere, 2,621,440
+    slots a bucket and three rows in eight remote: 17.6 ms against 41.2.
+    PERF.md section 6, PR 36.)"""
+    from ..copr.join import _tile_order, pack_rows
     me = lax.axis_index(axis)
     remote = live & (dest != me)
-    places, oks, need = [], [], jnp.zeros((), jnp.int32)
-    for d in range(n_dev):
-        rows, ok, need_d = live_rows(remote & (dest == d), capacity, stacked)
-        places.append(rows)
-        oks.append(ok)
-        need = jnp.maximum(need, need_d.astype(jnp.int32))  # valueflow: ok - at most the device's slots, below 2^31
+    places, oks, need = _bucket_places(remote, dest, n_dev, capacity,
+                                       stacked)
     at = jnp.concatenate(places)
 
     def taken(x):
@@ -217,4 +293,5 @@ def broadcast_gather(cols: Sequence, valid, axis: str = SHARD_AXIS):
 
 
 __all__ = ["hash_partition_ids", "all_to_all_exchange", "broadcast_gather",
-           "record_exchange", "key_places", "exchange_rows"]
+           "record_exchange", "key_places", "exchange_passes",
+           "exchange_rows"]
