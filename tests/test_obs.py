@@ -647,6 +647,82 @@ def test_served_tree_is_closed_under_wire_stmt(odom, wire, how):
         exe.end_ns - begin.start_ns, abs=1.0)
 
 
+def _self_ns(sp, spans) -> int:
+    """A span's duration less what its children cover (their union:
+    children of two threads may run side by side)."""
+    kids = sorted((k.start_ns, k.end_ns) for k in spans
+                  if k.parent_id == sp.span_id)
+    covered, upto = 0, sp.start_ns
+    for lo, hi in kids:
+        lo, hi = max(lo, upto), min(hi, sp.end_ns)
+        if hi > lo:
+            covered += hi - lo
+            upto = hi
+    return sp.end_ns - sp.start_ns - covered
+
+
+@pytest.mark.parametrize("how,packets", [("agg", 5), ("topn", 10),
+                                         ("prepared", 5)])
+def test_wire_write_says_what_left_the_server(odom, wire, how, packets):
+    """``wire.write`` brackets the encoding AND the flush of the answer
+    (PR 40: the packets are buffered and leave in one ``sendall``): it
+    carries ``packets``, ``bytes`` and ``flushes``, lies under
+    ``wire.stmt``, which ends at or after the flush's return; the tree's
+    self-times still sum to the root's duration; the registry's two
+    counters advance by the statement's packets and flushes."""
+    from tidb_tpu.server.client import Client
+    from tidb_tpu.utils.metrics import global_registry
+    dom, _s, _sched = odom
+    reg = global_registry()
+    n_packets = reg.counter("tidb_tpu_wire_packets_total")
+    n_flushes = reg.counter("tidb_tpu_wire_flushes_total")
+
+    def counted(conn):
+        """The registry's readings once the connection has added what
+        it wrote (it does so after the client has the answer)."""
+        deadline = time.monotonic() + 5
+        while time.monotonic() < deadline:
+            if conn._counted == (conn.io.packets, conn.io.flushes):
+                return n_packets.get(), n_flushes.get()
+            time.sleep(0.005)
+        raise AssertionError("the connection's writes were never counted")
+
+    c = Client("127.0.0.1", wire.port, db="test")
+    try:
+        (conn,) = wire._conns
+        st = c.prepare("select sum(p) from obs_t where q < ?") \
+            if how == "prepared" else None
+        before = counted(conn)
+        if how == "prepared":
+            frag = "sum(p) from obs_t where q < 29"
+            assert st.execute(29)
+        elif how == "topn":
+            frag = "order by p desc"
+            assert len(c.query(TOPN_QUERY)) == 5
+        else:
+            frag = "sum(p * p * p * d)"
+            assert c.query(OBS_QUERIES[1])
+        tree = _closed_tree(dom, frag)
+        after = counted(conn)
+    finally:
+        c.close()
+    spans = tree.spans
+    root = spans[0]
+    (write,) = [sp for sp in spans if sp.name == "wire.write"]
+    assert write.parent_id == root.span_id and root.name == "wire.stmt"
+    # count, a definition a column, EOF, a packet a row, EOF: in ONE
+    # sendall, whose return the span waited for and the root after it
+    assert write.attrs["packets"] == packets
+    assert write.attrs["flushes"] == 1
+    assert write.attrs["bytes"] > 4 * packets
+    assert root.start_ns <= write.start_ns < write.end_ns <= root.end_ns
+    assert (after[0] - before[0], after[1] - before[1]) == (packets, 1)
+    assert conn.io.bytes_out >= write.attrs["bytes"]
+    # PR 33's invariant: every nanosecond of the root is some span's own
+    total = sum(_self_ns(sp, spans) for sp in spans)
+    assert total == pytest.approx(root.end_ns - root.start_ns, rel=1e-3)
+
+
 @pytest.mark.parametrize("shape", ["agg", "topn"])
 def test_session_without_connection_keeps_its_root(odom, shape):
     """No connection, no ``wire.stmt``: ``session.ExecuteStmt`` is the

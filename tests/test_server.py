@@ -120,6 +120,13 @@ def test_status_http_api(server):
                 f"http://127.0.0.1:{st.port}/metrics") as r:
             text = r.read().decode()
         assert "tidb_tpu_query_total" in text
+        # the write buffer's counters (PR 40): the connections of the
+        # tests above framed packets and flushed them
+        for name in ("tidb_tpu_wire_packets_total",
+                     "tidb_tpu_wire_flushes_total"):
+            (line,) = [ln for ln in text.splitlines()
+                       if ln.startswith(name + " ")]
+            assert float(line.split()[1]) > 0
     finally:
         st.close()
 

@@ -456,16 +456,19 @@ def late_span(tree: Optional[SpanTree], name: str,
     """A span on a statement's tree after ``Session.execute`` returned
     and the recorder took the tree (it holds a reference; ``add`` is
     lock-protected): the connection's ``wire.write``, under the
-    statement's ``wire.stmt``.  ``tree`` None = untraced = no-op."""
+    statement's ``wire.stmt``.  Yields the span's attrs, for what is
+    known only once the body ran.  ``tree`` None = untraced = no-op."""
+    attrs: dict = {}
     if tree is None:
-        yield
+        yield attrs
         return
     t0 = time.perf_counter_ns()
     try:
         with annotation(name, tree.trace_id):
-            yield
+            yield attrs
     finally:
-        tree.add(name, t0, time.perf_counter_ns(), parent_id=parent_id)
+        tree.add(name, t0, time.perf_counter_ns(), parent_id=parent_id,
+                 **attrs)
 
 
 def leaf(name: str):

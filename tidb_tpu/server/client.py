@@ -70,6 +70,7 @@ class Client:
         try:
             self.io.reset_seq()
             self.io.write(bytes([P.COM_QUIT]))
+            self.io.flush()     # no read follows to send it
         except OSError:
             pass
         self.sock.close()
@@ -180,6 +181,7 @@ class Prepared:
         c.io.reset_seq()
         c.io.write(bytes([P.COM_STMT_CLOSE])
                    + struct.pack("<I", self.stmt_id))
+        c.io.flush()            # the server sends no answer to read
 
     def _read_binary_result(self) -> list[tuple]:
         c = self.client

@@ -35,6 +35,7 @@ from ..session.session import Domain, Session
 from ..sql.bind import (bind_placeholders as _bind_placeholders,
                         count_placeholders as _count_placeholders,
                         strip_placeholders as _strip_placeholders)
+from ..utils.metrics import global_registry
 from . import packet as P
 
 SERVER_VERSION = "8.0.11-tidb-tpu-0.1"
@@ -44,21 +45,39 @@ ER_UNKNOWN = 1105
 ER_PARSE = 1064
 ER_DUP_ENTRY = 1062
 
+MAX_PAYLOAD = 0xFFFFFF          # a longer payload is split into packets
+# the write buffer is handed to the socket once it holds this much, so a
+# large result set streams and the server's memory does not grow with it
+WRITE_BUFFER_BYTES = 64 * 1024
+# 3-byte little-endian length + sequence id, as one little-endian word
+_frame_header = struct.Struct("<I").pack
+
 
 class PacketIO:
     """Length-prefixed packet framing with sequence ids (conn.go
-    readPacket/writePacket analog)."""
+    readPacket/writePacket analog).  Writes are buffered (packetio.go's
+    bufio.Writer analog): ``write`` frames a packet into the buffer,
+    ``flush`` hands the buffer to ONE ``sendall``.  A command's answer
+    leaves in one write; whatever the peer must answer leaves before the
+    next ``read`` blocks on it."""
 
     def __init__(self, sock: socket.socket):
         self.sock = sock
         self.seq = 0
+        self._out = bytearray()
+        # running totals of this connection's writes: a caller reads
+        # them before and after what it wants counted
+        self.packets = 0
+        self.bytes_out = 0      # framed, as buffered (headers included)
+        self.flushes = 0
 
     def read(self) -> bytes:
+        self.flush()    # nothing the peer waits for stays behind a read
         header = self._read_n(4)
         length = int.from_bytes(header[:3], "little")
         self.seq = (header[3] + 1) & 0xFF
         payload = self._read_n(length)
-        while length == 0xFFFFFF:  # multi-packet payload
+        while length == MAX_PAYLOAD:  # multi-packet payload
             header = self._read_n(4)
             length = int.from_bytes(header[:3], "little")
             self.seq = (header[3] + 1) & 0xFF
@@ -66,14 +85,28 @@ class PacketIO:
         return payload
 
     def write(self, payload: bytes):
-        data = payload
+        """Frame ``payload`` into the write buffer; nothing is sent
+        unless the buffer passes ``WRITE_BUFFER_BYTES``."""
+        out, data = self._out, payload
         while True:
-            chunk, data = data[:0xFFFFFF], data[0xFFFFFF:]
-            self.sock.sendall(len(chunk).to_bytes(3, "little")
-                              + bytes([self.seq]) + chunk)
+            chunk, data = data[:MAX_PAYLOAD], data[MAX_PAYLOAD:]
+            out += _frame_header(len(chunk) | self.seq << 24)
+            out += chunk
             self.seq = (self.seq + 1) & 0xFF
-            if len(chunk) < 0xFFFFFF:
+            self.packets += 1
+            self.bytes_out += 4 + len(chunk)
+            if len(out) >= WRITE_BUFFER_BYTES:
+                self.flush()
+                out = self._out
+            if len(chunk) < MAX_PAYLOAD:
                 break
+
+    def flush(self):
+        """Send what is buffered, in one ``sendall``."""
+        if self._out:
+            out, self._out = self._out, bytearray()
+            self.flushes += 1
+            self.sock.sendall(out)
 
     def reset_seq(self):
         self.seq = 0
@@ -113,12 +146,16 @@ class ClientConn:
         self._next_stmt_id = 0
         self.user = ""
         self.tls = False
+        self._counted = (0, 0)      # io.packets, io.flushes at _count_wire
 
     # -------------------------------------------------------------- #
 
     def run(self):
         try:
-            if not self._handshake():
+            authed = self._handshake()
+            self.io.flush()     # the OK, or the ERR that refused the peer
+            self._count_wire()
+            if not authed:
                 return
             while not self.server._closing:
                 self.io.reset_seq()
@@ -136,13 +173,20 @@ class ClientConn:
                 if cmd == P.COM_QUIT:
                     return
                 try:
-                    self._dispatch(cmd, body)
+                    try:
+                        self._dispatch(cmd, body)
+                    except ConnectionError:
+                        raise
+                    except Exception as e:  # statement errors -> ERR packet
+                        # what was buffered before the error goes out
+                        # before it, in order
+                        self.io.write(P.err_packet(_errno_for(e), str(e)))
+                    self.io.flush()     # the command's answer, in one write
                 except ConnectionError:
                     return
-                except Exception as e:  # statement errors -> ERR packet
-                    self.io.write(P.err_packet(_errno_for(e), str(e)))
                 finally:
                     self._end_wire_stmt()
+                    self._count_wire()
         finally:
             try:
                 self.session.close()   # drop temp tables' KV rows
@@ -153,6 +197,15 @@ class ClientConn:
             except OSError:
                 pass
             self.server._conn_done(self)
+
+    def _count_wire(self):
+        """Advance the registry's counters by what this connection
+        framed and flushed since the last call."""
+        io, srv = self.io, self.server
+        packets, flushes = io.packets, io.flushes
+        srv.wire_packets.inc(packets - self._counted[0])
+        srv.wire_flushes.inc(flushes - self._counted[1])
+        self._counted = (packets, flushes)
 
     def _handshake(self) -> bool:
         salt = os.urandom(20).replace(b"\x00", b"\x01")
@@ -293,15 +346,23 @@ class ClientConn:
     def _write_result(self, rs, binary: bool):
         """Encode and send a statement's result set or OK packet: the
         ``wire.write`` span, added under the statement's ``wire.stmt``
-        on its finished tree."""
+        on its finished tree.  The span ends when the flush of the
+        result returned and says what left: ``packets``, ``bytes``
+        (framed) and ``flushes`` (``sendall`` calls)."""
         tree, root = self.session.wire_span or (None, None)
+        io = self.io
+        packets, nbytes, flushes = io.packets, io.bytes_out, io.flushes
         with late_span(tree, "wire.write",
-                       root.span_id if root is not None else None):
+                       root.span_id if root is not None else None) as attrs:
             if rs.names:
                 self._write_resultset(rs, binary)
             else:
-                self.io.write(P.ok_packet(rs.affected, rs.last_insert_id,
-                                          status=self._status()))
+                io.write(P.ok_packet(rs.affected, rs.last_insert_id,
+                                     status=self._status()))
+            io.flush()
+            attrs.update(packets=io.packets - packets,
+                         bytes=io.bytes_out - nbytes,
+                         flushes=io.flushes - flushes)
 
     def _handle_field_list(self, body: bytes):
         table = body.split(b"\x00", 1)[0].decode()
@@ -464,6 +525,13 @@ class MySQLServer:
         self._tls = tls
         self._ssl_cert, self._ssl_key = ssl_cert, ssl_key
         self._ssl_ctx: Optional[ssl.SSLContext] = None
+        reg = global_registry()
+        self.wire_packets = reg.counter(
+            "tidb_tpu_wire_packets_total",
+            "packets framed into connections' write buffers")
+        self.wire_flushes = reg.counter(
+            "tidb_tpu_wire_flushes_total",
+            "sendall calls that emptied a connection's write buffer")
 
     @property
     def tls_enabled(self) -> bool:
@@ -548,6 +616,9 @@ class MySQLServer:
             if self._closing:
                 sock.close()
                 return
+            # an answer leaves in one write, and a many-flush answer
+            # must not wait on the client's ACK between flushes
+            sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
             conn = ClientConn(self, sock)
             with self._lock:
                 self._conns.add(conn)
