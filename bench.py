@@ -1,5 +1,4 @@
-"""Benchmark: TPC-H Q1 + Q6 + Q19 + ROLLUP + high-NDV group-by through
-the coprocessor.
+"""Benchmark: TPC-H Q1 + Q6 + Q19 + ROLLUP through the coprocessor.
 
 Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline", ...},
 or exits non-zero when the chip child recorded no rung.
@@ -12,10 +11,9 @@ or exits non-zero when the chip child recorded no rung.
 - vs_baseline: ratio to a single-core vectorized numpy implementation of
   the same query on the same host (see BASELINE.md "reference CPU
   baseline").
-- per-rung fields: q6/q19/rollup/high-NDV times + ratios and achieved
-  physical GB/s for Q1+Q6.  The high-NDV rung sweeps 20k/200k/2M groups
-  under every strategy (hndv_sweep: SCATTER / SEGMENT / SORT / DENSE /
-  numpy oracle per NDV).
+- per-rung fields: q6/q19/rollup times + ratios and achieved physical
+  GB/s for Q1+Q6.  (The high-NDV GROUP BY is measured by the cell
+  `tpch1x1.hndv` of benchmark/run.py.)
 
 One process per chip.  This parent imports only numpy and never touches
 JAX.  It runs, one after the other:
@@ -1195,13 +1193,7 @@ def _bench_one_sf(sf, platform, n_chips, iters, mem_bw):
                         client, cols, ix, n_shards, iters,
                         dense=(platform == "tpu"))),
                     ("narrowagg", lambda: _rung_narrowagg(
-                        client, cols, ix, n_shards, iters)),
-                    ("hndv", lambda: _rung_hndv(client, cols, ix, sf,
-                                                n_shards, iters))):
-        # (the former sf>=10 hndv cap_stream special-case is gone: the
-        # SEGMENT strategy's single-key partition replaces the resident
-        # multi-key sort that OOM-crashed the v5e worker, and copcost
-        # admission rejects the degenerate DENSE plan pre-trace)
+                        client, cols, ix, n_shards, iters))):
         try:
             rec.update(fn())
         except Exception as e:      # noqa: BLE001 - rung isolation
@@ -1323,140 +1315,6 @@ def _rung_narrowagg(client, cols, ix, n_shards, iters):
             "narrowagg_limb_ms": round(t_l * 1e3, 3),
             "narrowagg_state_bytes": {"narrow": wn, "limb": wl},
             "narrowagg_identical": True}
-
-
-HNDV_SWEEP = (20_000, 200_000, 2_000_000)
-
-
-def _rung_hndv(client, cols, ix, sf, n_shards, iters):
-    """High-NDV group-by rung (ISSUE 6 + 11): per-strategy NDV sweep.
-
-    For each NDV the group key is l_partkey folded into [0, ndv) so one
-    dataset yields a 20k/200k/2M-group curve, measured under every
-    applicable strategy — SCATTER (the multi-pass scatter radix
-    partition, ISSUE 11), SEGMENT (the single-sort radix path it
-    refines), SORT (the multi-key comparator both replace), DENSE (the
-    degenerate large-domain plan: admission may reject it pre-trace
-    with CostError, recorded as its error string instead of a device
-    fault) — plus the single-core numpy oracle.  The strategy sweep
-    pins the DEVICE path open (the CPU host-oracle short-circuit would
-    otherwise measure np.unique four times); every strategy must
-    complete bit-identically to the oracle.  Each rung also records
-    ``radix_passes`` and a measured per-pass phase breakdown
-    (histogram/cumsum/scatter ms, copr/radix.phase_bench).  Headline
-    hndv_* fields report the best radix strategy (SEGMENT-or-better) at
-    the largest NDV that actually has that many distinct keys."""
-    from tidb_tpu import copr
-    from tidb_tpu.chunk.column import Column
-    from tidb_tpu.copr import dag as D
-    from tidb_tpu.copr import radix as R
-    from tidb_tpu.copr.aggregate import GroupKeyMeta
-    from tidb_tpu.expr import ColumnRef
-    from tidb_tpu.store import snapshot_from_columns
-    from tidb_tpu.types import dtypes as dt
-    pk = cols[ix["l_partkey"]]
-    n_rows = len(pk.data)
-    kt = dt.bigint(False)
-    sweep: dict = {}
-    headline = None
-    headline_strategy = None
-
-    # the strategy comparison only means something on the device path:
-    # pin the CPU host-oracle short-circuit closed for this rung
-    saved_host_sort = client._host_sort_agg
-    client._host_sort_agg = lambda *a, **kw: None
-    try:
-        for ndv in HNDV_SWEEP:
-            key = (pk.data.astype(np.int64) * 1_000_003) % ndv
-            kcol = Column(kt, key, np.ones(n_rows, bool))
-            ksnap = snapshot_from_columns(["k"], [kcol], n_shards=n_shards)
-            kref = ColumnRef(kt, 0, "k")
-            count = (copr.AggDesc(copr.AggFunc.COUNT, None,
-                                  dt.bigint(False)),)
-            scan = D.TableScan((0,), (kt,))
-            cap = max(1024, 1 << (int(ndv * 1.25) - 1).bit_length())
-            strategies = {
-                "scatter": D.Aggregation(scan, (kref,), count,
-                                         D.GroupStrategy.SCATTER,
-                                         num_buckets=cap),
-                "segment": D.Aggregation(scan, (kref,), count,
-                                         D.GroupStrategy.SEGMENT,
-                                         num_buckets=cap),
-                "sort": D.Aggregation(scan, (kref,), count,
-                                      D.GroupStrategy.SORT,
-                                      group_capacity=cap),
-                "dense": D.Aggregation(scan, (kref,), count,
-                                       D.GroupStrategy.DENSE,
-                                       domain_sizes=(ndv,)),
-            }
-            t = time.time()
-            uk, ucnt = np.unique(key, return_counts=True)
-            np_t = time.time() - t
-            entry: dict = {"numpy_ms": round(np_t * 1e3, 1),
-                           "groups": int(len(uk)),
-                           "radix_passes": D.radix_passes(cap)}
-            for name, hagg in strategies.items():
-                meta = [GroupKeyMeta(kt, 0)] if name != "dense" \
-                    else [GroupKeyMeta(kt, ndv)]
-                try:
-                    resh = client.execute_agg(hagg, ksnap, meta)
-                    assert len(resh.key_columns[0]) == len(uk), \
-                        f"{name} group-count mismatch"
-                    got_k = np.asarray([int(c) for c in
-                                        resh.key_columns[0].data])
-                    got_c = np.asarray([int(c) for c in
-                                        resh.columns[0].data])
-                    order = np.argsort(got_k)
-                    assert (got_k[order] == uk).all() \
-                        and (got_c[order] == ucnt).all(), \
-                        f"{name} not bit-identical to numpy"
-                    st = _median_times(
-                        lambda: client.execute_agg(hagg, ksnap, meta),
-                        max(iters // 2, 1))
-                    entry[f"{name}_ms"] = round(st * 1e3, 1)
-                    entry[f"{name}_vs_numpy"] = round(np_t / st, 2)
-                except Exception as e:  # noqa: BLE001 - strategy isolation:
-                    # a rejected strategy (e.g. DENSE CostError pre-trace
-                    # at degenerate NDV) degrades to its error, never the
-                    # rung
-                    entry[f"{name}_error"] = f"{type(e).__name__}: {e}"[:120]
-            # measured per-pass phase breakdown of the scatter partition
-            # (per-device row count; single-device phases)
-            try:
-                per_dev = max(n_rows // max(n_shards, 1), 1)
-                entry["radix_breakdown"] = R.phase_bench(per_dev, cap)
-            except Exception as e:  # noqa: BLE001 - breakdown is advisory
-                entry["radix_breakdown"] = {"error": str(e)[:80]}
-            log(f"high-NDV sweep ndv={ndv} ({entry['groups']} groups): " +
-                "  ".join(f"{k[:-3]}={v}ms" for k, v in entry.items()
-                          if k.endswith("_ms")))
-            sweep[str(ndv)] = entry
-            radix_ms = [entry[k] for k in ("scatter_ms", "segment_ms")
-                        if k in entry]
-            if radix_ms and entry["groups"] >= min(ndv, n_rows) // 2:
-                headline = entry
-                headline_strategy = min(
-                    (k for k in ("scatter_ms", "segment_ms") if k in entry),
-                    key=lambda k: entry[k])[:-3]
-            del ksnap, kcol, key
-    finally:
-        client._host_sort_agg = saved_host_sort
-
-    out = {"hndv_sweep": sweep}
-    if headline is not None:
-        seg_t = headline[f"{headline_strategy}_ms"]
-        out.update({
-            "hndv_ms": seg_t,
-            "hndv_vs_numpy": round(
-                headline["numpy_ms"] / max(seg_t, 1e-6), 2),
-            "hndv_groups": headline["groups"],
-            "hndv_strategy": headline_strategy,
-            "hndv_radix_passes": headline["radix_passes"]})
-        log(f"high-NDV headline ({headline_strategy}, "
-            f"{headline['groups']} groups): "
-            f"{seg_t:.1f} ms  ({n_rows / seg_t / 1e3:.1f} M rows/s)  "
-            f"speedup vs numpy {out['hndv_vs_numpy']}x")
-    return out
 
 
 def _bench_sf100(platform, mem_bw):

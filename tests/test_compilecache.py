@@ -219,7 +219,7 @@ def test_restart_serves_corpus_query_trace_free(cache_dir, monkeypatch):
 
 
 def test_restart_warm_pool_covers_regrow_capacity(cache_dir):
-    """A SORT/SEGMENT group-by whose capacity regrew persists the SIZED
+    """A SORT group-by whose capacity regrew persists the SIZED
     program; after a restart the client's warm-capacity pick re-enters
     at the warm capacity and serves from the pool."""
     cc = compile_cache()

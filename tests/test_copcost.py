@@ -27,6 +27,7 @@ import numpy as np
 import pytest
 
 from tidb_tpu.analysis.copcost import (CAP_BLOWUP_MAX, COST_TOLERANCE,
+                                       DENSE_BLOWUP_MIN_GROUPS,
                                        CostError, cost_findings,
                                        cost_report, dag_cost, plan_cost,
                                        snapshot_input_bytes,
@@ -253,6 +254,67 @@ def test_seeded_unbounded_node_is_a_gate_finding(q6_cop):
     bad = dataclasses.replace(cop, dag=_AlienNode(child=scan))
     findings = cost_findings([("select seeded", bad)], n_devices=N_DEV)
     assert [f.rule for f in findings] == ["COST-UNBOUNDED"]
+
+
+def _count_by_key(strategy, **sizes):
+    return D.Aggregation(
+        D.TableScan((0,), (dt.bigint(False),)),
+        (ColumnRef(dt.bigint(False), 0),),
+        (D.AggDesc(D.AggFunc.COUNT, None, dt.bigint(False)),),
+        strategy, **sizes)
+
+
+def _key_snapshot(n):
+    from tidb_tpu.chunk.column import Column
+    from tidb_tpu.store import snapshot_from_columns
+    return snapshot_from_columns(["k"], [Column(
+        dt.bigint(False), np.arange(n, dtype=np.int64),
+        np.ones(n, bool))], n_shards=N_DEV)
+
+
+def test_degenerate_dense_rejected_at_admission(mesh, monkeypatch):
+    """The large-NDV DENSE plan (the sf>=10 TPU-worker crash shape) is
+    priced as a dense-blowup and rejected with CostError at submit,
+    BEFORE anything traces — such keys group by SORT."""
+    cols, counts = _key_snapshot(4096).device_cols(mesh)
+    # past BOTH fences: the planner's dense ceiling AND the
+    # states-vs-rows ratio (states >> rows)
+    dom_size = 2 * DENSE_BLOWUP_MIN_GROUPS
+    dense = _count_by_key(D.GroupStrategy.DENSE, domain_sizes=(dom_size,))
+    _no_trace(monkeypatch)
+
+    sched = DeviceScheduler()
+    task = CopTask.structured(dense, mesh, 0, cols, counts, ())
+    r0 = sched.budget_rejects
+    with pytest.raises(CostError) as ei:
+        sched.submit(task)
+    assert ei.value.rule == "dense-blowup"
+    assert sched.budget_rejects == r0 + 1
+    # the cost model itself flags it too (gate-finding twin)
+    cost = task_cost(task)
+    assert cost.dense_blowups
+    # the equivalent SORT plan prices clean and admits
+    srt = _count_by_key(D.GroupStrategy.SORT,
+                        group_capacity=1 << (dom_size - 1).bit_length())
+    srt_cost = task_cost(CopTask.structured(srt, mesh, 0, cols, counts, ()))
+    assert not srt_cost.dense_blowups and not srt_cost.unbounded
+    assert srt_cost.peak_hbm_bytes > 0
+
+
+def test_dense_blowup_gate_finding():
+    """cost_findings reports COST-DENSE-BLOWUP for a degenerate dense
+    corpus plan (seeded via a fake physical op)."""
+    snap = _key_snapshot(1024)
+
+    class _FakeExec:
+        table = type("T", (), {"snapshot": staticmethod(lambda: snap)})()
+        children = ()
+        dag = _count_by_key(D.GroupStrategy.DENSE,
+                            domain_sizes=(4 * DENSE_BLOWUP_MIN_GROUPS,))
+    _FakeExec.__name__ = "CopTaskExec"
+
+    finds = cost_findings([("select 1", _FakeExec())], n_devices=N_DEV)
+    assert any(f.rule == "COST-DENSE-BLOWUP" for f in finds), finds
 
 
 def test_seeded_padding_waste_is_a_gate_finding():

@@ -613,13 +613,15 @@ def partjoin():
 
 
 @pytest.mark.parametrize("name,program", [
-    ("q14", "cop_solo_join_agg_scalar_71e54440f171"),
-    ("q19", "cop_solo_join_agg_scalar_c089d1bcbc66")])
+    ("q14", "cop_solo_join_agg_scalar_1addb69e3d59"),
+    ("q19", "cop_solo_join_agg_scalar_051d00ce9053")])
 def test_a_join_that_does_not_qualify_keeps_the_parent_s_program(
         partjoin, lowered_for, name, program):
     """`l_partkey` is in no order: Q14's and Q19's DAGs, lowered as for a
     TPU, carry no `probe_window`, and their restart-stable digests are
-    the ones the parent commit named these programs by (read there)."""
+    what they were before the window form (the literals are PR 44's,
+    where every `Aggregation` lost two fields and so changed its name
+    once: the programs' text did not)."""
     lowered_for("tpu")
     mod = _bench("classes", name)
     sess = Session(partjoin)

@@ -130,10 +130,8 @@ def test_scan_lifetime_classes():
     assert cls is BufferClass.LOOP_CARRIED and "regrow" in why
     cls, why = scan_lifetime(_join_agg())
     assert cls is BufferClass.LOOP_CARRIED and "join" in why
-    seg = dataclasses.replace(_sort_agg(),
-                              strategy=D.GroupStrategy.SEGMENT,
-                              group_capacity=0, num_buckets=64)
-    assert scan_lifetime(seg)[0] is BufferClass.LOOP_CARRIED
+    unsized = dataclasses.replace(_sort_agg(), group_capacity=0)
+    assert scan_lifetime(unsized)[0] is BufferClass.LOOP_CARRIED
 
 
 def test_donation_plan_argnums_per_program_shape():
@@ -289,6 +287,19 @@ def test_fused_donating_launch_bit_identical(mesh):
     _tree_equal(off(cols_a, counts_a), on(cols_b, counts_b))
 
 
+def _donated_more(sched, donated0, timeout=10.0) -> bool:
+    """Did a launch since `donated0` donate?  The drain thread counts a
+    launch (`_account`) AFTER it has answered the statement's thread
+    (`finish()`), so the answer can be here before the count: wait for
+    it (one streamed batch is one launch, and a loaded machine took the
+    interpreter from the drain in between: PR 44's full run)."""
+    import time
+    deadline = time.monotonic() + timeout
+    while sched.donated_tasks <= donated0 and time.monotonic() < deadline:
+        time.sleep(0.01)
+    return sched.donated_tasks > donated0
+
+
 def test_streamed_paging_loop_donates_and_residents_survive(mesh):
     """The acceptance shape: a paging-loop (streamed HBM batches) query
     donates its ephemeral batches — bit-identical to the resident run —
@@ -315,7 +326,7 @@ def test_streamed_paging_loop_donates_and_residents_survive(mesh):
     assert [c.to_python() for c in streamed.columns] \
         == [c.to_python() for c in resident.columns]
     assert int(streamed.columns[0].data[0]) == int(vals.sum())
-    assert sched.donated_tasks > donated0         # batches donated
+    assert _donated_more(sched, donated0)         # batches donated
     assert sched.donated_bytes >= 0
     # PERSISTENT residents survived every donating launch...
     assert not counts.is_deleted()
@@ -366,7 +377,7 @@ def test_corpus_query_paging_loop_donates(corpus, mesh):
     streamed = client.execute_agg(cop.dag, snap, [])
     assert [c.to_python() for c in streamed.columns] \
         == [c.to_python() for c in resident.columns]
-    assert sched.donated_tasks > donated0
+    assert _donated_more(sched, donated0)
     assert not counts.is_deleted()
     assert all(not v.is_deleted() for v, _m in cols)
 

@@ -15,7 +15,7 @@ from jax.sharding import Mesh
 
 from tidb_tpu.copr import dag as D
 from tidb_tpu.copr import exec as X
-from tidb_tpu.copr import runagg, segment
+from tidb_tpu.copr import runagg
 from tidb_tpu.copr.aggregate import merge_sorted_states, sum_out_dtype
 from tidb_tpu.expr import ColumnRef
 from tidb_tpu.expr.compile import Evaluator
@@ -124,15 +124,14 @@ def _table(n, ndv, seed, n_keys=1, nulls=True, live=0.7, spread=10 ** 5):
 @pytest.mark.parametrize("n,ndv", [(1000, 1), (1000, 7), (5000, 700),
                                    (1 << 17, 40000)])
 def test_tables_equal_the_reference(n, ndv, words):
-    """NDV from one group to above `SEGMENT_MIN_NDV`, NULL keys (one
-    group) and NULL arguments, three in ten rows dead."""
-    from tidb_tpu.executor.plan import SEGMENT_MIN_NDV
+    """NDV from one group to above 32,768, NULL keys (one group) and
+    NULL arguments, three in ten rows dead."""
     cols, sel = _table(n, ndv, seed=ndv)
     st, agg, _ = _regrown(_agg(words=words, cap=1 << 16), cols, sel)
     want = _want(agg, cols, sel)
     assert _groups(agg, st) == want
     assert int(st["__ngroups__"]) == len(want)
-    assert ndv < 40000 or len(want) > SEGMENT_MIN_NDV
+    assert ndv < 40000 or len(want) > 1 << 15
 
 
 @pytest.mark.parametrize("words", FORMS)
@@ -291,9 +290,9 @@ def test_keys_that_collide_in_the_hash_are_never_merged(monkeypatch):
     each (their rows interleave in the sort), never one group: the
     table's slots still add up to the reference per true key, and the
     host merge (`merge_sorted_states`) makes each key one group."""
-    real = segment.key_hash
+    real = runagg.key_hash
     monkeypatch.setattr(
-        segment, "key_hash",
+        runagg, "key_hash",
         lambda keyinfo, n: real(
             [(vz, m, nf, code // 4) for vz, m, nf, code in keyinfo], n))
     cols, sel = _table(2000, 40, seed=13)
@@ -564,7 +563,7 @@ def test_whole_statements_through_the_normal_path(monkeypatch, sql, words,
 
 def test_the_planner_keeps_its_choice_on_a_cpu_mesh():
     """No program is lowered for a TPU here, so nothing of this changes
-    a plan: no record words, the old strategies by their old rule."""
+    a plan: no record words, SORT with its capacity from the NDV."""
     from tidb_tpu.testing.tpch import tpch_plan_session
     sess = tpch_plan_session(0.002)
     sess.execute("analyze table lineitem")
@@ -585,8 +584,8 @@ def test_run_form_is_counts_and_integer_sums():
 @pytest.mark.parametrize("change,ok", [
     ({}, True),
     ({"pack_words": 3}, False),
-    ({"strategy": D.GroupStrategy.SEGMENT, "num_buckets": 1024,
-      "pack_words": 1}, False),
+    ({"strategy": D.GroupStrategy.DENSE, "domain_sizes": (1024,),
+      "group_capacity": 0, "pack_words": 1}, False),
     ({"topn": D.GroupTopN((("agg", 0, True), ("key", 0, False)), 10)}, True),
     ({"topn": D.GroupTopN((("agg", 5, True),), 10)}, False),
     ({"topn": D.GroupTopN((("key", 0, True),), 0)}, False),
@@ -614,7 +613,8 @@ def test_programs_that_use_neither_field_keep_their_names():
     DAG's digest (`dag.DIGEST_IF_SET`): at their defaults they are no
     part of it, so a DENSE or SCALAR program's name, and its place in
     every compile cache, is what the commit before them gave (the
-    literals are that commit's)."""
+    literals are PR 44's: a field that leaves `Aggregation` renames
+    every aggregation once, and two strategies' fields left there)."""
     from tidb_tpu.analysis.compilekey import stable_digest
     scan = D.TableScan((0, 1), (I64, I64))
     aggs = (D.AggDesc(SUM, ColumnRef(I64, 1), sum_out_dtype(I64)),)
@@ -622,8 +622,8 @@ def test_programs_that_use_neither_field_keep_their_names():
                           D.GroupStrategy.DENSE, domain_sizes=(6,))
     sort = D.Aggregation(scan, (ColumnRef(I64, 0),), aggs,
                          D.GroupStrategy.SORT, group_capacity=1024)
-    assert stable_digest(dense) == "256d398a896ddb8d"
-    assert stable_digest(sort) == "49a8ced7c75f597f"
+    assert stable_digest(dense) == "8f1736135998172f"
+    assert stable_digest(sort) == "659275d34c858f8c"
     assert len({stable_digest(sort), stable_digest(dataclasses.replace(
         sort, pack_words=1)), stable_digest(dataclasses.replace(
             sort, topn=D.GroupTopN((("key", 0, False),), 3)))}) == 3
@@ -632,23 +632,38 @@ def test_programs_that_use_neither_field_keep_their_names():
 # what the statements of the cells that never reach `agg_run_states`
 # launch, planned and lowered as for a TPU over `tpch_plan_session(0.002)`
 # (the class files' own text; Q14 against a stand-in for `part` with a
-# `p_type`): the program names, digest and all, of the commit before the
-# prefix sums changed
+# `p_type`): the program names, digest and all, of PR 44 (which renamed
+# every aggregation by taking two fields out of `dag.Aggregation`, and
+# changed no program's text)
 OTHER_CELLS = {
     "q6": ({"year": 1994, "discount": 6, "quantity": 24},
-           ["cop_solo_agg_scalar_d9813c8a8dcf"]),
-    "q1": ({"delta": 90}, ["cop_solo_agg_dense_976556d4f313"]),
+           ["cop_solo_agg_scalar_af993896b29b"]),
+    "q1": ({"delta": 90}, ["cop_solo_agg_dense_43ba43a5bbce"]),
     "topn": ({}, ["cop_solo_topn_16ce5d827856"]),
-    "part_agg": ({"size": 25}, ["cop_solo_agg_dense_6dc1e6a2f9bf"]),
-    "kv_agg": ({"grp": 5}, ["cop_solo_agg_scalar_ac64dc605029"]),
+    "part_agg": ({"size": 25}, ["cop_solo_agg_dense_3dc5cdbe0b84"]),
+    "kv_agg": ({"grp": 5}, ["cop_solo_agg_scalar_790de0a0b330"]),
     "q14": ({"year": 1995, "month": 9},
-            ["cop_solo_join_agg_scalar_4660eae87699",
+            ["cop_solo_join_agg_scalar_8b5d162ad98a",
              "cop_solo_rows_adc818226355"]),
     "q19": ({"quantity": [1, 10, 20],
              "brand": ["Brand#12", "Brand#23", "Brand#34"]},
-            ["cop_solo_join_agg_scalar_66af6b26d000",
+            ["cop_solo_join_agg_scalar_b390880e3a9e",
              "cop_solo_rows_dc761e59f787"]),
 }
+
+
+def _bench_module(monkeypatch, kind, name):
+    """``benchmark/<kind>/<name>.py``, as the harness loads it."""
+    import importlib.util
+    import os
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    monkeypatch.syspath_prepend(bench)      # the class files' `harness`
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{kind}_{name}", os.path.join(bench, kind, f"{name}.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 @pytest.fixture(scope="module")
@@ -697,21 +712,62 @@ def test_the_other_cells_statements_keep_their_programs(tpu_planned, cls,
     DENSE or SCALAR (or a TopN, or a lookup join under a scalar
     aggregation): their programs are named as the parent named them, so
     they are the parent's programs, and none says `scan_limbs`."""
-    import importlib.util
-    import os
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark")
-    monkeypatch.syspath_prepend(bench)      # the class files' `harness`
-    spec = importlib.util.spec_from_file_location(
-        f"bench_class_{cls}", os.path.join(bench, "classes", f"{cls}.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    mod = _bench_module(monkeypatch, "classes", cls)
     params, names = OTHER_CELLS[cls]
     sql = mod.sql(params)
     if cls == "q14":
         sql = sql.replace("lineitem, part ", "lineitem, part_t ")
     assert tpu_planned(sql) == names
     assert "_agg_sort_" not in " ".join(names)
+
+
+# the statement classes of the cells with a GROUP BY above 6M rows or a
+# join (`tpch1x1.hndv`, `tpch1x1.orderjoin`; `tpch10x4.shuffle`'s texts
+# are `q3`'s and `q12`'s), each with parameters its `draw` could give,
+# the tables its text reads, and how its root aggregation is planned
+CELL_GROUP_BYS = {
+    "hndv_qty": ({}, ("LINEITEM",), D.GroupStrategy.SORT),
+    "hndv_rev": ({"year": 1994}, ("LINEITEM",), D.GroupStrategy.SORT),
+    "q3": ({"segment": "BUILDING", "day": 15},
+           ("CUSTOMER", "ORDERS", "LineItem"), D.GroupStrategy.SORT),
+    # one group a ship mode, a dictionary of seven: in-program
+    "q12": ({"mode1": "MAIL", "mode2": "SHIP", "year": 1994},
+            ("ORDERS", "LineItem"), D.GroupStrategy.DENSE),
+}
+
+
+@pytest.mark.parametrize("cls", list(CELL_GROUP_BYS))
+def test_the_cells_group_bys_keep_their_lowering(monkeypatch, cls):
+    """A cell's statement that leaves `runagg` fails here, and not only
+    at the chip's `devicepath` check: planned as for a TPU over the
+    benchmark's own tables, the root aggregation of each class text is
+    SORT, of COUNTs and integer or DECIMAL SUMs alone (`run_form`); or,
+    for Q12, DENSE, which no host-merged lowering ever sees."""
+    from tidb_tpu.session import Domain, Session
+    from tidb_tpu.testing.tpch import built_tpch_plans
+    params, tables, strategy = CELL_GROUP_BYS[cls]
+    run_py = _bench_module(monkeypatch, "", "run")
+    dom = Domain()
+    for name in tables:
+        table = _bench_module(monkeypatch, "tables", name)
+        run_py._load_table(dom, None, table, table.generate(
+            0.002, 2147483659, list(table.TYPES)))
+    sess = Session(dom)
+    for name in tables:
+        sess.execute(f"analyze table {name}")
+    monkeypatch.setattr(spmd, "mesh_platform", lambda _mesh: "tpu")
+    sql = _bench_module(monkeypatch, "classes", cls).sql(params)
+    (_sql, phys), = built_tpch_plans(sess, [sql])
+    roots, stack = [], [phys]
+    while stack:
+        op = stack.pop()
+        if isinstance(getattr(op, "dag", None), D.Aggregation):
+            roots.append(op.dag)
+        stack.extend(c for c in getattr(op, "children", []) or [] if c)
+    (root,) = roots
+    assert root.strategy is strategy, root
+    if strategy is D.GroupStrategy.SORT:
+        assert root.host_merged and runagg.run_form(root), root.aggs
 
 
 def test_the_scan_limbs_fact_is_a_row_of_the_table_and_nothing_of_sched():

@@ -172,12 +172,11 @@ class _FakeTask:
 
 
 def test_fusion_signature_contract_class():
-    """Fusable classes: in-program agg chains ('inprog-agg'), SEGMENT
-    aggs keyed by bucket shape ('segment-agg', B), extras-free rows
-    chains ('rows'), and — the ISSUE 11 fusion-breadth satellite —
-    SORT aggs with a concrete pow2 capacity ('sort-agg', cap); a SORT
-    agg the planner left unsized (capacity 0: the client owns sizing)
-    still has no static shape class."""
+    """Fusable classes: in-program agg chains ('inprog-agg'),
+    extras-free rows chains ('rows'), and — the ISSUE 11 fusion-breadth
+    satellite — SORT aggs with a concrete pow2 capacity
+    ('sort-agg', cap); a SORT agg the planner left unsized (capacity 0:
+    the client owns sizing) still has no static shape class."""
     assert fusion_signature(_mk_agg_dag()) == ("inprog-agg",)
     # capacity-bucketed SORT shape class (pow2 capacities, which is all
     # the planner/regrow discipline ever produces)
@@ -194,11 +193,11 @@ def test_fusion_signature_contract_class():
     # rows chains fuse now, with per-member output capacities
     assert fusion_signature(D.Limit(scan, 5)) == ("rows",)
     assert fusion_signature(scan) == ("rows",)
-    seg = D.Aggregation(
+    srt = D.Aggregation(
         child=scan, group_by=(ColumnRef(dt.bigint(False), 0),),
         aggs=(D.AggDesc(D.AggFunc.COUNT, None, dt.bigint(False)),),
-        strategy=D.GroupStrategy.SEGMENT, num_buckets=4096)
-    assert fusion_signature(seg) == ("segment-agg", 4096)
+        strategy=D.GroupStrategy.SORT, group_capacity=4096)
+    assert fusion_signature(srt) == ("sort-agg", 4096)
 
 
 def test_rows_plans_sharing_scan_fuse_with_per_member_capacities():

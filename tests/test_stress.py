@@ -2,7 +2,7 @@
 the slow/bench-only ~1k-session rung.
 
 The smoke proves the whole vertical on every CI run: 64 open-loop
-sessions over the mixed corpus (dense/SORT/SEGMENT/rows/shuffle),
+sessions over the mixed corpus (dense/SORT at low and high NDV/rows/shuffle),
 4 resource groups, PR 8 chaos armed — completion 1.0 and ZERO wrong
 results, with the copmeter metrics (p50/p99 wait, fusion rate, RU
 fairness, calibrated-pricing error) present as first-class fields.

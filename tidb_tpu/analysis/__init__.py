@@ -17,8 +17,7 @@ Three passes, all wired into CI as a zero-findings gate
   anything new fails the gate.
 - copcost: a static shape/memory abstract interpreter that walks built
   cop DAGs using only contracts (padded device shapes from DENSE
-  domain_sizes / SORT capacities / SEGMENT bucket spaces, physical
-  dtype widths, per-shard
+  domain_sizes / SORT capacities, physical dtype widths, per-shard
   extents under the mesh) and rolls up a per-launch LaunchCost
   (peak HBM bytes, transfer bytes, flops, padding waste).  Gate rules
   COST-PAD-WASTE / COST-CAP-BLOWUP / COST-DENSE-BLOWUP /

@@ -501,21 +501,6 @@ class CorrectionStore:
             }
 
 
-def arbitrated_ms(digest: str, cost) -> float:
-    """Per-digest calibrated launch-time estimate for STRATEGY
-    ARBITRATION (executor/plan picking SORT vs SEGMENT vs SCATTER for a
-    high-NDV group-by): the static predict_ms bent by the digest's
-    measured (clamped) time_factor when launches have been observed,
-    the untouched static prediction otherwise.  A digest whose measured
-    factor beats a rival's flips selection with NO code change — the
-    closed-loop the ROADMAP names for the real-TPU hndv cliff."""
-    pred = predict_ms(cost)
-    ent = correction_store().get(digest)
-    if ent is not None and ent.samples > 0:
-        pred *= clamp_factor(ent.time_factor)
-    return pred
-
-
 _STORE: Optional[CorrectionStore] = None
 _STORE_MU = threading.Lock()
 
@@ -588,7 +573,7 @@ def calibration_report(plans, n_devices: int = 8) -> str:
 
 __all__ = ["CorrectionStore", "Correction", "BoundedLRU",
            "correction_store", "clamp_factor", "predict_ms",
-           "arbitrated_ms", "merge_correction_payloads",
+           "merge_correction_payloads",
            "simulate_corpus_calibration", "calibration_report",
            "CALIB_CLAMP_MIN", "CALIB_CLAMP_MAX", "CALIB_ALPHA",
            "CALIB_STORE_CAP", "CALIB_OOM_BUMP", "CALIB_TARGET_ERR",

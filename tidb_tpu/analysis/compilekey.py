@@ -21,7 +21,7 @@ mismatched entry is rejected, never silently deserialized):
 
 - ``digest``        restart-stable sha256 of the canonical dag encoding
 - ``family``        same, with regrow capacities (group_capacity /
-                    num_buckets / join out_capacity) zeroed — the warm
+                    join out_capacity) zeroed — the warm
                     pool's capacity-reuse index
 - ``mesh_fp``       axis names + shape + device ids (sched/task
                     fingerprint, hashed)
@@ -49,7 +49,7 @@ from ..copr import dag as D
 # fields that only size regrow loops: two dags differing ONLY here run
 # the same plan family, so the client's paging/regrow re-entry can round
 # up to a capacity the warm pool already holds
-_CAPACITY_FIELDS = ("group_capacity", "num_buckets", "out_capacity")
+_CAPACITY_FIELDS = ("group_capacity", "out_capacity")
 
 
 def _encode(obj, h, skip_capacity: bool) -> None:
@@ -263,7 +263,7 @@ def variant_key(dag: D.CopNode, mesh, program: str,
     donation_sig = (f"{plan.describe()}|argnums="
                     f"{tuple(int(a) for a in donate_argnums)}")
     if isinstance(dag, D.Aggregation):
-        capacity = dag.state_capacity or 0
+        capacity = dag.group_capacity or 0
     elif isinstance(dag, D.FusedDag):
         capacity = 0
     else:

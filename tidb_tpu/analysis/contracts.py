@@ -253,32 +253,6 @@ def _verify_dag(node: D.CopNode, path) -> None:
             if node.group_capacity < 0:
                 _fail("capacity-shape", p,
                       f"negative group capacity {node.group_capacity}")
-        elif node.strategy in D.RADIX_STRATEGIES:
-            sname = node.strategy.value.upper()
-            if not node.group_by:
-                _fail("capacity-shape", p,
-                      f"{sname} aggregation without keys")
-            b = node.num_buckets
-            if b <= 0 or (b & (b - 1)) != 0:
-                # the radix partition masks the top log2(B) hash bits and
-                # the state table is (B,): a malformed bucket count would
-                # trace a garbage-shaped program
-                _fail("capacity-shape", p,
-                      f"{sname} num_buckets {b} is not a positive power "
-                      "of two")
-            if node.strategy is D.GroupStrategy.SCATTER \
-                    and D.radix_passes(b) > D.MAX_RADIX_PASSES:
-                # pass well-formedness: each pass is a full-data
-                # reorder, so a bucket space whose bit span prices more
-                # than MAX_RADIX_PASSES passes would cost more data
-                # movement than the comparator sort it replaces
-                _fail("capacity-shape", p,
-                      f"SCATTER num_buckets {b} prices "
-                      f"{D.radix_passes(b)} radix passes "
-                      f"(> {D.MAX_RADIX_PASSES}): malformed bucket "
-                      "space")
-            if node.prehashed:
-                _verify_prehashed(node, schema, p)
         if node.pack_words not in ((0, 1, 2) if node.strategy
                                    == D.GroupStrategy.SORT else (0,)):
             _fail("capacity-shape", p,
@@ -296,7 +270,7 @@ def _verify_dag(node: D.CopNode, path) -> None:
         if node.topn is not None:
             # which groups the consumer keeps: read by the lowering of a
             # host-merged table alone, over its own keys and aggregates
-            if node.strategy not in D.HOST_MERGE_STRATEGIES:
+            if not node.host_merged:
                 _fail("capacity-shape", p,
                       f"topn on a {node.strategy.value} aggregation")
             for kind, i, _desc in node.topn.keys:
@@ -310,11 +284,6 @@ def _verify_dag(node: D.CopNode, path) -> None:
             if node.topn.limit <= 0:
                 _fail("capacity-shape", p,
                       f"topn limit {node.topn.limit}")
-        if node.prehashed and node.strategy not in D.RADIX_STRATEGIES:
-            _fail("capacity-shape", p,
-                  f"prehashed set on a {node.strategy.value} "
-                  "aggregation: only the radix strategies "
-                  "(SEGMENT/SCATTER) read a hoisted hash column")
         if node.narrow_sums:
             # valueflow-proven single-word SUM states: only in-program
             # (psum-merged) strategies carry them, and only int/decimal
@@ -485,41 +454,6 @@ def _verify_packing(node: D.LookupJoin, p) -> None:
             _fail("capacity-shape", p,
                   f"packed fields overlap in word {w}")
         used[w] |= mask
-
-
-def _verify_prehashed(node: D.Aggregation, schema, p) -> None:
-    """Contract of the prehash hoist (store/client + copr/radix): the
-    LAST scan column is the hoisted int64 key hash, the chain below the
-    aggregation is a plain TableScan(+Selection) (anything reshaping
-    the batch would strand the appended column), and no group key may
-    read the hash column itself."""
-    cur = node.child
-    while isinstance(cur, D.Selection):
-        cur = cur.child
-    if not isinstance(cur, D.TableScan):
-        _fail("capacity-shape", p,
-              "prehashed aggregation over a non-scan chain: the hoisted "
-              "hash column only rides a TableScan(+Selection) batch")
-    if not schema or _family(schema[-1]) != "int":
-        _fail("dtype-mismatch", p,
-              "prehashed aggregation whose last scan column is not an "
-              "int64-family hash lane")
-    hash_idx = len(schema) - 1
-    for g in node.group_by:
-        for ref in (x for x in _walk_refs(g)):
-            if ref.index == hash_idx:
-                _fail("column-ref", p,
-                      "group key reads the hoisted hash column "
-                      f"(index {hash_idx}) — keys must read data "
-                      "columns only")
-
-
-def _walk_refs(e: Expr):
-    if isinstance(e, ColumnRef):
-        yield e
-    elif isinstance(e, Func):
-        for a in e.args:
-            yield from _walk_refs(a)
 
 
 # --------------------------------------------------------------------- #
@@ -746,16 +680,6 @@ def fusion_signature(dag: D.CopNode) -> Optional[tuple]:
     - ``('inprog-agg',)`` — an Aggregation whose whole merge happens
       in-program (SCALAR/DENSE) with no expanding join in the chain
       (extras drive a per-task regrow loop).
-    - ``('segment-agg', num_buckets)`` — a SEGMENT (radix-partitioned
-      high-NDV) aggregation: host-merged group tables fuse via a
-      per-member sharded out_spec, but ONLY among identical bucket
-      spaces — the bucket count is part of the signature, so tasks with
-      incompatible bucket shapes refuse to group at the key level
-      instead of silently degrading to per-program launches.
-    - ``('scatter-agg', num_buckets, passes)`` — the SCATTER (multi-
-      pass scatter radix partition) twin: bucket space AND priced pass
-      count are both part of the class, so members always agree on the
-      partition program shape (a regrown bucket space changes both).
     - ``('sort-agg', group_capacity)`` — a SORT aggregation whose
       group-table capacity is a concrete power of two (the capacity-
       bucketed shape classes of the fusion-breadth follow-on: the
@@ -792,11 +716,6 @@ def fusion_signature(dag: D.CopNode) -> Optional[tuple]:
         return None
     if dag.strategy == D.GroupStrategy.SORT:
         return ("sort-agg", dag.group_capacity)
-    if dag.strategy == D.GroupStrategy.SCATTER:
-        return ("scatter-agg", dag.num_buckets,
-                D.radix_passes(dag.num_buckets))
-    if dag.strategy == D.GroupStrategy.SEGMENT:
-        return ("segment-agg", dag.num_buckets)
     if dag.narrow_sums:
         # proven-narrow members only fuse with members proving the SAME
         # slots narrow: the fused leaves' state layouts (single word vs
@@ -827,12 +746,12 @@ def verify_fusion_group(tasks: Sequence) -> None:
                   f"member {type(t.dag).__name__} is not in a fusable "
                   "contract class")
         if sig != lead_sig:
-            # e.g. a SEGMENT member whose bucket space differs from the
-            # group's: refuse loudly instead of silently degrading
+            # e.g. a SORT member whose capacity differs from the group's:
+            # refuse loudly instead of silently degrading
             _fail("fusion-class", p,
                   f"member fusion signature {sig} disagrees with the "
-                  f"group's {lead_sig} (incompatible strategy or bucket "
-                  "shape)")
+                  f"group's {lead_sig} (incompatible strategy or "
+                  "capacity)")
         if t.key[1] != lead.key[1]:
             _fail("mesh-mismatch", p,
                   "fusion group members were keyed against different "

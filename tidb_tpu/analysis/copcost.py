@@ -71,8 +71,8 @@ CAP_BLOWUP_MAX = 64.0
 # the rule) is the degenerate large-NDV dense plan — state vectors
 # dwarf the data; the strategy that 1000x-cliffed and then crashed the
 # real-TPU hndv rung at sf>=10.  A gate finding on corpus plans and a
-# CostError at sched admission, so selection falls back to the SEGMENT
-# strategy instead of faulting the device.
+# CostError at sched admission, so the statement is grouped by SORT
+# instead of faulting the device.
 DENSE_BLOWUP_MAX = 16.0
 DENSE_BLOWUP_MIN_GROUPS = 1_000_000
 # Validated prediction band: on the 8-vdev CPU mesh, peak_hbm_bytes
@@ -145,9 +145,6 @@ class LaunchCost:
     # ((path, num_groups, rows_per_device), ...) per degenerate DENSE agg
     # (group states > DENSE_BLOWUP_MAX x the per-device input rows)
     dense_blowups: tuple = ()
-    # ((path, passes, num_buckets), ...) per SCATTER agg whose priced
-    # radix pass count exceeds MAX_RADIX_PASSES (COST-RADIX-PASSES)
-    radix_blowups: tuple = ()
     # node paths for which no static bound could be derived
     unbounded: tuple = ()
     # ((label, bytes), ...) largest-first, for reports/EXPLAIN
@@ -199,7 +196,6 @@ class LaunchCost:
             self.live_cells + other.live_cells,
             self.expanding_joins + other.expanding_joins,
             self.dense_blowups + other.dense_blowups,
-            self.radix_blowups + other.radix_blowups,
             self.unbounded + other.unbounded,
             self.breakdown + other.breakdown,
             self.donated_bytes + other.donated_bytes,
@@ -283,14 +279,13 @@ class _Acc:
     """Per-device walk accumulator; totals multiply by D at rollup."""
 
     __slots__ = ("inter", "flops", "joins", "dense_blowups",
-                 "radix_blowups", "unbounded", "breakdown")
+                 "unbounded", "breakdown")
 
     def __init__(self):
         self.inter = 0
         self.flops = 0
         self.joins = []         # (path, out_capacity, probe_rows)
         self.dense_blowups = []  # (path, num_groups, rows)
-        self.radix_blowups = []  # (path, passes, num_buckets)
         self.unbounded = []
         self.breakdown = []     # (label, per-device bytes)
 
@@ -315,7 +310,7 @@ def _agg_state_width(a: D.AggDesc, narrow: bool = False) -> int:
 
 
 def _agg_groups(agg: D.Aggregation, rows: int) -> int:
-    """Static bound on the per-device group-state rows.  SORT/SEGMENT
+    """Static bound on the per-device group-state rows.  SORT
     capacity 0 means "client starts at its default and regrows" — the
     static bound is the per-device row count itself (distinct groups
     cannot exceed contributing rows), so every corpus shape stays
@@ -324,7 +319,7 @@ def _agg_groups(agg: D.Aggregation, rows: int) -> int:
         return 1
     if agg.strategy == D.GroupStrategy.DENSE:
         return max(agg.num_groups, 1)
-    cap = agg.state_capacity
+    cap = agg.group_capacity
     return cap if cap > 0 else max(min(rows, _default_group_capacity()), 1)
 
 
@@ -392,54 +387,19 @@ def _walk(node: D.CopNode, path: tuple, rows: int, layout: Layout,
             swidth += len(node.group_by) * 8 + 8       # keys + __ngroups__
             # device sort of (dead, nullflag/code per key, payload
             # index): the comparator carries 1 + 2*k lanes, and every
-            # lane rides every compare-exchange stage — the cost the
-            # radix strategies exist to shed (SURVEY.md §7)
+            # lane rides every compare-exchange stage
             acc.buf("/".join(p) + ":sort",
                     rows_in * (len(node.group_by) + 1) * 8)
             acc.flops += rows_in * _log2(rows_in) * (
                 1 + 2 * len(node.group_by))
-        elif node.strategy == D.GroupStrategy.SEGMENT:
-            swidth += len(node.group_by) * 8 + 8       # keys + __ngroups__
-            # avalanche hash (constant lanes per key) + ONE single-key
-            # radix partition pass of (hash, payload-index)
-            acc.buf("/".join(p) + ":radix", rows_in * 2 * 8)
-            acc.flops += rows_in * (6 * max(len(node.group_by), 1)
-                                    + _log2(rows_in))
-        elif node.strategy == D.GroupStrategy.SCATTER:
-            swidth += len(node.group_by) * 8 + 8       # keys + __ngroups__
-            passes = D.radix_passes(node.num_buckets
-                                    or max(rows_in, 2))
-            n_digits = 1 << D.RADIX_BITS
-            n_tiles = max(rows_in // D.RADIX_TILE, 1)
-            # per pass: a per-tile digit histogram, the tiny exclusive
-            # cumsum of bucket offsets, and the gather/scatter reorder
-            # of the int32 index permutation — O(passes * n) streaming
-            # data movement, NO comparator lanes.  Buffers (reused
-            # across passes, priced once): per-tile histograms +
-            # offsets + the int32 permutation ping-pong — under half of
-            # SEGMENT's (hash, index) int64 sort operands per row, the
-            # bytes half of the acceptance comparison (flops being the
-            # other: 3*passes streaming ops vs n*log2(n) comparator
-            # stages).
-            acc.buf("/".join(p) + ":radix-hist", n_tiles * n_digits * 4)
-            acc.buf("/".join(p) + ":radix-cumsum",
-                    n_tiles * n_digits * 4)
-            acc.buf("/".join(p) + ":radix-scatter", rows_in * 2 * 4)
-            # hash (6 lanes/key, as SEGMENT) + per pass: digit extract,
-            # histogram add, scatter store (3 ops/row)
-            acc.flops += rows_in * (6 * max(len(node.group_by), 1)
-                                    + 3 * passes)
-            if passes > D.MAX_RADIX_PASSES:
-                acc.radix_blowups.append(
-                    ("/".join(p), passes, node.num_buckets))
         acc.buf("/".join(p) + ":states", groups * swidth)
         if node.strategy == D.GroupStrategy.DENSE \
                 and groups > DENSE_BLOWUP_MIN_GROUPS \
                 and groups > DENSE_BLOWUP_MAX * max(rows_in, 1):
             # degenerate dense domain: the state vector dwarfs the data
-            # it aggregates — large-NDV keys must take SEGMENT instead
+            # it aggregates — large-NDV keys must group by SORT instead
             acc.dense_blowups.append(("/".join(p), groups, rows_in))
-        if node.strategy not in D.HOST_MERGE_STRATEGIES:
+        if not node.host_merged:
             # psum-merged states come back replicated; MIN/MAX ride the
             # psum-gather trick whose slot array is Dx the state
             acc.buf("/".join(p) + ":merged", groups * swidth)
@@ -543,8 +503,8 @@ def _dag_walk_cached(dag: D.CopNode, layout: Layout,
     acc.buf("flatten:base_sel", rows0 * _VALIDITY_BYTES)
     rows_out, w_out = _walk(dag, (), rows0, layout, widths, acc)
     return (acc.inter, acc.flops, tuple(acc.joins),
-            tuple(acc.dense_blowups), tuple(acc.radix_blowups),
-            tuple(acc.unbounded), tuple(acc.breakdown), rows_out, w_out)
+            tuple(acc.dense_blowups), tuple(acc.unbounded),
+            tuple(acc.breakdown), rows_out, w_out)
 
 
 def chain_rows(dag: D.CopNode, layout: Layout,
@@ -568,7 +528,7 @@ def _collective_breakdown(dag: D.CopNode, layout: Layout,
     per link (parallel/topology).  In-program psum merges (SCALAR/DENSE
     incl. the psum-gather MIN/MAX trick, whose constant factor
     calibration absorbs per digest) exchange each member's state table
-    across the mesh; host-merged group tables (SORT/SEGMENT/SCATTER)
+    across the mesh; host-merged group tables (SORT)
     leave the device over PCIe — their D2H bytes already ride
     ``output_bytes``, so per-host routing adds nothing here, while the
     coordinator anti-route is priced as DCI so reports can show what
@@ -581,7 +541,7 @@ def _collective_breakdown(dag: D.CopNode, layout: Layout,
             continue
         rows_out, w_out = chain_rows(m, layout, widths)
         state_bytes = rows_out * w_out
-        if m.strategy in D.HOST_MERGE_STRATEGIES:
+        if m.host_merged:
             if merge_route == T.MERGE_COORDINATOR and topology.multi_host:
                 bd = bd.combined(T.TransferBreakdown(
                     dci=(topology.n_devices - topology.devices_per_host)
@@ -627,12 +587,12 @@ def dag_cost(dag: D.CopNode, layout: Layout,
     fusion caps topology-aware with no runtime change."""
     d = max(layout.n_devices, 1)
     topo = topology if topology is not None else _default_topology(d)
-    (inter_pd, flops_pd, joins, dense_blowups, radix_blowups, unbounded,
+    (inter_pd, flops_pd, joins, dense_blowups, unbounded,
      breakdown, rows_out, w_out) = _dag_walk_cached(dag, layout, widths)
     root = dag.members[-1] if isinstance(dag, D.FusedDag) and dag.members \
         else dag
     if isinstance(root, D.Aggregation):
-        if root.strategy in D.HOST_MERGE_STRATEGIES:
+        if root.host_merged:
             out_bytes = d * rows_out * w_out      # per-device host merge
         else:
             out_bytes = rows_out * w_out          # replicated, one D2H copy
@@ -662,7 +622,6 @@ def dag_cost(dag: D.CopNode, layout: Layout,
         or layout.padded_rows,
         expanding_joins=joins,
         dense_blowups=dense_blowups,
-        radix_blowups=radix_blowups,
         unbounded=unbounded,
         breakdown=tuple(sorted(breakdown, key=lambda kv: -kv[1])[:8]),
         donated_bytes=donated,
@@ -961,15 +920,7 @@ def cost_findings(plans, n_devices: int = 8) -> list:
                 f"{rows} per-device rows "
                 f"({groups / max(rows, 1):.0f}x > "
                 f"{DENSE_BLOWUP_MAX:.0f}x): degenerate large-NDV dense "
-                f"domain, use a radix strategy ({one_line})"))
-        for path, passes, buckets in cost.radix_blowups:
-            out.append(Finding(
-                "COST-RADIX-PASSES", qid, 0, path.split("/")[-1],
-                f"SCATTER aggregation over {buckets} buckets prices "
-                f"{passes} radix passes (> {D.MAX_RADIX_PASSES}): each "
-                "pass is a full-data reorder — a malformed bucket space "
-                f"costs more movement than the sort it replaces "
-                f"({one_line})"))
+                f"domain, group by SORT ({one_line})"))
         for path in cost.unbounded:
             out.append(Finding(
                 "COST-UNBOUNDED", qid, 0, path.split("/")[-1],

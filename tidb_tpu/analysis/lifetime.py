@@ -18,7 +18,7 @@ contract DAG alone (no tracing, no device touch, no jax import):
   resident registry backs the static class with a runtime guard.
 - ``LOOP_CARRIED`` — inputs the client feeds back into the next launch
   of the same program (store/client.py regrow disciplines: the rows
-  paging loop, SORT/SEGMENT group-capacity regrow, expanding-join
+  paging loop, SORT group-capacity regrow, expanding-join
   capacity regrow).  Donating one would delete the array the next
   iteration re-reads.
 - ``EPHEMERAL``   — dead after the launch: streamed HBM batches
@@ -150,7 +150,7 @@ def scan_lifetime(dag: D.CopNode) -> Tuple[BufferClass, str]:
         return (BufferClass.LOOP_CARRIED,
                 "rows paging loop re-feeds the inputs on overflow "
                 "(store/client._execute_rows_once)")
-    if dag.strategy in D.HOST_MERGE_STRATEGIES:
+    if dag.host_merged:
         return (BufferClass.LOOP_CARRIED,
                 "group-capacity regrow re-feeds the inputs "
                 "(store/client._execute_sort_agg)")

@@ -142,7 +142,7 @@ from typing import Iterable, Optional
 # dual-backend (np|jnp) evaluator and its host-object op implementations
 # legitimately concretize when xp is numpy.
 TRACED_MODULES = {
-    "copr/exec.py", "copr/join.py", "copr/segment.py", "copr/radix.py",
+    "copr/exec.py", "copr/join.py",
     "parallel/spmd.py", "parallel/shuffle.py", "parallel/window.py",
     "parallel/exchange.py",
     # shardflow (ISSUE 12): the topology model and the sharding-flow

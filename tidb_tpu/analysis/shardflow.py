@@ -142,7 +142,7 @@ def _agg_needs_limb_fence(agg: D.Aggregation) -> bool:
     float sums, counts, host-merged programs, and valueflow-proven
     narrow SUMs (whole-table no-wrap proof subsumes the row fence) are
     exempt."""
-    if agg.strategy in D.HOST_MERGE_STRATEGIES:
+    if agg.host_merged:
         return False
     K = dt.TypeKind
     return any(a.func == D.AggFunc.SUM and a.arg is not None
@@ -183,7 +183,7 @@ def _flow(node: D.CopNode, topo: MeshTopology, path: tuple,
               "collective; route the exchange explicitly")
 
     if isinstance(node, D.Aggregation):
-        if node.strategy in D.HOST_MERGE_STRATEGIES:
+        if node.host_merged:
             # per-device group tables leave the device for the host
             # merge: on a multi-host topology the merge must route per
             # host — one coordinator host pulling every remote device's

@@ -514,7 +514,7 @@ def _check_agg(node: D.Aggregation, env: tuple, rows: int, strict: bool,
                       f"the 2^62 single-word bound — re-ANALYZE or drop "
                       "the narrow stamp")
         elif strict and rows >= PSUM_LIMB_ROWS \
-                and node.strategy not in D.HOST_MERGE_STRATEGIES:
+                and not node.host_merged:
             # value-aware generalization of the 2^31 row fence: past it,
             # the (hi, lo) limb psum stays exact only if the interval
             # proves the hi-limb sum cannot wrap

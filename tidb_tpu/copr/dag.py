@@ -110,70 +110,9 @@ class Expand(CopNode):
 class GroupStrategy(enum.Enum):
     SCALAR = "scalar"    # no GROUP BY: one output row
     DENSE = "dense"      # small known key domain -> dense group ids
-    SORT = "sort"        # device multi-key sort + segment reduce
-    SEGMENT = "segment"  # hash -> radix bucket partition + segment reduce
-                         # (high NDV: one single-key sort regardless of key
-                         # arity, bucket count from stats/copcost)
-    SCATTER = "scatter"  # hash -> MULTI-PASS scatter radix partition +
-                         # segment reduce (copr/radix.py): per-pass bucket
-                         # histogram + exclusive-cumsum offsets + stable
-                         # gather/scatter reorder, O(passes*n) data
-                         # movement instead of lax.sort's O(n log n)
-                         # comparator lanes
-
-
-# strategies whose per-device group tables merge HOST-side (per-device
-# group sets are not aligned, so there is no elementwise collective
-# merge); consumers: spmd/shuffle host_merge policy, the client's
-# regrow loop, contracts/fusion classes
-HOST_MERGE_STRATEGIES = (GroupStrategy.SORT, GroupStrategy.SEGMENT,
-                         GroupStrategy.SCATTER)
-
-# strategies whose per-device group table is a pow2 `num_buckets` radix
-# space regrown from observed __ngroups__ (the hash-partitioned pair)
-RADIX_STRATEGIES = (GroupStrategy.SEGMENT, GroupStrategy.SCATTER)
-
-# SCATTER radix geometry (jax-free so contracts/copcost can price passes
-# without importing jax): each pass orders RADIX_BITS of the partition
-# key, lowered as RADIX_BITS 1-bit stable partition subpasses.
-RADIX_BITS = 8
-# residual hash bits ordered BELOW the log2(B) bucket bits: two groups
-# colliding in the bucket bits alone would interleave into per-run
-# duplicate segments (the table overflows toward O(rows) at modest
-# NDV); eight residual bits cut that collision space 256x for under one
-# extra pass, so observed __ngroups__ stays ~NDV like SEGMENT's
-# full-hash ordering.  Remaining collisions are the usual duplicates,
-# merged host-side by true key equality.
-RADIX_RESIDUAL_BITS = 8
-# the partition key must fit int32: bucket + residual bits clamp to 30,
-# plus one dead-row tail bit above them
-RADIX_KEY_BITS_MAX = 30
-# rows per histogram tile in the copcost pricing of a pass (the per-tile
-# histogram/offset scratch the model charges beside the permutation)
-RADIX_TILE = 512
-# contract ceiling on the pass count: above this the partition does more
-# full-data passes than the comparator sort it replaces would ever pay —
-# a malformed (astronomically regrown) bucket space, rejected pre-trace
-# and surfaced as a COST-RADIX-PASSES gate finding
-MAX_RADIX_PASSES = 8
-
-
-def radix_key_bits(num_buckets: int) -> int:
-    """Ordered partition-key bits for a pow2 bucket space: log2(B)
-    bucket bits + residual bits (int32-clamped) + the dead-row tail
-    bit.  Shared by the kernels, copcost pricing, and contracts."""
-    log2b = max(int(num_buckets - 1).bit_length(), 0)
-    return min(log2b + RADIX_RESIDUAL_BITS, RADIX_KEY_BITS_MAX) + 1
-
-
-def radix_passes(num_buckets: int) -> int:
-    """Scatter-partition pass count, RADIX_BITS digit bits per pass.
-    The copcost pricing, the contract ceiling, the fusion signature,
-    and the kernels all share this one formula.  Computed from the raw
-    (unclamped) bit span so an absurd bucket space PRICES absurd —
-    the COST-RADIX-PASSES / capacity-shape seam."""
-    log2b = max(int(num_buckets - 1).bit_length(), 0)
-    return -(-(log2b + RADIX_RESIDUAL_BITS + 1) // RADIX_BITS)
+    SORT = "sort"        # device sort by the group key + a reduce of the
+                         # runs into a per-device group table, merged on
+                         # the host (`Aggregation.host_merged`)
 
 
 # metadata of a node's field that came after programs were named by their
@@ -213,19 +152,6 @@ class Aggregation(CopNode):
     reduces into a dense (prod(domain_sizes),) state vector — the psum seam.
     SORT strategy handles unbounded domains via multi-key sort +
     segment-reduce into a fixed-capacity group table.
-    SEGMENT strategy is the high-NDV device path: group keys avalanche-hash
-    to a power-of-two `num_buckets` radix space whose top bits are the
-    bucket id, ONE single-key partition pass orders rows bucket-major
-    (residual hash ordering inside each bucket comes free), and each
-    bucket's runs segment-reduce into a (num_buckets,) state table
-    (copr/segment.py).
-    SCATTER strategy replaces that single giant sort with a multi-pass
-    scatter radix partition (copr/radix.py): radix_passes(num_buckets)
-    stable counting-sort passes (histogram + exclusive cumsum + scatter
-    reorder) order rows bucket-major in O(passes*n) data movement.
-    `prehashed` (SEGMENT/SCATTER): the LAST scan column carries the
-    precomputed per-row key hash, so bucket-space regrow re-entries skip
-    re-hashing the key tuple (store/client hoists it once per statement).
     `narrow_sums` (SCALAR/DENSE): agg indexes whose int/decimal SUM the
     planner PROVED (analysis/valueflow, from ANALYZEd column stats) can
     never escape int64 across the whole table — those states accumulate
@@ -250,10 +176,6 @@ class Aggregation(CopNode):
     strategy: GroupStrategy = GroupStrategy.SCALAR
     domain_sizes: Tuple[int, ...] = ()   # DENSE only, aligned with group_by
     group_capacity: int = 0              # SORT only: max distinct groups/shard
-    num_buckets: int = 0                 # SEGMENT/SCATTER: pow2 radix space
-                                         # = state-table capacity per device
-    prehashed: bool = False              # SEGMENT/SCATTER: last scan column
-                                         # is the hoisted int64 key hash
     narrow_sums: Tuple[int, ...] = ()    # SCALAR/DENSE: agg indexes with a
                                          # valueflow-proven single-word SUM
     pack_words: int = field(             # SORT: words of the exact record
@@ -274,11 +196,12 @@ class Aggregation(CopNode):
         return n
 
     @property
-    def state_capacity(self) -> int:
-        """Per-device group-table capacity of a host-merged strategy."""
-        return (self.num_buckets
-                if self.strategy in RADIX_STRATEGIES
-                else self.group_capacity)
+    def host_merged(self) -> bool:
+        """The devices' group tables merge on the host: their group sets
+        are not aligned, so no elementwise collective merges them (what
+        spmd/shuffle hand back, the client's regrow loop, the contracts
+        and the cost model all turn on)."""
+        return self.strategy is GroupStrategy.SORT
 
 
 def wide_groups(agg: Aggregation) -> Aggregation:
@@ -1032,9 +955,7 @@ def dag_digest(node: CopNode) -> int:
 
 __all__ = [
     "AggFunc", "AggDesc", "CopNode", "TableScan", "Selection", "Projection",
-    "Expand", "GroupStrategy", "HOST_MERGE_STRATEGIES", "RADIX_STRATEGIES",
-    "RADIX_BITS", "RADIX_RESIDUAL_BITS", "MAX_RADIX_PASSES",
-    "radix_passes", "radix_key_bits", "Aggregation",
+    "Expand", "GroupStrategy", "Aggregation",
     "TopN", "TOPN_MIN_BLOCK", "topn_block_len",
     "COMPACT_COLUMNS", "probe_capacity_for", "exchange_capacity_for",
     "exchange_capacity_round", "exchanging_join", "groups_whole",

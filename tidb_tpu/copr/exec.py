@@ -280,29 +280,16 @@ def _agg_partial_states(agg: D.Aggregation, batch: DeviceBatch, ev: Evaluator,
     (pkg/executor/aggregate/agg_hash_executor.go:94); hash tables lose to
     sort+segment ops on TPU (SURVEY.md §7 hard part 4).  On a TPU its
     COUNTs and integer SUMs are lowered by copr/runagg (the aggregates'
-    inputs travel with the sort; no gather or scatter a slot).
-    SEGMENT: the high-NDV refinement — keys avalanche-hash into one
-    uint64 radix space, a SINGLE-key partition pass buckets rows, and
-    each bucket's runs segment-reduce (copr/segment.py).
-    SCATTER: SEGMENT with the giant sort replaced by a multi-pass
-    scatter radix partition — histogram + exclusive cumsum + stable
-    scatter reorder per pass, O(passes*n) data movement
-    (copr/radix.py).
+    inputs travel with the sort; no gather or scatter a slot); a MIN,
+    a MAX, a float SUM and the CPU mesh keep `_agg_sort_states`.
     Adds '__rows__' (COUNT(*) per group) for occupancy.
     """
-    if agg.strategy in D.HOST_MERGE_STRATEGIES:
+    if agg.host_merged:
         # what the launch says of itself (copr/facts.py)
         batch.facts["agg_strategy"] = agg.strategy.value
-        batch.facts["group_capacity"] = agg.state_capacity
+        batch.facts["group_capacity"] = agg.group_capacity
         if agg.topn is not None:    # copr/runagg alone ranks on the device
             batch.facts["group_topn"] = "host"
-    if agg.strategy == D.GroupStrategy.SCATTER:
-        from .radix import agg_scatter_states
-        return agg_scatter_states(agg, batch, ev, memo)
-    if agg.strategy == D.GroupStrategy.SEGMENT:
-        from .segment import agg_segment_states
-        return agg_segment_states(agg, batch, ev, memo)
-    if agg.strategy == D.GroupStrategy.SORT:
         from .runagg import agg_run_states, run_form
         if ev.platform == "tpu" and run_form(agg):
             return agg_run_states(agg, batch, ev, memo)
@@ -511,8 +498,8 @@ def _dense_limb_states(agg: D.Aggregation, batch: DeviceBatch, ev: Evaluator,
 def group_keyinfo(agg: D.Aggregation, batch: DeviceBatch, ev: Evaluator,
                   memo: dict, n: int) -> list:
     """Canonical per-group-key (zeroed value, mask, null flag, order-
-    preserving int64 code) tuples — the shared key representation of the
-    SORT and SEGMENT strategies.  NULL values are zeroed so all NULLs
+    preserving int64 code) tuples — the key representation both
+    lowerings of SORT share.  NULL values are zeroed so all NULLs
     share one group; -0.0 groups with +0.0 (SQL equality, not bit
     equality)."""
     keyinfo = []

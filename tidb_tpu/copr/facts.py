@@ -39,7 +39,7 @@ def _exchanges(root) -> bool:
 
 def _host_merged(root) -> bool:
     return isinstance(root, D.Aggregation) \
-        and root.strategy in D.HOST_MERGE_STRATEGIES
+        and root.host_merged
 
 
 @dataclass(frozen=True)

@@ -1,17 +1,15 @@
-"""Host (CPU) execution of SORT/SEGMENT/SCATTER-strategy group-by
-aggregation.
+"""Host (CPU) execution of SORT-strategy group-by aggregation.
 
 Per-platform engine choice (VERDICT r2 #2): the reference aggregates
 high-NDV group-by with a CPU hash table (parallel HashAgg,
 pkg/executor/aggregate/agg_hash_executor.go:94).  The TPU answer is the
-device sort/radix-partition + segment-reduce programs
-(copr/exec._agg_sort_states, copr/segment.py), but those programs
-lowered to XLA-CPU measured 56x slower than numpy's sorting unique.  So
-on a CPU mesh the CopClient routes the whole aggregation here:
-one np.unique (plus a stable argsort when any aggregate needs per-row
-segment reduction) producing the exact same partial-state pytree the
-device program emits, so merge/finalize stay one code path
-(copr/aggregate.merge_sorted_states).
+device sort + run-reduce programs (copr/exec._agg_sort_states,
+copr/runagg.py), but those programs lowered to XLA-CPU measured 56x
+slower than numpy's sorting unique.  So on a CPU mesh the CopClient
+routes the whole aggregation here: one np.unique (plus a stable argsort
+when any aggregate needs per-row segment reduction) producing the exact
+same partial-state pytree the device program emits, so merge/finalize
+stay one code path (copr/aggregate.merge_sorted_states).
 
 The hot shape — single non-nullable int64 key, COUNT(*) only — reduces to
 exactly `np.unique(key, return_index, return_counts)`, i.e. the numpy

@@ -6,10 +6,10 @@ Reference analog: the reference's answer to a high-NDV GROUP BY is the
 parallel HashAgg (pkg/executor/aggregate/agg_hash_executor.go:94); a TPU
 has no fast scatter (0.75 s for 2^23 elements on a v5e) and an XLA
 gather costs 7-10 ns an index whatever the table, so what
-`exec._agg_sort_states` and `segment.states_from_partition` do a slot
-(gather every column through the sort's permutation, scatter every state
-into the table) is seconds a statement.  Here the group key AND what the
-aggregates read travel with the sort, as one record a row:
+`exec._agg_sort_states` does a slot (gather every column through the
+sort's permutation, scatter every state into the table) is seconds a
+statement.  Here the group key AND what the aggregates read travel with
+the sort, as one record a row:
 
 1. *The record.*  Per row, most significant first: a dead bit, per key
    an optional NULL bit and the key, per aggregate an optional NULL bit
@@ -21,7 +21,7 @@ aggregates read travel with the sort, as one record a row:
    (`__bits__`) and the dispatcher reruns the statement wider.  In the
    **wide** form (`pack_words` 0) the layout is static, from the
    dtypes: the sort key is 31 bits of a hash of the key tuple
-   (`segment.key_hash`) and the keys ride as payload.  A **dependent**
+   (`key_hash`) and the keys ride as payload.  A **dependent**
    key (`dag.Aggregation.dependent`: a function of the other keys, as
    the columns a unique lookup join brings are of its probe key) is in
    neither form part of the key: every row of a run holds the same
@@ -74,6 +74,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..ops.limbscan import limb_count, limb_cumsum
@@ -85,6 +86,12 @@ from .join import COMPACT_COLUMNS, gather_rows, live_rows, _tile_order
 K = dt.TypeKind
 _U64 = jnp.uint64
 
+# splitmix64 finalizer constants (Steele et al.); numpy scalars so the
+# uint64 lanes stay 64-bit regardless of the embedder's x64 default
+_GOLDEN = np.uint64(0x9E3779B97F4A7C15)
+_MIX1 = np.uint64(0xBF58476D1CE4E5B9)
+_MIX2 = np.uint64(0x94D049BB133111EB)
+
 
 def run_form(agg: D.Aggregation) -> bool:
     """Can this form compute the aggregation?  COUNTs and integer or
@@ -95,6 +102,31 @@ def run_form(agg: D.Aggregation) -> bool:
         or (a.func == D.AggFunc.SUM
             and a.arg.dtype.kind not in (K.FLOAT64, K.FLOAT32))
         for a in agg.aggs)
+
+
+# --------------------------------------------------------------------- #
+# the wide form's sort key
+# --------------------------------------------------------------------- #
+
+def _finalize64(z):
+    """splitmix64 avalanche: every input bit reaches every output bit."""
+    z = (z ^ (z >> np.uint64(30))) * _MIX1
+    z = (z ^ (z >> np.uint64(27))) * _MIX2
+    return z ^ (z >> np.uint64(31))
+
+
+def key_hash(keyinfo, n):
+    """One uint64 avalanche hash per row over the canonical key tuple
+    (`exec.group_keyinfo`).  NULL flags fold in (a NULL key and a zero
+    key should land apart; exactness does not depend on it — the run
+    boundaries compare the keys themselves)."""
+    h = jnp.full((n,), _GOLDEN, jnp.uint64)
+    for _vz, m, nullf, code in keyinfo:
+        cu = code.astype(jnp.uint64)
+        if m is not True:
+            cu = cu + nullf.astype(jnp.uint64) * _GOLDEN
+        h = _finalize64(h ^ cu)
+    return h
 
 
 # --------------------------------------------------------------------- #
@@ -306,7 +338,6 @@ def agg_run_states(agg: D.Aggregation, batch, ev, memo: dict) -> dict:
     module's docstring describes."""
     from .exec import (_ensure_array, _limb_row_fence, _sel_array,
                        group_keyinfo)
-    from .segment import batch_hash
     G = agg.group_capacity
     assert G > 0, "SORT aggregation needs group_capacity"
     n0 = len(batch.cols[0][0]) if batch.cols else 0
@@ -337,8 +368,8 @@ def agg_run_states(agg: D.Aggregation, batch, ev, memo: dict) -> dict:
                                           agg.pack_words, riders)
         states["__bits__"] = bits.astype(jnp.int64)
     else:
-        hashed = batch_hash(agg, batch, [k for j, k in enumerate(keyinfo)
-                                         if j not in riders], n)
+        hashed = key_hash([k for j, k in enumerate(keyinfo)
+                           if j not in riders], n)
         words, read = _wide_record(keys, aggs, sel, hashed, riders)
     with jax.named_scope("sort"):
         words = lax.sort(tuple(_tile_order(w, stacked) for w in words),

@@ -34,15 +34,6 @@ K = dt.TypeKind
 
 MAX_DENSE_GROUPS = 1_000_000
 
-# NDV threshold between the two unbounded-domain device strategies: at or
-# above this estimated distinct-group capacity the planner picks SEGMENT
-# (hash -> radix bucket partition, ONE single-key sort lane, copcost-
-# derived pow2 bucket space) over SORT (multi-key comparator, 1 + 2*k
-# lanes) — the multi-operand sort is what turned the real-TPU 2M-group
-# bench rung into a 1000x cliff (0.05x numpy, ROADMAP.md's 2026-07-31
-# table).
-SEGMENT_MIN_NDV = 1 << 15
-
 # stats handle for the CURRENT planning pass (set by the session around
 # to_physical — the SUBQUERY_EXECUTOR contextvar precedent); consumers:
 # SORT-agg group-table capacity from column NDV, so fresh auto-analyze
@@ -282,7 +273,7 @@ def _push_group_topn(top: LogicalTopN, child: PhysOp) -> PhysOp:
     dag = getattr(cop, "dag", None)
     if not isinstance(cop, (CopTaskExec, CopJoinTaskExec)) \
             or not isinstance(dag, D.Aggregation) \
-            or dag.strategy not in D.HOST_MERGE_STRATEGIES:
+            or not dag.host_merged:
         return child
     keys = []
     for e, desc in top.keys:
@@ -1600,17 +1591,11 @@ def _bind_agg(agg: LogicalAggregate, child: D.CopNode, dicts,
         # still bounds NDV when stats are absent
         known_total = total
 
-    # SORT / SEGMENT / SCATTER for everything else orderable: device
-    # partition + segment-reduce handles arbitrary NDV (the reference's
+    # SORT for everything else orderable: a device sort by the group key
+    # and a reduce of its runs handles arbitrary NDV (the reference's
     # high-NDV parallel HashAgg, agg_hash_executor.go:94, re-designed for
     # TPU — SURVEY.md §7 hard part 4: sort-based group-by beats hashing
-    # on TPU).  Above SEGMENT_MIN_NDV estimated groups the radix-
-    # partitioned strategies win (one single-key partition lane instead
-    # of the SORT comparator's 1 + 2*k); between them — and SORT —
-    # selection is ARBITRATED per digest: the static copcost model
-    # prices each candidate and PR 10's calibration store bends each
-    # prediction by its measured time_factor, so a digest measured fast
-    # on real hardware flips selection with no code change.
+    # on TPU)
     metas = []
     lowered = []
     for g in agg.group_exprs:
@@ -1636,21 +1621,10 @@ def _bind_agg(agg: LogicalAggregate, child: D.CopNode, dicts,
     if _mesh_platform() == "tpu" and run_form(sort):
         # a TPU reduces the runs of ONE sort whose records carry what
         # the aggregates read (copr/runagg), whatever the NDV: SORT, in
-        # as few words as the columns' statistics say a record takes.
-        # Nothing is priced: the other forms gather and scatter a slot
-        # (seconds a statement at 2^23 slots: PERF.md section 6, PR 29)
+        # as few words as the columns' statistics say a record takes
         import dataclasses
         return dataclasses.replace(
             sort, pack_words=_pack_words(sort, ds))
-    if cap >= SEGMENT_MIN_NDV:
-        candidates = (
-            D.Aggregation(child, tuple(lowered), tuple(descs),
-                          D.GroupStrategy.SCATTER, num_buckets=cap),
-            D.Aggregation(child, tuple(lowered), tuple(descs),
-                          D.GroupStrategy.SEGMENT, num_buckets=cap),
-            sort,
-        )
-        return _arbitrate_strategy(candidates, ds)
     return sort
 
 
@@ -1702,63 +1676,19 @@ def _pack_words(agg: D.Aggregation, ds, unknown: int = 0) -> int:
     return 1 if key + rest <= 32 else 2
 
 
-# device count the arbitration prices the CPU mesh's candidates for: the
-# 8-vdev convention every plan-level copcost consumer uses (plan_cost
-# default).  A TPU's choice is not priced (`_bind_agg`); what it reads
-# of the mesh, it reads of the program's (`_mesh_platform`)
-_ARBITRATE_DEVICES = 8
-
-
-def _arbitrate_strategy(candidates, ds) -> D.Aggregation:
-    """Calibration-arbitrated high-NDV strategy choice: price every
-    candidate dag with the static copcost walk over the table's real
-    layout (a nominal one when stats/snapshot are unavailable), bend
-    each prediction by the candidate digest's MEASURED time_factor
-    (analysis/calibrate.arbitrated_ms, clamped), pick the cheapest —
-    first wins ties, so the declaration order (SCATTER, SEGMENT, SORT)
-    is the static preference.  Any pricing failure falls back to the
-    first candidate rather than failing the plan."""
-    try:
-        from ..analysis.calibrate import arbitrated_ms
-        from ..analysis.compilekey import stable_digest
-        from ..analysis.copcost import (Layout, dag_cost, snapshot_layout,
-                                        snapshot_scan_widths)
-        layout = widths = None
-        if ds is not None:
-            try:
-                snap = ds.table.snapshot()
-                layout = snapshot_layout(snap, _ARBITRATE_DEVICES)
-                widths = snapshot_scan_widths(snap)
-            except (AttributeError, TypeError, ValueError):
-                layout = widths = None
-        if layout is None:
-            layout = Layout(_ARBITRATE_DEVICES, 1 << 18,
-                            _ARBITRATE_DEVICES, 1 << 21)
-        best, best_ms = candidates[0], None
-        for dag in candidates:
-            ms = arbitrated_ms(stable_digest(dag),
-                               dag_cost(dag, layout, widths))
-            if best_ms is None or ms < best_ms:
-                best, best_ms = dag, ms
-        return best
-    except (ImportError, AttributeError, TypeError, ValueError):
-        return candidates[0]
-
-
 def _cap_pow2(total: int) -> int:
     """25% headroom, pow2-rounded, bounded to [1024, 2^22] — the shape
-    every group-table capacity / bucket count takes."""
+    every group-table capacity takes."""
     cap = 1 << (int(total * 1.25) - 1).bit_length()
     return max(1024, min(cap, 1 << 22))
 
 
 def _ndv_capacity(agg, ds) -> int:
-    """Initial SORT/SEGMENT group-table capacity from stats NDV (the
+    """Initial SORT group-table capacity from stats NDV (the
     consumer half of auto-analyze, VERDICT r2 #8): product of per-key
     NDVs with 25% headroom, pow2-rounded, bounded — 0 when stats are
     absent (the client then starts at its default and regrows from
-    observed __ngroups__).  Doubles as the strategy-selection NDV
-    estimate (SEGMENT above SEGMENT_MIN_NDV)."""
+    observed __ngroups__)."""
     handle = STATS_HANDLE.get()
     if handle is None or ds is None:
         return 0

@@ -138,7 +138,7 @@ class FaultRule:
 
 def _mix(*vals: int) -> int:
     """splitmix64-style avalanche over the inputs: the deterministic
-    dice (same idiom as copr/segment's key hash)."""
+    dice (same idiom as copr/runagg's key hash)."""
     x = 0x9E3779B97F4A7C15
     for v in vals:
         x ^= v & _MASK

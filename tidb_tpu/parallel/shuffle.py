@@ -66,10 +66,9 @@ class ShardedShuffleJoinProgram:
         self.agg = spec.top if isinstance(spec.top, D.Aggregation) else None
         self.kind = "agg" if self.agg is not None else "rows"
         # same host-merge policy as ShardedCopProgram (see spmd.py): only
-        # SORT/SEGMENT group tables merge on host; MIN/MAX merge
+        # SORT group tables merge on host; MIN/MAX merge
         # in-program via the psum-gather trick
-        self.host_merge = (self.agg is not None and self.agg.strategy
-                           in D.HOST_MERGE_STRATEGIES)
+        self.host_merge = self.agg is not None and self.agg.host_merged
         # same limb-exactness fence as spmd.py: int/decimal SUM (hi, lo)
         # limb psum stays int64-exact only below 2^31 contributing rows
         from ..types.dtypes import TypeKind as _K

@@ -146,12 +146,11 @@ class ShardedCopProgram:
         self._traced: dict = {}
         # MIN/MAX merge IN-PROGRAM via _psum_gather (psum-only all_gather +
         # reduce), so the whole merge stays on device behind one kind of
-        # collective.  Only SORT/SEGMENT-strategy group
-        # tables merge host-side: per-device group sets aren't aligned, so
-        # there is no elementwise collective merge (the repartition-
-        # exchange path is the in-program alternative).
-        self.host_merge = (self.agg is not None and self.agg.strategy
-                           in D.HOST_MERGE_STRATEGIES)
+        # collective.  Only SORT-strategy group tables merge host-side:
+        # per-device group sets aren't aligned, so there is no
+        # elementwise collective merge (the repartition-exchange path is
+        # the in-program alternative).
+        self.host_merge = self.agg is not None and self.agg.host_merged
         # int/decimal SUMs produce (hi, lo) limb states whose in-program
         # psum is int64-exact only below 2^31 global rows; float sums,
         # counts, host-merged (object-int) programs, and valueflow-proven
@@ -349,10 +348,10 @@ class FusedCopProgram:
     regrow loop re-runs programs per task — the contract class of
     analysis.contracts.fusion_signature).  In-program members
     (SCALAR/DENSE) come back replicated post-psum; host-merge members
-    (SEGMENT group tables) keep their per-device leading axis via a
+    (SORT group tables) keep their per-device leading axis via a
     per-member out_spec, so fused leaves never interact either way.
-    SEGMENT members additionally share one bucket shape — the fusion
-    signature carries num_buckets, so incompatible bucket spaces never
+    SORT members additionally share one table shape — the fusion
+    signature carries group_capacity, so incompatible capacities never
     reach this constructor."""
 
     def __init__(self, fused: D.FusedDag, mesh, donate: bool = False,

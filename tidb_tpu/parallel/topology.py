@@ -205,7 +205,7 @@ class MeshTopology:
     def split_host_merge(self, per_device_bytes: int,
                          route: str = MERGE_PER_HOST) -> TransferBreakdown:
         """Device->host transfer of per-device group tables (the
-        SORT/SEGMENT/SCATTER host merge).  ``per_host`` routing pulls
+        SORT host merge).  ``per_host`` routing pulls
         each host's own devices over PCIe — pure intra bytes, the
         discipline the multi-host runtime must follow.  ``coordinator``
         routing funnels every remote host's states over DCI to one

@@ -514,7 +514,7 @@ class DeviceScheduler:
         if cost.dense_blowups:
             # degenerate DENSE at large NDV: the plan that 1000x-cliffed
             # (and at sf>=10 crashed) the real-TPU hndv rung — reject
-            # pre-trace so selection falls back to the SEGMENT strategy
+            # pre-trace: such keys group by SORT
             path, groups, rows = cost.dense_blowups[0]
             with self._mu:
                 self.budget_rejects += 1
@@ -523,8 +523,7 @@ class DeviceScheduler:
                 "dense-blowup", p,
                 f"DENSE aggregation at {path} holds {groups} group "
                 f"states for {rows} per-device rows — degenerate "
-                "large-NDV dense domain; use a radix strategy "
-                "(GroupStrategy.SEGMENT/SCATTER)")
+                "large-NDV dense domain; group by GroupStrategy.SORT")
         budget = self.effective_budget(task.mesh)
         # copgauge: the prediction the budget gate enforces — surfaced
         # on the launch span (hbm_predicted) and in EXPLAIN ANALYZE

@@ -1657,9 +1657,8 @@ class Session:
     def _agg_strategy_footer(self, phys) -> Optional[str]:
         """EXPLAIN ``agg strategy:`` tag: which device group-by strategy
         the pushed aggregation takes, with its capacity knob — dense
-        (domain product), sort (regrow capacity), or segment (radix
-        bucket space).  None for scalar/host-only plans; must never
-        break EXPLAIN."""
+        (domain product) or sort (regrow capacity).  None for
+        scalar/host-only plans; must never break EXPLAIN."""
         try:
             from ..copr import dag as Dg
             stack = [phys]
@@ -1669,14 +1668,6 @@ class Session:
                 if dag is None:
                     dag = getattr(getattr(op, "spec", None), "top", None)
                 if isinstance(dag, Dg.Aggregation) and dag.group_by:
-                    if dag.strategy is Dg.GroupStrategy.SCATTER:
-                        return (f"agg strategy: scatter "
-                                f"({dag.num_buckets} buckets, "
-                                f"{Dg.radix_passes(dag.num_buckets)} "
-                                "passes)")
-                    if dag.strategy is Dg.GroupStrategy.SEGMENT:
-                        return (f"agg strategy: segment "
-                                f"({dag.num_buckets} buckets)")
                     if dag.strategy is Dg.GroupStrategy.SORT:
                         return (f"agg strategy: sort (capacity "
                                 f"{dag.group_capacity or 'auto'}"

@@ -480,7 +480,7 @@ class CopClient:
             batches = snap.row_batches(half)
             if batches and len(batches) >= 2:
                 try:
-                    if agg.strategy in D.HOST_MERGE_STRATEGIES:
+                    if agg.host_merged:
                         res = self._stream_sort_agg(agg, batches, key_meta)
                     else:
                         res = self._stream_dense_agg(agg, batches, key_meta)
@@ -508,7 +508,7 @@ class CopClient:
         so the client sees the structured failure."""
         res = None
         if self.host_fallback and not aux_cols:
-            if agg.strategy in D.HOST_MERGE_STRATEGIES:
+            if agg.host_merged:
                 res = self._host_sort_agg(agg, snap, key_meta)
             else:
                 from ..copr.hostagg import host_dense_agg
@@ -566,10 +566,8 @@ class CopClient:
     def _execute_agg_once(self, agg: D.Aggregation, snap: ColumnarSnapshot,
                           key_meta: list[GroupKeyMeta],
                           aux_cols=()) -> CopResult:
-        if agg.strategy in D.HOST_MERGE_STRATEGIES:
-            # SORT and SEGMENT share one dispatch path: per-device group
-            # tables, host final merge, capacity regrow (the SEGMENT
-            # knob is its pow2 bucket space instead of group_capacity)
+        if agg.host_merged:
+            # per-device group tables, host final merge, capacity regrow
             if not aux_cols and self._platform() == "cpu":
                 res = self._host_sort_agg(agg, snap, key_meta)
                 if res is not None:
@@ -684,19 +682,15 @@ class CopClient:
     @staticmethod
     def _with_capacity(agg: D.Aggregation, cap: int) -> D.Aggregation:
         """Rebuild a host-merged aggregation with a new per-device group
-        table capacity: SORT sizes group_capacity directly (pow2, so the
-        capacity lands in a shared fusion shape class), SEGMENT/SCATTER
-        their power-of-two radix bucket space (the regrow knob)."""
+        table capacity (pow2, so the capacity lands in a shared fusion
+        shape class): the regrow knob."""
         import dataclasses
-        if agg.strategy in D.RADIX_STRATEGIES:
-            return dataclasses.replace(agg,
-                                       num_buckets=_pow2_at_least(cap))
         return dataclasses.replace(agg,
                                    group_capacity=_pow2_at_least(cap))
 
     def _stream_sort_agg(self, agg, batches, key_meta) -> CopResult:
         agg = D.wide_groups(agg)    # a group's rows are in many batches
-        cap = self._warm_cap(agg, agg.state_capacity
+        cap = self._warm_cap(agg, agg.group_capacity
                              or DEFAULT_GROUP_CAPACITY)
         per_dev_all = []
         for b in batches:
@@ -894,32 +888,14 @@ class CopClient:
 
     def _execute_sort_agg(self, agg, cols, counts, key_meta,
                           aux_cols) -> CopResult:
-        """High-NDV group-by (SORT / SEGMENT / SCATTER): per-device
-        partition + segment-reduce group tables, regrown when a device
-        sees more distinct groups than capacity (the paging grow-from-
-        min analog), then host final merge."""
-        # prehash hoist (copr/radix): the avalanche key hash does not
-        # depend on the bucket space, so for radix strategies it is
-        # computed ONCE by a tiny sharded hash program and appended as
-        # an extra scan column — every regrow re-entry (a fresh program
-        # at a bigger num_buckets) reuses the hashed keys instead of
-        # re-hashing the key tuple per capacity
-        if agg.strategy in D.RADIX_STRATEGIES and not aux_cols \
-                and not agg.prehashed:
-            from ..copr import radix
-            pre = radix.prehash_plan(agg, len(cols))
-            if pre is not None:
-                hashed_dag, leaf_scan = pre
-                hprog = radix.get_hash_program(leaf_scan, agg.group_by,
-                                               self.mesh)
-                hv = self._launch_opaque(lambda: hprog(cols, counts),
-                                         program=hprog.name)
-                cols = list(cols) + [(hv, None)]
-                agg = hashed_dag
+        """High-NDV group-by (SORT): per-device group tables of the
+        sort's runs, regrown when a device sees more distinct groups
+        than capacity (the paging grow-from-min analog), then host final
+        merge."""
         agg = self._group_form(agg)
         with self._pf_mu:
             regrown = self._group_caps.get(self._record_key(agg), 0)
-        cap = self._warm_cap(agg, max(agg.state_capacity
+        cap = self._warm_cap(agg, max(agg.group_capacity
                                       or DEFAULT_GROUP_CAPACITY, regrown))
         if aux_cols:
             agg = self._join_form(agg)
@@ -987,11 +963,11 @@ class CopClient:
         rcols, rcounts = rsnap.device_cols(self.mesh)
         caps = self._shuffle_initial_caps(lsnap, rsnap, row_cap)
         agg = spec.top if isinstance(spec.top, D.Aggregation) else None
-        if agg is not None and agg.strategy in D.HOST_MERGE_STRATEGIES:
+        if agg is not None and agg.host_merged:
             # the exchange's output is no resident launch's: the wide
             # record, which always fits, and the host ranks the groups
             agg = D.wide_groups(agg)
-            spec = dataclasses.replace(spec, top=agg if agg.state_capacity
+            spec = dataclasses.replace(spec, top=agg if agg.group_capacity
                                        else self._with_capacity(
                                            agg, DEFAULT_GROUP_CAPACITY))
         for _ in range(12):
@@ -1021,10 +997,10 @@ class CopClient:
             if grew:
                 continue
             agg = spec.top if isinstance(spec.top, D.Aggregation) else None
-            if agg is not None and agg.strategy in D.HOST_MERGE_STRATEGIES:
+            if agg is not None and agg.host_merged:
                 true_ng = int(np.max(np.asarray(
                     self._fetch(out["__ngroups__"]))))
-                if true_ng > agg.state_capacity:
+                if true_ng > agg.group_capacity:
                     spec = dataclasses.replace(spec, top=self._with_capacity(
                         agg, _pow2_at_least(true_ng)))
                     continue
@@ -1082,7 +1058,7 @@ class CopClient:
         states = self._fetch(out)
         if prog.host_merge:
             per_dev = self._split_devices(states)
-            if agg.strategy in D.HOST_MERGE_STRATEGIES:
+            if agg.host_merged:
                 merged = merge_sorted_states(agg, per_dev)
                 key_cols, agg_cols = finalize_sorted(agg, merged, key_meta)
                 return CopResult(agg_cols, key_cols)

@@ -295,7 +295,7 @@ class RemoteCopClient:
             ent, snap,
             lambda table, ranges: ("exec_agg", table, snap.epoch, agg,
                                    ranges), round_cache)
-        if agg.strategy in D.HOST_MERGE_STRATEGIES:
+        if agg.host_merged:
             merged = merge_sorted_states(agg, per_store)
             key_cols, agg_cols = finalize_sorted(agg, merged, key_meta)
         else:

@@ -83,7 +83,7 @@ class StoreEngine:
         snap, err = self._snap_for(table, epoch, ranges)
         if err is not None:
             return err
-        if agg.strategy in D.HOST_MERGE_STRATEGIES:
+        if agg.host_merged:
             st = host_sort_agg(agg, snap)
         else:
             st = host_dense_agg(agg, snap)

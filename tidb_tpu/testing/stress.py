@@ -3,7 +3,7 @@
 The proving ground for the copmeter closed loop (ISSUE 10): an
 open-loop arrival process (arrivals never wait for completions — the
 "millions of users" shape) drives a mixed device corpus — DENSE/scalar
-aggregates, SORT group-by, SEGMENT high-NDV group-by, rows-kind
+aggregates, SORT group-by at low and at high NDV, rows-kind
 filters, and a shuffle join — through the full admission pipeline with
 the PR 8 fault plane armed, across several resource groups.
 
@@ -35,7 +35,7 @@ STRESS_QUERIES = [
     ("dense", "select min(p), sum(q) from stress_li where q > 10"),
     ("sort", "select d, count(*), sum(p) from stress_li "
              "where q < 40 group by d"),
-    ("segment", "select k, count(*) from stress_li group by k"),
+    ("hndv", "select k, count(*) from stress_li group by k"),
     ("rows", "select q, p from stress_li where p > 9900"),
     ("shuffle", "select count(*), sum(p + sp) from stress_li "
                 "join stress_sup on d = sd2"),
@@ -58,8 +58,7 @@ def build_stress_domain(n_rows: int = 60_000, seed: int = 7):
     d = rng.integers(0, 10, n_rows)
     p = rng.integers(100, 10_000, n_rows)
     sd = rng.integers(0, 2000, n_rows)
-    # high-NDV group key: NDV comfortably above SEGMENT_MIN_NDV (32768)
-    # so ANALYZE-driven selection takes the radix SEGMENT path
+    # high-NDV group key: some 35,000 distinct values of 50,000
     k = rng.integers(0, 50_000, n_rows)
     step = 10_000
     for lo in range(0, n_rows, step):

@@ -178,15 +178,14 @@ TPCH_PLAN_QUERIES = [
        from lineitem where l_shipdate <= date '1998-09-02'
        group by l_returnflag, l_linestatus
        order by l_returnflag, l_linestatus""",
-    # high-NDV group-by: SORT-strategy aggregation (single key: stats NDV
-    # stays below the SEGMENT threshold at corpus scale)
+    # high-NDV group-by: SORT-strategy aggregation (single key)
     """select l_orderkey, sum(l_extendedprice) from lineitem
        group by l_orderkey""",
-    # very-high-NDV group-bys: the per-key stats NDV PRODUCT crosses
-    # SEGMENT_MIN_NDV, so these plan as the radix-partitioned SEGMENT
-    # strategy (tpch_plan_session ANALYZEs lineitem so the estimates
-    # exist at plan time) — the gate keeps them contract-clean and
-    # rc-pricing-finite like every other corpus shape
+    # very-high-NDV group-bys: the per-key stats NDV PRODUCT seeds a
+    # SORT group table at the capacity ceiling (tpch_plan_session
+    # ANALYZEs lineitem so the estimates exist at plan time) — the gate
+    # keeps them contract-clean and rc-pricing-finite like every other
+    # corpus shape
     """select l_orderkey, l_partkey, count(*), sum(l_quantity)
        from lineitem group by l_orderkey, l_partkey""",
     """select l_orderkey, l_suppkey, max(l_extendedprice) from lineitem
@@ -252,9 +251,8 @@ def tpch_plan_session(sf: float = 0.001, n_orders: int = 512):
         t.register_columns(list(cols))
         dom.catalog.create_table("test", t)
     sess = Session(dom)
-    # stats NDV feeds SORT-vs-SEGMENT strategy selection and the
-    # group-table capacity seed (executor/plan._ndv_capacity): the
-    # corpus' high-NDV queries must plan as SEGMENT
+    # stats NDV seeds the SORT group-table capacity
+    # (executor/plan._ndv_capacity)
     sess.execute("analyze table lineitem")
     return sess
 
