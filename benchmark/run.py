@@ -166,6 +166,22 @@ def _widths(data: dict) -> dict:
     return out
 
 
+class _Laps:
+    """Set-up cut into named parts with nothing between them: a part ends
+    where the next begins, so the parts add up to ``setup_s``."""
+
+    def __init__(self, start: float):
+        self.parts: dict = {}
+        self._last = start
+
+    def lap(self, name: str) -> float:
+        """End the part ``name`` now; the time since the last lap is its."""
+        now = time.monotonic()
+        self.parts[name] = now - self._last
+        self._last = now
+        return now
+
+
 def _get(port: int, path: str):
     with urllib.request.urlopen(f"http://127.0.0.1:{port}{path}",
                                 timeout=60) as r:
@@ -242,7 +258,7 @@ def run_cell(cell: dict, config: dict, mix: dict, seed: int, seconds: float,
     from tidb_tpu.jaxcache import place_jax_compile_cache
 
     scale = config["scale"] if scale is None else scale
-    parts: dict = {}
+    laps = _Laps(T_START)
     log("jax compile cache at", place_jax_compile_cache())
     classes = {c: load_module("classes", c) for c in mix["mix"]}
     tables = {t: load_module("tables", t)
@@ -255,21 +271,22 @@ def run_cell(cell: dict, config: dict, mix: dict, seed: int, seconds: float,
 
     cfg = load_config(None)
     cfg.port = cfg.status_port = 0          # ephemeral
-    t = time.monotonic()
+    # the interpreter, JAX and the program imported, the chips found, the
+    # cell's class and table files loaded
+    laps.lap("import_s")
     dom, srv, st = start_server(cfg)
     admin = child = None
     try:
         admin = wire.Connection("127.0.0.1", srv.port, db=DB)
         for var, value in config["server"]["set_global"].items():
             admin.query(f"set global {var} = {value}")
-        parts["server_s"] = time.monotonic() - t
+        laps.lap("server_s")
 
-        t = time.monotonic()
         data = {name: tbl.generate(scale, seed,
                                    config["tables"][name]["columns"])
                 for name, tbl in tables.items()}
         run.rows = {name: len(next(_arrays(d))) for name, d in data.items()}
-        parts["generate_s"] = time.monotonic() - t
+        laps.lap("generate_s")
 
         # the oracle runs beside ANALYZE, H2D and nothing else: it is
         # numpy, which lets the interpreter go for most of its time
@@ -285,30 +302,31 @@ def run_cell(cell: dict, config: dict, mix: dict, seed: int, seconds: float,
         for th in oracle:
             th.start()
 
-        t = time.monotonic()
         for name, tbl in tables.items():
             _load_table(dom, admin, tbl, data[name])
-        parts["register_s"] = time.monotonic() - t
-        t = time.monotonic()
+        laps.lap("register_s")
         for name in tables:
             if config["tables"][name].get("analyze"):
                 admin.query(f"analyze table {name}")
-        parts["analyze_s"] = time.monotonic() - t
-        t = time.monotonic()
+        laps.lap("analyze_s")
         mesh = dom.client.mesh
         jax.block_until_ready([
             dom.catalog.get_table(DB, name).snapshot().device_cols(mesh)
             for name, tbl in tables.items() if tbl.LOAD == "bulk"])
-        parts["h2d_s"] = time.monotonic() - t
-        t = time.monotonic()
+        laps.lap("h2d_s")
         for th in oracle:
             th.join()
         if set(state) != set(classes):
             raise RuntimeError("an oracle failed: see the traceback above")
-        parts["oracle_wait_s"] = time.monotonic() - t
-        parts["oracle_s"] = sum(oracle_s.values())
+        laps.lap("oracle_wait_s")
+        log("the oracles' prepare, beside ANALYZE and H2D: " + "  ".join(
+            f"{c}={v:.2f}s" for c, v in oracle_s.items()))
 
+        # drawing the pools is the oracle's work too where a class draws
+        # again on a tie (`q3`: a pass over `lineitem` for every set it
+        # draws); `answer` then finds what `draw` worked out
         pools = traffic.pools(classes, mix)
+        laps.lap("pools_s")
         statements, number = [], {}
         for name, pool in pools.items():
             for k, params in enumerate(pool):
@@ -319,12 +337,12 @@ def run_cell(cell: dict, config: dict, mix: dict, seed: int, seconds: float,
                     "rows": classes[name].answer(state[name], params)})
         streams = [[number[c, k] for c, k in seq] for seq in traffic.streams(
             mix, {c: len(p) for c, p in pools.items()}, seed)]
+        laps.lap("answers_s")
 
         # warm-up: every statement the window will send, checked, twice
         # over: the scheduler compiles fused programs it predicts from the
         # statements it has seen, in the background, and the last of them
         # start only on the second pass.  Then wait until it has gone quiet
-        t = time.monotonic()
         first: dict = {}
         for k, s in enumerate(statements + statements):
             t1 = time.monotonic()
@@ -339,7 +357,7 @@ def run_cell(cell: dict, config: dict, mix: dict, seed: int, seconds: float,
                     f"warm-up: wrong answer for {s['sql']}\n got      "
                     f"{got[:4]}\n expected {want[:4]}")
         _quiesce(st.port)
-        parts["warmup_s"] = time.monotonic() - t
+        laps.lap("warmup_s")
         log("warm-up, first pass by class: " + "  ".join(
             f"{c}={v:.2f}s" for c, v in first.items()))
 
@@ -353,22 +371,22 @@ def run_cell(cell: dict, config: dict, mix: dict, seed: int, seconds: float,
                 "out": os.path.join(run_dir, "records.json")}
         with open(os.path.join(run_dir, "plan.json"), "w") as f:
             json.dump(plan, f)
-        t = time.monotonic()
         child = subprocess.Popen(
             [sys.executable, os.path.join(HERE, "harness", "loadgen.py"),
              os.path.join(run_dir, "plan.json")],
             stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True)
         if child.stdout.readline().strip() != "READY":   # after its ramp
             raise RuntimeError("the load generator did not get ready")
-        parts["loadgen_ramp_s"] = time.monotonic() - t
+        laps.lap("loadgen_ramp_s")
 
         sql_class = {" ".join(s["sql"].split()): s["class"]
                      for s in statements}
         run.summary_before = _summary(admin, sql_class)
         run.sched_before = _get(st.port, "/sched")
         wall0 = time.time()
-        run.setup_s = time.monotonic() - T_START
-        run.setup_parts = parts
+        # the program's counters read: the last of set-up
+        run.setup_s = laps.lap("counters_s") - T_START
+        run.setup_parts = laps.parts
         child.stdin.write("GO\n")
         child.stdin.flush()
         t_go = time.monotonic()
@@ -433,7 +451,9 @@ def result(run: Run, bench: dict, trace: bool, device: dict) -> dict:
         log(f"class {cls}: n={len(ms[cls])} median_ms={median(ms[cls]):.4f} "
             f"max_ms={max(ms[cls]):.4f}")
     log("set-up parts: " + "  ".join(
-        f"{k}={v:.2f}" for k, v in run.setup_parts.items()))
+        f"{k}={v:.2f}" for k, v in run.setup_parts.items())
+        + f"  (their sum {sum(run.setup_parts.values()):.2f}"
+        f" = setup_s {run.setup_s:.2f})")
     wrong = [r for r in run.records if r["ok"] is False]
     failed = [r for r in run.records if r["ok"] is None]
     for r in (wrong + failed)[:5]:

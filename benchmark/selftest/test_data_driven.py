@@ -113,6 +113,8 @@ def test_new_cell_is_files_and_entries_only(tmp_path):
     assert set(run.ms_by_class()) == {"tmp_small_parts", "kv_agg"}
     wanted = run_py.cell_metrics(loaded, "per_layer", "tmp.cell")
     assert "tmp_answered" in {m["name"] for m in wanted}
+    # set-up's parts list no cell: a cell that comes later reports them
+    assert "setup_part_s.oracle" in {m["name"] for m in wanted}
     # the one-chip power cells' metrics do not leak into the new cell
     assert "device_ms.q6" not in {m["name"] for m in wanted}
     got = run_py.read_metrics(

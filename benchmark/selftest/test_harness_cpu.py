@@ -46,6 +46,15 @@ def test_cell_runs_and_every_answer_is_right(run_py, bench, workload):
     # the ramp before it is set-up, and none of its readings is kept
     assert run.setup_parts["loadgen_ramp_s"] >= run_py.RAMP_S
     assert min(r["sent"] for r in run.records) >= run.t0
+    # set-up has no unnamed part: the laps add up to it, and every
+    # `setup_part_s.<part>` the benchmark lists finds its laps
+    assert sum(run.setup_parts.values()) == pytest.approx(run.setup_s,
+                                                          abs=2.0)
+    listed = [m for m in bench["per_layer"]
+              if m["name"].startswith("setup_part_s.")]
+    parts = run_py.read_metrics(run, "layer_metrics", listed)
+    assert listed and set(parts) == {m["name"] for m in listed}
+    assert parts["setup_part_s.ramp"]["value"] >= run_py.RAMP_S
     served = sum(n for n, _ in run.summary_after.values()) \
         - sum(n for n, _ in run.summary_before.values())
     assert served == len(run.records)       # the server's own count

@@ -50,4 +50,3 @@ def test_the_cell_lists_it():
                      "better": "higher", "source": "program_counter",
                      "layer": "device programs", "moves": "stmt_ms_geomean",
                      "workloads": ["tpch1x1.partjoin"]}
-    assert bench["per_layer"][-1] is entry      # appended, nothing moved

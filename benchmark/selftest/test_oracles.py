@@ -152,3 +152,122 @@ def test_group_sums_is_exact_beyond_float53():
     keys = np.zeros(1000, np.int64)
     values = np.full(1000, (1 << 47) + 1, np.int64)
     assert exact.group_sums(keys, values, 1)[0] == 1000 * ((1 << 47) + 1)
+
+
+# --------------------------------------------------------------------- #
+# Q3's pass over `lineitem`: a chunk sums over the range of order rows
+# its live lines touch (PR 46), whatever order the rows are stored in
+# --------------------------------------------------------------------- #
+
+Q3_SEED = 2147483659
+Q3_PARAMS = [{"segment": "BUILDING", "day": 15},
+             {"segment": "AUTOMOBILE", "day": 1},
+             {"segment": "MACHINERY", "day": 31},
+             {"segment": "HOUSEHOLD", "day": 9}]
+
+
+@pytest.fixture(scope="module")
+def orders_data(run_py):
+    """CUSTOMER, ORDERS and LineItem at SF0.01: 1,500 x 15,000 x about
+    60,000 rows."""
+    out = {}
+    for name in ("CUSTOMER", "ORDERS", "LineItem"):
+        table = run_py.load_module("tables", name)
+        out[name] = table.generate(SCALE, Q3_SEED, list(table.TYPES))
+    return out
+
+
+def permuted(table: dict, seed: int) -> dict:
+    """The table's rows in an order drawn from the seed."""
+    n = len(next(iter(table.values())))
+    order = np.random.default_rng(seed).permutation(n)
+    return {c: (v[0][order], v[1]) if isinstance(v, tuple) else v[order]
+            for c, v in table.items()}
+
+
+def q3_brute(data: dict, p: dict):
+    """(the first ten rows, whether the first eleven groups tie on
+    (revenue, o_orderdate)): a ``dict`` of ``int`` sums over the joined
+    rows, a row at a time."""
+    date = (datetime.date(1995, 3, p["day"]) - EPOCH).days
+    segment = {r["c_custkey"] for r in rows_of(data["CUSTOMER"])
+               if r["c_mktsegment"] == p["segment"]}
+    order = {r["o_orderkey"]: r for r in rows_of(data["ORDERS"])
+             if r["o_custkey"] in segment and r["o_orderdate"] < date}
+    revenue: dict = {}
+    for r in rows_of(data["LineItem"]):
+        if r["l_shipdate"] > date and r["l_orderkey"] in order:
+            revenue[r["l_orderkey"]] = revenue.get(r["l_orderkey"], 0) \
+                + r["l_extendedprice"] * (100 - r["l_discount"])
+    first = sorted(revenue, key=lambda k: (-revenue[k],
+                                           order[k]["o_orderdate"]))[:11]
+    pairs = [(revenue[k], order[k]["o_orderdate"]) for k in first]
+    rows = [(str(k), str(dec(revenue[k], 4)),
+             str(EPOCH + datetime.timedelta(order[k]["o_orderdate"])),
+             str(order[k]["o_shippriority"])) for k in first[:10]]
+    return rows, len(set(pairs)) != len(pairs)
+
+
+@pytest.mark.parametrize("chunk_rows", [1 << 21, 4096, 4])
+@pytest.mark.parametrize("stored", ["by_key", "permuted"])
+@pytest.mark.parametrize("p", Q3_PARAMS,
+                         ids=lambda p: f"{p['segment']}-{p['day']}")
+def test_q3_ranked_against_a_dict_of_int_sums(run_py, orders_data,
+                                              monkeypatch, p, stored,
+                                              chunk_rows):
+    from harness import exact
+    data = orders_data if stored == "by_key" else dict(
+        orders_data, LineItem=permuted(orders_data["LineItem"], 7))
+    monkeypatch.setattr(exact, "CHUNK_ROWS", chunk_rows)
+    widths = []                 # the order rows each chunk summed over
+    group_sums = exact.group_sums
+
+    def recorded(keys, values, n):
+        widths.append(n)
+        assert len(keys) and keys.min() == 0 and keys.max() == n - 1
+        return group_sums(keys, values, n)
+    monkeypatch.setattr(exact, "group_sums", recorded)
+    cls = run_py.load_module("classes", "q3")
+    rows, tie = cls._ranked(cls.prepare(data), p)
+    assert (rows, tie) == q3_brute(orders_data, p)
+    assert len(rows) == 10 and not tie
+    lines, orders = len(data["LineItem"]["l_shipdate"]), 15_000
+    chunks = -(-lines // chunk_rows)
+    if chunk_rows == 4:
+        # a chunk with no live line is skipped, a chunk of one order sums
+        # over that one row
+        assert len(widths) < chunks and min(widths) == 1
+    else:
+        assert len(widths) == chunks
+    if stored == "by_key":      # an order has a line or more: c rows, <= c orders
+        assert max(widths) <= min(chunk_rows, orders)
+    elif chunk_rows > 4:        # rows anywhere: the range is wide, not wrong
+        assert max(widths) > orders // 2
+
+
+def test_q3_ranked_sees_a_tie_among_the_first_eleven(run_py, monkeypatch):
+    """Made-up tables: two orders of one revenue and one date at the top,
+    their lines in chunks of their own and a dead chunk between them."""
+    from harness import exact
+    monkeypatch.setattr(exact, "CHUNK_ROWS", 2)
+    day = (datetime.date(1995, 3, 1) - EPOCH).days
+    data = {
+        "CUSTOMER": {"c_custkey": np.array([1, 2]),
+                     "c_mktsegment": (np.array([0, 1], np.int32),
+                                      ["BUILDING", "MACHINERY"])},
+        "ORDERS": {"o_orderkey": np.array([1, 2, 3, 33]),
+                   "o_custkey": np.array([1, 1, 2, 1]),
+                   "o_orderdate": np.array([day, day, day, day]),
+                   "o_shippriority": np.zeros(4, np.int64)},
+        "LineItem": {
+            "l_orderkey": np.array([1, 1, 3, 3, 2, 33]),
+            "l_extendedprice": np.array([600, 400, 900, 900, 1000, 500]),
+            "l_discount": np.array([0, 0, 0, 0, 0, 10]),
+            "l_shipdate": np.full(6, day + 30)}}
+    p = {"segment": "BUILDING", "day": 15}
+    cls = run_py.load_module("classes", "q3")
+    rows, tie = cls._ranked(cls.prepare(data), p)
+    assert tie and q3_brute(data, p)[1]
+    assert sorted(rows) == sorted(q3_brute(data, p)[0]) == [("1", "10.0000", "1995-03-01", "0"),
+                            ("2", "10.0000", "1995-03-01", "0"),
+                            ("33", "4.5000", "1995-03-01", "0")]

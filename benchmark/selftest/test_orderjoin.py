@@ -366,9 +366,11 @@ def test_the_cell_lists_what_it_reports():
     assert all(v.get("analyze") for v in config["tables"].values())
     e2e = {m["name"] for m in run_py.cell_metrics(bench, "end_to_end", CELL)}
     assert e2e == {"stmt_ms_geomean", "stmt_p95_x", "peak_hbm_gb", "setup_s"}
+    # what the cell lists, not the whole set or its place: later PRs
+    # append entries that list this cell, alone or beside others
     mine = {m["name"] for m in bench["per_layer"]
-            if m.get("workloads") == [CELL]}
-    assert mine == {
+            if CELL in m.get("workloads", [])}
+    assert mine >= {
         "device_ms.q3", "device_ms.q12", "program_ms.solo_join_rows",
         "program_ms.solo_join_agg_sort", "program_ms.solo_join_agg_dense",
         "q3_join_roofline", "q12_probe_roofline", "join_direct_share",
@@ -378,10 +380,6 @@ def test_the_cell_lists_what_it_reports():
     assert mine <= wanted and "device_idle_share" in wanted
     assert not {"device_ms.q14", "join_compact_share",
                 "hndv_device_share"} & wanted
-    # nothing the benchmark had lists the new cell or was moved
-    for m in bench["per_layer"]:
-        if m["name"] not in mine:
-            assert CELL not in m.get("workloads", [])
 
 
 def test_cell_rehearsed_on_the_cpu():

