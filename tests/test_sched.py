@@ -115,28 +115,42 @@ def test_batched_launch_splits_states_per_task():
     assert s.must_query(Q6) == [(exp1,)]
     assert s2.must_query(q2) == [(exp2,)]
     sched = dom.client._sched_obj
-    batched0 = sched.batched_launches
-    sched.pause()
-    try:
-        out, errors = {}, []
 
-        def run(sql, tag):
-            try:
-                out[tag] = Session(dom).must_query(sql)
-            except Exception as e:  # noqa: BLE001
-                errors.append(e)
-        threads = [threading.Thread(target=run, args=(Q6, 1)),
-                   threading.Thread(target=run, args=(q2, 2))]
+    def both():
+        sched.pause()
+        try:
+            out, errors = {}, []
+
+            def run(sql, tag):
+                try:
+                    out[tag] = Session(dom).must_query(sql)
+                except Exception as e:  # noqa: BLE001
+                    errors.append(e)
+            threads = [threading.Thread(target=run, args=(Q6, 1)),
+                       threading.Thread(target=run, args=(q2, 2))]
+            for t in threads:
+                t.start()
+            _wait_until(lambda: sched.depth >= 2, msg="2 queued cop tasks")
+        finally:
+            sched.resume()
         for t in threads:
-            t.start()
-        _wait_until(lambda: sched.depth >= 2, msg="2 queued cop tasks")
-    finally:
-        sched.resume()
-    for t in threads:
-        t.join(timeout=60)
-    assert not errors, errors
-    assert out[1] == [(exp1,)] and out[2] == [(exp2,)]
+            t.join(timeout=60)
+        assert not errors, errors
+        assert out[1] == [(exp1,)] and out[2] == [(exp2,)]
+
+    # the drain compiles no group program: the first time the two slots
+    # turn up they launch apart (unless an earlier test loaded the
+    # vmapped program); the explicit warm compiles it on a background
+    # thread
+    both()
+    sched.warm_groups()
+    _wait_until(lambda: not sched._groups_pending
+                and not sched._groups_inflight and not sched._warm_alive,
+                timeout=120, msg="the background group compile")
+    batched0, refused0 = sched.batched_launches, sched.batched_refused
+    both()
     assert sched.batched_launches > batched0
+    assert sched.batched_refused == refused0
 
 
 def test_weighted_fair_order_across_groups():

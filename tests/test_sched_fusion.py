@@ -9,6 +9,14 @@ and incompatible pairs are REFUSED pre-launch by verify_fusion_group.
 Also covers the two launch-shape follow-ons landed with it: rows-kind
 batched (vmapped) launches and the adaptive micro-batch window.
 
+The drain thread never compiles a group program, and nothing compiles
+one by itself: a member set whose fused (or batched) program is not
+loaded is served apart, by each member's solo program, until an
+explicit `warm_groups()` has compiled it on a background thread; from
+then on the set shares ONE launch.  So every test of a group launch
+here runs its queries once, warms (`_land`), and reads the group form
+off the second round.
+
 Like tests/test_sched.py, concurrency tests pin the device path open
 (`_platform` -> "tpu") and pause the drain loop so queue buildup is
 deterministic.
@@ -38,6 +46,15 @@ def _wait_until(pred, timeout=20.0, msg="condition"):
             return
         time.sleep(0.01)
     raise AssertionError(f"timed out waiting for {msg}")
+
+
+def _land(sched, timeout=120.0):
+    """The explicit warm, and its background threads done: every group
+    program of a set that was served apart is loaded."""
+    sched.warm_groups()
+    _wait_until(lambda: not sched._groups_pending
+                and not sched._groups_inflight and not sched._warm_alive,
+                timeout, "the background group compiles")
 
 
 def _mk_table(s: Session, name: str = "t", n: int = 4000, seed: int = 0):
@@ -105,31 +122,46 @@ def _run_concurrent(dom, sched, queries):
     return out
 
 
-def test_different_aggregates_fuse_into_one_launch():
-    """N sessions x N DIFFERENT aggregates over one table: the limb
-    aggs fuse into one device launch and the proven-narrow SUMs into a
-    second (fewer launches than tasks, every member fused), no new
-    solo-program compiles, answers exact."""
+def test_first_seen_sets_are_served_apart_and_fuse_the_next_time():
+    """N sessions x N DIFFERENT aggregates over one table.  The first
+    time the two member sets turn up (the limb aggs, the proven-narrow
+    SUMs) their fused programs are not loaded: the drain serves the
+    members apart (`groups_apart_unloaded`, NOT a refusal);
+    `warm_groups` compiles what co-occurred on a background thread.
+    The second time each set is ONE launch (fewer launches than tasks, every member fused),
+    no new solo-program compiles, answers exact both times."""
+    from tidb_tpu.compilecache import simulate_restart
     dom, s, _data = _fusion_domain()
+    simulate_restart()        # no group program of an earlier test
     # warm-up: compiles each member program once, starts the scheduler
     solo = [Session(dom).must_query(q) for q in FUSION_QUERIES]
     sched = dom.client._sched_obj
     assert sched is not None, "launch did not route through the scheduler"
+    _land(sched)
     misses0 = spmd._cached.cache_info().misses
-    f0, l0 = sched.fused_launches, sched.launches
-    ft0, t0 = sched.fused_tasks, sched.tasks_done
+    f0, r0 = sched.fused_launches, sched.fused_refused
+    a0, c0 = sched.groups_apart_unloaded, sched.group_compiles_bg
 
     out = _run_concurrent(dom, sched, FUSION_QUERIES)
+    assert [out[i] for i in range(len(FUSION_QUERIES))] == solo
+    assert sched.fused_launches == f0, "a first-seen set fused at once"
+    assert sched.groups_apart_unloaded == a0 + 2
+    assert sched.fused_refused == r0
+    _land(sched)
+    assert sched.group_compiles_bg == c0 + 2
+    assert sched.warm_failures == 0
 
+    l0, ft0, t0 = sched.launches, sched.fused_tasks, sched.tasks_done
+    out = _run_concurrent(dom, sched, FUSION_QUERIES)
     # every session got the same answer a solo run produces...
     assert [out[i] for i in range(len(FUSION_QUERIES))] == solo
     # ...both classes fused: fewer launches than tasks, fused launches
     # seen, and EVERY member (limb and narrow alike) rode a fusion
-    dl = sched.launches - l0
-    dtasks = sched.tasks_done - t0
-    assert sched.fused_launches > f0
-    assert dl < dtasks, (dl, dtasks)
+    assert sched.fused_launches == f0 + 2
+    assert sched.launches - l0 < sched.tasks_done - t0
     assert sched.fused_tasks - ft0 >= len(FUSION_QUERIES)
+    assert sched.groups_apart_unloaded == a0 + 2
+    assert sched.group_compiles_bg == c0 + 2
     # ...and the compile count stayed flat vs the warmed single-session
     # programs (the fused program caches separately on the FusedDag)
     assert spmd._cached.cache_info().misses == misses0
@@ -137,15 +169,17 @@ def test_different_aggregates_fuse_into_one_launch():
 
 def test_fused_results_bit_identical_across_op_kinds():
     """Each device agg op kind (COUNT/SUM/MIN/MAX) returns EXACTLY the
-    solo-run value when served by a fused launch — run twice so both a
-    cold and a warm fused program are covered."""
+    solo-run value whatever launch served it: apart while the fused
+    program is not loaded, fused afterwards — run three times so the
+    apart round and two fused rounds are covered."""
     dom, s, _data = _fusion_domain()
     solo = [Session(dom).must_query(q) for q in FUSION_QUERIES]
     sched = dom.client._sched_obj
-    for _round in range(2):
+    for _round in range(3):
         out = _run_concurrent(dom, sched, FUSION_QUERIES)
         for i, exp in enumerate(solo):
             assert out[i] == exp, (FUSION_QUERIES[i], out[i], exp)
+        _land(sched)
     assert sched.fused_launches >= 1
 
 
@@ -211,6 +245,10 @@ def test_rows_plans_sharing_scan_fuse_with_per_member_capacities():
     solo = [sorted(Session(dom).must_query(qa)),
             Session(dom).must_query(qb)]
     sched = dom.client._sched_obj
+    out = _run_concurrent(dom, sched, [qa, qb])     # apart, or loaded
+    assert sorted(out[0]) == solo[0]
+    assert out[1] == solo[1]
+    _land(sched)
     f0, l0 = sched.fused_launches, sched.launches
     t0 = sched.tasks_done
     out = _run_concurrent(dom, sched, [qa, qb])
@@ -318,10 +356,15 @@ def test_rows_kind_batched_launch_splits_rows_per_task():
     solo = [sorted(Session(dom).must_query(qa)),
             sorted(Session(dom).must_query(qb))]
     sched = dom.client._sched_obj
+    r0 = sched.batched_refused
+    out = _run_concurrent(dom, sched, [qa, qb])     # apart, or loaded
+    assert sorted(out[0]) == solo[0] and sorted(out[1]) == solo[1]
+    _land(sched)
     br0 = sched.batched_rows_launches
     out = _run_concurrent(dom, sched, [qa, qb])
     assert sorted(out[0]) == solo[0] and sorted(out[1]) == solo[1]
     assert sched.batched_rows_launches > br0
+    assert sched.batched_refused == r0
 
 
 def test_adaptive_window_ewma_and_clamp():

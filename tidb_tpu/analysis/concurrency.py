@@ -84,7 +84,8 @@ THREAD_ROOTS = {
     "statement": "session/connection statement threads (submit path, "
                  "pd coordinator tick, plan cache, catalog)",
     "drain":     "the sched-drain device launch loop (one per mesh)",
-    "warm":      "copforge-predict fusion warm threads (bounded pool)",
+    "warm":      "copforge-group background group-program compile "
+                 "threads (two alive at the most)",
     "status":    "status-server HTTP route threads",
     "owner":     "ddl owner job loop + election lease renewal",
     "timer":     "timer wheel ticks / profiler stop timers",
@@ -101,9 +102,10 @@ MULTI_ROOTS = frozenset({"statement", "warm", "status", "pool"})
 ROOT_ENTRIES = [
     ("drain", "sched/scheduler.py", r"^DeviceScheduler\._loop$"),
     ("warm", "sched/scheduler.py",
-     r"^DeviceScheduler\._predict_fusion\.warm$"),
+     r"^DeviceScheduler\._group_worker$"),
     ("statement", "sched/scheduler.py",
-     r"^DeviceScheduler\.(submit|configure|pause|resume|drain)"),
+     r"^DeviceScheduler\.(submit|configure|pause|resume|drain"
+     r"|warm_groups)"),
     ("statement", "sched/scheduler.py", r"^scheduler_for$"),
     ("status", "sched/scheduler.py", r"^DeviceScheduler\.stats$"),
     ("statement", "sched/scheduler.py", r"^DeviceScheduler\.stats$"),

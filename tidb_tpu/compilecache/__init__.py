@@ -9,12 +9,13 @@ a restarted server serves its first corpus-shaped query without
 tracing or compiling anything.
 """
 
-from .cache import (CachedProgram, CompileCache, cached_call,
-                    compile_cache, configure)
+from .cache import (GROUP_PROGRAMS_MAX, CachedProgram, CompileCache,
+                    cached_call, compile_cache, configure, is_group_key)
 from .manifest import WarmManifest
 from .warmup import (maybe_warm_start, reset_warmed, simulate_restart,
                      warm_start)
 
 __all__ = ["CompileCache", "CachedProgram", "compile_cache", "configure",
-           "cached_call", "WarmManifest", "warm_start",
+           "cached_call", "is_group_key", "GROUP_PROGRAMS_MAX",
+           "WarmManifest", "warm_start",
            "maybe_warm_start", "reset_warmed", "simulate_restart"]

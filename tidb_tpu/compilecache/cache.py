@@ -48,6 +48,18 @@ ENTRY_SUFFIX = ".copforge"
 FORMAT_VERSION = 1
 MAGIC = "copforge"
 
+# builders whose program serves a GROUP of tasks (several member programs
+# over one scan, or one program over stacked inputs): the admission
+# scheduler never compiles these where a client waits, and at most
+# GROUP_PROGRAMS_MAX of them may exist (warm pool and manifest together)
+GROUP_PROGRAMS = ("fused", "fused-rows", "batched", "batched-rows")
+GROUP_PROGRAMS_MAX = 32
+
+
+def is_group_key(key: CompileKey) -> bool:
+    return key.capacity_sig.split("/", 1)[0] in GROUP_PROGRAMS
+
+
 # nominal pool accounting for executables the backend cannot serialize
 # (no payload to size): small enough that a CPU-mesh pool holds the
 # whole corpus, large enough that eviction still means something
@@ -92,6 +104,12 @@ class CompileCache:
         self._mem_info: dict[str, int] = {}
         self._bad_entries: set = set()     # rejected on disk; don't re-read
         self._caps: dict[str, set] = {}    # family -> warm capacities
+        # group programs (GROUP_PROGRAMS) in the pool, slots a compile
+        # under way has reserved against GROUP_PROGRAMS_MAX, and the
+        # names of the threads that compiled one (never "sched-drain")
+        self._group_entries: set = set()
+        self._group_reserved = 0
+        self._group_compile_threads: set = set()
         self._quarantined: set = set()     # stable digests the breaker opened
         self._manifest: Optional[WarmManifest] = None
         # persistence support is probed on first serialize attempt:
@@ -212,17 +230,21 @@ class CompileCache:
 
     # ---- pool ------------------------------------------------------- #
 
-    def _pool_put_locked(self, entry_hex: str, exe, nbytes: int) -> None:
+    def _pool_put_locked(self, entry_hex: str, exe, nbytes: int,
+                         group: bool = False) -> None:
         old = self._pool.pop(entry_hex, None)
         if old is not None:
             self._pool_bytes -= old[1]
         self._pool[entry_hex] = (exe, nbytes)
         self._pool_bytes += nbytes
+        if group:
+            self._group_entries.add(entry_hex)
         while self.pool_cap_bytes > 0 and \
                 self._pool_bytes > self.pool_cap_bytes and \
                 len(self._pool) > 1:
-            _hx, (_exe, nb) = self._pool.popitem(last=False)
+            hx, (_exe, nb) = self._pool.popitem(last=False)
             self._pool_bytes -= nb
+            self._group_entries.discard(hx)
             self.evictions += 1
         self._m_bytes.set(self._pool_bytes)
 
@@ -356,6 +378,73 @@ class CompileCache:
                 self._bad_entries.add(entry_hex)
             return None
 
+    # ---- is it loaded? (asked before a group form is chosen) --------- #
+
+    def loaded(self, key: CompileKey, args) -> bool:
+        """Is the executable for (key, shape-of-args) in the warm pool?
+        Neither lowers nor compiles nor reads the disk; False while the
+        cache is off (nothing is known to be loaded then)."""
+        return self.enable and self._in_pool(
+            key.entry_hex(shape_signature(args)))
+
+    def on_disk(self, key: CompileKey, args) -> bool:
+        """Is it persisted in the cache directory (one ``stat``)?"""
+        return self.enable and self._entry_on_disk(
+            key.entry_hex(shape_signature(args)))
+
+    def _in_pool(self, entry_hex: str) -> bool:
+        with self._mu:
+            return entry_hex in self._pool
+
+    def _entry_on_disk(self, entry_hex: str) -> bool:
+        if not self.cache_dir:
+            return False
+        with self._mu:
+            if entry_hex in self._bad_entries:
+                return False
+        return os.path.isfile(self._entry_path(entry_hex))
+
+    def _recorded_groups(self) -> set:
+        m = self.manifest
+        return m.group_entries() if m is not None else set()
+
+    def group_programs(self) -> int:
+        """How many group programs exist: in the warm pool and in the
+        manifest together (an entry in both counts once)."""
+        recorded = self._recorded_groups()
+        with self._mu:
+            return len(self._group_entries | recorded)
+
+    def warm_group(self, key: CompileKey, jit_fn, args,
+                   limit: int = GROUP_PROGRAMS_MAX) -> str:
+        """Load or compile ONE group program off the serving path (the
+        scheduler's ``warm_groups`` threads): "present" (already in the
+        pool), "loaded" (from the cache directory), "compiled", "full"
+        (``limit`` group programs exist and this is not one of them:
+        nothing is compiled) or "failed"."""
+        if not self.enable:
+            return "failed"
+        entry_hex = key.entry_hex(shape_signature(args))
+        if self._in_pool(entry_hex):
+            return "present"
+        compiles = not self._entry_on_disk(entry_hex)
+        if compiles:
+            recorded = self._recorded_groups()
+            with self._mu:      # counted and reserved in one step
+                if len(self._group_entries | recorded) \
+                        + self._group_reserved >= limit:
+                    return "full"
+                self._group_reserved += 1
+        try:
+            misses0 = self._tl.misses
+            if self.resolve(key, jit_fn, args) is None:
+                return "failed"
+            return "compiled" if self._tl.misses > misses0 else "loaded"
+        finally:
+            if compiles:
+                with self._mu:
+                    self._group_reserved -= 1
+
     # ---- the resolve seam ------------------------------------------- #
 
     def resolve(self, key: CompileKey, jit_fn, args, execute_ok=True):
@@ -382,7 +471,8 @@ class CompileCache:
                 exe, nbytes = loaded
                 dt_ns = time.perf_counter_ns() - t0
                 with self._mu:
-                    self._pool_put_locked(entry_hex, exe, nbytes)
+                    self._pool_put_locked(entry_hex, exe, nbytes,
+                                          is_group_key(key))
                     self.disk_hits += 1
                     self.hits += 1
                     self.load_ms_total += dt_ns / 1e6
@@ -438,16 +528,20 @@ class CompileCache:
                 release_compile_claim(entry_hex)
             return None
         dt_ns = time.perf_counter_ns() - t0
+        group = is_group_key(key)
         with self._mu:
             self.misses += 1
             self.compile_ms_total += dt_ns / 1e6
             self._tl.misses += 1
             self._tl.compiled_ns += dt_ns
+            if group:
+                self._group_compile_threads.add(
+                    threading.current_thread().name)
         self._m_miss.inc()
         self._m_resolve_ms.observe(dt_ns / 1e6, outcome="compile")
         nbytes = self._persist(entry_hex, key, exe) or NOMINAL_EXE_BYTES
         with self._mu:
-            self._pool_put_locked(entry_hex, exe, nbytes)
+            self._pool_put_locked(entry_hex, exe, nbytes, group)
         self._note_caps(key)
         self._note_mem(entry_hex, exe)
         m = self.manifest
@@ -461,7 +555,7 @@ class CompileCache:
                      {"digest": key.digest, "family": key.family,
                       "mesh_fp": key.mesh_fp,
                       "donation_sig": key.donation_sig,
-                      "capacity": key.capacity},
+                      "capacity": key.capacity, "group": group},
                      nbytes, dt_ns / 1e6, quarantined=quarantined)
         if claim is True:
             # persisted (or at least pooled): peers polling on our
@@ -484,7 +578,8 @@ class CompileCache:
                 exe, nbytes = loaded
                 dt_ns = time.perf_counter_ns() - t0
                 with self._mu:
-                    self._pool_put_locked(entry_hex, exe, nbytes)
+                    self._pool_put_locked(entry_hex, exe, nbytes,
+                                          is_group_key(key))
                     self.disk_hits += 1
                     self.hits += 1
                     self.load_ms_total += dt_ns / 1e6
@@ -518,13 +613,14 @@ class CompileCache:
             return False
         exe, nbytes = loaded
         dt_ns = time.perf_counter_ns() - t0
+        m = self.manifest
+        group = m is not None and entry_hex in m.group_entries()
         with self._mu:
-            self._pool_put_locked(entry_hex, exe, nbytes)
+            self._pool_put_locked(entry_hex, exe, nbytes, group)
             self.warm_loaded += 1
             self.load_ms_total += dt_ns / 1e6
         self._m_load.inc(dt_ns / 1e6)
         self._m_resolve_ms.observe(dt_ns / 1e6, outcome="warm")
-        m = self.manifest
         if m is not None:
             m.touch(entry_hex, dt_ns / 1e6)
         return True
@@ -536,6 +632,7 @@ class CompileCache:
         with self._mu:
             self._pool.clear()
             self._pool_bytes = 0
+            self._group_entries.clear()
             self._caps.clear()
             self._mem_info.clear()
             self._m_bytes.set(0)
@@ -556,6 +653,9 @@ class CompileCache:
                    "evictions": self.evictions,
                    "fallback_calls": self.fallback_calls,
                    "persist_supported": self._persist_ok,
+                   "group_programs_loaded": len(self._group_entries),
+                   "group_compile_threads": sorted(
+                       self._group_compile_threads),
                    "compile_ms": round(self.compile_ms_total, 3),
                    "load_ms": round(self.load_ms_total, 3)}
         m = self.manifest
@@ -594,14 +694,19 @@ class CachedProgram:
                 cache.fallback_calls += 1
             return self._jit(*args)
 
-    def warm(self, args) -> bool:
-        """Compile-or-load WITHOUT executing: the background fusion
-        warmup and boot replay pass ``jax.ShapeDtypeStruct`` trees here
-        so no array is ever held by a warm prediction."""
-        cache = compile_cache()
-        if not cache.enable:
-            return False
-        return cache.resolve(self.key, self._jit, args) is not None
+    def entry_hex(self, args) -> str:
+        """The identity of the one executable these arguments call."""
+        return self.key.entry_hex(shape_signature(args))
+
+    def loaded(self, args) -> bool:
+        """``CompileCache.loaded`` for this program's key."""
+        return compile_cache().loaded(self.key, args)
+
+    def warm_group(self, args, limit: int = GROUP_PROGRAMS_MAX) -> str:
+        """``CompileCache.warm_group`` for this program's key: load or
+        compile WITHOUT executing; ``args`` are ``jax.ShapeDtypeStruct``
+        trees, so no array is held by a warm."""
+        return compile_cache().warm_group(self.key, self._jit, args, limit)
 
 
 _CACHE: Optional[CompileCache] = None
@@ -634,4 +739,5 @@ def cached_call(jit_fn, dag, mesh, program: str, row_capacity: int = 0,
 
 
 __all__ = ["CompileCache", "CachedProgram", "compile_cache", "configure",
-           "cached_call", "ENTRY_SUFFIX", "FORMAT_VERSION", "MAGIC"]
+           "cached_call", "is_group_key", "GROUP_PROGRAMS",
+           "GROUP_PROGRAMS_MAX", "ENTRY_SUFFIX", "FORMAT_VERSION", "MAGIC"]

@@ -152,6 +152,7 @@ class WarmManifest:
                 "family": key_parts.get("family", ""),
                 "mesh_fp": key_parts.get("mesh_fp", ""),
                 "capacity": key_parts.get("capacity", 0),
+                "group": bool(key_parts.get("group", False)),
                 "bytes": int(nbytes),
                 "compile_ms": round(float(compile_ms), 3),
                 "load_ms": 0.0,
@@ -237,6 +238,13 @@ class WarmManifest:
         with self._mu:
             return any(e.get("digest") == digest
                        for e in self._entries.values())
+
+    def group_entries(self) -> set:
+        """Entry hexes of the recorded GROUP programs (fused, batched):
+        the persisted half of what the bound on them counts."""
+        with self._mu:
+            return {hx for hx, e in self._entries.items()
+                    if e.get("group")}
 
     def capacities_for(self, family: str) -> list:
         """Recorded regrow capacities of one plan family, ascending —

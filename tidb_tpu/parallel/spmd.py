@@ -326,6 +326,29 @@ def get_sharded_program(dag_root: D.CopNode, mesh, row_capacity: int = 0,
     return _cached(dag_root, mesh, row_capacity, True if donate else False)
 
 
+def _abstract(a, slots: int = 0):
+    """One argument's shape, dtype and sharding, holding no array; with
+    ``slots``, what ``_stack_slots`` makes of that many such arrays."""
+    shape = a.shape if not slots else (a.shape[0], slots) + a.shape[1:]
+    return jax.ShapeDtypeStruct(shape, a.dtype,
+                                sharding=getattr(a, "sharding", None))
+
+
+def _abstract_args(self, stacked_cols: Sequence, counts):
+    """The arguments of this fused program's call as shapes: what the
+    compile cache is asked about and a background compile is given."""
+    return jax.tree_util.tree_map(
+        _abstract, (tuple(stacked_cols), counts, ()))
+
+
+def _abstract_slot_args(self, cols_list: Sequence, counts_list: Sequence):
+    """The same for a batched program: the stacked arguments' shapes,
+    worked out from one slot's, nothing stacked."""
+    return jax.tree_util.tree_map(
+        functools.partial(_abstract, slots=self.n_slots),
+        (tuple((v, m) for v, m in cols_list[0]), counts_list[0], ()))
+
+
 def _members_facts(self, stacked_cols: Sequence, counts) -> dict:
     """`ShardedCopProgram.facts` of a fused program: its members', merged
     as copr/facts.py says of each."""
@@ -400,6 +423,7 @@ class FusedCopProgram:
                      for p in self.members)
 
     facts = _members_facts
+    abstract_args = _abstract_args
 
     def __call__(self, stacked_cols: Sequence, counts, aux_cols=()):
         if self._psum_limb_fence and stacked_cols:
@@ -474,6 +498,7 @@ class FusedRowsProgram:
                      for p in self.members)
 
     facts = _members_facts
+    abstract_args = _abstract_args
 
     def __call__(self, stacked_cols: Sequence, counts, aux_cols=()):
         return self._cached(tuple(stacked_cols), counts, tuple(aux_cols))
@@ -543,6 +568,8 @@ class BatchedCopProgram:
                                    n_slots=n_slots,
                                    donate_argnums=self._donate_argnums)
 
+    abstract_args = _abstract_slot_args
+
     def __call__(self, cols_list: Sequence, counts_list: Sequence) -> list:
         k = len(cols_list)
         if self.base._psum_limb_fence and cols_list[0]:
@@ -608,6 +635,8 @@ class BatchedRowsProgram:
             self._fn, dag_root, mesh, "batched-rows",
             row_capacity=row_capacity, n_slots=n_slots,
             donate_argnums=self._donate_argnums)
+
+    abstract_args = _abstract_slot_args
 
     def __call__(self, cols_list: Sequence, counts_list: Sequence) -> list:
         k = len(cols_list)

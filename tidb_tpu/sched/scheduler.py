@@ -240,8 +240,19 @@ class DeviceScheduler:
         self.fused_refused = 0
         self.batched_refused = 0
         self._refusals_logged: set = set()
+        # groups of two or more programs (or slots) whose group program
+        # was not loaded: served apart, the program remembered for
+        # `warm_groups` (NOT a refusal: nothing raised)
+        self.groups_apart_unloaded = 0
+        self.group_compiles_bg = 0        # group programs compiled, and
+        self.group_loads_bg = 0           # loaded from the cache dir,
+                                          # by `warm_groups`' threads
+        self.dedup_tasks = 0              # waiters that shared another
+                                          # task's execution (same key,
+                                          # same input token)
         self.window_waits = 0             # drains that held for stragglers
         self.window_hits = 0              # holds that actually gained riders
+        self.hold_ns_total = 0            # time those holds took
         self.busy_rejects = 0
         # HBM-budget admission accounting (analysis/copcost LaunchCost)
         self.budget_admitted = 0          # structured tasks costed + admitted
@@ -261,13 +272,16 @@ class DeviceScheduler:
         # copforge compile-cache accounting (compilecache/): program
         # resolve/compile time the drain paid, split out of schedWait
         self.compile_ns_total = 0         # summed per-launch resolve time
-        self.warm_predicted = 0           # background fused-variant warms
-        self.warm_failures = 0            # predictions that failed to
-                                          # compile (never surfaced)
-        self._warm_alive = 0              # in-flight prediction threads
-        self._fusion_seen: dict = {}      # fusion key -> digest -> (dag,
-                                          # sds-args) for prediction
-        self._fusion_warmed: set = set()  # member-digest combos warmed
+        self.warm_failures = 0            # background group compiles
+                                          # that failed (never surfaced)
+        self._warm_alive = 0              # background compile threads
+        # group programs waiting for `warm_groups`: cache entry ->
+        # (CachedProgram, abstract args); those a thread is working on;
+        # and those none will try again (failed, or the bound on group
+        # programs was reached without them)
+        self._groups_pending: dict = {}
+        self._groups_inflight: set = set()
+        self._groups_settled: set = set()
         # supervised-launch accounting (faultline)
         self.retried_launches = 0         # serve attempts re-run after a
                                           # transient launch failure
@@ -312,6 +326,17 @@ class DeviceScheduler:
         self._m_fused = reg.counter("tidb_tpu_sched_fused_tasks_total",
                                     "tasks served by a cross-query "
                                     "fused launch")
+        self._m_apart = reg.counter(
+            "tidb_tpu_sched_groups_apart_unloaded_total",
+            "groups served apart because their group program was not "
+            "loaded")
+        self._m_group_bg = reg.counter(
+            "tidb_tpu_sched_group_programs_bg_total",
+            "group programs the background thread compiled or loaded",
+            labels=("outcome",))
+        self._m_dedup = reg.counter(
+            "tidb_tpu_sched_dedup_tasks_total",
+            "tasks that shared another task's execution")
         self._m_wait = reg.histogram("tidb_tpu_sched_wait_seconds",
                                      "admission queue wait")
         self._m_ru = reg.counter("tidb_tpu_sched_ru_total",
@@ -387,6 +412,9 @@ class DeviceScheduler:
         self._m_launch_ms = reg.histogram(
             "tidb_tpu_sched_launch_ms",
             "device launch wall time per launch (ms)", buckets=ms)
+        self._m_hold_ms = reg.histogram(
+            "tidb_tpu_sched_hold_ms",
+            "micro-batch window hold on a lead (ms)", buckets=ms)
         self._m_compile_ms = reg.histogram(
             "tidb_tpu_sched_compile_ms",
             "program resolve/compile time per launch (ms)", buckets=ms)
@@ -678,10 +706,6 @@ class DeviceScheduler:
         # starts at its pick-up); wait_ns (/sched wait_p50_ms) keeps
         # counting from submit_ns
         task.enqueue_ns = time.perf_counter_ns()
-        if task.fusion_key is not None and task.key is not None:
-            # copforge: a second digest joining this fusion key predicts
-            # the fused variant — warm it off-thread (lock released)
-            self._predict_fusion(task)
         return task
 
     def pause(self) -> None:
@@ -945,7 +969,8 @@ class DeviceScheduler:
             if w_ns > 0 and len(batch) < self.max_coalesce:
                 # wait-for-stragglers: _cv.wait releases the lock, so
                 # submits land and notify; re-collect after each wake
-                deadline = time.perf_counter_ns() + w_ns
+                held_ns = time.perf_counter_ns()
+                deadline = held_ns + w_ns
                 self.window_waits += 1
                 held_at = len(batch)
                 while len(batch) < self.max_coalesce:
@@ -955,7 +980,14 @@ class DeviceScheduler:
                     self._cv.wait(rem_ns / 1e9)
                     self._collect_riders(lead, batch)
                 # window feedback: did the hold actually gain riders?
-                self._note_window_outcome(lead, len(batch) > held_at)
+                gained = len(batch) - held_at
+                self._note_window_outcome(lead, gained > 0)
+                # the tree's sched.hold, on every task of the batch
+                hold = (held_ns, time.perf_counter_ns(), gained)
+                self.hold_ns_total += hold[1] - held_ns
+                self._m_hold_ms.observe((hold[1] - held_ns) / 1e6)
+                for t in batch:
+                    t.hold = hold
         self._m_depth.set(self._depth)
         return batch
 
@@ -1103,67 +1135,83 @@ class DeviceScheduler:
             if dmiss:
                 t.compile_miss = True
 
-    def _predict_fusion(self, task) -> None:
-        """Async background warmup of predicted fusion variants: when a
-        second distinct program digest joins a fusion key, the fused
-        program for the combined member set is probably about to be
-        needed — compile it into the warm pool on a background thread
-        (bounded) so the first real fused arrival pays a pool hit, not
-        a trace.  Never on the drain thread, never surfaced on failure."""
-        from ..compilecache import compile_cache
-        if not self.fusion_enable or not compile_cache().enable:
-            return
-        from ..copr import dag as D
-        if not isinstance(task.dag, D.Aggregation):
-            return          # rows fusion capacities are waiter-owned
-        import jax
-        sds = jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(
-                a.shape, a.dtype, sharding=getattr(a, "sharding", None)),
-            (tuple(task.cols), task.counts, ()))
+    def _group_unloaded(self, tasks: list, prog, args) -> None:
+        """A group of two or more programs (or slots) formed and its
+        group program ``prog`` is not loaded.  The drain never compiles
+        one where clients wait, and nothing else compiles one by itself
+        either: the caller serves the members apart in this turn, by
+        their solo programs, and the missing program is remembered (a
+        bounded list) for an explicit ``warm_groups``.  ``args``: the
+        program's call as shapes (``abstract_args``), which hold no
+        array."""
+        from ..compilecache import GROUP_PROGRAMS_MAX
+        for t in tasks:
+            t.apart = True
+        entry = prog._cached.entry_hex(args)
         with self._mu:
-            if len(self._fusion_seen) > 64:
-                self._fusion_seen.clear()
-            seen = self._fusion_seen.setdefault(task.fusion_key, {})
-            seen[task.key[0]] = (task.dag, sds)
-            if len(seen) < 2 or len(self._fusion_warmed) > 32 \
-                    or self._warm_alive >= 2:
-                return
-            combo = (task.fusion_key, frozenset(seen))
-            if combo in self._fusion_warmed:
-                return
-            self._fusion_warmed.add(combo)
-            members = [dag for dag, _s in seen.values()]
-            lead_sds = next(iter(seen.values()))[1]
-            self._warm_alive += 1
-        mesh = task.mesh
+            self.groups_apart_unloaded += 1
+            if entry not in self._groups_settled \
+                    and entry not in self._groups_inflight \
+                    and len(self._groups_pending) < GROUP_PROGRAMS_MAX:
+                self._groups_pending.setdefault(entry,
+                                                (prog._cached, args))
+        self._m_apart.inc()
 
-        def warm():
-            ok = False
-            try:
-                from ..parallel.spmd import get_fused_program
-                fused = D.FusedDag(tuple(members))
-                prog = get_fused_program(fused, mesh)
-                prog._cached.warm(lead_sds)
-                ok = True
-            except Exception as e:   # noqa: BLE001 - prediction is a
-                # pure optimization: an unfusable combo or a backend
-                # refusal just means the real arrival compiles as before
-                # (counted as warm_failures below)
-                _log.warning("predicted fusion warm-up failed: %s",
-                             self._err_label(e))
-            finally:
-                # counters under _mu: up to two warm threads run
-                # concurrently, so a bare += here loses updates
-                with self._mu:
+    def warm_groups(self) -> int:
+        """The explicit warm: load or compile, on background threads
+        (two alive at the most), the group programs of the member sets
+        that co-occurred and were served apart.  With a cache directory
+        they persist, and boot replay (compilecache/warmup) brings them
+        back with the next process.  -> how many were waiting."""
+        with self._mu:
+            n = len(self._groups_pending)
+            start = max(min(n, 2 - self._warm_alive), 0)
+            self._warm_alive += start
+        for _ in range(start):
+            # not a daemon: a process that exits inside an XLA compile
+            # aborts
+            threading.Thread(target=self._group_worker,
+                             name="copforge-group", daemon=False).start()
+        return n
+
+    def _group_worker(self) -> None:
+        """``warm_groups``' thread: load or compile the pending group
+        programs, oldest first, through ``CompileCache.warm_group``,
+        which holds them to ``GROUP_PROGRAMS_MAX``: what exists beyond
+        this process counts, so once a cache directory holds that many
+        no process over it compiles another, and a set that is not
+        among them is served apart for good.  Never surfaced on
+        failure: the members were served apart already."""
+        while True:
+            with self._mu:
+                if not self._groups_pending:
                     self._warm_alive -= 1
-                    if ok:
-                        self.warm_predicted += 1
-                    else:
+                    return
+                entry = next(iter(self._groups_pending))
+                cached, args = self._groups_pending.pop(entry)
+                self._groups_inflight.add(entry)
+            try:
+                outcome = cached.warm_group(args)
+            except Exception as e:   # noqa: BLE001 - a pure optimization:
+                # an unfusable set or a backend refusal means the set
+                # stays apart (counted as warm_failures below)
+                outcome = "failed"
+                _log.warning("background group compile failed: %s",
+                             self._err_label(e))
+            with self._mu:
+                self._groups_inflight.discard(entry)
+                if outcome == "compiled":
+                    self.group_compiles_bg += 1
+                elif outcome == "loaded":
+                    self.group_loads_bg += 1
+                elif outcome in ("failed", "full"):
+                    if len(self._groups_settled) > 1024:
+                        self._groups_settled.clear()
+                    self._groups_settled.add(entry)
+                    if outcome == "failed":
                         self.warm_failures += 1
-
-        threading.Thread(target=warm, name="copforge-predict",
-                         daemon=True).start()
+            if outcome in ("compiled", "loaded"):
+                self._m_group_bg.inc(outcome=outcome)
 
     # ------------------------------------------------------------- #
     # copscope span recording (obs/): the drain's side of the trace
@@ -1285,12 +1333,19 @@ class DeviceScheduler:
                 attrs["strategy"] = strat
             if t.retries:
                 attrs["retries"] = t.retries
+            queued_ns = min(t.enqueue_ns, t.start_ns)
             items = [
-                ("sched.queue", min(t.enqueue_ns, t.start_ns),
+                ("sched.queue", queued_ns,
                  t.start_ns, ctx.span_id,
                  {"group": t.group, "ru": round(t.rus_charged, 2)}),
                 ("sched.launch", start_ns, end_ns, ctx.span_id, attrs),
             ]
+            if t.hold is not None and t.hold[1] > max(t.hold[0], queued_ns):
+                # the micro-batch window's hold, as far as this task sat
+                # through it: a child of its sched.queue
+                items.append(("sched.hold", max(t.hold[0], queued_ns),
+                              t.hold[1], ("rel", 0),
+                              {"riders_gained": t.hold[2]}))
             if fused <= 1 and start_ns > t.start_ns:
                 # the drain between picking the batch up and the launch
                 # span: the ledger, the supervisor, the grouping by key
@@ -1573,15 +1628,22 @@ class DeviceScheduler:
 
     def _serve_fused(self, programs: list) -> bool:
         """ONE launch computing every member program's payload from the
-        shared scan; False = refused (contract violation / backend
-        can't), caller falls back to per-program launches.  Agg member
+        shared scan; False = the caller serves the programs apart:
+        refused (contract violation / backend can't; counted), or the
+        fused program of this member set is not loaded
+        (``_group_unloaded``: nothing compiles here).  Agg member
         groups run as a FusedCopProgram; rows-kind groups (fusion-breadth
         follow-on) run as a FusedRowsProgram with per-member output
         capacities."""
+        from ..analysis.compilekey import stable_digest
         from ..copr import dag as D
         from ..parallel.spmd import (get_fused_program,
                                      get_fused_rows_program,
                                      get_sharded_program)
+        # a member SET is one program, in whatever order its members
+        # arrived
+        programs = sorted(programs,
+                          key=lambda grp: stable_digest(grp[0].dag))
         members = [grp[0] for grp in programs]
         all_tasks = [t for grp in programs for t in grp]
         lead = members[0]
@@ -1608,6 +1670,10 @@ class DeviceScheduler:
                 fprog = get_fused_rows_program(
                     fused, lead.mesh,
                     tuple(t.row_capacity for t in members))
+            args = fprog.abstract_args(lead.cols, lead.counts)
+            if not fprog._cached.loaded(args):
+                self._group_unloaded(all_tasks, fprog, args)
+                return False
             with self._live_launch(all_tasks, "fused", fprog.name):
                 outs = fprog(lead.cols, lead.counts)
         except Exception as e:   # noqa: BLE001 - fusion capability probe:
@@ -1625,9 +1691,12 @@ class DeviceScheduler:
                        coalesced=len(all_tasks), fused=len(programs))
         return True
 
+    # a launch's form, as its span's `group` says it
+    GROUP_OF_MODE = {"single": "solo", "coalesced": "dedup"}
+
     def _launched(self, served: list, program, mode: str, facts: dict,
                   t0: int, cc0: tuple, coalesced: int,
-                  fused: int = 0) -> None:
+                  fused: int = 0, slots: int = 1) -> None:
         """The epilogue of every structured launch: `served` is its
         (task, the task's result) pairs, `facts` the program's
         ``facts()`` for these inputs, counted and put on the span as
@@ -1635,7 +1704,10 @@ class DeviceScheduler:
         BEFORE finish(): its _note_sched reads task.fused right after
         wait() returns, so setting it after finish raced the waiter and
         undercounted `fused`/`coalesced` in EXPLAIN ANALYZE and
-        statements_summary (copscope satellite: the note_sched seam)."""
+        statements_summary (copscope satellite: the note_sched seam).
+        ``slots``: a batched launch's distinct inputs; with ``fused``
+        (its member programs) what the launch executed, so the tasks
+        beyond that many shared another task's execution."""
         from ..copr import facts as F
         tasks = [t for t, _val in served]
         self._cc_note(tasks, cc0)
@@ -1643,10 +1715,17 @@ class DeviceScheduler:
             t.fused, t.coalesced = fused, coalesced
         self._mem_note(tasks, tasks[0].mesh)
         t1 = time.perf_counter_ns()
+        form = {"members": fused or 1, "waiters": len(tasks),
+                "group": "apart_unloaded" if tasks[0].apart
+                else self.GROUP_OF_MODE.get(mode, mode)}
+        dedup = len(tasks) - max(fused, slots)
+        if dedup:
+            self.dedup_tasks += dedup
+            self._m_dedup.inc(dedup)
         with self._epilogue(tasks):
             self._trace_launch(tasks, t0, t1, mode, fused=fused,
                                program=program.name,
-                               said=F.span_attrs(facts))
+                               said={**form, **F.span_attrs(facts)})
             for name in F.counters(facts):
                 self.kernel_counts[name] += 1
         for t, val in served:
@@ -1694,7 +1773,9 @@ class DeviceScheduler:
         if len(slots) > 1 and not prog.host_merge and not prog.has_extras \
                 and all(s[0].aux == () for s in slots):
             # distinct inputs, one program: stack along the batch-slot
-            # dim, ONE vmapped launch, split states/rows per task
+            # dim, ONE vmapped launch, split states/rows per task —
+            # where that program is loaded; else the slots launch apart
+            # below
             try:
                 if prog.kind == "agg":
                     bprog = get_batched_program(lead.dag, lead.mesh,
@@ -1702,16 +1783,20 @@ class DeviceScheduler:
                 else:
                     bprog = get_batched_rows_program(
                         lead.dag, lead.mesh, lead.row_capacity, len(slots))
-                with self._live_launch(batch, "batched", bprog.name):
-                    outs = bprog([s[0].cols for s in slots],
-                                 [s[0].counts for s in slots])
-                # a slot's facts are the solo program's
-                self._launched(
-                    [(t, (prog, out)) for s, out in zip(slots, outs)
-                     for t in s], bprog, "batched",
-                    prog.facts(lead.cols, lead.counts), t_l0, cc0,
-                    coalesced=len(batch))
-                return
+                cols_list = [s[0].cols for s in slots]
+                counts_list = [s[0].counts for s in slots]
+                args = bprog.abstract_args(cols_list, counts_list)
+                if bprog._cached.loaded(args):
+                    with self._live_launch(batch, "batched", bprog.name):
+                        outs = bprog(cols_list, counts_list)
+                    # a slot's facts are the solo program's
+                    self._launched(
+                        [(t, (prog, out)) for s, out in zip(slots, outs)
+                         for t in s], bprog, "batched",
+                        prog.facts(lead.cols, lead.counts), t_l0, cc0,
+                        coalesced=len(batch), slots=len(slots))
+                    return
+                self._group_unloaded(batch, bprog, args)
             except Exception as e:   # planlint: ok - vmap capability probe;
                 # op not vmappable on this backend: launch apart below
                 # (same results, no batching win) — counted and logged
@@ -1924,8 +2009,13 @@ class DeviceScheduler:
                 "fused_tasks": self.fused_tasks,
                 "fused_refused": self.fused_refused,
                 "batched_refused": self.batched_refused,
+                "groups_apart_unloaded": self.groups_apart_unloaded,
+                "group_compiles_bg": self.group_compiles_bg,
+                "group_loads_bg": self.group_loads_bg,
+                "dedup_tasks": self.dedup_tasks,
                 "window_waits": self.window_waits,
                 "window_hits": self.window_hits,
+                "hold_ns_total": self.hold_ns_total,
                 "busy_rejects": self.busy_rejects,
                 "hbm_budget": self.effective_budget(),
                 "budget_admitted": self.budget_admitted,
@@ -1938,9 +2028,8 @@ class DeviceScheduler:
                 "donated_tasks": self.donated_tasks,
                 "donated_bytes": self.donated_bytes,
                 # copforge (compilecache/): drain-paid resolve time +
-                # predicted-fusion background warms
+                # background group compiles that failed
                 "compile_ms_total": round(self.compile_ns_total / 1e6, 3),
-                "warm_predicted": self.warm_predicted,
                 "warm_failures": self.warm_failures,
                 # launch supervision (faultline): retry/bisect/breaker
                 "retried_launches": self.retried_launches,

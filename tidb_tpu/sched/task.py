@@ -118,7 +118,7 @@ class CopTask:
                  "donate", "retries", "compile_ns", "compile_miss",
                  "hbm_predicted", "hbm_measured", "value_drift", "trace",
                  "program", "epilogue", "finish_ns",
-                 "enqueue_ns")
+                 "enqueue_ns", "hold", "apart")
 
     def __init__(self, *, key=None, dag=None, mesh=None, row_capacity=0,
                  cols=None, counts=None, aux=(), input_token=None,
@@ -191,6 +191,12 @@ class CopTask:
         # the waiter's sched.wake runs
         self.epilogue = None
         self.finish_ns = 0
+        # the micro-batch window's hold this task's batch sat through:
+        # (start ns, end ns, riders gained), set by the drain; and
+        # whether its group was served apart because the group's
+        # program was not loaded
+        self.hold = None
+        self.apart = False
         self.cancelled = False
         self._done = threading.Event()
         self._value = None
