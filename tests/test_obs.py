@@ -934,6 +934,72 @@ def test_plan_cache_hit_skips_the_gates(odom):
     assert plan.attrs["cache"] == "hit" and "plan.gates" not in names
 
 
+@pytest.mark.parametrize("served", [False, True], ids=["session", "wire"])
+def test_session_parse_says_what_the_statement_memo_saved(odom, wire,
+                                                          served):
+    """``session.parse`` is in every tree, once, with ``memo`` = ``miss``
+    while the bracket parsed (first sight; second sight, whose parse the
+    memo keeps) and ``hit`` from then on; ``tidb_tpu_stmt_memo_total``
+    counts one outcome a statement; ``span_self_ms.session.parse``'s
+    reader finds the span whatever it holds."""
+    import importlib.util
+    import os
+    import sys
+    import types
+    from tests.helpers import memo_outcomes as _memo_outcomes
+    from tidb_tpu.server.client import Client
+    dom, _s, _sched = odom
+    frag = f"sum(p + {int(served)}) from obs_t where q < 17"
+    sql = "select " + frag
+    if served:
+        conn = Client("127.0.0.1", wire.port, db="test")
+        run = conn.query
+    else:
+        run = Session(dom).must_query
+    before, trees = _memo_outcomes(), []
+    try:
+        for _ in range(4):
+            assert run(sql)
+            tree = _served_tree(dom, frag) if served \
+                else _trace_of(dom, frag)
+            assert tree not in trees
+            trees.append(tree)
+        parses = [[sp for sp in t.spans if sp.name == "session.parse"]
+                  for t in trees]
+        assert [len(p) for p in parses] == [1, 1, 1, 1]
+        assert [p[0].attrs["memo"] for p in parses] \
+            == ["miss", "miss", "hit", "hit"]
+        after = _memo_outcomes()
+        assert {o: after[o] - before[o] for o in after} \
+            == {"miss": 1, "bypass": 1, "hit": 2}
+        # a packet of two statements: two outcomes, the first tree's
+        # span says what the bracket did for the packet
+        run(f"{sql}; select count(*) from obs_t")
+        assert sum(_memo_outcomes().values()) == sum(after.values()) + 2
+    finally:
+        if served:
+            conn.close()
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path.insert(0, bench)               # harness.xplane
+    try:
+        spec = importlib.util.spec_from_file_location(
+            "obs_span_self_ms",
+            os.path.join(bench, "layer_metrics", "span_self_ms.py"))
+        reader = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(reader)
+    finally:
+        sys.path.remove(bench)
+    for kind, some in (("miss", trees[:2]), ("hit", trees[2:])):
+        run_ = types.SimpleNamespace(
+            trees=[dict(t.to_dict(), **{"class": "c"}) for t in some])
+        assert reader.read(run_, "session.parse") > 0, kind
+        parse = [sp for t in run_.trees for sp in t["spans"]
+                 if sp["name"] == "session.parse"]
+        assert [sp["attrs"] for sp in parse] == [{"memo": kind}] * 2
+
+
+
 _NAME_PROBE = """
 import numpy as np
 from tidb_tpu.session import Domain, Session

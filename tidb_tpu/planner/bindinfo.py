@@ -46,8 +46,15 @@ class BindManager:
             return self._bindings.pop(d, None) is not None
 
     def match(self, sql: str) -> Optional[Binding]:
+        return self.match_digest(normalize_sql(sql))
+
+    def match_digest(self, digest: str) -> Optional[Binding]:
+        """``match`` for a caller that has ``normalize_sql(sql)`` already
+        (the session's statement memo)."""
+        if not self._bindings:      # every statement asks, twice
+            return None
         with self._lock:
-            b = self._bindings.get(normalize_sql(sql))
+            b = self._bindings.get(digest)
         return b if b is not None and b.status == "enabled" else None
 
     def rows(self) -> list[tuple]:

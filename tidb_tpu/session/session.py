@@ -29,6 +29,7 @@ from ..store.kv import KVError
 from ..types import dtypes as dt
 from .catalog import (Catalog, CatalogError, TableInfo, plainify,
                       type_from_sql)
+from .stmt_memo import StmtMemo, referenced_tables
 
 
 @dataclass
@@ -98,6 +99,8 @@ class Domain:
         self.watch.subscribe("privilege", self._on_privilege_event)
         from ..planner.plan_cache import PlanCache
         self.plan_cache = PlanCache()          # instance plan cache
+        # what is a pure function of a command's text, kept by the text
+        self.stmt_memo = StmtMemo()
         self.schema_version = 1                # bumped per DDL transition
         from ..ddl.mdl import MDLRegistry
         self.mdl = MDLRegistry()               # pkg/ddl/mdl analog
@@ -281,7 +284,8 @@ class Domain:
             return self._next_table_id
 
     def query_metrics(self):
-        """Cached (counter, histogram) pair for the statement hot path."""
+        """Cached (counter, histogram, statement-memo counter) for the
+        statement hot path."""
         m = getattr(self, "_query_metrics", None)
         if m is None:
             from ..utils.metrics import global_registry
@@ -290,7 +294,12 @@ class Domain:
                 reg.counter("tidb_tpu_query_total", "statements executed",
                             labels=("type",)),
                 reg.histogram("tidb_tpu_query_duration_seconds",
-                              "statement latency"))
+                              "statement latency"),
+                reg.counter("tidb_tpu_stmt_memo_total",
+                            "statements by what the statement memo saved "
+                            "of their text: hit (no parse), miss (first "
+                            "sight), bypass (known or too long, parsed "
+                            "afresh)", labels=("outcome",)))
         return m
 
     def register_session(self, sess) -> int:
@@ -326,6 +335,11 @@ class Session:
         self.txn = None              # active explicit transaction
         self._txn_tables: set = set()
         self._cur_sql: Optional[str] = None      # text of the running stmt
+        # set while the running statement's AST is the statement memo's
+        # (shared, never written to): a one-cell list holding the memo's
+        # outcome for it, which whoever parses it afresh for the builder
+        # turns from "hit" to "bypass"
+        self._cur_shared: Optional[list] = None
         # session-scoped temporary tables: (db, name) -> TableInfo;
         # installed as a catalog overlay per statement (catalog.TEMP_TABLES)
         self.temp_tables: dict = {}
@@ -353,7 +367,7 @@ class Session:
     # ------------------------------------------------------------- #
 
     def execute(self, sql: str) -> ResultSet:
-        qcnt, qdur = self.domain.query_metrics()
+        qcnt, _qdur, memo_cnt = self.domain.query_metrics()
         out = ResultSet()
         self.last_trace = None
         # served over the wire: the connection stamped the command's read
@@ -361,16 +375,20 @@ class Session:
         if wire_t0 is not None:     # (a nested execute leaves the
             self.wire_span = None   # served statement's where it is)
         # parsing precedes every statement's root span: it is bracketed
-        # here and added to each tree below as a completed span
+        # here and added to each tree below as a completed span.  The
+        # bracket holds the statement memo's lookup, and a parse only
+        # where the memo has no AST for the text (session/stmt_memo.py)
         tracing = _flag_on({**self.domain.sysvars, **self.vars},
                            "tidb_tpu_trace", True)
         parse_ann = (_obs_trace.annotation("session.parse") if tracing
                      else contextlib.nullcontext())
         parse_t0 = time.perf_counter_ns()
         with parse_ann:
-            stmts = parse_sql(sql)
+            stmts = self.domain.stmt_memo.resolve(sql)
         parse_t1 = time.perf_counter_ns()
-        for nstmt, stmt in enumerate(stmts):
+        parse_memo = ("hit" if all(r.outcome == "hit" for r in stmts)
+                      else "miss")         # miss: the bracket parsed
+        for nstmt, (rec, stmt, shared, memo_outcome) in enumerate(stmts):
             t0 = time.perf_counter_ns()
             # session.begin: from here to the root span (bindings, the
             # plugins' on_stmt_begin, the coordinator, the resource
@@ -380,22 +398,20 @@ class Session:
             begin_ann = (_obs_trace.annotation("session.begin") if tracing
                          else contextlib.nullcontext())
             begin_ann.__enter__()
-            span = getattr(stmt, "text_span", None)
-            text = sql[span[0]:span[1]].strip() if span else sql
+            text = rec.text
             self._cur_sql = text
             # plan bindings: a matching digest donates its hints
             # (bindinfo BindHandle match; session shadows global).
             # EXPLAIN shows the bound plan too.
-            target, btext = stmt, text
-            if isinstance(stmt, (A.Explain, A.TraceStmt)):
-                target = stmt.stmt
-                import re as _re
-                btext = _re.sub(r"(?is)^\s*(explain(\s+analyze)?|trace)\s+",
-                                "", text)
+            target = (stmt.stmt if isinstance(stmt, (A.Explain, A.TraceStmt))
+                      else stmt)
             if isinstance(target, A.SelectStmt) and not target.hints:
-                b = (self.bindings.match(btext)
-                     or self.domain.bindings.match(btext))
+                b = (self.bindings.match_digest(rec.bind_digest)
+                     or self.domain.bindings.match_digest(rec.bind_digest))
                 if b is not None:
+                    if shared:      # the memo's AST is not to be written to
+                        stmt = target = parse_sql(text)[0]
+                        shared, memo_outcome = False, "bypass"
                     target.hints = list(b.hints)
                     # bound statements bypass the plan cache: a cached
                     # unhinted plan must not shadow the binding (and
@@ -472,7 +488,7 @@ class Session:
                                parent_id=before)
                 if wire_root is None or nstmt == 0:
                     trace_tree.add("session.parse", parse_t0, parse_t1,
-                                   parent_id=before)
+                                   parent_id=before, memo=parse_memo)
                 obs_tok = _obs_trace.TRACE_CTX.set(
                     _obs_trace.TraceCtx(trace_tree, trace_root.span_id))
                 root_ann = _obs_trace.annotation(
@@ -498,9 +514,10 @@ class Session:
             qtok = SEQUENCE_RESOLVER.set(
                 lambda nm: self.domain.catalog.get_sequence(self.db, nm))
             ttok = TEMP_TABLES.set(self.temp_tables)
+            memo_cell = self._cur_shared = [memo_outcome] if shared else None
             try:
                 with root_ann:
-                    out = self._exec_stmt(stmt)
+                    out = self._exec_stmt(stmt, rec.tables)
             except Exception as e:
                 qcnt.inc(type="error")
                 _plugins.fire("on_stmt_end", self, text, str(e),
@@ -535,10 +552,12 @@ class Session:
                             "session.finish", trace_tree.trace_id)
                         fin_ann.__enter__()
                 self.domain.coordinator.end(self.conn_id)
-                self._cur_sql = None
+                self._cur_sql = self._cur_shared = None
+                memo_cnt.inc(outcome=memo_cell[0] if memo_cell
+                             else memo_outcome)
             try:
                 self._finish_stmt(stmt, text, out, t0, cpu0, handle,
-                                  _merged_obs, trace_tree)
+                                  _merged_obs, trace_tree, rec.digest)
             finally:
                 if fin_ann is not None:
                     fin_ann.__exit__(None, None, None)
@@ -551,13 +570,13 @@ class Session:
 
     def _finish_stmt(self, stmt, text: str, out: ResultSet, t0: int,
                      cpu0: int, handle, merged: dict,
-                     trace_tree) -> None:
+                     trace_tree, digest: str) -> None:
         """What a statement does after its root span has ended (the
         ``session.finish`` span): metrics, the statement summary, the
         flight recorder's offer, the resource group's charge, the
         plugins' ``on_stmt_end``."""
         from ..plugin import registry as _plugins
-        qcnt, qdur = self.domain.query_metrics()
+        qcnt, qdur, _memo_cnt = self.domain.query_metrics()
         dt_ns = time.perf_counter_ns() - t0
         qcnt.inc(type=type(stmt).__name__)
         qdur.observe(dt_ns / 1e9)
@@ -583,7 +602,8 @@ class Session:
             fused=handle.sched_fused,
             retried=handle.sched_retried,
             trace_id=trace_tree.trace_id
-            if trace_tree is not None else "")
+            if trace_tree is not None else "",
+            digest=digest)
         if trace_tree is not None:
             if seen.slow:
                 trace_tree.flag("slow")
@@ -682,8 +702,9 @@ class Session:
                   "DropDatabase", "CreateSequence", "DropSequence",
                   "CreateView", "DropView")
 
-    def _exec_stmt(self, stmt: A.Node) -> ResultSet:
-        self._check_privileges(stmt)
+    def _exec_stmt(self, stmt: A.Node,
+                   tables: Optional[tuple] = None) -> ResultSet:
+        self._check_privileges(stmt, tables)
         if (self.txn is not None
                 and type(stmt).__name__ in self._IMPLICIT_COMMIT):
             # MySQL semantics: DDL implicitly commits the open transaction
@@ -947,17 +968,22 @@ class Session:
         "DropDatabase": "DROP", "AnalyzeTable": "INSERT",
     }
 
-    def _check_privileges(self, stmt: A.Node) -> None:
+    def _check_privileges(self, stmt: A.Node,
+                          tables: Optional[tuple] = None) -> None:
         """Statement-level privilege verification (reference:
         planner/core/planbuilder.go visitInfo + privilege.Handle
-        RequestVerification)."""
+        RequestVerification).  ``tables``: what ``_referenced_tables``
+        gives for the query (``stmt``, or the one it explains), where
+        the statement memo has it; the verdict is never kept."""
         priv = self.domain.privileges
         if isinstance(stmt, (A.SelectStmt, A.SetOpStmt)):
-            for db, tbl in self._referenced_tables(stmt):
+            if tables is None:
+                tables = self._referenced_tables(stmt)
+            for db, tbl in tables:
                 priv.require(self.user, "SELECT", db or self.db, tbl)
             return
         if isinstance(stmt, (A.Explain, A.TraceStmt)):
-            return self._check_privileges(stmt.stmt)
+            return self._check_privileges(stmt.stmt, tables)
         if isinstance(stmt, (A.CreateUser, A.AlterUser, A.DropUser)):
             return priv.require(self.user, "CREATE USER")
         if isinstance(stmt, (A.GrantStmt, A.RevokeStmt)):
@@ -1014,40 +1040,7 @@ class Session:
         priv.require(self.user, need, db, target)
 
     def _referenced_tables(self, node: A.Node) -> list[tuple]:
-        """All (db, table) names a query reads — walks FROM clauses,
-        joins, subqueries, CTE bodies (skipping CTE self-references)."""
-        out: list[tuple] = []
-        cte_names: set = set()
-
-        def walk(n):
-            if n is None or not isinstance(n, A.Node):
-                return
-            if isinstance(n, A.TableName):
-                if n.name not in cte_names:
-                    out.append((n.db, n.name))
-                return
-            if isinstance(n, A.CTE):
-                cte_names.add(n.name)
-            # register CTE names BEFORE visiting FROM clauses that
-            # reference them (dataclass field order puts from_ first)
-            for cte in getattr(n, "ctes", ()):
-                walk(cte)
-            for f in getattr(n, "__dataclass_fields__", {}):
-                if f == "ctes":
-                    continue
-                v = getattr(n, f, None)
-                if isinstance(v, A.Node):
-                    walk(v)
-                elif isinstance(v, (list, tuple)):
-                    for x in v:
-                        if isinstance(x, A.Node):
-                            walk(x)
-                        elif isinstance(x, tuple):
-                            for y in x:
-                                if isinstance(y, A.Node):
-                                    walk(y)
-        walk(node)
-        return out
+        return referenced_tables(node)
 
     def _exec_user_admin(self, stmt: A.Node) -> ResultSet:
         priv = self.domain.privileges
@@ -1133,10 +1126,14 @@ class Session:
     # ------------------------------------------------------------- #
 
     @_obs_trace.span("session.plan")
-    def _plan_select(self, stmt, cache_sql: Optional[str] = None):
+    def _plan_select(self, stmt, cache_sql: Optional[str] = None,
+                     shared: Optional[list] = None):
         """Plan one SELECT inside a ``session.plan`` span, whose ``cache``
         attr says whether the plan cache answered or the plan was built,
-        optimised and gated."""
+        optimised and gated.  ``shared`` (``Session._cur_shared``):
+        ``stmt`` is the statement memo's AST of ``cache_sql``, and the
+        builder, which writes to what it is given, gets a parse of its
+        own."""
         from ..planner.plan_cache import PlanCacheEntry, table_fingerprint
         from ..planner.ranger import apply_index_paths
         cache = self.domain.plan_cache
@@ -1161,6 +1158,10 @@ class Session:
             if e is not None:
                 _obs_trace.annotate(cache="hit")
                 return e.built, e.phys
+        if shared is not None:
+            stmt = parse_sql(cache_sql)[0]
+            shared[0] = "bypass"
+            _obs_trace.annotate(memo="bypass")
         # uncorrelated scalar subqueries evaluate eagerly at plan time
         # (EvalSubqueryFirstRow analog); plans that did so are not cached
         # since the folded constant goes stale with the data
@@ -1410,9 +1411,10 @@ class Session:
     def _exec_select(self, stmt) -> ResultSet:
         cache_sql = self._cur_sql
         self._cur_sql = None  # inner selects (INSERT..SELECT) don't cache
+        shared, self._cur_shared = self._cur_shared, None
         if getattr(stmt, "for_update", False):
             self._lock_for_update(stmt)
-        built, phys = self._plan_select(stmt, cache_sql)
+        built, phys = self._plan_select(stmt, cache_sql, shared)
         # session.inputs: the execution context, the executor's walk
         # down to the client, snapshot and shards, the task: from here
         # to the statement's first cop.* span

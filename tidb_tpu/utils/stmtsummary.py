@@ -186,11 +186,13 @@ class StmtSummary:
                sched_wait_ns: int = 0, rus: float = 0.0,
                compile_ns: int = 0, sched_tasks: int = 0,
                fused: int = 0, retried: int = 0,
-               trace_id: str = "") -> Recorded:
+               trace_id: str = "", digest: str = "") -> Recorded:
         """Returns ``Recorded``: ``slow`` when the statement crossed
         the slow threshold (the caller flags its trace ``slow`` for
-        the flight recorder), and the digest's place and mean."""
-        digest = normalize_sql(sql)
+        the flight recorder), and the digest's place and mean.
+        ``digest``: ``normalize_sql(sql)`` where the caller has it
+        (the session's statement memo); computed here otherwise."""
+        digest = digest or normalize_sql(sql)
         now = time.time()
         with self._lock:
             st = self._stats.get(digest)
